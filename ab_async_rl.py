@@ -41,6 +41,9 @@ green. The headline number is the first hardware round: collect MFU
 schedule's upper bound is hiding all of epoch-1 plus the drain inside
 that window. See AB_ASYNC_RL.json for the latest dated record per
 (metric, device_kind).
+
+A CPU run of this script is a plumbing check (the model auto-shrinks) and
+records nothing under a device's name.
 """
 
 import json

@@ -28,6 +28,9 @@ identically, docs/serving.md parity caveat).
 Self-recording: updates ``AB_CHUNKED_PREFILL.json`` (latest record per
 metric + device kind, ``utils/ab_record.py``) and appends a run-ledger
 manifest (``telemetry/run_ledger.py``).
+
+A CPU run of this script is a plumbing check (the model auto-shrinks) and
+records nothing under a device's name.
 """
 
 import json

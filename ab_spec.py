@@ -25,6 +25,9 @@ which is the regime speculation targets (templated/repetitive spans).
 Self-recording: updates ``AB_SPEC.json`` (latest record per metric +
 device kind, ``utils/ab_record.py``) and appends a run-ledger manifest
 (``telemetry/run_ledger.py``).
+
+A CPU run of this script is a plumbing check (the model auto-shrinks) and
+records nothing under a device's name.
 """
 
 import json

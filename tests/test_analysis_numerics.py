@@ -274,7 +274,7 @@ def _psum_sequence_jaxpr(axis_ops):
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from trlx_tpu.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()), ("ax",))
 

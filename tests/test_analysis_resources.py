@@ -106,7 +106,7 @@ def test_collective_cost_model_counts_and_ring_bytes():
     from jax.sharding import PartitionSpec as P
 
     from trlx_tpu.analysis import resource_audit as ra
-    from trlx_tpu.compat import shard_map
+    from jax import shard_map
     from trlx_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh({"dp": -1, "fsdp": 1, "tp": 1})
@@ -188,7 +188,7 @@ def test_collective_bytes_regression_fires_on_new_collective():
     from jax.sharding import PartitionSpec as P
 
     from trlx_tpu.analysis import resource_audit as ra
-    from trlx_tpu.compat import shard_map
+    from jax import shard_map
     from trlx_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh({"dp": -1, "fsdp": 1, "tp": 1})

@@ -147,26 +147,23 @@ def test_ring_pallas_inner_integration_interpret():
     kv_mask = np.ones((B, T), np.int32)
     kv_mask[0, T - 3 :] = 0  # noqa: same mask row exercised across shards
 
-    old = ra._FORCE_PALLAS_BLOCKS
-    ra._FORCE_PALLAS_BLOCKS = True
-    try:
-        out = ra.ring_attention_sharded(
-            q, k, v, mesh, kv_mask=jnp.asarray(kv_mask), causal=True
-        )
-        expected = dense_reference(q, k, v, kv_mask, True)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(expected), atol=2e-5
-        )
+    out = ra.ring_attention_sharded(
+        q, k, v, mesh, kv_mask=jnp.asarray(kv_mask), causal=True,
+        interpret_blocks=True,
+    )
+    expected = dense_reference(q, k, v, kv_mask, True)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(expected), atol=2e-5
+    )
 
-        def loss(q, k, v):
-            o = ra.ring_attention_sharded(
-                q, k, v, mesh, kv_mask=jnp.asarray(kv_mask), causal=True
-            )
-            return jnp.sum(o ** 2)
+    def loss(q, k, v):
+        o = ra.ring_attention_sharded(
+            q, k, v, mesh, kv_mask=jnp.asarray(kv_mask), causal=True,
+            interpret_blocks=True,
+        )
+        return jnp.sum(o ** 2)
 
-        g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    finally:
-        ra._FORCE_PALLAS_BLOCKS = old
+    g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     def dense_loss(q, k, v):
         return jnp.sum(dense_reference(q, k, v, kv_mask, True) ** 2)

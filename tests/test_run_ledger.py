@@ -223,7 +223,6 @@ def test_attribution_hand_computed_mfu():
     # HBM: 60 MB x 20 / 2 s = 600 MB/s over the 819 GB/s peak
     assert step.achieved_gbps_per_dev == pytest.approx(0.6)
     assert step.hbm_util == pytest.approx(0.6 / 819.0)
-    assert not step.peak_nominal
     roll = by_program["ppo.rollout"]
     # 2e11 x 10 / 5 s = 4e11 FLOP/s = 0.4 TFLOP/s
     assert roll.achieved_tflops_per_dev == pytest.approx(0.4)
@@ -238,7 +237,7 @@ def test_attribution_hand_computed_mfu():
     assert step2.achieved_gbps_per_dev == pytest.approx(0.6)
 
 
-def test_attribution_count_key_nominal_and_missing():
+def test_attribution_count_key_unknown_device_and_missing():
     from trlx_tpu.telemetry import attribution as A
 
     resources = {"ppo.engine_decode_step": {"flops": 1.0e9}}
@@ -246,28 +245,29 @@ def test_attribution_count_key_nominal_and_missing():
         "ppo.engine_decode_step", "phase/collect",
         count_key="engine/decode_steps",
     ),)
+    kind = "TPU v5 lite"
     # count from the stats dict, not any span
     rows = A.attribute(
-        resources, _span_stats(), "cpu", work=work,
+        resources, _span_stats(), kind, work=work,
         counts={"engine/decode_steps": 500.0},
     )
     assert rows[0].calls == 500.0
-    # cpu prices off the documented nominal peaks and says so
-    assert rows[0].peak_nominal and rows[0].mfu is not None
     assert rows[0].mfu == pytest.approx(
-        1.0e9 * 500 / 5.0 / 1e12 / A.NOMINAL_PEAKS["cpu"][0]
+        1.0e9 * 500 / 5.0 / 1e12 / A.BF16_PEAK_TFLOPS[kind]
     )
-    # an unknown backend renders no utilization rather than lying
-    rows = A.attribute(
-        resources, _span_stats(), "Quantum Abacus", work=work,
-        counts={"engine/decode_steps": 500.0},
-    )
-    assert rows[0].mfu is None and rows[0].hbm_util is None
+    # a device without a published spec (the CPU included) is an error,
+    # never an assumed peak
+    for unknown in ("cpu", "Quantum Abacus"):
+        with pytest.raises(ValueError, match="no published peaks"):
+            A.attribute(
+                resources, _span_stats(), unknown, work=work,
+                counts={"engine/decode_steps": 500.0},
+            )
     # zero counts / missing programs / missing spans yield no row
     assert A.attribute(
-        resources, _span_stats(), "cpu", work=work, counts={}
+        resources, _span_stats(), kind, work=work, counts={}
     ) == []
-    assert A.attribute({}, _span_stats(), "cpu", work=work) == []
+    assert A.attribute({}, _span_stats(), kind, work=work) == []
 
 
 def test_bubble_breakdown_and_goodput():

@@ -53,7 +53,10 @@ def _emit_smoke(summary, format_smoke_text, as_json: bool) -> int:
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m trlx_tpu.analysis",
-        description="jaxpr + AST static analysis for the TPU port",
+        description="jaxpr + AST static analysis for the TPU port. A CPU "
+        "tool by design: every mode that builds programs forces "
+        "JAX_PLATFORMS=cpu and an 8-device virtual mesh unless the "
+        "environment already sets them, and never touches an accelerator.",
     )
     parser.add_argument(
         "--engine",

@@ -50,15 +50,15 @@ from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 from trlx_tpu.analysis.findings import Finding, Report, filter_suppressed
 from trlx_tpu.analysis.registry import get_rule
 
-# loggers that carry the compile/trace records we count (jax 0.4.x:
-# pxla logs "Compiling <name> with global shapes and types [...]" once
-# per actual backend compile; dispatch logs the trace/compile timings)
+# loggers that carry the compile/trace records we count: pxla logs
+# "Compiling jit(<name>) with global shapes and types [...]" once per
+# actual backend compile; dispatch logs the trace/compile timings
 _JAX_COMPILE_LOGGERS = (
     "jax._src.interpreters.pxla",
     "jax._src.dispatch",
 )
 
-_COMPILING_RE = re.compile(r"^Compiling ([^\s]+) with global shapes and types (.*)$", re.S)
+_COMPILING_RE = re.compile(r"^Compiling jit\(([^\s)]+)\) with global shapes and types (.*)$", re.S)
 _TRACING_RE = re.compile(r"^Finished tracing \+ transforming ([^\s]+) for pjit in ([0-9.eE+-]+) sec")
 _COMPILED_RE = re.compile(r"^Finished XLA compilation of jit\(([^\s)]+)\) in ([0-9.eE+-]+) sec")
 

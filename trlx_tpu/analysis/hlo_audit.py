@@ -1302,17 +1302,14 @@ def compiled_step_stats(trainer, kind: str) -> Dict[str, float]:
         ),
         "compiled_train_step_collectives": float(len(collectives)),
     }
-    try:
-        mem = compiled.memory_analysis()
-        peak = (
-            int(getattr(mem, "temp_size_in_bytes", 0))
-            + int(getattr(mem, "argument_size_in_bytes", 0))
-            + int(getattr(mem, "output_size_in_bytes", 0))
-            - int(getattr(mem, "alias_size_in_bytes", 0))
-        )
-        stats["compiled_train_step_peak_hbm_gb"] = max(0, peak) / 2**30
-    except Exception:
-        pass
+    mem = compiled.memory_analysis()
+    peak = (
+        mem.temp_size_in_bytes
+        + mem.argument_size_in_bytes
+        + mem.output_size_in_bytes
+        - mem.alias_size_in_bytes
+    )
+    stats["compiled_train_step_peak_hbm_gb"] = max(0, peak) / 2**30
     return stats
 
 

@@ -357,13 +357,13 @@ def analyze_closed_jaxpr(
     """Resources of one traced program (``jax.make_jaxpr`` output).
 
     When the program is a jitted callable, the outer jaxpr holds a single
-    pjit eqn: the analysis uses its ``donated_invars`` and recurses its
+    ``jit`` eqn: the analysis uses its ``donated_invars`` and recurses its
     body; a bare (un-jitted) jaxpr is analyzed directly, undonated.
     """
     outer = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
     axis_sizes = axis_sizes or {}
     target, donated, divisors = outer, None, input_divisors
-    pjit_eqns = [e for e in outer.eqns if e.primitive.name == "pjit"]
+    pjit_eqns = [e for e in outer.eqns if e.primitive.name == "jit"]
     if len(outer.eqns) == 1 and pjit_eqns:
         eqn = pjit_eqns[0]
         target = eqn.params["jaxpr"].jaxpr

@@ -51,7 +51,9 @@ def train(
         reward fn (the fork's tsv pairs as a proper argument).
     """
     from trlx_tpu.ops.ilql_math import ILQLConfig
+    from trlx_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if reward_fn is not None:
         config = config or TRLConfig.load_yaml(_DEFAULT_PPO_CONFIG)
         if isinstance(config.method, ILQLConfig):

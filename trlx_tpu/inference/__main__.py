@@ -7,6 +7,10 @@ serving process path), submit a prompt batch, and assert every request
 completes with zero health events. Prints one JSON line with the
 completion lengths and the engine's occupancy stats so the job log shows
 what the engine actually did.
+
+The smokes run on whatever platform the environment gives jax (the chip on
+a TPU host); CI and the tests export ``JAX_PLATFORMS=cpu`` and an 8-device
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` themselves.
 """
 
 from __future__ import annotations
@@ -16,17 +20,6 @@ import json
 import os
 import sys
 import tempfile
-
-
-def _force_cpu_platform() -> None:
-    if "jax" in sys.modules:
-        return
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8"
-        ).strip()
 
 
 def serving_smoke(mesh=None, n_prompts: int = 6) -> int:
@@ -433,7 +426,6 @@ def multi_tenant_smoke(mesh=None, span_log=None) -> int:
 
 
 def main(argv=None) -> int:
-    _force_cpu_platform()
     parser = argparse.ArgumentParser(
         prog="python -m trlx_tpu.inference",
         description="continuous-batching serving utilities",

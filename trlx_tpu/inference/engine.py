@@ -1142,8 +1142,19 @@ class ContinuousBatchingEngine:
             )
 
         if self.mesh is not None and self._param_shardings is not None:
-            from trlx_tpu.parallel.mesh import batch_sharding, replicated
+            from trlx_tpu.parallel.mesh import (
+                batch_sharding,
+                replicated,
+                traced_on,
+            )
 
+            # the programs that run a model forward over prompt columns
+            # declare their mesh (long prompts route attention to the
+            # flash kernels, which need it — parallel/mesh.py::traced_on)
+            prefill = traced_on(self.mesh, prefill)
+            prefill_chunks = traced_on(self.mesh, prefill_chunks)
+            prefill_finish = traced_on(self.mesh, prefill_finish)
+            verify_step = traced_on(self.mesh, verify_step)
             state_sh = self.state_sharding()
             batch_sh = batch_sharding(self.mesh)
             rep = replicated(self.mesh)

@@ -143,7 +143,9 @@ class InferenceServer:
         from trlx_tpu.serving.streaming import StreamRouter
         from trlx_tpu.telemetry.health import HealthConfig, HealthMonitor
         from trlx_tpu.trainer.ppo_trainer import get_causal_arch
+        from trlx_tpu.utils.compile_cache import enable_compile_cache
 
+        enable_compile_cache()
         if not isinstance(config, TRLConfig):
             config = TRLConfig.from_dict(config)
         self.config = config

@@ -15,10 +15,12 @@ Softmax runs in float32 regardless of compute dtype (bf16 logits lose
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e9  # large-negative mask value; avoids -inf NaN propagation in softmax
 
@@ -108,6 +110,53 @@ def causal_dispatch(
     return combine_biases(causal_bias(q_len, kv_len, offset=offset), pad), False
 
 
+def flash_on_program_mesh(q, k, v, bias=None, *, causal=False,
+                          interpret=False):
+    """The flash kernels over the mesh of the program being traced.
+
+    XLA refuses to partition a Mosaic kernel, so inside a GSPMD program on
+    more than one device the call is wrapped in a ``shard_map`` over the
+    program's mesh (:func:`trlx_tpu.parallel.mesh.traced_on` declares it):
+    batch split over dp x fsdp, heads over tp — attention is independent
+    across both, so every device runs the kernel on its own shard and
+    nothing is gathered. A single-device program, and a call already inside
+    a ``shard_map`` body (the pipeline's stages), run the kernel as it is.
+    """
+    from trlx_tpu.ops.flash_attention import flash_attention
+    from trlx_tpu.parallel.mesh import AXIS_TP, BATCH_AXES, program_mesh
+
+    kernel = functools.partial(
+        flash_attention, causal=causal, interpret=interpret
+    )
+    mesh = program_mesh()
+    if (
+        mesh is None
+        or mesh.size == 1
+        or jax.sharding.get_abstract_mesh().manual_axes
+    ):
+        return kernel(q, k, v, bias)
+
+    batch = tuple(a for a in BATCH_AXES if a in mesh.axis_names) or None
+    heads = AXIS_TP if AXIS_TP in mesh.axis_names else None
+    qkv = P(batch, None, heads, None)
+    if bias is None:
+        args, specs = (q, k, v), (qkv, qkv, qkv)
+    else:
+        # size-1 (broadcast) bias dims stay whole on every device
+        bias_spec = P(
+            batch if bias.shape[0] > 1 else None,
+            heads if bias.shape[1] > 1 else None,
+            None,
+            None,
+        )
+        args, specs = (q, k, v, bias), (qkv, qkv, qkv, bias_spec)
+    # pallas_call outputs carry no varying-axes annotation, which trips
+    # shard_map's check (same as ring_attention_sharded)
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=specs, out_specs=qkv, check_vma=False
+    )(*args)
+
+
 def dot_product_attention(
     q: jax.Array,  # [B, Q, H, D]
     k: jax.Array,  # [B, K, H, D]
@@ -135,9 +184,7 @@ def dot_product_attention(
         and min(Q, K) >= FLASH_MIN_SEQ
         and jax.default_backend() == "tpu"
     ):
-        from trlx_tpu.ops.flash_attention import flash_attention
-
-        return flash_attention(q, k, v, bias, causal=causal)
+        return flash_on_program_mesh(q, k, v, bias, causal=causal)
 
     if causal:
         bias = combine_biases(causal_bias(Q, K), bias)

@@ -44,8 +44,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from trlx_tpu.compat import pallas_tpu_compiler_params
-
 from trlx_tpu.ops.attention import NEG_INF
 
 BLOCK_Q = 512  # best on v5e across 1k-4k sequences (see tests/test_flash_attention.py)
@@ -195,7 +193,7 @@ def _fwd(q, k, v, bias, *, scale, block_q, block_k, causal, interpret):
             pltpu.VMEM((block_q, LANES), jnp.float32),  # running sum
             pltpu.VMEM((block_q, D), jnp.float32),      # output accumulator
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -361,7 +359,7 @@ def _bwd(q, k, v, bias, o, lse, do, *, scale, block_q, block_k, causal,
         out_specs=q_tile_qk,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -403,7 +401,7 @@ def _bwd(q, k, v, bias, o, lse, do, *, scale, block_q, block_k, causal,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -446,12 +444,10 @@ def _flash_bwd(scale, block_q, block_k, causal, interpret, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _prep_block_inputs(q, k, v, bias, block_q, block_k, interpret, scale):
-    """Shared prologue for the kernel entry points: interpret default,
-    shrink-to-ceil8 tile sizes, [B, H, T, D] transpose + tile padding, bias
-    padding/masking, default 1/sqrt(D) scale."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def _prep_block_inputs(q, k, v, bias, block_q, block_k, scale):
+    """Shared prologue for the kernel entry points: shrink-to-ceil8 tile
+    sizes, [B, H, T, D] transpose + tile padding, bias padding/masking,
+    default 1/sqrt(D) scale."""
     D = q.shape[-1]
     if scale is None:
         scale = float(1.0 / (D ** 0.5))
@@ -462,12 +458,12 @@ def _prep_block_inputs(q, k, v, bias, block_q, block_k, interpret, scale):
     kt, _ = _pad_to(jnp.transpose(k, (0, 2, 1, 3)), 2, block_k)
     vt, _ = _pad_to(jnp.transpose(v, (0, 2, 1, 3)), 2, block_k)
     bias = _prepare_bias(bias, kt.shape[2], K, block_q, block_k)
-    return qt, kt, vt, bias, block_q, block_k, interpret, scale
+    return qt, kt, vt, bias, block_q, block_k, scale
 
 
 def flash_block_fwd(q, k, v, bias, scale: Optional[float] = None,
                     block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
-                    interpret: Optional[bool] = None):
+                    interpret: bool = False):
     """Single-block forward returning the logsumexp — the building block for
     cross-block softmax combination (ring attention over the sp axis).
 
@@ -478,8 +474,8 @@ def flash_block_fwd(q, k, v, bias, scale: Optional[float] = None,
     :func:`flash_block_bwd`.
     """
     Q = q.shape[1]
-    qt, kt, vt, bias, block_q, block_k, interpret, scale = _prep_block_inputs(
-        q, k, v, bias, block_q, block_k, interpret, scale
+    qt, kt, vt, bias, block_q, block_k, scale = _prep_block_inputs(
+        q, k, v, bias, block_q, block_k, scale
     )
     o, lse = _fwd(
         qt, kt, vt, bias, scale=scale, block_q=block_q, block_k=block_k,
@@ -490,7 +486,7 @@ def flash_block_fwd(q, k, v, bias, scale: Optional[float] = None,
 
 def flash_block_bwd(q, k, v, bias, o, lse, do, scale: Optional[float] = None,
                     block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
-                    interpret: Optional[bool] = None):
+                    interpret: bool = False):
     """Single-block backward against an *external* (combined) logsumexp.
 
     Layouts: q/k/v [B, T, H, D]; o/do [B, H, Tq, D]; lse [B, H, Tq].
@@ -502,9 +498,9 @@ def flash_block_bwd(q, k, v, bias, o, lse, do, scale: Optional[float] = None,
     """
     B, Q, H, D = q.shape
     K = k.shape[1]
-    qt, kt, vt, bias, block_q, block_k, interpret, scale = _prep_block_inputs(
+    qt, kt, vt, bias, block_q, block_k, scale = _prep_block_inputs(
         q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
-        bias, block_q, block_k, interpret, scale,
+        bias, block_q, block_k, scale,
     )
     Qp = qt.shape[2]
     op, _ = _pad_to(o.astype(jnp.float32), 2, block_q)
@@ -558,7 +554,7 @@ def flash_attention(
     causal: bool = False,
     block_q: int = BLOCK_Q,
     block_k: int = BLOCK_K,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Flash attention over the framework's [B, T, H, D] layout.
 
@@ -571,10 +567,15 @@ def flash_attention(
     ``causal`` assumes query position i is absolute position i (offset 0) —
     the training / prefill case. For cache decode at an offset, pass an
     explicit bias.
+
+    The kernels are compiled by Mosaic for the TPU. ``interpret=True`` runs
+    them through the Pallas interpreter instead (any backend, slow) — a
+    caller asks for it by name (the CPU tests do); it is never inferred
+    from the platform.
     """
     Q = q.shape[1]
-    qt, kt, vt, bias, block_q, block_k, interpret, scale = _prep_block_inputs(
-        q, k, v, bias, block_q, block_k, interpret, None
+    qt, kt, vt, bias, block_q, block_k, scale = _prep_block_inputs(
+        q, k, v, bias, block_q, block_k, None
     )
     out = _flash(qt, kt, vt, bias, scale, block_q, block_k, causal, interpret)
     out = out[:, :, :Q, :]

@@ -108,23 +108,20 @@ def _block_bias(mask_blk, q_pos, k_pos, causal):
     return bias
 
 
-# None -> auto (TPU + size crossover); True/False -> force. Tests force True
-# to run the ring+pallas integration in interpret mode on CPU.
-_FORCE_PALLAS_BLOCKS = None
-
-
-def _use_pallas_blocks(Tq: int, Tk: int) -> bool:
+def _use_pallas_blocks(Tq: int, Tk: int, interpret_blocks: bool) -> bool:
     """Per-device block sizes above which the pallas kernels take over the
     inner block computation on TPU (below, XLA's fused path wins — the same
-    measured crossover as the dense dispatch)."""
-    if _FORCE_PALLAS_BLOCKS is not None:
-        return _FORCE_PALLAS_BLOCKS
+    measured crossover as the dense dispatch). ``interpret_blocks`` is the
+    caller asking for the kernels in interpret mode at any size and on any
+    backend (the CPU tests of the ring <-> kernel hand-off)."""
     from trlx_tpu.ops.attention import FLASH_MIN_SEQ
 
-    return min(Tq, Tk) >= FLASH_MIN_SEQ and jax.default_backend() == "tpu"
+    return interpret_blocks or (
+        min(Tq, Tk) >= FLASH_MIN_SEQ and jax.default_backend() == "tpu"
+    )
 
 
-def _block_fwd(q, k_blk, v_blk, bias, scale):
+def _block_fwd(q, k_blk, v_blk, bias, scale, interpret_blocks):
     """Per-block attention with logsumexp.
 
     q [B, Tq, H, D]; k/v [B, Tk, H, D]; bias [B, 1, Tq, Tk].
@@ -132,10 +129,12 @@ def _block_fwd(q, k_blk, v_blk, bias, scale):
     lse [B, H, Tq] f32). Large blocks on TPU run the pallas flash kernel
     (the [Tq, Tk] score matrix stays in VMEM tiles).
     """
-    if _use_pallas_blocks(q.shape[1], k_blk.shape[1]):
+    if _use_pallas_blocks(q.shape[1], k_blk.shape[1], interpret_blocks):
         from trlx_tpu.ops.flash_attention import flash_block_fwd
 
-        o, lse = flash_block_fwd(q, k_blk, v_blk, bias, scale=scale)
+        o, lse = flash_block_fwd(
+            q, k_blk, v_blk, bias, scale=scale, interpret=interpret_blocks
+        )
         return o.astype(jnp.float32), lse
     s = jnp.einsum(
         "bqhd,bkhd->bhqk", q.astype(jnp.float32), k_blk.astype(jnp.float32)
@@ -149,7 +148,8 @@ def _block_fwd(q, k_blk, v_blk, bias, scale):
     return o, lse
 
 
-def _block_bwd(q, k_blk, v_blk, bias, o, lse, do, delta, scale):
+def _block_bwd(q, k_blk, v_blk, bias, o, lse, do, delta, scale,
+               interpret_blocks):
     """Per-block gradients against the *global* (combined) logsumexp.
 
     ``o``/``do``/``delta`` are the GLOBAL combined output, its cotangent,
@@ -159,10 +159,13 @@ def _block_bwd(q, k_blk, v_blk, bias, o, lse, do, delta, scale):
     (dq [B,Tq,H,D], dk, dv [B,Tk,H,D]) in f32. Large blocks on TPU run the
     pallas backward kernels.
     """
-    if _use_pallas_blocks(q.shape[1], k_blk.shape[1]):
+    if _use_pallas_blocks(q.shape[1], k_blk.shape[1], interpret_blocks):
         from trlx_tpu.ops.flash_attention import flash_block_bwd
 
-        return flash_block_bwd(q, k_blk, v_blk, bias, o, lse, do, scale=scale)
+        return flash_block_bwd(
+            q, k_blk, v_blk, bias, o, lse, do, scale=scale,
+            interpret=interpret_blocks,
+        )
     q32 = q.astype(jnp.float32)
     s = jnp.einsum(
         "bqhd,bkhd->bhqk", q32, k_blk.astype(jnp.float32)
@@ -176,7 +179,7 @@ def _block_bwd(q, k_blk, v_blk, bias, o, lse, do, delta, scale):
     return dq, dk, dv
 
 
-def _ring_fwd(q, k, v, kv_mask, axis_name, causal):
+def _ring_fwd(q, k, v, kv_mask, axis_name, causal, interpret_blocks):
     n = jax.lax.psum(1, axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, Tq, H, D = q.shape
@@ -190,7 +193,7 @@ def _ring_fwd(q, k, v, kv_mask, axis_name, causal):
         src = (idx - i) % n
         k_pos = src * Tk + jnp.arange(Tk)
         bias = _block_bias(mask_blk, q_pos, k_pos, causal)
-        o_i, lse_i = _block_fwd(q, k_blk, v_blk, bias, scale)
+        o_i, lse_i = _block_fwd(q, k_blk, v_blk, bias, scale, interpret_blocks)
 
         # combine softmax-normalized block results by their logsumexp weights
         m_new = jnp.maximum(lse, lse_i)
@@ -215,7 +218,8 @@ def _ring_fwd(q, k, v, kv_mask, axis_name, causal):
     return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype), lse
 
 
-def _ring_bwd(q, k, v, kv_mask, out, lse, dout, axis_name, causal):
+def _ring_bwd(q, k, v, kv_mask, out, lse, dout, axis_name, causal,
+              interpret_blocks):
     """Second ring pass: recompute per-block softmax weights from the saved
     global logsumexp (exact — no stored score matrices) and accumulate dq
     locally while dk/dv ride the rotating buffers; after the full circle
@@ -239,7 +243,8 @@ def _ring_bwd(q, k, v, kv_mask, out, lse, dout, axis_name, causal):
         k_pos = src * Tk + jnp.arange(Tk)
         bias = _block_bias(mask_blk, q_pos, k_pos, causal)
         dq_i, dk_i, dv_i = _block_bwd(
-            q, k_blk, v_blk, bias, o32, lse, do, delta, scale
+            q, k_blk, v_blk, bias, o32, lse, do, delta, scale,
+            interpret_blocks,
         )
         dq = dq + dq_i
         dk_blk = dk_blk + dk_i
@@ -260,25 +265,28 @@ def _ring_bwd(q, k, v, kv_mask, out, lse, dout, axis_name, causal):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def ring_flash_attention(q, k, v, kv_mask, axis_name="sp", causal=True):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def ring_flash_attention(q, k, v, kv_mask, axis_name="sp", causal=True,
+                         interpret_blocks=False):
     """Ring attention with flash-style memory: the backward pass recomputes
     block scores from the saved (output, logsumexp) instead of autodiff
     storing every rotation's [Tq, Tk] score matrix — per-device residual
     memory is O(Tq·D) rather than O(Tq·T_global). Same semantics/layout as
     :func:`ring_attention`; call inside shard_map."""
-    out, _ = _ring_fwd(q, k, v, kv_mask, axis_name, causal)
+    out, _ = _ring_fwd(q, k, v, kv_mask, axis_name, causal, interpret_blocks)
     return out
 
 
-def _rfa_fwd(q, k, v, kv_mask, axis_name, causal):
-    out, lse = _ring_fwd(q, k, v, kv_mask, axis_name, causal)
+def _rfa_fwd(q, k, v, kv_mask, axis_name, causal, interpret_blocks):
+    out, lse = _ring_fwd(q, k, v, kv_mask, axis_name, causal, interpret_blocks)
     return out, (q, k, v, kv_mask, out, lse)
 
 
-def _rfa_bwd(axis_name, causal, res, dout):
+def _rfa_bwd(axis_name, causal, interpret_blocks, res, dout):
     q, k, v, kv_mask, out, lse = res
-    dq, dk, dv = _ring_bwd(q, k, v, kv_mask, out, lse, dout, axis_name, causal)
+    dq, dk, dv = _ring_bwd(
+        q, k, v, kv_mask, out, lse, dout, axis_name, causal, interpret_blocks
+    )
     return dq, dk, dv, None
 
 
@@ -295,6 +303,7 @@ def ring_attention_sharded(
     batch_axes=("dp", "fsdp"),
     causal: bool = True,
     impl: str = "flash",  # "flash" (recompute bwd) | "naive" (autodiff)
+    interpret_blocks: bool = False,
 ) -> jax.Array:
     """shard_map wrapper: shards T over ``axis_name``, B over batch axes.
 
@@ -302,18 +311,25 @@ def ring_attention_sharded(
     custom VJP recomputes block scores in a second ring pass — per-device
     residuals stay O(Tq·D) at any global length. ``impl="naive"`` keeps the
     autodiff path (stores each rotation's score panel; useful as a
-    reference)."""
-    from trlx_tpu.compat import shard_map
+    reference). ``interpret_blocks=True`` (``impl="flash"`` only) runs the
+    per-block math through the pallas kernels in interpret mode whatever
+    the block size or backend."""
+    from jax import shard_map
 
     qkv_spec = P(batch_axes, axis_name, None, None)
     mask_spec = P(batch_axes, axis_name)
 
     if impl not in ("flash", "naive"):
         raise ValueError(f"impl must be 'flash' or 'naive', got {impl!r}")
-    base = ring_flash_attention if impl == "flash" else ring_attention
+    if interpret_blocks and impl != "flash":
+        raise ValueError('interpret_blocks needs impl="flash"')
 
     def fn(q, k, v, m):  # custom_vjp requires positional args
-        return base(q, k, v, m, axis_name, causal)
+        if impl == "naive":
+            return ring_attention(q, k, v, m, axis_name, causal)
+        return ring_flash_attention(
+            q, k, v, m, axis_name, causal, interpret_blocks
+        )
     if kv_mask is None:
         kv_mask = jnp.ones(q.shape[:2], jnp.int32)
     # pallas_call outputs carry no vma annotation, which trips shard_map's
@@ -322,7 +338,7 @@ def ring_attention_sharded(
     # size) keep the safety check.
     sp = mesh.shape[axis_name]
     pallas_blocks = impl == "flash" and _use_pallas_blocks(
-        q.shape[1] // sp, k.shape[1] // sp
+        q.shape[1] // sp, k.shape[1] // sp, interpret_blocks
     )
     return shard_map(
         fn,

@@ -268,8 +268,8 @@ class PPOOrchestrator(Orchestrator):
         )
         # Start the device->host copy of what decode_responses will need as
         # soon as the sampler finishes (the copy is scheduled behind the
-        # computation): by the time the host fetches, the ~100ms transfer
-        # has already overlapped the previous chunk's scoring.
+        # computation): by the time the host fetches, the transfer has
+        # already overlapped the previous chunk's scoring.
         for arr in (sample_out.tokens, sample_out.response_mask):
             try:
                 arr.copy_to_host_async()
@@ -282,9 +282,10 @@ class PPOOrchestrator(Orchestrator):
         configured rollout engine (``train.rollout``): the fixed-batch
         double-buffered chunk loop (the default and parity baseline), or
         the continuous-batching slot-admission engine
-        (docs/inference.md). An engine-path failure degrades gracefully
-        to the fixed sampler — a health event and a restarted phase, not
-        an aborted run (docs/resilience.md)."""
+        (docs/inference.md). A TRANSIENT engine-path failure
+        (``utils/retry.py::classify_io_error``) degrades to the fixed
+        sampler — a health event and a restarted phase, not an aborted
+        run (docs/resilience.md); anything else propagates as itself."""
         if getattr(self.trainer, "rollout_engine", "fixed") == "continuous":
             try:
                 return self._make_experience_continuous(
@@ -317,6 +318,14 @@ class PPOOrchestrator(Orchestrator):
                     self._engine_error = None
                     raise
                 self._engine_error = None
+                from trlx_tpu.utils.retry import classify_io_error
+
+                if classify_io_error(e) != "transient":
+                    # a compile error, a VMEM/HBM out-of-memory, a shape
+                    # the chip refuses, a programming error: swapping
+                    # samplers under those would report a run "on the
+                    # engine" that never ran on it
+                    raise
                 self._degrade_engine(e, iter_count)
         return self._make_experience_fixed(num_rollouts, iter_count)
 
@@ -359,7 +368,8 @@ class PPOOrchestrator(Orchestrator):
 
     def _degrade_engine(self, error: BaseException, iter_count: int) -> None:
         """Fall back from the continuous engine to the fixed sampler for
-        the rest of the run: flip the trainer's engine selection (the
+        the rest of the run (``make_experience`` calls this for transient
+        failures only): flip the trainer's engine selection (the
         fixed sampler is always compiled — evaluation uses it), emit an
         ``engine-fallback`` health event (warning severity: degradation
         is the alternative to the abort policy, never its trigger), and

@@ -20,7 +20,9 @@ there is no explicit NCCL-equivalent call-site in the framework.
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Sequence
+import contextvars
+import functools
+from typing import Callable, Dict, Optional, Sequence
 
 import jax
 import numpy as np
@@ -83,6 +85,43 @@ def make_mesh(
     return Mesh(
         device_array, (AXIS_DP, AXIS_FSDP, AXIS_TP, AXIS_SP, AXIS_PP, AXIS_EP)
     )
+
+
+_PROGRAM_MESH: contextvars.ContextVar = contextvars.ContextVar(
+    "trlx_tpu_program_mesh", default=None
+)
+
+
+def program_mesh() -> Optional[Mesh]:
+    """The mesh of the program jax is tracing right now, as declared by
+    :func:`traced_on`; ``None`` outside one."""
+    return _PROGRAM_MESH.get()
+
+
+def traced_on(mesh: Mesh, fn: Callable) -> Callable:
+    """``fn``, for ``jax.jit``, with ``mesh`` declared as the mesh its
+    program runs on for as long as jax traces it.
+
+    Model code is mesh-agnostic — GSPMD partitions it from the shardings at
+    the jit boundary — and stays so. The one op that must know its mesh is
+    a Mosaic kernel: XLA will not partition it ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map", jax
+    0.9.0), and a ``shard_map`` needs the mesh at trace time, deep inside
+    the model. Whoever builds a jitted program knows its mesh; wrapping the
+    traced function here hands it down without threading it through every
+    module (``ops/attention.py`` reads :func:`program_mesh`). Scoped to
+    the trace, so two programs on two meshes — the learner's and an actor
+    subset's — never see each other's."""
+
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        token = _PROGRAM_MESH.set(mesh)
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _PROGRAM_MESH.reset(token)
+
+    return scoped
 
 
 def batch_sharding(mesh: Mesh) -> NamedSharding:

@@ -94,7 +94,7 @@ def moe_apply(
         y = jnp.where(keep[:, None], y, 0.0)
         return (y.astype(jnp.float32) * gate[:, None]).astype(x.dtype)
 
-    from trlx_tpu.compat import shard_map
+    from jax import shard_map
 
     param_specs = jax.tree_util.tree_map(
         lambda _: P(axis_name), stacked_expert_params

@@ -386,7 +386,7 @@ def pipeline_apply_cached(
         caps = jax.lax.psum(caps, axis_name)
         return outs.reshape(x.shape), cache, caps.reshape(x.shape)
 
-    from trlx_tpu.compat import shard_map
+    from jax import shard_map
 
     # Stage params enter shard_map sharded over pp ONLY: each device holds
     # its stage's L/S layers *fully materialized* for the loop's duration —
@@ -635,7 +635,7 @@ def pipeline_apply_remat(
             )
             return dparams, dxs.reshape(g.shape), daux
 
-        from trlx_tpu.compat import HAS_CHECK_VMA, shard_map
+        from jax import shard_map
 
         param_specs = jax.tree_util.tree_map(lambda _: P(axis_name), params)
         x_spec = P(batch_axes)
@@ -655,10 +655,6 @@ def pipeline_apply_remat(
                 x_spec,
                 jax.tree_util.tree_map(lambda _: P(batch_axes), aux_f_outer),
             ),
-            # dx/daux are psum'd inside local_bwd; newer jax's vma pass
-            # infers that replication, 0.4.x's check_rep cannot and rejects
-            # the out_specs — keep the check only where it can succeed
-            check_vma=None if HAS_CHECK_VMA else False,
         )(params, saves, a, g)
         return (
             _insert_float0(dparams, params),

@@ -25,14 +25,14 @@ accounting of the async schedule's bubbles. This module is the join:
   wall (collect + train + eval + checkpoint spans), the end-to-end
   number utilization percentages tend to flatter.
 
-Device peaks are the published per-chip specs (moved here from bench.py
-so bench and the attribution table can never disagree); backends
-without a published spec (CPU) fall back to a documented *nominal*
-entry so the table stays populated — those utilizations are only
-meaningful round-over-round on the same host, never against hardware.
+Device peaks are the published per-chip specs — the one table bench,
+``chip_smoke.py`` and the attribution rows all read. A ``device_kind``
+that is not in it (the CPU included) is an error, never a default: a
+utilization priced off an assumed peak is not a device number.
 
 Everything here is host-side arithmetic over dicts the caller already
 holds; nothing traces, compiles, or touches devices except
+:func:`require_tpu` (the device gate: it asks jax what it found) and
 :func:`trainer_program_resources`, which re-traces (tracing only, no
 compilation — the engine-7 pattern bench already pays for the train
 step) a LIVE trainer's programs at the real workload shape.
@@ -62,30 +62,40 @@ HBM_PEAK_GBPS = {
     "TPU v6 lite": 1640.0,  # v6e
 }
 
-# Nominal fallback peaks for backends with no published spec, so the
-# attribution table stays populated on a CPU run. A modern server
-# socket lands in this ballpark under XLA:CPU, but the point is
-# round-over-round comparability on ONE host, not absolute truth —
-# rows priced off these carry ``peak_nominal: true``.
-NOMINAL_PEAKS = {
-    "cpu": (0.2, 50.0),  # (tflops, GB/s)
-}
 
-
-def device_peaks(device_kind: str) -> Tuple[Optional[float], Optional[float], bool]:
-    """(peak_tflops, peak_gbps, nominal?) for a ``device_kind`` string;
-    (None, None, False) when neither a published nor a nominal entry
-    exists — utilization columns then render empty, honestly."""
-    if device_kind in BF16_PEAK_TFLOPS:
-        return (
-            BF16_PEAK_TFLOPS[device_kind],
-            HBM_PEAK_GBPS.get(device_kind),
-            False,
+def device_peaks(device_kind: str) -> Tuple[float, float]:
+    """(peak bf16 TFLOP/s, peak HBM GB/s) for a ``device_kind`` string as
+    jax reports it. Raises for a device missing from the table."""
+    if device_kind not in BF16_PEAK_TFLOPS:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r} (known: "
+            f"{sorted(BF16_PEAK_TFLOPS)}); utilization is only defined "
+            "against a published spec — add the device to "
+            "trlx_tpu/telemetry/attribution.py with its source"
         )
-    nominal = NOMINAL_PEAKS.get(device_kind.lower())
-    if nominal:
-        return nominal[0], nominal[1], True
-    return None, None, False
+    return BF16_PEAK_TFLOPS[device_kind], HBM_PEAK_GBPS[device_kind]
+
+
+def require_tpu() -> Dict[str, Any]:
+    """The device gate of every measurement path (``bench.py``,
+    ``chip_smoke.py``): jax must find a TPU whose ``device_kind`` has
+    published peaks, or this raises — a measurement path never falls back
+    to the CPU. Returns the device as jax reports it."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(
+            f"no TPU: jax found platform {dev.platform!r} "
+            f"({dev.device_kind}); this path reports device numbers and "
+            "runs on the chip only"
+        )
+    device_peaks(dev.device_kind)
+    return {
+        "platform": dev.platform,
+        "kind": dev.device_kind,
+        "count": len(jax.devices()),
+    }
 
 
 # ------------------------------- work maps -------------------------------- #
@@ -178,12 +188,11 @@ class AttributionRow:
     mbytes_per_call: float          # static boundary bytes / 1e6
     achieved_tflops_per_dev: float
     achieved_gbps_per_dev: float
-    mfu: Optional[float] = None
-    hbm_util: Optional[float] = None
-    peak_nominal: bool = False
+    mfu: float
+    hbm_util: float
 
     def to_dict(self) -> Dict[str, Any]:
-        out = {
+        return {
             "program": self.program,
             "span": self.span,
             "calls": self.calls,
@@ -192,14 +201,9 @@ class AttributionRow:
             "mbytes_per_call": round(self.mbytes_per_call, 3),
             "achieved_tflops_per_dev": round(self.achieved_tflops_per_dev, 4),
             "achieved_gbps_per_dev": round(self.achieved_gbps_per_dev, 2),
+            "mfu": round(self.mfu, 4),
+            "hbm_util": round(self.hbm_util, 4),
         }
-        if self.mfu is not None:
-            out["mfu"] = round(self.mfu, 4)
-        if self.hbm_util is not None:
-            out["hbm_util"] = round(self.hbm_util, 4)
-        if self.peak_nominal:
-            out["peak_nominal"] = True
-        return out
 
 
 def _static_bytes(res: Dict[str, Any]) -> float:
@@ -238,7 +242,7 @@ def attribute(
     traffic), so dividing again would understate HBM utilization by up
     to ``n_devices``×.
     """
-    peak_tf, peak_bw, nominal = device_peaks(device_kind)
+    peak_tf, peak_bw = device_peaks(device_kind)
     rows: List[AttributionRow] = []
     for item in work or PPO_FIXED_WORK:
         res = resources.get(item.program)
@@ -271,9 +275,8 @@ def attribute(
                 mbytes_per_call=nbytes / 1e6,
                 achieved_tflops_per_dev=achieved_tf,
                 achieved_gbps_per_dev=achieved_bw,
-                mfu=achieved_tf / peak_tf if peak_tf else None,
-                hbm_util=achieved_bw / peak_bw if peak_bw else None,
-                peak_nominal=nominal,
+                mfu=achieved_tf / peak_tf,
+                hbm_util=achieved_bw / peak_bw,
             )
         )
     return rows
@@ -487,29 +490,17 @@ def format_attribution(
         f"{'TFLOP/s':>9} {'MFU':>7} {'GB/s':>8} {'HBM%':>6}"
     )
     lines.append(header)
-    nominal = False
     for r in rows:
-        nominal = nominal or r.peak_nominal
-        # significant digits, not fixed decimals: tiny-shape/CPU runs
-        # produce MFUs like 4e-5 that fixed-point would render as 0
-        mfu = f"{r.mfu:>7.3g}" if r.mfu is not None else f"{'—':>7}"
-        bw = (
-            f"{100 * r.hbm_util:>6.3g}"
-            if r.hbm_util is not None
-            else f"{'—':>6}"
-        )
+        # significant digits, not fixed decimals: tiny shapes produce
+        # MFUs like 4e-5 that fixed-point would render as 0
         lines.append(
             f"  {r.program:24} {r.span:22} {r.calls:>7.0f} "
             f"{r.wall_ms:>10.1f} {r.achieved_tflops_per_dev:>9.3g} "
-            f"{mfu} {r.achieved_gbps_per_dev:>8.3g} {bw}"
+            f"{r.mfu:>7.3g} {r.achieved_gbps_per_dev:>8.3g} "
+            f"{100 * r.hbm_util:>6.3g}"
         )
     if not rows:
         lines.append("  (no program/span pairs observed)")
-    if nominal:
-        lines.append(
-            "  (utilizations priced off NOMINAL peaks — no published "
-            "spec for this backend; compare round-over-round only)"
-        )
     if bubbles:
         lines.append("async bubble breakdown (per phase):")
         wall = bubbles.get("phase_wall_ms", 0.0)

@@ -37,6 +37,7 @@ from trlx_tpu.parallel import (
     make_mesh,
     replicated,
 )
+from trlx_tpu.parallel.mesh import traced_on
 from trlx_tpu.trainer import BaseRLTrainer, register_trainer
 from trlx_tpu.trainer.common import (
     make_optimizer,
@@ -350,7 +351,7 @@ class ILQLTrainer(BaseRLTrainer):
             )
 
         self._train_step_jit = jax.jit(
-            train_step,
+            traced_on(self.mesh, train_step),
             in_shardings=(self.state_shardings, batch_sh),
             out_shardings=(self.state_shardings, rep),
             donate_argnums=(0,),
@@ -367,7 +368,7 @@ class ILQLTrainer(BaseRLTrainer):
             return jax.lax.scan(train_step, state, mbs)
 
         self._train_chunk_jit = jax.jit(
-            train_chunk,
+            traced_on(self.mesh, train_chunk),
             in_shardings=(self.state_shardings, self._stacked_batch_sh),
             out_shardings=(self.state_shardings, rep),
             donate_argnums=(0,),
@@ -487,7 +488,7 @@ class ILQLTrainer(BaseRLTrainer):
             "target": self.target_shardings,
         }
         self._sample_jit = jax.jit(
-            sampler,
+            traced_on(self.mesh, sampler),
             in_shardings=(bundle_shardings, batch_sh, batch_sh, rep),
             out_shardings=batch_sh,
         )
