@@ -1,0 +1,151 @@
+"""The plain float32 references against the program's own gpt2 and neox
+forward at a tiny size on the CPU, and the FLOP / byte / parameter
+arithmetic against the models' own parameter counts.
+
+Measured at this size over the five seeds below (PR 23, CPU; error of the
+program's logits against the reference, as a share of the reference
+logits' standard deviation, max over seeds):
+
+    family    program dtype   rms      max
+    gpt2      float32         4e-7     2.3e-6
+    neox      float32         3e-7     1.5e-6   (reference told "gelu_new")
+    neox      float32         2e-4     9e-4     (published erf GELU: the
+                                                program's tanh form differs)
+    gpt2      bfloat16        0.0091   0.056
+    neox      bfloat16        0.0079   0.038
+
+So float32 is held to 1e-5 (4x the largest max), which a bfloat16 or any
+lower-precision forward misses by three orders of magnitude; bfloat16 is
+held to rms 0.02 / max 0.15 (2x and 2.7x the largest), which float32 beats
+by four orders and an 8-bit forward (rms > 0.1 at this depth) would miss.
+The published-width tolerances that decide a run's ``correct`` are measured
+on the chip and kept, with their distributions, in benchmark/tolerances.json.
+"""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import arithmetic, checks, harness
+from benchmark.reference import FORWARD
+
+GPT2 = {"model_type": "gpt2", "vocab_size": 96, "n_positions": 64, "n_embd": 32,
+        "n_layer": 2, "n_head": 4}
+NEOX = {"model_type": "gpt_neox", "vocab_size": 96, "max_position_embeddings": 64,
+        "hidden_size": 32, "num_hidden_layers": 2, "num_attention_heads": 4,
+        "intermediate_size": 128, "rotary_pct": 0.25, "rotary_emb_base": 10000.0,
+        "use_parallel_residual": True}
+SEEDS = (0, 1, 2, 3, 2**31 + 4)
+TOL = {"float32": (1e-5, 1e-5), "bfloat16": (0.02, 0.15)}
+
+
+def program_and_reference(cfg, dtype, seed, hidden_act=None):
+    from trlx_tpu.models.registry import get_model_family
+
+    family = get_model_family(cfg["model_type"])
+    arch = family.config_cls.from_dict({**cfg, "dtype": dtype, "param_dtype": "float32"})
+    model = family.backbone_cls(arch)
+    params = model.init(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))["params"]
+    rng = np.random.default_rng(seed)
+    B, T = 4, 24
+    ids = rng.integers(0, cfg["vocab_size"], (B, T))
+    lens = rng.integers(4, T + 1, B)
+    mask = (np.arange(T)[None, :] >= (T - lens)[:, None]).astype(np.int32)  # left-padded
+    got = model.apply({"params": params}, jnp.asarray(ids), attention_mask=jnp.asarray(mask))
+    ref_cfg = dict(cfg, **({"hidden_act": hidden_act} if hidden_act else {}))
+    ref = FORWARD[cfg["model_type"]](params, ref_cfg, jnp.asarray(ids), jnp.asarray(mask))
+    m = mask.astype(bool)
+    return np.asarray(got["logits"])[m], np.asarray(ref)[m], params
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("cfg,act", [(GPT2, None), (NEOX, "gelu_new")], ids=["gpt2", "neox"])
+def test_program_agrees_with_the_plain_reference_over_five_seeds(cfg, act, dtype):
+    rms_tol, max_tol = TOL[dtype]
+    for seed in SEEDS:
+        got, ref, _ = program_and_reference(cfg, dtype, seed, act)
+        rms, mx = checks.error_stats(got, ref, scale=float(ref.std()))
+        assert rms <= rms_tol and mx <= max_tol, (seed, rms, mx)
+
+
+def test_lower_precision_than_stated_fails_the_float32_tolerance():
+    got, ref, _ = program_and_reference(GPT2, "bfloat16", 0)
+    rms, mx = checks.error_stats(got, ref, scale=float(ref.std()))
+    assert rms > 100 * TOL["float32"][0]
+
+
+def test_published_erf_gelu_differs_from_the_programs_tanh_by_under_1e3():
+    got, ref, _ = program_and_reference(NEOX, "float32", 0, "gelu")
+    rms, mx = checks.error_stats(got, ref, scale=float(ref.std()))
+    assert 1e-5 < mx < 2e-3
+
+
+@pytest.mark.parametrize("cfg", [GPT2, NEOX], ids=["gpt2", "neox"])
+def test_parameter_count_matches_the_programs_own_tree(cfg):
+    _, _, params = program_and_reference(cfg, "float32", 0)
+    own = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params))
+    assert arithmetic.backbone_params(arithmetic.model_shape(cfg)) == own
+
+
+@pytest.mark.parametrize("name,published", [("gpt2-medium", 354823168), ("pythia-1.4b", 1414647808)])
+def test_published_parameter_counts(name, published):
+    cf = harness.load_json("configs", f"{name}.json")
+    assert arithmetic.backbone_params(arithmetic.model_shape(cf)) == published == cf["parameters"]
+
+
+@pytest.mark.parametrize("cfg", [GPT2, NEOX], ids=["gpt2", "neox"])
+def test_flops_follow_the_matmul_parameters(cfg):
+    s = arithmetic.model_shape(cfg)
+    # a forward of n tokens with no attention context and the head on each
+    # costs 2 FLOPs per matmul parameter and token
+    n = 10
+    matmul = s["L"] * arithmetic.matmul_params_per_layer(s) + s["d"] * s["V"]
+    assert arithmetic.forward_flops(s, n, 0, n) == 2 * matmul * n
+    collect, train = arithmetic.ppo_phase_flops(s, Q=8, R=4, rollouts=2, ppo_epochs=3)
+    fwd = arithmetic.forward_flops(s, 12, 12 * 13 // 2, 4)
+    assert train == 3 * 2 * 3 * fwd  # epochs x rollouts x (forward + 2x backward)
+    _, pruned = arithmetic.ppo_phase_flops(s, 8, 4, 2, 3, unfrozen=1)
+    assert fwd * 6 < pruned < train  # the frozen trunk's backward is not required
+    assert collect > 2 * arithmetic.forward_flops(s, 12, 0, 0)
+
+
+@pytest.mark.parametrize("cfg", [GPT2, NEOX], ids=["gpt2", "neox"])
+def test_decode_step_bytes_count_weights_once_and_the_cache_by_dtype(cfg):
+    s = arithmetic.model_shape(cfg)
+    weights = s["L"] * arithmetic.block_params(s) + 2 * s["d"] + s["d"] * s["V"]
+    assert arithmetic.decode_step_bytes(s, 0, 0) == 2 * weights
+    one = arithmetic.decode_step_bytes(s, 1, 9, kv_bytes=2) - 2 * weights
+    assert one == 2 * s["L"] * 10 * s["d"] * 2
+    assert arithmetic.decode_step_bytes(s, 1, 9, kv_bytes=1) - 2 * weights == one / 2
+    assert arithmetic.decode_step_bytes(s, 0, 0, shards=4) == 2 * weights / 4
+
+
+def test_peaks_table_refuses_an_unknown_device():
+    assert arithmetic.load_peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
+    with pytest.raises(RuntimeError):
+        arithmetic.load_peaks("cpu")
+
+
+def test_tolerances_carry_their_measured_distribution():
+    table = harness.load_json("tolerances.json")
+    for key, tol in table["tolerances"].items():
+        assert set(tol) >= {"logits_rms_rel", "logits_max_rel", "logprob_rms", "logprob_max"}
+        assert key in table["measured"], key
+        for cell, dist in table["measured"][key].items():
+            for name, d in dist.items():  # every reading passed, with room
+                assert d["min"] <= d["median"] <= d["max"] < tol[name], (key, cell, name)
+
+
+def test_a_lower_precision_cache_than_stated_fails_the_bf16_tolerance():
+    """The int8 cache's measured log-probability error, every reading of
+    it, is over the tolerance a configuration stating a bf16 cache is held
+    to: the program cannot serve from a cheaper cache than it states and
+    still report ``correct``."""
+    table = harness.load_json("tolerances.json")
+    stated = table["tolerances"]["bfloat16/kv-bfloat16"]["logprob_rms"]
+    cheaper = [d["logprob_rms"]["min"] for d in table["measured"]["bfloat16/kv-int8"].values()]
+    assert cheaper and min(cheaper) > stated
