@@ -515,9 +515,9 @@ def test_stream_eligibility_rules():
     trainer.config.train.checkpoint_interval = 4
     assert not trainer._stream_eligible(0)
     trainer.config.train.checkpoint_interval = 10000
-    # profiler wants stepwise granularity
+    # a profiler window never changes the schedule it measures
     trainer.config.train.profile_dir = "/tmp/never"
-    assert not trainer._stream_eligible(0)
+    assert trainer._stream_eligible(0)
     trainer.config.train.profile_dir = None
     # fewer rollouts than one minibatch
     trainer.config.method.num_rollouts = 4

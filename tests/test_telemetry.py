@@ -759,10 +759,11 @@ def test_phase_profiler_close_is_crash_safe(tmp_path):
 
 
 def test_profile_phase_keeps_streaming_eligible():
-    """profile_dir alone forces the legacy stepwise path; the
-    single-phase window (profile_phase) must profile the streamed
-    schedule itself. The gate reads only config/orch, so a stub trainer
-    suffices — no model build."""
+    """Turning the profiler on must not change the schedule it
+    measures: profile_dir alone (a window over phase 0) and
+    profile_phase both leave the streamed phase eligible. The gate
+    reads only config/orch, so a stub trainer suffices — no model
+    build."""
     from types import SimpleNamespace
 
     from trlx_tpu.analysis import harness
@@ -773,7 +774,13 @@ def test_profile_phase_keeps_streaming_eligible():
     stub = SimpleNamespace(config=config, orch=object())
     eligible = lambda: PPOTrainer._stream_eligible(stub, 0)  # noqa: E731
     assert eligible()
+    from trlx_tpu.telemetry.profiler import PhaseProfiler
+
     config.train.profile_dir = "/tmp/prof"
-    assert not eligible()  # legacy first-steps trace
-    config.train.profile_phase = 0
+    assert eligible()  # profile_dir alone: phase 0, same schedule
+    alone = PhaseProfiler(config.train.profile_dir, config.train.profile_phase)
+    assert alone.enabled and alone.target == 0
+    config.train.profile_phase = 3
     assert eligible()  # windowed: streaming stays on
+    assert PhaseProfiler("/tmp/prof", 3).target == 3
+    assert not PhaseProfiler(None, None).enabled

@@ -211,9 +211,6 @@ EPHEMERAL_CONTRACTS: Dict[Tuple[str, str], str] = {
         "wall-clock phase profiler (host timing only — timings are "
         "not reproducible across runs by definition)"
     ),
-    ("PPOTrainer", "_profiling"): (
-        "bool latch for the profiler session; tied to _phase_profiler"
-    ),
     ("PPOTrainer", "logger"): (
         "run-scoped logger handle re-opened by learn(); sink, not state"
     ),

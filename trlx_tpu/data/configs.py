@@ -300,10 +300,10 @@ class TrainConfig:
     # when set, every collected rollout chunk is appended (one JSON line per
     # sample: query/response text + raw score) to rollouts_<iter>.jsonl here
     rollout_logging_dir: Optional[str] = None
-    # write a jax.profiler trace of the first ~10 optimizer steps here
-    # (SURVEY §5.1: timing stats + optional jax.profiler integration).
-    # With profile_phase set, this is instead the output directory of the
-    # single-phase window (and streaming stays enabled).
+    # output directory of the single-phase jax.profiler window (SURVEY
+    # §5.1: timing stats + optional jax.profiler integration); set
+    # without profile_phase it traces phase 0. The run's schedule is the
+    # same with and without it.
     profile_dir: Optional[str] = None
     # dump one xplane trace for EXACTLY phase N (one collect→train pair)
     # into profile_dir (default "profiles"): a programmatic jax.profiler

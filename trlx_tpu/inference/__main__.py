@@ -244,7 +244,7 @@ def multi_tenant_smoke(mesh=None, span_log=None) -> int:
     ttft_stream_ms = (telemetry.monotonic() - t0) * 1000.0
     result_at_first_token = server.poll(stream_rid)
     while server.poll(stream_rid) is None:
-        server._pump_once()
+        server.step()
     ttft_harvest_ms = (telemetry.monotonic() - t0) * 1000.0
 
     server.flush()

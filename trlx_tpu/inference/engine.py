@@ -128,6 +128,10 @@ class EngineStats:
     done_polls: int = 0  # [B]-bool device->host fetches actually paid
     weight_pushes: int = 0  # mid-generation behavior refreshes applied
     released: int = 0  # placeholder rows force-finished on admission
+    # wall the host spent blocked in the step loop's device->host
+    # fetches (the engine/fetch spans): a step's wall less this is the
+    # host's own exposed cost
+    host_blocked_ms: float = 0.0
     # chunked prefill (rollout.prefill_chunk > 0): chunks actually RUN
     # (the finish chunk included), prompt columns whose forward was
     # skipped (leading pad + pool-covered shared blocks), and the exact
@@ -202,6 +206,7 @@ class EngineStats:
             "engine/done_polls": float(self.done_polls),
             "engine/weight_pushes": float(self.weight_pushes),
             "engine/released": float(self.released),
+            "engine/host_blocked_ms": round(self.host_blocked_ms, 3),
             "engine/prefill_chunks": float(self.prefill_chunks),
             "engine/prefill_cols_skipped": float(self.prefill_cols_skipped),
             "engine/prefill_flops_saved": float(self.prefill_flops_saved),
@@ -680,6 +685,7 @@ class ContinuousBatchingEngine:
                 merge_layer(f, s) for f, s in zip(state.cache, cache_out)
             )
 
+        @jax.named_scope("prefill")
         def prefill(
             params,
             state: EngineState,
@@ -755,6 +761,7 @@ class ContinuousBatchingEngine:
                 row_index=put(state.row_index, row_index),
             )
 
+        @jax.named_scope("decode_step")
         def decode_step(params, state: EngineState):
             """One token for every slot. Finished/idle slots ride along
             with deterministic pad emissions whose output and cache
@@ -861,6 +868,7 @@ class ContinuousBatchingEngine:
         # ------------- speculative verify (rollout.spec_decode) ------------ #
         D = self.spec_max_draft
 
+        @jax.named_scope("decode_step")
         def verify_step(params, state: EngineState, draft, draft_len):
             """Drafted multi-token decode: sample each slot's anchor
             token from the carried logits (always the correct next token
@@ -1019,6 +1027,7 @@ class ContinuousBatchingEngine:
         n_scan_chunks = max(0, n_pc - 1)
         chunk_kwargs = self._chunk_kwargs
 
+        @jax.named_scope("prefill")
         def prefill_chunks(
             params,
             state: EngineState,
@@ -1068,6 +1077,7 @@ class ContinuousBatchingEngine:
                 ),
             )
 
+        @jax.named_scope("prefill")
         def prefill_finish(
             params,
             state: EngineState,
@@ -2043,8 +2053,7 @@ class ContinuousBatchingEngine:
             self._step_log.append(
                 (telemetry.monotonic(), self.stats.prefills)
             )
-        tok_host = np.asarray(jax.device_get(toks))
-        acc_host = np.asarray(jax.device_get(acc))
+        tok_host, acc_host = self.fetch(toks, acc)
         for slot, row in self._busy_rows.items():
             n_cols = int(acc_host[slot].sum())  # anchor + accepted drafts
             if lens[slot]:
@@ -2132,8 +2141,7 @@ class ContinuousBatchingEngine:
             # and the unfetched outputs are dropped on device). Spec
             # decode reads the same tap to keep the drafter histories
             # current through draftless fall-through steps.
-            tok_host = np.asarray(jax.device_get(token))
-            live_host = np.asarray(jax.device_get(live))
+            tok_host, live_host = self.fetch(token, live)
             if self.spec_drafter is not None:
                 for slot, row in self._busy_rows.items():
                     if live_host[slot]:
@@ -2160,7 +2168,7 @@ class ContinuousBatchingEngine:
         if self._steps_since_poll < self.done_poll_interval:
             return
         self._steps_since_poll = 0
-        done_host = np.asarray(jax.device_get(done))
+        (done_host,) = self.fetch(done)
         self.stats.done_polls += 1
         # occupancy timeseries: one gauge sample per paid done-poll
         # (the registry's ring is bounded; one host call per poll)
@@ -2184,7 +2192,23 @@ class ContinuousBatchingEngine:
                             self._step_base + len(self._step_log)
                         )
 
+    def fetch(self, *arrays) -> Tuple[np.ndarray, ...]:
+        """The step loop's blocking device->host fetch, in one transfer
+        event: the span ``engine/fetch`` is the host waiting on the
+        device, and its wall accumulates in ``stats.host_blocked_ms``
+        (forced: the counter stands with the tracer off)."""
+        with telemetry.span("engine/fetch", force=True) as sp:
+            host = jax.device_get(arrays)
+        self.stats.host_blocked_ms += sp.duration_ms
+        return host
+
     # ------------------------- serving interface ----------------------- #
+
+    @property
+    def done_waiting(self) -> int:
+        """Slots whose row has finished but that are held until their
+        fixed-width harvest group fills."""
+        return len(self._done_slots)
 
     @property
     def free_capacity(self) -> int:

@@ -438,7 +438,7 @@ class InferenceServer:
                 self._streams[rid] = TokenStream(
                     rid,
                     maxlen=self.serving_config.stream_buffer,
-                    pump=self._pump_once,
+                    pump=self.step,
                 )
             rids.append(rid)
         return rids
@@ -554,44 +554,103 @@ class InferenceServer:
         mask[:, Q - 1] = 1
         self.engine.submit(ids, mask, release=True)
 
-    def _pump_once(self) -> bool:
-        """One serving iteration: feed the engine from the scheduler,
-        advance decode a step, land any harvested groups. Returns
-        whether anything progressed.
+    def _schedule(self) -> int:
+        """Feed the engine's admission queue from the scheduler, and pad
+        a trailing partial harvest group with placeholders. The span
+        ``serve/schedule`` opens only where there is such work; returns
+        the requests handed to the engine."""
+        from trlx_tpu import telemetry
 
-        When the scheduler has nothing more to feed and the in-flight
-        rows cannot fill the last fixed-width harvest group, the pump
-        pads with release-on-admission placeholders — so a lone
-        streaming request (or a trailing partial group) drains without
-        waiting for traffic that may never come."""
-        engine = self.engine
+        engine, scheduler = self.engine, self.scheduler
         free = engine.free_capacity
-        if free > 0 and self.scheduler.has_work():
-            batch = self.scheduler.next_batch(free)
-            if batch:
-                self._engine_submit(batch)
+        feed = free > 0 and scheduler.has_work()
         Hw = engine.harvest_width
-        if (
-            not self.scheduler.has_work()
-            and engine.pending
-            and engine.pending % Hw
-        ):
-            self._submit_placeholders(Hw - engine.pending % Hw)
-        # tap cost is per-step host fetches: only pay while someone is
-        # actually streaming
-        engine.token_sink = (
-            self._router.on_tokens if self._router.active else None
-        )
-        busy_before = engine.pending
-        groups = engine.pump()
-        for group in groups:
-            self._land_group(group)
+        if not feed and (scheduler.has_work() or not engine.pending % Hw):
+            return 0
+        admitted = 0
+        with telemetry.span("serve/schedule") as sp:
+            if feed:
+                batch = scheduler.next_batch(free)
+                if batch:
+                    self._engine_submit(batch)
+                    admitted = len(batch)
+            # when the scheduler has nothing more to feed and the
+            # in-flight rows cannot fill the last fixed-width harvest
+            # group, pad with release-on-admission placeholders — a lone
+            # streaming request (or a trailing partial group) drains
+            # without waiting for traffic that may never come
+            if not scheduler.has_work() and engine.pending % Hw:
+                padded = Hw - engine.pending % Hw
+                self._submit_placeholders(padded)
+                sp.set(placeholders=padded)
+            sp.set(admitted=admitted)
+        return admitted
+
+    def step(self) -> bool:
+        """One serving iteration: feed the engine from the scheduler,
+        let it harvest, admit and advance decode a step
+        (:meth:`~trlx_tpu.inference.engine.ContinuousBatchingEngine.
+        pump`), land the harvested groups. Returns whether anything
+        progressed. A caller that owns the loop (an open-loop load
+        generator, an RPC front end) calls this; ``flush``/``wait`` and
+        a stream's iterator call it for everyone else.
+
+        Measured from inside (docs/observability.md "The serving loop"):
+        the span ``serve/step`` with ``serve/schedule``, ``engine/fetch``
+        and ``serve/land`` beneath it, and for an iteration that did
+        device work the histograms ``serve/pump_ms`` (a decode step and
+        no admission prefill) or ``serve/admit_pump_ms`` (a prefill was
+        dispatched: the stall every running stream feels),
+        ``serve/step_host_ms`` (the wall less the time blocked in
+        ``engine/fetch``) and ``serve/slots_done_waiting``. While a
+        client streams, the engine fetches every step's tokens, so the
+        walls are the device's; with no stream open only the ``done``
+        flags are fetched, and they read one step behind at most."""
+        from trlx_tpu import telemetry
+
+        engine = self.engine
+        stats = engine.stats
+        prefills = stats.prefills + stats.prefill_chunks
+        steps = stats.decode_steps
+        blocked_ms = stats.host_blocked_ms
+        with telemetry.span("serve/step", force=True) as sp:
+            admitted = self._schedule()
+            # tap cost is per-step host fetches: only pay while someone
+            # is actually streaming
+            engine.token_sink = (
+                self._router.on_tokens if self._router.active else None
+            )
+            busy_before = engine.pending
+            groups = engine.pump()
+            for group in groups:
+                with telemetry.span("serve/land"):
+                    self._land_group(group)
+            sp.set(
+                admitted=admitted,
+                harvested=sum(len(g["rows"]) for g in groups),
+            )
+        prefilled = stats.prefills + stats.prefill_chunks > prefills
+        if prefilled or stats.decode_steps > steps:
+            wall_ms = sp.duration_ms
+            registry = self._registry
+            registry.histogram(
+                "serve/admit_pump_ms" if prefilled else "serve/pump_ms"
+            ).observe(wall_ms)
+            registry.histogram("serve/step_host_ms").observe(
+                max(0.0, wall_ms - (stats.host_blocked_ms - blocked_ms))
+            )
+            registry.histogram("serve/slots_done_waiting").observe(
+                engine.done_waiting
+            )
         return bool(groups) or busy_before > 0
 
-    def _observe_group(self, group) -> None:
-        lp = np.asarray(group["logprobs"])
-        vals = np.asarray(group["values"])
-        m = np.asarray(group["response_mask"]).astype(bool)
+    def _pump_once(self) -> bool:
+        """The name :meth:`step` had before it was public (callers
+        outside the package still use it)."""
+        return self.step()
+
+    def _observe_group(self, lp, vals, mask) -> None:
+        m = mask.astype(bool)
         picked = lp[m] if m.any() else lp.ravel()
         row = {
             "health/logprob_mean": float(picked.mean()),
@@ -605,12 +664,12 @@ class InferenceServer:
         self._groups_served += 1
 
     def _land_group(self, group) -> None:
-        import jax
-
         engine = self.engine
-        toks = np.asarray(jax.device_get(group["tokens"]))
-        mask = np.asarray(jax.device_get(group["response_mask"]))
-        self._observe_group(group)
+        toks, mask, lp, vals = engine.fetch(
+            group["tokens"], group["response_mask"],
+            group["logprobs"], group["values"],
+        )
+        self._observe_group(lp, vals, mask)
         for j, row in enumerate(group["rows"]):
             record = engine.pop_request_record(row)
             timing = record["timing"] if record else None
@@ -647,6 +706,7 @@ class InferenceServer:
                 )
             out: Dict[str, Any] = {
                 "tokens": toks[j, :length].tolist(),
+                "logprobs": lp[j, :length].tolist(),
                 "length": length,
                 "tenant": req.tenant,
             }
@@ -717,7 +777,7 @@ class InferenceServer:
         if not open_before:
             return 0
         while any(self._open.get(r) for r in open_before):
-            progressed = self._pump_once()
+            progressed = self.step()
             if not progressed:
                 if self.scheduler.has_work():
                     # quota-throttled tenants: wait for bucket refill

@@ -441,15 +441,17 @@ def make_sampler(
         pad_tail = jnp.zeros((B, R), dtype=prompt_mask.dtype)
         cache_mask = concat_cols(prompt_mask, pad_tail)
         positions = jnp.clip(jnp.cumsum(prompt_mask, axis=-1) - 1, 0, None)
-        out = apply_fn(
-            params,
-            prompt_ids,
-            attention_mask=cache_mask,
-            position_ids=positions,
-            cache=cache,
-            cache_index=0,
-            **_prefill_kwargs,
-        )
+        # device-trace scope names are a contract (docs/observability.md)
+        with jax.named_scope("prefill"):
+            out = apply_fn(
+                params,
+                prompt_ids,
+                attention_mask=cache_mask,
+                position_ids=positions,
+                cache=cache,
+                cache_index=0,
+                **_prefill_kwargs,
+            )
         cache = pin_cache(out["cache"])
         logits_last = out["logits"][:, -1].astype(jnp.float32)  # [B, V]
         if with_values:
@@ -459,6 +461,7 @@ def make_sampler(
 
         slot_ids = jnp.arange(cap)[None, :]
 
+        @jax.named_scope("decode_step")
         def step(carry, t):
             cache, logits_last, value_last, finished, rng = carry
             if gen_config.per_row_rng:
@@ -643,6 +646,7 @@ def make_seq2seq_sampler(
             else jnp.zeros((B,), jnp.float32)
         )
 
+        @jax.named_scope("decode_step")
         def step(carry, t):
             cache, logits_last, value_last, finished, rng = carry
             rng, key = jax.random.split(rng)

@@ -50,6 +50,7 @@ one process-global tracer, enabled by default on the main process only
 from __future__ import annotations
 
 import os
+import threading
 from contextlib import contextmanager
 from typing import Optional
 
@@ -101,6 +102,7 @@ __all__ = [
     "scoped_tracer",
     "span",
     "warn_on_span_drops",
+    "watch_compiles",
 ]
 
 _tracer: Optional[Tracer] = None
@@ -201,12 +203,15 @@ def configure(
 
 def configure_from_dict(d) -> Tracer:
     """Apply the ``train.telemetry`` config section (and return the
-    global tracer). One knob today — ``ring_size``, the span-ring
+    global tracer). Every trainer and server is built through here, so
+    this is also where the process starts watching its compiles
+    (:func:`watch_compiles`). One knob today — ``ring_size``, the span-ring
     capacity (per-request serving spans multiply span volume; an
     evicting ring truncates every trace the ``--trace-report`` analyzer
     reads). Unknown keys refuse loudly, like every other config section.
     Precedence: an explicit ``TRLX_TELEMETRY_RING`` env var wins over
     the config — the operator at the terminal outranks the YAML."""
+    watch_compiles()
     d = dict(d or {})
     known = {"ring_size"}
     unknown = set(d) - known
@@ -235,3 +240,62 @@ def configure_from_dict(d) -> Tracer:
         if not env_valid:
             return configure(max_records=ring)
     return get_tracer()
+
+
+# ------------------------- which step recompiled ------------------------- #
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENT_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": "jit/cache_hits",
+    "/jax/compilation_cache/cache_misses": "jit/cache_misses",
+}
+_watching_compiles = False
+
+
+def watch_compiles() -> None:
+    """Listen to ``jax.monitoring`` (installed once per process; jax keeps
+    listeners for the life of the process): every backend compile
+    advances the counters ``jit/compiles`` and ``jit/compile_s`` and is
+    recorded as a span ``jit/compile`` stamped ``[now - duration, now]``
+    under the span open on the compiling thread, so a trace says inside
+    which step a compile fell; persistent-cache hits and misses advance
+    ``jit/cache_hits`` / ``jit/cache_misses``. A retrieval from the
+    persistent cache is a "compile" of its retrieval time, as jax
+    reports it. Events land in whatever tracer and registry are global
+    when they fire."""
+    global _watching_compiles
+    if _watching_compiles:
+        return
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_compile)
+    monitoring.register_event_listener(_on_cache_event)
+    _watching_compiles = True
+
+
+def _on_compile(event: str, duration: float, **_) -> None:
+    if event != COMPILE_EVENT:
+        return
+    registry = get_metrics()
+    registry.counter("jit/compiles").inc()
+    registry.counter("jit/compile_s").inc(duration)
+    tracer = get_tracer()
+    if not tracer.enabled:
+        return
+    from trlx_tpu.telemetry.request_trace import _stamp
+
+    end = monotonic()
+    thread = threading.current_thread()
+    stamped = _stamp(
+        "jit/compile", end - duration, end, thread.ident or 0, thread.name, {}
+    )
+    inside = tracer.current()
+    if inside is not None:
+        stamped.depth = inside.depth + 1
+    tracer.record(stamped, parent=None if inside is None else inside.index)
+
+
+def _on_cache_event(event: str, **_) -> None:
+    counter = _CACHE_EVENT_COUNTERS.get(event)
+    if counter is not None:
+        get_metrics().counter(counter).inc()

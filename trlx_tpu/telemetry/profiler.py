@@ -1,13 +1,19 @@
 """Programmatic ``jax.profiler`` windows: one xplane trace per phase.
 
-The legacy ``train.profile_dir`` path traced the first ~10 optimizer
-steps from loop start — useful for cold-start triage, useless for "what
-did phase 37 overlap with": by step 10 nothing interesting has streamed
-yet, and tracing a whole run is gigabytes. ``train.profile_phase: N``
-instead opens the profiler for EXACTLY phase N (one collect→train pair)
-and closes it at the phase boundary, yielding one loadable xplane/
-Perfetto artifact whose timeline lines up with the span tree the tracer
-recorded for the same phase (shared wall-clock).
+Tracing a whole run is gigabytes, and the first optimizer steps say
+nothing about "what did phase 37 overlap with". ``train.profile_phase: N``
+opens the profiler for EXACTLY phase N (one collect→train pair) and
+closes it at the phase boundary, yielding one loadable xplane/Perfetto
+artifact. ``train.profile_dir`` alone means phase 0. Either way the run
+keeps the schedule it would have had: turning the profiler on changes
+nothing that it measures.
+
+The trace and the tracer share a clock: every recorded span of
+``telemetry/tracer.py`` is also written into the open profiler session
+as the host event ``trlx/<span name>``, so the span tree of the profiled
+phase lies on the host plane of the xplane, beside the device ops it
+caused (the tracer's own ring keeps ``time.monotonic`` stamps; the two
+are the same intervals on two clocks, not one shared wall-clock).
 
 The stop fence (``block_until_ready``) sits at a phase boundary that
 already synchronizes (the phase's stats were fetched), so the window
@@ -30,7 +36,10 @@ class PhaseProfiler:
 
     def __init__(self, profile_dir: Optional[str], target_phase: Optional[int]):
         self.profile_dir = profile_dir or "profiles"
-        self.target = target_phase
+        # a directory alone asks for phase 0; neither asks for nothing
+        self.target = (
+            0 if target_phase is None and profile_dir else target_phase
+        )
         self.active = False
         self.done = False
 

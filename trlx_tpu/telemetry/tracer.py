@@ -12,7 +12,8 @@ perf auditor / bench / Perfetto exporter all read.
 Cost model, because spans sit on the collect critical path:
 
 - **enabled** (default on rank 0): two ``time.monotonic()`` calls, one
-  list push/pop, one deque append per span — no device work, no syncs.
+  list push/pop, one deque append and one profiler annotation (below)
+  per span — no device work, no syncs.
   Any ``block_until_ready`` fence belongs to the *instrumented code*,
   never to the tracer; spans are placed only at boundaries that already
   synchronize (drain, residual scan, phase end).
@@ -24,8 +25,17 @@ Cost model, because spans sit on the collect critical path:
   enabled. Use it for the handful of phase-boundary spans whose
   durations feed reported stats; never in per-token loops.
 
+One clock with the device: a recorded span also opens and closes a
+``jax.profiler.TraceAnnotation`` named ``trlx/<span name>``. Outside a
+profiler session that is a no-op; inside one (``train.profile_phase``,
+the benchmark's window, an operator's ``start_trace``) every span of the
+program sits on the host plane of the xplane, on the clock of the device
+ops, nested as the spans nest. Spans stamped after the fact
+(:meth:`Tracer.record`) have no annotation: their time has passed.
+
 Module is stdlib-only at import time so low-level utilities
-(``trlx_tpu.utils``) can source their clock from here without cycles.
+(``trlx_tpu.utils``) can source their clock from here without cycles
+(``jax.profiler`` is resolved by the first recorded span).
 """
 
 from __future__ import annotations
@@ -45,6 +55,25 @@ monotonic: Callable[[], float] = time.monotonic
 #: ``TRLX_TELEMETRY_RING`` env var or ``train.telemetry.ring_size``
 #: (per-request serving spans multiply span volume — docs/observability.md)
 DEFAULT_RING_SIZE = 65536
+
+
+#: prefix of the program's spans in a profiler trace (the benchmark
+#: harness writes its own under ``bench/``)
+ANNOTATION_PREFIX = "trlx/"
+
+_annotation_cls: Any = None  # jax.profiler.TraceAnnotation, once resolved
+
+
+def _annotate(name: str):
+    """An entered profiler annotation ``trlx/<name>``."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    annotation = _annotation_cls(ANNOTATION_PREFIX + name)
+    annotation.__enter__()
+    return annotation
 
 
 def env_ring_size() -> int:
@@ -99,6 +128,7 @@ class Span:
     __slots__ = (
         "name", "attrs", "start", "end", "status",
         "index", "parent", "depth", "thread_id", "thread_name", "_tracer",
+        "_annotation",
     )
 
     def __init__(
@@ -118,6 +148,7 @@ class Span:
         self.thread_id = 0
         self.thread_name = ""
         self._tracer = tracer  # None: forced-but-unrecorded span
+        self._annotation = None
 
     @property
     def duration_ms(self) -> float:
@@ -129,6 +160,7 @@ class Span:
     def __enter__(self) -> "Span":
         if self._tracer is not None:
             self._tracer._open(self)
+            self._annotation = _annotate(self.name)
         self.start = monotonic()
         return self
 
@@ -136,6 +168,9 @@ class Span:
         self.end = monotonic()
         if exc_type is not None:
             self.status = "error"
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if self._tracer is not None:
             self._tracer._close(self)
         return False  # never swallow
@@ -203,6 +238,11 @@ class Tracer:
                 self.dropped += 1
             self._records.append(span)
         return span.index
+
+    def current(self) -> Optional[Span]:
+        """The innermost span open on the calling thread, or ``None``."""
+        stack = self._stack()
+        return stack[-1] if stack else None
 
     def clear(self) -> None:
         with self._lock:

@@ -629,16 +629,26 @@ class BaseRLTrainer(ABC):
         """Detokenize responses, truncated at their mask (host boundary).
 
         Both arrays come back in ONE transfer event — one blocking
-        device->host fetch per boundary, not one per array (SURVEY §7.3)."""
-        tokens, response_mask = jax.device_get((tokens, response_mask))
-        lengths = response_mask.sum(axis=1)
-        out = []
-        for row, n in zip(tokens, lengths):
-            ids = row[: int(n)].tolist()
-            if self.tokenizer is not None:
-                out.append(self.tokenizer.decode(ids, skip_special_tokens=True))
-            else:
-                out.append(" ".join(map(str, ids)))
+        device->host fetch per boundary, not one per array (SURVEY §7.3).
+        Two spans split the boundary: ``collect/wait`` is the host
+        blocked on the device (near zero once the host, not the chip,
+        sets the collect phase's pace), ``collect/detokenize`` the host
+        loop after it."""
+        from trlx_tpu import telemetry
+
+        with telemetry.span("collect/wait"):
+            tokens, response_mask = jax.device_get((tokens, response_mask))
+        with telemetry.span("collect/detokenize"):
+            lengths = response_mask.sum(axis=1)
+            out = []
+            for row, n in zip(tokens, lengths):
+                ids = row[: int(n)].tolist()
+                if self.tokenizer is not None:
+                    out.append(
+                        self.tokenizer.decode(ids, skip_special_tokens=True)
+                    )
+                else:
+                    out.append(" ".join(map(str, ids)))
         return out
 
     def decode_queries(self, q_ids, q_mask) -> List[str]:

@@ -946,8 +946,13 @@ class PPOTrainer(BaseRLTrainer):
             out_shardings=self.param_shardings,
         )
 
+        # device-trace scope names (ref_score, train_step, train_phase,
+        # policy_forward, loss, optimizer) are a contract:
+        # docs/observability.md "Device scope names"
         self._score_ref_jit = jax.jit(
-            traced_on(self.mesh, self._ref_logprobs),
+            traced_on(
+                self.mesh, jax.named_scope("ref_score")(self._ref_logprobs)
+            ),
             in_shardings=(
                 self.ref_shardings,
                 self.param_shardings,
@@ -974,25 +979,27 @@ class PPOTrainer(BaseRLTrainer):
                 # num_layers_unfrozen > 0 re-enables the reference's
                 # commented-out freezing)
                 params = stop_frozen_gradients(params, self.trainable_mask)
-                logprobs, values, entropy, moe = self._forward_logprobs_values(
-                    params, mb
-                )
-                loss, stats = ppo_loss(
-                    logprobs,
-                    values,
-                    mb.logprobs,
-                    mb.values,
-                    advantages,
-                    returns,
-                    mb.response_mask,
-                    method.cliprange,
-                    method.cliprange_value,
-                    method.vf_coef,
-                    ent_coef=method.ent_coef,
-                    entropy=entropy,
-                    health=self._health_enabled,
-                    health_ev=self._health_ev,
-                )
+                with jax.named_scope("policy_forward"):
+                    logprobs, values, entropy, moe = (
+                        self._forward_logprobs_values(params, mb)
+                    )
+                with jax.named_scope("loss"):
+                    loss, stats = ppo_loss(
+                        logprobs,
+                        values,
+                        mb.logprobs,
+                        mb.values,
+                        advantages,
+                        returns,
+                        mb.response_mask,
+                        method.cliprange,
+                        method.cliprange_value,
+                        method.vf_coef,
+                        ent_coef=method.ent_coef,
+                        entropy=entropy,
+                        health=self._health_enabled,
+                        health_ev=self._health_ev,
+                    )
                 if moe is not None:
                     # Switch load-balancing: without this, top-1 routing
                     # collapses onto few experts once capacity drops are
@@ -1007,10 +1014,11 @@ class PPOTrainer(BaseRLTrainer):
             (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 state.params
             )
-            updates, new_opt_state = self.tx.update(
-                grads, state.opt_state, state.params
-            )
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt_state = self.tx.update(
+                    grads, state.opt_state, state.params
+                )
+                new_params = optax.apply_updates(state.params, updates)
             stats["optimizer/grad_norm"] = optax.global_norm(grads)
             if self._health_enabled:
                 # shaped-return distribution next to the loss stats — a
@@ -1025,6 +1033,7 @@ class PPOTrainer(BaseRLTrainer):
             )
             return new_state, stats
 
+        @jax.named_scope("train_step")
         def train_step(state: TrainState, mb: PPORolloutBatch):
             advantages, returns = self._advantages_and_returns(mb)
             return train_step_with_adv(state, mb, advantages, returns)
@@ -1036,6 +1045,7 @@ class PPOTrainer(BaseRLTrainer):
             donate_argnums=(0,),
         )
 
+        @jax.named_scope("train_phase")
         def train_phase(state: TrainState, mbs: PPORolloutBatch):
             """One full buffer pass in a single dispatch: flat scan over
             [n_mb * ppo_epochs] pre-repeated minibatch slices (the reference
@@ -1053,6 +1063,7 @@ class PPOTrainer(BaseRLTrainer):
             were already computed within)."""
             advantages, returns = jax.vmap(self._advantages_and_returns)(mbs)
 
+            @jax.named_scope("train_step")
             def step(st, xs):
                 mb, adv, ret = xs
                 return train_step_with_adv(st, mb, adv, ret)
@@ -1765,17 +1776,14 @@ class PPOTrainer(BaseRLTrainer):
     def _stream_eligible(self, iter_count: int) -> bool:
         """Whether the NEXT collect+train pass can run as a streamed phase:
         overlap enabled, an orchestrator attached, at least one planned
-        minibatch, no profiler trace wanted, and no eval/checkpoint
-        boundary or total_steps cutoff strictly inside the pass (those
-        fall back to the legacy fused/stepwise paths, which honor
-        mid-pass cadence)."""
+        minibatch, and no eval/checkpoint boundary or total_steps cutoff
+        strictly inside the pass (those fall back to the legacy
+        fused/stepwise paths, which honor mid-pass cadence). A profiler
+        window (``train.profile_dir`` / ``train.profile_phase``) never
+        changes the answer: it profiles the schedule the run has."""
         train = self.config.train
         method: PPOConfig = self.config.method
-        # profile_dir WITHOUT profile_phase is the legacy first-10-steps
-        # trace, which needs the stepwise path; the single-phase window
-        # (profile_phase) profiles the streamed schedule itself
-        legacy_profile = train.profile_dir and train.profile_phase is None
-        if not train.phase_overlap or self.orch is None or legacy_profile:
+        if not train.phase_overlap or self.orch is None:
             return False
         n_mb = method.num_rollouts // train.batch_size
         if n_mb < 1:
@@ -1849,8 +1857,9 @@ class PPOTrainer(BaseRLTrainer):
                 self._final_stats = {}
                 return {}
 
-        # single-phase profiler window (train.profile_phase): constructed
-        # before the initial collection so phase 0 is profileable
+        # single-phase profiler window (train.profile_phase, or phase 0
+        # with train.profile_dir alone): constructed before the initial
+        # collection so phase 0 is profileable
         from trlx_tpu.telemetry.profiler import PhaseProfiler
 
         self._phase_index = -1
@@ -1903,7 +1912,6 @@ class PPOTrainer(BaseRLTrainer):
             total_steps=total_steps,
         )
         self.logger = logger
-        self._profiling = False
         try:
             result = self._learn_body(
                 logger, total_steps, n_minibatches, start_step
@@ -1921,16 +1929,12 @@ class PPOTrainer(BaseRLTrainer):
             self.append_run_ledger(status="ok")
             return result
         finally:
-            # single epilogue for every exit (incl. exceptions): stop any
-            # live profiler trace (legacy first-steps AND the single-phase
-            # window), join in-flight async checkpoint writes (surfacing
-            # background write errors), close the logger even if that
-            # join raises
+            # single epilogue for every exit (incl. exceptions): stop a
+            # live profiler window, join in-flight async checkpoint
+            # writes (surfacing background write errors), close the
+            # logger even if that join raises
             try:
                 self._phase_profiler.close()
-                if self._profiling:
-                    jax.profiler.stop_trace()
-                    self._profiling = False
             finally:
                 try:
                     wait_for_checkpoints()
@@ -2010,11 +2014,6 @@ class PPOTrainer(BaseRLTrainer):
         if iter_count >= total_steps:
             # resumed a finished run: nothing left to train
             return final_stats
-        if train.profile_dir and train.profile_phase is None:
-            # legacy mode: trace the first ~10 optimizer steps from loop
-            # start (profile_phase traces one whole phase instead)
-            jax.profiler.start_trace(train.profile_dir)
-            self._profiling = True
         for epoch in range(getattr(self, "_epoch0", 0), train.epochs):
             # Streamed phase (the default): collection already interleaved
             # epoch-1 updates against the behavior snapshot; close the
@@ -2062,8 +2061,7 @@ class PPOTrainer(BaseRLTrainer):
                 for k in range(1, n_minibatches)
             ]
             fused_ok = (
-                not self._profiling
-                and len(self.buffer) >= train.batch_size
+                len(self.buffer) >= train.batch_size
                 and iter_count + pass_steps <= total_steps
                 and not any(
                     s % train.eval_interval == 0
@@ -2145,11 +2143,6 @@ class PPOTrainer(BaseRLTrainer):
                 )
                 step_stats["policy/kl_coef"] = self.kl_coef
                 step_stats["policy/mean_rollout_kl"] = self.mean_kl
-
-                if self._profiling and iter_count >= 10:
-                    jax.block_until_ready(self.state.params)
-                    jax.profiler.stop_trace()
-                    self._profiling = False
 
                 iv = self.intervals(iter_count)
                 at_end = iter_count >= total_steps
