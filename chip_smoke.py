@@ -329,6 +329,168 @@ def leg_train(arch, mesh, out_dir, name, *, engine="fixed", phases=1,
     return config.train.checkpoint_dir
 
 
+# ----------------------- leg 2b: the decode loop's KV ----------------------- #
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\((.*)$"
+)
+_HLO_LOOP_CALLEES = re.compile(
+    r"(?:body|condition|to_apply|true_computation|false_computation)="
+    r"%([\w.\-]+)|branch_computations=\{([^}]*)\}"
+)
+# what may yield a whole KV buffer inside the decode loop without moving
+# one: plumbing, the in-place write, control flow that hands the carry on,
+# and a kernel (which aliases its input)
+_KV_PLUMBING = {
+    "parameter", "get-tuple-element", "tuple", "bitcast", "opt-barrier",
+    "dynamic-update-slice", "while", "conditional", "call", "custom-call",
+}
+
+
+def kv_ops_in_loops(hlo_text, kv_shapes):
+    """``[(computation, instruction line)]``: every instruction inside a
+    ``while`` loop of a compiled HLO module (its body and what that calls,
+    fusion bodies aside — their values are never materialised) whose
+    result holds one of ``kv_shapes`` (``"bf16[8,128,768]"``) and which is
+    not plumbing, an in-place ``dynamic-update-slice`` (bare, or the root
+    of its fusion) or a move between memory spaces (a ``copy``,
+    ``copy-start`` or ``copy-done`` with a memory space, ``S(n)``, at
+    either end: the step's one read issued early into fast memory, or a
+    small buffer the compiler keeps there). A ``copy`` within HBM, a
+    ``pad``, a ``convert`` or any other fusion of that shape is a whole
+    buffer produced per step — the fault ISSUE 25 removed."""
+    computations, name = {}, None
+    for line in hlo_text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            computations[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            computations[name].append(line)
+
+    def root_op(computation):
+        for line in computations.get(computation, ()):
+            m = _HLO_INSTRUCTION.match(line)
+            if m and m.group(1):
+                return m.group(3)
+        return None
+
+    todo = [
+        m.group(1)
+        for lines in computations.values()
+        for line in lines
+        for m in re.finditer(r"body=%([\w.\-]+)", line)
+    ]
+    inside, found = set(), []
+    while todo:
+        name = todo.pop()
+        if name in inside or name not in computations:
+            continue
+        inside.add(name)
+        results = {}  # instruction -> its result type, operands aside
+        for line in computations[name]:
+            for m in _HLO_LOOP_CALLEES.finditer(line):
+                todo.extend(
+                    [m.group(1)] if m.group(1)
+                    else re.findall(r"%([\w.\-]+)", m.group(2))
+                )
+            m = _HLO_INSTRUCTION.match(line)
+            if not m:
+                continue
+            result, op, rest = m.group(2), m.group(3), m.group(4)
+            if op.endswith("-start") and result.startswith("(("):
+                # an async op's result leads with its operands' own types
+                depth = 0
+                for end, ch in enumerate(result[1:], 1):
+                    depth += (ch == "(") - (ch == ")")
+                    if depth == 0:
+                        break
+                result = result[end:]
+            results[line.split("=")[0].strip().removeprefix("ROOT ")] = result
+            if not any(shape in result for shape in kv_shapes):
+                continue
+            if op in _KV_PLUMBING:
+                continue
+            if op in ("copy", "copy-start", "copy-done"):
+                # a move between memory spaces (either end carries one)
+                source = re.match(r"(%[\w.\-]+)", rest)
+                ends = result + results.get(source.group(1), "") if source else result
+                if "S(" in ends:
+                    continue
+            if op == "fusion":
+                callee = re.search(r"calls=%([\w.\-]+)", rest)
+                if callee and root_op(callee.group(1)) == "dynamic-update-slice":
+                    continue
+            found.append((name, line.strip()[:240]))
+    return found
+
+
+def decode_loop_kv_ops(arch, kv_cache_dtype, *, batch=8, seq_length=112,
+                       new_tokens=16):
+    """Compile the fixed sampler for the default device at a small shape
+    and list what :func:`kv_ops_in_loops` finds in its optimised HLO."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from trlx_tpu.models.gpt2 import GPT2Config, GPT2Model, init_cache
+    from trlx_tpu.models.heads import CausalLMWithValueHead
+    from trlx_tpu.ops.sampling import GenerationConfig, make_sampler
+
+    cfg = GPT2Config.from_dict(
+        dict(arch, kv_cache_dtype=kv_cache_dtype, dtype="bfloat16")
+    )
+    model = CausalLMWithValueHead(cfg, backbone_cls=GPT2Model)
+    gen = GenerationConfig(
+        max_new_tokens=new_tokens, eos_token_id=arch["vocab_size"] - 1,
+        pad_token_id=arch["vocab_size"] - 1,
+    )
+
+    def apply_fn(params, input_ids, attention_mask=None, position_ids=None,
+                 cache=None, cache_index=None, last_only=False):
+        return model.apply(
+            {"params": params}, input_ids, attention_mask=attention_mask,
+            position_ids=position_ids, cache=cache, cache_index=cache_index,
+            last_only=last_only,
+        )
+
+    sampler = make_sampler(
+        apply_fn, functools.partial(init_cache, cfg), gen, seq_length
+    )
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0),
+    )
+    ids = jax.ShapeDtypeStruct((batch, seq_length), jnp.int32)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    text = jax.jit(sampler).lower(params, ids, ids, key).compile().as_text()
+    check("while(" in text, "no while loop in the compiled sampler")
+    capacity = seq_length + new_tokens
+    heads, width = cfg.n_head, cfg.n_embd
+    element = "s8" if kv_cache_dtype == "int8" else "bf16"
+    return kv_ops_in_loops(text, [
+        f"{element}[{batch},{capacity},{width}]",
+        f"{element}[{batch},{capacity},{heads},{width // heads}]",
+    ])
+
+
+def leg_decode_loop(arch):
+    """The regression guard a CPU test cannot give: in the program the
+    chip's compiler makes of the fixed sampler, no operation of the decode
+    loop produces a whole KV buffer — bf16 cache and int8 cache."""
+    for kv_cache_dtype in ("bfloat16", "int8"):
+        found = decode_loop_kv_ops(arch, kv_cache_dtype)
+        check(
+            not found,
+            f"{kv_cache_dtype} cache: the decode loop produces whole KV "
+            f"buffers: {found[:4]}",
+        )
+
+
 # ------------------------------ leg 4: serve ------------------------------ #
 
 
@@ -601,6 +763,7 @@ def main():
         "fixed", phases=2,
     )
     gc.collect()
+    run_leg("2b decode loop copies no KV buffer", leg_decode_loop, GPT2_SMALL)
     run_leg(
         "3 train continuous engine", leg_train, GPT2_SMALL, DP_MESH, OUT_DIR,
         "continuous", engine="continuous",

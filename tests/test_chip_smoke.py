@@ -130,6 +130,82 @@ def test_traced_on_declares_the_mesh_for_the_trace_only():
     assert program_mesh() is None
 
 
+# what the chip's compiler made of the decode loop before and after ISSUE 25,
+# cut to the lines the guard reads: the parent copied the carried buffer into
+# and out of its update fusion at every step (and around each `cond` branch)
+_LOOP_HLO = """\
+HloModule jit_sampler
+
+%fused_dus (p0: bf16[8,128,768], p1: bf16[8,1,768], p2: s32[]) -> bf16[8,128,768] {
+  %p0 = bf16[8,128,768]{2,1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %dus = bf16[8,128,768]{2,1,0:T(8,128)(2,1)} dynamic-update-slice(%p0, %p1, %c, %p2, %c)
+}
+
+%fused_read (p0: bf16[8,128,768]) -> f32[8,12,128] {
+  %p0 = bf16[8,128,768]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %inside = bf16[8,128,768]{2,1,0:T(8,128)(2,1)} bitcast(%p0)
+  ROOT %dot = f32[8,12,128]{2,1,0:T(8,128)} convolution(%q, %inside)
+}
+
+%branch_run (arg: (bf16[8,128,768])) -> (bf16[8,128,768]) {
+  %arg = (bf16[8,128,768]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  %gte.9 = bf16[8,128,768]{2,1,0:T(8,128)(2,1)} get-tuple-element(%arg), index=0
+  BRANCH_LINE
+  ROOT %t = (bf16[8,128,768]{2,1,0:T(8,128)(2,1)}) tuple(%gte.9)
+}
+
+%body (carry: (s32[], bf16[8,128,768])) -> (s32[], bf16[8,128,768]) {
+  %carry = (s32[]{:T(128)}, bf16[8,128,768]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  %gte.1 = bf16[8,128,768]{2,1,0:T(8,128)(2,1)} get-tuple-element(%carry), index=1
+  %write = bf16[8,128,768]{2,1,0:T(8,128)(2,1)} fusion(%gte.1, %new, %t), kind=kLoop, calls=%fused_dus
+  %copy-start.1 = (bf16[8,128,768]{2,1,0:T(8,128)(2,1)S(1)}, bf16[8,128,768]{2,1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%write)
+  %copy-done.1 = bf16[8,128,768]{2,1,0:T(8,128)(2,1)S(1)} copy-done(%copy-start.1)
+  %slice-start.1 = ((bf16[8,128,768]{2,1,0:T(8,128)(2,1)}), bf16[8,32,768]{2,1,0:T(8,128)(2,1)S(1)}, s32[]{:S(2)}) slice-start(%write), slice={[0:8], [0:32], [0:768]}
+  %scores = f32[8,12,128]{2,1,0:T(8,128)} fusion(%copy-done.1), kind=kOutput, calls=%fused_read
+  %cond.1 = (bf16[8,128,768]{2,1,0:T(8,128)(2,1)}) conditional(%p, %a, %a), branch_computations={%branch_run, %branch_run}
+  BODY_LINE
+  ROOT %out = (s32[]{:T(128)}, bf16[8,128,768]{2,1,0:T(8,128)(2,1)}) tuple(%t1, %write)
+}
+
+%cond (carry: (s32[], bf16[8,128,768])) -> pred[] {
+  %carry = (s32[]{:T(128)}, bf16[8,128,768]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  ROOT %lt = pred[]{:T(512)} compare(%t, %r), direction=LT
+}
+
+ENTRY %main (p: bf16[8,112,768]) -> bf16[8,128,768] {
+  %prefill = bf16[8,128,768]{2,1,0:T(8,128)(2,1)} copy(%padded)
+  %loop = (s32[]{:T(128)}, bf16[8,128,768]{2,1,0:T(8,128)(2,1)}) while(%init), condition=%cond, body=%body
+  ROOT %r = bf16[8,128,768]{2,1,0:T(8,128)(2,1)} get-tuple-element(%loop), index=1
+}
+"""
+_KV_COPY = "%copy.7 = bf16[8,128,768]{2,1,0:T(8,128)(2,1)} copy(%gte.1)"
+_KV_CONVERT = (
+    "%f.3 = bf16[8,128,768]{2,1,0:T(8,128)(2,1)} fusion(%gte.9), kind=kLoop, "
+    "calls=%fused_read"
+)
+
+
+@pytest.mark.parametrize(
+    "body_line,branch_line,expected",
+    [
+        ("", "", []),  # in-place write, prefetch, plumbing: clean
+        (_KV_COPY, "", [("body", _KV_COPY)]),  # the per-step copy
+        ("", _KV_CONVERT, [("branch_run", _KV_CONVERT)]),  # inside a cond
+    ],
+    ids=["clean", "copy_in_body", "fusion_in_cond_branch"],
+)
+def test_kv_ops_in_loops_reads_compiled_hlo(body_line, branch_line, expected):
+    text = _LOOP_HLO.replace("BODY_LINE", body_line).replace(
+        "BRANCH_LINE", branch_line
+    )
+    found = chip_smoke.kv_ops_in_loops(
+        text, ["bf16[8,128,768]", "bf16[8,128,12,64]"]
+    )
+    assert found == expected
+    # the prefill's copy, outside any loop, is not the guard's business
+    assert all("%prefill" not in line for _, line in found)
+
+
 @pytest.mark.slow  # ~1.5 min: two toy PPO runs, a server and the kernels
 def test_cpu_rehearsal_of_the_legs(tmp_path):
     toy = {
@@ -142,6 +218,13 @@ def test_cpu_rehearsal_of_the_legs(tmp_path):
     checkpoint = chip_smoke.leg_train(
         toy, mesh, out, "fixed", phases=2, **sizes
     )
+    # the chip's compiler decides what the guard finds; here only that
+    # the sampler compiles and the reader runs over its HLO
+    for kv in ("bfloat16", "int8"):
+        found = chip_smoke.decode_loop_kv_ops(
+            toy, kv, batch=4, seq_length=16, new_tokens=8
+        )
+        assert isinstance(found, list)
     chip_smoke.leg_train(
         toy, mesh, out, "continuous", engine="continuous", **sizes
     )

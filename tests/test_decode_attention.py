@@ -1,0 +1,258 @@
+"""The fixed sampler's decode read (``ops/attention.py::decode_attention`` on
+a cache in ``decode_kv_layout``) against the generic read — ``write_cache``
+and ``dot_product_attention`` on the dequantised updated buffer — and the
+dispatch between the two, counted at trace time."""
+
+import numpy as np
+import pytest
+
+B, H = 3, 4
+
+# (compute dtype, cache dtype, atol): float32 agrees to 1e-6 with either
+# cache (int8 values and their bf16 scales are exact in float32); in bf16
+# the fused read rounds where the generic one does but sums in another
+# order (bf16 cache), or skips the rounding of the dequantised buffer (int8)
+PRECISIONS = [
+    ("float32", "float32", 1e-6),
+    ("float32", "int8", 1e-6),
+    ("bfloat16", "bfloat16", 2e-2),
+    ("bfloat16", "int8", 3e-2),
+]
+# head size (gpt2's, pythia's) x capacity (not a tile multiple, a multiple)
+SHAPES = [(64, 560), (128, 560), (64, 512), (128, 512)]
+
+
+def _counts():
+    from trlx_tpu.telemetry import get_metrics
+
+    reg = get_metrics()
+    return {
+        path: reg.counter("attention/decode_path{path=%s}" % path).value
+        for path in ("fused", "generic")
+    }
+
+
+def _filled_cache(rng, C, Dh, dtype, cache_dtype, filled):
+    """One layer's ``kv_buffers``-layout cache holding ``filled`` random
+    positions, written through ``write_cache`` (so int8 holds what the
+    program would have quantised)."""
+    import jax.numpy as jnp
+
+    from trlx_tpu.models.gpt2 import kv_buffers, write_cache
+
+    kv = "int8" if cache_dtype == "int8" else "bfloat16"
+    cache = kv_buffers(1, B, C, H, Dh, dtype, kv)[0]
+    k = jnp.asarray(rng.standard_normal((B, filled, H, Dh)), dtype)
+    v = jnp.asarray(rng.standard_normal((B, filled, H, Dh)), dtype)
+    return write_cache(cache, k, v, 0, jnp.dtype(dtype))[2]
+
+
+def _bias(C, index, pad, shared):
+    """Additive [B|1, 1, 1, C]: causal at ``index`` and, per row, ``pad``
+    masked positions on the left (row 0 of a per-row bias has none)."""
+    from trlx_tpu.ops.attention import causal_bias, combine_biases, padding_bias
+
+    causal = causal_bias(1, C, offset=index)
+    if shared:
+        valid = (np.arange(C) >= pad)[None, :]
+    else:
+        valid = np.arange(C)[None, :] >= (np.arange(B) * pad)[:, None]
+    return combine_biases(causal, padding_bias(valid.astype(np.int32)))
+
+
+@pytest.mark.parametrize("shared_bias", [False, True], ids=["bias_B", "bias_1"])
+@pytest.mark.parametrize("where", ["first", "mid", "last"])
+@pytest.mark.parametrize("Dh,C", SHAPES)
+@pytest.mark.parametrize("dtype,cache_dtype,atol", PRECISIONS)
+def test_fused_read_matches_generic(dtype, cache_dtype, atol, Dh, C, where,
+                                    shared_bias):
+    import jax.numpy as jnp
+
+    from trlx_tpu.ops.attention import decode_attention, decode_kv_layout
+
+    rng = np.random.default_rng(C + Dh)
+    index = {"first": 0, "mid": C // 2, "last": C - 1}[where]
+    cache = _filled_cache(rng, C, Dh, dtype, cache_dtype, filled=index)
+    q, k_new, v_new = (
+        jnp.asarray(rng.standard_normal((B, 1, H, Dh)), dtype) for _ in range(3)
+    )
+    # left padding: at `first` the new position is the only one in view, so
+    # nothing is masked; elsewhere up to a third of the filled part is
+    bias = _bias(C, index, pad=index // 3, shared=shared_bias)
+
+    before = _counts()
+    ref, ref_kv = decode_attention(q, k_new, v_new, cache, index, bias)
+    out, new_kv = decode_attention(
+        q, k_new, v_new, decode_kv_layout(cache), index, bias
+    )
+    after = _counts()
+    assert after["generic"] == before["generic"] + 1
+    assert after["fused"] == before["fused"] + 1
+
+    assert out.shape == ref.shape and out.dtype == ref.dtype
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=atol
+    )
+    # the write: the same buffers, in the other layout, bit for bit
+    want = decode_kv_layout(ref_kv)
+    assert sorted(new_kv) == sorted(want)
+    for name in want:
+        assert new_kv[name].dtype == want[name].dtype, name
+        np.testing.assert_array_equal(
+            np.asarray(new_kv[name], np.float32),
+            np.asarray(want[name], np.float32), err_msg=name,
+        )
+
+
+def test_fully_masked_row_matches_generic():
+    """A row with every position masked (an idle row's bias) softmaxes to
+    uniform weights in both reads — no NaN, same output."""
+    import jax.numpy as jnp
+
+    from trlx_tpu.ops.attention import (
+        NEG_INF, decode_attention, decode_kv_layout,
+    )
+
+    rng = np.random.default_rng(0)
+    C, Dh, index = 40, 64, 17
+    cache = _filled_cache(rng, C, Dh, "float32", "float32", filled=index)
+    q, k_new, v_new = (
+        jnp.asarray(rng.standard_normal((B, 1, H, Dh)), jnp.float32)
+        for _ in range(3)
+    )
+    bias = np.array(_bias(C, index, pad=3, shared=False))
+    bias[1] = NEG_INF
+    bias = jnp.asarray(bias)
+    ref, _ = decode_attention(q, k_new, v_new, cache, index, bias)
+    out, _ = decode_attention(
+        q, k_new, v_new, decode_kv_layout(cache), index, bias
+    )
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+
+
+def test_decode_kv_layout_shapes():
+    """Per-layer tuple and the pp sampler's layer-major dict: heads fold
+    into the minor axis, int8 scales go capacity-minor."""
+    import jax.numpy as jnp
+
+    from trlx_tpu.models.gpt2 import kv_buffers
+    from trlx_tpu.ops.attention import decode_kv_layout
+
+    flat = decode_kv_layout(kv_buffers(2, B, 24, H, 8, jnp.bfloat16, "int8"))
+    assert len(flat) == 2
+    assert flat[0]["k"].shape == flat[1]["v"].shape == (B, 24, H * 8)
+    assert flat[0]["k"].dtype == jnp.int8
+    assert flat[0]["k_scale"].shape == flat[0]["v_scale"].shape == (B, H, 24)
+    stacked = {"k": jnp.zeros((5, B, 24, H, 8)), "v": jnp.zeros((5, B, 24, H, 8))}
+    assert decode_kv_layout(stacked)["v"].shape == (5, B, 24, H * 8)
+
+
+def _bypass_case(kind):
+    """(q_len, cache, cache_index, bias) for one call the generic read
+    keeps: the paged engine's cache, a learned per-head bias, two
+    positions a call."""
+    import jax.numpy as jnp
+
+    from trlx_tpu.models.gpt2 import kv_buffers
+    from trlx_tpu.ops.attention import causal_bias
+
+    C, Dh = 16, 8
+    if kind == "paged":
+        from trlx_tpu.inference.kv_cache import init_paged_cache
+
+        cache = init_paged_cache(1, B, C, H, Dh, jnp.float32, block_size=4)[0]
+        at = jnp.full((B,), 5, jnp.int32)
+        return 1, cache, at, causal_bias(1, C, offset=at)
+    cache = kv_buffers(1, B, C, H, Dh, jnp.float32, "bfloat16")[0]
+    if kind == "per_head_bias":
+        bias = causal_bias(1, C, offset=5) + jnp.arange(H, dtype=jnp.float32)[
+            None, :, None, None]
+        return 1, cache, 5, bias
+    return 2, cache, 5, causal_bias(2, C, offset=5)
+
+
+@pytest.mark.parametrize("kind", ["paged", "per_head_bias", "q_len_2"])
+def test_bypass_takes_the_generic_read(kind):
+    import jax.numpy as jnp
+
+    from trlx_tpu.ops.attention import decode_attention
+
+    q_len, cache, index, bias = _bypass_case(kind)
+    rng = np.random.default_rng(1)
+    q, k_new, v_new = (
+        jnp.asarray(rng.standard_normal((B, q_len, H, 8)), jnp.float32)
+        for _ in range(3)
+    )
+    before = _counts()
+    out, new_kv = decode_attention(
+        q, k_new, v_new, cache, index, bias,
+        learned_bias=kind == "per_head_bias",
+    )
+    after = _counts()
+    assert after["generic"] == before["generic"] + 1
+    assert after["fused"] == before["fused"]
+    assert out.shape == q.shape and new_kv["k"].shape == cache["k"].shape
+
+
+@pytest.mark.parametrize("kind", ["per_head_bias", "q_len_2"])
+def test_decode_layout_refuses_what_it_cannot_read(kind):
+    """A cache in the decode layout is never rerouted: a call it cannot
+    serve is an error, not a slower answer."""
+    import jax.numpy as jnp
+
+    from trlx_tpu.ops.attention import decode_attention, decode_kv_layout
+
+    q_len, cache, index, bias = _bypass_case(kind)
+    x = jnp.zeros((B, q_len, H, 8), jnp.float32)
+    with pytest.raises(ValueError, match="decode_kv_layout"):
+        decode_attention(
+            x, x, x, decode_kv_layout(cache), index, bias,
+            learned_bias=kind == "per_head_bias",
+        )
+
+
+def test_sampler_traces_the_fused_read_and_sp_keeps_generic():
+    """One traced call site a layer: a plain sampler's decode step takes
+    the fused read (its prefill the generic one); a cache sharded over the
+    capacity axis decodes through the generic read."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from trlx_tpu.models.gpt2 import GPT2Config, GPT2Model, init_cache
+    from trlx_tpu.ops.sampling import GenerationConfig, make_sampler
+
+    cfg = GPT2Config(vocab_size=50, n_positions=32, n_embd=16, n_layer=2,
+                     n_head=2, dtype="float32")
+    model = GPT2Model(cfg)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    gen = GenerationConfig(max_new_tokens=4, eos_token_id=49, pad_token_id=0)
+
+    def apply_fn(params, input_ids, attention_mask=None, position_ids=None,
+                 cache=None, cache_index=None):
+        return model.apply(
+            {"params": params}, input_ids, attention_mask=attention_mask,
+            position_ids=position_ids, cache=cache, cache_index=cache_index,
+        )
+
+    ids = jnp.ones((2, 4), jnp.int32)
+    args = (params, ids, jnp.ones_like(ids), jax.random.PRNGKey(0))
+    build = functools.partial(
+        make_sampler, apply_fn, functools.partial(init_cache, cfg), gen, 4,
+        with_values=False,
+    )
+    before = _counts()
+    jax.jit(build()).lower(*args)
+    mid = _counts()
+    assert mid["fused"] - before["fused"] == cfg.n_layer  # the decode step
+    assert mid["generic"] - before["generic"] == cfg.n_layer  # the prefill
+
+    mesh = Mesh(np.array(jax.devices()[:2]).reshape(1, 2), ("dp", "sp"))
+    sharded = build(cache_sharding=NamedSharding(mesh, P("dp", "sp")))
+    jax.jit(sharded).lower(*args)
+    after = _counts()
+    assert after["fused"] == mid["fused"]
+    assert after["generic"] - mid["generic"] == 2 * cfg.n_layer

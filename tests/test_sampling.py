@@ -456,10 +456,12 @@ def test_int8_cache_extends_to_all_causal_families():
         init_gptj_cache(replace(cases[0][1], kv_cache_dtype="fp8"), 4, 8)
 
 
-def _make_segmented_sampler(
-    config, model, Q, R, segment_size, eos=96, max_length=0
-):
-    """Sampler with an explicit decode_segment_size (0 = monolithic)."""
+def _make_counting_sampler(config, model, Q, R, eos=96, max_length=0,
+                           steps=None):
+    """Sampler whose decode forwards (one token a call) are counted into
+    ``steps[0]`` through a host callback, when a list is given."""
+    import jax
+
     from trlx_tpu.models.gpt2 import init_cache
     from trlx_tpu.ops.sampling import GenerationConfig, make_sampler
 
@@ -470,11 +472,15 @@ def _make_segmented_sampler(
         pad_token_id=0,
         top_k=0,
         max_length=max_length,
-        decode_segment_size=segment_size,
     )
+
+    def count():
+        steps[0] += 1
 
     def apply_fn(params, input_ids, attention_mask=None, position_ids=None,
                  cache=None, cache_index=None):
+        if steps is not None and input_ids.shape[1] == 1:
+            jax.debug.callback(count)
         return model.apply(
             {"params": params}, input_ids, attention_mask=attention_mask,
             position_ids=position_ids, cache=cache, cache_index=cache_index,
@@ -485,14 +491,14 @@ def _make_segmented_sampler(
     )
 
 
-def test_segmented_decode_bitwise_matches_monolithic(tiny_policy):
-    """Early-exit segmented decode: splitting the R-step scan into
-    cond-wrapped segments (skipping the transformer apply once every row
-    finished) must be BITWISE-identical to the monolithic scan — tokens,
-    masks, behavior logprobs, and values. max_length forces every row to
-    finish early DETERMINISTICALLY (row i after max_length - n_real_i
-    tokens), so the all-finished skip branch is guaranteed on the line
-    for the tail segments."""
+def test_early_exit_decode_bitwise_matches_full_run(tiny_policy, monkeypatch):
+    """The decode loop stops once every row has finished, and its outputs
+    are BITWISE what the full R-step run gives — tokens, masks, behavior
+    logprobs, and values (finished rows emit constants; the outputs are
+    pre-filled with them). max_length forces every row to finish early
+    DETERMINISTICALLY (row i after max_length - n_real_i tokens), so the
+    exit is guaranteed on the line. The full run is the same loop with the
+    all-finished term taken out of its predicate."""
     import jax
     import jax.numpy as jnp
 
@@ -506,49 +512,58 @@ def test_segmented_decode_bitwise_matches_monolithic(tiny_policy):
         mask[i, Q - L:] = 1
 
     # max_length=6: rows finish at t = 6 - n_real - 1 = [1, 2, 3, 4];
-    # all finished from t=5 on -> segments covering [5, 8) skip
-    mono = jax.jit(
-        _make_segmented_sampler(config, model, Q, R, 0, max_length=6)
-    )
-    # segment_size 2: real multi-step segments; 3: gcd(8,3)=1, the
-    # per-step cond fallback (one jitted monolith serves both)
-    for segment_size in (2, 3):
-        segd = jax.jit(
-            _make_segmented_sampler(
-                config, model, Q, R, segment_size, max_length=6
-            )
+    # all finished from t=5 on -> the loop runs steps 0..4 and stops
+    full_steps, steps = [0], [0]
+    full = jax.jit(_make_counting_sampler(
+        config, model, Q, R, max_length=6, steps=full_steps))
+    early = jax.jit(_make_counting_sampler(
+        config, model, Q, R, max_length=6, steps=steps))
+    key = jax.random.PRNGKey(0)
+    with monkeypatch.context() as m:
+        real = jax.lax.while_loop
+        # traced here, under the patch: carry[0] is the step counter t
+        m.setattr(
+            jax.lax, "while_loop",
+            lambda cond, body, init: real(lambda c: c[0] < R, body, init),
         )
-        for seed in range(2):
-            key = jax.random.PRNGKey(seed)
-            a = mono(params, jnp.asarray(ids), jnp.asarray(mask), key)
-            b = segd(params, jnp.asarray(ids), jnp.asarray(mask), key)
-            for name in ("tokens", "response_mask", "logprobs", "values"):
-                np.testing.assert_array_equal(
-                    np.asarray(getattr(a, name)),
-                    np.asarray(getattr(b, name)),
-                    err_msg=f"{name} (seed {seed}, segment {segment_size})",
-                )
-            lengths = np.asarray(a.response_mask).sum(axis=1)
-            # max_length caps row i at 6 - n_real_i live tokens (a
-            # sampled eos may finish a row even earlier)
-            assert (lengths <= np.array([2, 3, 4, 5])).all(), lengths
-            # the tail past t=5 is all-finished: segments there take
-            # the skip branch; emissions are pad/zeros
-            assert (np.asarray(a.tokens)[:, 5:] == 0).all()
-            assert (np.asarray(a.response_mask)[:, 5:] == 0).all()
+        full(params, jnp.asarray(ids), jnp.asarray(mask), key)
+    for seed in range(2):
+        key = jax.random.PRNGKey(seed)
+        full_steps[0] = steps[0] = 0
+        a = full(params, jnp.asarray(ids), jnp.asarray(mask), key)
+        b = early(params, jnp.asarray(ids), jnp.asarray(mask), key)
+        jax.block_until_ready((a, b))
+        jax.effects_barrier()
+        for name in ("tokens", "response_mask", "logprobs", "values"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(a, name)),
+                np.asarray(getattr(b, name)),
+                err_msg=f"{name} (seed {seed})",
+            )
+        lengths = np.asarray(a.response_mask).sum(axis=1)
+        # max_length caps row i at 6 - n_real_i live tokens (a
+        # sampled eos may finish a row even earlier)
+        assert (lengths <= np.array([2, 3, 4, 5])).all(), lengths
+        # the tail past t=5 is all-finished: never run, pre-filled
+        assert (np.asarray(b.tokens)[:, 5:] == 0).all()
+        assert (np.asarray(b.response_mask)[:, 5:] == 0).all()
+        # the loop stops: the step that finishes the last row is the last
+        # one run (its forward still runs), the full run takes all R
+        assert full_steps[0] == R, full_steps
+        assert steps[0] == lengths.max() <= 5, (steps, lengths)
 
 
 def test_finished_rows_emit_deterministic_zeros(tiny_policy):
     """Post-finish slots emit logprob 0.0 and value 0.0 (mask is 0 there;
-    training consumes neither) — the invariant that makes the segmented
-    skip branch exact and keeps masked slots independent of post-eos
+    training consumes neither) — the invariant that makes the early exit
+    exact and keeps masked slots independent of post-eos
     logits."""
     import jax
     import jax.numpy as jnp
 
     config, model, params = tiny_policy
     Q, R, B = 4, 8, 8
-    sampler = jax.jit(_make_segmented_sampler(config, model, Q, R, 2, eos=3))
+    sampler = jax.jit(_make_counting_sampler(config, model, Q, R, eos=3))
     ids = jnp.asarray(
         np.random.default_rng(0).integers(1, 96, size=(B, Q)), jnp.int32
     )

@@ -28,7 +28,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from trlx_tpu.ops.attention import causal_dispatch, dot_product_attention
+from trlx_tpu.ops.attention import (
+    causal_dispatch,
+    decode_attention,
+    dot_product_attention,
+)
 
 # KV cache: tuple over layers of {"k": [B, C, H, Dh], "v": [B, C, H, Dh]}
 Cache = Tuple[Dict[str, jax.Array], ...]
@@ -62,8 +66,9 @@ class GPT2Config:
     # Rollout KV-cache storage. Single-token decode is HBM-bound and the
     # cache is its dominant traffic (grows with context while weights
     # stay fixed), so "int8" halves the bottleneck: K/V quantized per
-    # (token, head) on write (absmax/127 scale), dequantized on read
-    # inside the attention matmul's operand fusion. Training/scoring
+    # (token, head) on write (absmax/127 scale); on read the fixed
+    # sampler scales scores and weights, the generic path dequantises the
+    # buffer (ops/attention.py::decode_attention). Training/scoring
     # forwards never touch this — only the sampler's cache buffers.
     # "auto" resolves per cache shape: int8 below the measured capacity
     # crossover (INT8_KV_MAX_CAPACITY), bf16 beyond it.
@@ -129,17 +134,14 @@ class Attention(nn.Module):
 
         new_kv = None
         if cache_kv is not None:
-            # Write this step's keys/values into the capacity buffer at
-            # cache_index, then attend over the buffer VIEW the bias was
-            # built for (invalid positions are masked by `bias`; a bias
-            # narrower than capacity — the chunked prefill's prompt-only
-            # mask — narrows the attention view to match).
-            view_len = bias.shape[-1] if bias is not None else None
-            k, v, new_kv = write_cache(
-                cache_kv, k, v, cache_index, dtype, view_len=view_len
+            # write this step's keys/values into the capacity buffer at
+            # cache_index and attend over it (invalid positions are masked
+            # by `bias`)
+            out, new_kv = decode_attention(
+                q, k, v, cache_kv, cache_index, bias, causal=causal
             )
-
-        out = dot_product_attention(q, k, v, bias, causal=causal)
+        else:
+            out = dot_product_attention(q, k, v, bias, causal=causal)
         out = out.reshape(B, T, cfg.n_embd)
         out = nn.Dense(cfg.n_embd, dtype=dtype, param_dtype=pdtype, name="c_proj")(out)
         return out, new_kv
@@ -271,16 +273,23 @@ def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 
 def write_cache(cache_kv, k, v, cache_index, dtype, view_len=None):
-    """Write this step's K/V into the capacity buffers at ``cache_index``;
+    """Write this call's K/V into the capacity buffers at ``cache_index``;
     returns ``(k, v, new_kv)`` — the full buffers to attend over and the
-    updated cache dict. Transparent over the three storage layouts
-    (shared by every causal family):
+    updated cache dict. The generic arm of
+    ``ops/attention.py::decode_attention`` (prefill, chunked prefill, the
+    verify step, the paged engine, T5, an sp-sharded cache); the fixed
+    sampler's one-token steps do not come here — they write in place and
+    read the stored buffers once, in ``decode_kv_layout``. Transparent over
+    the three storage layouts (shared by every causal family):
 
     - plain: ``{"k", "v"}`` in the compute dtype;
     - int8 (``kv_cache_dtype="int8"``): quantize the new slice, store
-      value+scale, dequantize the whole buffer for attention — the
-      convert+mul folds into the attention matmuls' operand read, so HBM
-      sees int8, the MXU sees bf16;
+      value+scale, dequantize the whole buffer for attention. On the chip
+      the convert+mul does NOT fold into the attention matmuls' operand
+      read for a one-token query: v5e traces showed each read of a
+      ``[64, 512, 16, 64]`` int8 buffer at 252 us where its bytes take
+      41 (PERF.md §5-§6, PR 23-25) — the reason the decode loop has its
+      own read;
     - paged (``"block_tables"`` present — the continuous-batching
       engine's cache, ``inference/kv_cache.py``): writes resolve logical
       positions through per-slot block tables (``cache_index`` may be a
@@ -288,8 +297,8 @@ def write_cache(cache_kv, k, v, cache_index, dtype, view_len=None):
       with the int8 layout.
 
     ``view_len`` (static) narrows the RETURNED attention view to the
-    leading ``view_len`` logical positions — the families derive it from
-    their attention bias width (``ops/attention.py::causal_dispatch``:
+    leading ``view_len`` logical positions — ``decode_attention`` derives
+    it from the attention bias width (``ops/attention.py::causal_dispatch``:
     mask width == view width), so the chunked prefill's prompt-chunk
     forwards never read (or pay attention FLOPs over) the decode region.
     ``None``/full-capacity is byte-identical to the unnarrowed program;
@@ -332,13 +341,18 @@ def write_cache(cache_kv, k, v, cache_index, dtype, view_len=None):
     return k, v, new_kv
 
 
-# Measured crossover for the int8 KV cache (LONGCTX.json): int8 wins 1.10x
-# at capacity 112 (the B=128 rollout shape — cache traffic dominates and
-# the dequant folds into the attention matmul read) but loses ~2x at a 2k
-# cache (B=8 long-context decode — XLA materializes the dequantized bf16
-# buffer instead of fusing the int8*scale read). The threshold sits
-# conservatively between the two measured points; a dequant-fused Pallas
-# decode read is the known fix if long-context rollouts ever dominate.
+# The int8 KV cache's capacity ceiling under ``kv_cache_dtype="auto"``. It
+# was set from LONGCTX.json (jax 0.4.36): int8 1.10x ahead at capacity 112,
+# ~2x behind at a 2k cache, explained then as "XLA materializes the
+# dequantized buffer". What the v5e traces under jax 0.9.0 showed instead
+# (PERF.md §6, PR 23-25): in the ``kv_buffers`` layout BOTH dtypes read far
+# off their bytes' time — ``Dh = 64`` is half a lane row, so the buffers
+# are padded to twice their size, and the int8 read ran at a sixth of the
+# memory's speed at capacity 512 already. The fixed sampler now decodes
+# from ``ops/attention.py::decode_kv_layout`` (int8 read within ~1.7x of
+# its bytes' time at capacity 512); the threshold stays where the
+# benchmark's configurations state it until a long-context cell measures
+# the new read beyond it (the paged engine still reads the old way).
 INT8_KV_MAX_CAPACITY = 512
 
 
@@ -354,8 +368,8 @@ def resolve_kv_cache_dtype(kv_cache_dtype: str, capacity: int) -> str:
         warnings.warn(
             f"kv_cache_dtype='int8' with a {capacity}-token cache: measured "
             f"~2x SLOWER than bfloat16 beyond ~{INT8_KV_MAX_CAPACITY} "
-            "(LONGCTX.json decode, B=8/2k — XLA materializes the "
-            "dequantized buffer); set kv_cache_dtype='auto' to pick the "
+            "(LONGCTX.json decode, B=8/2k, measured under jax 0.4.36 on "
+            "the generic read); set kv_cache_dtype='auto' to pick the "
             "faster layout per shape, or 'bfloat16' to silence this"
         )
     return kv_cache_dtype

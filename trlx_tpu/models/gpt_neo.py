@@ -35,6 +35,7 @@ from trlx_tpu.ops.attention import (
     NEG_INF,
     causal_dispatch,
     combine_biases,
+    decode_attention,
     dot_product_attention,
     padding_bias,
 )
@@ -166,21 +167,16 @@ class GPTNeoAttention(nn.Module):
         k = proj("k_proj", False)(x).reshape(B, T, cfg.num_heads, head_dim)
         v = proj("v_proj", False)(x).reshape(B, T, cfg.num_heads, head_dim)
 
-        new_kv = None
-        if cache_kv is not None:
-            from trlx_tpu.models.gpt2 import write_cache
-
-            # bias width == attention view width (a prompt-only mask —
-            # the chunked prefill — narrows the cache view to match)
-            view_len = bias.shape[-1] if bias is not None else None
-            k, v, new_kv = write_cache(
-                cache_kv, k, v, cache_index, dtype, view_len=view_len
-            )
-
         # GPT-Neo does not scale attention logits; cancel the shared core's
         # 1/sqrt(d) (HF computes q @ k^T directly in float32).
         q = q * jnp.asarray(head_dim, q.dtype) ** 0.5
-        out = dot_product_attention(q, k, v, bias, causal=causal)
+        new_kv = None
+        if cache_kv is not None:
+            out, new_kv = decode_attention(
+                q, k, v, cache_kv, cache_index, bias, causal=causal
+            )
+        else:
+            out = dot_product_attention(q, k, v, bias, causal=causal)
         out = out.reshape(B, T, cfg.hidden_size)
         return proj("out_proj", True)(out), new_kv
 

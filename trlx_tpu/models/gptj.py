@@ -18,7 +18,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from trlx_tpu.ops.attention import causal_dispatch, dot_product_attention
+from trlx_tpu.ops.attention import (
+    causal_dispatch,
+    decode_attention,
+    dot_product_attention,
+)
 from trlx_tpu.ops.rotary import apply_rotary_interleaved, rotary_angles
 
 
@@ -83,16 +87,11 @@ class GPTJAttention(nn.Module):
 
         new_kv = None
         if cache_kv is not None:
-            from trlx_tpu.models.gpt2 import write_cache
-
-            # bias width == attention view width (a prompt-only mask —
-            # the chunked prefill — narrows the cache view to match)
-            view_len = bias.shape[-1] if bias is not None else None
-            k, v, new_kv = write_cache(
-                cache_kv, k, v, cache_index, dtype, view_len=view_len
+            out, new_kv = decode_attention(
+                q, k, v, cache_kv, cache_index, bias, causal=causal
             )
-
-        out = dot_product_attention(q, k, v, bias, causal=causal)
+        else:
+            out = dot_product_attention(q, k, v, bias, causal=causal)
         out = out.reshape(B, T, cfg.n_embd)
         return proj("out_proj")(out), new_kv
 

@@ -31,7 +31,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from trlx_tpu.ops.attention import NEG_INF, dot_product_attention
+from trlx_tpu.ops.attention import (
+    NEG_INF,
+    decode_attention,
+    dot_product_attention,
+)
 
 
 @dataclass(frozen=True)
@@ -176,29 +180,30 @@ class T5Attention(nn.Module):
         B, T, _ = x.shape
         inner = cfg.num_heads * cfg.d_kv
 
+        # T5 attention is unscaled: pre-multiply q by sqrt(d_kv) to cancel
+        # the 1/sqrt(d) inside the shared attention core.
         q = self.q(x).reshape(B, T, cfg.num_heads, cfg.d_kv)
+        q = q * jnp.asarray(cfg.d_kv, q.dtype) ** 0.5
+        new_kv = None
         if static_kv is not None:
             k, v = static_kv
-            new_kv = None
         else:
             src = x if kv_source is None else kv_source
             S = src.shape[1]
             k = self.k(src).reshape(B, S, cfg.num_heads, cfg.d_kv)
             v = self.v(src).reshape(B, S, cfg.num_heads, cfg.d_kv)
-            new_kv = None
-            if cache_kv is not None:
-                # shared cache write path (would make int8 a config flip
-                # for seq2seq decode too; t5 currently ships bf16 only)
-                from trlx_tpu.models.gpt2 import write_cache
-
-                k, v, new_kv = write_cache(
-                    cache_kv, k, v, cache_index, jnp.dtype(cfg.dtype)
-                )
-
-        # T5 attention is unscaled: pre-multiply q by sqrt(d_kv) to cancel
-        # the 1/sqrt(d) inside the shared attention core.
-        q = q * jnp.asarray(cfg.d_kv, q.dtype) ** 0.5
-        out = dot_product_attention(q, k, v, bias, learned_bias=learned_bias)
+        if cache_kv is None or static_kv is not None:
+            out = dot_product_attention(
+                q, k, v, bias, learned_bias=learned_bias
+            )
+        else:
+            # shared cached-attention entry (would make int8 a config flip
+            # for seq2seq decode too; t5 currently ships bf16 only); the
+            # learned per-head bias keeps it on the generic read
+            out, new_kv = decode_attention(
+                q, k, v, cache_kv, cache_index, bias,
+                learned_bias=learned_bias,
+            )
         out = out.reshape(B, T, inner)
         return self.o(out), new_kv
 

@@ -11,6 +11,12 @@ models never branch on Python-level conditions inside jit.
 
 Softmax runs in float32 regardless of compute dtype (bf16 logits lose
 ~3 decimal digits; the MXU matmuls stay bf16 where the FLOPs are).
+
+Attention over a KV cache goes through :func:`decode_attention`: the fixed
+sampler's one-token steps read each layer's buffers once, in the lane-dense
+layout :func:`decode_kv_layout` gives them, and write the new position in
+place; every other cached call (prefill, chunked prefill, the verify step,
+the paged engine) is ``write_cache`` + :func:`dot_product_attention`.
 """
 
 from __future__ import annotations
@@ -21,6 +27,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from trlx_tpu.telemetry import get_metrics
 
 NEG_INF = -1e9  # large-negative mask value; avoids -inf NaN propagation in softmax
 
@@ -200,3 +208,159 @@ def dot_product_attention(
         preferred_element_type=jnp.float32,
     )
     return out.astype(q.dtype)
+
+
+def decode_kv_layout(cache):
+    """The dense cache in the layout the decode loop carries: heads folded
+    into the minor axis — ``k``/``v`` ``[..., C, H, Dh] -> [..., C, H*Dh]``,
+    int8 scales ``[..., C, H, 1] -> [..., H, C]``.
+
+    Why another layout: on the TPU a ``[B, C, H, Dh]`` buffer is tiled over
+    its two minor axes, and ``Dh = 64`` fills half of a 128-lane row — the
+    compiler pads it, so a gpt2-sized buffer takes (and every decode step
+    reads) twice its bytes; the ``[B, C, H, 1]`` scales take 128x theirs.
+    Folded, both are lane-dense. The prefill writes the ``kv_buffers``
+    layout and the sampler converts once, before its loop
+    (``ops/sampling.py::make_sampler``); :func:`decode_attention` keys on
+    the rank of ``k``. ``cache`` is one layer's dict, a tuple of them, or
+    the pp sampler's layer-major dict (leading ``L`` axis).
+    """
+    if not isinstance(cache, dict):
+        return tuple(decode_kv_layout(c) for c in cache)
+    out = {}
+    for name, a in cache.items():
+        if name.endswith("_scale"):
+            out[name] = jnp.swapaxes(a[..., 0], -1, -2)
+        else:
+            out[name] = a.reshape(*a.shape[:-2], a.shape[-2] * a.shape[-1])
+    return out
+
+
+def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias):
+    """One new position over a cache in :func:`decode_kv_layout`: write it in
+    place, then read K and V once each, as stored.
+
+    Per-head products run on the MXU against the folded ``[C, H*Dh]`` buffer:
+    ``q`` is laid out block-diagonally (``[H, H*Dh]``, head ``h``'s row holds
+    ``q[h]`` in its own ``Dh`` columns and zeros elsewhere), so one matmul
+    gives the ``[H, C]`` scores; the ``[H, C]`` weights times the buffer give
+    ``[H, H*Dh]``, whose diagonal blocks are the output. Neither needs the
+    buffer in ``[C, H, Dh]`` form, a cross-lane reduce, or a dequantised
+    copy: int8 values convert to the compute dtype exactly, and their
+    per-(position, head) scales multiply the scores and the weights.
+    Products accumulate in float32 and the softmax is float32, as in
+    :func:`dot_product_attention`.
+    """
+    from trlx_tpu.models.gpt2 import quantize_kv
+
+    B, _, H, Dh = q.shape
+    HD = H * Dh
+    quantized = "k_scale" in cache_kv
+    new_kv = {}
+    for name, new in (("k", k_new), ("v", v_new)):
+        if quantized:
+            new, scale = quantize_kv(new)
+            new_kv[name + "_scale"] = jax.lax.dynamic_update_slice(
+                cache_kv[name + "_scale"],
+                scale.reshape(B, H, 1),
+                (0, 0, cache_index),
+            )
+        new_kv[name] = jax.lax.dynamic_update_slice(
+            cache_kv[name],
+            new.reshape(B, 1, HD).astype(cache_kv[name].dtype),
+            (0, cache_index, 0),
+        )
+    # fenced: the update stays ONE in-place op with the loop carry as its
+    # only destination. Unfenced, XLA fuses a duplicate of it into the read
+    # as well, two ops then write from one operand, neither can be in place
+    # and copy insertion copies the whole buffer at every step (PERF.md §6)
+    new_kv = jax.lax.optimization_barrier(new_kv)
+
+    seg = (jnp.arange(HD)[None, :] // Dh == jnp.arange(H)[:, None])
+    q_blocks = jnp.where(seg[None], q.reshape(B, 1, HD), 0).astype(q.dtype)
+    scores = jnp.einsum(
+        "bhk,bck->bhc", q_blocks, new_kv["k"].astype(q.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    if quantized:
+        scores = scores * new_kv["k_scale"].astype(jnp.float32)
+    scores = scores * jax.lax.rsqrt(jnp.float32(Dh))
+    scores = scores + bias[:, 0].astype(jnp.float32)
+    weights = jax.nn.softmax(scores, axis=-1)
+    if quantized:
+        weights = weights * new_kv["v_scale"].astype(jnp.float32)
+    # rounded to the compute dtype where they meet V, as the generic read
+    # does: two-term weights (16 + 16 bits) read the same log-probability
+    # error on the chip, to 1% (PERF.md §6, PR 25)
+    blocks = jnp.einsum(
+        "bhc,bck->bhk", weights.astype(q.dtype), new_kv["v"].astype(q.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    out = jnp.sum(jnp.where(seg[None], blocks, 0.0), axis=1)
+    return out.reshape(B, 1, H, Dh).astype(q.dtype), new_kv
+
+
+def decode_attention(
+    q: jax.Array,  # [B, Q, H, D]
+    k_new: jax.Array,  # [B, Q, H, D]
+    v_new: jax.Array,  # [B, Q, H, D]
+    cache_kv,
+    cache_index,
+    bias: Optional[jax.Array],
+    *,
+    causal: bool = False,
+    learned_bias: bool = False,
+):
+    """Write this call's keys/values into ``cache_kv`` at ``cache_index``
+    and attend over the cache; returns ``(out [B, Q, H, D], new_kv)``. The
+    one cached-attention entry of every family.
+
+    Dispatch is on what the call shows, at trace time (counted per traced
+    call site in ``attention/decode_path{path=...}``):
+
+    - ``fused`` — the cache is in :func:`decode_kv_layout` (the fixed
+      sampler's decode loop): :func:`_decode_read`. Such a cache takes one
+      position a call under a bias broadcast over heads; anything else is
+      refused, not rerouted;
+    - ``generic`` — everything else, unchanged: ``write_cache`` (dense
+      ``kv_buffers`` layout or paged) returns the view the bias was built
+      for and :func:`dot_product_attention` reads it. Prefill and chunked
+      prefill, the verify step, T5's learned per-head bias, a cache whose
+      capacity axis is sharded (the sampler leaves those in the
+      ``kv_buffers`` layout), and the paged engine.
+    """
+    fused = "block_tables" not in cache_kv and cache_kv["k"].ndim == 3
+    get_metrics().counter(
+        "attention/decode_path{path=%s}" % ("fused" if fused else "generic")
+    ).inc()
+    if fused:
+        if (
+            q.shape[1] != 1
+            or learned_bias
+            or bias is None
+            or bias.shape[1] != 1
+            or jnp.ndim(cache_index) != 0
+        ):
+            raise ValueError(
+                "a cache in decode_kv_layout takes one position a call, at "
+                "a scalar cache_index, under a bias broadcast over heads; "
+                f"got q {q.shape}, bias "
+                f"{None if bias is None else bias.shape}, "
+                f"learned_bias={learned_bias}"
+            )
+        # device-trace scope names are a contract (docs/observability.md)
+        with jax.named_scope("decode_attention"):
+            return _decode_read(q, k_new, v_new, cache_kv, cache_index, bias)
+    from trlx_tpu.models.gpt2 import write_cache
+
+    # attend over the buffer VIEW the bias was built for: a bias narrower
+    # than capacity (the chunked prefill's prompt-only mask) narrows the
+    # view to match
+    view_len = bias.shape[-1] if bias is not None else None
+    k, v, new_kv = write_cache(
+        cache_kv, k_new, v_new, cache_index, q.dtype, view_len=view_len
+    )
+    out = dot_product_attention(
+        q, k, v, bias, causal=causal, learned_bias=learned_bias
+    )
+    return out, new_kv

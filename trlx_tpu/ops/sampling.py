@@ -1,4 +1,4 @@
-"""Jitted autoregressive sampling: prefill + ``lax.scan`` decode.
+"""Jitted autoregressive sampling: prefill + a compiled decode loop.
 
 Replaces the reference's HF ``generate`` Python token loop
 (``trlx/model/nn/ppo_models.py:620-622``; ILQL's hand-rolled loop
@@ -7,7 +7,8 @@ Replaces the reference's HF ``generate`` Python token loop
 - prompts are left-padded to a fixed query length Q, so the last prompt
   token always sits at buffer slot Q-1 and decode writes slots Q..Q+R-1 —
   static shapes, zero recompilation across batches;
-- the decode loop is ``lax.scan`` over R steps carrying the KV cache;
+- the decode loop is one ``lax.while_loop`` carrying the KV cache, at most
+  R steps, fewer once every row has finished (the seq2seq sampler scans R);
 - per-step behavior logprobs (under the *raw* logits, matching the
   training-time recompute — the reference likewise recomputes logprobs from
   unfiltered logits, `ppo_orchestrator.py:126-155`) and value estimates are
@@ -22,7 +23,6 @@ sequence with pad fill (`ilql_models.py:314-325` semantics).
 from __future__ import annotations
 
 import dataclasses
-import math
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -30,6 +30,7 @@ import flax.struct as struct
 import jax
 import jax.numpy as jnp
 
+from trlx_tpu.ops.attention import decode_kv_layout
 from trlx_tpu.utils import topk_mask
 
 
@@ -65,15 +66,6 @@ class GenerationConfig:
     # `ppo_models.py:620-622`); -1 = disabled
     forced_bos_token_id: int = -1
     decoder_start_token_id: int = 0
-    # Early-exit segmented decode (causal sampler): the R-step scan runs as
-    # fixed segments of gcd(R, decode_segment_size) steps, each wrapped in a
-    # lax.cond that skips the transformer apply once EVERY row has finished
-    # — the compiled program keeps static shapes but stops paying the
-    # per-token forward for all-pad tail steps (EOS-heavy workloads
-    # otherwise burn the full max_new_tokens budget emitting pad). 0
-    # disables segmentation (one monolithic scan). Segmented and monolithic
-    # decode are bitwise-identical (tests/test_sampling.py).
-    decode_segment_size: int = 8
     # Per-row RNG (docs/inference.md): the sampler's ``rng`` argument is a
     # [B, 2] array of per-row base keys instead of one batch key, and step
     # t of row b samples with ``fold_in(row_keys[b], t)`` — each row's
@@ -104,7 +96,7 @@ class GenerationConfig:
         for name in ("max_new_tokens", "min_new_tokens", "min_length",
                      "max_length", "top_k",
                      "eos_token_id", "pad_token_id", "forced_bos_token_id",
-                     "decoder_start_token_id", "decode_segment_size"):
+                     "decoder_start_token_id"):
             if name in d and d[name] is not None:
                 d[name] = int(d[name])
         return cls(**d)
@@ -400,7 +392,9 @@ def make_sampler(
     softmax reduction (the collective moves [B, H, cap] logits, head_dim
     times less than gathering the cache itself). Applied to the initial
     buffers and re-pinned on each step's updated cache so the constraint
-    sticks through the scan carry.
+    sticks through the loop carry. A cache whose capacity axis is sharded
+    stays in the ``kv_buffers`` layout and decodes through the generic read;
+    every other is carried in ``ops/attention.py::decode_kv_layout``.
     """
     Q = query_length
     R = gen_config.max_new_tokens
@@ -413,6 +407,15 @@ def make_sampler(
             lambda a: jax.lax.with_sharding_constraint(a, cache_sharding),
             cache,
         )
+
+    def capacity_sharded(cache):
+        # kv_buffers layout [..., C, H, Dh]; a pp cache leads with L
+        if cache_sharding is None:
+            return False
+        k = (cache if isinstance(cache, dict) else cache[0])["k"]
+        spec, axis = cache_sharding.spec, k.ndim - 3
+        return len(spec) > axis and spec[axis] is not None
+
     # Optional fast-prefill contract: an apply_fn accepting ``last_only``
     # may skip LM-head/value computation for all but the final position.
     import inspect
@@ -452,7 +455,12 @@ def make_sampler(
                 cache_index=0,
                 **_prefill_kwargs,
             )
-        cache = pin_cache(out["cache"])
+        cache = out["cache"]
+        if not capacity_sharded(cache):
+            # the decode loop carries the lane-dense layout, which
+            # decode_attention reads once a step and writes in place
+            cache = decode_kv_layout(cache)
+        cache = pin_cache(cache)
         logits_last = out["logits"][:, -1].astype(jnp.float32)  # [B, V]
         if with_values:
             value_last = out["values"][:, -1].astype(jnp.float32)
@@ -462,8 +470,8 @@ def make_sampler(
         slot_ids = jnp.arange(cap)[None, :]
 
         @jax.named_scope("decode_step")
-        def step(carry, t):
-            cache, logits_last, value_last, finished, rng = carry
+        def step(carry):
+            t, cache, logits_last, value_last, finished, rng, ys = carry
             if gen_config.per_row_rng:
                 # `rng` is the [B, 2] per-row base keys — folded with t
                 # inside choose_tokens, never chained through the carry
@@ -478,8 +486,10 @@ def make_sampler(
                 gen_config, logits_last, t, finished, value_last, n_real,
                 min_new=min_new, key=key, row_keys=row_keys,
             )
-
-            ys = (token, live, logprob, value_out)
+            ys = tuple(
+                jax.lax.dynamic_update_slice(buf, y[None], (t, 0))
+                for buf, y in zip(ys, (token, live, logprob, value_out))
+            )
 
             # forward the sampled token at slot Q+t
             cache_mask_t = (slot_ids <= Q + t).astype(jnp.int32) * concat_cols(
@@ -499,71 +509,32 @@ def make_sampler(
                 if with_values
                 else jnp.zeros((B,), jnp.float32)
             )
-            return (pin_cache(out["cache"]), new_logits, new_value, finished, rng), ys
+            return (t + 1, pin_cache(out["cache"]), new_logits, new_value,
+                    finished, rng, ys)
 
         if gen_config.max_length > 0:
             # prompts already at/over the total-length cap emit no tokens
             finished0 = n_real >= gen_config.max_length
         else:
             finished0 = jnp.zeros((B,), bool)
-        carry0 = (cache, logits_last, value_last, finished0, rng)
-
-        seg = (
-            math.gcd(R, gen_config.decode_segment_size)
-            if gen_config.decode_segment_size > 0
-            else R
+        # Early exit: the loop stops at R steps or once every row has
+        # finished. The outputs are pre-filled with what a finished row
+        # emits, (pad, 0, 0.0, 0.0), and each step writes its row `t`, so
+        # they are bitwise what the full R-step run gives (rows never
+        # un-finish; the RNG carry is not an output). The cache is a plain
+        # loop carry — no `cond` around it, whose branch boundaries copy it.
+        ys0 = (
+            jnp.full((R, B), gen_config.pad_token_id, jnp.int32),
+            jnp.zeros((R, B), jnp.int32),
+            jnp.zeros((R, B), jnp.float32),
+            jnp.zeros((R, B), jnp.float32),
         )
-        n_seg = R // seg
-        if n_seg <= 1:
-            # monolithic scan: every step runs the transformer apply
-            _, (tokens, mask, logprobs, values) = jax.lax.scan(
-                step, carry0, jnp.arange(R)
-            )
-        else:
-            # Early-exit segmented decode: scan over n_seg segments of
-            # `seg` steps; once every row is finished the segment's cond
-            # takes the skip branch — no transformer apply, no cache
-            # update. Bitwise-identical to the monolithic scan: finished
-            # rows emit (pad, 0, 0.0, 0.0) regardless of branch, the RNG
-            # carry advances by exactly one split per step in both
-            # branches, and rows never un-finish, so the stale
-            # cache/logits carried past a skipped segment are never read.
-            def run_seg(carry, ts):
-                return jax.lax.scan(step, carry, ts)
-
-            def skip_seg(carry, ts):
-                cache, logits_last, value_last, finished, rng = carry
-
-                if not gen_config.per_row_rng:
-                    # legacy batch keys chain through the carry: advance
-                    # by exactly one split per skipped step so segmented
-                    # and monolithic decode stay bitwise-identical.
-                    # Per-row keys are fold_in(row_key, t) — stateless in
-                    # t — so there is nothing to advance.
-                    def skip_step(r, t):
-                        return jax.random.split(r)[0], None
-
-                    rng, _ = jax.lax.scan(skip_step, rng, ts)
-                k = ts.shape[0]
-                ys = (
-                    jnp.full((k, B), gen_config.pad_token_id, jnp.int32),
-                    jnp.zeros((k, B), jnp.int32),
-                    jnp.zeros((k, B), jnp.float32),
-                    jnp.zeros((k, B), jnp.float32),
-                )
-                return (cache, logits_last, value_last, finished, rng), ys
-
-            def seg_body(carry, ts):
-                return jax.lax.cond(
-                    jnp.all(carry[3]), skip_seg, run_seg, carry, ts
-                )
-
-            _, (tokens, mask, logprobs, values) = jax.lax.scan(
-                seg_body, carry0, jnp.arange(R).reshape(n_seg, seg)
-            )
-            tokens, mask, logprobs, values = (
-                x.reshape(R, B) for x in (tokens, mask, logprobs, values)
-            )
+        *_, (tokens, mask, logprobs, values) = jax.lax.while_loop(
+            lambda carry: (carry[0] < R) & ~jnp.all(carry[4]),
+            step,
+            (jnp.int32(0), cache, logits_last, value_last, finished0, rng,
+             ys0),
+        )
         return SampleOutput(
             tokens=tokens.T,
             response_mask=mask.T,
