@@ -7,13 +7,36 @@ published; recomputation is not counted, and neither is work a clever
 program may skip (the frozen trunk's backward IS skipped in the count,
 because no algorithm needs it).
 
-Shapes come from a configuration file (HF key names) through
-:func:`model_shape`, so both families share every formula below:
+Nothing here knows a model family either. A family's file under
+``reference/`` exports ``shape(cfg)``, its shape rule on a configuration
+file's published keys, and every formula below is computed from what that
+returns (:func:`model_shape` checks it):
 
-- ``d`` hidden, ``L`` layers, ``V`` vocabulary, ``ff`` MLP width,
-- ``tied``: the head reuses the token embedding (gpt2) or is a matrix of
-  its own (neox ``embed_out``),
-- ``learned_pos``: rows of a learned position table (gpt2) or 0 (rotary).
+- ``embed_params``: the token table and any learned position table: held,
+  never multiplied with; a decode step touches ``batch`` rows of it, which
+  is not counted.
+- ``layers``: one entry per block, in order, so that leading dense layers,
+  window and global layers or recurrent layers are entries of the list
+  and not formulas of their own. An entry keeps three counts of
+  parameters apart, which are one number only in a dense block:
+
+  - ``params``: the parameters the block holds;
+  - ``matmul_params``: the matrix parameters one token is multiplied with
+    (2 FLOPs each) - in a routed block the router and
+    ``experts_per_token`` experts, not all of them;
+  - ``read_params``: the weights every decode step reads whatever it
+    routes, and, in a routed block, ``routed``: ``{"expert_params",
+    "per_token"}``, the size of one expert and how many a token
+    chooses: a step reads an expert only where a token chose it
+    (:func:`decode_read_params`);
+
+  and ``attn_dim``, query heads x head size (QK^T and AV cost 4 FLOPs
+  times this for each pair of token and context position), and
+  ``kv_values``, the cache values written per position: KV heads x head
+  size x 2, and 0 in a block that keeps none.
+- ``final``: the final norm and the head, as ``params`` (what they hold
+  beyond the token table: a tied head holds nothing), ``matmul_params``
+  and ``read_params``.
 """
 
 from __future__ import annotations
@@ -37,55 +60,41 @@ def load_peaks(device_kind: str) -> Dict[str, float]:
     return table[device_kind]
 
 
-def model_shape(cfg: Dict[str, Any]) -> Dict[str, Any]:
-    """The sizes the formulas need, from a configuration file's HF keys."""
-    if cfg["model_type"] == "gpt2":
-        d = cfg["n_embd"]
-        return {
-            "d": d, "L": cfg["n_layer"], "V": cfg["vocab_size"],
-            "H": cfg["n_head"], "ff": cfg.get("n_inner") or 4 * d,
-            "tied": True, "learned_pos": cfg["n_positions"],
-        }
-    if cfg["model_type"] == "gpt_neox":
-        d = cfg["hidden_size"]
-        return {
-            "d": d, "L": cfg["num_hidden_layers"], "V": cfg["vocab_size"],
-            "H": cfg["num_attention_heads"], "ff": cfg["intermediate_size"],
-            "tied": False, "learned_pos": 0,
-        }
-    raise ValueError(f"no shape rule for model_type {cfg['model_type']!r}")
+LAYER_KEYS = ("params", "matmul_params", "read_params", "attn_dim", "kv_values")
+FINAL_KEYS = ("params", "matmul_params", "read_params")
+ROUTED_KEYS = ("expert_params", "per_token")
 
 
-def block_params(s) -> int:
-    """One transformer block: QKV + output projection, the MLP, two
-    LayerNorms, all with biases."""
-    d, ff = s["d"], s["ff"]
-    attn = d * 3 * d + 3 * d + d * d + d
-    mlp = d * ff + ff + ff * d + d
-    return attn + mlp + 4 * d
+def model_shape(family, cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """The family's shape rule on a configuration, refused here, by name,
+    where it lacks a count the formulas read or gives one that is not a
+    whole number."""
+    s = family.shape(cfg)
+    groups = [("shape", s, ("embed_params",)), ("final", s["final"], FINAL_KEYS)]
+    for i, layer in enumerate(s["layers"]):
+        groups.append((f"layers[{i}]", layer, LAYER_KEYS))
+        if "routed" in layer:
+            groups.append((f"layers[{i}].routed", layer["routed"], ROUTED_KEYS))
+    for where, group, keys in groups:
+        for key in keys:
+            if not isinstance(group.get(key), int) or group[key] < 0:
+                raise ValueError(f"{family.__name__}.shape: {where}[{key!r}] is {group.get(key)!r}")
+    return s
 
 
 def backbone_params(s) -> int:
     """Every parameter of the language model (no value head)."""
-    head = 0 if s["tied"] else s["d"] * s["V"]
-    return (
-        s["V"] * s["d"] + s["learned_pos"] * s["d"]
-        + s["L"] * block_params(s) + 2 * s["d"] + head
-    )
+    return s["embed_params"] + sum(l["params"] for l in s["layers"]) + s["final"]["params"]
 
 
-def matmul_params_per_layer(s) -> int:
-    return 4 * s["d"] * s["d"] + 2 * s["d"] * s["ff"]
-
-
-def forward_flops(s, tokens: int, ctx_sum: int, head_tokens: int, layers=None) -> float:
+def forward_flops(s, tokens: int, ctx_sum: int, head_tokens: int, layers=None) -> int:
     """Matmul FLOPs of a forward over ``tokens`` positions whose attention
     contexts sum to ``ctx_sum`` (a causal pass over T: T(T+1)/2), with the
-    head applied at ``head_tokens`` positions. 2 FLOPs per multiply-add;
-    QK^T and AV cost 4*d per (token, context position) and layer."""
-    L = s["L"] if layers is None else layers
-    trunk = 2 * matmul_params_per_layer(s) * L * tokens + 4 * L * s["d"] * ctx_sum
-    return trunk + 2 * s["d"] * s["V"] * head_tokens
+    head applied at ``head_tokens`` positions; through the top ``layers``
+    blocks only where that is given. 2 FLOPs per multiply-add."""
+    blocks = s["layers"] if layers is None else s["layers"][len(s["layers"]) - layers:]
+    trunk = sum(2 * l["matmul_params"] * tokens + 4 * l["attn_dim"] * ctx_sum for l in blocks)
+    return trunk + 2 * s["final"]["matmul_params"] * head_tokens
 
 
 def ppo_phase_flops(s, Q: int, R: int, rollouts: int, ppo_epochs: int, unfrozen: int = 0):
@@ -103,21 +112,43 @@ def ppo_phase_flops(s, Q: int, R: int, rollouts: int, ppo_epochs: int, unfrozen:
     decode = forward_flops(s, R, sum(Q + t + 1 for t in range(R)), R)
     ref = forward_flops(s, T, ctx_T, R)
     fwd = forward_flops(s, T, ctx_T, R)
-    if 0 < unfrozen < s["L"]:
+    if 0 < unfrozen < len(s["layers"]):
         bwd = 2 * forward_flops(s, T, ctx_T, R, layers=unfrozen)
     else:
         bwd = 2 * fwd
     return rollouts * (prefill + decode + ref), ppo_epochs * rollouts * (fwd + bwd)
 
 
-def decode_step_bytes(s, batch: int, context: float, weight_bytes: int = 2,
+def decode_read_params(layer) -> int:
+    """The weights of one block that a decode step must read, whatever
+    its batch. A dense block: all of them. A routed block: what every
+    token passes through, and ``per_token`` experts. Which experts a step
+    reads is decided by the routing, not by shapes: the tokens of a step
+    choose between ``per_token`` distinct experts (they all agree) and
+    ``batch x per_token``, or every expert (no two agree). The least is
+    taken, because it is the one count no correct program can move fewer
+    bytes than: a single token is multiplied with ``per_token`` whole
+    experts, and a step whose tokens agree reads no more. Any larger
+    count, such as the expected number of distinct experts under even
+    routing, is one that a skewed and correct step beats: its share of
+    the roofline would read over 100%. So for a large batch this is a
+    floor well under what the step reads; a family that wants the tight
+    figure counts the experts the program did touch from a counter, in a
+    count function of its own (``readers.op_roofline``)."""
+    routed = layer.get("routed")
+    if not routed:
+        return layer["read_params"]
+    return layer["read_params"] + routed["expert_params"] * routed["per_token"]
+
+
+def decode_step_bytes(s, batch: float, context: float, weight_bytes: int = 2,
                       kv_bytes: int = 2, shards: int = 1) -> float:
-    """Bytes one decode step must move on one chip: every weight once at
-    the compute dtype (divided over ``shards`` chips where the weights are
-    sharded, as fsdp leaves them to be gathered), the keys and values of ``context`` cached positions read and
-    one position written, at the cache dtype, for ``batch`` sequences."""
-    # the blocks, the final LayerNorm and the head matrix (tied or not);
-    # the embedding lookup touches ``batch`` rows, which is not counted
-    weights = s["L"] * block_params(s) + 2 * s["d"] + s["d"] * s["V"]
-    kv = 2 * s["L"] * batch * (context + 1) * s["d"] * kv_bytes
+    """Bytes one decode step must move on one chip: the weights it must
+    read (:func:`decode_read_params` of every block, the final norm and
+    the head matrix, tied or not) once at the compute dtype, divided over
+    ``shards`` chips where the weights are sharded, as fsdp leaves them to
+    be gathered; the keys and values of ``context`` cached positions read
+    and one position written, at the cache dtype, for ``batch`` sequences."""
+    weights = sum(decode_read_params(l) for l in s["layers"]) + s["final"]["read_params"]
+    kv = sum(l["kv_values"] for l in s["layers"]) * batch * (context + 1) * kv_bytes
     return weights * weight_bytes / shards + kv

@@ -35,16 +35,13 @@ def error_stats(got, ref, scale: float = 1.0, where=None) -> Tuple[float, float]
     return float(np.sqrt((d**2).mean()) / scale), float(np.abs(d).max() / scale)
 
 
-def reference_logits(model_type: str, config_file: Dict[str, Any], backbone_params,
-                     ids, mask):
-    """The family's float32 reference on ``ids``/``mask`` ([n, T]); one
-    jitted call on the first device holding the (gathered) parameters."""
+def reference_logits(family, config_file: Dict[str, Any], backbone_params, ids, mask):
+    """The float32 reference of the configuration's ``family``
+    (``harness.load_family``) on ``ids``/``mask`` ([n, T]); one jitted
+    call on the first device holding the (gathered) parameters."""
     import jax
 
-    from benchmark.reference import FORWARD
-
-    fwd = FORWARD[model_type]
-    return jax.jit(lambda p, i, m: fwd(p, config_file, i, m))(backbone_params, ids, mask)
+    return jax.jit(lambda p, i, m: family.forward(p, config_file, i, m))(backbone_params, ids, mask)
 
 
 def compare_with_reference(tag: str, ref_logits, query_length: int,

@@ -2,15 +2,18 @@
 gate, the compile counter, host spans, the profiler window and the result
 line.
 
-Nothing here knows a cell, a configuration or a metric by name: those are
-files (``workloads/``, ``traffic/``, ``configs/``, ``layer_metrics/``)
-that ``BENCHMARK.json`` names.
+Nothing here knows a cell, a configuration, a model family or a metric by
+name: those are files (``workloads/``, ``traffic/``, ``configs/``,
+``reference/``, ``layer_metrics/``) that ``BENCHMARK.json`` and the
+configuration files name.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import glob
+import importlib.util
 import json
 import os
 import time
@@ -33,8 +36,35 @@ def load_json(*parts: str) -> Dict[str, Any]:
         return json.load(f)
 
 
+@functools.lru_cache(maxsize=None)
+def _module_at(path: str):
+    name = "benchmark_family_" + os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def load_family(config_file: Dict[str, Any], root: str = HERE):
+    """The model family of a configuration: the module at its ``reference``
+    path (relative to the checkout, which is the parent of ``root``),
+    loaded by file path so that a copy of the tree under another root
+    brings its own families. The module exports ``forward(params, cfg,
+    ids, mask)`` (the plain float32 reference), ``shape(cfg)`` (the sizes
+    ``arithmetic.py`` reckons with) and, where the family has one,
+    ``check_config(cfg)``, which raises on a configuration the program
+    cannot build as published."""
+    path = os.path.realpath(os.path.join(os.path.dirname(root), config_file["reference"]))
+    if not os.path.isfile(path):
+        raise FileNotFoundError(
+            f"configuration {config_file.get('name')!r} names the reference {path}, which is not there")
+    return _module_at(path)
+
+
 def load_cell(name: str, root: str = HERE) -> Dict[str, Any]:
-    """A cell with its configuration and traffic mix resolved by name."""
+    """A cell with its configuration and traffic mix resolved by name, and
+    the configuration's ``family`` (:func:`load_family`), which has
+    checked the configuration."""
     def read(kind, key):
         path = os.path.join(root, kind, f"{key}.json")
         if not os.path.exists(path):
@@ -45,18 +75,33 @@ def load_cell(name: str, root: str = HERE) -> Dict[str, Any]:
     cell = read("workloads", name)
     cell["config_file"] = read("configs", cell["config"])
     cell["traffic_file"] = read("traffic", cell["traffic"])
+    cell["family"] = load_family(cell["config_file"], root)
+    if hasattr(cell["family"], "check_config"):
+        cell["family"].check_config(cell["config_file"])
     return cell
 
 
 def load_layer_metrics(cell_name: str, root: str = HERE) -> List[Dict[str, Any]]:
-    """The per-layer metric files that are read in this cell (a file with
-    no ``workloads`` key is read in every cell)."""
+    """The per-layer metrics read in this cell: the ``per_layer`` entries
+    of the ``BENCHMARK.json`` beside ``root`` whose ``workloads`` list the
+    cell, each with the ``reader`` of its file
+    ``layer_metrics/<name>.json``. The manifest is the one place that
+    says which cell reads which metric, its unit and what it moves; the
+    file says how it is read. An entry names its cells: one without
+    ``workloads`` is refused."""
+    with open(os.path.join(os.path.dirname(root), "BENCHMARK.json")) as f:
+        manifest = json.load(f)
     out = []
-    for path in sorted(glob.glob(os.path.join(root, "layer_metrics", "*.json"))):
+    for entry in manifest["per_layer"]:
+        if "workloads" not in entry:
+            raise KeyError(f"the per-layer metric {entry['name']!r} lists no `workloads` in BENCHMARK.json")
+        if cell_name not in entry["workloads"]:
+            continue
+        path = os.path.join(root, "layer_metrics", f"{entry['name']}.json")
+        if not os.path.exists(path):
+            raise FileNotFoundError(f"no reader file for the per-layer metric {entry['name']!r}: {path}")
         with open(path) as f:
-            spec = json.load(f)
-        if "workloads" not in spec or cell_name in spec["workloads"]:
-            out.append(spec)
+            out.append(dict(entry, reader=json.load(f)["reader"]))
     return out
 
 
@@ -66,13 +111,6 @@ def arch_of(config_file: Dict[str, Any]) -> Dict[str, Any]:
     run = config_file["run"]
     arch = {k: config_file[k] for k in run["arch_keys"]}
     arch["kv_cache_dtype"] = run["kv_cache_dtype"]
-    if config_file["model_type"] == "gpt_neox":
-        # the program builds the MLP 4 x hidden wide and has no key for it
-        if config_file["intermediate_size"] != 4 * config_file["hidden_size"]:
-            raise ValueError(
-                "the program's NeoXConfig assumes intermediate_size = 4 x "
-                "hidden_size; this configuration publishes another"
-            )
     return arch
 
 
@@ -162,6 +200,20 @@ class CompileCounter:
 
     def mark(self):
         return (self.count, self.seconds)
+
+
+def registry_scalars(before: Optional[Dict[str, Dict[str, float]]] = None) -> Dict[str, Dict[str, float]]:
+    """The counters and gauges of the program's metrics registry, by name;
+    counters less what they read in ``before`` (an earlier call's result),
+    which leaves a window's own increments. A gauge is its last value."""
+    from trlx_tpu import telemetry
+
+    snap = telemetry.get_metrics().snapshot()
+    start = (before or {}).get("counters", {})
+    return {
+        "counters": {k: v - start.get(k, 0.0) for k, v in snap["counters"].items()},
+        "gauges": dict(snap["gauges"]),
+    }
 
 
 # ------------------------------ host spans ------------------------------ #

@@ -7,7 +7,10 @@ where ``phase_overlap`` is on (the default), the window closed on
 A traffic file with ``"driver": "ppo"`` gives: ``seq_length``,
 ``prompt_lengths``, ``new_tokens``, ``num_rollouts``, ``chunk_size``,
 ``batch_size``, ``ppo_epochs``, ``num_layers_unfrozen``,
-``ref_branch_layers``, ``lr``, ``warmup_phases``, ``trace_phases``.
+``ref_branch_layers``, ``lr``, ``warmup_phases``, ``trace_phases`` and,
+optionally, ``engine``: the program's ``train.rollout.engine`` (``fixed``,
+the compiled sampler, where the file has no such key; ``continuous`` is
+the slot engine).
 """
 
 from __future__ import annotations
@@ -53,7 +56,7 @@ def build_config(cell: Dict[str, Any], seed: int):
             "dtype": cf["run"]["dtype"],
             "param_dtype": cf["run"]["param_dtype"],
             "health": {"enabled": True, "dump_dir": os.path.join(scratch, "health_dumps")},
-            "rollout": {"engine": "fixed"},
+            "rollout": {"engine": t.get("engine", "fixed")},
         },
         "method": {
             "name": "PPOConfig",
@@ -167,6 +170,7 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
     window = harness.ProfilerWindow(cell["name"]) if trace else None
     setup_s = time.time() - t_start
     mark = compiles.mark()
+    scalars = harness.registry_scalars()
 
     attempted = failed = 0
     if window:
@@ -186,6 +190,7 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
             break
     xplane = window.stop() if window else None
     compiled_in_window = compiles.mark()[0] - mark[0]
+    scalars = harness.registry_scalars(scalars)
     done = attempted - failed
 
     # ---------------- outside the window: what decides `correct` ---------------- #
@@ -211,16 +216,21 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
     events = dict(sorted(trainer.health_monitor.event_counts.items()))
     print(f"note health_events (not part of correct): {events}", flush=True)
 
-    in_phases = sum(spans.durations_ms("phase")) / 1e3
+    phase_s = [ms / 1e3 for ms in spans.durations_ms("phase")]
     print(f"note ppo: phases={done} wall_to_last_fence_s={t_fenced - t0:.4f} "
-          f"inside_phase_spans_s={in_phases:.4f} "
+          f"inside_phase_spans_s={sum(phase_s):.4f} "
           f"samples_per_s={done * rollouts / (t_fenced - t0) if done else 0.0:.4f}", flush=True)
+    # each phase's own length: a run that reads far off shows here whether
+    # one phase stalled or all of them were slow
+    print(f"note ppo: phase_s={[round(x, 3) for x in phase_s]}", flush=True)
+    shape = model_shape(cell["family"], cf)
     record = {
         "kind": "ppo", "cell": cell, "device": device, "spans": spans,
-        "tracer_stats": tracer.stats(), "phases": done,
+        "tracer_stats": tracer.stats(), "phases": done, "window_s": t_fenced - t0,
+        "counters": scalars["counters"], "gauges": scalars["gauges"],
         "setup_s": setup_s, "compile_s_setup": mark[1], "xplane": xplane,
-        "chips": cell["chips"], "shape": model_shape(cf),
-        "flops": ppo_phase_flops(model_shape(cf), t["seq_length"], t["new_tokens"], rollouts,
+        "chips": cell["chips"], "shape": shape,
+        "flops": ppo_phase_flops(shape, t["seq_length"], t["new_tokens"], rollouts,
                                  t["ppo_epochs"], t["num_layers_unfrozen"] or 0),
         "kv_cache_dtype": harness.kv_dtype_of(cf, t["seq_length"] + t["new_tokens"]),
         # one sampler call decodes a chunk for new_tokens steps (its prefill
@@ -270,7 +280,7 @@ def reference_check(cell: Dict[str, Any], trainer, seed: int) -> bool:
     one = jax.devices()[0]
     params = jax.device_put(trainer.state.params, one)
     ids_d, mask_d = jax.device_put(jnp.asarray(ids), one), jax.device_put(jnp.asarray(mask), one)
-    ref = checks.reference_logits(cf["model_type"], cf, params[trainer.backbone_key], ids_d, mask_d)
+    ref = checks.reference_logits(cell["family"], cf, params[trainer.backbone_key], ids_d, mask_d)
     model = trainer.model
     # the update's forward on the parameters as the program holds them
     # (sharded over the cell's mesh where it has one)

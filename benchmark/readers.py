@@ -31,6 +31,19 @@ Kinds:
   memory traffic, so this is its share of the roofline.
 - ``collective``: ``what`` (``ms_per_phase``/``exposed_share``).
 - ``memory_peak_gb``.
+- ``counter``: ``name``[, ``per`` (``phase``/``second``)] - a counter (its
+  increments inside the window) or a gauge (its last value) of the
+  program's metrics registry, as it is, per completed phase, or per
+  second of the window.
+- ``op_roofline``: ``op`` (regex over device operation names as
+  ``trace_reduce.op_kind`` spells them: kind and result shape), ``count``
+  (the name of a function in the family's file, else in
+  ``arithmetic.py``) - one kernel's share of its roofline, %:
+  ``count(record, ops)`` gets the matching operations of the first chip
+  over the traced window (``{name: {"s", "count"}}``) and returns the
+  FLOPs and the bytes which that many executions require on one chip at
+  this cell's shapes; the reader divides the larger of FLOPs / peak
+  FLOP/s and bytes / peak bytes/s by the operations' summed device time.
 """
 
 from __future__ import annotations
@@ -39,8 +52,7 @@ import math
 import re
 from typing import Any, Callable, Dict, Optional
 
-from benchmark import harness
-from benchmark.arithmetic import decode_step_bytes
+from benchmark import arithmetic, harness
 
 
 def trace_of(record) -> Optional[Dict[str, Any]]:
@@ -117,7 +129,7 @@ def decode_hbm_share(record, spec):
     if not seconds or not calls or "decode" not in record:
         return None
     d = record["decode"]
-    need = decode_step_bytes(
+    need = arithmetic.decode_step_bytes(
         record["shape"], d["batch"] / record["chips"], d["mean_context"],
         weight_bytes=2, kv_bytes=1 if record["kv_cache_dtype"] == "int8" else 2,
         shards=d.get("weight_shards", 1),
@@ -140,6 +152,30 @@ def memory_peak_gb(record, spec):
     return peak / 1e9 if peak else None
 
 
+def counter(record, spec):
+    value = record.get("counters", {}).get(spec["name"])
+    if value is None:
+        value = record.get("gauges", {}).get(spec["name"])
+    per = {None: 1.0, "phase": _units(record), "second": record.get("window_s")}[spec.get("per")]
+    return value / per if value is not None and per else None
+
+
+def op_roofline(record, spec):
+    trace = trace_of(record)
+    if trace is None:
+        return None
+    rx = re.compile(spec["op"])
+    ops = {name: op for name, op in trace["ops"].items() if rx.search(name)}
+    seconds = sum(op["s"] for op in ops.values())
+    if not seconds:
+        return None
+    count = getattr(record["cell"]["family"], spec["count"], None) or getattr(arithmetic, spec["count"])
+    flops, moved = count(record, ops)
+    peaks = record["device"]["peaks"]
+    least = max(flops / peaks["bf16_flops_per_s"], moved / peaks["hbm_bytes_per_s"])
+    return 100.0 * least / seconds
+
+
 READERS: Dict[str, Callable] = {
     "harness_span": harness_span,
     "tracer_span_per_phase": tracer_span_per_phase,
@@ -150,6 +186,8 @@ READERS: Dict[str, Callable] = {
     "decode_hbm_share": decode_hbm_share,
     "collective": collective,
     "memory_peak_gb": memory_peak_gb,
+    "counter": counter,
+    "op_roofline": op_roofline,
 }
 
 

@@ -1,5 +1,4 @@
-"""Plain float32 references, one per model family, keyed by ``model_type``."""
-
-from benchmark.reference import gpt2, neox
-
-FORWARD = {"gpt2": gpt2.forward, "gpt_neox": neox.forward}
+"""Plain float32 references, one file per model family. A configuration
+names its family's file (``"reference": "benchmark/reference/<family>.py"``)
+and ``harness.load_family`` loads it from there: no list of families is
+kept anywhere. What a family's file exports is in ``harness.load_family``."""

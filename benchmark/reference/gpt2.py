@@ -16,6 +16,9 @@ that passes ``attention_mask`` and ``position_ids``.
 (``wte/embedding``, ``wpe/embedding``, ``h_<i>/{ln_1,attn/{c_attn,c_proj},
 ln_2,mlp/{c_fc,c_proj}}``, ``ln_f``); it is read as float32 whatever it is
 stored in.
+
+:func:`shape` is the family's shape rule: what ``benchmark/arithmetic.py``
+reckons parameters, FLOPs and bytes from (the keys are explained there).
 """
 
 import jax
@@ -71,3 +74,27 @@ def forward(params, cfg, input_ids, mask):
             x = x + dense(h, blk["mlp"]["c_proj"])
         x = layer_norm(x, p["ln_f"], eps)
         return x @ p["wte"]["embedding"].T
+
+
+def block_shape(d, ff):
+    """One block with a fused QKV and an output projection, a two-matrix
+    MLP ``ff`` wide and two LayerNorms, all with biases. Nothing is
+    routed: a token is multiplied with every matrix and a decode step
+    reads every weight; every head keeps its own keys and values."""
+    attn = d * 3 * d + 3 * d + d * d + d
+    mlp = d * ff + ff + ff * d + d
+    params = attn + mlp + 4 * d
+    return {"params": params, "matmul_params": 4 * d * d + 2 * d * ff,
+            "read_params": params, "attn_dim": d, "kv_values": 2 * d}
+
+
+def shape(cfg):
+    """Learned positions beside the token table; the head is the token
+    table again, so it holds nothing of its own and is multiplied with
+    (and read) all the same."""
+    d, V = cfg["n_embd"], cfg["vocab_size"]
+    return {
+        "embed_params": V * d + cfg["n_positions"] * d,
+        "layers": [block_shape(d, cfg.get("n_inner") or 4 * d)] * cfg["n_layer"],
+        "final": {"params": 2 * d, "matmul_params": d * V, "read_params": 2 * d + d * V},
+    }

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmark.reference.gpt2 import (
+    block_shape,
     dense,
     gelu_tanh,
     layer_norm,
@@ -87,3 +88,23 @@ def forward(params, cfg, input_ids, mask):
             x = x + a + m
         x = layer_norm(x, p["ln_f"], eps)
         return dense(x, p["lm_head"])
+
+
+def shape(cfg):
+    """The block holds what a GPT-2 block holds (the parallel residual
+    changes no size); no position table; the head is a matrix of its own."""
+    d, V = cfg["hidden_size"], cfg["vocab_size"]
+    return {
+        "embed_params": V * d,
+        "layers": [block_shape(d, cfg["intermediate_size"])] * cfg["num_hidden_layers"],
+        "final": {"params": 2 * d + d * V, "matmul_params": d * V, "read_params": 2 * d + d * V},
+    }
+
+
+def check_config(cfg):
+    """The program builds the MLP 4 x hidden wide and has no key for it."""
+    if cfg["intermediate_size"] != 4 * cfg["hidden_size"]:
+        raise ValueError(
+            "the program's NeoXConfig assumes intermediate_size = 4 x "
+            "hidden_size; this configuration publishes another"
+        )

@@ -297,16 +297,18 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
 
     mean_prompt = float(np.mean([c.prompt_len for c in done])) if done else 0.0
     metrics_now = server.metrics()
+    scalars = harness.registry_scalars()  # the registry was cleared at the window's start
     pct = lambda xs, q: loadgen.percentile(xs, q) * 1e3 if xs else None
     med_gap = float(np.median(gaps)) if gaps else 0.0
     record = {
         "kind": "serve", "cell": cell, "device": device, "spans": spans,
-        "tracer_stats": {}, "phases": 1, "setup_s": setup_s,
+        "tracer_stats": {}, "phases": 1, "window_s": window_s, "setup_s": setup_s,
         "compile_s_setup": mark[1], "xplane": xplane, "trace_clip": TraceSlice.SPAN,
         "chips": cell["chips"],
-        "shape": model_shape(cf), "flops": (0.0, 0.0),
+        "shape": model_shape(cell["family"], cf), "flops": (0.0, 0.0),
         "kv_cache_dtype": harness.kv_dtype_of(cf, t["seq_length"] + budget),
         "histograms": {k: v for k, v in metrics_now.items() if isinstance(v, dict)},
+        "counters": scalars["counters"], "gauges": scalars["gauges"],
         "engine_slot_util_pct": 100.0 * slot_util,
         "loadgen_lag_p95_ms": pct(lag, 95),
         "serve_ttft_p50_ms": pct(ttft, 50),
@@ -387,7 +389,7 @@ def reference_check(cell: Dict[str, Any], server, seed: int) -> bool:
     one = jax.devices()[0]
     params = jax.device_put(server.params, one)
     ref = checks.reference_logits(
-        cf["model_type"], cf, params["transformer"],
+        cell["family"], cf, params["transformer"],
         jax.device_put(jnp.asarray(full_ids), one), jax.device_put(jnp.asarray(full_mask), one),
     )
     tol = checks.tolerance_for(cf["run"]["dtype"], harness.kv_dtype_of(cf, Q + t["max_new_tokens"]))

@@ -18,6 +18,7 @@ recorded trace under ``benchmark/testdata`` can be asserted exactly.
 
 from __future__ import annotations
 
+import collections
 import re
 from typing import Dict, Iterable, List, Sequence, Tuple
 
@@ -146,7 +147,8 @@ def module_name(raw: str) -> str:
 
 def reduce_device(ops, modules, async_ops=()) -> Dict[str, object]:
     """One device's busy intervals (the core's own ops), per-module and
-    per-op-kind times, and its collectives' total and exposed time (ns): a
+    per-op-kind times (an op's own, less what nests inside it) and event
+    counts, and its collectives' total and exposed time (ns): a
     collective is exposed while no other operation of the core runs."""
     ops = [(s, e, op_kind(n)) for s, e, n in ops]
     busy = union((s, e) for s, e, _ in ops)
@@ -167,6 +169,7 @@ def reduce_device(ops, modules, async_ops=()) -> Dict[str, object]:
         "busy_ns": length(busy),
         "modules": per_module,
         "op_self_ns": own,
+        "op_count": dict(collections.Counter(n for _, _, n in ops)),
         "collective_ns": length(coll),
         "collective_exposed_ns": length(exposed),
     }
@@ -230,12 +233,16 @@ def reduce_trace(path: str, clip_span: str = None) -> Dict[str, object]:
     for name, rec in lead["modules"].items():
         modules[name] = {"s": rec["ns"] / 1e9, "count": rec["count"]}
     ops = sorted((kv for kv in lead["op_self_ns"].items() if kv[1] > 0), key=lambda kv: -kv[1])
+    # every kind, for the readers that take one kernel out of a module; the
+    # result line's breakdown keeps the ten longest
+    all_ops = {n: {"s": ns / 1e9, "count": lead["op_count"][n]} for n, ns in lead["op_self_ns"].items()}
     gaps = label_gaps(lead["busy"], (first, last), host)
     return {
         "devices": len(per),
         "busy_s": sum(p["busy_ns"] for p in per.values()) / len(per) / 1e9,
         "span_s": (last - first) / 1e9,
         "modules": modules,
+        "ops": all_ops,
         "device_ops": [[n, ns / 1e9] for n, ns in ops[:10]],
         "idle_gaps": [
             [n, ns / 1e9] for n, ns in sorted(gaps.items(), key=lambda kv: -kv[1])[:10]

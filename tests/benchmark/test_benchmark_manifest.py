@@ -1,15 +1,17 @@
 """``BENCHMARK.json`` against the contract's format rules, and against the
-data files the harness finds by name; and that a configuration, a cell and
-a per-layer metric can each be added as new files only."""
+data files the harness finds by name; and that a model family, a
+configuration, a cell and a per-layer metric can each be added as new
+files and manifest entries only (``files_only/`` holds the files)."""
 
 import json
 import os
 import re
 import shutil
 
+import numpy as np
 import pytest
 
-from benchmark import harness, readers
+from benchmark import arithmetic, checks, harness, readers
 
 REPO = harness.REPO
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -118,55 +120,156 @@ def test_every_metric_resolves_and_every_cell_reports_enough(manifest):
         assert others and layers, cell
 
 
-def test_layer_metric_files_match_the_manifest(manifest):
+def test_every_listed_metric_has_a_reader_file_and_no_file_is_an_orphan(manifest):
+    """Which cell reads which metric, its unit, layer and what it moves
+    stand in the manifest alone; a file under ``layer_metrics/`` holds the
+    reader and nothing the manifest says."""
     listed = {m["name"]: m for m in manifest["per_layer"]}
-    cells = [w["name"] for w in manifest["workloads"]]
+    on_disk = {f[:-len(".json")] for f in os.listdir(os.path.join(harness.HERE, "layer_metrics"))}
+    assert on_disk == set(listed)
+    for name in listed:
+        assert set(harness.load_json("layer_metrics", f"{name}.json")) == {"reader"}, name
     found = {}
-    for cell in cells:
-        for spec in harness.load_layer_metrics(cell):
-            found.setdefault(spec["name"], spec)
-            assert cell in listed[spec["name"]]["workloads"], (spec["name"], cell)
-    assert set(found) == set(listed)
-    for name, spec in found.items():
-        for key in ("unit", "better", "source", "layer", "moves"):
-            assert spec[key] == listed[name][key], (name, key)
-        assert spec["reader"]["kind"] in readers.READERS
-        assert set(listed[name]["workloads"]) == set(spec["workloads"]) & set(cells)
+    for w in manifest["workloads"]:
+        for spec in harness.load_layer_metrics(w["name"]):
+            assert w["name"] in listed[spec["name"]]["workloads"], (spec["name"], w["name"])
+            assert spec["reader"]["kind"] in readers.READERS
+            assert {k: v for k, v in spec.items() if k != "reader"} == listed[spec["name"]]
+            found.setdefault(spec["name"], []).append(w["name"])
+    assert {n: sorted(c) for n, c in found.items()} == {
+        n: sorted(m["workloads"]) for n, m in listed.items()}
     layers = {}
     for m in manifest["per_layer"]:  # one spelling per layer
         layers.setdefault(m["layer"].lower().strip(), set()).add(m["layer"])
     assert all(len(v) == 1 for v in layers.values())
 
 
-def test_a_config_a_cell_and_a_metric_are_added_as_files_only(tmp_path):
+def test_a_metric_that_lists_no_cells_is_refused_by_name(tmp_path, manifest):
     root = tmp_path / "benchmark"
-    for kind in ("configs", "workloads", "traffic", "layer_metrics"):
-        shutil.copytree(os.path.join(harness.HERE, kind), root / kind)
-    before = {p: p.read_bytes() for p in root.rglob("*.json")}
+    shutil.copytree(os.path.join(harness.HERE, "layer_metrics"), root / "layer_metrics")
+    entries = [dict(m) for m in manifest["per_layer"]]
+    del entries[-1]["workloads"]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(dict(manifest, per_layer=entries)))
+    with pytest.raises(KeyError, match=entries[-1]["name"].replace(".", r"\.")):
+        harness.load_layer_metrics("ppo-gpt2m-tldr", root=str(root))
 
-    cfg = json.loads((root / "configs" / "gpt2-medium.json").read_text())
-    cfg.update(name="gpt2-large", n_embd=1280, n_layer=36, n_head=20,
-               source="https://huggingface.co/openai-community/gpt2-large/blob/main/config.json")
-    (root / "configs" / "gpt2-large.json").write_text(json.dumps(cfg))
-    mix = json.loads((root / "traffic" / "chat.json").read_text())
-    mix.update(name="burst", arrivals={"process": "gamma", "cv": 3.0, "knee_per_s": 12.0, "load": 1.25})
-    (root / "traffic" / "burst.json").write_text(json.dumps(mix))
-    (root / "workloads" / "serve-gpt2l-burst.json").write_text(json.dumps({
-        "name": "serve-gpt2l-burst", "config": "gpt2-large", "traffic": "burst", "chips": 1,
-        "mesh": {"dp": 1, "fsdp": 1, "tp": 1}, "why": "a later PR's cell"}))
-    (root / "layer_metrics" / "serve_e2e_p50_ms.json").write_text(json.dumps({
-        "name": "serve_e2e_p50_ms", "unit": "ms", "better": "lower", "source": "program_counter",
-        "layer": "serving", "moves": "serve_itl_p95_ms", "workloads": ["serve-gpt2l-burst"],
-        "reader": {"kind": "histogram", "name": "serve/e2e_ms", "stat": "p50"}}))
 
-    cell = harness.load_cell("serve-gpt2l-burst", root=str(root))
-    assert cell["config_file"]["n_embd"] == 1280
-    assert cell["traffic_file"]["arrivals"]["process"] == "gamma"
-    assert harness.arch_of(cell["config_file"])["n_layer"] == 36
-    specs = harness.load_layer_metrics("serve-gpt2l-burst", root=str(root))
-    assert [s["name"] for s in specs] == ["serve_e2e_p50_ms"]
-    record = {"histograms": {"serve/e2e_ms": {"count": 3, "p50": 12.5}}}
-    assert readers.read_all(record, specs) == {"serve_e2e_p50_ms": {"value": 12.5, "unit": "ms"}}
+FILES_ONLY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "files_only")
+
+
+@pytest.fixture()
+def a_later_prs_tree(tmp_path, manifest):
+    """A copy of the benchmark with ``files_only/`` laid over it and its
+    manifest entries added, as a later PR would: ``(root, bytes of every
+    file that was there before)``."""
+    root = tmp_path / "benchmark"
+    for kind in ("configs", "workloads", "traffic", "layer_metrics", "reference"):
+        shutil.copytree(os.path.join(harness.HERE, kind), root / kind,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    shutil.copytree(os.path.join(FILES_ONLY, "benchmark"), root, dirs_exist_ok=True,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    with open(os.path.join(FILES_ONLY, "manifest_entries.json")) as f:
+        entries = json.load(f)
+    new = json.loads(json.dumps(manifest))
+    for group in ("configs", "workloads", "per_layer"):
+        new[group] += entries[group]
+    cell = entries["workloads"][0]["name"]
+    for m in new["end_to_end"] + new["per_layer"]:  # its name joins one list per metric it reads
+        if m["name"] in entries["joins"]:
+            m["workloads"].append(cell)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(new))
+    return root, before
+
+
+def test_a_family_a_config_a_cell_and_a_metric_are_added_as_files_only(a_later_prs_tree):
+    root, before = a_later_prs_tree
+    assert not any("toymoe" in p.name for p in before)
+    cell = harness.load_cell("ppo-toymoe-tldr", root=str(root))
+    cf = cell["config_file"]
+    assert cell["family"].__file__ == str(root / "reference" / "toymoe.py")
+    assert cell["traffic_file"]["new_tokens"] == 48  # a traffic mix that was there
+    assert harness.arch_of(cf)["num_experts_per_tok"] == 2
+
+    # the sizes by hand: d 32, 4 heads of 8, 2 KV heads, vocab 96, no biases.
+    # attention q 32x32 + k 32x16 + v 32x16 + o 32x32 = 3072; two RMSNorms 64
+    # dense block: gated MLP 3 x 32 x 64 = 6144            -> holds 9280
+    # routed block: router 32 x 8 = 256, 8 experts of 3 x 32 x 16 = 1536
+    #               -> holds 3072 + 256 + 12288 + 64 = 15680
+    # token table 3072, final norm 32, untied head 3072
+    s = arithmetic.model_shape(cell["family"], cf)
+    assert [l["params"] for l in s["layers"]] == [9280, 15680, 15680]
+    assert arithmetic.backbone_params(s) == 3072 + 9280 + 2 * 15680 + 32 + 3072 == cf["parameters"]
+    # a token is multiplied with 2 experts, not 8: 3072 + 256 + 2 x 1536 = 6400 a routed block
+    per_token = arithmetic.forward_flops(s, 1, 0, 1)
+    assert per_token == 2 * ((3072 + 6144) + 2 * 6400 + 3072) == 50176
+    assert per_token < 2 * ((3072 + 6144) + 2 * (3072 + 256 + 8 * 1536) + 3072) == 87040
+    # the cache is 2 KV heads x 8 x 2 = 32 values a position and block (2d would be 64)
+    assert [l["kv_values"] for l in s["layers"]] == [32, 32, 32]
+    # a decode step of 4 sequences at 9 cached positions, bf16 weights and cache: it
+    # reads the dense block whole, of a routed block all but the experts (3392) and
+    # 2 of them, the final norm and the head; and 3 x 32 values x 10 positions a row
+    weights = 9280 + 2 * (3392 + 2 * 1536) + (32 + 3072)
+    assert arithmetic.decode_step_bytes(s, 4, 9) == 2 * weights + 2 * (96 * 4 * 10) == 58304
+    assert arithmetic.decode_step_bytes(s, 4, 9, shards=4) == 2 * weights / 4 + 7680
+    # the weights read do not grow with the batch: 2 experts a routed block is the floor
+    assert arithmetic.decode_step_bytes(s, 64, 0, kv_bytes=0) == 2 * weights
+
+    # the cell reads a metric that was there and a new one of the new kind
+    specs = harness.load_layer_metrics("ppo-toymoe-tldr", root=str(root))
+    assert [m["name"] for m in specs] == ["collect_ms", "moe_tokens_routed"]
+    spans = harness.Spans()
+    spans.records += [("collect", 0.0, 0.25), ("collect", 1.0, 1.35), ("collect", 2.0, 2.25)]
+    record = {"spans": spans, "phases": 3, "counters": {"moe/tokens_routed": 1200.0}}
+    assert readers.read_all(record, specs) == {
+        "collect_ms": {"value": 250.0, "unit": "ms"},
+        "moe_tokens_routed": {"value": 400.0, "unit": "tokens"}}
     # a reader that finds nothing to read returns nothing
-    assert readers.read_all({"histograms": {}}, specs) == {}
+    assert readers.read_all({"spans": harness.Spans(), "phases": 3, "counters": {}}, specs) == {}
+    # the cells that were there read what they read
+    assert [m["name"] for m in harness.load_layer_metrics("ppo-gpt2m-tldr", root=str(root))] == [
+        m["name"] for m in harness.load_layer_metrics("ppo-gpt2m-tldr")]
     assert all(p.read_bytes() == b for p, b in before.items())  # nothing edited
+
+
+def test_the_added_familys_reference_runs_through_the_checks(a_later_prs_tree):
+    import jax
+
+    root, _ = a_later_prs_tree
+    cell = harness.load_cell("ppo-toymoe-tldr", root=str(root))
+    cf = cell["config_file"]
+    rng = np.random.default_rng(26)
+    d, V, kv, E = 32, 96, 16, 8
+    mat = lambda *shape: rng.normal(0, shape[-2] ** -0.5, shape).astype(np.float32)
+    attn = lambda: {"q": mat(d, d), "k": mat(d, kv), "v": mat(d, kv), "o": mat(d, d)}
+    ones = np.ones(d, np.float32)
+    params = {"wte": mat(V, d), "ln_f": ones, "lm_head": mat(d, V),
+              "h_0": {"ln_1": ones, "ln_2": ones, "attn": attn(),
+                      "mlp": {"gate": mat(d, 64), "up": mat(d, 64), "down": mat(64, d)}}}
+    for i in (1, 2):
+        params[f"h_{i}"] = {"ln_1": ones, "ln_2": ones, "attn": attn(), "moe": {
+            "router": mat(d, E), "gate": mat(E, d, 16), "up": mat(E, d, 16), "down": mat(E, 16, d)}}
+    held = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params))
+    assert held == cf["parameters"]  # the shape rule counts the tree the reference reads
+    ids = rng.integers(0, V, (2, 12))
+    mask = np.ones((2, 12), np.int32)
+    mask[1, :5] = 0  # left-padded
+    logits = np.asarray(checks.reference_logits(cell["family"], cf, params, ids, mask))
+    assert logits.shape == (2, 12, V) and np.isfinite(logits).all()
+    # causal: a later token changes no earlier real position's logits
+    ids2 = ids.copy()
+    ids2[:, -1] = (ids2[:, -1] + 1) % V
+    again = np.asarray(checks.reference_logits(cell["family"], cf, params, ids2, mask))
+    real = mask[:, :-1].astype(bool)
+    assert np.allclose(logits[:, :-1][real], again[:, :-1][real], atol=1e-5)
+    assert np.abs(logits[:, -1] - again[:, -1]).max() > 0.1
+
+
+def test_the_ppo_driver_takes_the_rollout_engine_from_the_traffic_file():
+    from benchmark import ppo_driver
+
+    cell = harness.load_cell("ppo-gpt2m-longgen")
+    assert "engine" not in cell["traffic_file"]
+    assert ppo_driver.build_config(cell, 1).train.rollout["engine"] == "fixed"
+    cell["traffic_file"]["engine"] = "continuous"
+    assert ppo_driver.build_config(cell, 1).train.rollout["engine"] == "continuous"

@@ -1,6 +1,7 @@
 """The plain float32 references against the program's own gpt2 and neox
 forward at a tiny size on the CPU, and the FLOP / byte / parameter
-arithmetic against the models' own parameter counts.
+arithmetic against the models' own parameter counts (the published sizes'
+numbers are pinned in ``test_benchmark_pinned.py``).
 
 Measured at this size over the five seeds below (PR 23, CPU; error of the
 program's logits against the reference, as a share of the reference
@@ -31,14 +32,13 @@ import numpy as np
 import pytest
 
 from benchmark import arithmetic, checks, harness
-from benchmark.reference import FORWARD
 
 GPT2 = {"model_type": "gpt2", "vocab_size": 96, "n_positions": 64, "n_embd": 32,
-        "n_layer": 2, "n_head": 4}
+        "n_layer": 2, "n_head": 4, "reference": "benchmark/reference/gpt2.py"}
 NEOX = {"model_type": "gpt_neox", "vocab_size": 96, "max_position_embeddings": 64,
         "hidden_size": 32, "num_hidden_layers": 2, "num_attention_heads": 4,
         "intermediate_size": 128, "rotary_pct": 0.25, "rotary_emb_base": 10000.0,
-        "use_parallel_residual": True}
+        "use_parallel_residual": True, "reference": "benchmark/reference/neox.py"}
 SEEDS = (0, 1, 2, 3, 2**31 + 4)
 TOL = {"float32": (1e-5, 1e-5), "bfloat16": (0.02, 0.15)}
 
@@ -57,7 +57,7 @@ def program_and_reference(cfg, dtype, seed, hidden_act=None):
     mask = (np.arange(T)[None, :] >= (T - lens)[:, None]).astype(np.int32)  # left-padded
     got = model.apply({"params": params}, jnp.asarray(ids), attention_mask=jnp.asarray(mask))
     ref_cfg = dict(cfg, **({"hidden_act": hidden_act} if hidden_act else {}))
-    ref = FORWARD[cfg["model_type"]](params, ref_cfg, jnp.asarray(ids), jnp.asarray(mask))
+    ref = harness.load_family(cfg).forward(params, ref_cfg, jnp.asarray(ids), jnp.asarray(mask))
     m = mask.astype(bool)
     return np.asarray(got["logits"])[m], np.asarray(ref)[m], params
 
@@ -84,44 +84,61 @@ def test_published_erf_gelu_differs_from_the_programs_tanh_by_under_1e3():
     assert 1e-5 < mx < 2e-3
 
 
+def shape_of(cfg):
+    return arithmetic.model_shape(harness.load_family(cfg), cfg)
+
+
 @pytest.mark.parametrize("cfg", [GPT2, NEOX], ids=["gpt2", "neox"])
 def test_parameter_count_matches_the_programs_own_tree(cfg):
     _, _, params = program_and_reference(cfg, "float32", 0)
     own = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params))
-    assert arithmetic.backbone_params(arithmetic.model_shape(cfg)) == own
-
-
-@pytest.mark.parametrize("name,published", [("gpt2-medium", 354823168), ("pythia-1.4b", 1414647808)])
-def test_published_parameter_counts(name, published):
-    cf = harness.load_json("configs", f"{name}.json")
-    assert arithmetic.backbone_params(arithmetic.model_shape(cf)) == published == cf["parameters"]
+    assert arithmetic.backbone_params(shape_of(cfg)) == own
 
 
 @pytest.mark.parametrize("cfg", [GPT2, NEOX], ids=["gpt2", "neox"])
 def test_flops_follow_the_matmul_parameters(cfg):
-    s = arithmetic.model_shape(cfg)
+    s = shape_of(cfg)
     # a forward of n tokens with no attention context and the head on each
-    # costs 2 FLOPs per matmul parameter and token
-    n = 10
-    matmul = s["L"] * arithmetic.matmul_params_per_layer(s) + s["d"] * s["V"]
+    # costs 2 FLOPs per matmul parameter and token: per block the four
+    # d x d of attention and the two d x 4d of the MLP, and the d x V head
+    n, d, V = 10, 32, 96
+    assert [l["matmul_params"] for l in s["layers"]] == [4 * d * d + 2 * d * 4 * d] * 2
+    matmul = 2 * (4 * d * d + 2 * d * 4 * d) + d * V
     assert arithmetic.forward_flops(s, n, 0, n) == 2 * matmul * n
+    # QK^T and AV: 4 x d for each pair of token and context position, a block
+    assert arithmetic.forward_flops(s, n, 7, n) - arithmetic.forward_flops(s, n, 0, n) == 2 * 4 * d * 7
     collect, train = arithmetic.ppo_phase_flops(s, Q=8, R=4, rollouts=2, ppo_epochs=3)
     fwd = arithmetic.forward_flops(s, 12, 12 * 13 // 2, 4)
     assert train == 3 * 2 * 3 * fwd  # epochs x rollouts x (forward + 2x backward)
     _, pruned = arithmetic.ppo_phase_flops(s, 8, 4, 2, 3, unfrozen=1)
     assert fwd * 6 < pruned < train  # the frozen trunk's backward is not required
+    top = arithmetic.forward_flops(s, 12, 12 * 13 // 2, 4, layers=1)
+    assert pruned == 3 * 2 * (fwd + 2 * top) and top < fwd
     assert collect > 2 * arithmetic.forward_flops(s, 12, 0, 0)
 
 
 @pytest.mark.parametrize("cfg", [GPT2, NEOX], ids=["gpt2", "neox"])
 def test_decode_step_bytes_count_weights_once_and_the_cache_by_dtype(cfg):
-    s = arithmetic.model_shape(cfg)
-    weights = s["L"] * arithmetic.block_params(s) + 2 * s["d"] + s["d"] * s["V"]
+    s = shape_of(cfg)
+    d, V = 32, 96
+    # the blocks (what the program's own tree holds in them), the final
+    # LayerNorm and the head matrix, tied or not; no embedding table
+    weights = sum(l["params"] for l in s["layers"]) + 2 * d + d * V
     assert arithmetic.decode_step_bytes(s, 0, 0) == 2 * weights
     one = arithmetic.decode_step_bytes(s, 1, 9, kv_bytes=2) - 2 * weights
-    assert one == 2 * s["L"] * 10 * s["d"] * 2
+    assert one == 2 * len(s["layers"]) * 10 * d * 2  # keys and values d wide each
     assert arithmetic.decode_step_bytes(s, 1, 9, kv_bytes=1) - 2 * weights == one / 2
     assert arithmetic.decode_step_bytes(s, 0, 0, shards=4) == 2 * weights / 4
+
+
+def test_a_shape_rule_that_lacks_a_count_is_refused_by_name():
+    import types
+
+    broken = types.SimpleNamespace(__name__="broken", shape=lambda cfg: {
+        "embed_params": 1, "final": {"params": 1, "matmul_params": 1, "read_params": 1},
+        "layers": [{"params": 1, "matmul_params": 1, "read_params": 1, "attn_dim": 1}]})
+    with pytest.raises(ValueError, match=r"layers\[0\]\['kv_values'\]"):
+        arithmetic.model_shape(broken, {})
 
 
 def test_peaks_table_refuses_an_unknown_device():

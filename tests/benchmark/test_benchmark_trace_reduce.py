@@ -16,6 +16,7 @@ so busy 90000 of 100000; collectives cover 45000-55000 and 72000-92000 =
 """
 
 import os
+import types
 
 import pytest
 
@@ -70,6 +71,10 @@ def test_device_zero_exactly():
         "all-reduce f32[8]": 10000, "copy bf16[4,4]": 20000,
         "all-gather-done bf16[8,4]": 2000, "fusion f32[2,2]": 8000,
     }
+    assert d0["op_count"] == {
+        "fusion f32[8,8]": 1, "while s32[]": 1, "fusion f32[4,8]": 2, "all-reduce f32[8]": 1,
+        "copy bf16[4,4]": 1, "all-gather-done bf16[8,4]": 1, "fusion f32[2,2]": 1,
+    }
     assert tr.label_gaps(d0["busy"], (0, 100000), host) == {"collect": 10000}
     assert tr.label_gaps(d0["busy"], (0, 100000), []) == {"unlabelled": 10000}
     d1 = tr.reduce_device(devices[1]["ops"], devices[1]["modules"])
@@ -85,6 +90,9 @@ def test_whole_trace_and_the_readers_built_on_it():
     assert out["modules"]["jit_train_step"] == {"s": pytest.approx(6e-5), "count": 1}
     assert out["device_ops"][0] == ["fusion f32[8,8]", pytest.approx(3e-5)]
     assert all(s > 0 for _, s in out["device_ops"]) and len(out["device_ops"]) == 6
+    # every kind's time and count is kept for the readers, a container's too
+    assert len(out["ops"]) == 7 and out["ops"]["while s32[]"] == {"s": 0.0, "count": 1}
+    assert out["ops"]["fusion f32[4,8]"] == {"s": pytest.approx(2e-5), "count": 2}
     assert out["idle_gaps"] == [["collect", pytest.approx(1e-5)]]
     assert out["collective_exposed_s"] / out["collective_s"] == pytest.approx(0.4, rel=1e-9)
 
@@ -96,6 +104,68 @@ def test_whole_trace_and_the_readers_built_on_it():
     assert readers.collective(record, {"what": "ms_per_phase"}) == pytest.approx(3e-5 * 1e3 / 2)
     assert readers.collective(record, {"what": "exposed_share"}) == pytest.approx(40.0)
     assert readers.module_roofline(record, dict(spec, module="^jit_absent")) is None
+
+
+def test_one_kernels_share_of_its_roofline_from_the_recorded_trace():
+    """``fusion f32[4,8]`` ran twice on device 0 (fusion.2 15000 ns, fusion.3
+    5000 ns): 2e-5 s. Say one execution needs 0.985e9 FLOPs and 4.095e6
+    bytes: two need 1.97e9 / 197e12 = 1e-5 s of the matrix unit and 8.19e6 /
+    819e9 = 1e-5 s of the memory, so the kernel is at 50% of either roof.
+    With 3x the bytes the memory bounds it: 3e-5 s needed, 150%."""
+    calls = []
+
+    def cost(record, ops):
+        calls.append(ops)
+        n = sum(op["count"] for op in ops.values())
+        return n * 0.985e9, n * 4.095e6 * record["bytes_scale"]
+
+    record = {"xplane": PB, "bytes_scale": 1, "cell": {"family": types.SimpleNamespace(kernel_cost=cost)},
+              "device": {"peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}}}
+    spec = {"kind": "op_roofline", "op": r"^fusion f32\[4,8\]$", "count": "kernel_cost"}
+    assert readers.READERS["op_roofline"](record, spec) == pytest.approx(50.0, rel=1e-9)
+    assert calls[-1] == {"fusion f32[4,8]": {"s": pytest.approx(2e-5), "count": 2}}
+    assert readers.op_roofline(dict(record, bytes_scale=3), spec) == pytest.approx(150.0, rel=1e-9)
+    # every fusion: 30000 + 20000 + 8000 ns, four executions
+    every = readers.op_roofline(record, dict(spec, op="^fusion "))
+    assert every == pytest.approx(100.0 * 4 * 0.985e9 / 197e12 / 5.8e-5, rel=1e-9)
+    # an operation the trace does not hold, or no trace: nothing
+    assert readers.op_roofline(record, dict(spec, op="^custom-call")) is None
+    assert readers.op_roofline(dict(record, xplane=None), spec) is None
+    # a count function the family lacks is looked for in arithmetic.py, and is an error there
+    with pytest.raises(AttributeError):
+        readers.op_roofline(record, dict(spec, count="no_such_function"))
+
+
+def test_a_counter_or_gauge_of_the_registry_by_name():
+    record = {"counters": {"moe/tokens_routed": 1200.0, "attention/decode_path{path=fused}": 48.0},
+              "gauges": {"engine/occupancy": 25.5}, "phases": 3, "window_s": 8.0}
+    read = readers.READERS["counter"]
+    assert read(record, {"name": "moe/tokens_routed"}) == 1200.0
+    assert read(record, {"name": "moe/tokens_routed", "per": "phase"}) == 400.0
+    assert read(record, {"name": "moe/tokens_routed", "per": "second"}) == 150.0
+    assert read(record, {"name": "attention/decode_path{path=fused}", "per": "phase"}) == 16.0
+    assert read(record, {"name": "engine/occupancy"}) == 25.5
+    # a name the program did not write, or a record without the registry: nothing
+    assert read(record, {"name": "moe/absent"}) is None
+    assert read({"phases": 1}, {"name": "moe/tokens_routed", "per": "second"}) is None
+    specs = [{"name": "routed", "unit": "tokens", "reader": {"kind": "counter", "name": "moe/absent"}}]
+    assert readers.read_all(record, specs) == {}
+
+
+def test_registry_scalars_leave_a_windows_own_increments():
+    from trlx_tpu import telemetry
+
+    with telemetry.scoped_metrics(telemetry.MetricsRegistry()) as registry:
+        registry.counter("moe/tokens_routed").inc(5)
+        registry.gauge("engine/occupancy").set(3.0)
+        before = harness.registry_scalars()
+        registry.counter("moe/tokens_routed").inc(7)
+        registry.counter("moe/dropped").inc(2)
+        registry.gauge("engine/occupancy").set(4.0)
+        registry.histogram("serve/e2e_ms").observe(1.0)
+        assert harness.registry_scalars(before) == {
+            "counters": {"moe/tokens_routed": 7.0, "moe/dropped": 2.0},
+            "gauges": {"engine/occupancy": 4.0}}
 
 
 def test_clip_cuts_events_to_a_window():
