@@ -833,13 +833,17 @@ class ContinuousBatchingEngine:
                 out_logprobs=out_logprobs,
                 out_values=out_values,
             )
+            # what the host polls: the done flags and, from a routed
+            # family, the step's routing statistics (device scalars in the
+            # same fetch: no wait of their own)
+            polled = {"done": done, **out.get("moe_stats", {})}
             if self.stream_taps:
                 # streaming decode: this step's emissions come home with
                 # the done flags so the host can route tokens the step
                 # they exist instead of at harvest (TTFT decouples from
                 # harvest-group completion)
-                return new_state, done, token, live
-            return new_state, done
+                return new_state, polled, token, live
+            return new_state, polled
 
         def refill(state: EngineState, slot_ids):
             """Harvest ``slot_ids``'s finished rollouts and free the
@@ -2107,14 +2111,15 @@ class ContinuousBatchingEngine:
         """Dispatch one decode step for the whole pool and run the
         amortized done-poll + streaming-tap bookkeeping."""
         if self.stream_taps:
-            self._state, done, token, live = self.decode_step_jit(
+            self._state, polled, token, live = self.decode_step_jit(
                 self._params, self._state
             )
         else:
-            self._state, done = self.decode_step_jit(
+            self._state, polled = self.decode_step_jit(
                 self._params, self._state
             )
             token = live = None
+        done = polled.pop("done")
         try:
             done.copy_to_host_async()
         except (AttributeError, RuntimeError):
@@ -2156,9 +2161,9 @@ class ContinuousBatchingEngine:
                 }
                 if emitted:
                     self.token_sink(emitted)
-        self._poll_done(done)
+        self._poll_done(done, polled)
 
-    def _poll_done(self, done) -> None:
+    def _poll_done(self, done, moe_stats=None) -> None:
         """Amortized done polling: the flags are sticky (a finished slot
         stays done until harvested), so fetching only every k-th step's
         flags is exact — k=1 reproduces the poll-every-step loop
@@ -2168,8 +2173,12 @@ class ContinuousBatchingEngine:
         if self._steps_since_poll < self.done_poll_interval:
             return
         self._steps_since_poll = 0
-        (done_host,) = self.fetch(done)
+        done_host, moe_host = self.fetch(done, moe_stats or {})
         self.stats.done_polls += 1
+        if moe_host:
+            from trlx_tpu.ops.moe import record_step_stats
+
+            record_step_stats(moe_host)
         # occupancy timeseries: one gauge sample per paid done-poll
         # (the registry's ring is bounded; one host call per poll)
         # — the Perfetto counter track rides these samples

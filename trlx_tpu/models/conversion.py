@@ -374,6 +374,69 @@ def load_neox_checkpoint(model_path: str, dtype: str = "float32"):
     return config, convert_neox_state_dict(model.state_dict(), config, dtype)
 
 
+def olmoe_config_from_hf(path_or_dict) -> "OlmoeConfig":
+    from trlx_tpu.models.olmoe import OlmoeConfig
+
+    if isinstance(path_or_dict, (str, os.PathLike)):
+        with open(os.path.join(path_or_dict, "config.json")) as f:
+            d = json.load(f)
+    elif hasattr(path_or_dict, "to_dict"):
+        d = path_or_dict.to_dict()
+    else:
+        d = dict(path_or_dict)
+    # the published keys by their published names; what the family does
+    # not build (grouped KV heads, clip_qkv, rope_scaling) it refuses
+    return OlmoeConfig.from_dict(d)
+
+
+def convert_olmoe_state_dict(
+    state_dict: Mapping[str, Any], config, dtype: str = "float32"
+) -> Dict[str, Any]:
+    """HF ``OlmoeForCausalLM`` -> ``OlmoeModel`` params. Every matrix is a
+    bias-free torch ``nn.Linear`` (kernels transpose); the per-expert
+    ``mlp.experts.M.{gate,up,down}_proj`` stack on a leading ``[E]`` axis."""
+    sd = {k.removeprefix("model."): v for k, v in state_dict.items()}
+    cast = lambda t: jnp.asarray(_np(t), dtype=jnp.dtype(dtype))
+    castT = lambda t: jnp.asarray(_np(t).T.copy(), dtype=jnp.dtype(dtype))
+
+    params: Dict[str, Any] = {
+        "wte": {"embedding": cast(sd["embed_tokens.weight"])},
+        "ln_f": {"scale": cast(sd["norm.weight"])},
+        "lm_head": {"kernel": castT(sd["lm_head.weight"])},
+    }
+    for i in range(config.num_hidden_layers):
+        p = f"layers.{i}."
+        a = p + "self_attn."
+        experts = lambda name: jnp.stack([
+            castT(sd[f"{p}mlp.experts.{m}.{name}.weight"]) for m in range(config.num_experts)
+        ])
+        params[f"h_{i}"] = {
+            "ln_1": {"scale": cast(sd[p + "input_layernorm.weight"])},
+            "ln_2": {"scale": cast(sd[p + "post_attention_layernorm.weight"])},
+            "attn": {
+                **{n: {"kernel": castT(sd[f"{a}{n}.weight"])}
+                   for n in ("q_proj", "k_proj", "v_proj", "o_proj")},
+                "q_norm": {"scale": cast(sd[a + "q_norm.weight"])},
+                "k_norm": {"scale": cast(sd[a + "k_norm.weight"])},
+            },
+            "mlp": {
+                "router": castT(sd[p + "mlp.gate.weight"]),
+                "w_gate": experts("gate_proj"),
+                "w_up": experts("up_proj"),
+                "w_down": experts("down_proj"),
+            },
+        }
+    return params
+
+
+def load_olmoe_checkpoint(model_path: str, dtype: str = "float32"):
+    from transformers import AutoModelForCausalLM
+
+    model = AutoModelForCausalLM.from_pretrained(model_path, local_files_only=True)
+    config = olmoe_config_from_hf(model.config)
+    return config, convert_olmoe_state_dict(model.state_dict(), config, dtype)
+
+
 def gpt_neo_config_from_hf(path_or_dict) -> "GPTNeoConfig":
     from trlx_tpu.models.gpt_neo import GPTNeoConfig, expand_attention_types
 

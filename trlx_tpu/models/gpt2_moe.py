@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from trlx_tpu.models.gpt2 import Attention, GPT2Model, PARTITION_RULES
+# one copy for both MoE families; re-exported for this module's importers
+from trlx_tpu.ops.moe import apply_router_penalty, moe_loss_summary  # noqa: F401
 
 _EP_MESH: Optional[Mesh] = None
 
@@ -243,51 +245,6 @@ GPT2_MOE_PARTITION_RULES = list(PARTITION_RULES) + [
     (r"mlp/wo", P("ep", None, None)),
     (r"mlp/bo", P("ep", None)),
 ]
-
-
-def moe_loss_summary(collection) -> Dict[str, jax.Array]:
-    """Aggregate a ``moe_losses`` sow collection (one entry per MoE block)
-    into scalars: mean ``aux_loss`` / ``router_z`` across layers, max
-    ``max_load`` across layers. Used by trainers to add the balance
-    penalty to the training loss and to surface routing health in stats."""
-    buckets: Dict[str, list] = {"aux_loss": [], "router_z": [], "max_load": []}
-
-    def walk(node):
-        if isinstance(node, dict):
-            for k, v in node.items():
-                if k in buckets:
-                    buckets[k].extend(v)  # sow stores a tuple per call
-                else:
-                    walk(v)
-
-    walk(collection)
-    if not buckets["aux_loss"]:
-        raise ValueError("no MoE losses were sown — is this an MoE model?")
-    return {
-        "aux_loss": jnp.mean(jnp.stack(buckets["aux_loss"])),
-        "router_z": jnp.mean(jnp.stack(buckets["router_z"])),
-        "max_load": jnp.max(jnp.stack(buckets["max_load"])),
-    }
-
-
-def apply_router_penalty(loss, stats, moe: Dict[str, jax.Array], cfg):
-    """Add the router load-balancing penalty to a training loss and surface
-    the routing health in the step stats — shared by every trainer that
-    trains an MoE family (PPO and ILQL use identical objectives here)."""
-    penalty = (
-        cfg.router_aux_coef * moe["aux_loss"]
-        + cfg.router_z_coef * moe["router_z"]
-    )
-    stats = dict(
-        stats,
-        **{
-            "losses/total_loss": stats["losses/total_loss"] + penalty,
-            "losses/moe_aux": moe["aux_loss"],
-            "losses/router_z": moe["router_z"],
-            "moe/max_load": moe["max_load"],
-        },
-    )
-    return loss + penalty, stats
 
 
 def _no_checkpoint(path: str, dtype: str = "float32"):

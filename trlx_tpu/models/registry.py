@@ -23,7 +23,8 @@ class ModelFamily:
     init_cache: Callable  # (config, batch, capacity) -> cache
     load_checkpoint: Callable  # (path, dtype) -> (config, params)
     is_seq2seq: bool = False
-    # has switch-MoE experts an `ep` mesh axis can shard (models/gpt2_moe.py)
+    # has experts an `ep` mesh axis can shard (models/gpt2_moe.py,
+    # models/olmoe.py); such a family sows router losses into `moe_losses`
     supports_ep: bool = False
 
 
@@ -141,4 +142,17 @@ def _register_builtins() -> None:
             init_cache, _no_checkpoint, supports_ep=True,
         ),
         "gpt2-moe",
+    )
+    from trlx_tpu.models.olmoe import (
+        OLMOE_PARTITION_RULES,
+        OlmoeConfig,
+        OlmoeModel,
+        init_olmoe_cache,
+    )
+
+    register_model_family(
+        ModelFamily(
+            "olmoe", OlmoeConfig, OlmoeModel, OLMOE_PARTITION_RULES,
+            init_olmoe_cache, conversion.load_olmoe_checkpoint, supports_ep=True,
+        )
     )

@@ -1,0 +1,333 @@
+"""A dropless top-k expert layer: float32 routing, token copies sorted by
+expert, a grouped matrix multiplication over the sorted rows, a weighted
+float32 combine. The work follows the routed rows: ``k / E`` of computing
+every expert on every token, at every token count, forward and backward.
+
+The layer (OLMoE, Muennighoff et al. 2024, section 2; no token is ever
+dropped and no capacity exists):
+
+    p = softmax_f32(h W_r)                       over E experts
+    (p_1..p_k, e_1..e_k) = top_k(p)              p_j kept as they are unless
+                                                 ``norm_topk`` divides them
+                                                 by their sum
+    y = sum_j p_j * W_down[e_j]( silu(W_gate[e_j] h) * W_up[e_j] h )
+
+How it is computed, all shapes static:
+
+1. ``moe_router``: the logits and the softmax in float32, ``lax.top_k``.
+2. ``moe_dispatch``: the ``N * k`` token copies are ordered by expert with
+   one stable ``argsort``; ``group_sizes[e]`` counts the copies of expert
+   ``e`` (they sum to ``N * k``: nothing is dropped). Rows are gathered
+   into that order.
+3. ``moe_experts``: the **grouped matrix multiplication**
+   (:func:`grouped_matmul`, ``jax.lax.ragged_dot``): rows
+   ``[offset_e, offset_e + group_sizes[e])`` are multiplied with expert
+   ``e``'s matrix. On the TPU it compiles to one Mosaic kernel
+   (``ragged-dot`` in a trace) that visits only the (row tile, expert)
+   pairs that hold rows, so a prefill of 32768 rows is compute-bound on
+   ``rows x 3 D F`` and a decode step of 256 rows reads each *touched*
+   expert once. Gate and up are two calls on the same sorted rows (the
+   parameter tree keeps ``w_gate`` and ``w_up`` apart as the checkpoint
+   does; joining them per call would copy 537 MB a layer), down is the
+   third. On the CPU the same primitive lowers to masked dense products
+   (tests only).
+4. ``moe_combine``: rows return to token order through the inverse
+   permutation and the ``k`` copies of a token are summed in float32 under
+   their routing weights.
+
+Both permutations are gathers forward *and* backward (a permutation's
+transpose is its inverse), so the backward pass scatters nothing.
+
+On an ``ep`` mesh (:func:`expert_layer` with ``mesh``) the experts' ``[E]``
+axis is sharded: inside a ``shard_map`` every rank holds the tokens of its
+data shard (they are replicated over ``ep`` outside the layer, as the rest
+of the block computes them), orders them so that *its own* experts' rows
+come first, runs the same grouped multiplication over those rows alone,
+and a ``psum`` over ``ep`` adds the ranks' partial sums - the gather of
+tokens and the scatter of the sum folded into the one all-reduce the
+replicated attention that follows needs anyway. Still dropless.
+
+Training forwards sow ``aux_loss`` (load balancing over the top-k
+assignments, ``E * sum_e f_e P_e`` with ``f_e`` the copies routed to ``e``
+per token and ``P_e`` the mean router probability; uniform routing gives
+``k``), ``router_z`` and ``max_load`` into the ``moe_losses`` collection;
+:func:`moe_loss_summary` and :func:`apply_router_penalty` are the one copy
+both MoE families (``models/olmoe.py``, ``models/gpt2_moe.py``) and both
+trainers use.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
+
+
+class Routing(NamedTuple):
+    logits: jax.Array  # [N, E] float32
+    probs: jax.Array  # [N, E] float32
+    weights: jax.Array  # [N, k] float32, the combine weights
+    experts: jax.Array  # [N, k] int32
+
+
+def route(h: jax.Array, router_w: jax.Array, k: int, norm_topk: bool = False) -> Routing:
+    """Float32 routing of ``h`` [N, D] over ``router_w`` [D, E]."""
+    logits = jnp.dot(
+        h.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    if norm_topk:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return Routing(logits, probs, weights, experts.astype(jnp.int32))
+
+
+# -- permutations whose backward pass is a gather too ---------------------- #
+
+
+@jax.custom_vjp
+def _take_copies(x, token_of, inverse):
+    """``x[token_of]``: row ``i`` of the result is the token that sorted copy
+    ``i`` belongs to. ``inverse`` (sorted position of copy ``n * k + j``)
+    is only read by the backward pass."""
+    return x[token_of]
+
+
+def _take_copies_fwd(x, token_of, inverse):
+    return x[token_of], (inverse, x.shape[0])
+
+
+def _take_copies_bwd(res, g):
+    inverse, n = res
+    return g[inverse].reshape(n, -1, g.shape[-1]).sum(axis=1).astype(g.dtype), None, None
+
+
+_take_copies.defvjp(_take_copies_fwd, _take_copies_bwd)
+
+
+@jax.custom_vjp
+def _permute(y, perm, inverse):
+    """``y[perm]`` for a permutation ``perm`` whose inverse is ``inverse``."""
+    return y[perm]
+
+
+def _permute_fwd(y, perm, inverse):
+    return y[perm], inverse
+
+
+def _permute_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def sort_by_expert(experts: jax.Array, num_experts: int, first_expert=0):
+    """Order the ``N * k`` copies by expert. Returns ``(order, inverse,
+    group_sizes)``: ``order[i]`` is the copy (``n * k + j``) at sorted row
+    ``i``, ``inverse`` its inverse permutation, ``group_sizes[e]`` the rows
+    of expert ``(first_expert + e) % E`` - with ``first_expert`` a rank's
+    own experts come first (the ``ep`` path). Σ ``group_sizes`` = N·k."""
+    flat = (experts.reshape(-1) - first_expert) % num_experts
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=jnp.int32)
+    )
+    group_sizes = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1)
+    return order, inverse, group_sizes
+
+
+def grouped_matmul(rows: jax.Array, weights: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """``rows`` [M, K] sorted by group, ``weights`` [G, K, N]: rows of group
+    ``g`` times ``weights[g]``; rows past Σ ``group_sizes`` are unspecified
+    (callers mask them). One kernel on the TPU, forward and each of its two
+    transposes."""
+    return jax.lax.ragged_dot(
+        rows, weights, group_sizes, preferred_element_type=rows.dtype
+    )
+
+
+def _experts_on_sorted(rows, w_gate, w_up, w_down, group_sizes):
+    with jax.named_scope("moe_experts"):
+        gate = grouped_matmul(rows, w_gate, group_sizes)
+        up = grouped_matmul(rows, w_up, group_sizes)
+        return grouped_matmul(jax.nn.silu(gate) * up, w_down, group_sizes)
+
+
+def _apply_routed(h, routing: Routing, w_gate, w_up, w_down, dtype,
+                  num_experts: int, first_expert=0):
+    """The expert layer after routing, over the experts ``w_*`` hold
+    (all ``E`` of them, or a rank's ``E / ep`` starting at
+    ``first_expert``): [N, D] in ``h``'s token order, float32."""
+    N, k = routing.experts.shape
+    local = w_gate.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        order, inverse, sizes = sort_by_expert(routing.experts, num_experts, first_expert)
+        rows = _take_copies(h.astype(dtype), order // k, inverse)
+    out = _experts_on_sorted(
+        rows, w_gate.astype(dtype), w_up.astype(dtype), w_down.astype(dtype), sizes[:local]
+    )
+    with jax.named_scope("moe_combine"):
+        if local < num_experts:
+            # rows of other ranks' experts were multiplied with nothing
+            mine = jnp.arange(N * k) < jnp.sum(sizes[:local])
+            out = jnp.where(mine[:, None], out, jnp.zeros((), out.dtype))
+        back = _permute(out, inverse, order).reshape(N, k, -1)
+        return jnp.einsum(
+            "nkd,nk->nd", back.astype(jnp.float32), routing.weights,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+
+
+def routing_stats(routing: Routing, num_experts: int) -> Dict[str, jax.Array]:
+    """What a step's routing looked like, as device scalars that ride in
+    outputs the caller fetches anyway: ``experts_touched`` (distinct
+    experts with at least one row), ``max_load`` (the busiest expert's
+    share of the rows; ``1 / E`` is perfect balance) and ``rows_routed``
+    (``N * k``)."""
+    N, k = routing.experts.shape
+    counts = jnp.zeros((num_experts,), jnp.int32).at[routing.experts.reshape(-1)].add(1)
+    return {
+        "experts_touched": jnp.sum(counts > 0).astype(jnp.float32),
+        "max_load": jnp.max(counts).astype(jnp.float32) / (N * k),
+        "rows_routed": jnp.float32(N * k),
+    }
+
+
+def record_step_stats(stats: Dict[str, Any]) -> None:
+    """A fetched :func:`routing_stats` (averaged over a call's blocks) into
+    the metrics registry: counter ``moe/rows_routed``; gauges
+    ``moe/experts_touched`` (the mean over the steps recorded since the
+    registry was cleared) and ``moe/max_load`` (the last step's)."""
+    from trlx_tpu import telemetry
+
+    registry = telemetry.get_metrics()
+    registry.counter("moe/rows_routed").inc(float(stats["rows_routed"]))
+    steps = registry.counter("moe/steps_recorded")
+    total = registry.counter("moe/experts_touched_sum")
+    steps.inc()
+    total.inc(float(stats["experts_touched"]))
+    if steps.value:  # 0 while the registry is disabled
+        registry.gauge("moe/experts_touched").set(total.value / steps.value)
+    registry.gauge("moe/max_load").set(float(stats["max_load"]))
+
+
+def balance_losses(routing: Routing, num_experts: int, token_mask=None) -> Dict[str, jax.Array]:
+    """``aux_loss``, ``router_z`` and ``max_load`` of one layer's routing
+    (module docstring) over the tokens ``token_mask`` [N] marks (padding
+    is routed like any row but balances nothing). ``f_e`` carries no
+    gradient, ``P_e`` does."""
+    N, k = routing.experts.shape
+    m = jnp.ones((N,), jnp.float32) if token_mask is None else token_mask.reshape(N).astype(jnp.float32)
+    n = jnp.maximum(jnp.sum(m), 1.0)
+    counts = jnp.zeros((num_experts,), jnp.float32).at[routing.experts.reshape(-1)].add(
+        jnp.repeat(m, k)
+    )
+    mean_prob = jnp.sum(routing.probs * m[:, None], axis=0) / n
+    return {
+        "aux_loss": num_experts * jnp.sum(jax.lax.stop_gradient(counts) / n * mean_prob),
+        "router_z": jnp.sum(m * jax.nn.logsumexp(routing.logits, axis=-1) ** 2) / n,
+        "max_load": jnp.max(counts) / (n * k),
+    }
+
+
+def expert_layer(h: jax.Array, router_w, w_gate, w_up, w_down, *, k: int,
+                 norm_topk: bool = False, dtype=jnp.bfloat16,
+                 mesh: Optional[Mesh] = None, batch_axes=("dp", "fsdp")):
+    """``h`` [B, T, D] -> ``(y [B, T, D] in dtype, routing)``; ``routing``
+    is over all ``B * T`` tokens, for the losses and the statistics.
+
+    ``mesh``: an ``ep`` mesh whose ``ep`` axis shards the experts' leading
+    axis; tokens are split over ``batch_axes`` where their count allows
+    and replicated otherwise (a decode step of a few rows)."""
+    D, E = h.shape[-1], router_w.shape[-1]
+
+    def run(h_loc, router_w, w_gate, w_up, w_down, first_expert=0):
+        flat = h_loc.reshape(-1, D)
+        with jax.named_scope("moe_router"):
+            routing = route(flat, router_w, k, norm_topk)
+        return _apply_routed(flat, routing, w_gate, w_up, w_down, dtype, E, first_expert), routing
+
+    if mesh is None or dict(mesh.shape).get("ep", 1) == 1:
+        y, routing = run(h, router_w, w_gate, w_up, w_down)
+        return y.reshape(h.shape).astype(dtype), routing
+
+    ep = mesh.shape["ep"]
+    if E % ep:
+        raise ValueError(f"{E} experts cannot be split over ep={ep}")
+    axes = tuple(a for a in batch_axes if a in mesh.axis_names)
+    shards = 1
+    for a in axes:
+        shards *= mesh.shape[a]
+    tok = P(axes) if axes and h.shape[0] % shards == 0 else P()
+
+    def local(h_loc, *weights):
+        part, routing = run(h_loc, *weights, first_expert=jax.lax.axis_index("ep") * (E // ep))
+        return jax.lax.psum(part, "ep").reshape(h_loc.shape).astype(dtype), routing
+
+    experts = P("ep")
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(tok, P(), experts, experts, experts),
+        out_specs=(tok, Routing(tok, tok, tok, tok)),
+        check_vma=False,
+    )(h, router_w, w_gate, w_up, w_down)
+
+
+# -- what the trainers read ------------------------------------------------- #
+
+
+def moe_loss_summary(collection) -> Dict[str, jax.Array]:
+    """Aggregate a ``moe_losses`` sow collection (one entry per MoE block)
+    into scalars: mean ``aux_loss`` / ``router_z`` across layers, max
+    ``max_load`` across layers. Used by trainers to add the balance
+    penalty to the training loss and to surface routing health in stats."""
+    buckets: Dict[str, list] = {"aux_loss": [], "router_z": [], "max_load": []}
+
+    def walk(node):
+        if isinstance(node, dict):
+            for key, v in node.items():
+                if key in buckets:
+                    buckets[key].extend(v)  # sow stores a tuple per call
+                else:
+                    walk(v)
+
+    walk(collection)
+    if not buckets["aux_loss"]:
+        raise ValueError("no MoE losses were sown — is this an MoE model?")
+    return {
+        "aux_loss": jnp.mean(jnp.stack(buckets["aux_loss"])),
+        "router_z": jnp.mean(jnp.stack(buckets["router_z"])),
+        "max_load": jnp.max(jnp.stack(buckets["max_load"])),
+    }
+
+
+def router_coefficients(cfg: Any):
+    """(aux, z) coefficients of a family's config: gpt2_moe names them
+    ``router_aux_coef`` / ``router_z_coef``, OLMoE publishes
+    ``router_aux_loss_coef`` and has no z-loss (reported, coefficient 0)."""
+    aux = getattr(cfg, "router_aux_coef", None)
+    if aux is None:
+        aux = getattr(cfg, "router_aux_loss_coef", 0.0)
+    return aux, getattr(cfg, "router_z_coef", 0.0)
+
+
+def apply_router_penalty(loss, stats, moe: Dict[str, jax.Array], cfg):
+    """Add the router load-balancing penalty to a training loss and surface
+    the routing health in the step stats — shared by every trainer that
+    trains an MoE family (PPO and ILQL use identical objectives here)."""
+    aux_coef, z_coef = router_coefficients(cfg)
+    penalty = aux_coef * moe["aux_loss"] + z_coef * moe["router_z"]
+    stats = dict(
+        stats,
+        **{
+            "losses/total_loss": stats["losses/total_loss"] + penalty,
+            "losses/moe_aux": moe["aux_loss"],
+            "losses/router_z": moe["router_z"],
+            "moe/max_load": moe["max_load"],
+        },
+    )
+    return loss + penalty, stats

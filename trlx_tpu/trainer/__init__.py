@@ -508,8 +508,8 @@ class BaseRLTrainer(ABC):
     def setup_ep_axis(self, mesh, family) -> None:
         """Validate + install expert parallelism for this trainer's model.
 
-        An ``ep`` mesh axis is only meaningful for families with switch-MoE
-        experts (``ModelFamily.supports_ep``); for any other family the
+        An ``ep`` mesh axis is only meaningful for families with experts
+        (``ModelFamily.supports_ep``: gpt2_moe, olmoe); for any other family the
         axis would silently replicate all compute, so reject it loudly. For
         MoE families, install the mesh as the module-level ep context
         (`models/gpt2_moe.py::set_ep_mesh`) — call this *after* parameter

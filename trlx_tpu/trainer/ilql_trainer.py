@@ -311,7 +311,7 @@ class ILQLTrainer(BaseRLTrainer):
                 )
                 if moe_family:
                     # same Switch load-balancing objective as the PPO path
-                    from trlx_tpu.models.gpt2_moe import (
+                    from trlx_tpu.ops.moe import (
                         apply_router_penalty, moe_loss_summary,
                     )
 

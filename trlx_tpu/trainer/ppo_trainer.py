@@ -710,7 +710,7 @@ class PPOTrainer(BaseRLTrainer):
                 remat=self.pp_remat,
             )
         elif self._moe_family:
-            from trlx_tpu.models.gpt2_moe import moe_loss_summary
+            from trlx_tpu.ops.moe import moe_loss_summary
 
             (logits, values), state = self.model.apply(
                 {"params": params}, full_ids, full_mask, Q,
@@ -1004,7 +1004,7 @@ class PPOTrainer(BaseRLTrainer):
                     # Switch load-balancing: without this, top-1 routing
                     # collapses onto few experts once capacity drops are
                     # real (anything below capacity_factor >= n_experts)
-                    from trlx_tpu.models.gpt2_moe import apply_router_penalty
+                    from trlx_tpu.ops.moe import apply_router_penalty
 
                     loss, stats = apply_router_penalty(
                         loss, stats, moe, self.model_config
