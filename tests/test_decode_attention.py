@@ -1,7 +1,8 @@
 """The fixed sampler's decode read (``ops/attention.py::decode_attention`` on
-a cache in ``decode_kv_layout``) against the generic read — ``write_cache``
-and ``dot_product_attention`` on the dequantised updated buffer — and the
-dispatch between the two, counted at trace time."""
+a cache in ``decode_kv_layout``) and the paged engine's (the pools read in
+the order they are stored) against the generic read — ``write_cache`` and
+``dot_product_attention`` on the dequantised updated buffer — and the
+dispatch between the three, counted at trace time."""
 
 import numpy as np
 import pytest
@@ -28,7 +29,7 @@ def _counts():
     reg = get_metrics()
     return {
         path: reg.counter("attention/decode_path{path=%s}" % path).value
-        for path in ("fused", "generic")
+        for path in ("fused", "paged", "generic")
     }
 
 
@@ -150,20 +151,24 @@ def test_decode_kv_layout_shapes():
 
 def _bypass_case(kind):
     """(q_len, cache, cache_index, bias) for one call the generic read
-    keeps: the paged engine's cache, a learned per-head bias, two
-    positions a call."""
+    keeps: the paged engine's int8 pool or a window of positions into its
+    pool, a learned per-head bias, two positions a call."""
     import jax.numpy as jnp
 
     from trlx_tpu.models.gpt2 import kv_buffers
     from trlx_tpu.ops.attention import causal_bias
 
     C, Dh = 16, 8
-    if kind == "paged":
+    if kind.startswith("paged"):
         from trlx_tpu.inference.kv_cache import init_paged_cache
 
-        cache = init_paged_cache(1, B, C, H, Dh, jnp.float32, block_size=4)[0]
+        # an int8 pool is read dequantised, a window of two positions (the
+        # verify step) through the logical view: both stay generic
+        kv = "int8" if kind == "paged_int8" else "bfloat16"
+        q_len = 2 if kind == "paged_q_len_2" else 1
+        cache = init_paged_cache(1, B, C, H, Dh, jnp.float32, kv, block_size=4)[0]
         at = jnp.full((B,), 5, jnp.int32)
-        return 1, cache, at, causal_bias(1, C, offset=at)
+        return q_len, cache, at, causal_bias(q_len, C, offset=at)
     cache = kv_buffers(1, B, C, H, Dh, jnp.float32, "bfloat16")[0]
     if kind == "per_head_bias":
         bias = causal_bias(1, C, offset=5) + jnp.arange(H, dtype=jnp.float32)[
@@ -172,7 +177,9 @@ def _bypass_case(kind):
     return 2, cache, 5, causal_bias(2, C, offset=5)
 
 
-@pytest.mark.parametrize("kind", ["paged", "per_head_bias", "q_len_2"])
+@pytest.mark.parametrize(
+    "kind", ["paged_int8", "paged_q_len_2", "per_head_bias", "q_len_2"]
+)
 def test_bypass_takes_the_generic_read(kind):
     import jax.numpy as jnp
 
@@ -192,6 +199,7 @@ def test_bypass_takes_the_generic_read(kind):
     after = _counts()
     assert after["generic"] == before["generic"] + 1
     assert after["fused"] == before["fused"]
+    assert after["paged"] == before["paged"]
     assert out.shape == q.shape and new_kv["k"].shape == cache["k"].shape
 
 
@@ -256,3 +264,152 @@ def test_sampler_traces_the_fused_read_and_sp_keeps_generic():
     after = _counts()
     assert after["fused"] == mid["fused"]
     assert after["generic"] - mid["generic"] == 2 * cfg.n_layer
+    # no sampler program reads a paged pool (the PPO cells run this one)
+    assert after["paged"] == before["paged"]
+
+
+# ----------------- the paged engine's read, as stored ------------------ #
+
+
+def _paged_case(cache_dtype, rotated, dropped, vector_index, dtype="float32"):
+    """One layer's paged pool (``C`` 24 in blocks of 4) filled to
+    position 10 through ``write_cache``, its tables rotated a slot or left
+    identity, and one more position a slot: at a per-slot depth, or at one
+    scalar depth; ``dropped`` parks slot 1 at the discard sentinel."""
+    import jax.numpy as jnp
+
+    from trlx_tpu.inference.kv_cache import init_paged_cache, rotate_block_table
+    from trlx_tpu.models.gpt2 import write_cache
+    from trlx_tpu.ops.attention import causal_bias, combine_biases, padding_bias
+
+    C, Dh, filled = 24, 8, 10
+    rng = np.random.default_rng(7)
+    cache = init_paged_cache(1, B, C, H, Dh, dtype, cache_dtype, block_size=4)[0]
+    if rotated:
+        tables = cache["block_tables"]
+        for b in range(B):
+            tables = tables.at[b].set(rotate_block_table(tables[b], 2 * b + 1))
+        cache = dict(cache, block_tables=tables)
+    k, v = (
+        jnp.asarray(rng.standard_normal((B, filled, H, Dh)), dtype) for _ in range(2)
+    )
+    cache = write_cache(cache, k, v, jnp.zeros((B,), jnp.int32), jnp.dtype(dtype))[2]
+    if vector_index:
+        depth = np.asarray([filled, filled - 3, filled - 1])
+        index = jnp.asarray(np.where(dropped, [filled, C, filled - 1], depth), jnp.int32)
+    else:
+        depth = np.full((B,), filled)
+        index = jnp.asarray(C if dropped else filled, jnp.int32)
+    valid = np.arange(C)[None, :] >= np.arange(B)[:, None]  # left padding a row
+    bias = combine_biases(
+        causal_bias(1, C, offset=jnp.asarray(depth, jnp.int32)),
+        padding_bias(valid.astype(np.int32)),
+    )
+    q, k_new, v_new = (
+        jnp.asarray(rng.standard_normal((B, 1, H, Dh)), dtype) for _ in range(3)
+    )
+    return cache, q, k_new, v_new, index, bias
+
+
+@pytest.mark.parametrize("vector_index", [True, False], ids=["index_B", "index_scalar"])
+@pytest.mark.parametrize("dropped", [False, True], ids=["all_live", "some_dropped"])
+@pytest.mark.parametrize("rotated", [False, True], ids=["identity", "rotated"])
+@pytest.mark.parametrize("cache_dtype", ["bfloat16", "int8"])
+def test_paged_one_token_read_matches_the_logical_view(
+    cache_dtype, rotated, dropped, vector_index
+):
+    """One new position a slot into a paged pool: a floating pool is read
+    as stored (``path=paged``), an int8 pool through the dequantised logical
+    view (``generic``). Either way the stored pools are, bit for bit, what
+    the logical-view form stores; the pools returned as stored, gathered
+    into logical order, ARE the logical view; and the attention output
+    agrees with the logical-view read to float32 rounding (the same terms,
+    summed in the slot's physical order)."""
+    import jax.numpy as jnp
+
+    from trlx_tpu.inference.kv_cache import (
+        _gather_logical,
+        logical_view_index,
+        paged_write_read,
+        reads_as_stored,
+    )
+    from trlx_tpu.ops.attention import decode_attention, dot_product_attention
+
+    cache, q, k_new, v_new, index, bias = _paged_case(
+        cache_dtype, rotated, dropped, vector_index
+    )
+    k_view, v_view, want_kv = paged_write_read(cache, k_new, v_new, index, q.dtype)
+    want = dot_product_attention(q, k_view, v_view, bias)
+
+    before = _counts()
+    out, new_kv = decode_attention(q, k_new, v_new, cache, index, bias)
+    after = _counts()
+    path = "generic" if cache_dtype == "int8" else "paged"
+    assert reads_as_stored(cache, k_new, index) == (path == "paged")
+    assert {p: after[p] - before[p] for p in after} == {
+        p: float(p == path) for p in after
+    }
+
+    assert sorted(new_kv) == sorted(want_kv)
+    for name in want_kv:
+        assert new_kv[name].dtype == want_kv[name].dtype, name
+        np.testing.assert_array_equal(
+            np.asarray(new_kv[name], np.float32),
+            np.asarray(want_kv[name], np.float32), err_msg=name,
+        )
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-6)
+    if path == "generic":
+        with pytest.raises(ValueError, match="as_stored"):
+            paged_write_read(cache, k_new, v_new, index, q.dtype, as_stored=True)
+        return
+    k_st, v_st, _ = paged_write_read(
+        cache, k_new, v_new, index, q.dtype, as_stored=True
+    )
+    view = logical_view_index(cache["block_tables"], k_st.shape[1])
+    np.testing.assert_array_equal(
+        np.asarray(_gather_logical(k_st, view)), np.asarray(k_view)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(_gather_logical(v_st, view)), np.asarray(v_view)
+    )
+
+
+def test_paged_read_in_bfloat16_matches_the_logical_view():
+    """The compute dtype the cells serve in: products and sums in float32
+    on both sides, the output rounded once, so the two orders of one sum
+    agree to a bfloat16 ulp of the output."""
+    import jax.numpy as jnp
+
+    from trlx_tpu.inference.kv_cache import paged_write_read
+    from trlx_tpu.ops.attention import decode_attention, dot_product_attention
+
+    cache, q, k_new, v_new, index, bias = _paged_case(
+        "bfloat16", True, True, True, dtype="bfloat16"
+    )
+    k_view, v_view, _ = paged_write_read(cache, k_new, v_new, index, q.dtype)
+    want = dot_product_attention(q, k_view, v_view, bias)
+    out, _ = decode_attention(q, k_new, v_new, cache, index, bias)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(want, np.float32), atol=2e-2
+    )
+
+
+def test_stored_order_bias_follows_the_tables():
+    """Column ``p`` of the re-indexed bias is the bias of the logical
+    position whose row the slot keeps at ``p``; a shared ``[1, ...]`` bias
+    is spread over the slots first."""
+    import jax.numpy as jnp
+
+    from trlx_tpu.inference.kv_cache import logical_view_index, stored_order_bias
+
+    tables = jnp.asarray([[2, 0, 3, 1], [0, 1, 2, 3], [1, 2, 3, 0]], jnp.int32)
+    C = 12
+    view = np.asarray(logical_view_index(tables, C))  # physical of each logical
+    bias = np.arange(3 * 2 * C, dtype=np.float32).reshape(3, 2, 1, C)
+    got = np.asarray(stored_order_bias(tables, jnp.asarray(bias)))
+    for b in range(3):
+        np.testing.assert_array_equal(got[b][..., view[b]], bias[b])
+    shared = np.asarray(stored_order_bias(tables, jnp.asarray(bias[:1, :1])))
+    assert shared.shape == (3, 1, 1, C)
+    np.testing.assert_array_equal(shared[0][..., view[0]], bias[0, :1])

@@ -11,6 +11,8 @@ cache, recycled slots with rotated block tables) and the fixed-batch
 sampler, both per-call and through a full streamed PPO phase.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -420,6 +422,99 @@ def test_per_row_rng_is_admission_order_invariant():
     ]
     half_toks = np.concatenate([np.asarray(h.tokens) for h in halves])
     np.testing.assert_array_equal(np.asarray(whole.tokens), half_toks)
+
+
+# ----------------- which read each engine program takes ---------------- #
+
+
+@functools.lru_cache(maxsize=None)
+def _lowered_engine_programs():
+    """(decode_step, prefill) of the tier-1 engine, lowered once from
+    shapes (a new ``jax.jit`` each: a cached trace would count nothing),
+    and what one traced call site a layer added to
+    ``attention/decode_path``."""
+    from trlx_tpu.telemetry import get_metrics
+
+    trainer = _cached_trainer("cont_dp", DP_MESH, ENGINE_ROLLOUT)
+    engine = trainer.rollout_engine_obj
+    sds = harness._sds
+    params = sds(trainer.rollout_params())
+    state = sds(engine.init_state())
+    A, Q = engine.admit_width, engine.Q
+
+    def counts():
+        return {
+            path: get_metrics().counter(
+                "attention/decode_path{path=%s}" % path
+            ).value
+            for path in ("fused", "paged", "generic")
+        }
+
+    def lowered(jitted, *args):
+        before = counts()
+        out = jax.jit(jitted.__wrapped__).lower(*args)
+        after = counts()
+        return out, {p: after[p] - before[p] for p in after}
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    decode = lowered(engine.decode_step_jit, params, state)
+    prefill = lowered(
+        engine.prefill_jit, params, state, i32(A), i32(A, Q), i32(A, Q),
+        i32(A), i32(A), jax.ShapeDtypeStruct((2,), jnp.uint32),
+    )
+    n_layer = len(state.cache)
+    return engine, n_layer, decode, prefill
+
+
+def test_decode_step_reads_the_pool_as_stored_and_prefill_the_view():
+    """``attention/decode_path``: every layer of a traced ``decode_step``
+    takes the paged read, every layer of the admission prefill the generic
+    one, and neither the fixed sampler's."""
+    _, n_layer, (_, decode), (_, prefill) = _lowered_engine_programs()
+    assert decode == {"fused": 0, "paged": n_layer, "generic": 0}
+    assert prefill == {"fused": 0, "paged": 0, "generic": n_layer}
+
+
+def test_decode_step_holds_no_copy_of_a_pool():
+    """What made the serving decode step 43 ms on the v5e (PERF.md §6, PR
+    28): a gather of each layer's whole pool into logical order, which the
+    TPU compiler then converted to float32, whole, for a one-row query. The
+    lowered ``decode_step`` holds no gather that permutes a pool (same
+    shape in and out) and keeps its one scatter of rows a pool. The
+    pattern does find the gathers where they are: the admission prefill
+    attends over the logical view of its group of slots, K and V a
+    layer."""
+    import re
+
+    engine, n_layer, (decode, _), (prefill, _) = _lowered_engine_programs()
+    layer = engine.init_state().cache[0]
+    B, cap, H, Dh = layer["k"].shape
+    dt = {"bfloat16": "bf16", "float32": "f32"}[str(layer["k"].dtype)]
+
+    def pool_shapes(n_slots):
+        """A pool of ``n_slots`` slots as the indexing lowers it."""
+        return (f"{n_slots}x{cap}x{H}x{Dh}x{dt}", f"{n_slots * cap}x{H}x{Dh}x{dt}")
+
+    def permuting_gathers(text, n_slots):
+        return [
+            m for m in re.finditer(
+                r'"stablehlo\.gather"[^\n]*: \(tensor<([0-9x]+\w+)>, [^\n]*'
+                r"-> tensor<([0-9x]+\w+)>", text)
+            if m.group(1) in pool_shapes(n_slots) and m.group(2) in pool_shapes(n_slots)
+        ]
+
+    def pool_scatters(text, n_slots):
+        # a scatter's type follows its update region
+        return [
+            m for m in re.finditer(
+                r'"stablehlo\.scatter".*?\}\) : \(tensor<([0-9x]+\w+)>', text, re.S)
+            if m.group(1) in pool_shapes(n_slots)
+        ]
+
+    decode_text = decode.as_text()
+    assert not permuting_gathers(decode_text, B)
+    assert len(pool_scatters(decode_text, B)) == 2 * n_layer
+    assert len(permuting_gathers(prefill.as_text(), engine.admit_width)) == 2 * n_layer
 
 
 # --------------------------- config refusals --------------------------- #

@@ -1,0 +1,56 @@
+"""Compiles for a TPU that is described, not attached: what the chip's own
+compiler makes of the main path's layers at the sizes the benchmark serves.
+Nothing runs, so nothing here is a time or a result (PERF.md has those).
+
+The topology is described inside a fixture and never at import: one worker
+loads the TPU's library, and only when a test of this file starts. Keep
+every such compile in this one file."""
+
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_paged_decode_layer_holds_no_copy_of_its_pool(one_chip):
+    """One layer of the serving cells' decode step (pythia-1.4b and
+    OLMoE-1B-7B: 32 slots x 640 positions x 16 heads of 128, bf16, blocks of
+    16): write one row a slot, attend over the pool. Read through the
+    logical view this compiled to a bf16 gather and a float32 convert of the
+    whole pool, for K and for V: 168 MB of temporaries a layer and 1.4 ms a
+    layer on the chip (PERF.md §6, PR 28). Read as stored it needs none."""
+    from trlx_tpu.ops.attention import decode_attention
+
+    B, C, H, Dh, n_blocks = 32, 640, 16, 128, 40
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((B, C, H, Dh), jnp.bfloat16)
+    cache = {"k": pool, "v": pool, "block_tables": sds((B, n_blocks), jnp.int32)}
+    row = sds((B, 1, H, Dh), jnp.bfloat16)
+    compiled = (
+        jax.jit(decode_attention, donate_argnums=(3,))
+        .lower(row, row, row, cache, sds((B,), jnp.int32), sds((B, 1, 1, C), jnp.float32))
+        .compile()
+    )
+    pool_bytes = B * C * H * Dh * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+    # no operation of the entry computation returns a pool in float32
+    # (fused converts live inside a fusion and return scores or outputs)
+    entry = compiled.as_text().split("\nENTRY ", 1)[1]
+    assert not re.search(r"= f32\[(%d,%d|%d),%d,%d\]" % (B, C, B * C, H, Dh), entry)

@@ -15,18 +15,28 @@ indirects logical token positions through fixed-size blocks:
   through the table, so a recycled slot can be handed a permuted table
   (the engine rotates tables on recycle — the indirection is exercised,
   not decorative);
-- reads materialize the slot's **logical view** — a per-position gather
-  back into logical order — so attention over the paged cache is the
-  exact computation the fixed cache runs (bitwise: a gather permutes,
-  it never re-associates any reduction). This is what makes
-  ``rollout.engine: continuous`` per-row token-identical to the fixed
-  sampler.
+- a call with more than one position (prefill, the verify step) reads
+  the slot's **logical view** — a per-position gather back into logical
+  order, materialised — so attention over it is the computation the
+  fixed cache runs, term for term in the same order;
+- the decode step (one position a slot, a floating pool) reads the pool
+  **as stored**: attention is a sum over positions, so it runs in
+  physical order with the bias re-indexed (:func:`stored_order_bias`)
+  and nothing is gathered or copied. The terms are the same, their
+  order within a slot is rotated with its table, so a float32 sum may
+  differ in its last bits; ``rollout.engine: continuous`` stays per-row
+  token-identical to the fixed sampler (tests/test_inference_engine.py).
+  On the v5e the gathered view cost 1.36 ms a layer of a 32 x 640 x 16 x
+  128 pool (the compiler converted it to float32, whole, on top of the
+  gather); the stored read costs 0.31 (PERF.md §5-§6, PR 28).
 
 ``kv_cache_dtype`` is honored exactly as in the linear cache
 (``models/gpt2.py::kv_buffers``): ``int8`` stores quantized values +
 per-(position, head) bf16 scales and dequantizes on read — the same
 absmax/127 quantizer, so int8 paged and int8 linear caches hold
-identical bits per logical position.
+identical bits per logical position. An int8 pool is always read through
+the dequantised logical view (its ``[B, C, H, 1]`` scales are no layout
+to read in place).
 
 Why per-slot block regions instead of one global pool: a single shared
 pool would put every slot's blocks behind one un-sharded physical axis,
@@ -253,6 +263,50 @@ def logical_view_index(block_tables: jax.Array, capacity: int) -> jax.Array:
     return phys.reshape(block_tables.shape[0], capacity)
 
 
+def reads_as_stored(cache_kv: Dict[str, jax.Array], k: jax.Array,
+                    cache_index, view_len: int = 0) -> bool:
+    """Whether this call can attend over the pools in the order they are
+    stored (:func:`paged_write_read` with ``as_stored=True``): one new
+    position a slot at a per-slot or scalar ``cache_index``, a floating
+    pool read at full width, and no shared-prefix overlay. Decided on what
+    the call shows, at trace time; everything else reads the logical
+    view."""
+    capacity = cache_kv["k"].shape[1]
+    return (
+        k.shape[1] == 1
+        and jnp.ndim(cache_index) <= 1
+        and "k_scale" not in cache_kv
+        and "shared_tables" not in cache_kv
+        and not 0 < view_len < capacity
+    )
+
+
+def stored_order_bias(
+    block_tables: jax.Array,  # [B, n_blocks] int32, a permutation a slot
+    bias: jax.Array,  # [B or 1, heads or 1, Q, capacity] over LOGICAL positions
+) -> jax.Array:
+    """``bias`` re-indexed over PHYSICAL positions: column ``p`` of slot
+    ``b`` takes the bias of the logical position stored at ``p``. Whole
+    blocks move, so each physical block selects its logical block's
+    ``block_size`` columns by comparison with the table: ``n_blocks**2 *
+    block_size`` selects a slot in one fused pass, exact, and no gather (on
+    the v5e a gather of these 32 x 640 floats took 148 us a layer, more
+    than the read of K it served; PERF.md §6, PR 28)."""
+    n_slots, n_blocks = block_tables.shape
+    capacity = bias.shape[-1]
+    lead = (n_slots,) + bias.shape[1:-1]
+    blocks = jnp.broadcast_to(bias, lead + (capacity,)).reshape(
+        lead + (1, n_blocks, capacity // n_blocks)
+    )  # [B, heads, Q, 1, logical block, column]
+    # holds[b, p, j]: physical block p of slot b holds logical block j
+    holds = (
+        block_tables[:, None, :]
+        == jnp.arange(n_blocks, dtype=block_tables.dtype)[None, :, None]
+    ).reshape((n_slots,) + (1,) * (len(lead) - 1) + (n_blocks, n_blocks, 1))
+    stored = jnp.sum(jnp.where(holds, blocks, 0), axis=-2)
+    return stored.reshape(lead + (capacity,))
+
+
 def _gather_logical(pool: jax.Array, view_idx: jax.Array) -> jax.Array:
     """Gather ``pool`` [B, cap, ...] rows into logical order."""
     b_idx = jnp.arange(pool.shape[0], dtype=jnp.int32)[:, None]
@@ -309,10 +363,29 @@ def paged_write_read(
     cache_index,  # scalar/[B] logical base position, or [B, T] per column
     dtype,
     view_len: int = 0,
+    as_stored: bool = False,
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
     """Paged counterpart of the linear ``write_cache`` arm: write the new
-    K/V rows through the block table, then return the **logical view** of
-    the whole buffer for attention (plus the updated cache dict).
+    K/V rows through the block table, then return the buffers to attend
+    over (plus the updated cache dict).
+
+    The write is one scatter of the call's rows, in place in the donated
+    pool whatever its dtype (on the v5e a bf16 scatter of 32 rows into an
+    84 MB pool takes microseconds; PERF.md §6, PR 28). What is returned to
+    attend over comes in two forms, and the form is most of a decode
+    step's cost:
+
+    - the **logical view** (default): a per-position gather of the pool
+      back into logical order, materialised, so the caller's bias and
+      causal structure apply as they are. Prefill, chunked prefill, the
+      verify step, int8 pools (dequantised after the gather) and
+      shared-prefix reads (overlaid on it);
+    - ``as_stored=True`` (callers check :func:`reads_as_stored`): the
+      updated pools themselves, in physical order, with nothing gathered
+      or copied. Attention is a sum over positions, so it may run in any
+      order if the bias follows: the caller re-indexes its bias with
+      :func:`stored_order_bias`. The engine's one-token decode step reads
+      this way (``ops/attention.py::decode_attention``, ``path=paged``).
 
     ``cache_index`` may be per-slot (the continuous engine's rows sit at
     different depths), scalar (broadcast), or a full [B, T] per-column
@@ -329,6 +402,11 @@ def paged_write_read(
     attend the decode region. Writes are NEVER narrowed: positions
     resolve through the table at full capacity regardless.
     """
+    if as_stored and not reads_as_stored(cache_kv, k, cache_index, view_len):
+        raise ValueError(
+            "as_stored serves one position a slot into a floating pool read "
+            "at full width, without a shared-prefix overlay (reads_as_stored)"
+        )
     B, T = k.shape[0], k.shape[1]
     capacity = cache_kv["k"].shape[1]
     tables = cache_kv["block_tables"]
@@ -435,6 +513,8 @@ def paged_write_read(
         "k": _scatter_rows(cache_kv["k"], phys, k),
         "v": _scatter_rows(cache_kv["v"], phys, v),
     })
+    if as_stored:
+        return new_kv["k"], new_kv["v"], new_kv
     if sharing:
         new_kv["shared_k"] = _publish_rows(cache_kv["shared_k"], pub_pos, k)
         new_kv["shared_v"] = _publish_rows(cache_kv["shared_v"], pub_pos, v)

@@ -277,9 +277,11 @@ def write_cache(cache_kv, k, v, cache_index, dtype, view_len=None):
     returns ``(k, v, new_kv)`` — the full buffers to attend over and the
     updated cache dict. The generic arm of
     ``ops/attention.py::decode_attention`` (prefill, chunked prefill, the
-    verify step, the paged engine, T5, an sp-sharded cache); the fixed
-    sampler's one-token steps do not come here — they write in place and
-    read the stored buffers once, in ``decode_kv_layout``. Transparent over
+    verify step, T5, an sp-sharded cache, a paged int8 pool); the one-token
+    steps do not come here — the fixed sampler's write in place and read
+    the stored buffers once, in ``decode_kv_layout``, and the paged
+    engine's read their pool as stored
+    (``kv_cache.py::paged_write_read(as_stored=True)``). Transparent over
     the three storage layouts (shared by every causal family):
 
     - plain: ``{"k", "v"}`` in the compute dtype;
@@ -293,8 +295,8 @@ def write_cache(cache_kv, k, v, cache_index, dtype, view_len=None):
     - paged (``"block_tables"`` present — the continuous-batching
       engine's cache, ``inference/kv_cache.py``): writes resolve logical
       positions through per-slot block tables (``cache_index`` may be a
-      per-slot [B] vector), reads return the logical view; composes
-      with the int8 layout.
+      per-slot [B] vector), and what comes back from here is the logical
+      view, gathered; composes with the int8 layout.
 
     ``view_len`` (static) narrows the RETURNED attention view to the
     leading ``view_len`` logical positions — ``decode_attention`` derives
@@ -352,7 +354,10 @@ def write_cache(cache_kv, k, v, cache_index, dtype, view_len=None):
 # from ``ops/attention.py::decode_kv_layout`` (int8 read within ~1.7x of
 # its bytes' time at capacity 512); the threshold stays where the
 # benchmark's configurations state it until a long-context cell measures
-# the new read beyond it (the paged engine still reads the old way).
+# the new read beyond it. The paged engine reads a floating pool as stored
+# since PR 28; its int8 pool is still gathered and dequantised whole at
+# every step, so int8 under ``rollout.engine: continuous`` is the slow
+# choice at any capacity until that read exists.
 INT8_KV_MAX_CAPACITY = 512
 
 

@@ -15,8 +15,9 @@ Softmax runs in float32 regardless of compute dtype (bf16 logits lose
 Attention over a KV cache goes through :func:`decode_attention`: the fixed
 sampler's one-token steps read each layer's buffers once, in the lane-dense
 layout :func:`decode_kv_layout` gives them, and write the new position in
-place; every other cached call (prefill, chunked prefill, the verify step,
-the paged engine) is ``write_cache`` + :func:`dot_product_attention`.
+place; the paged engine's one-token steps read each layer's pool once, as
+it is stored; every other cached call (prefill, chunked prefill, the verify
+step) is ``write_cache`` + :func:`dot_product_attention`.
 """
 
 from __future__ import annotations
@@ -322,17 +323,45 @@ def decode_attention(
       sampler's decode loop): :func:`_decode_read`. Such a cache takes one
       position a call under a bias broadcast over heads; anything else is
       refused, not rerouted;
+    - ``paged`` — one position a slot into a floating paged pool (the
+      continuous engine's decode step; ``kv_cache.py::reads_as_stored``):
+      the rows are scattered in place and :func:`dot_product_attention`
+      reads the pools as stored, in each slot's physical order, under the
+      bias re-indexed to that order. No logical view is gathered;
     - ``generic`` — everything else, unchanged: ``write_cache`` (dense
       ``kv_buffers`` layout or paged) returns the view the bias was built
       for and :func:`dot_product_attention` reads it. Prefill and chunked
       prefill, the verify step, T5's learned per-head bias, a cache whose
       capacity axis is sharded (the sampler leaves those in the
-      ``kv_buffers`` layout), and the paged engine.
+      ``kv_buffers`` layout), a paged int8 pool and a paged pool with a
+      shared-prefix overlay.
     """
+    # attend over the buffer VIEW the bias was built for: a bias narrower
+    # than capacity (the chunked prefill's prompt-only mask) narrows the
+    # view to match
+    from trlx_tpu.inference.kv_cache import (
+        paged_write_read,
+        reads_as_stored,
+        stored_order_bias,
+    )
+
+    view_len = bias.shape[-1] if bias is not None else None
     fused = "block_tables" not in cache_kv and cache_kv["k"].ndim == 3
-    get_metrics().counter(
-        "attention/decode_path{path=%s}" % ("fused" if fused else "generic")
-    ).inc()
+    paged = (
+        "block_tables" in cache_kv
+        and bias is not None
+        and not learned_bias
+        and not causal
+        and reads_as_stored(cache_kv, k_new, cache_index, view_len)
+    )
+    path = "fused" if fused else "paged" if paged else "generic"
+    get_metrics().counter("attention/decode_path{path=%s}" % path).inc()
+    if paged:
+        k, v, new_kv = paged_write_read(
+            cache_kv, k_new, v_new, cache_index, q.dtype, as_stored=True
+        )
+        bias = stored_order_bias(cache_kv["block_tables"], bias)
+        return dot_product_attention(q, k, v, bias), new_kv
     if fused:
         if (
             q.shape[1] != 1
@@ -353,10 +382,6 @@ def decode_attention(
             return _decode_read(q, k_new, v_new, cache_kv, cache_index, bias)
     from trlx_tpu.models.gpt2 import write_cache
 
-    # attend over the buffer VIEW the bias was built for: a bias narrower
-    # than capacity (the chunked prefill's prompt-only mask) narrows the
-    # view to match
-    view_len = bias.shape[-1] if bias is not None else None
     k, v, new_kv = write_cache(
         cache_kv, k_new, v_new, cache_index, q.dtype, view_len=view_len
     )
