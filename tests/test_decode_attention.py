@@ -1,6 +1,6 @@
 """The fixed sampler's decode read (``ops/attention.py::decode_attention`` on
 a cache in ``decode_kv_layout``) and the paged engine's (the pools read in
-the order they are stored) against the generic read — ``write_cache`` and
+the order they are stored) against the generic read — ``dense_write_read`` / ``paged_write_read`` and
 ``dot_product_attention`` on the dequantised updated buffer — and the
 dispatch between the three, counted at trace time."""
 
@@ -35,17 +35,17 @@ def _counts():
 
 def _filled_cache(rng, C, Dh, dtype, cache_dtype, filled):
     """One layer's ``kv_buffers``-layout cache holding ``filled`` random
-    positions, written through ``write_cache`` (so int8 holds what the
+    positions, written through ``dense_write_read`` (so int8 holds what the
     program would have quantised)."""
     import jax.numpy as jnp
 
-    from trlx_tpu.models.gpt2 import kv_buffers, write_cache
+    from trlx_tpu.ops.kv_cache import dense_write_read, kv_buffers
 
     kv = "int8" if cache_dtype == "int8" else "bfloat16"
     cache = kv_buffers(1, B, C, H, Dh, dtype, kv)[0]
     k = jnp.asarray(rng.standard_normal((B, filled, H, Dh)), dtype)
     v = jnp.asarray(rng.standard_normal((B, filled, H, Dh)), dtype)
-    return write_cache(cache, k, v, 0, jnp.dtype(dtype))[2]
+    return dense_write_read(cache, k, v, 0, jnp.dtype(dtype))[2]
 
 
 def _bias(C, index, pad, shared):
@@ -69,7 +69,8 @@ def test_fused_read_matches_generic(dtype, cache_dtype, atol, Dh, C, where,
                                     shared_bias):
     import jax.numpy as jnp
 
-    from trlx_tpu.ops.attention import decode_attention, decode_kv_layout
+    from trlx_tpu.ops.attention import decode_attention
+    from trlx_tpu.ops.kv_cache import decode_kv_layout
 
     rng = np.random.default_rng(C + Dh)
     index = {"first": 0, "mid": C // 2, "last": C - 1}[where]
@@ -110,9 +111,8 @@ def test_fully_masked_row_matches_generic():
     uniform weights in both reads — no NaN, same output."""
     import jax.numpy as jnp
 
-    from trlx_tpu.ops.attention import (
-        NEG_INF, decode_attention, decode_kv_layout,
-    )
+    from trlx_tpu.ops.attention import NEG_INF, decode_attention
+    from trlx_tpu.ops.kv_cache import decode_kv_layout
 
     rng = np.random.default_rng(0)
     C, Dh, index = 40, 64, 17
@@ -137,8 +137,7 @@ def test_decode_kv_layout_shapes():
     into the minor axis, int8 scales go capacity-minor."""
     import jax.numpy as jnp
 
-    from trlx_tpu.models.gpt2 import kv_buffers
-    from trlx_tpu.ops.attention import decode_kv_layout
+    from trlx_tpu.ops.kv_cache import decode_kv_layout, kv_buffers
 
     flat = decode_kv_layout(kv_buffers(2, B, 24, H, 8, jnp.bfloat16, "int8"))
     assert len(flat) == 2
@@ -155,12 +154,12 @@ def _bypass_case(kind):
     pool, a learned per-head bias, two positions a call."""
     import jax.numpy as jnp
 
-    from trlx_tpu.models.gpt2 import kv_buffers
+    from trlx_tpu.ops.kv_cache import kv_buffers
     from trlx_tpu.ops.attention import causal_bias
 
     C, Dh = 16, 8
     if kind.startswith("paged"):
-        from trlx_tpu.inference.kv_cache import init_paged_cache
+        from trlx_tpu.ops.kv_cache import init_paged_cache
 
         # an int8 pool is read dequantised, a window of two positions (the
         # verify step) through the logical view: both stay generic
@@ -209,7 +208,8 @@ def test_decode_layout_refuses_what_it_cannot_read(kind):
     serve is an error, not a slower answer."""
     import jax.numpy as jnp
 
-    from trlx_tpu.ops.attention import decode_attention, decode_kv_layout
+    from trlx_tpu.ops.attention import decode_attention
+    from trlx_tpu.ops.kv_cache import decode_kv_layout
 
     q_len, cache, index, bias = _bypass_case(kind)
     x = jnp.zeros((B, q_len, H, 8), jnp.float32)
@@ -273,13 +273,14 @@ def test_sampler_traces_the_fused_read_and_sp_keeps_generic():
 
 def _paged_case(cache_dtype, rotated, dropped, vector_index, dtype="float32"):
     """One layer's paged pool (``C`` 24 in blocks of 4) filled to
-    position 10 through ``write_cache``, its tables rotated a slot or left
+    position 10 through ``paged_write_read``, its tables rotated a slot or left
     identity, and one more position a slot: at a per-slot depth, or at one
     scalar depth; ``dropped`` parks slot 1 at the discard sentinel."""
     import jax.numpy as jnp
 
-    from trlx_tpu.inference.kv_cache import init_paged_cache, rotate_block_table
-    from trlx_tpu.models.gpt2 import write_cache
+    from trlx_tpu.ops.kv_cache import (
+        init_paged_cache, paged_write_read, rotate_block_table,
+    )
     from trlx_tpu.ops.attention import causal_bias, combine_biases, padding_bias
 
     C, Dh, filled = 24, 8, 10
@@ -293,7 +294,9 @@ def _paged_case(cache_dtype, rotated, dropped, vector_index, dtype="float32"):
     k, v = (
         jnp.asarray(rng.standard_normal((B, filled, H, Dh)), dtype) for _ in range(2)
     )
-    cache = write_cache(cache, k, v, jnp.zeros((B,), jnp.int32), jnp.dtype(dtype))[2]
+    cache = paged_write_read(
+        cache, k, v, jnp.zeros((B,), jnp.int32), jnp.dtype(dtype)
+    )[2]
     if vector_index:
         depth = np.asarray([filled, filled - 3, filled - 1])
         index = jnp.asarray(np.where(dropped, [filled, C, filled - 1], depth), jnp.int32)
@@ -327,7 +330,7 @@ def test_paged_one_token_read_matches_the_logical_view(
     summed in the slot's physical order)."""
     import jax.numpy as jnp
 
-    from trlx_tpu.inference.kv_cache import (
+    from trlx_tpu.ops.kv_cache import (
         _gather_logical,
         logical_view_index,
         paged_write_read,
@@ -380,7 +383,7 @@ def test_paged_read_in_bfloat16_matches_the_logical_view():
     agree to a bfloat16 ulp of the output."""
     import jax.numpy as jnp
 
-    from trlx_tpu.inference.kv_cache import paged_write_read
+    from trlx_tpu.ops.kv_cache import paged_write_read
     from trlx_tpu.ops.attention import decode_attention, dot_product_attention
 
     cache, q, k_new, v_new, index, bias = _paged_case(
@@ -401,7 +404,7 @@ def test_stored_order_bias_follows_the_tables():
     is spread over the slots first."""
     import jax.numpy as jnp
 
-    from trlx_tpu.inference.kv_cache import logical_view_index, stored_order_bias
+    from trlx_tpu.ops.kv_cache import logical_view_index, stored_order_bias
 
     tables = jnp.asarray([[2, 0, 3, 1], [0, 1, 2, 3], [1, 2, 3, 0]], jnp.int32)
     C = 12

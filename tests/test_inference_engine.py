@@ -22,15 +22,17 @@ import jax.numpy as jnp
 from trlx_tpu.analysis import harness
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.inference import RolloutEngineConfig
-from trlx_tpu.inference.kv_cache import (
+from trlx_tpu.ops.kv_cache import (
     choose_block_size,
+    dense_write_read,
     identity_block_tables,
     init_paged_cache,
+    kv_buffers,
     logical_view_index,
+    paged_write_read,
     physical_positions,
     rotate_block_table,
 )
-from trlx_tpu.models.gpt2 import kv_buffers, write_cache
 
 
 DP_MESH = {"dp": -1, "fsdp": 1, "tp": 1}
@@ -78,16 +80,20 @@ def test_paged_cache_matches_linear(kv_dtype):
 
     k = jnp.asarray(rng.normal(size=(B, 3, H, Dh)), jnp.bfloat16)
     v = jnp.asarray(rng.normal(size=(B, 3, H, Dh)), jnp.bfloat16)
-    kl, vl, lin = write_cache(lin, k, v, 0, jnp.bfloat16)
-    kp, vp, paged = write_cache(paged, k, v, jnp.asarray([0, 0]), jnp.bfloat16)
+    kl, vl, lin = dense_write_read(lin, k, v, 0, jnp.bfloat16)
+    kp, vp, paged = paged_write_read(
+        paged, k, v, jnp.asarray([0, 0]), jnp.bfloat16
+    )
     np.testing.assert_array_equal(np.asarray(kl, np.float32),
                                   np.asarray(kp, np.float32))
     np.testing.assert_array_equal(np.asarray(vl, np.float32),
                                   np.asarray(vp, np.float32))
     k2 = jnp.asarray(rng.normal(size=(B, 1, H, Dh)), jnp.bfloat16)
     v2 = jnp.asarray(rng.normal(size=(B, 1, H, Dh)), jnp.bfloat16)
-    kl2, _, _ = write_cache(lin, k2, v2, 3, jnp.bfloat16)
-    kp2, _, _ = write_cache(paged, k2, v2, jnp.asarray([3, 3]), jnp.bfloat16)
+    kl2, _, _ = dense_write_read(lin, k2, v2, 3, jnp.bfloat16)
+    kp2, _, _ = paged_write_read(
+        paged, k2, v2, jnp.asarray([3, 3]), jnp.bfloat16
+    )
     np.testing.assert_array_equal(np.asarray(kl2, np.float32),
                                   np.asarray(kp2, np.float32))
 
@@ -99,8 +105,8 @@ def test_paged_oob_writes_drop():
     paged = init_paged_cache(1, B, cap, H, Dh, "bfloat16", "bfloat16",
                              block_size=4)[0]
     ones = jnp.ones((B, 1, H, Dh), jnp.bfloat16)
-    _, _, out = write_cache(paged, ones, ones, jnp.asarray([cap, 0]),
-                            jnp.bfloat16)
+    _, _, out = paged_write_read(paged, ones, ones, jnp.asarray([cap, 0]),
+                                 jnp.bfloat16)
     assert np.asarray(out["k"], np.float32)[0].sum() == 0  # dropped
     assert np.asarray(out["k"], np.float32)[1].sum() != 0  # written
 

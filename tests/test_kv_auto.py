@@ -1,7 +1,8 @@
-"""`kv_cache_dtype: "auto"` + the int8 long-context guardrail (VERDICT r3
-#6): int8 wins at the rollout shape but measured ~2x slower at a 2k cache
-(LONGCTX.json) — no config may silently decode 2x slower. "auto" resolves
-per cache capacity; an explicit "int8" past the crossover warns loudly."""
+"""`kv_cache_dtype: "auto"` + the int8 long-context guardrail: the int8
+read is measured on the chip up to capacity 512 (`ppo-gpt2m-longgen`) and
+not beyond — no config may silently take an unmeasured read. "auto"
+resolves per cache capacity; an explicit "int8" past the measured capacity
+warns loudly."""
 
 import os
 import sys
@@ -14,7 +15,7 @@ sys.path.insert(0, REPO)
 
 
 def test_resolve_auto_by_capacity():
-    from trlx_tpu.models.gpt2 import (
+    from trlx_tpu.ops.kv_cache import (
         INT8_KV_MAX_CAPACITY, resolve_kv_cache_dtype,
     )
 
@@ -25,12 +26,18 @@ def test_resolve_auto_by_capacity():
 
 
 def test_explicit_int8_past_crossover_warns():
-    from trlx_tpu.models.gpt2 import resolve_kv_cache_dtype
+    from trlx_tpu.ops.kv_cache import resolve_kv_cache_dtype
 
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         assert resolve_kv_cache_dtype("int8", 2048) == "int8"  # honored
-    assert any("2x SLOWER" in str(w.message) for w in caught)
+    said = [str(w.message) for w in caught]
+    assert any(
+        "measured only up to capacity 512" in m and "ppo-gpt2m-longgen" in m
+        and "paged engine's int8 read still gathers" in m
+        for m in said
+    )
+    assert not any("LONGCTX" in m or "0.4.36" in m for m in said)
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         resolve_kv_cache_dtype("int8", 112)

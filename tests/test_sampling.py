@@ -365,7 +365,7 @@ def test_filter_logits_temperature_and_top_k():
 
 def test_int8_kv_cache_matches_bf16_closely(tiny_policy):
     """The int8 rollout cache (absmax-per-token/head quantization,
-    `models/gpt2.py::quantize_kv`) must produce decode logprobs close to
+    `ops/kv_cache.py::quantize_kv`) must produce decode logprobs close to
     the exact cache: same sampler, same rng, cache dtype the only delta.
     Quantization noise bounds the drift; the importance ratios in the PPO
     update absorb this (behavior logprobs stay self-consistent either
@@ -425,7 +425,7 @@ def test_int8_kv_cache_matches_bf16_closely(tiny_policy):
 
 def test_int8_cache_extends_to_all_causal_families():
     """`kv_cache_dtype="int8"` plumbs through every causal family's cache
-    initializer (the write path is shared: `models/gpt2.py::write_cache`);
+    initializer (the write path is shared: `ops/kv_cache.py`);
     unknown values fail loudly."""
     import jax.numpy as jnp
     import pytest as _pytest

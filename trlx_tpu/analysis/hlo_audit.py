@@ -1280,39 +1280,6 @@ def audit_hlo(
     return report, result
 
 
-# ------------------------------ bench hook ------------------------------ #
-
-def compiled_step_stats(trainer, kind: str) -> Dict[str, float]:
-    """Compiled ground truth for bench.py's ``static_vs_compiled`` row:
-    the train step's HLO-measured collective payload and the
-    buffer-assignment peak, from the same jit instance bench drives."""
-    from trlx_tpu.analysis import harness
-
-    state_sds = harness._sds(trainer.state)
-    mb = (
-        harness._ilql_minibatch_sds(trainer)
-        if kind == "ilql"
-        else harness._ppo_minibatch_sds(trainer)
-    )
-    compiled = trainer._train_step_jit.lower(state_sds, mb).compile()
-    collectives = parse_hlo_collectives(compiled.as_text())
-    stats = {
-        "compiled_train_step_collective_mb": (
-            sum(c.bytes for c in collectives) / 2**20
-        ),
-        "compiled_train_step_collectives": float(len(collectives)),
-    }
-    mem = compiled.memory_analysis()
-    peak = (
-        mem.temp_size_in_bytes
-        + mem.argument_size_in_bytes
-        + mem.output_size_in_bytes
-        - mem.alias_size_in_bytes
-    )
-    stats["compiled_train_step_peak_hbm_gb"] = max(0, peak) / 2**30
-    return stats
-
-
 # ------------------------------ rendering ------------------------------- #
 
 def format_hlo_text(result: HloAuditResult) -> str:

@@ -416,8 +416,8 @@ def analyze_traced_program(traced) -> ProgramResources:
 
 def trainer_step_resources(trainer, kind: str = "ppo") -> ProgramResources:
     """Static resources of a LIVE trainer's jitted train step — tracing
-    only (no compilation), so bench.py can print the budget numbers next
-    to measured stats at the real workload shape."""
+    only (no compilation): the budget numbers at the real workload shape,
+    for ``telemetry/device_metrics.py`` to set beside measured stats."""
     import jax
 
     from trlx_tpu.analysis import harness

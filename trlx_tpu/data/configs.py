@@ -111,9 +111,9 @@ class TrainConfig:
     grad_clip: float = 1.0
     # Storage dtype for BOTH Adam moments ("float32" | "bfloat16"). bf16
     # halves the optimizer's resident bytes and its per-step HBM read+write
-    # (measured ~24% of the bench train step at f32); stores use stochastic
-    # rounding so sub-resolution EMA increments ((1-b2)·g²) still
-    # accumulate. Update math stays f32. See trainer/common.py.
+    # (its share of a train step is not measured on the chip); stores use
+    # stochastic rounding so sub-resolution EMA increments ((1-b2)·g²)
+    # still accumulate. Update math stays f32. See trainer/common.py.
     adam_moment_dtype: str = "float32"
 
     checkpoint_interval: int = 10000
@@ -201,11 +201,10 @@ class TrainConfig:
     # Compute the PPO update's response logprobs in chunks of this many
     # positions (0 = off): the LM head + log-softmax + gather run per
     # chunk under jax.checkpoint, so the [B, R, vocab] f32 logits buffer
-    # — the train step's largest intermediate, ~5 HBM crossings
-    # (bench_train_audit.py bytes_split) — never materializes at full
-    # width; the backward recomputes each chunk's logits (one extra head
-    # matmul). Must divide gen max_new_tokens. Measured-neutral guardrail:
-    # only enable where an A/B shows a win (ab in bench_train_audit.py);
+    # — the train step's largest intermediate — never materializes at
+    # full width; the backward recomputes each chunk's logits (one extra
+    # head matmul). Must divide gen max_new_tokens. Not measured on the
+    # chip (no cell sets it): enable only where a chip run shows a win;
     # entropy-bonus runs (ent_coef) fall back to the full buffer.
     logprob_chunk: int = 0
     dtype: str = "bfloat16"
@@ -214,12 +213,12 @@ class TrainConfig:
     # compute-dtype copy of the master params instead of the f32 masters.
     # Bit-identical outputs: every op already casts params to the compute
     # dtype per use; leaves that genuinely compute in f32 (value-head fc2,
-    # MoE router logits) are excluded. Measured ~neutral on the single-chip
-    # bench (ab_rollout_cast.py: sampler 1.02x, ref scoring 0.92x — XLA
-    # hoists the loop-invariant f32->bf16 weight conversion out of the
-    # decode scan, so per-token reads were already bf16); kept default-on
-    # for the halved frozen-ref HBM residency and because on an fsdp mesh
-    # the compute-dtype copy halves rollout param all-gather volume.
+    # MoE router logits) are excluded. Its speed is not measured on the
+    # chip (every cell runs the default; XLA may hoist the loop-invariant
+    # f32->bf16 weight conversion out of the decode scan anyway); kept
+    # default-on for the halved frozen-ref HBM residency and because on an
+    # fsdp mesh the compute-dtype copy halves rollout param all-gather
+    # volume.
     # Causal families only — the seq2seq trainer keeps f32 (T5's RMSNorm
     # scales / relative bias are consumed at f32).
     rollout_param_cast: bool = True

@@ -4,11 +4,11 @@ The collect-phase decode loop re-built as a real inference engine
 (ROADMAP "make the rollout engine a real inference server"; PipelineRL's
 continuous rollout streams in PAPERS.md):
 
-- :mod:`trlx_tpu.inference.kv_cache` — paged/block KV cache: the same
-  ``[B, capacity]`` physical buffers the fixed sampler uses, plus
-  per-slot block tables indirecting logical positions through fixed-size
-  blocks, honoring ``kv_cache_dtype`` (int8) and the sp-sharded-cache
-  layout measured in LONGCTX.json;
+- :mod:`trlx_tpu.ops.kv_cache` (below the models, shared with the fixed
+  sampler) — paged/block KV cache: the same ``[B, capacity]`` physical
+  buffers the fixed sampler uses, plus per-slot block tables indirecting
+  logical positions through fixed-size blocks, honoring
+  ``kv_cache_dtype`` (int8) and an sp-sharded capacity axis;
 - :mod:`trlx_tpu.inference.engine` — the continuous-batching decode
   loop: a fixed pool of decode slots, a host-side admission queue that
   prefills a fresh prompt into a slot the step after its row emits eos,
@@ -136,7 +136,7 @@ class RolloutEngineConfig:
         pool — so prefill compute scales with the group's real prompt
         length, and prefix sharing saves prefill FLOPs, not just HBM
         traffic. Rounded to a block-aligned divisor of the query length
-        (``inference/kv_cache.py::choose_prefill_chunk``). Chunked and
+        (``ops/kv_cache.py::choose_prefill_chunk``). Chunked and
         monolithic prefill are token/mask-bitwise-identical
         (logprobs/values at the engine's established bf16 resolution).
         0 — the default — keeps the monolithic program byte-identical.

@@ -174,7 +174,7 @@ def multi_tenant_smoke(mesh=None, span_log=None) -> int:
     scfg = harness.tiny_config_dict("ppo", mesh=mesh)
     # near-greedy decode with a longer budget: random-init generation
     # falls into short loops the trie/n-gram drafter locks onto, so the
-    # spec path sees real acceptance (same trick as ab_spec.py)
+    # spec path sees real acceptance
     scfg["method"]["gen_kwargs"].update(
         {"temperature": 0.05, "max_new_tokens": 16, "min_new_tokens": 8}
     )

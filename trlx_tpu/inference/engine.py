@@ -2,10 +2,10 @@
 
 The fixed-batch sampler (``ops/sampling.py``) decodes B prompts in
 lockstep: a row that emits eos at step 3 still occupies its batch lane
-for all ``max_new_tokens`` steps, emitting pad — at the bench shape that
-is the dominant collect-phase waste (BENCH_r05: collect MFU 0.157 vs
-0.299 train). This engine replaces the lockstep with a **fixed pool of B
-decode slots** and a host-side admission queue:
+for all ``max_new_tokens`` steps, emitting pad (how much of a collect
+phase that wastes is not measured on the chip: the benchmark's PPO cells
+generate fixed lengths). This engine replaces the lockstep with a **fixed
+pool of B decode slots** and a host-side admission queue:
 
 - ``decode_step`` advances every slot one token (one compiled program,
   static shapes — the pool IS the batch);
@@ -19,7 +19,7 @@ decode slots** and a host-side admission queue:
   order and batch composition, so the engine is per-row token-identical
   to the fixed sampler under ``per_row_rng`` (the parity contract,
   tests/test_inference_engine.py);
-- the KV cache is the paged/block cache (``inference/kv_cache.py``):
+- the KV cache is the paged/block cache (``ops/kv_cache.py``):
   slot recycling hands the new occupant a rotated block table, writes
   and reads resolve through the table, and ``kv_cache_dtype: int8`` and
   the sp-sharded capacity layout compose unchanged.
@@ -75,7 +75,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from trlx_tpu import telemetry
-from trlx_tpu.inference.kv_cache import choose_block_size
+from trlx_tpu.ops.kv_cache import (
+    SHARED_POOL_KEYS,
+    cache_kind,
+    choose_block_size,
+    choose_prefill_chunk,
+    empty_share_tables,
+    identity_block_tables,
+    init_shared_pool,
+)
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
     accept_drafts,
@@ -246,7 +254,7 @@ class ContinuousBatchingEngine:
     :param mesh / param_shardings / cache_sharding: optional GSPMD
         pinning; ``cache_sharding`` shards the capacity axis (sp).
     :param prefix_pool_blocks: size (in blocks) of the cross-request
-        shared-prefix KV pool (``inference/kv_cache.py``; managed by
+        shared-prefix KV pool (``ops/kv_cache.py``; managed by
         :class:`trlx_tpu.serving.prefix_cache.PrefixBlockPool`). 0 — the
         default, and the trainer collect path — disables sharing and
         keeps every jitted program byte-identical to the pool-less
@@ -258,7 +266,7 @@ class ContinuousBatchingEngine:
         the trainer-path program unchanged.
     :param prefill_chunk: chunked-prefill width in prompt columns
         (``rollout.prefill_chunk``; rounded by
-        :func:`~trlx_tpu.inference.kv_cache.choose_prefill_chunk` to a
+        :func:`~trlx_tpu.ops.kv_cache.choose_prefill_chunk` to a
         block-aligned divisor of Q). ``> 0`` replaces the monolithic
         admission prefill with a scan over block-aligned prompt-column
         chunks, each wrapped in a ``lax.cond`` that SKIPS the forward
@@ -334,8 +342,6 @@ class ContinuousBatchingEngine:
         spec_drafter=None,
         spec_min_accept_ewma: float = 0.0,
     ):
-        from trlx_tpu.inference.kv_cache import choose_prefill_chunk
-
         self.gen_config = dataclasses.replace(gen_config, per_row_rng=True)
         self.Q = int(query_length)
         self.R = int(self.gen_config.max_new_tokens)
@@ -516,12 +522,6 @@ class ContinuousBatchingEngine:
         return state
 
     def _make_state(self) -> EngineState:
-        from trlx_tpu.inference.kv_cache import (
-            empty_share_tables,
-            identity_block_tables,
-            init_shared_pool,
-        )
-
         B, Q, R, V = self.num_slots, self.Q, self.R, self.vocab_size
         cfg = self.gen_config
         linear = self._init_cache_fn(B, self.capacity)
@@ -541,7 +541,7 @@ class ContinuousBatchingEngine:
                     kv.shape[2],
                     kv.shape[3],
                     kv.dtype,
-                    "int8" if "k_scale" in layer else "bfloat16",
+                    "int8" if cache_kind(layer).quantized else "bfloat16",
                 )
                 return dict(
                     layer,
@@ -572,10 +572,9 @@ class ContinuousBatchingEngine:
     def state_sharding(self):
         """Sharding pytree for :class:`EngineState`: slot axis over
         dp×fsdp everywhere; cache K/V capacity axis additionally over sp
-        when a ``cache_sharding`` was given (the LONGCTX layout); the
-        shared-prefix pool (no slot axis — a broadcast structure every
-        data shard reads) replicates."""
-        from trlx_tpu.inference.kv_cache import SHARED_POOL_KEYS
+        when a ``cache_sharding`` was given; the shared-prefix pool (no
+        slot axis — a broadcast structure every data shard reads)
+        replicates."""
         from trlx_tpu.parallel.mesh import batch_sharding, replicated
 
         batch_sh = batch_sharding(self.mesh)
@@ -629,7 +628,6 @@ class ContinuousBatchingEngine:
             )
 
         sharing = self.prefix_pool_blocks > 0
-        from trlx_tpu.inference.kv_cache import SHARED_POOL_KEYS
 
         def slice_group_cache(state, slot_ids, table_turns,
                               shared_map, publish_map):

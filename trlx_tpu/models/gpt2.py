@@ -33,22 +33,15 @@ from trlx_tpu.ops.attention import (
     decode_attention,
     dot_product_attention,
 )
-
-# KV cache: tuple over layers of {"k": [B, C, H, Dh], "v": [B, C, H, Dh]}
-Cache = Tuple[Dict[str, jax.Array], ...]
-
-
-VALID_KV_CACHE_DTYPES = ("bfloat16", "int8", "auto")
-
-
-def validate_kv_cache_dtype(value: str) -> None:
-    """Shared __post_init__ validation for every causal family config."""
-    if value not in VALID_KV_CACHE_DTYPES:
-        raise ValueError(
-            f"kv_cache_dtype={value!r} is not supported (choose one of "
-            f"{VALID_KV_CACHE_DTYPES}) — an unrecognized value would "
-            "otherwise silently fall back to bf16 buffers"
-        )
+from trlx_tpu.ops.kv_cache import (
+    Cache,
+    kv_buffers,
+    # not used here: benchmark/harness.py imports it from this module, and
+    # no file under benchmark/ may change outside a `benchmark` issue; held
+    # until one repoints that import at ops/kv_cache.py
+    resolve_kv_cache_dtype,  # noqa: F401
+    validate_kv_cache_dtype,
+)
 
 
 @dataclass(frozen=True)
@@ -70,8 +63,8 @@ class GPT2Config:
     # sampler scales scores and weights, the generic path dequantises the
     # buffer (ops/attention.py::decode_attention). Training/scoring
     # forwards never touch this — only the sampler's cache buffers.
-    # "auto" resolves per cache shape: int8 below the measured capacity
-    # crossover (INT8_KV_MAX_CAPACITY), bf16 beyond it.
+    # "auto" resolves per cache shape: int8 up to the capacity its read is
+    # measured to (ops/kv_cache.py::INT8_KV_MAX_CAPACITY), bf16 beyond it.
     kv_cache_dtype: str = "bfloat16"  # "bfloat16" | "int8" | "auto"
 
     def __post_init__(self):
@@ -261,162 +254,6 @@ class GPT2Model(nn.Module):
         if capture_hidden_at is not None:
             out["branch_hidden"] = branch_hidden
         return out
-
-
-def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Symmetric int8 quantization over the head dim: per (batch, token,
-    head) absmax/127 scale. Returns (int8 values, scale[..., :1])."""
-    scale = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    scale = jnp.maximum(scale, 1e-8) / 127.0
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127)
-    return q.astype(jnp.int8), scale.astype(jnp.bfloat16)
-
-
-def write_cache(cache_kv, k, v, cache_index, dtype, view_len=None):
-    """Write this call's K/V into the capacity buffers at ``cache_index``;
-    returns ``(k, v, new_kv)`` — the full buffers to attend over and the
-    updated cache dict. The generic arm of
-    ``ops/attention.py::decode_attention`` (prefill, chunked prefill, the
-    verify step, T5, an sp-sharded cache, a paged int8 pool); the one-token
-    steps do not come here — the fixed sampler's write in place and read
-    the stored buffers once, in ``decode_kv_layout``, and the paged
-    engine's read their pool as stored
-    (``kv_cache.py::paged_write_read(as_stored=True)``). Transparent over
-    the three storage layouts (shared by every causal family):
-
-    - plain: ``{"k", "v"}`` in the compute dtype;
-    - int8 (``kv_cache_dtype="int8"``): quantize the new slice, store
-      value+scale, dequantize the whole buffer for attention. On the chip
-      the convert+mul does NOT fold into the attention matmuls' operand
-      read for a one-token query: v5e traces showed each read of a
-      ``[64, 512, 16, 64]`` int8 buffer at 252 us where its bytes take
-      41 (PERF.md §5-§6, PR 23-25) — the reason the decode loop has its
-      own read;
-    - paged (``"block_tables"`` present — the continuous-batching
-      engine's cache, ``inference/kv_cache.py``): writes resolve logical
-      positions through per-slot block tables (``cache_index`` may be a
-      per-slot [B] vector), and what comes back from here is the logical
-      view, gathered; composes with the int8 layout.
-
-    ``view_len`` (static) narrows the RETURNED attention view to the
-    leading ``view_len`` logical positions — ``decode_attention`` derives
-    it from the attention bias width (``ops/attention.py::causal_dispatch``:
-    mask width == view width), so the chunked prefill's prompt-chunk
-    forwards never read (or pay attention FLOPs over) the decode region.
-    ``None``/full-capacity is byte-identical to the unnarrowed program;
-    writes always resolve at full capacity.
-    """
-    if "block_tables" in cache_kv:
-        from trlx_tpu.inference.kv_cache import paged_write_read
-
-        return paged_write_read(
-            cache_kv, k, v, cache_index, dtype, view_len=view_len or 0
-        )
-    at = (0, cache_index, 0, 0)
-    capacity = cache_kv["k"].shape[1]
-    narrow = view_len is not None and 0 < view_len < capacity
-    if "k_scale" in cache_kv:
-        k_q, k_s = quantize_kv(k)
-        v_q, v_s = quantize_kv(v)
-        new_kv = {
-            "k": jax.lax.dynamic_update_slice(cache_kv["k"], k_q, at),
-            "v": jax.lax.dynamic_update_slice(cache_kv["v"], v_q, at),
-            "k_scale": jax.lax.dynamic_update_slice(
-                cache_kv["k_scale"], k_s, at
-            ),
-            "v_scale": jax.lax.dynamic_update_slice(
-                cache_kv["v_scale"], v_s, at
-            ),
-        }
-        k_read = new_kv["k"][:, :view_len] if narrow else new_kv["k"]
-        v_read = new_kv["v"][:, :view_len] if narrow else new_kv["v"]
-        k_s_read = new_kv["k_scale"][:, :view_len] if narrow else new_kv["k_scale"]
-        v_s_read = new_kv["v_scale"][:, :view_len] if narrow else new_kv["v_scale"]
-        k = k_read.astype(dtype) * k_s_read.astype(dtype)
-        v = v_read.astype(dtype) * v_s_read.astype(dtype)
-        return k, v, new_kv
-    k = jax.lax.dynamic_update_slice(cache_kv["k"], k, at)
-    v = jax.lax.dynamic_update_slice(cache_kv["v"], v, at)
-    new_kv = {"k": k, "v": v}
-    if narrow:
-        return k[:, :view_len], v[:, :view_len], new_kv
-    return k, v, new_kv
-
-
-# The int8 KV cache's capacity ceiling under ``kv_cache_dtype="auto"``. It
-# was set from LONGCTX.json (jax 0.4.36): int8 1.10x ahead at capacity 112,
-# ~2x behind at a 2k cache, explained then as "XLA materializes the
-# dequantized buffer". What the v5e traces under jax 0.9.0 showed instead
-# (PERF.md §6, PR 23-25): in the ``kv_buffers`` layout BOTH dtypes read far
-# off their bytes' time — ``Dh = 64`` is half a lane row, so the buffers
-# are padded to twice their size, and the int8 read ran at a sixth of the
-# memory's speed at capacity 512 already. The fixed sampler now decodes
-# from ``ops/attention.py::decode_kv_layout`` (int8 read within ~1.7x of
-# its bytes' time at capacity 512); the threshold stays where the
-# benchmark's configurations state it until a long-context cell measures
-# the new read beyond it. The paged engine reads a floating pool as stored
-# since PR 28; its int8 pool is still gathered and dequantised whole at
-# every step, so int8 under ``rollout.engine: continuous`` is the slow
-# choice at any capacity until that read exists.
-INT8_KV_MAX_CAPACITY = 512
-
-
-def resolve_kv_cache_dtype(kv_cache_dtype: str, capacity: int) -> str:
-    """Resolve ``"auto"`` by cache capacity and warn when an explicit
-    ``"int8"`` is forced past the measured crossover — a long-context
-    config must not silently decode 2x slower (VERDICT r3 #6)."""
-    if kv_cache_dtype == "auto":
-        return "int8" if capacity <= INT8_KV_MAX_CAPACITY else "bfloat16"
-    if kv_cache_dtype == "int8" and capacity > INT8_KV_MAX_CAPACITY:
-        import warnings
-
-        warnings.warn(
-            f"kv_cache_dtype='int8' with a {capacity}-token cache: measured "
-            f"~2x SLOWER than bfloat16 beyond ~{INT8_KV_MAX_CAPACITY} "
-            "(LONGCTX.json decode, B=8/2k, measured under jax 0.4.36 on "
-            "the generic read); set kv_cache_dtype='auto' to pick the "
-            "faster layout per shape, or 'bfloat16' to silence this"
-        )
-    return kv_cache_dtype
-
-
-def kv_buffers(
-    n_layer: int,
-    batch_size: int,
-    capacity: int,
-    n_head: int,
-    head_dim: int,
-    dtype,
-    kv_cache_dtype: str = "bfloat16",
-) -> Cache:
-    """Per-layer fixed-capacity KV buffers, shared by every causal family.
-    ``"int8"`` stores int8 values + per (token, head) bf16 scales — ~half
-    the HBM traffic of a bf16 cache (`write_cache` handles both);
-    ``"auto"`` picks int8 only below the measured capacity crossover."""
-    shape = (batch_size, capacity, n_head, head_dim)
-    kv_cache_dtype = resolve_kv_cache_dtype(kv_cache_dtype, capacity)
-    if kv_cache_dtype == "int8":
-        sshape = (batch_size, capacity, n_head, 1)
-        return tuple(
-            {
-                "k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(sshape, jnp.bfloat16),
-                "v_scale": jnp.zeros(sshape, jnp.bfloat16),
-            }
-            for _ in range(n_layer)
-        )
-    if kv_cache_dtype != "bfloat16":
-        raise ValueError(
-            f"kv_cache_dtype={kv_cache_dtype!r} is not supported (choose "
-            "'bfloat16' or 'int8') — an unrecognized value would otherwise "
-            "silently fall back to bf16 buffers"
-        )
-    return tuple(
-        {"k": jnp.zeros(shape, jnp.dtype(dtype)),
-         "v": jnp.zeros(shape, jnp.dtype(dtype))}
-        for _ in range(n_layer)
-    )
 
 
 def init_cache(config: GPT2Config, batch_size: int, capacity: int) -> Cache:

@@ -39,6 +39,7 @@ from trlx_tpu.ops.attention import (
     dot_product_attention,
     padding_bias,
 )
+from trlx_tpu.ops.kv_cache import kv_buffers, validate_kv_cache_dtype
 
 
 def expand_attention_types(attention_types, n_layer: int) -> Tuple[str, ...]:
@@ -71,13 +72,11 @@ class GPTNeoConfig:
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
     # rollout KV-cache storage ("bfloat16" | "int8" | "auto"); see
-    # models/gpt2.py::write_cache — decode is HBM-bound and the
+    # ops/kv_cache.py — decode is HBM-bound and the
     # cache is its dominant traffic, int8 halves it
     kv_cache_dtype: str = "bfloat16"
 
     def __post_init__(self):
-        from trlx_tpu.models.gpt2 import validate_kv_cache_dtype
-
         validate_kv_cache_dtype(self.kv_cache_dtype)
 
     @property
@@ -317,8 +316,6 @@ class GPTNeoModel(nn.Module):
 
 
 def init_gpt_neo_cache(config: GPTNeoConfig, batch_size: int, capacity: int):
-    from trlx_tpu.models.gpt2 import kv_buffers
-
     return kv_buffers(
         config.num_layers, batch_size, capacity, config.num_heads,
         config.hidden_size // config.num_heads, config.dtype,

@@ -23,6 +23,7 @@ from trlx_tpu.ops.attention import (
     decode_attention,
     dot_product_attention,
 )
+from trlx_tpu.ops.kv_cache import kv_buffers, validate_kv_cache_dtype
 from trlx_tpu.ops.rotary import apply_rotary_interleaved, rotary_angles
 
 
@@ -38,13 +39,11 @@ class GPTJConfig:
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
     # rollout KV-cache storage ("bfloat16" | "int8" | "auto"); see
-    # models/gpt2.py::write_cache — decode is HBM-bound and the
+    # ops/kv_cache.py — decode is HBM-bound and the
     # cache is its dominant traffic, int8 halves it
     kv_cache_dtype: str = "bfloat16"
 
     def __post_init__(self):
-        from trlx_tpu.models.gpt2 import validate_kv_cache_dtype
-
         validate_kv_cache_dtype(self.kv_cache_dtype)
 
     @classmethod
@@ -203,8 +202,6 @@ class GPTJModel(nn.Module):
 
 
 def init_gptj_cache(config: GPTJConfig, batch_size: int, capacity: int):
-    from trlx_tpu.models.gpt2 import kv_buffers
-
     return kv_buffers(
         config.n_layer, batch_size, capacity, config.n_head,
         config.n_embd // config.n_head, config.dtype,

@@ -40,6 +40,7 @@ from trlx_tpu.ops.attention import (
     decode_attention,
     dot_product_attention,
 )
+from trlx_tpu.ops.kv_cache import kv_buffers, validate_kv_cache_dtype
 from trlx_tpu.ops.rotary import apply_rotary_half, rotary_angles
 
 
@@ -67,8 +68,6 @@ class OlmoeConfig:
     kv_cache_dtype: str = "bfloat16"
 
     def __post_init__(self):
-        from trlx_tpu.models.gpt2 import validate_kv_cache_dtype
-
         validate_kv_cache_dtype(self.kv_cache_dtype)
         if self.num_key_value_heads != self.num_attention_heads:
             raise ValueError(
@@ -286,8 +285,6 @@ class OlmoeModel(nn.Module):
 
 
 def init_olmoe_cache(config: OlmoeConfig, batch_size: int, capacity: int):
-    from trlx_tpu.models.gpt2 import kv_buffers
-
     return kv_buffers(
         config.num_hidden_layers, batch_size, capacity,
         config.num_attention_heads,

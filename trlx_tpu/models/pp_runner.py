@@ -53,6 +53,7 @@ from trlx_tpu.ops.attention import (
     combine_biases,
     padding_bias,
 )
+from trlx_tpu.ops.kv_cache import resolve_kv_cache_dtype
 from trlx_tpu.parallel.pipeline import (
     pipeline_apply,
     spmd_stack,
@@ -715,15 +716,15 @@ def pp_init_cache(config, batch_size: int, capacity: int):
     """Layer-major KV buffers for pp decode: ``{"k","v"}: [L, B, C, H, Dh]``
     (vs the GSPMD sampler's per-layer tuple). ``kv_cache_dtype="int8"``
     composes: value+scale leaves, stage-sliced and microbatch-sliced like
-    any other cache leaf (`write_cache` keys on the ``k_scale`` entry, so
-    the per-layer dict the stage scan hands to the block is already in the
-    quantized layout)."""
+    any other cache leaf (``ops/kv_cache.py::cache_kind`` keys on the
+    ``k_scale`` entry, so the per-layer dict the stage scan hands to the
+    block is already in the quantized layout). The layer-major allocation
+    is this file's own; folding it into ``ops/kv_cache.py`` is a named debt
+    (ROADMAP.md)."""
     L = num_layers_of(config)
     H = n_heads_of(config)
     head_dim = hidden_size_of(config) // H
     shape = (L, batch_size, capacity, H, head_dim)
-    from trlx_tpu.models.gpt2 import resolve_kv_cache_dtype
-
     kv_dtype = resolve_kv_cache_dtype(
         getattr(config, "kv_cache_dtype", "bfloat16"), capacity
     )

@@ -12,12 +12,15 @@ models never branch on Python-level conditions inside jit.
 Softmax runs in float32 regardless of compute dtype (bf16 logits lose
 ~3 decimal digits; the MXU matmuls stay bf16 where the FLOPs are).
 
-Attention over a KV cache goes through :func:`decode_attention`: the fixed
+Attention over a KV cache goes through :func:`decode_attention`, which asks
+``ops/kv_cache.py::cache_kind`` what storage it was handed: the fixed
 sampler's one-token steps read each layer's buffers once, in the lane-dense
-layout :func:`decode_kv_layout` gives them, and write the new position in
+layout ``decode_kv_layout`` gives them, and write the new position in
 place; the paged engine's one-token steps read each layer's pool once, as
 it is stored; every other cached call (prefill, chunked prefill, the verify
-step) is ``write_cache`` + :func:`dot_product_attention`.
+step) is ``dense_write_read`` / ``paged_write_read`` +
+:func:`dot_product_attention`. How a cache is stored and written is
+``ops/kv_cache.py``'s; the reads here are the math.
 """
 
 from __future__ import annotations
@@ -29,6 +32,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from trlx_tpu.ops.kv_cache import (
+    FOLDED,
+    PAGED,
+    cache_kind,
+    dense_write_read,
+    paged_write_read,
+    quantize_kv,
+    reads_as_stored,
+    stored_order_bias,
+)
 from trlx_tpu.telemetry import get_metrics
 
 NEG_INF = -1e9  # large-negative mask value; avoids -inf NaN propagation in softmax
@@ -93,7 +106,7 @@ def causal_dispatch(
     With a cache, the MASK WIDTH is the attention view width: a caller
     that passes a validity mask narrower than the cache capacity attends
     over only the leading ``mask.shape[-1]`` logical positions
-    (``models/gpt2.py::write_cache`` narrows the returned K/V view to the
+    (``ops/kv_cache.py``'s writes narrow the returned K/V view to the
     bias width). Every full-capacity caller is unchanged — the narrowed
     view is the chunked-prefill contract (docs/inference.md): prompt
     chunks never attend the decode region, whose masked columns carry
@@ -211,34 +224,8 @@ def dot_product_attention(
     return out.astype(q.dtype)
 
 
-def decode_kv_layout(cache):
-    """The dense cache in the layout the decode loop carries: heads folded
-    into the minor axis — ``k``/``v`` ``[..., C, H, Dh] -> [..., C, H*Dh]``,
-    int8 scales ``[..., C, H, 1] -> [..., H, C]``.
-
-    Why another layout: on the TPU a ``[B, C, H, Dh]`` buffer is tiled over
-    its two minor axes, and ``Dh = 64`` fills half of a 128-lane row — the
-    compiler pads it, so a gpt2-sized buffer takes (and every decode step
-    reads) twice its bytes; the ``[B, C, H, 1]`` scales take 128x theirs.
-    Folded, both are lane-dense. The prefill writes the ``kv_buffers``
-    layout and the sampler converts once, before its loop
-    (``ops/sampling.py::make_sampler``); :func:`decode_attention` keys on
-    the rank of ``k``. ``cache`` is one layer's dict, a tuple of them, or
-    the pp sampler's layer-major dict (leading ``L`` axis).
-    """
-    if not isinstance(cache, dict):
-        return tuple(decode_kv_layout(c) for c in cache)
-    out = {}
-    for name, a in cache.items():
-        if name.endswith("_scale"):
-            out[name] = jnp.swapaxes(a[..., 0], -1, -2)
-        else:
-            out[name] = a.reshape(*a.shape[:-2], a.shape[-2] * a.shape[-1])
-    return out
-
-
 def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias):
-    """One new position over a cache in :func:`decode_kv_layout`: write it in
+    """One new position over a cache in ``decode_kv_layout``: write it in
     place, then read K and V once each, as stored.
 
     Per-head products run on the MXU against the folded ``[C, H*Dh]`` buffer:
@@ -252,11 +239,9 @@ def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias):
     Products accumulate in float32 and the softmax is float32, as in
     :func:`dot_product_attention`.
     """
-    from trlx_tpu.models.gpt2 import quantize_kv
-
     B, _, H, Dh = q.shape
     HD = H * Dh
-    quantized = "k_scale" in cache_kv
+    quantized = cache_kind(cache_kv).quantized
     new_kv = {}
     for name, new in (("k", k_new), ("v", v_new)):
         if quantized:
@@ -316,10 +301,11 @@ def decode_attention(
     and attend over the cache; returns ``(out [B, Q, H, D], new_kv)``. The
     one cached-attention entry of every family.
 
-    Dispatch is on what the call shows, at trace time (counted per traced
-    call site in ``attention/decode_path{path=...}``):
+    Dispatch is on what the call shows, at trace time: the cache's kind
+    (``ops/kv_cache.py::cache_kind``) and the call's shapes (counted per
+    traced call site in ``attention/decode_path{path=...}``):
 
-    - ``fused`` — the cache is in :func:`decode_kv_layout` (the fixed
+    - ``fused`` — the cache is in ``decode_kv_layout`` (the fixed
       sampler's decode loop): :func:`_decode_read`. Such a cache takes one
       position a call under a bias broadcast over heads; anything else is
       refused, not rerouted;
@@ -328,27 +314,22 @@ def decode_attention(
       the rows are scattered in place and :func:`dot_product_attention`
       reads the pools as stored, in each slot's physical order, under the
       bias re-indexed to that order. No logical view is gathered;
-    - ``generic`` — everything else, unchanged: ``write_cache`` (dense
-      ``kv_buffers`` layout or paged) returns the view the bias was built
-      for and :func:`dot_product_attention` reads it. Prefill and chunked
+    - ``generic`` — everything else, unchanged: ``dense_write_read`` or
+      ``paged_write_read`` returns the view the bias was built for and
+      :func:`dot_product_attention` reads it. Prefill and chunked
       prefill, the verify step, T5's learned per-head bias, a cache whose
       capacity axis is sharded (the sampler leaves those in the
       ``kv_buffers`` layout), a paged int8 pool and a paged pool with a
       shared-prefix overlay.
     """
+    kind = cache_kind(cache_kv)
     # attend over the buffer VIEW the bias was built for: a bias narrower
     # than capacity (the chunked prefill's prompt-only mask) narrows the
-    # view to match
-    from trlx_tpu.inference.kv_cache import (
-        paged_write_read,
-        reads_as_stored,
-        stored_order_bias,
-    )
-
-    view_len = bias.shape[-1] if bias is not None else None
-    fused = "block_tables" not in cache_kv and cache_kv["k"].ndim == 3
+    # view to match (0 = the whole capacity)
+    view_len = bias.shape[-1] if bias is not None else 0
+    fused = kind.layout == FOLDED
     paged = (
-        "block_tables" in cache_kv
+        kind.layout == PAGED
         and bias is not None
         and not learned_bias
         and not causal
@@ -380,9 +361,8 @@ def decode_attention(
         # device-trace scope names are a contract (docs/observability.md)
         with jax.named_scope("decode_attention"):
             return _decode_read(q, k_new, v_new, cache_kv, cache_index, bias)
-    from trlx_tpu.models.gpt2 import write_cache
-
-    k, v, new_kv = write_cache(
+    write = paged_write_read if kind.layout == PAGED else dense_write_read
+    k, v, new_kv = write(
         cache_kv, k_new, v_new, cache_index, q.dtype, view_len=view_len
     )
     out = dot_product_attention(

@@ -1,11 +1,42 @@
-"""Paged/block KV cache for the continuous-batching engine.
+"""The KV cache: how it is stored, written, classified and handed to
+attention. The one module that knows; it imports nothing from the models,
+the inference engine, the serving tier or the trainers.
+
+    ops/kv_cache.py  <-  ops/attention.py  <-  models/*  <-  inference/engine.py
+
+A cache is a tuple over layers of plain dicts (the engine donates them,
+``models/pp_runner.py`` and the analysis harness trace them, checkpoints do
+not hold them). Which storage a layer's dict is, :func:`cache_kind` reads
+from its keys and ranks — the one place that does:
+
+- **dense** (:func:`kv_buffers`): ``{"k", "v"}`` ``[B, C, H, Dh]`` in the
+  compute dtype; what every family's ``init_cache`` allocates and every
+  prefill writes. :func:`dense_write_read` writes a call's rows at
+  ``cache_index`` and returns the buffers to attend over.
+- **folded** (:func:`decode_kv_layout`): the dense cache with heads folded
+  into the minor axis, ``[B, C, H*Dh]``; the layout the fixed sampler's
+  decode loop carries. Its in-place write and single read are attention
+  math and live in ``ops/attention.py::_decode_read``.
+- **paged** (:func:`init_paged_cache`): the dense pools plus
+  ``"block_tables"``; the continuous-batching engine's cache.
+  :func:`paged_write_read` writes through the tables and returns either the
+  logical view or the pools as stored.
+
+Each composes with **int8** storage (``"k_scale"``/``"v_scale"`` present:
+values quantised per (position, head) by :func:`quantize_kv`, bf16 scales;
+``kv_cache_dtype``, resolved by :func:`resolve_kv_cache_dtype`), and a paged
+cache may carry a **shared-prefix overlay** (``"shared_tables"`` present).
+``ops/attention.py::decode_attention`` picks the read from the kind.
+
+Paging
+------
 
 vLLM-style paging adapted to the TPU/GSPMD substrate: physical storage
 keeps the fixed sampler's ``[B, capacity, heads, head_dim]`` per-layer
 buffers (so the batch axis shards over dp×fsdp exactly like the fixed
-cache, and an ``sp`` mesh axis shards the capacity axis per the
-LONGCTX.json sp-sharded-cache row), while a per-slot **block table**
-indirects logical token positions through fixed-size blocks:
+cache, and an ``sp`` mesh axis shards the capacity axis), while a per-slot
+**block table** indirects logical token positions through fixed-size
+blocks:
 
 - physical layout: capacity = ``n_blocks * block_size`` contiguous
   positions per slot; block ``j`` of slot ``b`` is positions
@@ -30,13 +61,12 @@ indirects logical token positions through fixed-size blocks:
   128 pool (the compiler converted it to float32, whole, on top of the
   gather); the stored read costs 0.31 (PERF.md §5-§6, PR 28).
 
-``kv_cache_dtype`` is honored exactly as in the linear cache
-(``models/gpt2.py::kv_buffers``): ``int8`` stores quantized values +
-per-(position, head) bf16 scales and dequantizes on read — the same
-absmax/127 quantizer, so int8 paged and int8 linear caches hold
-identical bits per logical position. An int8 pool is always read through
-the dequantised logical view (its ``[B, C, H, 1]`` scales are no layout
-to read in place).
+``kv_cache_dtype`` is honored exactly as in the dense cache: ``int8``
+stores quantized values + per-(position, head) bf16 scales and dequantizes
+on read — the same absmax/127 quantizer, so int8 paged and int8 dense
+caches hold identical bits per logical position. An int8 pool is always
+read through the dequantised logical view (its ``[B, C, H, 1]`` scales are
+no layout to read in place).
 
 Why per-slot block regions instead of one global pool: a single shared
 pool would put every slot's blocks behind one un-sharded physical axis,
@@ -81,10 +111,222 @@ one).
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+import warnings
+from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+
+# KV cache: tuple over layers of {"k": [B, C, H, Dh], "v": [B, C, H, Dh]}
+Cache = Tuple[Dict[str, jax.Array], ...]
+
+
+# ----------------------------- storage dtype ----------------------------- #
+
+VALID_KV_CACHE_DTYPES = ("bfloat16", "int8", "auto")
+
+
+def validate_kv_cache_dtype(value: str) -> None:
+    """Shared __post_init__ validation for every causal family config."""
+    if value not in VALID_KV_CACHE_DTYPES:
+        raise ValueError(
+            f"kv_cache_dtype={value!r} is not supported (choose one of "
+            f"{VALID_KV_CACHE_DTYPES}) — an unrecognized value would "
+            "otherwise silently fall back to bf16 buffers"
+        )
+
+
+# The int8 KV cache's capacity ceiling under ``kv_cache_dtype="auto"``: the
+# largest capacity at which the int8 read has been measured on the chip.
+# The fixed sampler decodes from :func:`decode_kv_layout`, where the int8
+# read runs within ~1.7x of its bytes' time at capacity 512 (cell
+# ``ppo-gpt2m-longgen``, PERF.md §5-§6, PR 25); nothing beyond 512 is on
+# record, so the threshold stays where the benchmark's configurations state
+# it until a long-context cell measures past it. The paged engine reads a
+# floating pool as stored since PR 28; its int8 pool is still gathered and
+# dequantised whole at every step, so int8 under ``rollout.engine:
+# continuous`` is the slow choice at any capacity until that read exists.
+INT8_KV_MAX_CAPACITY = 512
+
+
+def resolve_kv_cache_dtype(kv_cache_dtype: str, capacity: int) -> str:
+    """Resolve ``"auto"`` by cache capacity and warn when an explicit
+    ``"int8"`` is forced past the capacity it is measured to — a
+    long-context config must not silently take an unmeasured read."""
+    if kv_cache_dtype == "auto":
+        return "int8" if capacity <= INT8_KV_MAX_CAPACITY else "bfloat16"
+    if kv_cache_dtype == "int8" and capacity > INT8_KV_MAX_CAPACITY:
+        warnings.warn(
+            f"kv_cache_dtype='int8' with a {capacity}-token cache: the fused "
+            f"int8 read is measured only up to capacity "
+            f"{INT8_KV_MAX_CAPACITY} (PERF.md, cell ppo-gpt2m-longgen) and "
+            "nothing is on record beyond it; the paged engine's int8 read "
+            "still gathers and dequantises the whole view a step. Set "
+            "kv_cache_dtype='auto' to take int8 only where it is measured, "
+            "or 'bfloat16' to silence this"
+        )
+    return kv_cache_dtype
+
+
+def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Symmetric int8 quantization over the head dim: per (batch, token,
+    head) absmax/127 scale. Returns (int8 values, scale[..., :1])."""
+    scale = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
+    scale = jnp.maximum(scale, 1e-8) / 127.0
+    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127)
+    return q.astype(jnp.int8), scale.astype(jnp.bfloat16)
+
+
+# ------------------------------ dense, folded ---------------------------- #
+
+
+def kv_buffers(
+    n_layer: int,
+    batch_size: int,
+    capacity: int,
+    n_head: int,
+    head_dim: int,
+    dtype,
+    kv_cache_dtype: str = "bfloat16",
+) -> Cache:
+    """Per-layer fixed-capacity KV buffers, shared by every causal family.
+    ``"int8"`` stores int8 values + per (token, head) bf16 scales — ~half
+    the HBM traffic of a bf16 cache (every write and read here handles
+    both); ``"auto"`` picks int8 only up to the capacity it is measured
+    to."""
+    shape = (batch_size, capacity, n_head, head_dim)
+    kv_cache_dtype = resolve_kv_cache_dtype(kv_cache_dtype, capacity)
+    if kv_cache_dtype == "int8":
+        sshape = (batch_size, capacity, n_head, 1)
+        return tuple(
+            {
+                "k": jnp.zeros(shape, jnp.int8),
+                "v": jnp.zeros(shape, jnp.int8),
+                "k_scale": jnp.zeros(sshape, jnp.bfloat16),
+                "v_scale": jnp.zeros(sshape, jnp.bfloat16),
+            }
+            for _ in range(n_layer)
+        )
+    if kv_cache_dtype != "bfloat16":
+        raise ValueError(
+            f"kv_cache_dtype={kv_cache_dtype!r} is not supported (choose "
+            "'bfloat16' or 'int8') — an unrecognized value would otherwise "
+            "silently fall back to bf16 buffers"
+        )
+    return tuple(
+        {"k": jnp.zeros(shape, jnp.dtype(dtype)),
+         "v": jnp.zeros(shape, jnp.dtype(dtype))}
+        for _ in range(n_layer)
+    )
+
+
+def decode_kv_layout(cache):
+    """The dense cache in the layout the decode loop carries: heads folded
+    into the minor axis — ``k``/``v`` ``[..., C, H, Dh] -> [..., C, H*Dh]``,
+    int8 scales ``[..., C, H, 1] -> [..., H, C]``.
+
+    Why another layout: on the TPU a ``[B, C, H, Dh]`` buffer is tiled over
+    its two minor axes, and ``Dh = 64`` fills half of a 128-lane row — the
+    compiler pads it, so a gpt2-sized buffer takes (and every decode step
+    reads) twice its bytes; the ``[B, C, H, 1]`` scales take 128x theirs.
+    Folded, both are lane-dense. The prefill writes the ``kv_buffers``
+    layout and the sampler converts once, before its loop
+    (``ops/sampling.py::make_sampler``); :func:`cache_kind` keys on the rank
+    of ``k``. ``cache`` is one layer's dict, a tuple of them, or the pp
+    sampler's layer-major dict (leading ``L`` axis).
+    """
+    if not isinstance(cache, dict):
+        return tuple(decode_kv_layout(c) for c in cache)
+    out = {}
+    for name, a in cache.items():
+        if name.endswith("_scale"):
+            out[name] = jnp.swapaxes(a[..., 0], -1, -2)
+        else:
+            out[name] = a.reshape(*a.shape[:-2], a.shape[-2] * a.shape[-1])
+    return out
+
+
+DENSE, FOLDED, PAGED = "dense", "folded", "paged"
+
+
+class CacheKind(NamedTuple):
+    """What storage one layer's cache dict is (:func:`cache_kind`)."""
+
+    layout: str  # DENSE | FOLDED | PAGED
+    quantized: bool  # int8 values + bf16 scales
+    shared: bool  # a paged cache with a shared-prefix overlay
+
+
+def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
+    """Classify one layer's cache dict, at trace time, from the keys it
+    carries and the rank of ``k`` — every reader of "which storage is
+    this" asks here."""
+    if "block_tables" in cache_kv:
+        layout = PAGED
+    elif cache_kv["k"].ndim == 3:
+        layout = FOLDED
+    else:
+        layout = DENSE
+    return CacheKind(
+        layout, "k_scale" in cache_kv, "shared_tables" in cache_kv
+    )
+
+
+def dense_write_read(cache_kv, k, v, cache_index, dtype, view_len: int = 0):
+    """Write this call's K/V into the dense capacity buffers at
+    ``cache_index``; returns ``(k, v, new_kv)`` — the full buffers to attend
+    over and the updated cache dict. The dense half of the generic arm of
+    ``ops/attention.py::decode_attention`` (the fixed sampler's prefill,
+    T5, an sp-sharded cache; :func:`paged_write_read` is the paged half).
+    The one-token steps do not come here — the fixed sampler's write in
+    place and read the stored buffers once, in :func:`decode_kv_layout`.
+
+    - floating: ``{"k", "v"}`` in the compute dtype;
+    - int8: quantize the new slice, store value+scale, dequantize the
+      whole buffer for attention. On the chip the convert+mul does NOT
+      fold into the attention matmuls' operand read for a one-token
+      query: v5e traces showed each read of a ``[64, 512, 16, 64]`` int8
+      buffer at 252 us where its bytes take 41 (PERF.md §5-§6, PR 23-25)
+      — the reason the decode loop has its own read.
+
+    ``view_len`` (static, ``0`` = all) narrows the RETURNED attention view
+    to the leading ``view_len`` positions — ``decode_attention`` derives it
+    from the attention bias width (``ops/attention.py::causal_dispatch``:
+    mask width == view width). Full capacity is byte-identical to the
+    unnarrowed program; writes always resolve at full capacity.
+    """
+    at = (0, cache_index, 0, 0)
+    capacity = cache_kv["k"].shape[1]
+    narrow = 0 < view_len < capacity
+    if cache_kind(cache_kv).quantized:
+        k_q, k_s = quantize_kv(k)
+        v_q, v_s = quantize_kv(v)
+        new_kv = {
+            "k": jax.lax.dynamic_update_slice(cache_kv["k"], k_q, at),
+            "v": jax.lax.dynamic_update_slice(cache_kv["v"], v_q, at),
+            "k_scale": jax.lax.dynamic_update_slice(
+                cache_kv["k_scale"], k_s, at
+            ),
+            "v_scale": jax.lax.dynamic_update_slice(
+                cache_kv["v_scale"], v_s, at
+            ),
+        }
+        k_read = new_kv["k"][:, :view_len] if narrow else new_kv["k"]
+        v_read = new_kv["v"][:, :view_len] if narrow else new_kv["v"]
+        k_s_read = new_kv["k_scale"][:, :view_len] if narrow else new_kv["k_scale"]
+        v_s_read = new_kv["v_scale"][:, :view_len] if narrow else new_kv["v_scale"]
+        k = k_read.astype(dtype) * k_s_read.astype(dtype)
+        v = v_read.astype(dtype) * v_s_read.astype(dtype)
+        return k, v, new_kv
+    k = jax.lax.dynamic_update_slice(cache_kv["k"], k, at)
+    v = jax.lax.dynamic_update_slice(cache_kv["v"], v, at)
+    new_kv = {"k": k, "v": v}
+    if narrow:
+        return k[:, :view_len], v[:, :view_len], new_kv
+    return k, v, new_kv
+
+
+# --------------------------------- paged --------------------------------- #
 
 
 def choose_block_size(capacity: int, requested: int) -> int:
@@ -168,14 +410,11 @@ def init_paged_cache(
 ) -> Tuple[Dict[str, jax.Array], ...]:
     """Per-layer paged KV buffers + shared block tables.
 
-    Layer dicts carry the physical pools under the linear cache's key
+    Layer dicts carry the physical pools under the dense cache's key
     names ("k"/"v" [+ scales]) plus "block_tables" — the presence of
-    that key is what routes ``models/gpt2.py::write_cache`` onto the
-    paged write/read path, so every causal family decodes through the
-    paged cache with no model changes.
+    that key is what :func:`cache_kind` calls paged, so every causal
+    family decodes through the paged cache with no model changes.
     """
-    from trlx_tpu.models.gpt2 import kv_buffers
-
     bs = choose_block_size(capacity, block_size)
     n_blocks = capacity // bs
     tables = identity_block_tables(n_slots, n_blocks)
@@ -204,7 +443,7 @@ def init_shared_pool(
 ) -> Dict[str, jax.Array]:
     """Per-layer shared-prefix pool buffers: ``pool_blocks * block_size``
     flat positions in the private regions' storage layout (int8 pools
-    carry scales exactly like the int8 linear cache)."""
+    carry scales exactly like the int8 dense cache)."""
     if pool_blocks < 1:
         raise ValueError(
             f"prefix pool needs >= 1 block, got {pool_blocks}"
@@ -271,12 +510,13 @@ def reads_as_stored(cache_kv: Dict[str, jax.Array], k: jax.Array,
     pool read at full width, and no shared-prefix overlay. Decided on what
     the call shows, at trace time; everything else reads the logical
     view."""
+    kind = cache_kind(cache_kv)
     capacity = cache_kv["k"].shape[1]
     return (
         k.shape[1] == 1
         and jnp.ndim(cache_index) <= 1
-        and "k_scale" not in cache_kv
-        and "shared_tables" not in cache_kv
+        and not kind.quantized
+        and not kind.shared
         and not 0 < view_len < capacity
     )
 
@@ -365,9 +605,9 @@ def paged_write_read(
     view_len: int = 0,
     as_stored: bool = False,
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
-    """Paged counterpart of the linear ``write_cache`` arm: write the new
-    K/V rows through the block table, then return the buffers to attend
-    over (plus the updated cache dict).
+    """Paged counterpart of :func:`dense_write_read`: write the new K/V
+    rows through the block table, then return the buffers to attend over
+    (plus the updated cache dict).
 
     The write is one scatter of the call's rows, in place in the donated
     pool whatever its dtype (on the v5e a bf16 scatter of 32 rows into an
@@ -394,7 +634,7 @@ def paged_write_read(
     parks the rest at ``capacity`` (the same OOB-drop sentinel idle
     slots use, applied per column instead of per row). int8 pools
     quantize on write and dequantize the gathered view — same bits as
-    the linear int8 path per logical position.
+    the dense int8 path per logical position.
 
     ``view_len > 0`` narrows the returned logical view (and the shared
     overlay) to the leading ``view_len`` positions — chunk-granular
@@ -407,6 +647,7 @@ def paged_write_read(
             "as_stored serves one position a slot into a floating pool read "
             "at full width, without a shared-prefix overlay (reads_as_stored)"
         )
+    kind = cache_kind(cache_kv)
     B, T = k.shape[0], k.shape[1]
     capacity = cache_kv["k"].shape[1]
     tables = cache_kv["block_tables"]
@@ -423,7 +664,7 @@ def paged_write_read(
     if 0 < view_len < capacity:
         view = view[:, :view_len]
 
-    sharing = "shared_tables" in cache_kv
+    sharing = kind.shared
     pub_pos = None
     if sharing:
         shared_tables = cache_kv["shared_tables"]
@@ -475,9 +716,7 @@ def paged_write_read(
             new_kv["publish_tables"] = cache_kv["publish_tables"]
         return new_kv
 
-    if "k_scale" in cache_kv:
-        from trlx_tpu.models.gpt2 import quantize_kv
-
+    if kind.quantized:
         k_q, k_s = quantize_kv(k)
         v_q, v_s = quantize_kv(v)
         new_kv = carry({

@@ -30,7 +30,7 @@ import flax.struct as struct
 import jax
 import jax.numpy as jnp
 
-from trlx_tpu.ops.attention import decode_kv_layout
+from trlx_tpu.ops.kv_cache import decode_kv_layout
 from trlx_tpu.utils import topk_mask
 
 
@@ -394,7 +394,7 @@ def make_sampler(
     buffers and re-pinned on each step's updated cache so the constraint
     sticks through the loop carry. A cache whose capacity axis is sharded
     stays in the ``kv_buffers`` layout and decodes through the generic read;
-    every other is carried in ``ops/attention.py::decode_kv_layout``.
+    every other is carried in ``ops/kv_cache.py::decode_kv_layout``.
     """
     Q = query_length
     R = gen_config.max_new_tokens

@@ -11,7 +11,7 @@ The request-level half of the ROADMAP "millions of users" direction
 - :mod:`trlx_tpu.serving.prefix_cache` — host-side radix trie +
   refcounted shared-block pool: requests with a common prompt prefix
   map their leading KV blocks onto the same published pool blocks
-  (``inference/kv_cache.py`` shared-pool layout; read-only sharing,
+  (``ops/kv_cache.py`` shared-pool layout; read-only sharing,
   copy-on-divergence at block granularity);
 - :mod:`trlx_tpu.serving.streaming` — per-request bounded token queues
   fed by the engine's per-decode-step tap, so a ``stream=True`` submit

@@ -3,7 +3,7 @@
 The allocator behind cross-request prefix/KV reuse (docs/serving.md).
 Device storage is the per-layer ``shared_k``/``shared_v`` pool the
 engine carries when built with ``prefix_pool_blocks > 0``
-(``inference/kv_cache.py``); this module decides **which** pool block
+(``ops/kv_cache.py``); this module decides **which** pool block
 holds **which** prefix content, and for every admitted request builds
 the per-row ``shared_map`` / ``publish_map`` the prefill consumes.
 

@@ -170,7 +170,7 @@ def warn_on_span_drops(tracer: Optional[Tracer] = None) -> int:
     when it is nonzero. Silent ring evictions skew every per-name p50
     (the oldest — often slowest, compile-bearing — spans vanish first),
     so any consumer aggregating span stats for a report should surface
-    this; bench.py ships the count in its payload and calls this."""
+    this; the serving CLI (``inference/__main__.py``) calls it."""
     global _drops_warned
     t = tracer if tracer is not None else get_tracer()
     dropped = int(t.dropped)

@@ -4,9 +4,9 @@ The measurement layer already holds both halves of "where did the time
 go": engine 7 (``analysis/resource_audit.py``) counts each traced
 program's exact matmul FLOPs and boundary bytes *statically*, and the
 span tracer measures what every phase region actually took. Nothing
-joined them — train MFU 0.299 / collect 0.157 were whole-phase numbers
-hand-derived in bench.py, with no per-program breakdown and no
-accounting of the async schedule's bubbles. This module is the join:
+joined them inside the program: whole-phase utilization came from outside
+(today ``benchmark/``'s ``phase_mfu``), with no per-program breakdown and
+no accounting of the async schedule's bubbles. This module is the join:
 
 - :func:`attribute` — for each (traced program, span) pair in a work
   map, ``measured utilization = static work × fires ÷ (span wall ×
@@ -15,7 +15,7 @@ accounting of the async schedule's bubbles. This module is the join:
   byte side is the program's boundary traffic floor (sharded input
   bytes + output bytes — the program must at least read its inputs and
   write its outputs; fused internals are uncounted, so the utilization
-  is a lower bound exactly like bench's roofline denominators).
+  is a lower bound).
 - :func:`bubble_breakdown` — the async schedule's idle attribution
   (learner drain, version-lag guard hold, admission bookkeeping,
   learner idle) as per-phase milliseconds and fractions of the phase
@@ -25,8 +25,9 @@ accounting of the async schedule's bubbles. This module is the join:
   wall (collect + train + eval + checkpoint spans), the end-to-end
   number utilization percentages tend to flatter.
 
-Device peaks are the published per-chip specs — the one table bench,
-``chip_smoke.py`` and the attribution rows all read. A ``device_kind``
+Device peaks are the published per-chip specs — the table ``chip_smoke.py``
+and the attribution rows read (``benchmark/peaks.json`` is the
+benchmark's own copy; ROADMAP.md names the pair as a debt). A ``device_kind``
 that is not in it (the CPU included) is an error, never a default: a
 utilization priced off an assumed peak is not a device number.
 
@@ -34,8 +35,7 @@ Everything here is host-side arithmetic over dicts the caller already
 holds; nothing traces, compiles, or touches devices except
 :func:`require_tpu` (the device gate: it asks jax what it found) and
 :func:`trainer_program_resources`, which re-traces (tracing only, no
-compilation — the engine-7 pattern bench already pays for the train
-step) a LIVE trainer's programs at the real workload shape.
+compilation — the engine-7 pattern) a LIVE trainer's programs at the real workload shape.
 """
 
 from __future__ import annotations
@@ -43,8 +43,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-# Published bf16 peak per chip by device_kind (dense, no sparsity) —
-# the single source bench.py imports.
+# Published bf16 peak per chip by device_kind (dense, no sparsity).
 BF16_PEAK_TFLOPS = {
     "TPU v3": 123.0,
     "TPU v4": 275.0,
@@ -77,8 +76,8 @@ def device_peaks(device_kind: str) -> Tuple[float, float]:
 
 
 def require_tpu() -> Dict[str, Any]:
-    """The device gate of every measurement path (``bench.py``,
-    ``chip_smoke.py``): jax must find a TPU whose ``device_kind`` has
+    """The device gate of the program's own measurement path
+    (``chip_smoke.py``): jax must find a TPU whose ``device_kind`` has
     published peaks, or this raises — a measurement path never falls back
     to the CPU. Returns the device as jax reports it."""
     import jax

@@ -1,19 +1,20 @@
 """Run ledger: one manifest per run, a JSONL to diff them against.
 
-The repo's measurement artifacts are latest-per-key JSON files
-(``AB_*.json``, ``BENCH_rNN.json``) — good for "the current number",
-useless for *mechanical* run-over-run comparison: nothing in-tree could
-answer "what moved between yesterday's bench and today's" without a
-human eyeballing two JSON blobs. The ledger closes that:
+A run's numbers printed once are useless for *mechanical* run-over-run
+comparison: nothing could answer "what moved between yesterday's run and
+today's" without a human eyeballing two JSON blobs. The ledger closes
+that (for the program's own runs; the benchmark's record across PRs is
+``PERF_LEDGER.jsonl``, which the driver writes — ROADMAP.md names the two
+ledgers as a debt):
 
 - :func:`build_manifest` — a :class:`RunManifest`-shaped dict capturing
   everything a later diff needs: config fingerprint, platform, git sha,
   span stats, the metrics-registry snapshot, health-event counts, the
-  attribution table, and the producer's free-form payload (the BENCH
-  record, an A/B record, a learn() summary).
+  attribution table, and the producer's free-form payload (a smoke's
+  record, a learn() summary).
 - :func:`append_manifest` — append it as one JSONL line to the ledger
   (``TRLX_RUN_LEDGER`` env, or an explicit path). Append-only: the
-  ledger is history, the AB artifacts stay the latest-per-key view.
+  ledger is history.
 - ``python -m trlx_tpu.telemetry --compare <run_a> <run_b>`` — resolve
   two runs (by run_id, ledger index, or manifest file path) and render
   the regression diff: numeric movers ranked by relative delta, span
@@ -124,8 +125,8 @@ def build_manifest(
 def numeric_payload(record: Dict[str, Any]) -> Dict[str, Any]:
     """The ledger-payload projection of a producer's record: plain
     numeric scalars only (bools excluded — they are flags, not
-    measurements). One definition for every producer (bench, the A/B
-    harnesses, the smoke, the learn() epilogue), so a change to the
+    measurements). One definition for every producer (the smoke, the
+    learn() epilogue), so a change to the
     filtering rule lands everywhere at once."""
     return {
         k: float(v)
@@ -214,26 +215,6 @@ def resolve_run(
         "a run_id, ~1/~2/last/prev back-references, an integer index, "
         "or a manifest path)"
     )
-
-
-def append_ab_manifest(kind: str, record: Dict[str, Any]) -> Optional[str]:
-    """The A/B-harness recording path (``ab_*.py``): the latest-per-key
-    artifact (``utils/ab_record.py``) stays the current-number view;
-    this ALSO appends the measurement to the run ledger as history, so
-    ``--compare`` can diff any two A/B rounds. Numeric payload only;
-    best-effort (returns None on failure — a ledger hiccup must not
-    fail a measurement that already printed)."""
-    try:
-        flat: Dict[str, Any] = numeric_payload(record)
-        flat["metric"] = record.get("metric", "")
-        return append_manifest(build_manifest(kind, payload=flat))
-    except Exception as e:
-        print(
-            f"run_ledger: A/B manifest append failed "
-            f"({type(e).__name__}: {e})",
-            file=sys.stderr,
-        )
-        return None
 
 
 # -------------------------------- compare --------------------------------- #

@@ -124,8 +124,9 @@ def scale_by_adam_low_precision(
     (step, leaf) — bitwise reproducible, no RNG state to checkpoint.
 
     Halves the optimizer's per-step HBM traffic (m+v read+write is ~8B/param
-    at f32 — measured ~24% of the bench train step) and its resident bytes
-    (the `test_neox20b_sharding.py` budget for the 20B stretch)."""
+    at f32; its share of a train step is not measured on the chip) and its
+    resident bytes (the `test_neox20b_sharding.py` budget for the 20B
+    stretch)."""
     moment_dtype = jnp.dtype(moment_dtype)
 
     def init_fn(params):
@@ -155,8 +156,8 @@ def scale_by_adam_low_precision(
         )
         # rbg keys: XLA's RngBitGenerator is ~3x cheaper than threefry for
         # the 2N uint32 draws a full-model SR store needs — with threefry
-        # the RNG cost exceeded the halved-moment traffic saving (measured
-        # +120ms vs -40ms per 32-step phase at the bench shape)
+        # the RNG cost exceeded the halved-moment traffic saving (a
+        # pre-chip reading; not measured on the chip)
         # the literal seed is the CONTRACT here: stochastic rounding must
         # be bitwise reproducible per (step, leaf) with no RNG state to
         # checkpoint — it is noise injection, not statistical sampling

@@ -1055,9 +1055,9 @@ class PPOTrainer(BaseRLTrainer):
             GAE/whitening is params-INDEPENDENT, so it is hoisted out of
             the scan and computed for every minibatch in one batched pass:
             inside the scan it was a fresh R-step sequential chain per
-            update — measured ~5 ms each (latency-, not compute-bound;
-            bench_train_audit.py) — i.e. ~29% of the faithful workload's
-            17 ms train step. vmap turns the 32 sequential chains into one
+            update, latency- and not compute-bound (its share of a train
+            step is not measured on the chip). vmap turns the sequential
+            chains into one
             chain of batched steps; per-minibatch whitening semantics are
             bitwise preserved (vmap axis = the minibatch axis the stats
             were already computed within)."""
@@ -1667,9 +1667,9 @@ class PPOTrainer(BaseRLTrainer):
             )))
         self.kl_coef = kl_seq[-1]
 
-        # Overlap attribution (exp/overlap_saved_ms). Ground truth is the
-        # interleaved A/B (ab_phase_overlap.py); these stats are the
-        # cheap per-phase estimate: epoch-1 serial cost is taken from the
+        # Overlap attribution (exp/overlap_saved_ms). Ground truth would be
+        # an interleaved A/B on the chip (no cell runs one; not measured);
+        # these stats are the cheap per-phase estimate: epoch-1 serial cost is taken from the
         # residual pass (same programs, (ppo_epochs-1) identical epochs)
         # when available, else bounded by the dispatch window. Every term
         # is span-derived: drain/residual from their span durations, the

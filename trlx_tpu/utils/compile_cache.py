@@ -10,7 +10,8 @@ a fixed one inside the checkout, because the path must be the same in
 every process for an entry written by one to be found by the next.
 
 Called from the entry points that build jitted programs (``api.train``,
-``InferenceServer``, ``bench.py``, ``chip_smoke.py``); this is the only
+``InferenceServer``, ``chip_smoke.py``; ``benchmark/harness.py`` places its
+own the same way); this is the only
 place ``jax_compilation_cache_dir`` is set.
 """
 
