@@ -157,14 +157,14 @@ def _mixed_prompts(n, seed=0, lo=2, hi=None, sort=True):
     return ids, mask
 
 
-def _drive_rows(engine, ids, mask, key, pool=None, pump=False):
+def _drive_rows(engine, ids, mask, key, pool=None, pump=False, params=None):
     """Run a prompt set through the engine; returns {row: fields}.
     ``pool`` plans prefix sharing just-in-time per admission wave (the
     serving flow — a later wave reads the earlier wave's published
     blocks once ready); ``pump`` uses the serving pump loop instead of
     drive() (exercises the per-pump chunk budget path)."""
     N = ids.shape[0]
-    engine.start_phase(_params(), key)
+    engine.start_phase(_params() if params is None else params, key)
     published_by_row = {}
 
     def on_admitted(rows):
@@ -378,6 +378,395 @@ def test_request_marks_carry_chunk_offsets():
         assert cols[-1] == (chunked.n_prefill_chunks - 1) * chunked.prefill_chunk
     finally:
         chunked.trace_requests = False
+
+
+# ------------------- the families the serving cells run ------------------ #
+
+FAMILY_ARCH = {
+    "gpt_neox": dict(
+        vocab_size=VOCAB, max_position_embeddings=64, hidden_size=32,
+        num_hidden_layers=2, num_attention_heads=2, rotary_pct=0.25,
+    ),
+    "olmoe": dict(
+        vocab_size=VOCAB, max_position_embeddings=64, hidden_size=32,
+        num_hidden_layers=2, num_attention_heads=2, num_key_value_heads=2,
+        intermediate_size=16, num_experts=4, num_experts_per_tok=2,
+        norm_topk_prob=False,
+    ),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _family_engines(name):
+    """(params, monolithic, chunked, chunked with a pump budget of one)
+    over a tiny float32 model of a registered family."""
+    from trlx_tpu.models.heads import CausalLMWithValueHead
+    from trlx_tpu.models.registry import get_model_family
+
+    family = get_model_family(name)
+    cfg = family.config_cls.from_dict(
+        dict(FAMILY_ARCH[name], dtype="float32", param_dtype="float32")
+    )
+    model = CausalLMWithValueHead(cfg, backbone_cls=family.backbone_cls)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+
+    def apply_fn(p, input_ids, attention_mask=None, position_ids=None,
+                 cache=None, cache_index=None, last_only=False,
+                 skip_heads=False):
+        return model.apply(
+            {"params": p}, input_ids, attention_mask=attention_mask,
+            position_ids=position_ids, cache=cache,
+            cache_index=cache_index, last_only=last_only,
+            skip_heads=skip_heads,
+        )
+
+    gen = GenerationConfig(
+        max_new_tokens=R, min_new_tokens=1, eos_token_id=EOS,
+        pad_token_id=EOS, do_sample=True,
+    )
+    common = dict(
+        apply_fn=apply_fn,
+        init_cache_fn=functools.partial(family.init_cache, cfg),
+        gen_config=gen, query_length=Q, vocab_size=VOCAB, num_slots=4,
+        admit_width=2, harvest_width=2, block_size=4,
+    )
+    return (
+        params,
+        ContinuousBatchingEngine(**common),
+        ContinuousBatchingEngine(**common, prefill_chunk=4),
+        ContinuousBatchingEngine(
+            **common, prefill_chunk=4, prefill_chunks_per_pump=1
+        ),
+        ContinuousBatchingEngine(
+            **common, prefill_chunk=4, prefill_chunks_per_pump=1,
+            prefill_min_skip_share=0.5,
+        ),
+    )
+
+
+@pytest.mark.parametrize(
+    "how", ["drive", "pump-budget-1", "pump-min-skip-half"]
+)
+@pytest.mark.parametrize("family", sorted(FAMILY_ARCH))
+def test_chunked_matches_monolithic_by_family(family, how):
+    """The serving cells' families (pythia is ``gpt_neox``: rotary
+    positions, parallel residual; OLMoE: a routed expert layer whose
+    grouped multiplication sees a chunk's rows, not the prompt's) through
+    the chunked admission, whole in ``drive``, one forward a pump, and one
+    forward a pump with the groups that can skip under half their chunks
+    forwarded whole (what a server derives): the monolithic program's
+    tokens and masks, bitwise."""
+    params, mono, chunked, budgeted, mixed = _family_engines(family)
+    ids, mask = _mixed_prompts(8, seed=5)
+    key = jax.random.PRNGKey(23)
+    want = _drive_rows(mono, ids, mask, key, params=params)
+    engine = {"drive": chunked, "pump-budget-1": budgeted}.get(how, mixed)
+    got = _drive_rows(
+        engine, ids, mask, key, pump=how != "drive", params=params
+    )
+    _assert_rows_equal(want, got)
+    stats = engine.stats
+    assert stats.prefill_cols_skipped > 0
+    if engine is mixed:
+        # both kinds of admission ran, and a whole one runs no chunk
+        assert 0 < stats.prefill_whole < stats.prefills
+        assert stats.prefill_chunks >= stats.prefills - stats.prefill_whole
+    else:
+        assert stats.prefill_whole == 0
+        assert stats.prefill_chunks > stats.prefills  # > finish alone
+
+
+# ------------------------- what a server derives -------------------------- #
+
+
+def _server(rollout=None, seq_length=16):
+    from trlx_tpu.analysis import harness
+    from trlx_tpu.data.configs import TRLConfig
+    from trlx_tpu.inference.server import InferenceServer
+
+    cfg = harness.tiny_config_dict("ppo")  # dp 2 x fsdp 2 x tp 2
+    cfg["train"]["seq_length"] = seq_length
+    cfg["train"]["dtype"] = "float32"  # the tier where parity is bitwise
+    cfg["train"]["rollout"] = dict(
+        {"slots": 8, "admit_width": 4, "harvest_width": 4, "block_size": 4},
+        **(rollout or {}),
+    )
+    cfg["method"]["gen_kwargs"].update(max_new_tokens=8, min_new_tokens=1)
+    return InferenceServer(TRLConfig.from_dict(cfg), seed=3)
+
+
+@pytest.fixture(scope="module")
+def derived_server():
+    return _server()
+
+
+def _stream_all(server, prompts, every=3):
+    """Submit ``prompts`` one every ``every`` iterations (0: all at
+    once), streamed, and step the server dry. Returns (tokens each stream
+    delivered, results, the largest number of prefill forwards, chunk or
+    whole, one iteration dispatched)."""
+    stats = server.engine.stats
+    streams, rids, most = {}, [], 0
+    todo = list(prompts)
+    it = 0
+    while todo or any(server.poll(r) is None for r in rids):
+        while todo and (not every or it % every == 0):
+            (rid,) = server.submit([todo.pop(0)], stream=True)
+            rids.append(rid)
+            streams[rid] = (server.stream(rid), [])
+            if every:
+                break
+        before = stats.prefill_chunks + stats.prefill_whole
+        server.step()
+        most = max(most, stats.prefill_chunks + stats.prefill_whole - before)
+        for stream, got in streams.values():
+            got.extend(stream.drain())
+        it += 1
+    results = [server.pop_result(r) for r in rids]
+    return [streams[r][1] for r in rids], results, most
+
+
+def _server_prompts(n, q, seed):
+    rng = np.random.default_rng(seed)
+    return [
+        list(rng.integers(1, 30, int(rng.integers(1, q + 1)))) for _ in range(n)
+    ]
+
+
+@pytest.mark.parametrize("rollout,chunk,per_pump,min_skip", [
+    # nothing set: Q // 4 through choose_prefill_chunk, budget 1, a group
+    # that needs 3 or 4 of its 4 forwards goes whole
+    ({}, 4, 1, 0.5),
+    # the user's width, with the budget it came with, every group chunked
+    ({"prefill_chunk": 8}, 8, 0, 0),
+    ({"prefill_chunk": 8, "prefill_chunks_per_pump": 2}, 8, 2, 0),
+])
+def test_server_chunk_rule(rollout, chunk, per_pump, min_skip, derived_server):
+    server = derived_server if not rollout else _server(rollout)
+    engine = server.engine
+    assert engine.prefill_chunk == chunk
+    assert engine.prefill_chunks_per_pump == per_pump
+    assert engine.n_prefill_chunks == 16 // chunk
+    assert engine.prefill_min_skip_share == min_skip
+
+
+def test_server_streams_the_monolithic_programs_tokens(derived_server, monkeypatch):
+    """No chunk option: the server streams, token for token and
+    log-probability for log-probability, what one forced to the monolithic
+    ``prefill`` streams for the same seed, with 14 requests of mixed
+    lengths over 8 slots, so that groups are admitted, one forward an
+    iteration (chunks, or the whole group where little can be skipped),
+    into recycled slots while the others decode. (What the tap
+    must not do there: credit the tokens a reserved slot's previous
+    occupant still emits to the row waiting for the slot. Submitted at
+    once, because a placeholder takes a row index, and with it a draw:
+    when one is needed depends on when requests arrive.)"""
+    real = ContinuousBatchingEngine.__init__
+
+    def monolithic(self, **kw):
+        kw.update(
+            prefill_chunk=0, prefill_chunks_per_pump=0, prefill_min_skip_share=0
+        )
+        real(self, **kw)
+
+    # groups of up to half length (chunked, leading chunks skipped), then
+    # groups with a longer prompt (forwarded whole)
+    prompts = _server_prompts(8, 8, seed=8) + _server_prompts(6, 16, seed=9)
+    stats = derived_server.engine.stats
+    skipped0, whole0 = stats.prefill_cols_skipped, stats.prefill_whole
+    got_streams, got, _ = _stream_all(derived_server, prompts, every=0)
+    assert stats.prefill_cols_skipped > skipped0
+    assert stats.prefill_whole > whole0
+    monkeypatch.setattr(ContinuousBatchingEngine, "__init__", monolithic)
+    mono = _server()
+    assert mono.engine.prefill_chunk == 0 and mono.engine.prefill_finish_jit is None
+    want_streams, want, _ = _stream_all(mono, prompts, every=0)
+    for g, w in zip(got, want):
+        assert g["tokens"] == w["tokens"] and g["length"] == w["length"]
+        np.testing.assert_allclose(g["logprobs"], w["logprobs"], rtol=0, atol=2e-6)
+    # a stream holds its request's tokens, then what the slot emits past
+    # its budget until its harvest group fills (PERF.md section 7)
+    for streams, results in ((got_streams, got), (want_streams, want)):
+        for streamed, res in zip(streams, results):
+            assert streamed[: res["length"]] == res["tokens"]
+
+
+def test_a_pump_dispatches_at_most_one_chunk_forward(derived_server):
+    """The stall a running stream feels is one forward and a decode step,
+    whatever arrives, a new prompt every second iteration: half-length
+    prompts take two chunk forwards each, in two iterations; full-length
+    ones can skip nothing and take the one whole forward."""
+    stats = derived_server.engine.stats
+    chunks0, whole0 = stats.prefill_chunks, stats.prefill_whole
+    prompts = [list(range(1, 9)), list(range(1, 17))] * 3
+    _, results, most = _stream_all(derived_server, prompts, every=2)
+    assert most == 1 and all(r["length"] >= 1 for r in results)
+    assert stats.prefill_whole - whole0 == 3
+    assert stats.prefill_chunks - chunks0 == 6
+
+
+def test_server_compiles_nothing_after_setup():
+    """Construction builds every admission program; a warm-up whose
+    prompts all fit the final chunk (so no non-final chunk ever ran) then
+    leaves nothing for the first longer prompts to compile: backend
+    compiles counted from jax's monitoring events as the benchmark's
+    ``accounting.compiles_in_window`` counts them, and the engine's jitted
+    programs by their cache sizes. The scan of chunks and the finish
+    program after it are never built under a budget of one."""
+    from jax import monitoring
+
+    compiles = []
+    counting = [False]
+
+    def on(event, duration, **_):
+        if counting[0] and event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    monitoring.register_event_duration_secs_listener(on)
+    server = _server()
+    engine = server.engine
+    # 5 short prompts: a partial harvest group, so placeholders too
+    _stream_all(server, _server_prompts(5, 3, seed=2), every=1)
+    assert engine.stats.prefill_chunks == engine.stats.prefills  # finish only
+    assert engine.stats.released > 0
+    programs = {
+        name: getattr(engine, name)._cache_size()
+        for name in ("prefill_jit", "prefill_chunk_jit", "release_jit",
+                     "decode_step_jit", "refill_jit")
+    }
+    assert all(n == 1 for n in programs.values()), programs
+    counting[0] = True
+    try:
+        # half-length prompts reach ``prefill_chunk``, full-length ones
+        # the whole ``prefill``
+        late = [list(range(1, 9)), list(range(1, 17))] * 3
+        _, results, _ = _stream_all(server, late, every=1)
+    finally:
+        counting[0] = False
+    assert all(r["length"] >= 1 for r in results)
+    assert engine.stats.prefill_whole > 0
+    assert (
+        engine.stats.prefill_chunks
+        > engine.stats.prefills - engine.stats.prefill_whole
+    )
+    assert compiles == []
+    assert programs == {
+        name: getattr(engine, name)._cache_size() for name in programs
+    }
+    # one chunk a pump: ``prefill_chunk`` forwards the final chunk too
+    assert engine.prefill_chunks_jit._cache_size() == 0
+    assert engine.prefill_finish_jit._cache_size() == 0
+
+
+def test_skip_share_histogram_is_observed_once_an_admission(derived_server):
+    """``engine/prefill_skip_share`` (the benchmark's
+    ``serve_prefill_skip_share``): the group's skipped chunks over its
+    chunks, once an admission, in the registry the server reports."""
+    from trlx_tpu import telemetry
+
+    with telemetry.scoped_metrics() as reg:
+        derived_server._registry = reg
+        try:
+            before = derived_server.engine.stats.prefills
+            # one group that fits the final chunk (3 of 4 skipped), then
+            # one of full length (none skipped)
+            derived_server.generate([[1, 2, 3]] * 4)
+            derived_server.generate([list(range(1, 17))] * 4)
+            summary = derived_server.metrics()["engine/prefill_skip_share"]
+        finally:
+            derived_server._registry = telemetry.get_metrics()
+    assert summary["count"] == derived_server.engine.stats.prefills - before == 2
+    assert summary["max"] == 0.75 and summary["min"] == 0.0
+    assert summary["mean"] == pytest.approx(0.375)
+
+
+# ---------------- the paths that keep the parent's programs --------------- #
+
+# sha256[:16] of ``jitted.lower(args).as_text()`` (StableHLO, no source
+# locations) at commit 270555e (PR 29; PERF.md section 6 lists the same
+# digests): gpt2 2 x 32 in bf16, Q 16 + R 8, 4 slots, admit/harvest 2
+PARENT_PROGRAMS = {
+    "sampler": "0ad7eebdd65972e5",
+    "prefill": "09eb3fddfb0669cc",
+    "decode_step": "d393924ac90fb367",
+    "refill": "5e8422c7555df564",
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _default_path_digests():
+    import hashlib
+
+    from trlx_tpu.models.gpt2 import GPT2Config, init_cache
+    from trlx_tpu.models.heads import CausalLMWithValueHead
+    from trlx_tpu.ops.sampling import make_sampler
+
+    digest = lambda text: hashlib.sha256(text.encode()).hexdigest()[:16]  # noqa: E731
+    cfg = GPT2Config(
+        vocab_size=VOCAB, n_positions=64, n_embd=32, n_layer=2, n_head=2,
+        dtype="bfloat16", kv_cache_dtype="bfloat16",
+    )
+    model = CausalLMWithValueHead(cfg)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+
+    def apply_fn(p, input_ids, attention_mask=None, position_ids=None,
+                 cache=None, cache_index=None, **kw):
+        return model.apply(
+            {"params": p}, input_ids, attention_mask=attention_mask,
+            position_ids=position_ids, cache=cache,
+            cache_index=cache_index, **kw,
+        )
+
+    gen = GenerationConfig(
+        max_new_tokens=R, min_new_tokens=1, eos_token_id=EOS,
+        pad_token_id=EOS, do_sample=True,
+    )
+    init_fn = functools.partial(init_cache, cfg)
+    out = {}
+    ids, mask = _mixed_prompts(4, seed=0)
+    sampler = jax.jit(make_sampler(apply_fn, init_fn, gen, Q))
+    out["sampler"] = digest(
+        sampler.lower(
+            params, jnp.asarray(ids), jnp.asarray(mask), jax.random.PRNGKey(1)
+        ).as_text()
+    )
+    # the trainer's collect loop with default options: no chunk, drive()
+    engine = ContinuousBatchingEngine(
+        apply_fn=apply_fn, init_cache_fn=init_fn, gen_config=gen,
+        query_length=Q, vocab_size=VOCAB, num_slots=4, admit_width=2,
+        harvest_width=2, block_size=4,
+    )
+    for name in ("prefill", "decode_step", "refill"):
+        fn = getattr(engine, name + "_jit")
+
+        def recording(*a, _fn=fn, _name=name):
+            if _name not in out:
+                out[_name] = digest(_fn.lower(*a).as_text())
+            return _fn(*a)
+
+        setattr(engine, name + "_jit", recording)
+    ids, mask = _mixed_prompts(8, seed=3)
+    engine.start_phase(params, jax.random.PRNGKey(7))
+    engine.submit(ids, mask)
+    for _ in engine.drive(8):
+        pass
+    assert engine.prefill_finish_jit is None and engine.stats.prefill_chunks == 0
+    return out
+
+
+@pytest.mark.parametrize("program", sorted(PARENT_PROGRAMS))
+def test_default_rollout_paths_lower_the_parents_programs(program):
+    """Chunked admission is the serving pump's: the trainer's collect loop
+    on the continuous engine with default options still lowers the
+    monolithic ``prefill``, and ``decode_step`` and ``refill`` with it, text
+    for text what they were before PR 30, and the fixed sampler is not
+    touched. A change that means to alter one of these programs updates
+    its digest here (``tools/program_hashes.py`` compares whole runs)."""
+    assert _default_path_digests()[program] == PARENT_PROGRAMS[program]
 
 
 # ------------------------------- FLOPs ---------------------------------- #

@@ -105,13 +105,13 @@ def _prompts(server, n, seed):
 def test_streamed_run_fills_the_serving_histograms(server, monkeypatch):
     """A streamed run through the public ``step()``: the four histograms
     fill, ``serve/admit_pump_ms`` only in iterations that dispatched a
-    prefill, the host's share never exceeds the iteration, an ordinary
+    prefill (one forward each, a chunk or a whole group), the host's share never exceeds the iteration, an ordinary
     iteration stays inside its budget of spans, and a result carries the
     log-probabilities of its tokens."""
     with telemetry.scoped_tracer() as tracer, telemetry.scoped_metrics() as reg:
         monkeypatch.setattr(server, "_registry", reg)
         stats = server.engine.stats
-        prefills0 = stats.prefills
+        prefills0, forwards0 = stats.prefills, stats.prefill_chunks + stats.prefill_whole
         rids = server.submit(_prompts(server, 6, seed=0), stream=True)
         streams = [server.stream(r) for r in rids]
         iterations = 0
@@ -128,7 +128,10 @@ def test_streamed_run_fills_the_serving_histograms(server, monkeypatch):
     host, held = hist["serve/step_host_ms"], hist["serve/slots_done_waiting"]
     prefills = stats.prefills - prefills0
     assert prefills >= 2  # 6 requests at admit width 4
-    assert 1 <= admit["count"] <= prefills
+    # a server admits in chunks, one forward an iteration (PR 30): an
+    # admission's iterations are its forwards, each the stall of its own
+    forwards = stats.prefill_chunks + stats.prefill_whole - forwards0
+    assert prefills <= admit["count"] == forwards
     assert pump["count"] >= 1
     # every iteration that did device work is in exactly one of the two
     assert pump["count"] + admit["count"] == host["count"] == held["count"]

@@ -240,7 +240,9 @@ class TrainConfig:
     # chunks (skips leading pad + prefix-pool-covered blocks; bitwise
     # vs the monolithic program — docs/inference.md "Chunked prefill"),
     # and "prefill_chunks_per_pump" bounds chunk forwards per serving
-    # pump (stall-free admission under bursts).
+    # pump (stall-free admission under bursts). An InferenceServer given
+    # neither derives both: a chunk of Q // 4 columns, one forward a pump
+    # (a group that can skip under half its chunks: the whole prefill).
     rollout: Dict[str, Any] = field(default_factory=dict)
     # Multi-tenant serving tier (trlx_tpu/serving, docs/serving.md),
     # parsed into trlx_tpu.serving.ServingConfig and consumed by

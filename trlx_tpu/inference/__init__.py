@@ -139,14 +139,20 @@ class RolloutEngineConfig:
         (``ops/kv_cache.py::choose_prefill_chunk``). Chunked and
         monolithic prefill are token/mask-bitwise-identical
         (logprobs/values at the engine's established bf16 resolution).
-        0 — the default — keeps the monolithic program byte-identical.
+        0 — the default — keeps the monolithic program byte-identical
+        on the trainer's collect loop; an ``InferenceServer`` reads 0 as
+        "not set" and derives the width from Q
+        (``ops/kv_cache.py::serving_prefill_chunk``) with a budget of
+        one chunk forward a pump, and forwards a group whole where it
+        can skip under half its chunks (docs/inference.md "Who sets
+        it").
     :param prefill_chunks_per_pump: serving-pump chunk budget
         (Sarathi-style stall-free admission; needs ``prefill_chunk``):
         one ``pump()`` dispatches at most this many prefill-chunk
         forwards before advancing decode, so an admission burst
         interleaves with decode steps instead of stalling them. 0 =
         unbounded; the trainer collect loop (``drive``) always completes
-        an admission inline.
+        an admission inline. A server that derives the chunk sets 1.
     :param spec_decode: speculative-decoding section
         (:class:`SpecDecodeConfig`): host drafter + multi-token verify
         steps, bitwise-pinned against the one-token loop

@@ -67,7 +67,16 @@ from __future__ import annotations
 import dataclasses
 import inspect
 import threading
-from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
+from typing import (
+    Any,
+    Callable,
+    Dict,
+    Iterable,
+    Iterator,
+    List,
+    Optional,
+    Tuple,
+)
 
 import flax.struct as struct
 import jax
@@ -141,13 +150,20 @@ class EngineStats:
     # host's own exposed cost
     host_blocked_ms: float = 0.0
     # chunked prefill (rollout.prefill_chunk > 0): chunks actually RUN
-    # (the finish chunk included), prompt columns whose forward was
-    # skipped (leading pad + pool-covered shared blocks), and the exact
-    # dot-FLOPs those skipped columns would have cost (per-chunk cost
-    # from the traced program — engine-7's counter, not an estimate)
+    # (the finish chunk included) and prompt columns whose forward was
+    # skipped (leading pad + pool-covered shared blocks). What one
+    # skipped column would have cost is asked of the engine when
+    # ``prefill_flops_saved`` is READ (an abstract trace of the chunked
+    # program: 8 s of host time at pythia-1.4b's size, which PR 30 found
+    # inside a serving window when the first skip priced it)
     prefill_chunks: int = 0
     prefill_cols_skipped: int = 0
-    prefill_flops_saved: float = 0.0
+    # groups a chunked engine forwarded whole (``prefill_min_skip_share``):
+    # one monolithic ``prefill`` each, no chunk program run
+    prefill_whole: int = 0
+    col_flops: Callable[[], float] = dataclasses.field(
+        default=lambda: 0.0, repr=False, compare=False
+    )
     # cross-request prefix sharing (serving tier): block-granular lookup
     # accounting per admitted real row — hits are blocks served from the
     # shared pool WITHOUT this row publishing them (true reuse), saved
@@ -165,6 +181,14 @@ class EngineStats:
     spec_drafted: int = 0
     spec_accepted: int = 0
     spec_draft_lens: List[int] = dataclasses.field(default_factory=list)
+
+    @property
+    def prefill_flops_saved(self) -> float:
+        """Exact dot-FLOPs the skipped columns would have cost (per-chunk
+        cost from the traced program — engine-7's counter, not an
+        estimate)."""
+        skipped = self.prefill_cols_skipped
+        return skipped * self.col_flops() if skipped else 0.0
 
     @property
     def slot_util(self) -> float:
@@ -282,7 +306,10 @@ class ContinuousBatchingEngine:
         token/mask-identical to the monolithic program (logprobs/values
         at the established bf16 resolution). 0 — the default, and the
         trainer collect path unless configured — keeps the monolithic
-        program byte-identical.
+        program byte-identical. ``InferenceServer`` never passes 0: where
+        the user set none it passes
+        :func:`~trlx_tpu.ops.kv_cache.serving_prefill_chunk` of Q and a
+        budget of one.
     :param prefill_chunks_per_pump: with ``prefill_chunk > 0``, bound
         how many chunk forwards one :meth:`pump` iteration dispatches
         (Sarathi-style stall-free admission): a large admission burst
@@ -292,6 +319,18 @@ class ContinuousBatchingEngine:
         prefill dispatches in one pump, as the monolithic path does).
         :meth:`drive` (the trainer collect loop) always completes an
         admission inline regardless.
+    :param prefill_min_skip_share: with ``prefill_chunk > 0``, a group
+        that can skip less than this share of its chunks is dispatched
+        as the one monolithic ``prefill`` instead of in chunks: chunking
+        pays through the columns it skips, and such a group's forwards
+        would hold the admission path for most of ``Q / chunk``
+        iterations (the requests behind it wait, its own first token
+        comes that much later) and pay the per-forward costs (the
+        group's slice, its merge, the view gathers) each time. Same
+        tokens and masks either way. 0 — the default — never: every
+        group goes in chunks. ``InferenceServer`` passes
+        :data:`~trlx_tpu.ops.kv_cache.SERVING_PREFILL_MIN_SKIP_SHARE`
+        where it derives the chunk itself.
     :param spec_max_draft: speculative decoding (``rollout.spec_decode``,
         docs/inference.md): ``> 0`` adds a jitted ``verify_step`` program
         that forwards each slot's anchor sample plus up to this many
@@ -338,6 +377,7 @@ class ContinuousBatchingEngine:
         stream_taps: bool = False,
         prefill_chunk: int = 0,
         prefill_chunks_per_pump: int = 0,
+        prefill_min_skip_share: float = 0.0,
         spec_max_draft: int = 0,
         spec_drafter=None,
         spec_min_accept_ewma: float = 0.0,
@@ -388,6 +428,16 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 "prefill_chunks_per_pump needs chunked prefill "
                 "(prefill_chunk > 0) — there is nothing to budget on the "
+                "monolithic program"
+            )
+        self.prefill_min_skip_share = float(prefill_min_skip_share)
+        if not 0.0 <= self.prefill_min_skip_share <= 1.0 or (
+            self.prefill_min_skip_share and not self.prefill_chunk
+        ):
+            raise ValueError(
+                f"prefill_min_skip_share={prefill_min_skip_share} must "
+                "lie in [0, 1] and needs chunked prefill (prefill_chunk "
+                "> 0): without it every group already takes the "
                 "monolithic program"
             )
         #: host callback ``{row: token_id} -> None`` fired per decode
@@ -486,7 +536,7 @@ class ContinuousBatchingEngine:
         #: published prefix blocks ready for later admission groups here
         #: (dispatch order guarantees the device writes land first)
         self._admit_listener: Optional[Callable[[List[int]], None]] = None
-        self.stats = EngineStats(num_slots=self.num_slots)
+        self.stats = self._new_stats()
         # per-request latency bookkeeping (docs/observability.md,
         # "Serving metrics"): submit/admit/prefill/complete marks on the
         # shared telemetry clock, popped by the serving layer into its
@@ -509,6 +559,12 @@ class ContinuousBatchingEngine:
         self._step_base = 0
 
     # ------------------------- jitted programs ------------------------- #
+
+    def _new_stats(self) -> EngineStats:
+        return EngineStats(
+            num_slots=self.num_slots,
+            col_flops=lambda: self._chunk_flop_cost() / self.prefill_chunk,
+        )
 
     def init_state(self) -> EngineState:
         """Fresh all-idle pool, committed to the engine's shardings.
@@ -1029,6 +1085,27 @@ class ContinuousBatchingEngine:
         n_scan_chunks = max(0, n_pc - 1)
         chunk_kwargs = self._chunk_kwargs
 
+        def chunk_forward(params, cache, prompt_ids, prompt_mask,
+                          positions, c):
+            """Non-final chunk ``c`` forwarded against ``cache`` (heads
+            skipped); returns the cache with the chunk's KV written."""
+            ids_c = jax.lax.dynamic_slice_in_dim(
+                prompt_ids, c * W, W, axis=1
+            )
+            pos_c = jax.lax.dynamic_slice_in_dim(
+                positions, c * W, W, axis=1
+            )
+            out = apply_fn(
+                params,
+                ids_c,
+                attention_mask=prompt_mask,  # Q-wide view
+                position_ids=pos_c,
+                cache=cache,
+                cache_index=c * W,
+                **chunk_kwargs,
+            )
+            return out["cache"]
+
         @jax.named_scope("prefill")
         def prefill_chunks(
             params,
@@ -1050,22 +1127,9 @@ class ContinuousBatchingEngine:
 
             def body(cache, c):
                 def run(cch):
-                    ids_c = jax.lax.dynamic_slice_in_dim(
-                        prompt_ids, c * W, W, axis=1
+                    return chunk_forward(
+                        params, cch, prompt_ids, prompt_mask, positions, c
                     )
-                    pos_c = jax.lax.dynamic_slice_in_dim(
-                        positions, c * W, W, axis=1
-                    )
-                    out = apply_fn(
-                        params,
-                        ids_c,
-                        attention_mask=prompt_mask,  # Q-wide view
-                        position_ids=pos_c,
-                        cache=cch,
-                        cache_index=c * W,
-                        **chunk_kwargs,
-                    )
-                    return out["cache"]
 
                 return jax.lax.cond(need[c], run, lambda cch: cch, cache), None
 
@@ -1079,38 +1143,16 @@ class ContinuousBatchingEngine:
                 ),
             )
 
-        @jax.named_scope("prefill")
-        def prefill_finish(
-            params,
-            state: EngineState,
-            slot_ids,
-            prompt_ids,
-            prompt_mask,
-            row_index,
-            table_turns,
-            phase_key,
-            shared_map=None,
-            publish_map=None,
+        def seed_group(
+            state, seed_ids, new_cache, prompt_ids, prompt_mask,
+            row_index, phase_key, out,
         ) -> EngineState:
+            """The state with ``new_cache`` and, for the rows of
+            ``seed_ids`` (out of bounds: none), the slot fields of a
+            group whose final chunk's forward gave ``out``."""
             A = prompt_ids.shape[0]
             row_keys = make_row_keys(phase_key, row_index)
             n_real = jnp.sum(prompt_mask, axis=-1).astype(jnp.int32)
-            cache_slice = slice_group_cache(
-                state, slot_ids, table_turns, shared_map, publish_map
-            )
-            positions = jnp.clip(
-                jnp.cumsum(prompt_mask, axis=-1) - 1, 0, None
-            )
-            off = Q - W  # static: the final chunk's column offset
-            out = apply_fn(
-                params,
-                prompt_ids[:, off:],
-                attention_mask=prompt_mask,  # Q-wide view
-                position_ids=positions[:, off:],
-                cache=cache_slice,
-                cache_index=off,
-                **prefill_kwargs,
-            )
             logits_last = out["logits"][:, -1].astype(jnp.float32)
             if with_values:
                 value_last = out["values"][:, -1].astype(jnp.float32)
@@ -1120,10 +1162,9 @@ class ContinuousBatchingEngine:
                 finished0 = n_real >= cfg.max_length
             else:
                 finished0 = jnp.zeros((A,), bool)
-            new_cache = merge_group_cache(state, slot_ids, out["cache"])
 
             def put(field, rows):
-                return field.at[slot_ids].set(
+                return field.at[seed_ids].set(
                     rows.astype(field.dtype), mode="drop"
                 )
 
@@ -1153,6 +1194,91 @@ class ContinuousBatchingEngine:
                 row_index=put(state.row_index, row_index),
             )
 
+        @jax.named_scope("prefill")
+        def prefill_chunk(
+            params,
+            state: EngineState,
+            slot_ids,
+            prompt_ids,
+            prompt_mask,
+            row_index,
+            table_turns,
+            phase_key,
+            c,  # int32 scalar: which chunk, the final one included
+            shared_map=None,
+            publish_map=None,
+        ) -> EngineState:
+            """Chunk ``c`` in a straight line, whichever it is: one
+            program for every forward of an admission that goes one
+            chunk a pump (a server builds its programs before it takes
+            traffic, and each costs 2-3 s of tracing there: PERF.md
+            section 6, PR 30). The final chunk seeds the group's slots as
+            ``prefill_finish`` does; any other writes its KV alone: its
+            seeds go to the out-of-bounds slot and drop, and its heads
+            see one column a row. No scan and no ``lax.cond``: inside the
+            scan's ``while`` the compiler converts float32 served weights
+            to bf16 whole and holds 3.3x the temporaries, 47.7 ms a
+            forward against 38.3 in pythia-1.4b's serving cell."""
+            cache_slice = slice_group_cache(
+                state, slot_ids, table_turns, shared_map, publish_map
+            )
+            positions = jnp.clip(
+                jnp.cumsum(prompt_mask, axis=-1) - 1, 0, None
+            )
+            out = apply_fn(
+                params,
+                jax.lax.dynamic_slice_in_dim(prompt_ids, c * W, W, axis=1),
+                attention_mask=prompt_mask,  # Q-wide view
+                position_ids=jax.lax.dynamic_slice_in_dim(
+                    positions, c * W, W, axis=1
+                ),
+                cache=cache_slice,
+                cache_index=c * W,
+                **prefill_kwargs,
+            )
+            return seed_group(
+                state,
+                jnp.where(c == n_pc - 1, slot_ids, self.num_slots),
+                merge_group_cache(state, slot_ids, out["cache"]),
+                prompt_ids, prompt_mask, row_index, phase_key, out,
+            )
+
+        @jax.named_scope("prefill")
+        def prefill_finish(
+            params,
+            state: EngineState,
+            slot_ids,
+            prompt_ids,
+            prompt_mask,
+            row_index,
+            table_turns,
+            phase_key,
+            shared_map=None,
+            publish_map=None,
+        ) -> EngineState:
+            cache_slice = slice_group_cache(
+                state, slot_ids, table_turns, shared_map, publish_map
+            )
+            positions = jnp.clip(
+                jnp.cumsum(prompt_mask, axis=-1) - 1, 0, None
+            )
+            off = Q - W  # static: the final chunk's column offset
+            out = apply_fn(
+                params,
+                prompt_ids[:, off:],
+                attention_mask=prompt_mask,  # Q-wide view
+                position_ids=positions[:, off:],
+                cache=cache_slice,
+                cache_index=off,
+                **prefill_kwargs,
+            )
+            return seed_group(
+                state,
+                slot_ids,
+                merge_group_cache(state, slot_ids, out["cache"]),
+                prompt_ids, prompt_mask, row_index, phase_key, out,
+            )
+
         if self.mesh is not None and self._param_shardings is not None:
             from trlx_tpu.parallel.mesh import (
                 batch_sharding,
@@ -1165,6 +1291,7 @@ class ContinuousBatchingEngine:
             # flash kernels, which need it — parallel/mesh.py::traced_on)
             prefill = traced_on(self.mesh, prefill)
             prefill_chunks = traced_on(self.mesh, prefill_chunks)
+            prefill_chunk = traced_on(self.mesh, prefill_chunk)
             prefill_finish = traced_on(self.mesh, prefill_finish)
             verify_step = traced_on(self.mesh, verify_step)
             state_sh = self.state_sharding()
@@ -1218,6 +1345,7 @@ class ContinuousBatchingEngine:
             self.release_jit = jax.jit(release, donate_argnums=(0,))
 
         self.prefill_chunks_jit = None
+        self.prefill_chunk_jit = None
         self.prefill_finish_jit = None
         if self.prefill_chunk > 0:
             if self.mesh is not None and self._param_shardings is not None:
@@ -1250,6 +1378,16 @@ class ContinuousBatchingEngine:
                     out_shardings=state_sh,
                     donate_argnums=(1,),
                 )
+                # the finish program's arguments, then the chunk's index
+                # (replicated) ahead of the sharing maps
+                self.prefill_chunk_jit = jax.jit(
+                    prefill_chunk,
+                    in_shardings=tuple(
+                        finish_in[:8] + [rep] + finish_in[8:]
+                    ),
+                    out_shardings=state_sh,
+                    donate_argnums=(1,),
+                )
             else:
                 if n_scan_chunks > 0:
                     self.prefill_chunks_jit = jax.jit(
@@ -1257,6 +1395,9 @@ class ContinuousBatchingEngine:
                     )
                 self.prefill_finish_jit = jax.jit(
                     prefill_finish, donate_argnums=(1,)
+                )
+                self.prefill_chunk_jit = jax.jit(
+                    prefill_chunk, donate_argnums=(1,)
                 )
 
         self.verify_step_jit = None
@@ -1311,7 +1452,7 @@ class ContinuousBatchingEngine:
         with sched_points.guard(self._push_lock, "engine.push_lock"):
             self._pending_push = None
         self._steps_since_poll = 0
-        self.stats = EngineStats(num_slots=self.num_slots)
+        self.stats = self._new_stats()
         self._req_times = {}
         self._step_log = []
         self._step_base = 0
@@ -1630,10 +1771,18 @@ class ContinuousBatchingEngine:
                 if self.prefill_chunk > 0
                 else None
             ),
+            "whole": False,
             "next_chunk": 0,
             "chunk_walls": [],
             "t_admit": telemetry.monotonic(),
         }
+        if self.prefill_min_skip_share:
+            # the finish chunk always runs, whatever ``need`` says of it
+            need = self._inflight_admission["need"]
+            skippable = need.size - 1 - np.count_nonzero(need[:-1])
+            self._inflight_admission["whole"] = bool(
+                skippable < self.prefill_min_skip_share * need.size
+            )
 
     def _advance_admission(
         self, budget: Optional[int]
@@ -1651,7 +1800,7 @@ class ContinuousBatchingEngine:
                 jnp.asarray(adm["shared_map"]),
                 jnp.asarray(adm["publish_map"]),
             ]
-        if self.prefill_chunk == 0:
+        if self.prefill_chunk == 0 or adm["whole"]:
             with telemetry.span(
                 "collect/prefill", force=True, admitted=adm["take"]
             ):
@@ -1666,6 +1815,9 @@ class ContinuousBatchingEngine:
                     self._phase_key,
                     *map_args,
                 )
+            if adm["whole"]:
+                self.stats.prefill_whole += 1
+                adm["skipped"] = 0
             self._finalize_admission()
             return True, 1
         n_scan = self.n_prefill_chunks - 1
@@ -1681,22 +1833,27 @@ class ContinuousBatchingEngine:
                 run = idx
                 hi = n_scan
             if run:
-                window = np.zeros((n_scan,), bool)
-                window[run] = True
                 with telemetry.span(
                     "collect/prefill", force=True,
                     admitted=adm["take"], chunks=len(run),
                 ):
-                    self._state = self.prefill_chunks_jit(
-                        self._params,
-                        self._state,
-                        jnp.asarray(adm["slot_ids"]),
-                        adm["ids"],
-                        adm["mask"],
-                        jnp.asarray(adm["turns"]),
-                        jnp.asarray(window),
-                        *map_args,
-                    )
+                    # a window of one chunk runs in a straight line; only
+                    # a window of several pays for the scan of conds
+                    if len(run) == 1:
+                        self._dispatch_chunk(adm, run[0], map_args)
+                    else:
+                        window = np.zeros((n_scan,), bool)
+                        window[run] = True
+                        self._state = self.prefill_chunks_jit(
+                            self._params,
+                            self._state,
+                            jnp.asarray(adm["slot_ids"]),
+                            adm["ids"],
+                            adm["mask"],
+                            jnp.asarray(adm["turns"]),
+                            jnp.asarray(window),
+                            *map_args,
+                        )
                 self.stats.prefill_chunks += len(run)
                 spent = len(run)
                 adm["chunk_walls"].append(
@@ -1711,34 +1868,47 @@ class ContinuousBatchingEngine:
             "collect/prefill", force=True,
             admitted=adm["take"], chunks=1, finish=True,
         ):
-            self._state = self.prefill_finish_jit(
-                self._params,
-                self._state,
-                jnp.asarray(adm["slot_ids"]),
-                adm["ids"],
-                adm["mask"],
-                jnp.asarray(adm["row_index"]),
-                jnp.asarray(adm["turns"]),
-                self._phase_key,
-                *map_args,
-            )
+            if self.prefill_chunks_per_pump == 1:
+                # one chunk a pump: one program for all of them
+                self._dispatch_chunk(adm, n_scan, map_args)
+            else:
+                self._state = self.prefill_finish_jit(
+                    self._params,
+                    self._state,
+                    jnp.asarray(adm["slot_ids"]),
+                    adm["ids"],
+                    adm["mask"],
+                    jnp.asarray(adm["row_index"]),
+                    jnp.asarray(adm["turns"]),
+                    self._phase_key,
+                    *map_args,
+                )
         self.stats.prefill_chunks += 1
         adm["chunk_walls"].append(
             ((self.n_prefill_chunks - 1) * self.prefill_chunk,
              telemetry.monotonic())
         )
-        skipped = int(n_scan - np.count_nonzero(need[:n_scan]))
-        self.stats.prefill_cols_skipped += skipped * self.prefill_chunk
-        if skipped:
-            # lazy one-time abstract trace — only ever paid once a group
-            # actually skipped something (a no-skip serving workload
-            # must not stall its first admission tracing the program
-            # just to multiply the per-chunk cost by zero)
-            self.stats.prefill_flops_saved += (
-                skipped * self._chunk_flop_cost()
-            )
+        adm["skipped"] = int(n_scan - np.count_nonzero(need[:n_scan]))
+        self.stats.prefill_cols_skipped += (
+            adm["skipped"] * self.prefill_chunk
+        )
         self._finalize_admission()
         return True, spent + 1
+
+    def _dispatch_chunk(self, adm, c: int, map_args) -> None:
+        """Chunk ``c`` of the in-flight group through ``prefill_chunk``."""
+        self._state = self.prefill_chunk_jit(
+            self._params,
+            self._state,
+            jnp.asarray(adm["slot_ids"]),
+            adm["ids"],
+            adm["mask"],
+            jnp.asarray(adm["row_index"]),
+            jnp.asarray(adm["turns"]),
+            self._phase_key,
+            jnp.asarray(c, jnp.int32),
+            *map_args,
+        )
 
     def _finalize_admission(self) -> None:
         """Admission bookkeeping after the group's LAST prefill dispatch:
@@ -1802,14 +1972,17 @@ class ContinuousBatchingEngine:
                 self.stats.prefix_blocks_saved
             )
         if self.prefill_chunk > 0:
+            # once an admission, so a reader that clears the registry at
+            # its window's start sees the window's own admissions (the
+            # gauges below count from the engine's construction)
+            registry.histogram("engine/prefill_skip_share").observe(
+                adm["skipped"] / self.n_prefill_chunks
+            )
             registry.gauge("engine/prefill_chunks").set(
                 float(self.stats.prefill_chunks)
             )
             registry.gauge("engine/prefill_cols_skipped").set(
                 float(self.stats.prefill_cols_skipped)
-            )
-            registry.gauge("engine/prefill_flops_saved").set(
-                float(self.stats.prefill_flops_saved)
             )
         if self._admit_listener is not None:
             self._admit_listener([e[2] for e in adm["entries"]])
@@ -1818,8 +1991,9 @@ class ContinuousBatchingEngine:
         """Exact dot-FLOPs of ONE prefill chunk forward, read off the
         traced chunked program with engine-7's counter
         (``analysis/resource_audit.py::count_flops``: the scan body at
-        its cond's run branch, times one). Traced lazily once per engine
-        — abstract trace only, no compilation — so
+        its cond's run branch, times one). Traced once per engine, when
+        ``stats.prefill_flops_saved`` is first read and never by an
+        admission — abstract trace only, no compilation — so
         ``engine/prefill_flops_saved`` is a real FLOP number, not a
         heuristic; 0.0 when tracing is unavailable."""
         if self._chunk_flops is not None:
@@ -1886,6 +2060,56 @@ class ContinuousBatchingEngine:
             remaining -= max(1, spent)
             if not done:
                 return
+
+    def compile_admission_programs(self) -> None:
+        """Build every program an admission can dispatch, whatever the
+        first requests happen to hold, by running each once on a group of
+        dummies: every slot id is out of bounds, so each write drops and
+        the pool is what it was. A server calls this before it takes
+        traffic (after :meth:`start_phase`): a pump must never compile
+        under a running stream, and which of ``prefill`` (a group
+        forwarded whole), ``prefill_chunk`` / ``prefill_chunks`` and
+        ``release`` the first prompts reach is the traffic's business."""
+        A, Q = self.admit_width, self.Q
+        slot_ids = jnp.full((A,), self.num_slots, jnp.int32)
+        ids = np.full((A, Q), self.gen_config.pad_token_id, np.int32)
+        mask = np.zeros((A, Q), np.int32)
+        mask[:, -1] = 1
+        if self.mesh is not None:
+            from trlx_tpu.parallel.mesh import batch_sharding
+
+            ids, mask = jax.device_put(
+                (ids, mask), batch_sharding(self.mesh)
+            )
+        zeros = jnp.zeros((A,), jnp.int32)
+        maps = []
+        if self.prefix_pool_blocks > 0:
+            maps = [jnp.full((A, self.n_blocks), -1, jnp.int32)] * 2
+        if self.prefill_chunk == 0 or self.prefill_min_skip_share:
+            self._state = self.prefill_jit(
+                self._params, self._state, slot_ids, ids, mask, zeros,
+                zeros, self._phase_key, *maps,
+            )
+        if self.prefill_chunk > 0:
+            self._state = self.prefill_chunk_jit(
+                self._params, self._state, slot_ids, ids, mask, zeros,
+                zeros, self._phase_key, jnp.zeros((), jnp.int32), *maps,
+            )
+            # windows of several chunks, and the finish program after
+            # them, exist only off a budget of one
+            if self.prefill_chunks_per_pump != 1:
+                if self.prefill_chunks_jit is not None:
+                    self._state = self.prefill_chunks_jit(
+                        self._params, self._state, slot_ids, ids, mask,
+                        zeros,
+                        jnp.zeros((self.n_prefill_chunks - 1,), bool),
+                        *maps,
+                    )
+                self._state = self.prefill_finish_jit(
+                    self._params, self._state, slot_ids, ids, mask, zeros,
+                    zeros, self._phase_key, *maps,
+                )
+        self._state = self.release_jit(self._state, slot_ids)
 
     def _harvest_ready(self) -> Iterator[Dict[str, Any]]:
         """Yield fixed-width harvest groups while enough slots are done."""
@@ -2003,6 +2227,23 @@ class ContinuousBatchingEngine:
                 return
         self._decode_once()
 
+    def _seeded_rows(self) -> Iterable[Tuple[int, int]]:
+        """``(slot, row)`` of the busy slots whose device rows are their
+        row's. A slot the in-flight admission has reserved holds its
+        previous occupant's state until ``prefill_finish`` seeds it (a
+        budget-ended occupant still reads live there), so between the
+        chunk forwards of a pump-budgeted admission no token, draft or
+        acceptance of such a slot belongs to the row waiting for it."""
+        adm = self._inflight_admission
+        if adm is None:
+            return self._busy_rows.items()
+        reserved = set(adm["slot_ids"][: adm["take"]].tolist())
+        return [
+            (slot, row)
+            for slot, row in self._busy_rows.items()
+            if slot not in reserved
+        ]
+
     def _take_drafts(self) -> Tuple[np.ndarray, np.ndarray]:
         """The next step's per-slot draft matrix: the prefetched stage
         if it survived (no weight push / admission / harvest since it
@@ -2022,7 +2263,7 @@ class ContinuousBatchingEngine:
         if self.spec_drafter is None:
             return draft, lens
         done = set(self._done_slots)
-        for slot, row in self._busy_rows.items():
+        for slot, row in self._seeded_rows():
             if slot in done:
                 continue
             toks = self.spec_drafter.draft(row)
@@ -2056,7 +2297,8 @@ class ContinuousBatchingEngine:
                 (telemetry.monotonic(), self.stats.prefills)
             )
         tok_host, acc_host = self.fetch(toks, acc)
-        for slot, row in self._busy_rows.items():
+        seeded = self._seeded_rows()
+        for slot, row in seeded:
             n_cols = int(acc_host[slot].sum())  # anchor + accepted drafts
             if lens[slot]:
                 n_drafted = int(lens[slot])
@@ -2090,7 +2332,7 @@ class ContinuousBatchingEngine:
             for j in range(acc_host.shape[1]):
                 emitted = {
                     row: int(tok_host[slot, j])
-                    for slot, row in self._busy_rows.items()
+                    for slot, row in seeded
                     if acc_host[slot, j]
                 }
                 if emitted:
@@ -2145,8 +2387,9 @@ class ContinuousBatchingEngine:
             # decode reads the same tap to keep the drafter histories
             # current through draftless fall-through steps.
             tok_host, live_host = self.fetch(token, live)
+            seeded = self._seeded_rows()
             if self.spec_drafter is not None:
-                for slot, row in self._busy_rows.items():
+                for slot, row in seeded:
                     if live_host[slot]:
                         self.spec_drafter.observe_tokens(
                             row, [int(tok_host[slot])]
@@ -2154,7 +2397,7 @@ class ContinuousBatchingEngine:
             if self.token_sink is not None:
                 emitted = {
                     row: int(tok_host[slot])
-                    for slot, row in self._busy_rows.items()
+                    for slot, row in seeded
                     if live_host[slot]
                 }
                 if emitted:
@@ -2256,6 +2499,8 @@ class ContinuousBatchingEngine:
             if self._inflight_admission is None:
                 self._apply_pending_push()
             self._admit()
-        if self._busy_rows:
+        # a decode step is for the rows that decode: slots an unfinished
+        # admission has reserved hold nothing to advance yet
+        if self._seeded_rows():
             self._step_once()
         return groups

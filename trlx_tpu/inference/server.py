@@ -132,6 +132,10 @@ class InferenceServer:
         from trlx_tpu.inference import RolloutEngineConfig
         from trlx_tpu.inference.engine import ContinuousBatchingEngine
         from trlx_tpu.models.heads import CausalLMWithValueHead
+        from trlx_tpu.ops.kv_cache import (
+            SERVING_PREFILL_MIN_SKIP_SHARE,
+            serving_prefill_chunk,
+        )
         from trlx_tpu.ops.sampling import (
             GenerationConfig,
             validate_gen_config,
@@ -248,6 +252,20 @@ class InferenceServer:
 
         spec = rollout.spec_decode
         spec_on = spec is not None and spec.enabled
+        # every running stream waits for whatever a pump iteration
+        # dispatches, so where the user set no chunk the admission goes
+        # through the engine's chunked prefill: a width derived from Q,
+        # one chunk forward an iteration (all-pad chunks are not
+        # computed), and a group that can skip fewer than half of its
+        # chunks forwarded whole. An explicit rollout.prefill_chunk wins,
+        # with the budget it came with and every group in chunks.
+        prefill_chunk = rollout.prefill_chunk
+        prefill_chunks_per_pump = rollout.prefill_chunks_per_pump
+        prefill_min_skip_share = 0.0
+        if not prefill_chunk:
+            prefill_chunk = serving_prefill_chunk(self.query_length)
+            prefill_chunks_per_pump = 1
+            prefill_min_skip_share = SERVING_PREFILL_MIN_SKIP_SHARE
         self.engine = ContinuousBatchingEngine(
             apply_fn=apply_fn,
             init_cache_fn=functools.partial(
@@ -265,8 +283,9 @@ class InferenceServer:
             with_values=True,
             prefix_pool_blocks=self.serving_config.prefix_cache_blocks,
             stream_taps=True,
-            prefill_chunk=rollout.prefill_chunk,
-            prefill_chunks_per_pump=rollout.prefill_chunks_per_pump,
+            prefill_chunk=prefill_chunk,
+            prefill_chunks_per_pump=prefill_chunks_per_pump,
+            prefill_min_skip_share=prefill_min_skip_share,
             spec_max_draft=spec.max_draft if spec_on else 0,
             spec_min_accept_ewma=(
                 spec.min_accept_ewma if spec_on else 0.0
@@ -276,6 +295,8 @@ class InferenceServer:
         # key-lineage engine's key-discard rule)
         phase_key = jax.random.fold_in(rng, 7)
         self.engine.start_phase(self.params, phase_key)
+        # set-up pays for every admission program; no pump compiles
+        self.engine.compile_admission_programs()
 
         from trlx_tpu import telemetry
 
@@ -844,7 +865,8 @@ class InferenceServer:
     def metrics(self) -> Dict[str, Any]:
         """The ``serve/*`` slice of the metrics-registry snapshot: the
         per-request latency histograms (summaries) and counters this
-        process accumulated — aggregate AND tenant-labeled keys."""
+        process accumulated — aggregate AND tenant-labeled keys — and
+        the histograms its engine observes (``engine/*``)."""
         snap = self._registry.snapshot()
         out: Dict[str, Any] = {}
         for section in ("counters", "gauges"):
@@ -852,6 +874,6 @@ class InferenceServer:
                 if name.startswith("serve/"):
                     out[name] = value
         for name, summary in snap.get("histograms", {}).items():
-            if name.startswith("serve/"):
+            if name.startswith(("serve/", "engine/")):
                 out[name] = summary
         return out

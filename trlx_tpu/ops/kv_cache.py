@@ -376,6 +376,42 @@ def choose_prefill_chunk(
     return fallback
 
 
+#: what a serving pump asks of ``choose_prefill_chunk`` where the user set
+#: no ``rollout.prefill_chunk``: this share of the prompt columns a chunk
+#: forward. Pinned from one sweep on a v5e in ``serve-pythia1b4-chat``
+#: (Q 512; PERF.md section 6, PR 30: Q // 8, Q // 4, Q // 2).
+SERVING_PREFILL_CHUNK_DIVISOR = 4
+
+
+def serving_prefill_chunk(query_length: int) -> int:
+    """The chunk width an ``InferenceServer`` requests for its engine
+    when ``rollout.prefill_chunk`` is unset: a constant rule of Q (the
+    engine rounds it with :func:`choose_prefill_chunk`). Under a serving
+    pump every running stream waits for whatever an iteration
+    dispatches, so an admission goes in chunks of this width, one
+    forward an iteration, and all-pad leading chunks are never computed;
+    the trainer's collect loop has no stream to stall and keeps the
+    width it is configured with."""
+    return max(1, int(query_length) // SERVING_PREFILL_CHUNK_DIVISOR)
+
+
+#: with the chunk a server derived itself: a group that can skip less
+#: than this share of its chunks (3 or 4 forwards of 4) is forwarded
+#: whole, as one monolithic ``prefill`` (``ContinuousBatchingEngine``'s
+#: ``prefill_min_skip_share``). Chunking pays through the columns it
+#: skips; such a group skips little, would hold the admission path for
+#: 3-4 iterations with the requests behind it waiting
+#: (``serve_queue_wait_p95_ms`` 29 -> 131 and ``serve_ttft_p95_ms`` up in
+#: three of four runs of ``serve-olmoe1b7b-chat`` with every group in
+#: chunks; PERF.md section 6, PR 30) and would pay the per-forward costs
+#: each time. About one group in five of chat traffic (a prompt over
+#: Q // 2), under 2% of the gaps between tokens: the p99 gap, not the
+#: p95, keeps the whole forward's length. (The benchmark's
+#: ``moe_gmm_prefill_roofline`` reads that program's grouped
+#: multiplication, 32768 rows: with 0 here it has nothing to read.)
+SERVING_PREFILL_MIN_SKIP_SHARE = 0.5
+
+
 def identity_block_tables(n_slots: int, n_blocks: int) -> jax.Array:
     """[B, n_blocks] int32 identity mapping (fresh slots)."""
     return jnp.broadcast_to(

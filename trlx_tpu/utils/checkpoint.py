@@ -33,20 +33,32 @@ import os
 import time
 from typing import Any, Dict, Optional, Tuple
 
-import orbax.checkpoint as ocp
-
 from trlx_tpu.resilience import chaos
 from trlx_tpu.utils.retry import classify_io_error, retry_call
+
+
+def _orbax():
+    """``orbax.checkpoint``, imported by the first save or load and not
+    with this module: the trainers import this module at their top, a
+    serving process imports a trainer module for its architecture table
+    and never writes a checkpoint, and the import is seconds of every
+    such process's set-up (about half of importing
+    ``trainer/ppo_trainer.py``)."""
+    import orbax.checkpoint as ocp
+
+    return ocp
+
 
 # One manager per directory: managers own background threads, per-directory
 # step bookkeeping, and (multi-host) coordination state. Async is always
 # enabled at the manager level; a *sync* save simply joins the write before
 # returning — so a directory never has two managers with divergent GC state.
-_managers: Dict[str, ocp.CheckpointManager] = {}
+_managers: Dict[str, Any] = {}  # directory -> ocp.CheckpointManager
 
 
-def _manager(directory: str) -> ocp.CheckpointManager:
+def _manager(directory: str):
     if directory not in _managers:
+        ocp = _orbax()
         _managers[directory] = ocp.CheckpointManager(
             directory,
             options=ocp.CheckpointManagerOptions(
@@ -67,6 +79,7 @@ def save_checkpoint(
     """Save state + metadata as one atomically-committed checkpoint under
     ``directory/<step>/``; the previous checkpoint survives until the new
     one commits."""
+    ocp = _orbax()
     directory = os.path.abspath(directory)
     os.makedirs(directory, exist_ok=True)
     mgr = _manager(directory)
@@ -195,6 +208,7 @@ def load_checkpoint(
     ``abstract_state`` (e.g. a different freezing mask or moment dtype)
     raises a :class:`ValueError` naming the config keys instead of Orbax's
     opaque internal mismatch error."""
+    ocp = _orbax()
     wait_for_checkpoints()
     directory = os.path.abspath(directory)
     mgr = _manager(directory)
