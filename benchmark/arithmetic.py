@@ -33,7 +33,19 @@ returns (:func:`model_shape` checks it):
   and ``attn_dim``, query heads x head size (QK^T and AV cost 4 FLOPs
   times this for each pair of token and context position), and
   ``kv_values``, the cache values written per position: KV heads x head
-  size x 2, and 0 in a block that keeps none.
+  size x 2, and 0 in a block that keeps none. Two more counts are given
+  only by a block that has them, and an absent one means what it meant
+  before they existed:
+
+  - ``kv_read_cap``: the most cached positions a decode step must read in
+    this block whatever the context - a window's width less the position
+    being written; a selection's top-k x block with its initial and local
+    blocks and, in ``kv_values``' units, its compressed keys. Absent: the
+    step reads its whole context;
+  - ``state_values``: the values of state a sequence reads and writes
+    once a step (a linear-attention or state-space block: heads x key size
+    x value size, plus any convolution tail), at the byte width the
+    configuration's ``run`` group states (``state_dtype``). Absent: none.
 - ``final``: the final norm and the head, as ``params`` (what they hold
   beyond the token table: a tied head holds nothing), ``matmul_params``
   and ``read_params``.
@@ -61,8 +73,10 @@ def load_peaks(device_kind: str) -> Dict[str, float]:
 
 
 LAYER_KEYS = ("params", "matmul_params", "read_params", "attn_dim", "kv_values")
+OPTIONAL_LAYER_KEYS = ("kv_read_cap", "state_values")
 FINAL_KEYS = ("params", "matmul_params", "read_params")
 ROUTED_KEYS = ("expert_params", "per_token")
+DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
 
 
 def model_shape(family, cfg: Dict[str, Any]) -> Dict[str, Any]:
@@ -72,7 +86,7 @@ def model_shape(family, cfg: Dict[str, Any]) -> Dict[str, Any]:
     s = family.shape(cfg)
     groups = [("shape", s, ("embed_params",)), ("final", s["final"], FINAL_KEYS)]
     for i, layer in enumerate(s["layers"]):
-        groups.append((f"layers[{i}]", layer, LAYER_KEYS))
+        groups.append((f"layers[{i}]", layer, LAYER_KEYS + tuple(k for k in OPTIONAL_LAYER_KEYS if k in layer)))
         if "routed" in layer:
             groups.append((f"layers[{i}].routed", layer["routed"], ROUTED_KEYS))
     for where, group, keys in groups:
@@ -142,13 +156,27 @@ def decode_read_params(layer) -> int:
 
 
 def decode_step_bytes(s, batch: float, context: float, weight_bytes: int = 2,
-                      kv_bytes: int = 2, shards: int = 1) -> float:
+                      kv_bytes: int = 2, shards: int = 1, state_bytes: int = None) -> float:
     """Bytes one decode step must move on one chip: the weights it must
     read (:func:`decode_read_params` of every block, the final norm and
     the head matrix, tied or not) once at the compute dtype, divided over
     ``shards`` chips where the weights are sharded, as fsdp leaves them to
-    be gathered; the keys and values of ``context`` cached positions read
-    and one position written, at the cache dtype, for ``batch`` sequences."""
+    be gathered; for ``batch`` sequences, at the cache dtype, the keys and
+    values of ``context`` cached positions read - in a block that gives
+    ``kv_read_cap``, of that many at most - and one position written; and
+    a block's ``state_values`` read and written once a sequence at
+    ``state_bytes`` a value. The count is the least a correct program can
+    move, so a share of the roofline over 100% is a fault of the program's
+    accounting or of a family's shape rule, never of this formula: a cap
+    or a state must be what the published equations require a step to
+    touch, not what one implementation happens to."""
     weights = sum(decode_read_params(l) for l in s["layers"]) + s["final"]["read_params"]
-    kv = sum(l["kv_values"] for l in s["layers"]) * batch * (context + 1) * kv_bytes
-    return weights * weight_bytes / shards + kv
+    whole = sum(l["kv_values"] for l in s["layers"] if "kv_read_cap" not in l)
+    kv = whole * batch * (context + 1) * kv_bytes
+    kv += sum(l["kv_values"] * batch * (min(context, l["kv_read_cap"]) + 1) * kv_bytes
+              for l in s["layers"] if "kv_read_cap" in l)
+    state = sum(l.get("state_values", 0) for l in s["layers"])
+    if state and state_bytes is None:
+        raise ValueError("the shape has blocks with `state_values` and no `state_bytes` was given: "
+                         "the configuration's `run` group states no `state_dtype`")
+    return weights * weight_bytes / shards + kv + 2 * state * batch * (state_bytes or 0)

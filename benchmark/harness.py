@@ -16,6 +16,7 @@ import glob
 import importlib.util
 import json
 import os
+import sys
 import time
 from typing import Any, Dict, List, Optional
 
@@ -50,7 +51,9 @@ def load_family(config_file: Dict[str, Any], root: str = HERE):
     path (relative to the checkout, which is the parent of ``root``),
     loaded by file path so that a copy of the tree under another root
     brings its own families. The module exports ``forward(params, cfg,
-    ids, mask)`` (the plain float32 reference), ``shape(cfg)`` (the sizes
+    ids, mask)`` (the plain float32 reference) as the composition of its
+    two halves ``trunk(params, cfg, ids, mask)`` and ``head(params, cfg,
+    hidden)``, which the checks call, ``shape(cfg)`` (the sizes
     ``arithmetic.py`` reckons with) and, where the family has one,
     ``check_config(cfg)``, which raises on a configuration the program
     cannot build as published."""
@@ -62,9 +65,9 @@ def load_family(config_file: Dict[str, Any], root: str = HERE):
 
 
 def load_cell(name: str, root: str = HERE) -> Dict[str, Any]:
-    """A cell with its configuration and traffic mix resolved by name, and
-    the configuration's ``family`` (:func:`load_family`), which has
-    checked the configuration."""
+    """A cell with its configuration and traffic mix resolved by name, the
+    ``root`` they were found under, and the configuration's ``family``
+    (:func:`load_family`), which has checked the configuration."""
     def read(kind, key):
         path = os.path.join(root, kind, f"{key}.json")
         if not os.path.exists(path):
@@ -75,6 +78,7 @@ def load_cell(name: str, root: str = HERE) -> Dict[str, Any]:
     cell = read("workloads", name)
     cell["config_file"] = read("configs", cell["config"])
     cell["traffic_file"] = read("traffic", cell["traffic"])
+    cell["root"] = root
     cell["family"] = load_family(cell["config_file"], root)
     if hasattr(cell["family"], "check_config"):
         cell["family"].check_config(cell["config_file"])
@@ -277,11 +281,27 @@ class ProfilerWindow:
 # ------------------------------ the result ------------------------------ #
 
 
-def check_line(name: str, value: float, tolerance: str, ok: bool) -> bool:
-    """One sub-check of ``correct`` on a line of its own, so that a
-    ``false`` in a log says which comparison failed."""
-    print(f"check {name}: value={value!r} tolerance={tolerance} ok={bool(ok)}", flush=True)
-    return bool(ok)
+class CheckLog:
+    """The sub-checks of one run's ``correct``: each printed on a line of
+    its own as it is made, so that a ``false`` in a log says which
+    comparison failed, and kept, the number compared beside its limit, for
+    the end of the result line and of the error stream (what a record of a
+    refused run keeps)."""
+
+    def __init__(self):
+        self.entries: Dict[str, Dict[str, Any]] = {}
+
+    def line(self, name: str, value, limit: str, ok: bool) -> bool:
+        self.entries[name] = {"value": value, "limit": limit, "ok": bool(ok)}
+        self.echo(sys.stdout, [name])
+        return bool(ok)
+
+    def echo(self, stream, names=None) -> None:
+        """The named checks (all of them where none is named), a line each."""
+        for name in self.entries if names is None else names:
+            c = self.entries[name]
+            print(f"check {name}: value={c['value']!r} tolerance={c['limit']} ok={c['ok']}",
+                  file=stream, flush=True)
 
 
 def median(values: List[float]) -> Optional[float]:
@@ -292,11 +312,14 @@ def median(values: List[float]) -> Optional[float]:
 
 def result_line(correct: bool, attempted: int, failed: int,
                 metrics: Dict[str, Dict[str, Any]], device: Dict[str, Any],
-                breakdown: Optional[Dict[str, Any]] = None) -> str:
+                breakdown: Optional[Dict[str, Any]] = None,
+                checks: Optional[Dict[str, Dict[str, Any]]] = None) -> str:
+    """The result, ``checks`` (each number compared beside its limit) last."""
     out: Dict[str, Any] = {
         "correct": bool(correct), "attempted": int(attempted),
         "failed": int(failed), "metrics": metrics, "device": device,
     }
     if breakdown is not None:
         out["breakdown"] = breakdown
+    out["checks"] = checks or {}
     return json.dumps(out)

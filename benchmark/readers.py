@@ -30,7 +30,8 @@ Kinds:
   over the module's mean device time per call, %. Decode is bound by
   memory traffic, so this is its share of the roofline.
 - ``collective``: ``what`` (``ms_per_phase``/``exposed_share``).
-- ``memory_peak_gb``.
+- ``memory_peak_gb``: the peak on the fullest chip as the driver read it
+  when the window had closed, before the reference ran.
 - ``counter``: ``name``[, ``per`` (``phase``/``second``)] - a counter (its
   increments inside the window) or a gauge (its last value) of the
   program's metrics registry, as it is, per completed phase, or per
@@ -133,6 +134,7 @@ def decode_hbm_share(record, spec):
         record["shape"], d["batch"] / record["chips"], d["mean_context"],
         weight_bytes=2, kv_bytes=1 if record["kv_cache_dtype"] == "int8" else 2,
         shards=d.get("weight_shards", 1),
+        state_bytes=arithmetic.DTYPE_BYTES.get(record.get("state_dtype")),
     )
     step_s = seconds / (calls * d.get("steps_per_call", 1))
     return 100.0 * need / record["device"]["peaks"]["hbm_bytes_per_s"] / step_s
@@ -148,7 +150,7 @@ def collective(record, spec):
 
 
 def memory_peak_gb(record, spec):
-    peak = harness.memory_peak_bytes()
+    peak = record.get("memory_peak_bytes")
     return peak / 1e9 if peak else None
 
 

@@ -17,6 +17,10 @@ that passes ``attention_mask`` and ``position_ids``.
 ln_2,mlp/{c_fc,c_proj}}``, ``ln_f``); it is read as float32 whatever it is
 stored in.
 
+The forward comes in two halves, :func:`trunk` and :func:`head`, so that a
+check can ask for the logits of a few positions without forming those of
+every one (``benchmark/checks.py``); :func:`forward` is their composition.
+
 :func:`shape` is the family's shape rule: what ``benchmark/arithmetic.py``
 reckons parameters, FLOPs and bytes from (the keys are explained there).
 """
@@ -40,24 +44,48 @@ def gelu_tanh(x):
     return 0.5 * x * (1.0 + jnp.tanh(0.7978845608028654 * (x + 0.044715 * x**3)))
 
 
-def masked_attention(q, k, v, mask):
-    """q, k, v: [B, T, H, Dh]; mask: [B, T] of 0/1. Causal, padded keys out."""
-    T = q.shape[1]
+QUERY_BLOCK = 1024
+
+
+def attend(q, k, v, mask, first=0):
+    """q: [B, Tq, H, Dh], the queries at positions ``first`` onwards; k, v:
+    [B, T, H, Dh]; mask: [B, T] of 0/1. Causal, padded keys out."""
+    T = k.shape[1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(q.shape[-1])
-    allowed = jnp.tril(jnp.ones((T, T), bool))[None, None] & (
+    at = first + jnp.arange(q.shape[1])
+    allowed = (jnp.arange(T)[None, :] <= at[:, None])[None, None] & (
         mask[:, None, None, :] > 0
     )
     scores = jnp.where(allowed, scores, -1e30)
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v)
 
 
+def masked_attention(q, k, v, mask):
+    """q, k, v: [B, T, H, Dh]; mask: [B, T] of 0/1. Causal, padded keys out.
+    Past ``QUERY_BLOCK`` positions the queries go ``QUERY_BLOCK`` at a time,
+    each block against every key: a query's row of the softmax is its own,
+    so the arithmetic is the same and no [T, T] array is formed."""
+    B, T, H, Dh = q.shape
+    if T <= QUERY_BLOCK:
+        return attend(q, k, v, mask)
+    blocks = -(-T // QUERY_BLOCK)
+    padded = jnp.pad(q, ((0, 0), (0, blocks * QUERY_BLOCK - T), (0, 0), (0, 0)))
+    padded = jnp.moveaxis(padded.reshape(B, blocks, QUERY_BLOCK, H, Dh), 1, 0)
+    out = jax.lax.map(
+        lambda x: attend(x[0], k, v, mask, x[1]),
+        (padded, jnp.arange(blocks) * QUERY_BLOCK),
+    )
+    return jnp.moveaxis(out, 0, 1).reshape(B, blocks * QUERY_BLOCK, H, Dh)[:, :T]
+
+
 def positions_of(mask):
     return jnp.clip(jnp.cumsum(mask, axis=-1) - 1, 0, None)
 
 
-def forward(params, cfg, input_ids, mask):
-    """Logits [B, T, V] in float32. ``cfg`` holds the HF keys ``n_embd``,
-    ``n_layer``, ``n_head`` and optionally ``layer_norm_epsilon``."""
+def trunk(params, cfg, input_ids, mask):
+    """The hidden states after the final LayerNorm, [B, T, D] in float32.
+    ``cfg`` holds the HF keys ``n_embd``, ``n_layer``, ``n_head`` and
+    optionally ``layer_norm_epsilon``."""
     p = jax.tree_util.tree_map(lambda a: jnp.asarray(a, jnp.float32), params)
     eps = cfg.get("layer_norm_epsilon", 1e-5)
     H = cfg["n_head"]
@@ -72,8 +100,18 @@ def forward(params, cfg, input_ids, mask):
             x = x + dense(a, blk["attn"]["c_proj"])
             h = gelu_tanh(dense(layer_norm(x, blk["ln_2"], eps), blk["mlp"]["c_fc"]))
             x = x + dense(h, blk["mlp"]["c_proj"])
-        x = layer_norm(x, p["ln_f"], eps)
-        return x @ p["wte"]["embedding"].T
+        return layer_norm(x, p["ln_f"], eps)
+
+
+def head(params, cfg, hidden):
+    """Logits [..., V] of hidden states [..., D]: the token table again."""
+    with jax.default_matmul_precision("highest"):
+        return hidden @ jnp.asarray(params["wte"]["embedding"], jnp.float32).T
+
+
+def forward(params, cfg, input_ids, mask):
+    """Logits [B, T, V] in float32: the head on every position of the trunk."""
+    return head(params, cfg, trunk(params, cfg, input_ids, mask))
 
 
 def block_shape(d, ff):

@@ -57,11 +57,11 @@ def gelu_erf(x):
 ACTIVATIONS = {"gelu": gelu_erf, "gelu_new": gelu_tanh}
 
 
-def forward(params, cfg, input_ids, mask):
-    """Logits [B, T, V] in float32. ``cfg`` holds the HF keys ``hidden_size``,
-    ``num_hidden_layers``, ``num_attention_heads``, ``rotary_pct``,
-    ``rotary_emb_base``, ``use_parallel_residual``, ``layer_norm_eps``,
-    ``hidden_act``."""
+def trunk(params, cfg, input_ids, mask):
+    """The hidden states after the final LayerNorm, [B, T, D] in float32.
+    ``cfg`` holds the HF keys ``hidden_size``, ``num_hidden_layers``,
+    ``num_attention_heads``, ``rotary_pct``, ``rotary_emb_base``,
+    ``use_parallel_residual``, ``layer_norm_eps``, ``hidden_act``."""
     act = ACTIVATIONS[cfg.get("hidden_act", "gelu")]
     p = jax.tree_util.tree_map(lambda a: jnp.asarray(a, jnp.float32), params)
     eps = cfg.get("layer_norm_eps", 1e-5)
@@ -86,8 +86,19 @@ def forward(params, cfg, input_ids, mask):
                 m_in = layer_norm(x + a, blk["ln_2"], eps)
             m = dense(act(dense(m_in, blk["mlp"]["dense_h_to_4h"])), blk["mlp"]["dense_4h_to_h"])
             x = x + a + m
-        x = layer_norm(x, p["ln_f"], eps)
-        return dense(x, p["lm_head"])
+        return layer_norm(x, p["ln_f"], eps)
+
+
+def head(params, cfg, hidden):
+    """Logits [..., V] of hidden states [..., D]: the untied ``lm_head``."""
+    p = jax.tree_util.tree_map(lambda a: jnp.asarray(a, jnp.float32), params["lm_head"])
+    with jax.default_matmul_precision("highest"):
+        return dense(hidden, p)
+
+
+def forward(params, cfg, input_ids, mask):
+    """Logits [B, T, V] in float32: the head on every position of the trunk."""
+    return head(params, cfg, trunk(params, cfg, input_ids, mask))
 
 
 def shape(cfg):

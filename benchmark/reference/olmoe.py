@@ -70,11 +70,14 @@ def experts(h, mlp, weights):
     return out
 
 
-def forward_with_aux(params, cfg, input_ids, mask):
-    """(logits [B, T, V] float32, the load-balancing loss as the program
-    sows it: the mean over blocks of ``E * sum_e f_e P_e``, ``f_e`` the
-    copies routed to expert ``e`` per token, ``P_e`` the mean ``p_e``, both
-    over the real tokens)."""
+def trunk_with_aux(params, cfg, input_ids, mask):
+    """(the hidden states after the final RMSNorm, [B, T, D] float32; the
+    load-balancing loss as the program sows it: the mean over blocks of
+    ``E * sum_e f_e P_e``, ``f_e`` the copies routed to expert ``e`` per
+    token, ``P_e`` the mean ``p_e``, both over the real tokens). ``cfg``
+    holds the HF keys ``hidden_size``, ``num_hidden_layers``,
+    ``num_attention_heads``, ``num_experts``, ``num_experts_per_tok``,
+    ``norm_topk_prob``, ``rms_norm_eps``, ``rope_theta``."""
     eps, H = cfg["rms_norm_eps"], cfg["num_attention_heads"]
     E, k = cfg["num_experts"], cfg["num_experts_per_tok"]
     pos = positions_of(mask)
@@ -98,15 +101,27 @@ def forward_with_aux(params, cfg, input_ids, mask):
             real = mask.astype(jnp.float32)[..., None] / mask.sum()
             per_token = jax.lax.stop_gradient(((w > 0) * real).sum((0, 1)))
             aux.append(E * (per_token * (p * real).sum((0, 1))).sum())
-        x = rms_norm(x, params["ln_f"], eps)
-        return x @ f32(params["lm_head"]["kernel"]), jnp.mean(jnp.stack(aux))
+        return rms_norm(x, params["ln_f"], eps), jnp.mean(jnp.stack(aux))
+
+
+def trunk(params, cfg, input_ids, mask):
+    return trunk_with_aux(params, cfg, input_ids, mask)[0]
+
+
+def head(params, cfg, hidden):
+    """Logits [..., V] of hidden states [..., D]: the untied ``lm_head``."""
+    with jax.default_matmul_precision("highest"):
+        return hidden @ f32(params["lm_head"]["kernel"])
+
+
+def forward_with_aux(params, cfg, input_ids, mask):
+    """(logits [B, T, V] float32, the load-balancing loss)."""
+    hidden, aux = trunk_with_aux(params, cfg, input_ids, mask)
+    return head(params, cfg, hidden), aux
 
 
 def forward(params, cfg, input_ids, mask):
-    """Logits [B, T, V] in float32. ``cfg`` holds the HF keys
-    ``hidden_size``, ``num_hidden_layers``, ``num_attention_heads``,
-    ``num_experts``, ``num_experts_per_tok``, ``norm_topk_prob``,
-    ``rms_norm_eps``, ``rope_theta``."""
+    """Logits [B, T, V] in float32: the head on every position of the trunk."""
     return forward_with_aux(params, cfg, input_ids, mask)[0]
 
 

@@ -8,7 +8,9 @@ metrics by name under ``benchmark/``, runs the cell's driver in this one
 process (which holds the chips), and prints one JSON object as the last
 line of its output: with ``--trace 0`` the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, ``busy_s``/``window_s`` and the
-breakdown. Every sub-check of ``correct`` is printed on an earlier line.
+breakdown, and in both, last, ``checks``: every number ``correct`` compared
+beside its limit, each also on an earlier line and again as the last lines
+of the error stream.
 Exits nonzero, and prints no result, without a TPU listed in
 ``benchmark/peaks.json`` or with fewer chips than the cell asks for.
 """
@@ -31,10 +33,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     (an already-loaded, shrunken cell) are the tests' rehearsal; the
     command below passes neither."""
     os.environ.setdefault("WANDB_DISABLED", "1")
-    from benchmark import harness, readers
+    from benchmark import checks, harness, readers
 
     t_start = time.time() if t_start is None else t_start
     cell = harness.load_cell(workload) if cell is None else cell
+    checks.tolerance_table(cell["config_file"], cell["root"])  # a broken table is refused before the run
     harness.place_compile_cache()
     t_jax = time.time()
     device = harness.require_chips(int(cell["chips"]), allow_cpu=allow_cpu)
@@ -53,7 +56,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     record = out["record"]
     dev = {
         "platform": device["platform"], "kind": device["kind"], "count": device["count"],
-        "memory_peak_bytes": harness.memory_peak_bytes(),
+        "memory_peak_bytes": record["memory_peak_bytes"],
     }
     breakdown = None
     if trace:
@@ -65,8 +68,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             breakdown = {"device_ops": reduced["device_ops"], "idle_gaps": reduced["idle_gaps"]}
     else:
         metrics = out["end_to_end"]
+    out["checks"].echo(sys.stderr)  # again, as the last lines of the error stream
     return harness.result_line(
-        out["correct"], out["attempted"], out["failed"], metrics, dev, breakdown
+        out["correct"], out["attempted"], out["failed"], metrics, dev, breakdown, out["checks"].entries
     )
 
 

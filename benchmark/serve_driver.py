@@ -272,6 +272,8 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
     tokens_in_window = seen["tokens_in_window"]
 
     # ---------------- outside the window: what decides `correct` ---------------- #
+    memory_peak = harness.memory_peak_bytes()  # the program's own: read before the reference runs
+    log = harness.CheckLog()
     ok = True
     bad_len = bad_tok = 0
     for c in done:
@@ -284,14 +286,13 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
             bad_len += 1
         if any(not 0 <= int(x) < vocab for x in toks):
             bad_tok += 1
-    ok &= harness.check_line("accounting.requests_off_budget", bad_len,
-                             f"== 0 (each returned {budget} tokens or stopped on EOS; "
-                             "streamed as many as returned)", bad_len == 0)
-    ok &= harness.check_line("accounting.requests_with_token_outside_vocab", bad_tok, "== 0",
-                             bad_tok == 0)
-    ok &= harness.check_line("accounting.compiles_in_window", compiled_in_window, "== 0",
-                             compiled_in_window == 0)
-    ok &= reference_check(cell, server, seed)
+    ok &= log.line("accounting.requests_off_budget", bad_len,
+                   f"== 0 (each returned {budget} tokens or stopped on EOS; "
+                   "streamed as many as returned)", bad_len == 0)
+    ok &= log.line("accounting.requests_with_token_outside_vocab", bad_tok, "== 0", bad_tok == 0)
+    ok &= log.line("accounting.compiles_in_window", compiled_in_window, "== 0",
+                   compiled_in_window == 0)
+    ok &= reference_check(cell, server, seed, log)
     events = [e.to_dict().get("detector") for e in server.health_events]
     print(f"note health_events (not part of correct): {events}", flush=True)
 
@@ -307,6 +308,7 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
         "chips": cell["chips"],
         "shape": model_shape(cell["family"], cf), "flops": (0.0, 0.0),
         "kv_cache_dtype": harness.kv_dtype_of(cf, t["seq_length"] + budget),
+        "state_dtype": cf["run"].get("state_dtype"), "memory_peak_bytes": memory_peak,
         "histograms": {k: v for k, v in metrics_now.items() if isinstance(v, dict)},
         "counters": scalars["counters"], "gauges": scalars["gauges"],
         "engine_slot_util_pct": 100.0 * slot_util,
@@ -337,10 +339,10 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
           f"lag_p95_ms={record['loadgen_lag_p95_ms']} ended_s={out['ended_s']:.2f} "
           f"stream_tokens_past_budget={sum(c.surplus for c in done)}", flush=True)
     return {"correct": bool(ok), "attempted": attempted, "failed": failed,
-            "end_to_end": end_to_end, "record": record}
+            "end_to_end": end_to_end, "record": record, "checks": log}
 
 
-def reference_check(cell: Dict[str, Any], server, seed: int) -> bool:
+def reference_check(cell: Dict[str, Any], server, seed: int, log: harness.CheckLog) -> bool:
     """One more harvest group through the idle server (admission prefill,
     then decode through the paged cache); for ``N_CHECK_ROWS`` of its
     requests the log-probabilities the engine recorded for the tokens it
@@ -377,8 +379,7 @@ def reference_check(cell: Dict[str, Any], server, seed: int) -> bool:
             if rid in rids[:N_CHECK_ROWS]:
                 found[rid] = {k: v[j] for k, v in fetched.items()}
     if len(found) != N_CHECK_ROWS:
-        return harness.check_line("reference.requests_read_back", len(found),
-                                  f"== {N_CHECK_ROWS}", False)
+        return log.line("reference.requests_read_back", len(found), f"== {N_CHECK_ROWS}", False)
     padded = [server._pad_prompt(p, i) for i, p in enumerate(prompts[:N_CHECK_ROWS])]
     ids = np.stack([p[0] for p in padded])
     mask = np.stack([p[1] for p in padded])
@@ -390,8 +391,7 @@ def reference_check(cell: Dict[str, Any], server, seed: int) -> bool:
     params = jax.device_put(server.params, one)
     ref = checks.reference_logits(
         cell["family"], cf, params["transformer"],
-        jax.device_put(jnp.asarray(full_ids), one), jax.device_put(jnp.asarray(full_mask), one),
+        jax.device_put(jnp.asarray(full_ids), one), jax.device_put(jnp.asarray(full_mask), one), Q,
     )
-    tol = checks.tolerance_for(cf["run"]["dtype"], harness.kv_dtype_of(cf, Q + t["max_new_tokens"]))
-    return checks.compare_with_reference(
-        "reference", ref, Q, r_ids, r_mask, r_lp, None, tol)
+    tol = checks.tolerances_of(cf, harness.kv_dtype_of(cf, Q + t["max_new_tokens"]), cell["root"])
+    return checks.compare_with_reference(log, "reference", ref, r_ids, r_mask, r_lp, None, tol)

@@ -1,7 +1,9 @@
 """``BENCHMARK.json`` against the contract's format rules, and against the
 data files the harness finds by name; and that a model family, a
 configuration, a cell and a per-layer metric can each be added as new
-files and manifest entries only (``files_only/`` holds the files)."""
+files and manifest entries only (``files_only/`` holds the files: the family with its two halves, a window
+block and a state block in its shape rule, and a tolerance file of the
+configuration's own)."""
 
 import json
 import os
@@ -18,7 +20,9 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-WIDTHS = re.compile(r"(hidden|intermediate|latent|state|proj|_dim$|_rank$|head_dim|n_embd|n_inner|expand)")
+# a key of `reduced` may never name a width; a count of layers (the depth cut) is none,
+# though `num_hidden_layers` holds the word
+WIDTHS = re.compile(r"(hidden(?!_layers$)|intermediate|latent|state|proj|window|_dim$|_rank$|head_dim|n_embd|n_inner|expand)")
 
 
 @pytest.fixture(scope="module")
@@ -93,6 +97,16 @@ def test_cells_configs_and_traffic_resolve_to_files(manifest):
         assert (cf["name"], cf["source"], cf["reduced"]) == (c["name"], c["source"], c["reduced"])
         assert "assumed" in cf and not any(WIDTHS.search(k) for k in cf["reduced"])
         assert os.path.exists(os.path.join(REPO, cf["reference"]))
+
+
+@pytest.mark.parametrize("key,is_width", [
+    ("num_hidden_layers", False), ("num_layers", False), ("n_layer", False),
+    ("hidden_size", True), ("intermediate_size", True), ("moe_intermediate_size", True),
+    ("head_dim", True), ("mamba_d_state", True), ("lightning_head_dim", True),
+    ("kv_lora_rank", True), ("sliding_window", True),
+])
+def test_a_depth_cut_is_no_width_and_a_width_is(key, is_width):
+    assert bool(WIDTHS.search(key)) == is_width
 
 
 def test_at_most_one_cell_asks_for_four_chips(manifest):
@@ -204,16 +218,44 @@ def test_a_family_a_config_a_cell_and_a_metric_are_added_as_files_only(a_later_p
     per_token = arithmetic.forward_flops(s, 1, 0, 1)
     assert per_token == 2 * ((3072 + 6144) + 2 * 6400 + 3072) == 50176
     assert per_token < 2 * ((3072 + 6144) + 2 * (3072 + 256 + 8 * 1536) + 3072) == 87040
-    # the cache is 2 KV heads x 8 x 2 = 32 values a position and block (2d would be 64)
-    assert [l["kv_values"] for l in s["layers"]] == [32, 32, 32]
-    # a decode step of 4 sequences at 9 cached positions, bf16 weights and cache: it
-    # reads the dense block whole, of a routed block all but the experts (3392) and
-    # 2 of them, the final norm and the head; and 3 x 32 values x 10 positions a row
+    # the cache is 2 KV heads x 8 x 2 = 32 values a position and block (2d would be 64);
+    # the linear block keeps none, and a state of 2 KV heads x 8 x 8 = 128 values a sequence;
+    # the window block (4 wide) reads 3 cached positions at most beside the one it writes
+    assert [l["kv_values"] for l in s["layers"]] == [32, 32, 0]
+    assert [(l.get("kv_read_cap"), l.get("state_values")) for l in s["layers"]] == [
+        (None, None), (3, None), (None, 128)]
+    # a decode step of 4 sequences at 9 cached positions, bf16 weights and cache, float32
+    # state: it reads the dense block whole, of a routed block all but the experts (3392)
+    # and 2 of them, the final norm and the head; the full block's 32 values x 10
+    # positions a row and the window block's 32 x (3 + 1); and reads and writes 128
+    # values of state a row
     weights = 9280 + 2 * (3392 + 2 * 1536) + (32 + 3072)
-    assert arithmetic.decode_step_bytes(s, 4, 9) == 2 * weights + 2 * (96 * 4 * 10) == 58304
-    assert arithmetic.decode_step_bytes(s, 4, 9, shards=4) == 2 * weights / 4 + 7680
+    cache = 2 * 4 * (32 * 10 + 32 * 4)
+    state = 2 * 4 * 128 * 4
+    assert (2 * weights, cache, state) == (50624, 3584, 4096)
+    assert arithmetic.decode_step_bytes(s, 4, 9, state_bytes=4) == 50624 + 3584 + 4096 == 58304
+    assert arithmetic.decode_step_bytes(s, 4, 9, shards=4, state_bytes=4) == 50624 / 4 + 7680
+    # below, at and above the window's cap of 3 cached positions: the window block follows
+    # the context up to it and no further, the full block all the way
+    assert [arithmetic.decode_step_bytes(s, 1, c, state_bytes=0) - 2 * weights
+            for c in (2, 3, 4, 100)] == [2 * 32 * (3 + 3), 2 * 32 * (4 + 4), 2 * 32 * (5 + 4),
+                                         2 * 32 * (101 + 4)]
     # the weights read do not grow with the batch: 2 experts a routed block is the floor
-    assert arithmetic.decode_step_bytes(s, 64, 0, kv_bytes=0) == 2 * weights
+    assert arithmetic.decode_step_bytes(s, 64, 0, kv_bytes=0, state_bytes=0) == 2 * weights
+    # a state needs its byte width: the configuration's run group states it
+    assert cf["run"]["state_dtype"] == "float32" and arithmetic.DTYPE_BYTES["float32"] == 4
+    with pytest.raises(ValueError, match="state_dtype"):
+        arithmetic.decode_step_bytes(s, 4, 9)
+    # and the reader passes it on as it passes the cache's
+    record = {"xplane": "recorded", "trace": {"devices": [0], "modules": {"jit_sampler": {"s": 1.0, "count": 1}}},
+              "shape": s, "chips": 1, "kv_cache_dtype": "bfloat16", "state_dtype": cf["run"]["state_dtype"],
+              "decode": {"batch": 4, "mean_context": 9}, "device": {"peaks": {"hbm_bytes_per_s": 58304.0}}}
+    assert readers.decode_hbm_share(record, {"module": "jit_sampler"}) == 100.0
+
+    # the configuration names a tolerance file of its own, and is held to that
+    assert checks.tolerances_of(cf, "bfloat16", root=str(root)) == {
+        "logits_rms_rel": 0.02, "logits_max_rel": 0.12, "logprob_rms": 0.012, "logprob_max": 0.09}
+    assert checks.tolerances_of(cf, "bfloat16", root=str(root)) != checks.tolerance_for("bfloat16", "bfloat16")
 
     # the cell reads a metric that was there and a new one of the new kind
     specs = harness.load_layer_metrics("ppo-toymoe-tldr", root=str(root))
@@ -254,15 +296,27 @@ def test_the_added_familys_reference_runs_through_the_checks(a_later_prs_tree):
     ids = rng.integers(0, V, (2, 12))
     mask = np.ones((2, 12), np.int32)
     mask[1, :5] = 0  # left-padded
-    logits = np.asarray(checks.reference_logits(cell["family"], cf, params, ids, mask))
-    assert logits.shape == (2, 12, V) and np.isfinite(logits).all()
+    # the checks take the two halves a row at a time, at the response-predicting
+    # positions Q-1 .. T-2 alone; every logit is the composition's, for the tests
+    Q = 8
+    cut = checks.reference_logits(cell["family"], cf, params, ids, mask, Q)
+    assert cut.shape == (2, 12 - Q, V) and np.isfinite(cut).all()
+    logits = np.asarray(cell["family"].forward(params, cf, ids, mask))
+    assert logits.shape == (2, 12, V) and np.abs(cut - logits[:, Q - 1 : -1]).max() <= 1e-5
     # causal: a later token changes no earlier real position's logits
     ids2 = ids.copy()
     ids2[:, -1] = (ids2[:, -1] + 1) % V
-    again = np.asarray(checks.reference_logits(cell["family"], cf, params, ids2, mask))
+    again = np.asarray(cell["family"].forward(params, cf, ids2, mask))
     real = mask[:, :-1].astype(bool)
     assert np.allclose(logits[:, :-1][real], again[:, :-1][real], atol=1e-5)
     assert np.abs(logits[:, -1] - again[:, -1]).max() > 0.1
+    # the window block forgets: with every block a window of 4, three of them see 9 places
+    # back and the first token, 11 back, moves nothing; the full and the linear block remember it
+    ids3 = ids.copy()
+    ids3[:, 0] = (ids3[:, 0] + 1) % V
+    far = lambda c: np.abs(np.asarray(cell["family"].forward(params, c, ids3, mask))
+                           - np.asarray(cell["family"].forward(params, c, ids, mask)))[:, -1].max()
+    assert far(dict(cf, layer_types=["window"] * 3)) < 1e-5 < far(cf)
 
 
 def test_the_ppo_driver_takes_the_rollout_engine_from_the_traffic_file():

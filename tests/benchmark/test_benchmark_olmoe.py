@@ -88,6 +88,31 @@ def test_prefill_count_is_the_routed_rows_work(config_file):
     assert moved == 3 * 2 * 32768 * (2048 + 1024)
 
 
+def test_the_two_gmm_readers_split_the_forwards_from_the_decode_step():
+    """A server's admission runs the grouped multiplication at a chunk
+    forward's 8192 rows (8 x 128 columns x 8 experts a token) and at the
+    whole group's 32768; a decode step runs it at 256 (32 slots x 8)."""
+    import re
+
+    prefill, decode = (re.compile(harness.load_json("layer_metrics", f"moe_gmm_{k}_roofline.json")["reader"]["op"])
+                       for k in ("prefill", "decode"))
+    names = {rows: [f"ragged-dot-none bf16[{rows},{w}]" for w in (1024, 2048)] for rows in (256, 8192, 32768)}
+    for rows in (8192, 32768):
+        assert all(prefill.search(n) and not decode.search(n) for n in names[rows])
+    assert all(decode.search(n) and not prefill.search(n) for n in names[256])
+    assert not prefill.search("fusion bf16[8192,2048]") and not prefill.search("ragged-dot-none f32[8192,1024]")
+
+
+def test_prefill_count_adds_the_chunk_forwards_to_the_whole_ones(config_file):
+    family = harness.load_family(config_file)
+    ops = {"ragged-dot-none bf16[8192,1024]": {"s": 1.0, "count": 50},
+           "ragged-dot-none bf16[8192,2048]": {"s": 1.0, "count": 25},
+           "ragged-dot-none bf16[32768,1024]": {"s": 1.0, "count": 14},
+           "ragged-dot-none bf16[32768,2048]": {"s": 1.0, "count": 7}}
+    flops, _ = family.gmm_prefill_count(record_of(config_file), ops)
+    assert flops == 3 * 2 * 2048 * 1024 * (25 * 8192 + 7 * 32768)
+
+
 def test_decode_count_reads_the_touched_experts_only(config_file):
     family = harness.load_family(config_file)
     ops = {"ragged-dot bf16[256,1024]": {"s": 1.0, "count": 2},

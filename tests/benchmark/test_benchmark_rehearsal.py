@@ -48,7 +48,11 @@ def quiet_program(monkeypatch, tmp_path):
 
 def last_line(name, trace, capsys, seconds=1.0):
     line = run_cell(name, 2**31 + 77, seconds, trace, allow_cpu=True, cell=shrunk(name))
-    checks = [l for l in capsys.readouterr().out.splitlines() if l.startswith("check ")]
+    said = capsys.readouterr()
+    checks = [l for l in said.out.splitlines() if l.startswith("check ")]
+    # the error stream ends with the same checks, each number beside its limit
+    assert [l.split(":")[0] for l in said.err.splitlines()[-len(checks):]] == [
+        l.split(":")[0] for l in checks]
     return json.loads(line), checks
 
 
@@ -58,7 +62,7 @@ def last_line(name, trace, capsys, seconds=1.0):
 ])
 def test_end_to_end_line_has_exactly_the_contract_keys(name, metrics, capsys):
     out, checks = last_line(name, False, capsys)
-    assert set(out) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(out) == ["correct", "attempted", "failed", "metrics", "device", "checks"]
     assert set(out["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
     assert out["device"]["platform"] == "cpu"  # a rehearsal says what it ran on
     assert set(out["metrics"]) == metrics
@@ -69,6 +73,32 @@ def test_end_to_end_line_has_exactly_the_contract_keys(name, metrics, capsys):
     assert len(checks) >= 5 and all("value=" in c and "tolerance=" in c for c in checks)
     assert any(c.startswith("check accounting.compiles_in_window") for c in checks)
     assert any(c.startswith("check reference.sampled_logprob_rms") for c in checks)
+    # and the result's last key holds each number compared beside its limit
+    assert list(out["checks"]) == [c.split()[1].rstrip(":") for c in checks]
+    rms = out["checks"]["reference.sampled_logprob_rms"]
+    # (at this size `auto` resolves the PPO cell's cache to int8, the serve cell's is bf16)
+    assert rms["limit"] in ("<= 0.011", "<= 0.035") and 0 < rms["value"] <= 0.035 and rms["ok"] is True
+    assert all(c["ok"] for c in out["checks"].values())
+
+
+def test_a_token_altered_where_it_is_produced_turns_correct_false(capsys, monkeypatch):
+    """The rest of a run with the timed path broken underneath: every group
+    the engine harvests carries tokens other than the ones it drew (and
+    recorded log-probabilities for), and the reference comparison says so."""
+    from trlx_tpu.inference.engine import ContinuousBatchingEngine
+
+    harvest = ContinuousBatchingEngine._harvest_ready
+    vocab = TINY["gpt_neox"]["vocab_size"]
+
+    def altered(self):
+        for group in harvest(self):
+            yield dict(group, tokens=(group["tokens"] + 1) % (vocab - 1))
+
+    monkeypatch.setattr(ContinuousBatchingEngine, "_harvest_ready", altered)
+    out, _ = last_line("serve-pythia1b4-chat", False, capsys)
+    assert out["correct"] is False
+    wrong = out["checks"]["reference.sampled_logprob_rms"]
+    assert wrong["ok"] is False and wrong["value"] > 10 * 0.011
 
 
 def test_traced_line_reports_per_layer_metrics_of_the_cell(capsys):
