@@ -86,6 +86,7 @@ import numpy as np
 from trlx_tpu import telemetry
 from trlx_tpu.ops.kv_cache import (
     SHARED_POOL_KEYS,
+    STATE,
     cache_kind,
     choose_block_size,
     choose_prefill_chunk,
@@ -493,6 +494,7 @@ class ContinuousBatchingEngine:
         )
         self._param_shardings = param_shardings
         self._cache_sharding = cache_sharding
+        self._cache_gb = self._measure_cache()
         self._build_programs()
 
         # host bookkeeping (reset per phase)
@@ -577,6 +579,38 @@ class ContinuousBatchingEngine:
             state = jax.device_put(state, self.state_sharding())
         return state
 
+    def _measure_cache(self) -> Dict[str, float]:
+        """What the pool will hold, by kind of layer (GB; gauges
+        ``cache/state_gb`` and ``cache/kv_gb``), from shapes alone; and
+        the refusals a state layer brings: a state cannot be rolled back
+        to a rejected draft's column or shared by prefix, and the pp
+        runner carries KV layers only."""
+        linear = jax.eval_shape(lambda: self._init_cache_fn(self.num_slots, self.capacity))
+        gb = {"state": 0.0, "kv": 0.0}
+        for layer in linear:
+            key = "state" if cache_kind(layer).layout == STATE else "kv"
+            gb[key] += sum(v.size * v.dtype.itemsize for v in layer.values()) / 1e9
+        if gb["state"]:
+            pp = dict(self.mesh.shape).get("pp", 1) if self.mesh is not None else 1
+            for what, on in (
+                ("prefix_pool_blocks > 0 (a shared prefix of states)", self.prefix_pool_blocks > 0),
+                ("a speculative drafter / verify_step (a state snapshot)", self.spec_max_draft > 0),
+                ("a pp mesh", pp > 1),
+            ):
+                if on:
+                    raise ValueError(
+                        f"{what} is not built for a model with state layers "
+                        "(ops/kv_cache.py, the state kind)"
+                    )
+        self._publish_cache_gauges(gb)
+        return gb
+
+    @staticmethod
+    def _publish_cache_gauges(gb: Dict[str, float]) -> None:
+        registry = telemetry.get_metrics()
+        registry.gauge("cache/state_gb").set(gb["state"])
+        registry.gauge("cache/kv_gb").set(gb["kv"])
+
     def _make_state(self) -> EngineState:
         B, Q, R, V = self.num_slots, self.Q, self.R, self.vocab_size
         cfg = self.gen_config
@@ -584,9 +618,13 @@ class ContinuousBatchingEngine:
         tables = identity_block_tables(B, self.n_blocks)
         # one table array PER layer (logically shared, physically
         # distinct): the jitted programs donate the whole state, and XLA
-        # refuses to donate one buffer appearing as several arguments
+        # refuses to donate one buffer appearing as several arguments.
+        # A state layer has no positions to indirect and gets none.
         cache = tuple(
-            dict(layer, block_tables=jnp.array(tables)) for layer in linear
+            layer
+            if cache_kind(layer).layout == STATE
+            else dict(layer, block_tables=jnp.array(tables))
+            for layer in linear
         )
         if self.prefix_pool_blocks > 0:
             def with_pool(layer):
@@ -638,6 +676,9 @@ class ContinuousBatchingEngine:
         rep = replicated(self.mesh)
 
         def layer_sharding(layer: Dict[str, Any]) -> Dict[str, Any]:
+            if cache_kind(layer).layout == STATE:
+                # no capacity axis for sp to shard: the slot axis, as the rest
+                return {k: batch_sh for k in layer}
             return {
                 k: (
                     rep
@@ -672,7 +713,9 @@ class ContinuousBatchingEngine:
                 return cache
             sh = self._cache_sharding
             return tuple(
-                {
+                layer
+                if cache_kind(layer).layout == STATE
+                else {
                     k: (
                         jax.lax.with_sharding_constraint(v, sh)
                         if v.ndim == 4
@@ -706,6 +749,13 @@ class ContinuousBatchingEngine:
                     for k, v in layer.items()
                     if k != "block_tables" and k not in SHARED_POOL_KEYS
                 }
+                if cache_kind(layer).layout == STATE:
+                    # the slots' rows as they stand: the model starts a row
+                    # from zeros where no valid column precedes the call
+                    # (ops/ssm.py::call_columns), so a recycled slot never
+                    # reads its predecessor's state and a later chunk
+                    # carries on from the one before
+                    return sl
                 sl["block_tables"] = new_tables
                 if sharing:
                     # the pool is global — pass it whole; the admitted
@@ -2426,6 +2476,8 @@ class ContinuousBatchingEngine:
         telemetry.get_metrics().gauge("engine/slot_util").set(
             self.stats.slot_util
         )
+        if self._cache_gb["state"]:  # again: the registry may have been cleared
+            self._publish_cache_gauges(self._cache_gb)
         t_done = telemetry.monotonic() if self.trace_requests else 0.0
         for slot, row in list(self._busy_rows.items()):
             if done_host[slot] and slot not in self._done_slots:

@@ -15,7 +15,8 @@ probability, the weights being those probabilities as they are unless
 engine the paged one.
 
 What the published configuration may say and this family does not build is
-refused by name: grouped KV heads, ``clip_qkv``, ``rope_scaling``, a tied
+refused by name: grouped KV heads (the ops below take them; this family's
+QK-norm over the projected width does not), ``clip_qkv``, ``rope_scaling``, a tied
 head, an activation other than ``silu``.
 
 Parameters: ``wte``, ``h_<i>/{ln_1, attn/{q_proj, k_proj, v_proj, o_proj,
@@ -73,7 +74,10 @@ class OlmoeConfig:
             raise ValueError(
                 f"num_key_value_heads={self.num_key_value_heads} != "
                 f"num_attention_heads={self.num_attention_heads}: grouped "
-                "KV heads are not built for olmoe"
+                "KV heads are not built for olmoe: its QK-norm spans the "
+                "whole projected width, which the two projections would no "
+                "longer share (ops/attention.py takes grouped heads; "
+                "models/granite_hybrid.py uses them)"
             )
         for key in ("clip_qkv", "rope_scaling"):
             if getattr(self, key) is not None:

@@ -156,3 +156,18 @@ def _register_builtins() -> None:
             init_olmoe_cache, conversion.load_olmoe_checkpoint, supports_ep=True,
         )
     )
+    from trlx_tpu.models.granite_hybrid import (
+        GRANITE_HYBRID_PARTITION_RULES,
+        GraniteMoeHybridConfig,
+        GraniteMoeHybridModel,
+        init_granite_hybrid_cache,
+        no_granite_checkpoint,
+    )
+
+    register_model_family(
+        ModelFamily(
+            "granitemoehybrid", GraniteMoeHybridConfig, GraniteMoeHybridModel,
+            GRANITE_HYBRID_PARTITION_RULES, init_granite_hybrid_cache,
+            no_granite_checkpoint, supports_ep=True,
+        )
+    )

@@ -187,8 +187,15 @@ def dot_product_attention(
     *,
     causal: bool = False,
     learned_bias: bool = False,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Multi-head attention; returns [B, Q, H, D].
+
+    ``k``/``v`` may hold fewer heads than ``q`` (grouped-query attention,
+    ``H = G * H_kv``): query head ``h`` reads KV head ``h // G``, and a
+    per-head bias is per query head. ``scale`` multiplies the scores
+    (``None``: ``1 / sqrt(D)``). The flash kernels take neither; a call
+    that would reach them with either is refused by name.
 
     ``causal=True`` applies offset-0 causal masking (training / prefill) —
     prefer it over baking a causal term into ``bias``: the flash kernel then
@@ -201,17 +208,26 @@ def dot_product_attention(
     XLA fuses the scale/bias/softmax chain between the two MXU matmuls.
     """
     Q, K = q.shape[1], k.shape[1]
+    H, H_kv = q.shape[2], k.shape[2]
     if (
         not learned_bias
         and min(Q, K) >= FLASH_MIN_SEQ
         and jax.default_backend() == "tpu"
     ):
+        if H != H_kv or scale is not None:
+            raise ValueError(
+                f"the flash kernels (contexts of {FLASH_MIN_SEQ} and more on "
+                f"a TPU) take equal heads and the 1/sqrt(D) scale; got "
+                f"{H} query over {H_kv} KV heads, scale={scale}"
+            )
         return flash_on_program_mesh(q, k, v, bias, causal=causal)
 
     if causal:
         bias = combine_biases(causal_bias(Q, K), bias)
     depth = q.shape[-1]
-    scale = jax.lax.rsqrt(jnp.float32(depth))
+    scale = jax.lax.rsqrt(jnp.float32(depth)) if scale is None else jnp.float32(scale)
+    if H != H_kv:
+        return _grouped_attention(q, k, v, bias, scale)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
     logits = logits * scale
     if bias is not None:
@@ -224,7 +240,36 @@ def dot_product_attention(
     return out.astype(q.dtype)
 
 
-def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias):
+def _grouped_attention(q, k, v, bias, scale):
+    """The XLA path of :func:`dot_product_attention` for ``H = G * H_kv``:
+    the same two products and float32 softmax, the query heads of a group
+    side by side over their one KV head; K and V are read once, never
+    repeated."""
+    B, Q, H, D = q.shape
+    K, H_kv = k.shape[1], k.shape[2]
+    if H % H_kv:
+        raise ValueError(f"{H} query heads do not divide over {H_kv} KV heads")
+    G = H // H_kv
+    logits = jnp.einsum(
+        "bqhgd,bkhd->bhgqk", q.reshape(B, Q, H_kv, G, D), k,
+        preferred_element_type=jnp.float32,
+    ) * scale
+    if bias is not None:
+        b32 = bias.astype(jnp.float32)
+        if bias.shape[1] == 1:
+            b32 = b32[:, :, None]
+        else:
+            b32 = b32.reshape(bias.shape[0], H_kv, G, Q, K)
+        logits = logits + b32
+    weights = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum(
+        "bhgqk,bkhd->bqhgd", weights.astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
+    )
+    return out.reshape(B, Q, H, D).astype(q.dtype)
+
+
+def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias, scale=None):
     """One new position over a cache in ``decode_kv_layout``: write it in
     place, then read K and V once each, as stored.
 
@@ -237,7 +282,8 @@ def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias):
     copy: int8 values convert to the compute dtype exactly, and their
     per-(position, head) scales multiply the scores and the weights.
     Products accumulate in float32 and the softmax is float32, as in
-    :func:`dot_product_attention`.
+    :func:`dot_product_attention`; ``scale`` as there. Equal heads only:
+    :func:`decode_attention` refuses grouped heads on this read by name.
     """
     B, _, H, Dh = q.shape
     HD = H * Dh
@@ -245,10 +291,10 @@ def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias):
     new_kv = {}
     for name, new in (("k", k_new), ("v", v_new)):
         if quantized:
-            new, scale = quantize_kv(new)
+            new, absmax = quantize_kv(new)
             new_kv[name + "_scale"] = jax.lax.dynamic_update_slice(
                 cache_kv[name + "_scale"],
-                scale.reshape(B, H, 1),
+                absmax.reshape(B, H, 1),
                 (0, 0, cache_index),
             )
         new_kv[name] = jax.lax.dynamic_update_slice(
@@ -270,7 +316,7 @@ def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias):
     )
     if quantized:
         scores = scores * new_kv["k_scale"].astype(jnp.float32)
-    scores = scores * jax.lax.rsqrt(jnp.float32(Dh))
+    scores = scores * (jax.lax.rsqrt(jnp.float32(Dh)) if scale is None else jnp.float32(scale))
     scores = scores + bias[:, 0].astype(jnp.float32)
     weights = jax.nn.softmax(scores, axis=-1)
     if quantized:
@@ -296,10 +342,16 @@ def decode_attention(
     *,
     causal: bool = False,
     learned_bias: bool = False,
+    scale: Optional[float] = None,
 ):
     """Write this call's keys/values into ``cache_kv`` at ``cache_index``
     and attend over the cache; returns ``(out [B, Q, H, D], new_kv)``. The
-    one cached-attention entry of every family.
+    one cached-attention entry of every family. ``k_new``/``v_new`` and the
+    cache may hold fewer heads than ``q`` (grouped-query attention) on the
+    ``paged`` and ``generic`` paths (the ``fused`` read refuses them by
+    name: no family with grouped heads reaches the fixed sampler) and
+    ``scale`` replaces ``1 / sqrt(D)`` on every path, as in
+    :func:`dot_product_attention`.
 
     Dispatch is on what the call shows, at trace time: the cache's kind
     (``ops/kv_cache.py::cache_kind``) and the call's shapes (counted per
@@ -342,7 +394,7 @@ def decode_attention(
             cache_kv, k_new, v_new, cache_index, q.dtype, as_stored=True
         )
         bias = stored_order_bias(cache_kv["block_tables"], bias)
-        return dot_product_attention(q, k, v, bias), new_kv
+        return dot_product_attention(q, k, v, bias, scale=scale), new_kv
     if fused:
         if (
             q.shape[1] != 1
@@ -358,14 +410,19 @@ def decode_attention(
                 f"{None if bias is None else bias.shape}, "
                 f"learned_bias={learned_bias}"
             )
+        if q.shape[2] != k_new.shape[2]:
+            raise ValueError(
+                "the fused read of a cache in decode_kv_layout takes equal "
+                f"heads; got {q.shape[2]} query over {k_new.shape[2]} KV heads"
+            )
         # device-trace scope names are a contract (docs/observability.md)
         with jax.named_scope("decode_attention"):
-            return _decode_read(q, k_new, v_new, cache_kv, cache_index, bias)
+            return _decode_read(q, k_new, v_new, cache_kv, cache_index, bias, scale)
     write = paged_write_read if kind.layout == PAGED else dense_write_read
     k, v, new_kv = write(
         cache_kv, k_new, v_new, cache_index, q.dtype, view_len=view_len
     )
     out = dot_product_attention(
-        q, k, v, bias, causal=causal, learned_bias=learned_bias
+        q, k, v, bias, causal=causal, learned_bias=learned_bias, scale=scale
     )
     return out, new_kv

@@ -28,6 +28,21 @@ values quantised per (position, head) by :func:`quantize_kv`, bf16 scales;
 cache may carry a **shared-prefix overlay** (``"shared_tables"`` present).
 ``ops/attention.py::decode_attention`` picks the read from the kind.
 
+A fourth kind holds no keys at all:
+
+- **state** (:func:`state_buffers`): ``{"ssm_state", "conv_tail"}``, what a
+  state-space layer (``ops/ssm.py``) keeps of a sequence whatever its
+  length: ``[B, H, P, N]`` in ``state_dtype`` and the last ``K - 1`` inputs
+  of its convolution. No capacity axis, no block table, no int8 form; it
+  never reaches ``decode_attention``. A model that mixes the kinds
+  (:func:`hybrid_cache`) hands the engine a tuple whose layers differ, and
+  everything that walks a cache asks :func:`cache_kind` layer by layer.
+
+The pools are sized by **KV heads**: a family with fewer KV heads than
+query heads (grouped-query attention) allocates and reads ``H_kv`` of them,
+and the reads in ``ops/attention.py`` map query head ``h`` to KV head
+``h // (H_q / H_kv)``.
+
 Paging
 ------
 
@@ -184,20 +199,21 @@ def kv_buffers(
     n_layer: int,
     batch_size: int,
     capacity: int,
-    n_head: int,
+    n_kv_head: int,
     head_dim: int,
     dtype,
     kv_cache_dtype: str = "bfloat16",
 ) -> Cache:
-    """Per-layer fixed-capacity KV buffers, shared by every causal family.
+    """Per-layer fixed-capacity KV buffers, shared by every causal family,
+    ``n_kv_head`` heads wide (the query heads where every head has its own).
     ``"int8"`` stores int8 values + per (token, head) bf16 scales — ~half
     the HBM traffic of a bf16 cache (every write and read here handles
     both); ``"auto"`` picks int8 only up to the capacity it is measured
     to."""
-    shape = (batch_size, capacity, n_head, head_dim)
+    shape = (batch_size, capacity, n_kv_head, head_dim)
     kv_cache_dtype = resolve_kv_cache_dtype(kv_cache_dtype, capacity)
     if kv_cache_dtype == "int8":
-        sshape = (batch_size, capacity, n_head, 1)
+        sshape = (batch_size, capacity, n_kv_head, 1)
         return tuple(
             {
                 "k": jnp.zeros(shape, jnp.int8),
@@ -246,13 +262,74 @@ def decode_kv_layout(cache):
     return out
 
 
-DENSE, FOLDED, PAGED = "dense", "folded", "paged"
+# one value until a check can tell another from it: the benchmark's
+# comparison reads a bfloat16 state as it reads float32 (PERF.md section 7 (20))
+VALID_STATE_DTYPES = ("float32",)
+
+
+def state_buffers(
+    batch_size: int,
+    n_head: int,
+    head_dim: int,
+    d_state: int,
+    conv_width: int,
+    conv_channels: int,
+    state_dtype: str = "float32",
+) -> Dict[str, jax.Array]:
+    """One state-space layer's memory of ``batch_size`` sequences
+    (``ops/ssm.py``): the state ``[B, H, P, N]`` and the convolution's last
+    ``conv_width - 1`` inputs ``[B, K - 1, C]``, both in ``state_dtype``,
+    zeros (what a sequence starts from)."""
+    if state_dtype not in VALID_STATE_DTYPES:
+        raise ValueError(
+            f"state_dtype={state_dtype!r} is not supported (choose one of "
+            f"{VALID_STATE_DTYPES})"
+        )
+    dt = jnp.dtype(state_dtype)
+    return {
+        "ssm_state": jnp.zeros((batch_size, n_head, head_dim, d_state), dt),
+        "conv_tail": jnp.zeros((batch_size, conv_width - 1, conv_channels), dt),
+    }
+
+
+def hybrid_cache(
+    layer_types,
+    batch_size: int,
+    capacity: int,
+    *,
+    n_kv_head: int,
+    head_dim: int,
+    dtype,
+    kv_cache_dtype: str,
+    state: Dict[str, int],
+    state_dtype: str = "float32",
+) -> Cache:
+    """The cache of a model whose layers differ: :func:`kv_buffers` for an
+    ``"attention"`` entry of ``layer_types``, :func:`state_buffers` (with
+    the sizes in ``state``) for any other. ``int8`` has no state form and
+    a step that reads one layer in ten through it gains nothing: with a
+    state layer it is refused by name (``auto`` could resolve to it)."""
+    if any(t != "attention" for t in layer_types) and kv_cache_dtype != "bfloat16":
+        raise ValueError(
+            f"kv_cache_dtype={kv_cache_dtype!r} with a state layer "
+            f"({sorted(set(layer_types) - {'attention'})}) is not built: a "
+            "state has no int8 form; choose 'bfloat16'"
+        )
+    return tuple(
+        kv_buffers(1, batch_size, capacity, n_kv_head, head_dim, dtype, kv_cache_dtype)[0]
+        if kind == "attention"
+        else state_buffers(batch_size, state_dtype=state_dtype, **state)
+        for kind in layer_types
+    )
+
+
+DENSE, FOLDED, PAGED, STATE = "dense", "folded", "paged", "state"
 
 
 class CacheKind(NamedTuple):
     """What storage one layer's cache dict is (:func:`cache_kind`)."""
 
-    layout: str  # DENSE | FOLDED | PAGED
+    layout: str  # DENSE | FOLDED | PAGED | STATE
     quantized: bool  # int8 values + bf16 scales
     shared: bool  # a paged cache with a shared-prefix overlay
 
@@ -261,6 +338,8 @@ def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
     """Classify one layer's cache dict, at trace time, from the keys it
     carries and the rank of ``k`` — every reader of "which storage is
     this" asks here."""
+    if "ssm_state" in cache_kv:
+        return CacheKind(STATE, False, False)
     if "block_tables" in cache_kv:
         layout = PAGED
     elif cache_kv["k"].ndim == 3:
@@ -438,7 +517,7 @@ def init_paged_cache(
     n_layer: int,
     n_slots: int,
     capacity: int,
-    n_head: int,
+    n_kv_head: int,
     head_dim: int,
     dtype,
     kv_cache_dtype: str = "bfloat16",
@@ -455,7 +534,7 @@ def init_paged_cache(
     n_blocks = capacity // bs
     tables = identity_block_tables(n_slots, n_blocks)
     layers = kv_buffers(
-        n_layer, n_slots, capacity, n_head, head_dim, dtype, kv_cache_dtype
+        n_layer, n_slots, capacity, n_kv_head, head_dim, dtype, kv_cache_dtype
     )
     # per-layer table copies: donated-state programs must not see one
     # buffer behind several arguments (XLA double-donation refusal)

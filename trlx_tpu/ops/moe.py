@@ -47,6 +47,17 @@ and a ``psum`` over ``ep`` adds the ranks' partial sums - the gather of
 tokens and the scatter of the sum folded into the one all-reduce the
 replicated attention that follows needs anyway. Still dropless.
 
+**One rank's share without a mesh** (:func:`expert_layer` with
+``first_expert`` and fewer expert matrices than the router has outputs):
+the same ordering and masking with nothing to sum over - the layer is told
+which experts it holds (``first_expert .. first_expert + w_gate.shape[0]``
+of the router's ``E``), routes over all ``E``, and returns the part of the
+result its own experts give. What the absent experts would have added is
+left out, and nothing stands in for the exchange: this is one chip of an
+``ep`` group measured alone (a configuration whose file states that
+deployment). ``shared`` is a float32 term added before the cast (a shared
+expert); on an ``ep`` mesh it is refused by name: nothing runs it there.
+
 Training forwards sow ``aux_loss`` (load balancing over the top-k
 assignments, ``E * sum_e f_e P_e`` with ``f_e`` the copies routed to ``e``
 per token and ``P_e`` the mean router probability; uniform routing gives
@@ -182,18 +193,28 @@ def _apply_routed(h, routing: Routing, w_gate, w_up, w_down, dtype,
         )
 
 
-def routing_stats(routing: Routing, num_experts: int) -> Dict[str, jax.Array]:
+def routing_stats(routing: Routing, num_experts: int, first_expert: int = 0,
+                  held: Optional[int] = None) -> Dict[str, jax.Array]:
     """What a step's routing looked like, as device scalars that ride in
     outputs the caller fetches anyway: ``experts_touched`` (distinct
     experts with at least one row), ``max_load`` (the busiest expert's
     share of the rows; ``1 / E`` is perfect balance) and ``rows_routed``
-    (``N * k``)."""
+    (``N * k``). Where the layer holds ``held < num_experts`` experts from
+    ``first_expert`` on, the first two are over the held experts alone
+    (what this chip reads and multiplies) and a fourth figure,
+    ``rows_here_share``, is the share of the routed copies whose expert is
+    held here."""
     N, k = routing.experts.shape
     counts = jnp.zeros((num_experts,), jnp.int32).at[routing.experts.reshape(-1)].add(1)
+    stats = {}
+    if held is not None and held < num_experts:
+        counts = jnp.roll(counts, -first_expert)[:held]
+        stats["rows_here_share"] = jnp.sum(counts).astype(jnp.float32) / (N * k)
     return {
         "experts_touched": jnp.sum(counts > 0).astype(jnp.float32),
         "max_load": jnp.max(counts).astype(jnp.float32) / (N * k),
         "rows_routed": jnp.float32(N * k),
+        **stats,
     }
 
 
@@ -201,17 +222,22 @@ def record_step_stats(stats: Dict[str, Any]) -> None:
     """A fetched :func:`routing_stats` (averaged over a call's blocks) into
     the metrics registry: counter ``moe/rows_routed``; gauges
     ``moe/experts_touched`` (the mean over the steps recorded since the
-    registry was cleared) and ``moe/max_load`` (the last step's)."""
+    registry was cleared), ``moe/max_load`` (the last step's) and, from a
+    layer that holds a share of its experts, ``moe/rows_here_share`` (the
+    mean, like the first)."""
     from trlx_tpu import telemetry
 
     registry = telemetry.get_metrics()
     registry.counter("moe/rows_routed").inc(float(stats["rows_routed"]))
     steps = registry.counter("moe/steps_recorded")
-    total = registry.counter("moe/experts_touched_sum")
     steps.inc()
-    total.inc(float(stats["experts_touched"]))
-    if steps.value:  # 0 while the registry is disabled
-        registry.gauge("moe/experts_touched").set(total.value / steps.value)
+    for name in ("experts_touched", "rows_here_share"):
+        if name not in stats:
+            continue
+        total = registry.counter(f"moe/{name}_sum")
+        total.inc(float(stats[name]))
+        if steps.value:  # 0 while the registry is disabled
+            registry.gauge(f"moe/{name}").set(total.value / steps.value)
     registry.gauge("moe/max_load").set(float(stats["max_load"]))
 
 
@@ -236,13 +262,18 @@ def balance_losses(routing: Routing, num_experts: int, token_mask=None) -> Dict[
 
 def expert_layer(h: jax.Array, router_w, w_gate, w_up, w_down, *, k: int,
                  norm_topk: bool = False, dtype=jnp.bfloat16,
-                 mesh: Optional[Mesh] = None, batch_axes=("dp", "fsdp")):
+                 mesh: Optional[Mesh] = None, batch_axes=("dp", "fsdp"),
+                 first_expert: int = 0, shared: Optional[jax.Array] = None):
     """``h`` [B, T, D] -> ``(y [B, T, D] in dtype, routing)``; ``routing``
     is over all ``B * T`` tokens, for the losses and the statistics.
 
     ``mesh``: an ``ep`` mesh whose ``ep`` axis shards the experts' leading
     axis; tokens are split over ``batch_axes`` where their count allows
-    and replicated otherwise (a decode step of a few rows)."""
+    and replicated otherwise (a decode step of a few rows). Without one,
+    ``w_*`` are experts ``first_expert .. first_expert + w_gate.shape[0]``
+    of the router's ``E`` (all of them, or one rank's share: module
+    docstring). ``shared`` [B, T, D] float32 is added before the cast
+    (off a mesh only)."""
     D, E = h.shape[-1], router_w.shape[-1]
 
     def run(h_loc, router_w, w_gate, w_up, w_down, first_expert=0):
@@ -252,12 +283,25 @@ def expert_layer(h: jax.Array, router_w, w_gate, w_up, w_down, *, k: int,
         return _apply_routed(flat, routing, w_gate, w_up, w_down, dtype, E, first_expert), routing
 
     if mesh is None or dict(mesh.shape).get("ep", 1) == 1:
-        y, routing = run(h, router_w, w_gate, w_up, w_down)
-        return y.reshape(h.shape).astype(dtype), routing
+        if not 0 <= first_expert <= E - w_gate.shape[0]:
+            raise ValueError(
+                f"experts {first_expert} .. {first_expert + w_gate.shape[0]} "
+                f"are not among the router's {E}"
+            )
+        y, routing = run(h, router_w, w_gate, w_up, w_down, first_expert)
+        y = y.reshape(h.shape)
+        if shared is not None:
+            y = y + shared.astype(jnp.float32)
+        return y.astype(dtype), routing
 
     ep = mesh.shape["ep"]
-    if E % ep:
-        raise ValueError(f"{E} experts cannot be split over ep={ep}")
+    if E % ep or w_gate.shape[0] != E or first_expert:
+        raise ValueError(
+            f"an ep mesh shards all {E} experts over ep={ep}; got "
+            f"{w_gate.shape[0]} from {first_expert} on"
+        )
+    if shared is not None:
+        raise ValueError("a shared term beside the experts is not built on an ep mesh")
     axes = tuple(a for a in batch_axes if a in mesh.axis_names)
     shards = 1
     for a in axes:

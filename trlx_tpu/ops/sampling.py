@@ -30,7 +30,7 @@ import flax.struct as struct
 import jax
 import jax.numpy as jnp
 
-from trlx_tpu.ops.kv_cache import decode_kv_layout
+from trlx_tpu.ops.kv_cache import STATE, cache_kind, decode_kv_layout
 from trlx_tpu.utils import topk_mask
 
 
@@ -439,7 +439,17 @@ def make_sampler(
         else:
             min_new = None
 
-        cache = pin_cache(init_cache_fn(B, cap))
+        cache = init_cache_fn(B, cap)
+        if not isinstance(cache, dict) and any(
+            cache_kind(layer).layout == STATE for layer in cache
+        ):
+            raise ValueError(
+                "the fixed sampler carries KV layers only (its loop folds "
+                "every layer into decode_kv_layout): a model with state "
+                "layers (granitemoehybrid) samples through rollout.engine: "
+                "continuous"
+            )
+        cache = pin_cache(cache)
         # prefill: cache validity = prompt mask over slots [0, Q)
         pad_tail = jnp.zeros((B, R), dtype=prompt_mask.dtype)
         cache_mask = concat_cols(prompt_mask, pad_tail)
