@@ -19,7 +19,14 @@ from trlx_tpu.ops.attention import (
     dot_product_attention,
     padding_bias,
 )
-from trlx_tpu.ops.flash_attention import flash_attention
+from trlx_tpu.ops.flash_attention import (
+    LONG_BLOCK,
+    LONG_SEQ,
+    ROW_CHUNK,
+    _row_chunk,
+    fitted_block,
+    flash_attention,
+)
 
 RNG = np.random.default_rng(0)
 
@@ -134,6 +141,98 @@ class TestFlashBackward:
         bias = rand(1, 1, T, T)
         db = jax.grad(lambda b: flash_loss(q, k, v, b))(bias)
         assert float(jnp.abs(db).max()) == 0.0
+
+
+def _assert_output_and_grads_match(xla, flash, loss, q, k, v):
+    np.testing.assert_allclose(
+        np.asarray(xla(q, k, v)), np.asarray(flash(q, k, v)), atol=2e-5
+    )
+    gr = jax.grad(lambda *a: loss(xla(*a)), argnums=(0, 1, 2))(q, k, v)
+    gf = jax.grad(lambda *a: loss(flash(*a)), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gr, gf, strict=True):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+
+
+def test_tiles_are_fitted_to_the_length():
+    # one tile over the whole axis (in sublanes of 16) under LONG_SEQ: the
+    # cells' updates (T 512, 560) and everything a direct caller sends
+    assert [fitted_block(t) for t in (1, 8, 21, 48, 512, 560, 563, 640, 1000)] == [
+        8, 8, 32, 48, 512, 560, 576, 640, 1008,
+    ]
+    assert [fitted_block(t) for t in (LONG_SEQ, 1500, 4096)] == [LONG_BLOCK] * 3
+    # the rows a loop iteration of the one-tile kernels takes: the whole tile
+    # while it is small, else a divisor of it in bf16 sublanes
+    assert [_row_chunk(t) for t in (8, 32, 128, 512, 560, 576, 592, 640, 1008)] == [
+        8, 32, 128, 128, 112, 96, 16, 128, 112,
+    ]
+    assert all(
+        _row_chunk(t) <= ROW_CHUNK and t % _row_chunk(t) == 0
+        for t in map(fitted_block, range(1, LONG_SEQ))
+    )
+
+
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("T", [512, 560, 640])
+def test_fitted_tiles_match_xla_at_the_update_lengths(T, D):
+    """The uncached causal call of an update at the tiles the rule picks
+    (no ``block_q`` / ``block_k``): output and dq, dk, dv against the XLA
+    path under a left-padding ``[B, 1, 1, T]`` bias. Row 0 is half padding,
+    row 1 has none. A padding position's own output is uniform weights over
+    the keys its row visits, on both paths and over the same keys while the
+    axis is one tile, so the forward is compared on those rows too. The
+    loss is over the real positions, as a trainer's is: no real position
+    reads a padding one, so its cotangent is exactly zero, and the kernels'
+    backward is only good for that (it recomputes the weights from a
+    logsumexp in which -1e9 has absorbed log n, so an all-padding row's
+    are 1 and not 1/n)."""
+    B, H = 2, 2
+    q, k, v = rand(B, T, H, D), rand(B, T, H, D), rand(B, T, H, D)
+    pads = np.array([[T // 2], [0]])
+    mask = jnp.asarray(np.arange(T)[None] >= pads, jnp.float32)
+    bias = padding_bias(mask)
+    assert bias.shape == (B, 1, 1, T)
+
+    def loss(out):
+        return ((out * mask[:, :, None, None]) ** 2).sum()
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, bias, causal=True, interpret=True)
+
+    def xla(q, k, v):
+        return dot_product_attention(q, k, v, bias, causal=True)
+
+    _assert_output_and_grads_match(xla, flash, loss, q, k, v)
+
+
+@pytest.mark.parametrize(
+    "Q,K,bias_shape,causal",
+    [
+        (160, 160, (1, 2, 160, 160), False),  # per-head, per-row bias: sliced by chunk
+        (160, 200, (2, 1, 160, 200), True),   # Q < K, neither a lane multiple
+        (144, 144, None, True),               # no bias at all
+        (272, 40, (2, 1, 1, 40), False),      # cross-attention style: padding only
+    ],
+    ids=["per-row-bias", "unequal-causal", "no-bias", "short-keys"],
+)
+def test_one_tile_row_loop_matches_xla(Q, K, bias_shape, causal):
+    """The one-tile kernels where their row loop runs more than once (over
+    ``ROW_CHUNK`` query rows) for every kind of bias the BlockSpecs
+    broadcast: output and gradients against the XLA path."""
+    B, H, D = 2, 2, 32
+    assert fitted_block(Q) // _row_chunk(fitted_block(Q)) > 1
+    q, k, v = rand(B, Q, H, D), rand(B, K, H, D), rand(B, K, H, D)
+    bias = None if bias_shape is None else rand(*bias_shape)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, bias, causal=causal, interpret=True)
+
+    def xla(q, k, v):
+        full = combine_biases(causal_bias(Q, K) if causal else None, bias)
+        return dot_product_attention(q, k, v, full)
+
+    _assert_output_and_grads_match(
+        xla, flash, lambda out: (out ** 2).sum(), q, k, v
+    )
 
 
 class TestBlockHelpers:

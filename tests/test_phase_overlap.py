@@ -606,3 +606,87 @@ def test_orchestrator_close_closes_rollout_writer(tmp_path):
     orch.close()  # idempotent
     # the base class close is a safe no-op for writer-less orchestrators
     Orchestrator.close(orch)
+
+
+def test_streamed_phase_is_the_fused_pass_and_compiles_no_train_phase(caplog):
+    """The residual epochs go through ``_train_step_jit`` a dispatch a
+    minibatch (PR 37): parameters and the ``[n_updates]`` statistics rows
+    of a streamed phase equal the fused ``train_phase`` scan over the same
+    plan, and the streamed phase compiles no ``jit_train_phase`` (compiles
+    counted by name from jax's own compile log)."""
+    import logging
+
+    import jax
+
+    from trlx_tpu.pipeline.ppo_buffer import make_stream_plan
+    from trlx_tpu.utils.loading import get_trainer
+
+    config = _parity_config({"dp": -1, "fsdp": 1, "tp": 1})
+    trainer = get_trainer("PPOTrainer")(config, reward_fn=_reward_fn)
+    init_state = jax.device_get(trainer.state)
+
+    def compiled(name):
+        return sum(
+            rec.getMessage().startswith(f"Compiling {name} ")
+            for rec in caplog.records
+        )
+
+    with jax.log_compiles(), caplog.at_level(logging.WARNING, logger="jax"):
+        params, rows, _, n_updates = _run_phase(trainer, init_state, overlap=True)
+        assert compiled("jit(train_step)") == 1
+        assert compiled("jit(train_phase)") == 0
+        assert trainer._train_phase_jit._cache_size() == 0
+
+        # the same plan through the fused scan, from the same state and
+        # the rollouts the phase left in its store
+        plan = make_stream_plan(
+            config.method.num_rollouts, config.train.batch_size,
+            config.method.ppo_epochs, seed=11,
+        )
+        assert plan.residual.shape[0] == 3 and n_updates == 6
+        mbs = trainer.buffer.gather(
+            np.concatenate([plan.epoch1, plan.residual]),
+            sharding=trainer._stacked_batch_sh,
+        )
+        fused_state, fused_rows = trainer._train_phase_jit(
+            jax.device_put(init_state, trainer.state_shardings), mbs
+        )
+        assert compiled("jit(train_phase)") == 1
+
+    for a, b in zip(
+        jax.tree_util.tree_leaves(params),
+        jax.tree_util.tree_leaves(jax.device_get(fused_state.params)),
+        strict=True,
+    ):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    fused_rows = jax.device_get(fused_rows)
+    assert set(rows) == set(fused_rows)
+    for key in rows:
+        assert rows[key].shape == (n_updates,), key
+        np.testing.assert_array_equal(
+            rows[key], np.asarray(fused_rows[key]), err_msg=key
+        )
+
+
+def test_the_second_update_of_a_run_traces_nothing():
+    """A trainer's initial state has the types its train step returns, the
+    step counter's mesh included: the second update of a run reuses the
+    first one's trace (PR 37; left uncommitted, the counter made every run
+    trace, lower, key and load the whole train program twice)."""
+    import jax
+    import jax.numpy as jnp
+
+    from trlx_tpu.analysis.harness import _ppo_minibatch_sds
+    from trlx_tpu.utils.loading import get_trainer
+
+    config = _parity_config({"dp": -1, "fsdp": 1, "tp": 1})
+    trainer = get_trainer("PPOTrainer")(config, reward_fn=_reward_fn)
+    mb = jax.device_put(
+        jax.tree_util.tree_map(
+            lambda s: jnp.ones(s.shape, s.dtype), _ppo_minibatch_sds(trainer)
+        ),
+        trainer._batch_sh,
+    )
+    state, _ = trainer._train_step_jit(trainer.state, mb)
+    trainer._train_step_jit(state, mb)
+    assert trainer._train_step_jit._cache_size() == 1

@@ -54,3 +54,43 @@ def test_paged_decode_layer_holds_no_copy_of_its_pool(one_chip):
     # (fused converts live inside a fusion and return scores or outputs)
     entry = compiled.as_text().split("\nENTRY ", 1)[1]
     assert not re.search(r"= f32\[(%d,%d|%d),%d,%d\]" % (B, C, B * C, H, Dh), entry)
+
+
+@pytest.mark.parametrize(
+    "B,T,H,Dh",
+    [(16, 560, 16, 64), (16, 512, 16, 64), (8, 1000, 16, 128)],
+    ids=["tldr-update", "longgen-update", "one-tile-limit-Dh128"],
+)
+def test_the_updates_attention_compiles_to_the_kernels_and_no_scores(
+    one_chip, monkeypatch, B, T, H, Dh
+):
+    """A layer's uncached causal self-attention at the PPO cells' update
+    shapes (minibatch 16, gpt2-medium's 16 heads of 64, T 560 and 512),
+    forward and backward: on the TPU's path Mosaic takes the one-tile
+    kernels ``fitted_block`` chooses (the third case is the largest tile the
+    rule can hand it, just under ``LONG_SEQ``, at heads of 128: it has to fit
+    the kernels' VMEM), two custom calls (the forward and the one backward
+    kernel of a tile that covers both axes), and no ``[B, H, T, T]`` array
+    is left in the program, which on the XLA path holds the float32 scores
+    (321 MB a layer at T 560) forward and backward (PERF.md §6, PR 37)."""
+    from trlx_tpu.ops.attention import dot_product_attention
+
+    # the rule reads the backend at trace time and this process's is the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, bias):
+        out = dot_product_attention(q, k, v, bias, causal=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    x = sds((B, T, H, Dh), jnp.bfloat16)
+    text = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        .lower(x, x, x, sds((B, 1, 1, T), jnp.float32))
+        .compile()
+        .as_text()
+    )
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 2
+    assert not re.search(r"\[%d,%d,%d,%d\]" % (B, H, T, T), text)

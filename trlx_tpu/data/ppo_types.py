@@ -52,8 +52,17 @@ class PPORolloutBatch:
         return self.batch_size
 
     def select(self, idx: jax.Array) -> "PPORolloutBatch":
-        """Gather a sub-batch by integer indices (for minibatch sampling)."""
-        return jax.tree_util.tree_map(lambda x: x[idx], self)
+        """Gather a sub-batch by integer indices (for minibatch sampling),
+        all fields in ONE device program: taken eagerly a gather is ~50
+        launches, which held the host through epoch 1's streamed updates
+        and left the chip idle 16 ms a step between back-to-back train
+        steps (PERF.md §6, PR 37)."""
+        return _select(self, idx)
+
+
+@jax.jit
+def _select(batch, idx):
+    return jax.tree_util.tree_map(lambda x: x[idx], batch)
 
 
 def concat_rollouts(batches) -> PPORolloutBatch:

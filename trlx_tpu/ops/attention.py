@@ -4,8 +4,10 @@ All attention in the framework funnels through :func:`dot_product_attention`
 (SURVEY §2.9: "Pallas kernels only where XLA fusion is insufficient"): on
 TPU, long sequences route to the Pallas flash kernel
 (:mod:`trlx_tpu.ops.flash_attention` — blocked online softmax, causal tile
-skipping, custom-VJP backward); short sequences and CPU stay on the XLA
-einsum path, which XLA fuses well below the flash crossover point. Masks
+skipping, custom-VJP backward), from the length at which it was measured to
+win for the kind of call (:func:`attention_path`); short sequences and CPU
+stay on the XLA einsum path, which writes the ``[B, H, Q, K]`` float32
+scores to HBM and reads them back, forward and backward. Masks
 are additive float biases built once per program by the helpers below —
 models never branch on Python-level conditions inside jit.
 
@@ -46,10 +48,20 @@ from trlx_tpu.telemetry import get_metrics
 
 NEG_INF = -1e9  # large-negative mask value; avoids -inf NaN propagation in softmax
 
-# Flash kernel dispatch: measured crossover on v5e — XLA wins below ~1k
-# context (its fused softmax has no kernel-launch/transpose overhead), the
-# pallas kernel wins above (2.4x fwd / 4x bwd at 4k). Settable for tests.
+# Flash kernel dispatch, measured on one TPU v5e chip, jax 0.9.0, 2026-09-29
+# (tools/attention_crossover.py; the tables are PERF.md §6 "PR 37"): bf16,
+# 16 rows x 16 heads of 64, causal with a left-padding bias, tiles as
+# flash_attention.py::fitted_block chooses them, XLA's time over the kernels'.
+# Forward + backward: T 256 0.25 (the general kernels; not read again), T 384
+# 1.05, T 512 1.69, T 560 1.82, T 1024 1.83; forward alone: 0.27, 1.04, 1.63,
+# 1.51, 1.88 (heads of 128: 1.91 and 1.63 at 512, 2.02 and 1.70 at 640). So
+# the uncached causal self-attention of an update or a scoring forward takes
+# the kernels from 512, the lowest length at which they clearly won (384 is
+# level). Every other call (a cached call's Q x K rectangle under an explicit
+# bias, where nothing is differentiated) keeps the crossover of the earlier
+# sweep of 1k-4k contexts, which these tables did not repeat.
 FLASH_MIN_SEQ = 1024
+FLASH_MIN_SEQ_CAUSAL = 512
 
 
 def causal_bias(q_len: int, kv_len: int, offset: int = 0, dtype=jnp.float32) -> jax.Array:
@@ -132,6 +144,38 @@ def causal_dispatch(
     return combine_biases(causal_bias(q_len, kv_len, offset=offset), pad), False
 
 
+def attention_path(q_shape, k_shape, *, causal, learned_bias, scale) -> str:
+    """``"flash"`` or ``"xla"``: which path :func:`dot_product_attention`
+    takes, from what the call shows (counted per traced call site in
+    ``attention/path{path=...}``).
+
+    The flash kernels run on a TPU only, and never under a learned bias
+    (their VJP treats the bias as constant). From ``FLASH_MIN_SEQ`` they
+    take every call, and a call with grouped KV heads or a ``scale`` is
+    refused by name there. From ``FLASH_MIN_SEQ_CAUSAL`` they take the call
+    a trainer's or scorer's uncached forward makes: ``causal=True`` with
+    ``Q == K`` (``causal_dispatch`` hands the flag out only without a
+    cache; every cached call arrives with ``causal=False`` and an explicit
+    bias). Under ``FLASH_MIN_SEQ`` a grouped or scaled call stays on the XLA
+    path, which takes both.
+    """
+    Q, K = q_shape[1], k_shape[1]
+    if learned_bias or jax.default_backend() != "tpu":
+        return "xla"
+    plain = q_shape[2] == k_shape[2] and scale is None
+    if min(Q, K) >= FLASH_MIN_SEQ:
+        if not plain:
+            raise ValueError(
+                f"the flash kernels (contexts of {FLASH_MIN_SEQ} and more on "
+                f"a TPU) take equal heads and the 1/sqrt(D) scale; got "
+                f"{q_shape[2]} query over {k_shape[2]} KV heads, scale={scale}"
+            )
+        return "flash"
+    if causal and Q == K and Q >= FLASH_MIN_SEQ_CAUSAL and plain:
+        return "flash"
+    return "xla"
+
+
 def flash_on_program_mesh(q, k, v, bias=None, *, causal=False,
                           interpret=False):
     """The flash kernels over the mesh of the program being traced.
@@ -194,8 +238,9 @@ def dot_product_attention(
     ``k``/``v`` may hold fewer heads than ``q`` (grouped-query attention,
     ``H = G * H_kv``): query head ``h`` reads KV head ``h // G``, and a
     per-head bias is per query head. ``scale`` multiplies the scores
-    (``None``: ``1 / sqrt(D)``). The flash kernels take neither; a call
-    that would reach them with either is refused by name.
+    (``None``: ``1 / sqrt(D)``). The flash kernels take neither: such a
+    call stays on the XLA path under ``FLASH_MIN_SEQ`` and is refused by
+    name from there (:func:`attention_path`).
 
     ``causal=True`` applies offset-0 causal masking (training / prefill) —
     prefer it over baking a causal term into ``bias``: the flash kernel then
@@ -209,17 +254,11 @@ def dot_product_attention(
     """
     Q, K = q.shape[1], k.shape[1]
     H, H_kv = q.shape[2], k.shape[2]
-    if (
-        not learned_bias
-        and min(Q, K) >= FLASH_MIN_SEQ
-        and jax.default_backend() == "tpu"
-    ):
-        if H != H_kv or scale is not None:
-            raise ValueError(
-                f"the flash kernels (contexts of {FLASH_MIN_SEQ} and more on "
-                f"a TPU) take equal heads and the 1/sqrt(D) scale; got "
-                f"{H} query over {H_kv} KV heads, scale={scale}"
-            )
+    path = attention_path(
+        q.shape, k.shape, causal=causal, learned_bias=learned_bias, scale=scale
+    )
+    get_metrics().counter("attention/path{path=%s}" % path).inc()
+    if path == "flash":
         return flash_on_program_mesh(q, k, v, bias, causal=causal)
 
     if causal:

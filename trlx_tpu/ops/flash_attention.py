@@ -17,6 +17,12 @@ standard flash recomputation) in two kernels: dQ (key tiles innermost) and
 dK/dV (query tiles innermost); ``delta = rowsum(dO · O)`` is folded into
 both rather than materialized.
 
+Under ``LONG_SEQ`` positions one tile covers both axes (``fitted_block``:
+on the chip a grid step costs more than skipping a future tile saves), and
+then nothing is carried between tiles: the forward is a plain softmax over
+all keys and the backward is ONE kernel, both walking the query rows in a
+loop inside the grid step (``_fwd_one_tile`` / ``_bwd_one_tile``).
+
 Numerics match :func:`trlx_tpu.ops.attention.dot_product_attention`: logits
 and softmax statistics in float32, the two MXU matmuls in the input dtype,
 finite ``NEG_INF`` masking (fully-masked rows degrade to uniform weights
@@ -46,9 +52,31 @@ from jax.experimental.pallas import tpu as pltpu
 
 from trlx_tpu.ops.attention import NEG_INF
 
-BLOCK_Q = 512  # best on v5e across 1k-4k sequences (see tests/test_flash_attention.py)
-BLOCK_K = 512
+# Tiles fitted to the length, from one v5e chip (tools/attention_crossover.py;
+# the tables are PERF.md §6 "PR 37"; [16, T, 16, 64] bf16, causal, forward +
+# backward, ms a layer). Under LONG_SEQ one tile over the whole length wins at
+# every length measured, because a grid step (~0.44 us) costs more than
+# skipping a future tile saves: T 560 as one 560 tile 1.99 (2.06 with the
+# kernels the tiles below still use), padded to one 640 tile 2.39, 256 x 256
+# over 768 6.6, 320 x 128 over 640 7.3, 128 x 128 10.5, and the 512 x 512 over
+# 1024 that min(512, ceil8(T)) used to choose 5.9 (XLA 3.6). From LONG_SEQ
+# 512 x 512 (T 1024: 5.9 against XLA's 10.7; the 1k-4k sweep before it),
+# which bounds VMEM at any length.
+LONG_SEQ = 1024
+LONG_BLOCK = 512
 LANES = 128  # trailing broadcast dim for row statistics
+
+
+def fitted_block(length: int) -> int:
+    """The tile along an axis of ``length`` positions: the whole axis,
+    rounded up to the sublane multiple (16; 8 for up to 8 positions), under
+    ``LONG_SEQ``; ``LONG_BLOCK`` from there."""
+    return LONG_BLOCK if length >= LONG_SEQ else _one_tile(length)
+
+
+def _one_tile(length: int) -> int:
+    sub = 8 if length <= 8 else 16  # a bf16 tile holds 16 sublanes
+    return -(-length // sub) * sub
 
 
 def _bias_spec(bias_shape, block_q, block_k, q_axis, k_axis):
@@ -153,6 +181,10 @@ def _fwd(q, k, v, bias, *, scale, block_q, block_k, causal, interpret):
     """q/k/v: [B, H, Qp, D] / [B, H, Kp, D]; returns (o, lse)."""
     B, H, Qp, D = q.shape
     Kp = k.shape[2]
+    if (block_q, block_k) == (Qp, Kp):
+        return _fwd_one_tile(
+            q, k, v, bias, scale=scale, causal=causal, interpret=interpret
+        )
     grid = (B, H, Qp // block_q, Kp // block_k)
 
     q_spec = pl.BlockSpec(
@@ -326,6 +358,11 @@ def _bwd(q, k, v, bias, o, lse, do, *, scale, block_q, block_k, causal,
          interpret):
     B, H, Qp, D = q.shape
     Kp = k.shape[2]
+    if (block_q, block_k) == (Qp, Kp):
+        return _bwd_one_tile(
+            q, k, v, bias, o, lse, do, scale=scale, causal=causal,
+            interpret=interpret,
+        )
     n_q, n_k = Qp // block_q, Kp // block_k
 
     q_tile_qk = pl.BlockSpec(
@@ -410,6 +447,190 @@ def _bwd(q, k, v, bias, o, lse, do, *, scale, block_q, block_k, causal,
 
 
 # ---------------------------------------------------------------------------
+# One tile over both axes (every length under LONG_SEQ): a loop over query rows
+# ---------------------------------------------------------------------------
+#
+# With the whole key axis in the tile there is nothing to carry from tile to
+# tile: a grid step is one (row, head), and inside it a ``fori_loop`` walks
+# the query rows ``ROW_CHUNK`` at a time, each chunk against all keys: a
+# plain softmax forward, and one backward kernel that recomputes the weights
+# once for dQ, dK and dV together (dK and dV accumulate in VMEM across the
+# chunks). The loop keeps Mosaic's code for a [560, 560] tile small (a
+# forward call site weighs 33 KB in a compiled program against 358 KB
+# unrolled: PERF.md §6, PR 37) and is 3-10% faster than the general kernels
+# at one tile.
+
+ROW_CHUNK = 128
+
+
+def _row_chunk(rows: int) -> int:
+    """Query rows a loop iteration takes: all of them up to ``ROW_CHUNK``,
+    else the largest divisor of ``rows`` that is a multiple of 16 (a bf16
+    tile's sublanes; ``_one_tile`` pads to that) and at most ``ROW_CHUNK``."""
+    if rows <= ROW_CHUNK:
+        return rows
+    return max(c for c in range(16, ROW_CHUNK + 1, 16) if rows % c == 0)
+
+
+def _for_row_chunks(n_rows, chunk, body):
+    """``body(r0)`` for each chunk's first row; one chunk is straight code."""
+    if n_rows == chunk:
+        body(0)
+        return
+
+    def step(i, carry):
+        body(pl.multiple_of(i * chunk, chunk))
+        return carry
+
+    jax.lax.fori_loop(0, n_rows // chunk, step, 0)
+
+
+def _chunk_scores(q, k_ref, bias_ref, r0, rows, scale, causal):
+    """[rows, Kp] float32 masked scores of query rows ``r0..r0+rows``."""
+    k_all = k_ref[0, 0]
+    s = jax.lax.dot_general(
+        q, k_all, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+    if bias_ref is not None:
+        if bias_ref.shape[2] > 1:
+            b = bias_ref[0, 0, pl.ds(r0, rows), :]
+        else:
+            b = bias_ref[0, 0]
+        s = s + b.astype(jnp.float32)
+    if causal:
+        s = s + _causal_mask(r0, rows, 0, k_all.shape[0])
+    return s
+
+
+def _fwd_one_tile_kernel(*refs, scale, rows, has_bias, causal):
+    if has_bias:
+        q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, lse_ref = refs
+        bias_ref = None
+
+    def chunk(r0):
+        at = pl.ds(r0, rows)
+        s = _chunk_scores(
+            q_ref[0, 0, at, :], k_ref, bias_ref, r0, rows, scale, causal
+        )
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l_safe = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+        v_all = v_ref[0, 0]
+        acc = jax.lax.dot_general(
+            p.astype(v_all.dtype), v_all, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        o_ref[0, 0, at, :] = (acc / l_safe).astype(o_ref.dtype)
+        lse_ref[0, 0, at, :] = jnp.broadcast_to(
+            m + jnp.log(l_safe), (rows, lse_ref.shape[-1])
+        )
+
+    _for_row_chunks(q_ref.shape[2], rows, chunk)
+
+
+def _bwd_one_tile_kernel(*refs, scale, rows, has_bias, causal):
+    if has_bias:
+        (q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref, lse_ref,
+         dq_ref, dk_ref, dv_ref, dk_s, dv_s) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+         dq_ref, dk_ref, dv_ref, dk_s, dv_s) = refs
+        bias_ref = None
+
+    dk_s[:] = jnp.zeros_like(dk_s)
+    dv_s[:] = jnp.zeros_like(dv_s)
+
+    def chunk(r0):
+        at = pl.ds(r0, rows)
+        q = q_ref[0, 0, at, :]
+        do = do_ref[0, 0, at, :].astype(jnp.float32)
+        o = o_ref[0, 0, at, :].astype(jnp.float32)
+        delta = jnp.sum(do * o, axis=-1, keepdims=True)  # [rows, 1]
+        s = _chunk_scores(q, k_ref, bias_ref, r0, rows, scale, causal)
+        p = jnp.exp(s - lse_ref[0, 0, at, 0:1])  # [rows, Kp]
+        dv_s[:] = dv_s[:] + jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dp = jax.lax.dot_general(
+            do, v_ref[0, 0].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = p * (dp - delta)
+        dk_s[:] = dk_s[:] + jax.lax.dot_general(
+            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        k_all = k_ref[0, 0]
+        dq = jax.lax.dot_general(
+            ds.astype(k_all.dtype), k_all, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dq_ref[0, 0, at, :] = (dq * scale).astype(dq_ref.dtype)
+
+    _for_row_chunks(q_ref.shape[2], rows, chunk)
+    dk_ref[0, 0] = (dk_s[:] * scale).astype(dk_ref.dtype)
+    dv_ref[0, 0] = dv_s[:].astype(dv_ref.dtype)
+
+
+def _whole(shape):
+    """BlockSpec of one (row, head)'s whole [T, X] slab under a (B, H) grid;
+    size-1 batch and head dims (a broadcast bias) pin to block 0."""
+    b, h = shape[0], shape[1]
+    return pl.BlockSpec(
+        (1, 1) + tuple(shape[2:]),
+        lambda bi, hi: (bi if b > 1 else 0, hi if h > 1 else 0, 0, 0),
+        memory_space=pltpu.VMEM,
+    )
+
+
+def _one_tile_call(kernel, q, bias, *, scale, causal, interpret, **call):
+    return pl.pallas_call(
+        functools.partial(
+            kernel, scale=scale, rows=_row_chunk(q.shape[2]),
+            has_bias=bias is not None, causal=causal,
+        ),
+        grid=q.shape[:2],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+        ),
+        interpret=interpret,
+        **call,
+    )
+
+
+def _fwd_one_tile(q, k, v, bias, *, scale, causal, interpret):
+    B, H, Qp, _ = q.shape
+    lse = jax.ShapeDtypeStruct((B, H, Qp, LANES), jnp.float32)
+    args = [q, k, v] + ([bias] if bias is not None else [])
+    return _one_tile_call(
+        _fwd_one_tile_kernel, q, bias, scale=scale, causal=causal,
+        interpret=interpret,
+        in_specs=[_whole(a.shape) for a in args],
+        out_specs=[_whole(q.shape), _whole(lse.shape)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), lse],
+    )(*args)
+
+
+def _bwd_one_tile(q, k, v, bias, o, lse, do, *, scale, causal, interpret):
+    D = q.shape[-1]
+    args = [q, k, v] + ([bias] if bias is not None else []) + [do, o, lse]
+    return _one_tile_call(
+        _bwd_one_tile_kernel, q, bias, scale=scale, causal=causal,
+        interpret=interpret,
+        in_specs=[_whole(a.shape) for a in args],
+        out_specs=[_whole(q.shape), _whole(k.shape), _whole(v.shape)],
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in (q, k, v)],
+        scratch_shapes=[
+            pltpu.VMEM((k.shape[2], D), jnp.float32),
+            pltpu.VMEM((k.shape[2], D), jnp.float32),
+        ],
+    )(*args)
+
+
+# ---------------------------------------------------------------------------
 # custom_vjp wrapper over padded [B, H, Q, D] layout
 # ---------------------------------------------------------------------------
 
@@ -445,15 +666,16 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _prep_block_inputs(q, k, v, bias, block_q, block_k, scale):
-    """Shared prologue for the kernel entry points: shrink-to-ceil8 tile
-    sizes, [B, H, T, D] transpose + tile padding, bias padding/masking,
-    default 1/sqrt(D) scale."""
+    """Shared prologue for the kernel entry points: tile sizes fitted to
+    the lengths (:func:`fitted_block`; a caller's own are shrunk to one
+    tile over the axis), [B, H, T, D] transpose + tile padding, bias
+    padding/masking, default 1/sqrt(D) scale."""
     D = q.shape[-1]
     if scale is None:
         scale = float(1.0 / (D ** 0.5))
     Q, K = q.shape[1], k.shape[1]
-    block_q = min(block_q, max(8, -(-Q // 8) * 8))
-    block_k = min(block_k, max(8, -(-K // 8) * 8))
+    block_q = fitted_block(Q) if block_q is None else min(block_q, _one_tile(Q))
+    block_k = fitted_block(K) if block_k is None else min(block_k, _one_tile(K))
     qt, _ = _pad_to(jnp.transpose(q, (0, 2, 1, 3)), 2, block_q)
     kt, _ = _pad_to(jnp.transpose(k, (0, 2, 1, 3)), 2, block_k)
     vt, _ = _pad_to(jnp.transpose(v, (0, 2, 1, 3)), 2, block_k)
@@ -462,7 +684,8 @@ def _prep_block_inputs(q, k, v, bias, block_q, block_k, scale):
 
 
 def flash_block_fwd(q, k, v, bias, scale: Optional[float] = None,
-                    block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: bool = False):
     """Single-block forward returning the logsumexp — the building block for
     cross-block softmax combination (ring attention over the sp axis).
@@ -485,7 +708,8 @@ def flash_block_fwd(q, k, v, bias, scale: Optional[float] = None,
 
 
 def flash_block_bwd(q, k, v, bias, o, lse, do, scale: Optional[float] = None,
-                    block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: bool = False):
     """Single-block backward against an *external* (combined) logsumexp.
 
@@ -552,8 +776,8 @@ def flash_attention(
     v: jax.Array,  # [B, K, H, D]
     bias: Optional[jax.Array] = None,  # broadcastable to [B, H, Q, K]
     causal: bool = False,
-    block_q: int = BLOCK_Q,
-    block_k: int = BLOCK_K,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Flash attention over the framework's [B, T, H, D] layout.

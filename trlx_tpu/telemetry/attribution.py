@@ -122,8 +122,9 @@ class WorkItem:
 
 #: the fixed-sampler PPO phase: the compiled sampler fires once per
 #: chunk (collect/decode spans count them) over the collect window;
-#: streamed epoch-1 steps + the residual fused scan (epochs 2..E)
-#: charge the train window — under phase overlap their device work
+#: streamed epoch-1 steps + the residual epochs 2..E (one
+#: ``train/residual`` span of single-step dispatches, priced as the
+#: fused scan over as many steps) charge the train window — under phase overlap their device work
 #: partially hides inside collect, and the train window holds the
 #: drain that waits for it (a conservative split, documented).
 PPO_FIXED_WORK: Tuple[WorkItem, ...] = (

@@ -376,13 +376,21 @@ class PPOTrainer(BaseRLTrainer):
         self.opt_shardings = self._shardings_for(opt_shapes)
         opt_state = jax.jit(self.tx.init, out_shardings=self.opt_shardings)(params)
 
-        self.state = TrainState(
-            params=params, opt_state=opt_state, step=jnp.zeros((), jnp.int32)
-        )
         self.state_shardings = TrainState(
             params=self.param_shardings,
             opt_state=self.opt_shardings,
             step=replicated(self.mesh),
+        )
+        # the counter is placed as the train step returns it: left
+        # uncommitted, its type differs from the returned state's by the
+        # mesh, and the second update of a run traces, lowers, keys and
+        # loads the whole train program again (PERF.md §6, PR 37)
+        self.state = TrainState(
+            params=params,
+            opt_state=opt_state,
+            step=jax.device_put(
+                jnp.zeros((), jnp.int32), self.state_shardings.step
+            ),
         )
 
         self.buffer = PPORolloutBuffer()
@@ -1383,7 +1391,7 @@ class PPOTrainer(BaseRLTrainer):
     #    dispatched the moment their constituent rollouts exist — while
     #    later chunks are still decoding against the frozen snapshot;
     # 3. `finish_streamed_phase` dispatches any remainder, runs epochs
-    #    2..ppo_epochs as the fused train_phase scan, advances the KL
+    #    2..ppo_epochs through the same train step, advances the KL
     #    controller once per minibatch (it only feeds the NEXT phase),
     #    and reports overlap attribution stats.
     #
@@ -1609,7 +1617,6 @@ class PPOTrainer(BaseRLTrainer):
         # exporter, bench's span payload, and the --perf-audit lockfile.
         # Forced spans still measure when the tracer is disabled (the
         # exp/overlap_* stats stay correct), they just go unrecorded.
-        residual_stats = None
         residual_ms = 0.0
         if isinstance(st, _AsyncStreamedPhase):
             st.collect_done = True
@@ -1629,31 +1636,37 @@ class PPOTrainer(BaseRLTrainer):
             drain_ms = drain_sp.duration_ms
 
             # the snapshot is dead weight for the residual epochs — drop
-            # our reference before the fused dispatch (in-flight consumers
+            # our reference before they are dispatched (in-flight consumers
             # keep the device buffers alive until they complete)
             self._behavior_params = None
 
+            # Epochs 2..E through the SAME program as epoch 1, a dispatch a
+            # minibatch and nothing blocked on between them: the fused scan
+            # (`_train_phase_jit`) took twelve steps' device time to the
+            # 0.1% and cost every run a second model-sized forward-and-
+            # backward program to trace, lower, key and load (PERF.md §6,
+            # PR 37). Same steps in the same order, so parameters and
+            # statistics are the fused pass's.
+            residual_stats = []
             if plan.residual.size:
-                mbs = self.buffer.gather(
-                    plan.residual, sharding=self._stacked_batch_sh
-                )
                 with telemetry.span("train/residual", force=True) as res_sp:
-                    self.state, residual_stats = self._train_phase_jit(
-                        self.state, mbs
-                    )
+                    for row in plan.residual:
+                        mb = self.buffer.gather(row, sharding=self._batch_sh)
+                        self.state, stats = self._train_step_jit(
+                            self.state, mb
+                        )
+                        residual_stats.append(stats)
                     jax.block_until_ready(self.state.params)
                 residual_ms = res_sp.duration_ms
 
             # one transfer event for every host consumer of the phase
-            e1_rows, res_rows, mean_kl = jax.device_get(
-                (st.epoch1_stats, residual_stats, self.mean_kl)
+            step_rows, mean_kl = jax.device_get(
+                (st.epoch1_stats + residual_stats, self.mean_kl)
             )
-        rows: Dict[str, np.ndarray] = {}
-        for key in e1_rows[0]:
-            seq = np.stack([np.asarray(r[key]) for r in e1_rows])
-            if res_rows is not None:
-                seq = np.concatenate([seq, np.asarray(res_rows[key])])
-            rows[key] = seq
+        rows: Dict[str, np.ndarray] = {
+            key: np.stack([np.asarray(r[key]) for r in step_rows])
+            for key in step_rows[0]
+        }
 
         # adaptive KL controller: one update per minibatch, compounding as
         # the stepwise/fused paths do — it only feeds the NEXT collection,
