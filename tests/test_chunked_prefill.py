@@ -689,7 +689,9 @@ def test_skip_share_histogram_is_observed_once_an_admission(derived_server):
 # digests): gpt2 2 x 32 in bf16, Q 16 + R 8, 4 slots, admit/harvest 2
 PARENT_PROGRAMS = {
     "sampler": "0ad7eebdd65972e5",
-    "prefill": "09eb3fddfb0669cc",
+    # PR 42: the forward addresses its group's rows inside the whole pool
+    # (no slice of the group, no merge back); 09eb3fddfb0669cc before it
+    "prefill": "e1d3e97bfe73739e",
     "decode_step": "d393924ac90fb367",
     "refill": "5e8422c7555df564",
 }
@@ -763,8 +765,8 @@ def test_default_rollout_paths_lower_the_parents_programs(program):
     """Chunked admission is the serving pump's: the trainer's collect loop
     on the continuous engine with default options still lowers the
     monolithic ``prefill``, and ``decode_step`` and ``refill`` with it, text
-    for text what they were before PR 30, and the fixed sampler is not
-    touched. A change that means to alter one of these programs updates
+    for text what they were before PR 30 (``prefill`` as PR 42 left it),
+    and the fixed sampler is not touched. A change that means to alter one of these programs updates
     its digest here (``tools/program_hashes.py`` compares whole runs)."""
     assert _default_path_digests()[program] == PARENT_PROGRAMS[program]
 

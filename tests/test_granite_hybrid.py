@@ -539,6 +539,69 @@ def test_engine_logprobs_match_the_uncached_forward_on_the_tokens_it_drew(chunk,
         assert eng.stats.prefill_cols_skipped > 0  # all-pad chunks were not computed
 
 
+@pytest.mark.parametrize("program", ["prefill", "prefill_chunk"])
+def test_an_admission_leaves_every_other_slots_keys_and_state_as_they_were(program):
+    """Grouped KV heads beside state layers: the attention layer's pool is
+    handed to the forward whole and written where the group's rows lie, the
+    state layers' rows are taken and set back; either way slots 0 and 1 (a
+    running group, two steps in) and the idle slot 2 read bit for bit what
+    they read before slot 3 and a dummy are admitted, and slot 3 holds the
+    keys and the state of its prompt alone."""
+    import dataclasses
+
+    eng, params = engine(4, 1)
+    cfg, _, backbone = model_and_params()
+    state = eng.init_state()
+    ids0, mask0 = left_padded([9, 16], Q, seed=1)
+    key = jax.random.PRNGKey(5)
+    state = eng.prefill_jit(params, state, jnp.asarray([0, 1], jnp.int32), ids0, mask0,
+                            jnp.asarray([7, 8], jnp.int32), jnp.asarray([1, 3], jnp.int32), key)
+    for _ in range(2):
+        state = eng.decode_step_jit(params, state)[0]
+    before = jax.device_get(jax.tree_util.tree_map(jnp.array, state))
+
+    slot_ids = jnp.asarray([3, eng.num_slots], jnp.int32)
+    turns = jnp.asarray([2, 4], jnp.int32)
+    ids, mask = left_padded([13, 6], Q, seed=2)
+    rows = jnp.arange(2, dtype=jnp.int32)
+    if program == "prefill":
+        state = eng.prefill_jit(params, state, slot_ids, ids, mask, rows, turns, key)
+    else:
+        for c in range(Q // 4):
+            state = eng.prefill_chunk_jit(params, state, slot_ids, ids, mask, rows, turns, key,
+                                          jnp.asarray(c, jnp.int32))
+    after = jax.device_get(state)
+
+    others = [0, 1, 2]
+    for was, now in zip(before.cache, after.cache):
+        for k in was:
+            np.testing.assert_array_equal(np.asarray(now[k])[others], np.asarray(was[k])[others], err_msg=k)
+    for f in dataclasses.fields(before):
+        if f.name != "cache":
+            np.testing.assert_array_equal(np.asarray(getattr(after, f.name))[others],
+                                          np.asarray(getattr(before, f.name))[others], err_msg=f.name)
+    # slot 3 against the same prompt through a dense cache of one row
+    dense = init_granite_hybrid_cache(cfg, 1, eng.capacity)
+    cache_mask = jnp.concatenate([mask[:1], jnp.zeros((1, R), mask.dtype)], axis=1)
+    want = GraniteMoeHybridModel(cfg).apply(
+        {"params": backbone}, ids[:1], attention_mask=cache_mask,
+        position_ids=jnp.clip(jnp.cumsum(mask[:1], axis=-1) - 1, 0, None), cache=dense, cache_index=0,
+    )["cache"]
+    nb, bs = eng.n_blocks, eng.block_size
+    table = (np.arange(nb) + 2) % nb
+    real = np.flatnonzero(np.asarray(mask[0]))
+    phys = table[real // bs] * bs + real % bs
+    for now, ref_layer in zip(after.cache, want):
+        if cache_kind(now).layout == STATE:
+            for k in ref_layer:
+                np.testing.assert_allclose(np.asarray(now[k])[3], np.asarray(ref_layer[k])[0], rtol=0, atol=2e-5, err_msg=k)
+        else:
+            np.testing.assert_array_equal(np.asarray(now["block_tables"])[3], table)
+            for k in ("k", "v"):
+                np.testing.assert_allclose(np.asarray(now[k])[3, phys], np.asarray(ref_layer[k])[0, real],
+                                           rtol=0, atol=2e-5, err_msg=k)
+
+
 def test_engine_refuses_what_a_state_layer_cannot_give():
     from trlx_tpu.inference.engine import ContinuousBatchingEngine
     from trlx_tpu.ops.sampling import GenerationConfig
@@ -594,7 +657,8 @@ def test_the_fused_read_takes_a_scale_over_an_int8_cache_as_the_generic_read_doe
 def test_which_paths_the_engines_programs_traced():
     """Counted per traced call site: the decode step reads its one KV layer
     as stored (``paged``) and steps its three state layers; an admission
-    program scans them and takes the generic read."""
+    program scans them and addresses its group's rows inside the whole
+    pool (``paged_rows``), none left under ``generic``."""
     from trlx_tpu import telemetry
 
     eng, params = engine.__wrapped__(4, 1)  # its own: a program traced before counts nothing again
@@ -617,4 +681,5 @@ def test_which_paths_the_engines_programs_traced():
     assert after_step["ssm/path{path=step}"] == n_state and "ssm/path{path=scan}" not in after_step
     assert after_step["attention/decode_path{path=paged}"] == 1
     assert after_chunk["ssm/path{path=scan}"] == n_state
-    assert after_chunk["attention/decode_path{path=generic}"] == 1
+    assert after_chunk["attention/decode_path{path=paged_rows}"] == 1
+    assert "attention/decode_path{path=generic}" not in after_chunk

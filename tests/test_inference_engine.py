@@ -453,7 +453,7 @@ def _lowered_engine_programs():
             path: get_metrics().counter(
                 "attention/decode_path{path=%s}" % path
             ).value
-            for path in ("fused", "paged", "generic")
+            for path in ("fused", "paged", "paged_rows", "generic")
         }
 
     def lowered(jitted, *args):
@@ -472,13 +472,14 @@ def _lowered_engine_programs():
     return engine, n_layer, decode, prefill
 
 
-def test_decode_step_reads_the_pool_as_stored_and_prefill_the_view():
+def test_decode_step_reads_the_pool_as_stored_and_prefill_its_rows_of_it():
     """``attention/decode_path``: every layer of a traced ``decode_step``
-    takes the paged read, every layer of the admission prefill the generic
-    one, and neither the fixed sampler's."""
+    takes the paged read, every layer of the admission prefill addresses
+    its group's rows inside the whole pool (``paged_rows``), and neither
+    the fixed sampler's."""
     _, n_layer, (_, decode), (_, prefill) = _lowered_engine_programs()
-    assert decode == {"fused": 0, "paged": n_layer, "generic": 0}
-    assert prefill == {"fused": 0, "paged": 0, "generic": n_layer}
+    assert decode == {"fused": 0, "paged": n_layer, "paged_rows": 0, "generic": 0}
+    assert prefill == {"fused": 0, "paged": 0, "paged_rows": n_layer, "generic": 0}
 
 
 def test_decode_step_holds_no_copy_of_a_pool():
@@ -487,9 +488,10 @@ def test_decode_step_holds_no_copy_of_a_pool():
     TPU compiler then converted to float32, whole, for a one-row query. The
     lowered ``decode_step`` holds no gather that permutes a pool (same
     shape in and out) and keeps its one scatter of rows a pool. The
-    pattern does find the gathers where they are: the admission prefill
-    attends over the logical view of its group of slots, K and V a
-    layer."""
+    pattern does find a gather where there is one: the admission prefill
+    attends over the logical view of its group's rows, gathered from the
+    whole pool, K and V a layer (and since PR 42 takes no other:
+    tests/test_admission_in_place.py)."""
     import re
 
     engine, n_layer, (decode, _), (prefill, _) = _lowered_engine_programs()
@@ -501,12 +503,13 @@ def test_decode_step_holds_no_copy_of_a_pool():
         """A pool of ``n_slots`` slots as the indexing lowers it."""
         return (f"{n_slots}x{cap}x{H}x{Dh}x{dt}", f"{n_slots * cap}x{H}x{Dh}x{dt}")
 
-    def permuting_gathers(text, n_slots):
+    def permuting_gathers(text, n_slots, n_rows=None):
         return [
             m for m in re.finditer(
                 r'"stablehlo\.gather"[^\n]*: \(tensor<([0-9x]+\w+)>, [^\n]*'
                 r"-> tensor<([0-9x]+\w+)>", text)
-            if m.group(1) in pool_shapes(n_slots) and m.group(2) in pool_shapes(n_slots)
+            if m.group(1) in pool_shapes(n_slots)
+            and m.group(2) in pool_shapes(n_rows or n_slots)
         ]
 
     def pool_scatters(text, n_slots):
@@ -520,7 +523,7 @@ def test_decode_step_holds_no_copy_of_a_pool():
     decode_text = decode.as_text()
     assert not permuting_gathers(decode_text, B)
     assert len(pool_scatters(decode_text, B)) == 2 * n_layer
-    assert len(permuting_gathers(prefill.as_text(), engine.admit_width)) == 2 * n_layer
+    assert len(permuting_gathers(prefill.as_text(), B, engine.admit_width)) == 2 * n_layer
 
 
 # --------------------------- config refusals --------------------------- #

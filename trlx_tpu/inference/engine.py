@@ -86,6 +86,7 @@ import numpy as np
 from trlx_tpu import telemetry
 from trlx_tpu.ops.kv_cache import (
     SHARED_POOL_KEYS,
+    SHARE_TABLE_KEYS,
     STATE,
     cache_kind,
     choose_block_size,
@@ -326,10 +327,10 @@ class ContinuousBatchingEngine:
         pays through the columns it skips, and such a group's forwards
         would hold the admission path for most of ``Q / chunk``
         iterations (the requests behind it wait, its own first token
-        comes that much later) and pay the per-forward costs (the
-        group's slice, its merge, the view gathers) each time. Same
-        tokens and masks either way. 0 — the default — never: every
-        group goes in chunks. ``InferenceServer`` passes
+        comes that much later) and pay what a forward costs whatever
+        its columns (the gather of the group's prompt-wide view, the
+        launch of every layer) each time. Same tokens and masks either
+        way. 0 — the default — never: every group goes in chunks. ``InferenceServer`` passes
         :data:`~trlx_tpu.ops.kv_cache.SERVING_PREFILL_MIN_SKIP_SHARE`
         where it derives the chunk itself.
     :param spec_max_draft: speculative decoding (``rollout.spec_decode``,
@@ -728,13 +729,19 @@ class ContinuousBatchingEngine:
 
         sharing = self.prefix_pool_blocks > 0
 
-        def slice_group_cache(state, slot_ids, table_turns,
-                              shared_map, publish_map):
-            """The admitted slots' cache slice with freshly-rotated block
-            tables (+ the group's share/publish maps and the whole pool
-            when sharing) — shared by the monolithic prefill and every
-            chunked-prefill call (one implementation, one parity
-            surface). Recycled slots get a rotated table: physical block
+        def group_cache(state, slot_ids, table_turns,
+                        shared_map, publish_map):
+            """The cache an admission forward is handed — shared by the
+            monolithic prefill and every chunked-prefill call (one
+            implementation, one parity surface). A paged layer goes
+            WHOLE: its pools (and the shared-prefix pool) as they lie,
+            with the group's freshly-rotated block tables, its
+            share/publish maps and its slot ids under ``"slot_ids"``, so
+            the forward scatters its columns at (slot, physical position)
+            in the donated pool and gathers the group's view alone
+            (``ops/kv_cache.py::paged_write_read``, ``cache_kind().rows``):
+            no slice of the group's rows, no merge back, no copy of a
+            pool. Recycled slots get a rotated table: physical block
             reuse order differs from logical order, so table resolution
             is exercised on every refill."""
             new_tables = (
@@ -743,50 +750,48 @@ class ContinuousBatchingEngine:
                 % nb
             )
 
-            def slice_layer(layer):
-                sl = {
-                    k: jnp.take(v, slot_ids, axis=0)
-                    for k, v in layer.items()
-                    if k != "block_tables" and k not in SHARED_POOL_KEYS
-                }
+            def group_layer(layer):
                 if cache_kind(layer).layout == STATE:
                     # the slots' rows as they stand: the model starts a row
                     # from zeros where no valid column precedes the call
                     # (ops/ssm.py::call_columns), so a recycled slot never
                     # reads its predecessor's state and a later chunk
                     # carries on from the one before
-                    return sl
-                sl["block_tables"] = new_tables
+                    return {
+                        k: jnp.take(v, slot_ids, axis=0)
+                        for k, v in layer.items()
+                    }
+                out = dict(layer, block_tables=new_tables, slot_ids=slot_ids)
                 if sharing:
-                    # the pool is global — pass it whole; the admitted
-                    # rows' share/publish assignments replace the
-                    # recycled slots' stale metadata
-                    for k in SHARED_POOL_KEYS:
-                        if k in layer:
-                            sl[k] = layer[k]
-                    sl["shared_tables"] = shared_map
-                    sl["publish_tables"] = publish_map
-                return sl
+                    # the admitted rows' share/publish assignments; the
+                    # recycled slots' stale ones are replaced on landing
+                    out["shared_tables"] = shared_map
+                    out["publish_tables"] = publish_map
+                return out
 
-            return tuple(slice_layer(l) for l in state.cache)
+            return tuple(group_layer(l) for l in state.cache)
 
-        def merge_group_cache(state, slot_ids, cache_out):
-            def merge_layer(full, sl):
+        def land_group_cache(state, slot_ids, cache_out):
+            """The state's cache after an admission forward: the pools as
+            the forward left them, and the group's rows of everything
+            kept a slot (block tables, share/publish maps, a state
+            layer's rows) set at ``slot_ids`` (a dummy's drop)."""
+            def land_layer(full, out):
+                by_slot = cache_kind(full).layout == STATE
+
                 def one(k):
-                    if k in SHARED_POOL_KEYS:
-                        # global pool: take the (possibly published-to)
-                        # pool wholesale, never slot-scattered
-                        return sl[k].astype(full[k].dtype)
-                    return (
-                        full[k]
-                        .at[slot_ids]
-                        .set(sl[k].astype(full[k].dtype), mode="drop")
-                    )
+                    if by_slot or k == "block_tables" or k in SHARE_TABLE_KEYS:
+                        return (
+                            full[k]
+                            .at[slot_ids]
+                            .set(out[k].astype(full[k].dtype), mode="drop")
+                        )
+                    return out[k]
 
                 return {k: one(k) for k in full}
 
             return tuple(
-                merge_layer(f, s) for f, s in zip(state.cache, cache_out)
+                land_layer(f, o) for f, o in zip(state.cache, cache_out)
             )
 
         @jax.named_scope("prefill")
@@ -806,7 +811,7 @@ class ContinuousBatchingEngine:
             row_keys = make_row_keys(phase_key, row_index)
             n_real = jnp.sum(prompt_mask, axis=-1).astype(jnp.int32)
 
-            cache_slice = slice_group_cache(
+            cache_in = group_cache(
                 state, slot_ids, table_turns, shared_map, publish_map
             )
             cache_mask = concat_cols(
@@ -818,7 +823,7 @@ class ContinuousBatchingEngine:
                 prompt_ids,
                 attention_mask=cache_mask,
                 position_ids=positions,
-                cache=cache_slice,
+                cache=cache_in,
                 cache_index=0,
                 **prefill_kwargs,
             )
@@ -832,7 +837,7 @@ class ContinuousBatchingEngine:
             else:
                 finished0 = jnp.zeros((A,), bool)
 
-            new_cache = merge_group_cache(state, slot_ids, out["cache"])
+            new_cache = land_group_cache(state, slot_ids, out["cache"])
 
             def put(field, rows):
                 return field.at[slot_ids].set(
@@ -1168,7 +1173,7 @@ class ContinuousBatchingEngine:
             shared_map=None,  # [A, nb] int32 (sharing engines only)
             publish_map=None,
         ) -> EngineState:
-            cache_slice = slice_group_cache(
+            cache_in = group_cache(
                 state, slot_ids, table_turns, shared_map, publish_map
             )
             positions = jnp.clip(
@@ -1183,13 +1188,13 @@ class ContinuousBatchingEngine:
 
                 return jax.lax.cond(need[c], run, lambda cch: cch, cache), None
 
-            cache_slice, _ = jax.lax.scan(
-                body, cache_slice, jnp.arange(n_scan_chunks)
+            cache_in, _ = jax.lax.scan(
+                body, cache_in, jnp.arange(n_scan_chunks)
             )
             return dataclasses.replace(
                 state,
                 cache=pin_cache(
-                    merge_group_cache(state, slot_ids, cache_slice)
+                    land_group_cache(state, slot_ids, cache_in)
                 ),
             )
 
@@ -1269,7 +1274,7 @@ class ContinuousBatchingEngine:
             scan's ``while`` the compiler converts float32 served weights
             to bf16 whole and holds 3.3x the temporaries, 47.7 ms a
             forward against 38.3 in pythia-1.4b's serving cell."""
-            cache_slice = slice_group_cache(
+            cache_in = group_cache(
                 state, slot_ids, table_turns, shared_map, publish_map
             )
             positions = jnp.clip(
@@ -1282,14 +1287,14 @@ class ContinuousBatchingEngine:
                 position_ids=jax.lax.dynamic_slice_in_dim(
                     positions, c * W, W, axis=1
                 ),
-                cache=cache_slice,
+                cache=cache_in,
                 cache_index=c * W,
                 **prefill_kwargs,
             )
             return seed_group(
                 state,
                 jnp.where(c == n_pc - 1, slot_ids, self.num_slots),
-                merge_group_cache(state, slot_ids, out["cache"]),
+                land_group_cache(state, slot_ids, out["cache"]),
                 prompt_ids, prompt_mask, row_index, phase_key, out,
             )
 
@@ -1306,7 +1311,7 @@ class ContinuousBatchingEngine:
             shared_map=None,
             publish_map=None,
         ) -> EngineState:
-            cache_slice = slice_group_cache(
+            cache_in = group_cache(
                 state, slot_ids, table_turns, shared_map, publish_map
             )
             positions = jnp.clip(
@@ -1318,14 +1323,14 @@ class ContinuousBatchingEngine:
                 prompt_ids[:, off:],
                 attention_mask=prompt_mask,  # Q-wide view
                 position_ids=positions[:, off:],
-                cache=cache_slice,
+                cache=cache_in,
                 cache_index=off,
                 **prefill_kwargs,
             )
             return seed_group(
                 state,
                 slot_ids,
-                merge_group_cache(state, slot_ids, out["cache"]),
+                land_group_cache(state, slot_ids, out["cache"]),
                 prompt_ids, prompt_mask, row_index, phase_key, out,
             )
 

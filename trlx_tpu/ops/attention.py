@@ -405,13 +405,19 @@ def decode_attention(
       the rows are scattered in place and :func:`dot_product_attention`
       reads the pools as stored, in each slot's physical order, under the
       bias re-indexed to that order. No logical view is gathered;
+    - ``paged_rows`` — a group's rows inside the whole paged pool (the
+      engine's admission programs; ``cache_kind(...).rows``):
+      ``paged_write_read`` scatters the call's columns at (slot, physical
+      position), in place, and gathers the group's logical view, which
+      :func:`dot_product_attention` reads under the bias as it is. The
+      rest of the pool is neither sliced, merged nor copied;
     - ``generic`` — everything else, unchanged: ``dense_write_read`` or
       ``paged_write_read`` returns the view the bias was built for and
-      :func:`dot_product_attention` reads it. Prefill and chunked
+      :func:`dot_product_attention` reads it. The fixed sampler's
       prefill, the verify step, T5's learned per-head bias, a cache whose
       capacity axis is sharded (the sampler leaves those in the
-      ``kv_buffers`` layout), a paged int8 pool and a paged pool with a
-      shared-prefix overlay.
+      ``kv_buffers`` layout), and a one-token call into a paged int8 pool
+      or a paged pool with a shared-prefix overlay.
     """
     kind = cache_kind(cache_kv)
     # attend over the buffer VIEW the bias was built for: a bias narrower
@@ -426,7 +432,12 @@ def decode_attention(
         and not causal
         and reads_as_stored(cache_kv, k_new, cache_index, view_len)
     )
-    path = "fused" if fused else "paged" if paged else "generic"
+    path = (
+        "fused" if fused
+        else "paged" if paged
+        else "paged_rows" if kind.rows
+        else "generic"
+    )
     get_metrics().counter("attention/decode_path{path=%s}" % path).inc()
     if paged:
         k, v, new_kv = paged_write_read(

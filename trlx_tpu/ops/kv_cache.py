@@ -65,6 +65,12 @@ blocks:
   the slot's **logical view** — a per-position gather back into logical
   order, materialised — so attention over it is the computation the
   fixed cache runs, term for term in the same order;
+- an admission call is a **group's**: the engine hands the model the
+  pools whole with the group's fresh tables and, under ``"slot_ids"``,
+  the pool row of each of the call's rows. Its columns are scattered at
+  (slot, physical position) where they lie and its view is gathered from
+  there; no slice of the group's rows, no merge back (on the v5e the two
+  were 16 of a 39.6 ms chunk forward of pythia-1.4b; PERF.md §6, PR 42);
 - the decode step (one position a slot, a floating pool) reads the pool
   **as stored**: attention is a sum over positions, so it runs in
   physical order with the bias re-indexed (:func:`stored_order_bias`)
@@ -332,12 +338,16 @@ class CacheKind(NamedTuple):
     layout: str  # DENSE | FOLDED | PAGED | STATE
     quantized: bool  # int8 values + bf16 scales
     shared: bool  # a paged cache with a shared-prefix overlay
+    rows: bool = False  # a paged call over a group's rows of the whole pool
 
 
 def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
     """Classify one layer's cache dict, at trace time, from the keys it
     carries and the rank of ``k`` — every reader of "which storage is
-    this" asks here."""
+    this" asks here. ``"slot_ids"`` beside ``"block_tables"`` marks an
+    admission call: the pools are whole (``num_slots`` rows), the tables
+    and the call's K/V are the group's (``A`` rows), and row ``i`` of the
+    call lives in pool row ``slot_ids[i]`` (:func:`paged_write_read`)."""
     if "ssm_state" in cache_kv:
         return CacheKind(STATE, False, False)
     if "block_tables" in cache_kv:
@@ -347,7 +357,10 @@ def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
     else:
         layout = DENSE
     return CacheKind(
-        layout, "k_scale" in cache_kv, "shared_tables" in cache_kv
+        layout,
+        "k_scale" in cache_kv,
+        "shared_tables" in cache_kv,
+        layout == PAGED and "slot_ids" in cache_kv,
     )
 
 
@@ -482,8 +495,10 @@ def serving_prefill_chunk(query_length: int) -> int:
 #: 3-4 iterations with the requests behind it waiting
 #: (``serve_queue_wait_p95_ms`` 29 -> 131 and ``serve_ttft_p95_ms`` up in
 #: three of four runs of ``serve-olmoe1b7b-chat`` with every group in
-#: chunks; PERF.md section 6, PR 30) and would pay the per-forward costs
-#: each time. About one group in five of chat traffic (a prompt over
+#: chunks; PERF.md section 6, PR 30) and would pay what a forward costs
+#: whatever its columns each time (the gather of the group's view; the
+#: slice of the group and the merge back went with PR 42, so the share is
+#: due a new sweep). About one group in five of chat traffic (a prompt over
 #: Q // 2), under 2% of the gaps between tokens: the p99 gap, not the
 #: p95, keeps the whole forward's length. (The benchmark's
 #: ``moe_gmm_prefill_roofline`` reads that program's grouped
@@ -624,7 +639,8 @@ def reads_as_stored(cache_kv: Dict[str, jax.Array], k: jax.Array,
     position a slot at a per-slot or scalar ``cache_index``, a floating
     pool read at full width, and no shared-prefix overlay. Decided on what
     the call shows, at trace time; everything else reads the logical
-    view."""
+    view (a group's call does: its bias is the group's, the pool every
+    slot's)."""
     kind = cache_kind(cache_kv)
     capacity = cache_kv["k"].shape[1]
     return (
@@ -632,6 +648,7 @@ def reads_as_stored(cache_kv: Dict[str, jax.Array], k: jax.Array,
         and jnp.ndim(cache_index) <= 1
         and not kind.quantized
         and not kind.shared
+        and not kind.rows
         and not 0 < view_len < capacity
     )
 
@@ -662,18 +679,30 @@ def stored_order_bias(
     return stored.reshape(lead + (capacity,))
 
 
-def _gather_logical(pool: jax.Array, view_idx: jax.Array) -> jax.Array:
-    """Gather ``pool`` [B, cap, ...] rows into logical order."""
-    b_idx = jnp.arange(pool.shape[0], dtype=jnp.int32)[:, None]
-    return pool[b_idx, view_idx]
+def _pool_rows(pool: jax.Array, slot_ids) -> jax.Array:
+    """[B, 1] the pool row each of a call's rows lives in: its own where
+    the call spans every slot, ``slot_ids`` for a group's call."""
+    if slot_ids is None:
+        return jnp.arange(pool.shape[0], dtype=jnp.int32)[:, None]
+    return jnp.asarray(slot_ids, jnp.int32)[:, None]
 
 
-def _scatter_rows(pool: jax.Array, phys: jax.Array, rows: jax.Array) -> jax.Array:
-    """Scatter ``rows`` [B, T, ...] into ``pool`` [B, cap, ...] at
-    physical positions ``phys`` [B, T]; OOB positions drop (jax scatter
-    semantics — the discard sentinel relies on this)."""
-    b_idx = jnp.arange(pool.shape[0], dtype=jnp.int32)[:, None]
-    return pool.at[b_idx, phys].set(rows.astype(pool.dtype), mode="drop")
+def _gather_logical(pool: jax.Array, view_idx: jax.Array, slot_ids=None) -> jax.Array:
+    """Gather the call's rows of ``pool`` [num_slots, cap, ...] into logical
+    order, [B, view, ...]: one gather of (row, physical position) pairs."""
+    return pool[_pool_rows(pool, slot_ids), view_idx]
+
+
+def _scatter_rows(
+    pool: jax.Array, phys: jax.Array, rows: jax.Array, slot_ids=None
+) -> jax.Array:
+    """Scatter ``rows`` [B, T, ...] into ``pool`` [num_slots, cap, ...] at
+    physical positions ``phys`` [B, T] of the call's rows; an out-of-bounds
+    position or row (a group's dummy, ``slot_ids == num_slots``) drops (jax
+    scatter semantics — the discard sentinel relies on this)."""
+    return pool.at[_pool_rows(pool, slot_ids), phys].set(
+        rows.astype(pool.dtype), mode="drop"
+    )
 
 
 def _publish_rows(
@@ -724,17 +753,30 @@ def paged_write_read(
     rows through the block table, then return the buffers to attend over
     (plus the updated cache dict).
 
-    The write is one scatter of the call's rows, in place in the donated
-    pool whatever its dtype (on the v5e a bf16 scatter of 32 rows into an
-    84 MB pool takes microseconds; PERF.md §6, PR 28). What is returned to
-    attend over comes in two forms, and the form is most of a decode
-    step's cost:
+    The call's rows are every slot's (``k`` has the pool's ``num_slots``
+    rows: the decode and verify steps) or a **group's** (``cache_kv``
+    carries ``"slot_ids"`` ``[A]``, ``"block_tables"`` and the share maps
+    are the group's ``[A, n_blocks]``, the pools are whole: the engine's
+    admission programs, ``cache_kind(...).rows``). Either way a row is
+    addressed inside the pool by (pool row, physical position): nothing
+    is sliced out of the pool for a group and nothing merged back, and a
+    group's dummy row (``slot_ids == num_slots``) is out of bounds, so its
+    writes drop like a position at ``capacity``. The pools come back whole,
+    the tables and ``slot_ids`` as they were handed in.
 
-    - the **logical view** (default): a per-position gather of the pool
-      back into logical order, materialised, so the caller's bias and
-      causal structure apply as they are. Prefill, chunked prefill, the
-      verify step, int8 pools (dequantised after the gather) and
-      shared-prefix reads (overlaid on it);
+    The write is one scatter of the call's ``T`` columns at
+    ``(row, phys)``, in place in the donated pool whatever its dtype (on
+    the v5e ~75 ns a position of 16 heads x 128: 32 rows into an 84 MB
+    pool in microseconds, a chunk's 8 x 128 in 77 us, a whole prompt's 8 x
+    512 in 310 us; PERF.md §6, PR 28 and PR 42). What is returned to attend
+    over comes in two forms, and the form is most of a decode step's cost:
+
+    - the **logical view** (default): one gather of (row, physical
+      position) pairs from the pool into logical order, ``[B, view_len,
+      H, Dh]``, materialised, so the caller's bias and causal structure
+      apply as they are. Prefill, chunked prefill, the verify step, int8
+      pools (dequantised after the gather) and shared-prefix reads
+      (overlaid on it);
     - ``as_stored=True`` (callers check :func:`reads_as_stored`): the
       updated pools themselves, in physical order, with nothing gathered
       or copied. Attention is a sum over positions, so it may run in any
@@ -766,6 +808,7 @@ def paged_write_read(
     B, T = k.shape[0], k.shape[1]
     capacity = cache_kv["k"].shape[1]
     tables = cache_kv["block_tables"]
+    slot_ids = cache_kv["slot_ids"] if kind.rows else None
     idx = jnp.asarray(cache_index, jnp.int32)
     if idx.ndim == 2:
         # per-column targets: the caller names every column's logical
@@ -806,6 +849,12 @@ def paged_write_read(
             pool_size,
         )
 
+    def scatter(key, rows):
+        return _scatter_rows(cache_kv[key], phys, rows, slot_ids)
+
+    def logical(key):
+        return _gather_logical(new_kv[key], view, slot_ids)
+
     def overlay(full, pool_key, scale_key=None):
         if not sharing:
             return full
@@ -826,6 +875,8 @@ def paged_write_read(
         """Thread the share metadata (+ updated pools) through so the
         next step's cache dict keeps the full layout."""
         new_kv["block_tables"] = tables
+        if kind.rows:
+            new_kv["slot_ids"] = slot_ids
         if sharing:
             new_kv["shared_tables"] = cache_kv["shared_tables"]
             new_kv["publish_tables"] = cache_kv["publish_tables"]
@@ -835,10 +886,10 @@ def paged_write_read(
         k_q, k_s = quantize_kv(k)
         v_q, v_s = quantize_kv(v)
         new_kv = carry({
-            "k": _scatter_rows(cache_kv["k"], phys, k_q),
-            "v": _scatter_rows(cache_kv["v"], phys, v_q),
-            "k_scale": _scatter_rows(cache_kv["k_scale"], phys, k_s),
-            "v_scale": _scatter_rows(cache_kv["v_scale"], phys, v_s),
+            "k": scatter("k", k_q),
+            "v": scatter("v", v_q),
+            "k_scale": scatter("k_scale", k_s),
+            "v_scale": scatter("v_scale", v_s),
         })
         if sharing:
             new_kv["shared_k"] = _publish_rows(
@@ -853,25 +904,25 @@ def paged_write_read(
             new_kv["shared_v_scale"] = _publish_rows(
                 cache_kv["shared_v_scale"], pub_pos, v_s
             )
-        k_full = _gather_logical(new_kv["k"], view).astype(dtype) * (
-            _gather_logical(new_kv["k_scale"], view).astype(dtype)
+        k_full = logical("k").astype(dtype) * (
+            logical("k_scale").astype(dtype)
         )
-        v_full = _gather_logical(new_kv["v"], view).astype(dtype) * (
-            _gather_logical(new_kv["v_scale"], view).astype(dtype)
+        v_full = logical("v").astype(dtype) * (
+            logical("v_scale").astype(dtype)
         )
         k_full = overlay(k_full, "shared_k", "shared_k_scale")
         v_full = overlay(v_full, "shared_v", "shared_v_scale")
         return k_full, v_full, new_kv
 
     new_kv = carry({
-        "k": _scatter_rows(cache_kv["k"], phys, k),
-        "v": _scatter_rows(cache_kv["v"], phys, v),
+        "k": scatter("k", k),
+        "v": scatter("v", v),
     })
     if as_stored:
         return new_kv["k"], new_kv["v"], new_kv
     if sharing:
         new_kv["shared_k"] = _publish_rows(cache_kv["shared_k"], pub_pos, k)
         new_kv["shared_v"] = _publish_rows(cache_kv["shared_v"], pub_pos, v)
-    k_full = overlay(_gather_logical(new_kv["k"], view), "shared_k")
-    v_full = overlay(_gather_logical(new_kv["v"], view), "shared_v")
+    k_full = overlay(logical("k"), "shared_k")
+    v_full = overlay(logical("v"), "shared_v")
     return k_full, v_full, new_kv
