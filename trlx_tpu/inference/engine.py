@@ -104,6 +104,12 @@ from trlx_tpu.ops.sampling import (
 )
 from trlx_tpu.utils import sched_points
 
+#: what the host was doing while the chip sat drained (the starved
+#: ledger, docs/observability.md "The serving loop, from inside"): a
+#: part is entered with ``mark_starved`` and ``other`` is what no part
+#: claimed, so the parts sum to the total
+STARVED_PARTS = ("tap", "admit", "land", "caller", "other")
+
 
 @struct.dataclass
 class EngineState:
@@ -151,6 +157,17 @@ class EngineStats:
     # fetches (the engine/fetch spans): a step's wall less this is the
     # host's own exposed cost
     host_blocked_ms: float = 0.0
+    # the starved ledger: wall from the return of a *draining* fetch (a
+    # blocking fetch of an output of the newest dispatched program: that
+    # program has ended and nothing is queued behind it) to the entry of
+    # the next call that dispatches one, by the part of the loop the
+    # host was in (STARVED_PARTS). Closed episodes only; a lower bound
+    # on the chip's idle time (the launch after and the transfer's tail
+    # before are the device clock's to show). A host that runs ahead of
+    # the chip drains nothing and reads 0 here
+    starved_by_ms: Dict[str, float] = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(STARVED_PARTS, 0.0)
+    )
     # chunked prefill (rollout.prefill_chunk > 0): chunks actually RUN
     # (the finish chunk included) and prompt columns whose forward was
     # skipped (leading pad + pool-covered shared blocks). What one
@@ -183,6 +200,11 @@ class EngineStats:
     spec_drafted: int = 0
     spec_accepted: int = 0
     spec_draft_lens: List[int] = dataclasses.field(default_factory=list)
+
+    @property
+    def starved_ms(self) -> float:
+        """The ledger's total: its parts, summed in their one order."""
+        return sum(self.starved_by_ms.values())
 
     @property
     def prefill_flops_saved(self) -> float:
@@ -241,6 +263,7 @@ class EngineStats:
             "engine/weight_pushes": float(self.weight_pushes),
             "engine/released": float(self.released),
             "engine/host_blocked_ms": round(self.host_blocked_ms, 3),
+            "engine/starved_ms": round(self.starved_ms, 3),
             "engine/prefill_chunks": float(self.prefill_chunks),
             "engine/prefill_cols_skipped": float(self.prefill_cols_skipped),
             "engine/prefill_flops_saved": float(self.prefill_flops_saved),
@@ -534,6 +557,13 @@ class ContinuousBatchingEngine:
         self._pending_push: Optional[Tuple[Any, int]] = None
         self._push_lock = threading.Lock()
         self._steps_since_poll = 0
+        # the starved ledger's open episode (EngineStats.starved_by_ms
+        # holds the closed ones): when the chip was seen drained (None:
+        # it is fed, or the host runs ahead of it), the part of the loop
+        # the host is in (None: charged to nobody), the episode's parts
+        self._drained_at: Optional[float] = None
+        self._starved_part: Optional[str] = None
+        self._episode = dict.fromkeys(STARVED_PARTS, 0.0)
         #: host callback fired with the admitted rows' indices right
         #: after each prefill dispatch — the serving tier marks newly
         #: published prefix blocks ready for later admission groups here
@@ -1507,6 +1537,9 @@ class ContinuousBatchingEngine:
         with sched_points.guard(self._push_lock, "engine.push_lock"):
             self._pending_push = None
         self._steps_since_poll = 0
+        self._drained_at = None
+        self._starved_part = None
+        self._episode = dict.fromkeys(STARVED_PARTS, 0.0)
         self.stats = self._new_stats()
         self._req_times = {}
         self._step_log = []
@@ -1805,6 +1838,7 @@ class ContinuousBatchingEngine:
             if self.mesh is not None:
                 from trlx_tpu.parallel.mesh import batch_sharding
 
+                self._fed()
                 args = jax.device_put(args, batch_sharding(self.mesh))
         self._inflight_admission = {
             "take": take,
@@ -1849,6 +1883,7 @@ class ContinuousBatchingEngine:
         Returns ``(admission complete, chunk forwards dispatched)``."""
         adm = self._inflight_admission
         sharing = self.prefix_pool_blocks > 0
+        self._fed()
         map_args = []
         if sharing:
             map_args = [
@@ -2175,6 +2210,7 @@ class ContinuousBatchingEngine:
             with telemetry.span(
                 "collect/slot_recycle", force=True, harvested=C
             ):
+                self._fed()
                 self._state, outs = self.refill_jit(
                     self._state, jnp.asarray(slots, jnp.int32)
                 )
@@ -2275,12 +2311,16 @@ class ContinuousBatchingEngine:
         spec decode is on and any slot proposed a draft, else the plain
         one-token ``decode_step`` (the fall-through — draftless rounds
         never pay the wider program)."""
+        drafted = False
         if self.spec_max_draft > 0:
             draft, lens = self._take_drafts()
-            if lens.any():
-                self._verify_once(draft, lens)
-                return
-        self._decode_once()
+            drafted = bool(lens.any())
+        if drafted:
+            self._verify_once(draft, lens)
+        else:
+            self._decode_once()
+        # the step's own bookkeeping ends here (the ledger's ``tap``)
+        self.mark_starved("other")
 
     def _seeded_rows(self) -> Iterable[Tuple[int, int]]:
         """``(slot, row)`` of the busy slots whose device rows are their
@@ -2334,12 +2374,14 @@ class ContinuousBatchingEngine:
         into the drafter histories / stream taps, and prefetch the next
         step's drafts (host drafting overlaps the device's next work;
         the stage is dropped if a push/admission/harvest intervenes)."""
-        self._state, done, toks, acc = self.verify_step_jit(
-            self._params,
-            self._state,
-            jnp.asarray(draft),
-            jnp.asarray(lens),
-        )
+        with telemetry.span("engine/dispatch", program="verify_step"):
+            self._fed()
+            self._state, done, toks, acc = self.verify_step_jit(
+                self._params,
+                self._state,
+                jnp.asarray(draft),
+                jnp.asarray(lens),
+            )
         try:
             done.copy_to_host_async()
         except (AttributeError, RuntimeError):
@@ -2351,7 +2393,24 @@ class ContinuousBatchingEngine:
             self._step_log.append(
                 (telemetry.monotonic(), self.stats.prefills)
             )
-        tok_host, acc_host = self.fetch(toks, acc)
+        tok_host, acc_host = self.fetch(
+            toks, acc, what="tokens", newest=True
+        )
+        with telemetry.span("engine/route"):
+            self._route_verified(lens, tok_host, acc_host)
+        registry = telemetry.get_metrics()
+        registry.gauge("engine/spec_accept_rate").set(
+            self.stats.spec_accept_rate
+        )
+        registry.gauge("engine/spec_tokens_per_step").set(
+            self.stats.spec_tokens_per_step
+        )
+        self._staged_drafts = self._draft_now()
+        self._poll_done(done)
+
+    def _route_verified(self, lens, tok_host, acc_host) -> None:
+        """A verify step's accepted emissions into the acceptance
+        counters, the drafter's histories and the stream taps."""
         seeded = self._seeded_rows()
         for slot, row in seeded:
             n_cols = int(acc_host[slot].sum())  # anchor + accepted drafts
@@ -2392,28 +2451,21 @@ class ContinuousBatchingEngine:
                 }
                 if emitted:
                     self.token_sink(emitted)
-        registry = telemetry.get_metrics()
-        registry.gauge("engine/spec_accept_rate").set(
-            self.stats.spec_accept_rate
-        )
-        registry.gauge("engine/spec_tokens_per_step").set(
-            self.stats.spec_tokens_per_step
-        )
-        self._staged_drafts = self._draft_now()
-        self._poll_done(done)
 
     def _decode_once(self) -> None:
         """Dispatch one decode step for the whole pool and run the
         amortized done-poll + streaming-tap bookkeeping."""
-        if self.stream_taps:
-            self._state, polled, token, live = self.decode_step_jit(
-                self._params, self._state
-            )
-        else:
-            self._state, polled = self.decode_step_jit(
-                self._params, self._state
-            )
-            token = live = None
+        with telemetry.span("engine/dispatch", program="decode_step"):
+            self._fed()
+            if self.stream_taps:
+                self._state, polled, token, live = self.decode_step_jit(
+                    self._params, self._state
+                )
+            else:
+                self._state, polled = self.decode_step_jit(
+                    self._params, self._state
+                )
+                token = live = None
         done = polled.pop("done")
         try:
             done.copy_to_host_async()
@@ -2441,22 +2493,25 @@ class ContinuousBatchingEngine:
             # and the unfetched outputs are dropped on device). Spec
             # decode reads the same tap to keep the drafter histories
             # current through draftless fall-through steps.
-            tok_host, live_host = self.fetch(token, live)
-            seeded = self._seeded_rows()
-            if self.spec_drafter is not None:
-                for slot, row in seeded:
-                    if live_host[slot]:
-                        self.spec_drafter.observe_tokens(
-                            row, [int(tok_host[slot])]
-                        )
-            if self.token_sink is not None:
-                emitted = {
-                    row: int(tok_host[slot])
-                    for slot, row in seeded
-                    if live_host[slot]
-                }
-                if emitted:
-                    self.token_sink(emitted)
+            tok_host, live_host = self.fetch(
+                token, live, what="tokens", newest=True
+            )
+            with telemetry.span("engine/route"):
+                seeded = self._seeded_rows()
+                if self.spec_drafter is not None:
+                    for slot, row in seeded:
+                        if live_host[slot]:
+                            self.spec_drafter.observe_tokens(
+                                row, [int(tok_host[slot])]
+                            )
+                if self.token_sink is not None:
+                    emitted = {
+                        row: int(tok_host[slot])
+                        for slot, row in seeded
+                        if live_host[slot]
+                    }
+                    if emitted:
+                        self.token_sink(emitted)
         self._poll_done(done, polled)
 
     def _poll_done(self, done, moe_stats=None) -> None:
@@ -2469,7 +2524,10 @@ class ContinuousBatchingEngine:
         if self._steps_since_poll < self.done_poll_interval:
             return
         self._steps_since_poll = 0
-        done_host, moe_host = self.fetch(done, moe_stats or {})
+        # this step's flags: the newest program's, whatever the interval
+        done_host, moe_host = self.fetch(
+            done, moe_stats or {}, what="done", newest=True
+        )
         self.stats.done_polls += 1
         if moe_host:
             from trlx_tpu.ops.moe import record_step_stats
@@ -2499,15 +2557,51 @@ class ContinuousBatchingEngine:
                             self._step_base + len(self._step_log)
                         )
 
-    def fetch(self, *arrays) -> Tuple[np.ndarray, ...]:
+    def fetch(
+        self, *arrays, what: str = "group", newest: bool = False
+    ) -> Tuple[np.ndarray, ...]:
         """The step loop's blocking device->host fetch, in one transfer
-        event: the span ``engine/fetch`` is the host waiting on the
+        event: the span ``engine/fetch`` (attr ``what``: ``tokens``,
+        ``done``, or a harvested ``group``) is the host waiting on the
         device, and its wall accumulates in ``stats.host_blocked_ms``
-        (forced: the counter stands with the tracer off)."""
-        with telemetry.span("engine/fetch", force=True) as sp:
+        (forced: the counter stands with the tracer off). ``newest``
+        says the arrays are outputs of the newest dispatched program:
+        when the fetch returns the chip has drained, and the starved
+        ledger's clock starts (a harvested group's arrays are an older
+        program's, and drain nothing)."""
+        with telemetry.span("engine/fetch", force=True, what=what) as sp:
             host = jax.device_get(arrays)
         self.stats.host_blocked_ms += sp.duration_ms
+        if newest and self._drained_at is None:
+            self._drained_at = sp.end
+            self._starved_part = "tap"
         return host
+
+    # ------------------------- the starved ledger ---------------------- #
+
+    def mark_starved(self, part: Optional[str]) -> None:
+        """The host loop enters ``part`` (one of ``STARVED_PARTS``; None:
+        nobody's, where the engine holds no rows to be starved of). While
+        the chip is drained, the time since the last mark goes to the
+        part that was running; while it is fed this is one assignment."""
+        if self._drained_at is not None:
+            now = telemetry.monotonic()
+            if self._starved_part is not None:
+                self._episode[self._starved_part] += now - self._drained_at
+            self._drained_at = now
+        self._starved_part = part
+
+    def _fed(self) -> None:
+        """Called on entry to every dispatch of the loop: a drained chip
+        is fed again, and the episode closes into ``stats``."""
+        if self._drained_at is None:
+            return
+        self.mark_starved(None)
+        self._drained_at = None
+        by, episode = self.stats.starved_by_ms, self._episode
+        for part in STARVED_PARTS:
+            by[part] += episode[part] * 1000.0
+            episode[part] = 0.0
 
     # ------------------------- serving interface ----------------------- #
 

@@ -39,6 +39,7 @@ stay at zero events.
 from __future__ import annotations
 
 import itertools
+import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Union
 
@@ -57,6 +58,13 @@ SERVE_HISTOGRAMS = (
     "serve/decode_per_token_ms",
     "serve/e2e_ms",
 )
+
+#: the starved ledger by part, an observation an iteration beside the
+#: total ``serve/starved_ms`` (``other`` is the total less these)
+STARVED_HISTOGRAMS = {
+    part: f"serve/starved_ms[by={part}]"
+    for part in ("tap", "admit", "land", "caller")
+}
 
 
 def observe_request_metrics(
@@ -361,6 +369,10 @@ class InferenceServer:
         self._next_request = itertools.count()
         self.completion_order: List[int] = []
         self._groups_served = 0
+        # the ledger as the last observing iteration left it, and when
+        # the last iteration handed the loop back with rows in flight
+        self._starved_seen = dict(self.engine.stats.starved_by_ms)
+        self._returned_at: Optional[float] = None
 
     # ------------------------------ API -------------------------------- #
 
@@ -623,7 +635,11 @@ class InferenceServer:
         no admission prefill) or ``serve/admit_pump_ms`` (a prefill was
         dispatched: the stall every running stream feels),
         ``serve/step_host_ms`` (the wall less the time blocked in
-        ``engine/fetch``) and ``serve/slots_done_waiting``. While a
+        ``engine/fetch``), ``serve/slots_done_waiting``, and
+        ``serve/starved_ms`` with its ``[by=...]`` twins: how long the
+        chip sat drained before this iteration fed it, by the part of
+        the loop that held the host (the engine's starved ledger; this
+        method marks ``admit``, ``land`` and ``caller``). While a
         client streams, the engine fetches every step's tokens, so the
         walls are the device's; with no stream open only the ``done``
         flags are fetched, and they read one step behind at most."""
@@ -634,7 +650,9 @@ class InferenceServer:
         prefills = stats.prefills + stats.prefill_chunks
         steps = stats.decode_steps
         blocked_ms = stats.host_blocked_ms
+        engine.mark_starved("admit")
         with telemetry.span("serve/step", force=True) as sp:
+            self._stamp_caller(sp.start)
             admitted = self._schedule()
             # tap cost is per-step host fetches: only pay while someone
             # is actually streaming
@@ -644,8 +662,11 @@ class InferenceServer:
             busy_before = engine.pending
             groups = engine.pump()
             for group in groups:
+                engine.mark_starved("land")
                 with telemetry.span("serve/land"):
                     self._land_group(group)
+            if groups:
+                engine.mark_starved("other")
             sp.set(
                 admitted=admitted,
                 harvested=sum(len(g["rows"]) for g in groups),
@@ -663,7 +684,38 @@ class InferenceServer:
             registry.histogram("serve/slots_done_waiting").observe(
                 engine.done_waiting
             )
+            by = stats.starved_by_ms
+            seen, self._starved_seen = self._starved_seen, dict(by)
+            registry.histogram("serve/starved_ms").observe(
+                sum(by[part] - seen[part] for part in by)
+            )
+            for part, name in STARVED_HISTOGRAMS.items():
+                registry.histogram(name).observe(by[part] - seen[part])
+        # the caller's turn: starved time only while rows wait on it
+        waiting = engine.pending > 0
+        engine.mark_starved("caller" if waiting else None)
+        self._returned_at = sp.end if waiting else None
         return bool(groups) or busy_before > 0
+
+    def _stamp_caller(self, until: float) -> None:
+        """The caller's turn, from the last iteration's return with rows
+        in flight to this one's entry, as the span ``serve/caller``:
+        stamped after the fact like a request's trace (no parent, no
+        profiler annotation; in a device trace it is the time outside
+        every ``trlx/serve/step``)."""
+        since, self._returned_at = self._returned_at, None
+        if since is None:
+            return
+        from trlx_tpu import telemetry
+        from trlx_tpu.telemetry.request_trace import _stamp
+
+        tracer = telemetry.get_tracer()
+        if tracer.enabled:
+            thread = threading.current_thread()
+            tracer.record(_stamp(
+                "serve/caller", since, until, thread.ident or 0,
+                thread.name, {},
+            ))
 
     def _pump_once(self) -> bool:
         """The name :meth:`step` had before it was public (callers
