@@ -580,7 +580,9 @@ def test_request_trace_closes_for_early_popped_stream(server):
 def test_released_placeholders_cost_one_decode_step(server):
     """The padding-waste fix, pinned at the engine: release-flagged rows
     are force-finished on admission — a full harvest group of them
-    drains after ONE decode step instead of the R-step token budget."""
+    is done after ONE decode step instead of the R-step token budget
+    (its flags are read behind the dispatch of a second, which rides the
+    finished rows along: the loop reads one step behind)."""
     import jax
 
     eng = server.engine
@@ -593,7 +595,8 @@ def test_released_placeholders_cost_one_decode_step(server):
     mask[:, Q - 1] = 1
     eng.submit(ids, mask, release=True)
     groups = list(eng.drive(Hw))
-    assert eng.stats.decode_steps == 1  # was R before the fix
+    assert eng.stats.decode_steps == 2  # was R before the fix
+    assert eng.stats.steps_ahead == 1
     assert eng.stats.released == Hw
     assert np.asarray(groups[0]["response_mask"]).sum() == 0
 

@@ -5,7 +5,14 @@ starved ledger, the spans that tile an iteration, and the histograms
 Everything here runs on a fake clock that moves only where a test moves
 it (a hook on the device fetch, the token sink, the landing, the
 scheduler, the caller's turn), in units of 2**-10 s, so every expected
-number is exact: no assertion compares two spans on the real clock."""
+number is exact: no assertion compares two spans on the real clock.
+
+Since ISSUE 44 the loop reads one step behind what it has dispatched, so
+no fetch of a steady run drains the chip and the ledger reads 0 there
+(``tests/test_serving_step_ahead.py`` holds that). What the ledger says
+of a chip that *is* drained is pinned here as it was, on a loop made to
+drain every step (``drained``: each step's outputs read out before the
+next is dispatched, the order every step had before)."""
 
 import threading
 
@@ -68,6 +75,20 @@ def server():
     return InferenceServer(TRLConfig.from_dict(cfg))
 
 
+@pytest.fixture(autouse=True)
+def settled(server):
+    """Each test starts from a closed ledger: a run's tail drains its
+    pool, and that episode stays open until the next dispatch."""
+    server.engine._fed()
+    server._starved_seen = dict(server.engine.stats.starved_by_ms)
+
+
+def close(engine, clock, log):
+    """Close the episode a run's tail left open, where the log ends."""
+    log.append(("dispatch", clock.t, False))
+    engine._fed()
+
+
 @pytest.fixture(scope="module")
 def bare_engine(server):
     """An engine with no stream tap (PPO's ``rollout.engine: continuous``
@@ -82,10 +103,22 @@ def bare_engine(server):
     )
 
 
+def drained(monkeypatch, engine):
+    """Make ``engine`` read every step out before it dispatches the
+    next: each step's fetch is then of the newest program and drains."""
+    decode_once = engine._decode_once
+    monkeypatch.setattr(
+        engine, "_decode_once", lambda: (decode_once(), engine._read_held())
+    )
+
+
 def record(monkeypatch, engine, clock, device_units=0.0):
     """Log every fetch's return and every dispatch's entry of ``engine``
-    with the clock's reading; a fetch holds the host ``device_units``."""
+    with the clock's reading; a fetch holds the host ``device_units``.
+    Whether the step in flight has ended is the real device's to say and
+    not this clock's: here it never has."""
     log = []
+    monkeypatch.setattr(engine, "_ran_out", lambda: False)
     real_get, real_fetch = jax.device_get, engine.fetch
 
     def device_get(x):
@@ -93,12 +126,26 @@ def record(monkeypatch, engine, clock, device_units=0.0):
         return real_get(x)
 
     def fetch(*arrays, **kw):
+        fed = engine._drained_at is None
         out = real_fetch(*arrays, **kw)
-        log.append(("fetch", clock.t, kw.get("newest", False)))
+        # whether this fetch drained the chip is the engine's to say (a
+        # group's is the newest program's where nothing follows its refill)
+        log.append(("fetch", clock.t, fed and engine._drained_at is not None))
         return out
+
+    real_mark = engine.mark_starved
+
+    def mark_starved(part):
+        fed = engine._drained_at is None
+        real_mark(part)
+        if fed and engine._drained_at is not None:
+            # the step in flight was found ended here: as good as a fetch
+            log.append(("fetch", clock.t, True))
+        log.append(("mark", clock.t, part is None))
 
     monkeypatch.setattr(jax, "device_get", device_get)
     monkeypatch.setattr(engine, "fetch", fetch)
+    monkeypatch.setattr(engine, "mark_starved", mark_starved)
     for name in PROGRAMS:
         program = getattr(engine, name)
         if program is None:
@@ -113,14 +160,22 @@ def record(monkeypatch, engine, clock, device_units=0.0):
 
 
 def starved_ms_of(log) -> float:
-    """The definition, from the log alone: from the return of a fetch of
-    the newest program's output to the entry of the next dispatch."""
-    total, drained = 0.0, None
-    for kind, t, newest in log:
-        if kind == "fetch" and newest and drained is None:
+    """The definition, from the log alone: from the return of a fetch
+    that drained the chip (of the newest program's output) to the entry
+    of the next dispatch, less the time the loop was nobody's (marked
+    ``None``: the engine held no rows to be starved of)."""
+    total, drained, nobodys = 0.0, None, False
+    for kind, t, flag in log:
+        if kind == "fetch" and flag and drained is None and not nobodys:
             drained = t
-        elif kind == "dispatch" and drained is not None:
-            total, drained = total + (t - drained), None
+        elif kind == "mark" and flag and drained is not None:
+            total, drained, nobodys = total + (t - drained), None, True
+        elif kind == "mark" and not flag and nobodys:
+            drained, nobodys = t, False
+        elif kind == "dispatch":
+            if drained is not None:
+                total += t - drained
+            drained, nobodys = None, False
     return total * 1000.0
 
 
@@ -163,14 +218,16 @@ def ledger_delta(stats, before):
 def test_starved_is_the_distance_from_a_draining_fetch_to_the_next_dispatch(
     server, bare_engine, clock, monkeypatch, interval
 ):
-    """No tap: the ``done`` flags fetched are the newest program's, so
-    that fetch drains and the next dispatch feeds. A step that fetches
-    nothing lets the host run ahead (every other step at an interval of
-    2, every step at 64) and adds nothing; a fetch of an older
-    program's output adds nothing and changes no state."""
+    """No tap, every step read out before the next: the ``done`` flags
+    fetched are the newest program's, so that fetch drains and the next
+    dispatch feeds. A step that fetches nothing lets the host run ahead
+    (every other step at an interval of 2, every step at 64) and adds
+    nothing; a fetch of an older program's output adds nothing and
+    changes no state."""
     engine = bare_engine
     monkeypatch.setattr(engine, "done_poll_interval", interval)
     engine.start_phase(server.params, jax.random.PRNGKey(interval))
+    drained(monkeypatch, engine)
     log = record(monkeypatch, engine, clock, device_units=7)
     older = jnp.arange(3)
     # four pools' worth of rows: every pump has rows to step
@@ -195,22 +252,62 @@ def test_starved_is_the_distance_from_a_draining_fetch_to_the_next_dispatch(
         assert stats.starved_ms == 0.0 and stats.host_blocked_ms > 0.0
 
 
+@pytest.mark.parametrize("interval", [1, 2, 64])
+def test_a_step_in_flight_drains_nothing_and_the_tail_read_out_does(
+    server, bare_engine, clock, monkeypatch, interval
+):
+    """The loop as it runs: step n is dispatched before step n-1's flags
+    are fetched, so those are never the newest program's and the ledger
+    stands at 0 whatever the host does in between. The tail read out
+    with nothing dispatched since is the newest, and the time from there
+    to the next dispatch is starved time."""
+    engine = bare_engine
+    monkeypatch.setattr(engine, "done_poll_interval", interval)
+    engine.start_phase(server.params, jax.random.PRNGKey(interval))
+    log = record(monkeypatch, engine, clock, device_units=7)
+    ids = np.asarray(prompts_for(server, 32, seed=interval), np.int32)
+    engine.submit(ids, np.ones_like(ids))
+    for _ in range(10):
+        engine.pump()
+        clock.advance(4)
+    stats = engine.stats
+    assert (stats.decode_steps, stats.steps_ahead) == (10, 9)
+    assert stats.done_polls == 9 // interval
+    assert not any(newest for kind, _, newest in log if kind == "fetch")
+    assert stats.starved_ms == starved_ms_of(log) == 0.0
+    assert stats.host_blocked_ms > 0.0 or interval == 64
+    engine._read_held()  # the tenth step, with nothing behind it
+    clock.advance(5)
+    engine.pump()
+    assert (stats.decode_steps, stats.steps_ahead) == (11, 9)
+    assert stats.done_polls == 10 // interval
+    # at 64 the read fetched nothing: nobody saw the chip drain
+    want = 0.0 if interval == 64 else 5 * U * 1000.0
+    assert stats.starved_ms == starved_ms_of(log) == want
+
+
 # ------------------------------- the parts -------------------------------- #
 
 
 class Held:
     """Hold the host ``units[part]`` in each part's own code: the token
     sink, ``_land_group``, ``_schedule``. Counts the sink's calls and
-    the landings that came after a decode step of their own iteration
-    (``entered``: the step count the iteration began with)."""
+    the landings entered with the chip drained, of which those that
+    came after a decode step of their own iteration (``entered``: the
+    step count the iteration began with)."""
 
     def __init__(self, monkeypatch, server, clock, units):
-        self.sinks = self.lands_after_a_step = self.entered = 0
-        stats = server.engine.stats
+        self.sinks = self.lands_drained = self.lands_after_a_step = 0
+        self.entered = 0
+        engine = server.engine
+        stats = engine.stats
 
         def slow(part, fn):
             def held(*args):
                 self.sinks += part == "tap"
+                self.lands_drained += (
+                    part == "land" and engine._drained_at is not None
+                )
                 self.lands_after_a_step += (
                     part == "land" and stats.decode_steps > self.entered
                 )
@@ -235,6 +332,7 @@ def test_each_part_lands_under_its_name(server, clock, monkeypatch, part):
     each to the unit, and nothing under any other name."""
     stats = server.engine.stats
     before = dict(stats.starved_by_ms)
+    drained(monkeypatch, server.engine)
     log = record(monkeypatch, server.engine, clock)
     held = Held(monkeypatch, server, clock, {part: 3})
     between = 3 if part == "caller" else 0
@@ -245,6 +343,7 @@ def test_each_part_lands_under_its_name(server, clock, monkeypatch, part):
         assert server.step() is False
         clock.advance(1000)
         stepped += run_streamed(server, clock, seed=1, between=between)
+    close(server.engine, clock, log)
     got = ledger_delta(stats, before)
     times = {
         # the sink runs straight after the draining fetch, every time
@@ -252,9 +351,11 @@ def test_each_part_lands_under_its_name(server, clock, monkeypatch, part):
         # the scheduler runs with the chip drained where the iteration
         # before ran a step
         "admit": sum(stepped[:-1]),
-        # a landing in an iteration whose step drained the chip (the
-        # last group's comes after its refill and no step: fed)
-        "land": held.lands_after_a_step,
+        # a landing in an iteration whose step drained the chip, or
+        # behind the landing of a group harvested with nothing dispatched
+        # after its refill (the pool ran empty: that fetch drains too,
+        # inside the first landing of the tail)
+        "land": held.lands_drained,
         "caller": sum(stepped),
     }[part]
     assert times > 0
@@ -269,11 +370,13 @@ def test_parts_sum_to_the_total_to_the_last_bit(server, clock, monkeypatch):
     stats = server.engine.stats
     before, total0 = dict(stats.starved_by_ms), stats.starved_ms
     seen = dict(server._starved_seen)
+    drained(monkeypatch, server.engine)
     log = record(monkeypatch, server.engine, clock, device_units=7)
     Held(monkeypatch, server, clock, {"tap": 3, "admit": 2, "land": 5})
     with telemetry.scoped_metrics() as reg:
         monkeypatch.setattr(server, "_registry", reg)
         run_streamed(server, clock, between=11)
+    close(server.engine, clock, log)
     got = ledger_delta(stats, before)
     assert all(got[p] > 0.0 for p in ("tap", "admit", "land", "caller"))
     assert sum(got.values()) == starved_ms_of(log) == stats.starved_ms - total0
@@ -352,6 +455,7 @@ def test_tracer_off_the_ledger_stands_and_the_new_spans_cost_nothing(
 ):
     stats = server.engine.stats
     before, seen = dict(stats.starved_by_ms), dict(server._starved_seen)
+    drained(monkeypatch, server.engine)
     log = record(monkeypatch, server.engine, clock, device_units=7)
     Held(monkeypatch, server, clock, {"tap": 3, "admit": 2, "land": 5})
     opened = []
@@ -363,6 +467,7 @@ def test_tracer_off_the_ledger_stands_and_the_new_spans_cost_nothing(
         assert telemetry.span("engine/route") is telemetry.NULL_SPAN
         run_streamed(server, clock, between=11)
     assert off.spans() == [] and opened == []
+    close(server.engine, clock, log)
     got = ledger_delta(stats, before)
     assert sum(got.values()) == starved_ms_of(log) > 0.0
     assert all(got[p] > 0.0 for p in ("tap", "admit", "land", "caller"))
@@ -379,11 +484,12 @@ def test_tracer_off_the_ledger_stands_and_the_new_spans_cost_nothing(
 def test_drive_keeps_the_ledger_and_observes_nothing(
     server, bare_engine, clock, monkeypatch
 ):
-    """PPO's loop: the same ledger in ``EngineStats`` (no server marks
-    its parts, so what follows a step's own bookkeeping is ``other``),
-    no ``serve/*`` histogram."""
+    """PPO's loop, read out step by step: the same ledger in
+    ``EngineStats`` (no server marks its parts, so what follows a step's
+    own bookkeeping is ``other``), no ``serve/*`` histogram."""
     engine = bare_engine
     engine.start_phase(server.params, jax.random.PRNGKey(5))
+    drained(monkeypatch, engine)
     log = record(monkeypatch, engine, clock, device_units=7)
     admit = engine._admit
     monkeypatch.setattr(

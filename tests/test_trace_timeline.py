@@ -69,3 +69,50 @@ def test_launch_and_tail_show_the_skew_and_their_sum_does_not_move_with_it(tool)
     assert early["launch_us"] == [-0.3] * 4 and early["tail_us"] == [0.45] * 4
     assert early["launch_plus_tail_us"] == true["launch_plus_tail_us"] == [pytest.approx(0.15)] * 4
     assert tool.launch_and_tail(steps, []) == {}
+
+
+def test_a_step_in_flight_is_paired_with_the_fetch_of_its_own_outputs(tool):
+    """The loop one step behind (ISSUE 44): step n's dispatch is entered
+    100 ns after step n-2 ended (its fetch returned 50 ns after), so
+    while n-1 runs, and its outputs are fetched an iteration later,
+    behind the dispatch of n+1, returning 50 ns after step n ends. Steps
+    follow one another 20 ns apart, but for one pair with a 300 ns
+    admission forward between them, during which the dispatch of the step
+    after next is entered. Each step is paired with its own dispatch and
+    its own fetch, with the device's clock 400 ns early too."""
+    steps, spans = [], []
+    at = 100
+    for n in range(6):
+        steps.append((at, at + 980, "jit_decode_step(1)"))
+        at += 1000 + (300 if n == 2 else 0)
+    forward = [(steps[2][1] + 10, steps[2][1] + 310, "jit_prefill_chunk(2)")]
+    spans.append((0, 90, "engine/dispatch"))  # step 0: from an empty pipeline
+    for n in range(1, 6):
+        d = steps[n - 2][1] + 100 if n > 1 else steps[0][0] + 200
+        spans.append((d, d + 100, "engine/dispatch"))  # step n, behind n-1
+        spans.append((d + 110, steps[n - 1][1] + 50, "engine/fetch"))  # n-1's tokens
+        spans.append((steps[n - 1][1] + 60, steps[n - 1][1] + 70, "engine/fetch"))  # n-1's flags
+    spans.append((steps[5][0] + 200, steps[5][1] + 50, "engine/fetch"))  # the tail read out
+    got = tool.launch_and_tail(steps, spans, forward)
+    assert got["steps"] == 6 and got["ahead_share"] == 1.0
+    assert got["tail_us"] == [0.05] * 4 and got["clock_shift_us"] == 0.05
+    # entered 80 ns into the step before: it waited out the other 900,
+    # the gap, and twice the forward as well (the step behind it, and the
+    # next, whose dispatch was entered while the forward ran)
+    assert got["launch_us"] == [0.1, 0.92, 1.22, 1.22]
+    assert got["device_gap_us"] == [0.02] * 4  # the forward's 300 ns are not idle
+    early = tool.launch_and_tail(
+        [(s - 400, e - 400, n) for s, e, n in steps], spans,
+        [(s - 400, e - 400, n) for s, e, n in forward],
+    )
+    assert early["steps"] == 6 and early["ahead_share"] == 1.0
+    assert early["tail_us"] == [0.45] * 4 and early["clock_shift_us"] == 0.45
+    assert early["launch_plus_tail_us"] == [pytest.approx(v) for v in got["launch_plus_tail_us"]]
+    assert early["device_gap_us"] == got["device_gap_us"]
+    # the order every step had before: read out behind its own dispatch
+    true = tool.launch_and_tail(
+        [(100, 1100, "jit_decode_step(1)"), (2100, 3100, "jit_decode_step(1)")],
+        [(0, 300, "engine/dispatch"), (310, 1150, "engine/fetch"), (1160, 1200, "engine/fetch"),
+         (2000, 2300, "engine/dispatch"), (2310, 3150, "engine/fetch")],
+    )
+    assert true["ahead_share"] == 0.0 and true["device_gap_us"] == [1.0] * 4

@@ -92,8 +92,9 @@ def slice_idle(dev, bench, spans):
         tr.union((s, e) for s, e, _ in ops), [(s, e) for s, e, _ in modules], window, tr.clip(spans, window)
     )
     steps = [m for m in modules if tr.module_name(m[2]) in STEP_MODULES]
+    others = [m for m in modules if tr.module_name(m[2]) not in STEP_MODULES]
     return {
-        "launch_and_tail": launch_and_tail(steps, spans),
+        "launch_and_tail": launch_and_tail(steps, spans, others),
         "window_s": (window[1] - window[0]) / 1e9,
         "idle_s": got["idle"] / 1e9,
         "in_module_s": got["in_module"] / 1e9,
@@ -103,39 +104,87 @@ def slice_idle(dev, bench, spans):
     }
 
 
-def launch_and_tail(modules, spans):
+def launch_and_tail(modules, spans, others=()):
     """How far the overlap split can be trusted, and what it cannot split.
 
     Each executed step (``modules``: the decode and verify steps' events)
-    is paired with the ``engine/dispatch`` span that launched it (the last that began before the module ended) and the
-    first ``engine/fetch`` after that span (the one that waited for it).
+    is paired with **the fetch of its own outputs**: a fetch waits for its
+    program, so it is the first ``engine/fetch`` that returns once the
+    step has ended (to within half a step, or 1 ms, of the two clocks),
+    whether it was entered behind the step's own dispatch or, since the
+    loop reads one step behind (ISSUE 44), an iteration later behind the
+    next step's. The least ``fetch return - module end`` over the pairs
+    is how far the device's clock stands from the host's at most
+    (``clock_shift_us``); on the clock shifted by it the step's
+    ``engine/dispatch`` span is the one after its predecessor's (a
+    dispatch launches one step, in order: with a step in flight and an
+    admission forward ahead of this one, the *next* step's dispatch is
+    entered before this one begins too), and the last entered before the
+    step began where the trace starts or has a hole.
     ``launch_us`` (module start less the span's entry) and ``tail_us``
-    (the fetch's return less the module's end) each compare the device's
-    clock with the host's: a module that starts *before* its own dispatch
-    was entered shows by how much the two are apart in this trace, and
-    idle pieces shorter than that are under the wrong span. Their sum
-    compares host with host and device with device, so it holds whatever
-    the skew: the time a step loses to the dispatch call, the launch and
-    the transfer's tail together, which the starved ledger leaves to the
-    device's clock. Each as ``[p10, p50, p90, max]`` in us (the largest shows
-    a stall the deciles hide); ``{}`` without the spans."""
+    (the fetch's return less the module's end) are unshifted, so each
+    compares the device's clock with the host's: a module that starts
+    *before* its own dispatch was entered shows by how much the two are
+    apart in this trace, and idle pieces shorter than that are under the
+    wrong span. Their sum compares host with host and device with device,
+    so it holds whatever the skew: from the dispatch's entry to the
+    fetch's return, less the step itself. While every step is read out
+    before the next is dispatched that is the time a step loses to the
+    dispatch call, the launch and the transfer's tail together, which the
+    starved ledger leaves to the device's clock; with a step in flight
+    (``ahead_share``: the steps whose dispatch was entered while the step
+    before them still ran) it is mostly the wait behind that step and
+    prices nothing of the host's. What is left to read then is the
+    device's own: ``device_gap_us``, from one step's end to the next's
+    start less the ``others`` (admission forwards, harvests) that ran
+    between them. Each as ``[p10, p50, p90, max]`` in us (the largest
+    shows a stall the deciles hide); ``{}`` without the spans."""
     dispatch = sorted((s, e) for s, e, n in spans if n == "engine/dispatch")
     fetch = sorted((s, e) for s, e, n in spans if n == "engine/fetch")
-    launch, tail = [], []
-    for s, e, _ in modules:
-        i = bisect.bisect_left(dispatch, (e,)) - 1
-        j = bisect.bisect_left(fetch, (dispatch[i][1],)) if i >= 0 else len(fetch)
-        if j < len(fetch) and fetch[j][1] > e - 5_000_000:  # a step's own fetch, not a later one's
-            launch.append((s - dispatch[i][0]) / 1e3)
-            tail.append((fetch[j][1] - e) / 1e3)
+    steps = sorted((s, e) for s, e, _ in modules)
+    if not (dispatch and fetch and steps):
+        return {}
+    tol = min(1_000_000, sorted(e - s for s, e in steps)[len(steps) // 2] // 2)
+    ends = [e for _, e in fetch]
+    own = []  # (step, the fetch that waited for it)
+    for s, e in steps:
+        j = bisect.bisect_left(ends, e - tol)
+        if j < len(fetch) and ends[j] < e + 5_000_000:  # a step's own fetch, not a later one's
+            own.append(((s, e), fetch[j]))
+    if not own:
+        return {}
+    shift = min(f[1] - m[1] for m, f in own)
+    entries = [s for s, _ in dispatch]
+    launch, tail, ahead = [], [], []
+    before = dict(zip(steps[1:], steps))  # a step -> the step ahead of it
+    i = None
+    for (s, e), f in own:
+        last = bisect.bisect_right(entries, s + shift) - 1
+        if last < 0:
+            continue
+        i = i + 1 if i is not None and i + 1 <= last <= i + 2 else last
+        launch.append((s - entries[i]) / 1e3)
+        tail.append((f[1] - e) / 1e3)
+        if (s, e) in before:
+            ahead.append(entries[i] < before[(s, e)][1] + shift)
     if not launch:
         return {}
+    busy = sorted((s, e) for s, e, _ in others)
+    gaps = []
+    for (_, e0), (s1, _) in zip(steps, steps[1:]):
+        between = sum(min(e, s1) - max(s, e0) for s, e in busy if s < s1 and e > e0)
+        gaps.append((s1 - e0 - between) / 1e3)
     deciles = lambda xs: [sorted(xs)[len(xs) * k // 10] for k in (1, 5, 9)] + [max(xs)]
-    return {
+    out = {
         "steps": len(launch), "launch_us": deciles(launch), "tail_us": deciles(tail),
         "launch_plus_tail_us": deciles([a + b for a, b in zip(launch, tail)]),
         "dispatch_call_us": deciles([(e - s) / 1e3 for s, e in dispatch]),
+        "clock_shift_us": shift / 1e3,
+        "ahead_share": sum(ahead) / len(ahead) if ahead else 0.0,
     }
+    if gaps:
+        out["device_gap_us"] = deciles(gaps)
+    return out
 
 
 def main(argv) -> int:

@@ -38,15 +38,35 @@ as ``ppo.engine_prefill`` / ``ppo.engine_decode_step`` /
   rollouts and mark the slots free (the admission queue refills them on
   the next poll).
 
-Host loop cost model: one small [B]-bool device->host fetch per
-``done_poll_interval`` decode steps (the admission decision needs the
-flags; they are *sticky* — a finished slot stays done until harvested —
-so polling only the latest step's flags every k-th step is exact). The
-fetch is started asynchronously right behind each dispatch; at k=1 the
-loop is bitwise-identical to polling every step (the parity contract,
-tests/test_async_rl.py), at k>1 the fetch round-trip amortizes over k
-dispatches and slots idle at most k-1 extra steps before harvest (the
-group composition may then differ — per-row tokens never do).
+Host loop cost model: **the loop reads one step behind what it has
+dispatched.** ``_decode_once`` dispatches step n, starts the async
+copies of its outputs and holds them (:class:`HeldStep`); then it
+fetches, routes and polls the outputs of step n-1 that the call before
+held. While the host blocks in that fetch, routes the tokens, lands a
+group, returns to its caller, schedules, stages an admission and makes
+the next call into ``decode_step_jit``, step n is running, and n+1 is
+queued behind it before it ends: no fetch of the steady loop drains the
+chip. A held step carries the ``(slot, row)`` pairs that stood at its
+dispatch, so a slot harvested and re-admitted between the dispatch and
+the read routes nothing of its old occupant to the new row; a step's
+tokens and flags are read together, so a stream holds a row's last
+token before its flag can close it. A finished slot is therefore seen
+one step later than its flag was computed (the step rides it along as
+a non-live row, as it does any slot waiting for its harvest group), and
+harvest and the admission into it follow one iteration later. What is
+held is read without a new dispatch where there is nothing to step
+(:meth:`pump` with no seeded row, :meth:`drive` at its target): a lone
+request finishes with no further traffic. A drafted ``verify_step``
+round stays synchronous, since the next draft continues the tokens the
+host has seen: an engine that drafts reads what is held before it
+drafts. The flags cost one small [B]-bool device->host fetch per
+``done_poll_interval`` steps read (they are *sticky* — a finished slot
+stays done until harvested — so polling only every k-th step's flags is
+exact); at k>1 the round-trip amortizes over k dispatches and slots
+idle at most k-1 further steps before harvest. Per-row tokens, masks,
+log-probabilities and values never depend on any of this (the parity
+contract, tests/test_async_rl.py, tests/test_serving_step_ahead.py);
+the composition and order of harvest groups may.
 
 Asynchronous actor–learner support (``train.async_rl``,
 docs/async_pipeline.md): :meth:`push_weights` hands the engine a
@@ -111,6 +131,23 @@ from trlx_tpu.utils import sched_points
 STARVED_PARTS = ("tap", "admit", "land", "caller", "other")
 
 
+@dataclasses.dataclass
+class HeldStep:
+    """A dispatched decode step's outputs, held unread while the step
+    runs: the loop reads them after it has dispatched the next step, so
+    the chip always has one queued. Everything the read needs is what
+    stood at the dispatch, since a harvest and an admission may come
+    between the two."""
+
+    seq: int  # the loop's count of dispatches when this one was entered
+    rows: List[Tuple[int, int]]  # ``_seeded_rows()`` at the dispatch
+    done: jax.Array  # [B] bool
+    moe_stats: Dict[str, jax.Array]  # a routed family's gauges, else {}
+    taps: Optional[Tuple[jax.Array, jax.Array]]  # (token, live) if routed
+    log_end: int  # the cadence log's absolute end (``trace_requests``)
+    forwards: int  # admission forwards dispatched before it, all told
+
+
 @struct.dataclass
 class EngineState:
     """Device-resident state of the slot pool; every leaf's leading axis
@@ -148,14 +185,22 @@ class EngineStats:
     prefills: int = 0
     decode_steps: int = 0
     recycles: int = 0
+    # decode steps dispatched while an earlier step's outputs were still
+    # unread: the chip had work queued behind the step it was running.
+    # ``decode_steps`` less this is the restarts from an empty pipeline
+    # (a phase's first step, one after a tail was read out, every round
+    # of an engine that drafts)
+    steps_ahead: int = 0
     occupancy_sum: int = 0  # sum over steps of active slots
     num_slots: int = 0
     done_polls: int = 0  # [B]-bool device->host fetches actually paid
     weight_pushes: int = 0  # mid-generation behavior refreshes applied
     released: int = 0  # placeholder rows force-finished on admission
     # wall the host spent blocked in the step loop's device->host
-    # fetches (the engine/fetch spans): a step's wall less this is the
-    # host's own exposed cost
+    # fetches (the engine/fetch spans), and in a call that dispatched a
+    # step and came back only when the step before it had ended (a
+    # backend that keeps one program in flight: the CPU's multi-device
+    # client): a step's wall less this is the host's own exposed cost
     host_blocked_ms: float = 0.0
     # the starved ledger: wall from the return of a *draining* fetch (a
     # blocking fetch of an output of the newest dispatched program: that
@@ -205,6 +250,11 @@ class EngineStats:
     def starved_ms(self) -> float:
         """The ledger's total: its parts, summed in their one order."""
         return sum(self.starved_by_ms.values())
+
+    @property
+    def forwards(self) -> int:
+        """Admission forwards dispatched: a group whole, or a chunk."""
+        return self.prefills + self.prefill_chunks
 
     @property
     def prefill_flops_saved(self) -> float:
@@ -257,6 +307,7 @@ class EngineStats:
             "engine/completed": float(self.completed),
             "engine/prefills": float(self.prefills),
             "engine/decode_steps": float(self.decode_steps),
+            "engine/steps_ahead": float(self.steps_ahead),
             "engine/slot_recycles": float(self.recycles),
             "engine/slot_util": round(self.slot_util, 4),
             "engine/done_polls": float(self.done_polls),
@@ -557,6 +608,17 @@ class ContinuousBatchingEngine:
         self._pending_push: Optional[Tuple[Any, int]] = None
         self._push_lock = threading.Lock()
         self._steps_since_poll = 0
+        # the decode step that is running or queued and whose outputs
+        # nobody has read yet (None: the pipeline is empty), and the
+        # count of dispatches entered: a held step whose ``seq`` is still
+        # that count is the newest program, and its fetch drains the chip
+        self._held: Optional[HeldStep] = None
+        self._dispatches = 0
+        self._last_refill = -1  # that count at the newest harvest
+        #: admission forwards (whole or chunk) dispatched ahead of the
+        #: newest step read: where it grew, the step this iteration
+        #: waited for ran behind a forward (``serve/admit_pump_ms``)
+        self.forwards_waited = 0
         # the starved ledger's open episode (EngineStats.starved_by_ms
         # holds the closed ones): when the chip was seen drained (None:
         # it is fed, or the host runs ahead of it), the part of the loop
@@ -1537,6 +1599,11 @@ class ContinuousBatchingEngine:
         with sched_points.guard(self._push_lock, "engine.push_lock"):
             self._pending_push = None
         self._steps_since_poll = 0
+        # a step of the pool that is gone: its rows went with it
+        self._held = None
+        self._dispatches = 0
+        self._last_refill = -1
+        self.forwards_waited = 0
         self._drained_at = None
         self._starved_part = None
         self._episode = dict.fromkeys(STARVED_PARTS, 0.0)
@@ -2211,6 +2278,7 @@ class ContinuousBatchingEngine:
                 "collect/slot_recycle", force=True, harvested=C
             ):
                 self._fed()
+                self._last_refill = self._dispatches
                 self._state, outs = self.refill_jit(
                     self._state, jnp.asarray(slots, jnp.int32)
                 )
@@ -2286,6 +2354,8 @@ class ContinuousBatchingEngine:
                 yield group
                 yielded += len(group["rows"])
                 if yielded >= target:
+                    # the tail: what the last step left is read out
+                    self._read_held()
                     return
             # safe point for a staged weight push (async actor–learner):
             # harvest bookkeeping is settled and the queued admit group
@@ -2313,6 +2383,11 @@ class ContinuousBatchingEngine:
         never pay the wider program)."""
         drafted = False
         if self.spec_max_draft > 0:
+            # a draft continues the tokens the host has seen, and a
+            # drafted round needs the last acceptance before the next:
+            # an engine that drafts reads what is held first, and its
+            # ``verify_step`` rounds keep the synchronous order
+            self._read_held()
             draft, lens = self._take_drafts()
             drafted = bool(lens.any())
         if drafted:
@@ -2396,6 +2471,7 @@ class ContinuousBatchingEngine:
         tok_host, acc_host = self.fetch(
             toks, acc, what="tokens", newest=True
         )
+        self.forwards_waited = self.stats.forwards
         with telemetry.span("engine/route"):
             self._route_verified(lens, tok_host, acc_host)
         registry = telemetry.get_metrics()
@@ -2453,8 +2529,13 @@ class ContinuousBatchingEngine:
                     self.token_sink(emitted)
 
     def _decode_once(self) -> None:
-        """Dispatch one decode step for the whole pool and run the
-        amortized done-poll + streaming-tap bookkeeping."""
+        """Dispatch one decode step for the whole pool and hold its
+        outputs; then read the step the call before held (the module
+        docstring's cost model): the host's work on step n-1 runs under
+        step n."""
+        prev = self._held
+        behind = prev is not None and not prev.done.is_ready()
+        entered = telemetry.monotonic()
         with telemetry.span("engine/dispatch", program="decode_step"):
             self._fed()
             if self.stream_taps:
@@ -2466,12 +2547,32 @@ class ContinuousBatchingEngine:
                     self._params, self._state
                 )
                 token = live = None
+        if behind and prev.done.is_ready():
+            # queued behind a running step, the call came back with that
+            # step ended: it waited for the device (a backend that keeps
+            # one program in flight), which is no work of the host's
+            self.stats.host_blocked_ms += (
+                telemetry.monotonic() - entered
+            ) * 1000.0
         done = polled.pop("done")
-        try:
-            done.copy_to_host_async()
-        except (AttributeError, RuntimeError):
-            pass
+        # streaming tap: this step's live emissions go to the per-request
+        # queues when the step is read — time-to-first-token decouples
+        # from harvest-group completion (the per-step fetch is the
+        # streaming cost; non-streaming runs leave token_sink unset and
+        # the unfetched outputs are dropped on device). Spec decode reads
+        # the same tap to keep the drafter histories current through
+        # draftless fall-through steps.
+        routed = token is not None and (
+            self.token_sink is not None or self.spec_drafter is not None
+        )
+        taps = (token, live) if routed else None
+        for out in (done, *polled.values(), *(taps or ())):
+            try:
+                out.copy_to_host_async()
+            except (AttributeError, RuntimeError):
+                pass
         self.stats.decode_steps += 1
+        self.stats.steps_ahead += prev is not None
         self.stats.occupancy_sum += len(self._busy_rows)
         if self.trace_requests:
             # one (dispatch wall, admission epoch) pair per decode step:
@@ -2482,24 +2583,47 @@ class ContinuousBatchingEngine:
             self._step_log.append(
                 (telemetry.monotonic(), self.stats.prefills)
             )
-        need_tokens = (
-            self.token_sink is not None or self.spec_drafter is not None
+        self._held = HeldStep(
+            seq=self._dispatches,
+            rows=list(self._seeded_rows()),
+            done=done,
+            moe_stats=polled,
+            taps=taps,
+            log_end=self._step_base + len(self._step_log),
+            forwards=self.stats.forwards,
         )
-        if token is not None and need_tokens:
-            # streaming tap: route this step's live emissions to the
-            # per-request queues NOW — time-to-first-token decouples
-            # from harvest-group completion (the per-step fetch is the
-            # streaming cost; non-streaming runs leave token_sink unset
-            # and the unfetched outputs are dropped on device). Spec
-            # decode reads the same tap to keep the drafter histories
-            # current through draftless fall-through steps.
+        if prev is not None:
+            self._read_step(prev)
+
+    def _read_held(self) -> None:
+        """Read what is held without a new dispatch: the tail of a run
+        (nothing left to step), or a round that must see every token
+        before it goes on (an engine that drafts)."""
+        held, self._held = self._held, None
+        if held is not None:
+            self._read_step(held)
+            self.mark_starved("other")
+
+    def _read_step(self, held: HeldStep) -> None:
+        """Route a dispatched step's tokens and poll its flags, both
+        from the one step and against the rows that stood at its
+        dispatch, less those harvested since (all a harvested row was
+        owed it has: its flag was read with its last token)."""
+        # nothing dispatched since: this fetch drains the chip
+        newest = held.seq == self._dispatches
+        self.forwards_waited = held.forwards
+        rows = [
+            (slot, row)
+            for slot, row in held.rows
+            if self._busy_rows.get(slot) == row
+        ]
+        if held.taps is not None:
             tok_host, live_host = self.fetch(
-                token, live, what="tokens", newest=True
+                *held.taps, what="tokens", newest=newest
             )
             with telemetry.span("engine/route"):
-                seeded = self._seeded_rows()
                 if self.spec_drafter is not None:
-                    for slot, row in seeded:
+                    for slot, row in rows:
                         if live_host[slot]:
                             self.spec_drafter.observe_tokens(
                                 row, [int(tok_host[slot])]
@@ -2507,26 +2631,33 @@ class ContinuousBatchingEngine:
                 if self.token_sink is not None:
                     emitted = {
                         row: int(tok_host[slot])
-                        for slot, row in seeded
+                        for slot, row in rows
                         if live_host[slot]
                     }
                     if emitted:
                         self.token_sink(emitted)
-        self._poll_done(done, polled)
+        self._poll_done(
+            held.done, held.moe_stats, rows=rows, newest=newest,
+            log_end=held.log_end,
+        )
 
-    def _poll_done(self, done, moe_stats=None) -> None:
+    def _poll_done(
+        self, done, moe_stats=None, *, rows=None, newest=True, log_end=None
+    ) -> None:
         """Amortized done polling: the flags are sticky (a finished slot
-        stays done until harvested), so fetching only every k-th step's
-        flags is exact — k=1 reproduces the poll-every-step loop
-        bitwise, and the async copy started at dispatch has k dispatches
-        to land the transfer before the host reads it."""
+        stays done until harvested), so fetching only every k-th read
+        step's flags is exact, and the async copy started at dispatch
+        has had a step to land before the host reads it. ``rows``: the
+        ``(slot, row)`` pairs the flags speak of (a held step's; default
+        every busy slot, for a step read where it was dispatched);
+        ``newest``: whether these are the newest program's flags;
+        ``log_end``: the cadence log's end at the step's dispatch."""
         self._steps_since_poll += 1
         if self._steps_since_poll < self.done_poll_interval:
             return
         self._steps_since_poll = 0
-        # this step's flags: the newest program's, whatever the interval
         done_host, moe_host = self.fetch(
-            done, moe_stats or {}, what="done", newest=True
+            done, moe_stats or {}, what="done", newest=newest
         )
         self.stats.done_polls += 1
         if moe_host:
@@ -2542,20 +2673,22 @@ class ContinuousBatchingEngine:
         if self._cache_gb["state"]:  # again: the registry may have been cleared
             self._publish_cache_gauges(self._cache_gb)
         t_done = telemetry.monotonic() if self.trace_requests else 0.0
-        for slot, row in list(self._busy_rows.items()):
+        if rows is None:
+            rows = list(self._busy_rows.items())
+        if log_end is None:
+            log_end = self._step_base + len(self._step_log)
+        for slot, row in rows:
             if done_host[slot] and slot not in self._done_slots:
                 self._done_slots.append(slot)
                 if self.trace_requests:
                     # host-visible decode end: the harvest-wait stage
-                    # (done → refill) starts here. With amortized
-                    # polling (k>1) this lags the device by up to k-1
-                    # steps — it is the host-observable bound.
+                    # (done → refill) starts here, a step after the
+                    # flag was computed (k-1 more under amortized
+                    # polling) — it is the host-observable bound.
                     marks = self._req_times.get(row)
                     if marks is not None:
                         marks["done"] = t_done
-                        marks["done_step"] = (
-                            self._step_base + len(self._step_log)
-                        )
+                        marks["done_step"] = log_end
 
     def fetch(
         self, *arrays, what: str = "group", newest: bool = False
@@ -2565,17 +2698,38 @@ class ContinuousBatchingEngine:
         ``done``, or a harvested ``group``) is the host waiting on the
         device, and its wall accumulates in ``stats.host_blocked_ms``
         (forced: the counter stands with the tracer off). ``newest``
-        says the arrays are outputs of the newest dispatched program:
-        when the fetch returns the chip has drained, and the starved
-        ledger's clock starts (a harvested group's arrays are an older
-        program's, and drain nothing)."""
+        says the arrays are outputs of the newest dispatched program,
+        with nothing queued behind it: when the fetch returns the chip
+        has drained, and the starved ledger's clock starts. A held
+        step read behind the next step's dispatch is not, nor is a
+        harvested group with a step dispatched behind its ``refill``
+        (an older program's arrays): there the clock starts only if the
+        step queued behind them is found ended too (:meth:`_ran_out`:
+        the host has fallen a whole step behind the chip), so the steady
+        loop reads 0. The tail read out with nothing dispatched since is
+        the newest, so is a ``verify_step``'s, and so is a group
+        harvested with nothing dispatched behind it (the pool ran
+        empty: the engine knows, whatever the caller says)."""
         with telemetry.span("engine/fetch", force=True, what=what) as sp:
             host = jax.device_get(arrays)
         self.stats.host_blocked_ms += sp.duration_ms
-        if newest and self._drained_at is None:
+        if what == "group":
+            newest = self._last_refill == self._dispatches
+        if self._drained_at is None and (newest or self._ran_out()):
             self._drained_at = sp.end
-            self._starved_part = "tap"
+            if what != "group":  # a landing keeps its own part
+                self._starved_part = "tap"
         return host
+
+    def _ran_out(self) -> bool:
+        """Whether the chip has nothing left to run: the held step is
+        the newest dispatch and has ended (one non-blocking query)."""
+        held = self._held
+        return (
+            held is not None
+            and held.seq == self._dispatches
+            and held.done.is_ready()
+        )
 
     # ------------------------- the starved ledger ---------------------- #
 
@@ -2583,17 +2737,23 @@ class ContinuousBatchingEngine:
         """The host loop enters ``part`` (one of ``STARVED_PARTS``; None:
         nobody's, where the engine holds no rows to be starved of). While
         the chip is drained, the time since the last mark goes to the
-        part that was running; while it is fed this is one assignment."""
+        part that was running; while it is fed this is one assignment
+        and one look at the step in flight: found ended with nothing
+        behind it, the chip is drained from here (when it ran out in the
+        part that just ended nobody saw: a lower bound)."""
         if self._drained_at is not None:
             now = telemetry.monotonic()
             if self._starved_part is not None:
                 self._episode[self._starved_part] += now - self._drained_at
             self._drained_at = now
+        elif self._ran_out():
+            self._drained_at = telemetry.monotonic()
         self._starved_part = part
 
     def _fed(self) -> None:
         """Called on entry to every dispatch of the loop: a drained chip
         is fed again, and the episode closes into ``stats``."""
+        self._dispatches += 1
         if self._drained_at is None:
             return
         self.mark_starved(None)
@@ -2651,7 +2811,10 @@ class ContinuousBatchingEngine:
                 self._apply_pending_push()
             self._admit()
         # a decode step is for the rows that decode: slots an unfinished
-        # admission has reserved hold nothing to advance yet
+        # admission has reserved hold nothing to advance yet. With none
+        # to step, what the last step left is read out (the tail)
         if self._seeded_rows():
             self._step_once()
+        else:
+            self._read_held()
         return groups

@@ -631,24 +631,39 @@ class InferenceServer:
         Measured from inside (docs/observability.md "The serving loop"):
         the span ``serve/step`` with ``serve/schedule``, ``engine/fetch``
         and ``serve/land`` beneath it, and for an iteration that did
-        device work the histograms ``serve/pump_ms`` (a decode step and
-        no admission prefill) or ``serve/admit_pump_ms`` (a prefill was
-        dispatched: the stall every running stream feels),
+        device work the histograms ``serve/pump_ms`` (it waited for a
+        decode step and no admission prefill) or ``serve/admit_pump_ms``
+        (the step it read had run behind an admission forward: the stall
+        every running stream feels; since the loop reads one step behind,
+        that is the iteration *after* the one that dispatched the forward),
         ``serve/step_host_ms`` (the wall less the time blocked in
-        ``engine/fetch``), ``serve/slots_done_waiting``, and
+        ``engine/fetch``), ``serve/slots_done_waiting``,
         ``serve/starved_ms`` with its ``[by=...]`` twins: how long the
         chip sat drained before this iteration fed it, by the part of
         the loop that held the host (the engine's starved ledger; this
-        method marks ``admit``, ``land`` and ``caller``). While a
-        client streams, the engine fetches every step's tokens, so the
-        walls are the device's; with no stream open only the ``done``
-        flags are fetched, and they read one step behind at most."""
+        method marks ``admit``, ``land`` and ``caller``), and, where the
+        iteration ran a decode step, ``serve/step_ahead``: 1.0 if the
+        step was dispatched while the one before it was still unread,
+        else 0.0 (a restart from an empty pipeline).
+
+        The engine reads one step behind what it has dispatched: this
+        iteration dispatches step n and then fetches, routes and polls
+        step n-1, so the tokens and flags a caller sees are those of
+        the step before the one now running, the fetch waits for n-1
+        with n already queued, and no fetch of the steady loop drains
+        the chip (the ledger reads 0 there; what it still shows is a
+        real drain: a tail read out, a pool refilling from empty). A
+        freed slot is seen, harvested and re-admitted one iteration
+        later than its flag was computed. While a client streams, the
+        engine fetches every step's tokens, so the walls are the
+        device's; with no stream open only the ``done`` flags are."""
         from trlx_tpu import telemetry
 
         engine = self.engine
         stats = engine.stats
-        prefills = stats.prefills + stats.prefill_chunks
-        steps = stats.decode_steps
+        forwards = stats.forwards
+        steps, ahead = stats.decode_steps, stats.steps_ahead
+        waited = engine.forwards_waited
         blocked_ms = stats.host_blocked_ms
         engine.mark_starved("admit")
         with telemetry.span("serve/step", force=True) as sp:
@@ -671,12 +686,12 @@ class InferenceServer:
                 admitted=admitted,
                 harvested=sum(len(g["rows"]) for g in groups),
             )
-        prefilled = stats.prefills + stats.prefill_chunks > prefills
-        if prefilled or stats.decode_steps > steps:
+        if stats.forwards > forwards or stats.decode_steps > steps:
             wall_ms = sp.duration_ms
             registry = self._registry
+            behind_forward = engine.forwards_waited > waited
             registry.histogram(
-                "serve/admit_pump_ms" if prefilled else "serve/pump_ms"
+                "serve/admit_pump_ms" if behind_forward else "serve/pump_ms"
             ).observe(wall_ms)
             registry.histogram("serve/step_host_ms").observe(
                 max(0.0, wall_ms - (stats.host_blocked_ms - blocked_ms))
@@ -691,6 +706,10 @@ class InferenceServer:
             )
             for part, name in STARVED_HISTOGRAMS.items():
                 registry.histogram(name).observe(by[part] - seen[part])
+            if stats.decode_steps > steps:
+                registry.histogram("serve/step_ahead").observe(
+                    float(stats.steps_ahead > ahead)
+                )
         # the caller's turn: starved time only while rows wait on it
         waiting = engine.pending > 0
         engine.mark_starved("caller" if waiting else None)
