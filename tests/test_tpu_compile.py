@@ -94,3 +94,36 @@ def test_the_updates_attention_compiles_to_the_kernels_and_no_scores(
     )
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 2
     assert not re.search(r"\[%d,%d,%d,%d\]" % (B, H, T, T), text)
+
+
+def test_cca_decode_layer_steps_its_tail_and_holds_no_copy_of_its_pool(one_chip):
+    """One CCA attention sublayer of the ``serve-zaya1-8b-reason`` decode step
+    at published widths (32 slots x 1024 positions x 2 KV heads of 128, bf16
+    pool, float32 tail; 8 query heads a latent of 1024): the mix from the
+    tail, one row a slot written in place, the grouped read of the pool as
+    stored. The chip's compiler takes it, and nothing pool-sized is copied
+    or converted beside the 2 KB a row of tail."""
+    from trlx_tpu.models.zaya import ZayaAttention, ZayaConfig, init_zaya_cache
+
+    cfg = ZayaConfig(num_hidden_layers=1, dtype="bfloat16", param_dtype="bfloat16")
+    B, C, n_blocks = 32, 1024, 64
+    module = ZayaAttention(cfg)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+    layer = jax.eval_shape(lambda: dict(init_zaya_cache(cfg, B, C)[0], block_tables=jnp.zeros((B, n_blocks), jnp.int32)))
+    args = (sds((B, 1, cfg.hidden_size), jnp.bfloat16), sds((B, 1, 1, C), jnp.float32), sds((B, 1), jnp.int32),
+            sds((B, 1), jnp.float32), sds((B,), jnp.bool_))
+    index = sds((B,), jnp.int32)
+    params = jax.eval_shape(lambda *a: module.init(jax.random.PRNGKey(0), *a, False), *args, layer, index)
+
+    def step(params, x, bias, pos, mask, fresh, layer, index):
+        return module.apply(params, x, bias, pos, mask, fresh, layer, index, False)
+
+    compiled = jax.jit(step, donate_argnums=(6,)).lower(on_chip(params), *args, on_chip(layer), index).compile()
+    pool_bytes = B * C * 2 * 128 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
+    entry = compiled.as_text().split("\nENTRY ", 1)[1]
+    assert not re.search(r"= f32\[(%d,%d|%d),2,128\]" % (B, C, B * C), entry)

@@ -673,17 +673,20 @@ class ContinuousBatchingEngine:
         return state
 
     def _measure_cache(self) -> Dict[str, float]:
-        """What the pool will hold, by kind of layer (GB; gauges
-        ``cache/state_gb`` and ``cache/kv_gb``), from shapes alone; and
-        the refusals a state layer brings: a state cannot be rolled back
-        to a rejected draft's column or shared by prefix, and the pp
-        runner carries KV layers only."""
+        """What the pool will hold (GB; gauges ``cache/state_gb``,
+        ``cache/kv_gb`` and ``cache/tail_gb``), from shapes alone: a state
+        layer's rows, the pools, and the rows a layer of keys keeps a slot
+        beside them (``cache_kind(...).tail``); and the refusals by-slot
+        rows bring: they cannot be rolled back to a rejected draft's column
+        or shared by prefix, and the pp runner carries KV layers only."""
         linear = jax.eval_shape(lambda: self._init_cache_fn(self.num_slots, self.capacity))
-        gb = {"state": 0.0, "kv": 0.0}
+        gb = {"state": 0.0, "kv": 0.0, "tail": 0.0}
         for layer in linear:
-            key = "state" if cache_kind(layer).layout == STATE else "kv"
-            gb[key] += sum(v.size * v.dtype.itemsize for v in layer.values()) / 1e9
-        if gb["state"]:
+            kind = cache_kind(layer)
+            for k, v in layer.items():
+                key = "state" if kind.layout == STATE else "tail" if k in kind.tail else "kv"
+                gb[key] += v.size * v.dtype.itemsize / 1e9
+        if gb["state"] or gb["tail"]:
             pp = dict(self.mesh.shape).get("pp", 1) if self.mesh is not None else 1
             for what, on in (
                 ("prefix_pool_blocks > 0 (a shared prefix of states)", self.prefix_pool_blocks > 0),
@@ -693,7 +696,7 @@ class ContinuousBatchingEngine:
                 if on:
                     raise ValueError(
                         f"{what} is not built for a model with state layers "
-                        "(ops/kv_cache.py, the state kind)"
+                        "or a tail beside its keys (ops/kv_cache.py: rows kept a slot)"
                     )
         self._publish_cache_gauges(gb)
         return gb
@@ -701,8 +704,8 @@ class ContinuousBatchingEngine:
     @staticmethod
     def _publish_cache_gauges(gb: Dict[str, float]) -> None:
         registry = telemetry.get_metrics()
-        registry.gauge("cache/state_gb").set(gb["state"])
-        registry.gauge("cache/kv_gb").set(gb["kv"])
+        for key, value in gb.items():
+            registry.gauge(f"cache/{key}_gb").set(value)
 
     def _make_state(self) -> EngineState:
         B, Q, R, V = self.num_slots, self.Q, self.R, self.vocab_size
@@ -769,14 +772,14 @@ class ContinuousBatchingEngine:
         rep = replicated(self.mesh)
 
         def layer_sharding(layer: Dict[str, Any]) -> Dict[str, Any]:
-            if cache_kind(layer).layout == STATE:
-                # no capacity axis for sp to shard: the slot axis, as the rest
-                return {k: batch_sh for k in layer}
+            # rows kept a slot have no capacity axis for sp to shard: the
+            # slot axis, as the rest
+            by_slot = cache_kind(layer).tail
             return {
                 k: (
                     rep
                     if k in SHARED_POOL_KEYS
-                    else (cache_sh if v.ndim == 4 else batch_sh)
+                    else (cache_sh if v.ndim == 4 and k not in by_slot else batch_sh)
                 )
                 for k, v in layer.items()
             }
@@ -805,19 +808,19 @@ class ContinuousBatchingEngine:
             if self._cache_sharding is None:
                 return cache
             sh = self._cache_sharding
-            return tuple(
-                layer
-                if cache_kind(layer).layout == STATE
-                else {
+
+            def pin_layer(layer):
+                by_slot = cache_kind(layer).tail
+                return {
                     k: (
                         jax.lax.with_sharding_constraint(v, sh)
-                        if v.ndim == 4
+                        if v.ndim == 4 and k not in by_slot
                         else v
                     )
                     for k, v in layer.items()
                 }
-                for layer in cache
-            )
+
+            return tuple(pin_layer(layer) for layer in cache)
 
         sharing = self.prefix_pool_blocks > 0
 
@@ -843,17 +846,17 @@ class ContinuousBatchingEngine:
             )
 
             def group_layer(layer):
-                if cache_kind(layer).layout == STATE:
-                    # the slots' rows as they stand: the model starts a row
-                    # from zeros where no valid column precedes the call
-                    # (ops/ssm.py::call_columns), so a recycled slot never
-                    # reads its predecessor's state and a later chunk
-                    # carries on from the one before
-                    return {
-                        k: jnp.take(v, slot_ids, axis=0)
-                        for k, v in layer.items()
-                    }
-                out = dict(layer, block_tables=new_tables, slot_ids=slot_ids)
+                kind = cache_kind(layer)
+                # what is kept a slot (a state layer's rows, the tail beside
+                # a layer's keys): the slots' rows as they stand. The model
+                # starts a row from zeros where no valid column precedes
+                # the call (ops/ssm.py::call_columns), so a recycled slot
+                # never reads its predecessor's and a later chunk carries
+                # on from the one before
+                rows = {k: jnp.take(layer[k], slot_ids, axis=0) for k in kind.tail}
+                if kind.layout == STATE:
+                    return rows
+                out = dict(layer, **rows, block_tables=new_tables, slot_ids=slot_ids)
                 if sharing:
                     # the admitted rows' share/publish assignments; the
                     # recycled slots' stale ones are replaced on landing
@@ -867,12 +870,12 @@ class ContinuousBatchingEngine:
             """The state's cache after an admission forward: the pools as
             the forward left them, and the group's rows of everything
             kept a slot (block tables, share/publish maps, a state
-            layer's rows) set at ``slot_ids`` (a dummy's drop)."""
+            layer's rows, a tail) set at ``slot_ids`` (a dummy's drop)."""
             def land_layer(full, out):
-                by_slot = cache_kind(full).layout == STATE
+                by_slot = cache_kind(full).tail
 
                 def one(k):
-                    if by_slot or k == "block_tables" or k in SHARE_TABLE_KEYS:
+                    if k in by_slot or k == "block_tables" or k in SHARE_TABLE_KEYS:
                         return (
                             full[k]
                             .at[slot_ids]
@@ -2670,7 +2673,7 @@ class ContinuousBatchingEngine:
         telemetry.get_metrics().gauge("engine/slot_util").set(
             self.stats.slot_util
         )
-        if self._cache_gb["state"]:  # again: the registry may have been cleared
+        if self._cache_gb["state"] or self._cache_gb["tail"]:  # again: the registry may have been cleared
             self._publish_cache_gauges(self._cache_gb)
         t_done = telemetry.monotonic() if self.trace_requests else 0.0
         if rows is None:

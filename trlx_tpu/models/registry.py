@@ -171,3 +171,19 @@ def _register_builtins() -> None:
             no_granite_checkpoint, supports_ep=True,
         )
     )
+    from trlx_tpu.models.zaya import (
+        ZAYA_PARTITION_RULES,
+        ZayaConfig,
+        ZayaModel,
+        init_zaya_cache,
+        no_zaya_checkpoint,
+    )
+
+    # supports_ep: it sows router losses like the other expert families; an
+    # ep mesh itself is refused by name where the experts are built
+    register_model_family(
+        ModelFamily(
+            "zaya", ZayaConfig, ZayaModel, ZAYA_PARTITION_RULES, init_zaya_cache,
+            no_zaya_checkpoint, supports_ep=True,
+        )
+    )

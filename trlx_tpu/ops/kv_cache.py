@@ -38,6 +38,15 @@ A fourth kind holds no keys at all:
   (:func:`hybrid_cache`) hands the engine a tuple whose layers differ, and
   everything that walks a cache asks :func:`cache_kind` layer by layer.
 
+And a layer of keys may keep a **tail** beside them (:func:`tail_buffers`):
+rows kept a slot under keys that start with ``tail_`` (a convolution's last
+inputs, a shifted value's source; ``ops/cca.py``), in ``state_dtype``, with
+no capacity axis. ``cache_kind(...).tail`` names a layer's by-slot keys (a
+state layer's: all of them), which is the one answer the engine's walks
+use: those keys are taken and set back by slot, the rest are pools. The
+tail never reaches ``decode_attention``: the model hands it the layer
+without (:func:`split_tail`).
+
 The pools are sized by **KV heads**: a family with fewer KV heads than
 query heads (grouped-query attention) allocates and reads ``H_kv`` of them,
 and the reads in ``ops/attention.py`` map query head ``h`` to KV head
@@ -273,6 +282,15 @@ def decode_kv_layout(cache):
 VALID_STATE_DTYPES = ("float32",)
 
 
+def _state_dtype(state_dtype: str):
+    if state_dtype not in VALID_STATE_DTYPES:
+        raise ValueError(
+            f"state_dtype={state_dtype!r} is not supported (choose one of "
+            f"{VALID_STATE_DTYPES})"
+        )
+    return jnp.dtype(state_dtype)
+
+
 def state_buffers(
     batch_size: int,
     n_head: int,
@@ -286,12 +304,7 @@ def state_buffers(
     (``ops/ssm.py``): the state ``[B, H, P, N]`` and the convolution's last
     ``conv_width - 1`` inputs ``[B, K - 1, C]``, both in ``state_dtype``,
     zeros (what a sequence starts from)."""
-    if state_dtype not in VALID_STATE_DTYPES:
-        raise ValueError(
-            f"state_dtype={state_dtype!r} is not supported (choose one of "
-            f"{VALID_STATE_DTYPES})"
-        )
-    dt = jnp.dtype(state_dtype)
+    dt = _state_dtype(state_dtype)
     return {
         "ssm_state": jnp.zeros((batch_size, n_head, head_dim, d_state), dt),
         "conv_tail": jnp.zeros((batch_size, conv_width - 1, conv_channels), dt),
@@ -329,6 +342,32 @@ def hybrid_cache(
     )
 
 
+TAIL_PREFIX = "tail_"
+
+
+def tail_buffers(batch_size: int, rows: Dict[str, Tuple[int, ...]],
+                 state_dtype: str = "float32") -> Dict[str, jax.Array]:
+    """The by-slot rows a layer of keys keeps beside them: ``rows`` maps a
+    name to the shape one sequence holds (``{"z": (K - 1, C)}``); each
+    comes back under ``tail_<name>`` as ``[B, *shape]`` zeros in
+    ``state_dtype`` (what a sequence starts from)."""
+    dt = _state_dtype(state_dtype)
+    return {
+        TAIL_PREFIX + name: jnp.zeros((batch_size,) + tuple(shape), dt)
+        for name, shape in rows.items()
+    }
+
+
+def split_tail(cache_kv: Dict[str, jax.Array]):
+    """``(the layer without its tail, the tail)``: what ``decode_attention``
+    is handed, and what the model's own step reads and writes."""
+    tail = cache_kind(cache_kv).tail
+    return (
+        {k: v for k, v in cache_kv.items() if k not in tail},
+        {k: cache_kv[k] for k in tail},
+    )
+
+
 DENSE, FOLDED, PAGED, STATE = "dense", "folded", "paged", "state"
 
 
@@ -339,6 +378,9 @@ class CacheKind(NamedTuple):
     quantized: bool  # int8 values + bf16 scales
     shared: bool  # a paged cache with a shared-prefix overlay
     rows: bool = False  # a paged call over a group's rows of the whole pool
+    # the keys kept a slot, not a position: every key of a state layer, the
+    # ``tail_*`` keys of a layer of keys that keeps a tail, else none
+    tail: Tuple[str, ...] = ()
 
 
 def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
@@ -347,9 +389,10 @@ def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
     this" asks here. ``"slot_ids"`` beside ``"block_tables"`` marks an
     admission call: the pools are whole (``num_slots`` rows), the tables
     and the call's K/V are the group's (``A`` rows), and row ``i`` of the
-    call lives in pool row ``slot_ids[i]`` (:func:`paged_write_read`)."""
+    call lives in pool row ``slot_ids[i]`` (:func:`paged_write_read`).
+    ``tail`` names the keys that live by slot."""
     if "ssm_state" in cache_kv:
-        return CacheKind(STATE, False, False)
+        return CacheKind(STATE, False, False, tail=tuple(sorted(cache_kv)))
     if "block_tables" in cache_kv:
         layout = PAGED
     elif cache_kv["k"].ndim == 3:
@@ -361,6 +404,7 @@ def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
         "k_scale" in cache_kv,
         "shared_tables" in cache_kv,
         layout == PAGED and "slot_ids" in cache_kv,
+        tuple(sorted(k for k in cache_kv if k.startswith(TAIL_PREFIX))),
     )
 
 

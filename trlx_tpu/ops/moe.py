@@ -58,6 +58,15 @@ left out, and nothing stands in for the exchange: this is one chip of an
 deployment). ``shared`` is a float32 term added before the cast (a shared
 expert); on an ``ep`` mesh it is refused by name: nothing runs it there.
 
+**A router of the caller's own** (:func:`expert_layer` with ``routing``): a
+family whose router is not one matrix on the block's input (an MLP, a carry
+from the layer before, a bias that moves the choice and not the weight)
+computes its :class:`Routing` itself and hands it over; steps 2-4 are the
+same. A **skip** choice rides on the share above: a router ``E + 1`` wide
+over ``E`` held experts, whose last index no expert holds, so its copies
+are multiplied with nothing and contribute exactly zero
+(:func:`routing_stats` counts them as ``skip_share``). Off a mesh only.
+
 Training forwards sow ``aux_loss`` (load balancing over the top-k
 assignments, ``E * sum_e f_e P_e`` with ``f_e`` the copies routed to ``e``
 per token and ``P_e`` the mean router probability; uniform routing gives
@@ -194,7 +203,7 @@ def _apply_routed(h, routing: Routing, w_gate, w_up, w_down, dtype,
 
 
 def routing_stats(routing: Routing, num_experts: int, first_expert: int = 0,
-                  held: Optional[int] = None) -> Dict[str, jax.Array]:
+                  held: Optional[int] = None, skip: Optional[int] = None) -> Dict[str, jax.Array]:
     """What a step's routing looked like, as device scalars that ride in
     outputs the caller fetches anyway: ``experts_touched`` (distinct
     experts with at least one row), ``max_load`` (the busiest expert's
@@ -203,13 +212,18 @@ def routing_stats(routing: Routing, num_experts: int, first_expert: int = 0,
     ``first_expert`` on, the first two are over the held experts alone
     (what this chip reads and multiplies) and a fourth figure,
     ``rows_here_share``, is the share of the routed copies whose expert is
-    held here."""
+    held here. Where the router's index ``skip`` is a choice of no expert
+    at all, what is not held is that choice and not another chip's: the
+    fourth figure is ``skip_share``, the share of the copies that chose it."""
     N, k = routing.experts.shape
     counts = jnp.zeros((num_experts,), jnp.int32).at[routing.experts.reshape(-1)].add(1)
     stats = {}
+    if skip is not None:
+        stats["skip_share"] = counts[skip].astype(jnp.float32) / (N * k)
     if held is not None and held < num_experts:
         counts = jnp.roll(counts, -first_expert)[:held]
-        stats["rows_here_share"] = jnp.sum(counts).astype(jnp.float32) / (N * k)
+        if skip is None:
+            stats["rows_here_share"] = jnp.sum(counts).astype(jnp.float32) / (N * k)
     return {
         "experts_touched": jnp.sum(counts > 0).astype(jnp.float32),
         "max_load": jnp.max(counts).astype(jnp.float32) / (N * k),
@@ -231,7 +245,7 @@ def record_step_stats(stats: Dict[str, Any]) -> None:
     registry.counter("moe/rows_routed").inc(float(stats["rows_routed"]))
     steps = registry.counter("moe/steps_recorded")
     steps.inc()
-    for name in ("experts_touched", "rows_here_share"):
+    for name in ("experts_touched", "rows_here_share", "skip_share"):
         if name not in stats:
             continue
         total = registry.counter(f"moe/{name}_sum")
@@ -260,12 +274,18 @@ def balance_losses(routing: Routing, num_experts: int, token_mask=None) -> Dict[
     }
 
 
-def expert_layer(h: jax.Array, router_w, w_gate, w_up, w_down, *, k: int,
+def expert_layer(h: jax.Array, router_w, w_gate, w_up, w_down, *, k: Optional[int] = None,
                  norm_topk: bool = False, dtype=jnp.bfloat16,
                  mesh: Optional[Mesh] = None, batch_axes=("dp", "fsdp"),
-                 first_expert: int = 0, shared: Optional[jax.Array] = None):
+                 first_expert: int = 0, shared: Optional[jax.Array] = None,
+                 routing: Optional[Routing] = None):
     """``h`` [B, T, D] -> ``(y [B, T, D] in dtype, routing)``; ``routing``
     is over all ``B * T`` tokens, for the losses and the statistics.
+
+    The router is ``router_w`` [D, E] with ``k`` and ``norm_topk``
+    (:func:`route`), or the caller's own: ``router_w`` None and ``routing``
+    a finished :class:`Routing` over the ``B * T`` tokens, ``E`` its width
+    (off a mesh only; module docstring).
 
     ``mesh``: an ``ep`` mesh whose ``ep`` axis shards the experts' leading
     axis; tokens are split over ``batch_axes`` where their count allows
@@ -274,12 +294,16 @@ def expert_layer(h: jax.Array, router_w, w_gate, w_up, w_down, *, k: int,
     of the router's ``E`` (all of them, or one rank's share: module
     docstring). ``shared`` [B, T, D] float32 is added before the cast
     (off a mesh only)."""
-    D, E = h.shape[-1], router_w.shape[-1]
+    if (router_w is None) == (routing is None):
+        raise ValueError("expert_layer takes router_w (with k) or a finished routing, not both or neither")
+    D = h.shape[-1]
+    E = routing.probs.shape[-1] if router_w is None else router_w.shape[-1]
 
-    def run(h_loc, router_w, w_gate, w_up, w_down, first_expert=0):
+    def run(h_loc, router_w, w_gate, w_up, w_down, first_expert=0, routing=routing):
         flat = h_loc.reshape(-1, D)
-        with jax.named_scope("moe_router"):
-            routing = route(flat, router_w, k, norm_topk)
+        if routing is None:
+            with jax.named_scope("moe_router"):
+                routing = route(flat, router_w, k, norm_topk)
         return _apply_routed(flat, routing, w_gate, w_up, w_down, dtype, E, first_expert), routing
 
     if mesh is None or dict(mesh.shape).get("ep", 1) == 1:
@@ -295,6 +319,8 @@ def expert_layer(h: jax.Array, router_w, w_gate, w_up, w_down, *, k: int,
         return y.astype(dtype), routing
 
     ep = mesh.shape["ep"]
+    if router_w is None:
+        raise ValueError("a caller's own routing (a skip among its choices) is not built on an ep mesh")
     if E % ep or w_gate.shape[0] != E or first_expert:
         raise ValueError(
             f"an ep mesh shards all {E} experts over ep={ep}; got "

@@ -30,7 +30,7 @@ import flax.struct as struct
 import jax
 import jax.numpy as jnp
 
-from trlx_tpu.ops.kv_cache import STATE, cache_kind, decode_kv_layout
+from trlx_tpu.ops.kv_cache import cache_kind, decode_kv_layout
 from trlx_tpu.utils import topk_mask
 
 
@@ -441,13 +441,13 @@ def make_sampler(
 
         cache = init_cache_fn(B, cap)
         if not isinstance(cache, dict) and any(
-            cache_kind(layer).layout == STATE for layer in cache
+            cache_kind(layer).tail for layer in cache
         ):
             raise ValueError(
                 "the fixed sampler carries KV layers only (its loop folds "
                 "every layer into decode_kv_layout): a model with state "
-                "layers (granitemoehybrid) samples through rollout.engine: "
-                "continuous"
+                "layers (granitemoehybrid) or a tail beside its keys (zaya) "
+                "samples through rollout.engine: continuous"
             )
         cache = pin_cache(cache)
         # prefill: cache validity = prompt mask over slots [0, Q)
