@@ -453,6 +453,28 @@ def test_per_tenant_histograms_and_clean_health(server):
     assert server.health_events == []
 
 
+def test_metrics_say_what_the_server_holds(server):
+    """bf16 arithmetic on float32 masters (the tiny config's dtypes): the
+    engine reads the served copy and measures what it was handed,
+    and the done poll publishes the gauge (docs/serving.md)."""
+    import jax
+
+    from trlx_tpu import telemetry
+    from trlx_tpu.utils import tree_gb
+
+    server.generate(_full_prompts(server, 2, seed=7))
+    metrics = server.metrics()
+    served = tree_gb(server.served)
+    assert metrics["param_gb_served"] == server.engine.stats.param_gb == served
+    assert served < metrics["param_gb_as_given"] == tree_gb(server.params) < 2 * served
+    assert metrics["serve/param_leaves_cast"] > 0
+    kinds = {(leaf.ndim >= 2, str(leaf.dtype))
+             for leaf in jax.tree_util.tree_leaves(server.served["transformer"]["h_0"])}
+    assert kinds == {(True, "bfloat16"), (False, "float32")}
+    gauges = telemetry.get_metrics().snapshot()["gauges"]
+    assert gauges["engine/param_gb"] == served
+
+
 def test_submit_batch_atomic_on_refusal(server):
     """A mid-batch refusal enqueues NOTHING: the caller received no
     ids, so a partially-enqueued batch would decode orphan rows and

@@ -211,9 +211,16 @@ class TrainConfig:
     param_dtype: str = "float32"
     # Serve the rollout phase (sampler + frozen-ref scoring) a one-time
     # compute-dtype copy of the master params instead of the f32 masters.
-    # Bit-identical outputs: every op already casts params to the compute
-    # dtype per use; leaves that genuinely compute in f32 (value-head fc2,
-    # MoE router logits) are excluded. Its speed is not measured on the
+    # Every matrix an op casts to the compute dtype per use (Dense and
+    # expert kernels, embedding tables) comes out bit-identical, and the
+    # matrices named in utils.ROLLOUT_CAST_EXCLUDE (value-head fc2, MoE
+    # router logits) keep their width. Not every leaf is cast per use,
+    # though: LayerNorm / RMSNorm apply scale and bias at f32, so the copy
+    # rounds them (exact on the initialisers' ones and zeros, not on
+    # trained vectors), and granite's / zaya's convolution taps, shared
+    # expert output and tied table are multiplied at f32
+    # (ModelFamily.stored_width_leaves). A server's copy leaves all of
+    # these as stored: utils.served_params. Its speed is not measured on the
     # chip (every cell runs the default; XLA may hoist the loop-invariant
     # f32->bf16 weight conversion out of the decode scan anyway); kept
     # default-on for the halved frozen-ref HBM residency and because on an

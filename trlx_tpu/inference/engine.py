@@ -122,7 +122,7 @@ from trlx_tpu.ops.sampling import (
     concat_cols,
     make_row_keys,
 )
-from trlx_tpu.utils import sched_points
+from trlx_tpu.utils import sched_points, tree_gb
 
 #: what the host was doing while the chip sat drained (the starved
 #: ledger, docs/observability.md "The serving loop, from inside"): a
@@ -196,6 +196,10 @@ class EngineStats:
     done_polls: int = 0  # [B]-bool device->host fetches actually paid
     weight_pushes: int = 0  # mid-generation behavior refreshes applied
     released: int = 0  # placeholder rows force-finished on admission
+    # what the params the engine was handed hold (GB, from shapes and
+    # dtypes at start_phase and at an applied push): what every decode
+    # step reads, whoever cast it
+    param_gb: float = 0.0
     # wall the host spent blocked in the step loop's device->host
     # fetches (the engine/fetch spans), and in a call that dispatched a
     # step and came back only when the step before it had ended (a
@@ -313,6 +317,7 @@ class EngineStats:
             "engine/done_polls": float(self.done_polls),
             "engine/weight_pushes": float(self.weight_pushes),
             "engine/released": float(self.released),
+            "engine/param_gb": round(self.param_gb, 4),
             "engine/host_blocked_ms": round(self.host_blocked_ms, 3),
             "engine/starved_ms": round(self.starved_ms, 3),
             "engine/prefill_chunks": float(self.prefill_chunks),
@@ -1611,6 +1616,7 @@ class ContinuousBatchingEngine:
         self._starved_part = None
         self._episode = dict.fromkeys(STARVED_PARTS, 0.0)
         self.stats = self._new_stats()
+        self.stats.param_gb = tree_gb(params)
         self._req_times = {}
         self._step_log = []
         self._step_base = 0
@@ -1660,6 +1666,7 @@ class ContinuousBatchingEngine:
         # drafting overlap window inside one params version)
         self._staged_drafts = None
         self.stats.weight_pushes += 1
+        self.stats.param_gb = tree_gb(self._params)
 
     def min_inflight_version(self) -> Optional[int]:
         """Oldest behavior version any not-yet-harvested work will carry:
@@ -2670,10 +2677,11 @@ class ContinuousBatchingEngine:
         # occupancy timeseries: one gauge sample per paid done-poll
         # (the registry's ring is bounded; one host call per poll)
         # — the Perfetto counter track rides these samples
-        telemetry.get_metrics().gauge("engine/slot_util").set(
-            self.stats.slot_util
-        )
-        if self._cache_gb["state"] or self._cache_gb["tail"]:  # again: the registry may have been cleared
+        registry = telemetry.get_metrics()
+        registry.gauge("engine/slot_util").set(self.stats.slot_util)
+        # again: the registry may have been cleared
+        registry.gauge("engine/param_gb").set(self.stats.param_gb)
+        if self._cache_gb["state"] or self._cache_gb["tail"]:
             self._publish_cache_gauges(self._cache_gb)
         t_done = telemetry.monotonic() if self.trace_requests else 0.0
         if rows is None:

@@ -233,6 +233,16 @@ class InferenceServer:
             is_leaf=lambda x: isinstance(x, P),
         )
         self.params = jax.device_put(params, self.param_shardings)
+        # what the engine reads: matrices at the compute dtype, vectors as
+        # stored (utils.served_params); `params` itself where the weights
+        # are stored at that dtype already
+        from trlx_tpu.utils import served_params
+
+        self.served = served_params(
+            self.params,
+            getattr(self.model_config, "dtype", train.dtype),
+            self.family.stored_width_leaves,
+        )
 
         rollout = RolloutEngineConfig.from_dict(train.rollout)
         num_slots = rollout.slots or int(
@@ -302,7 +312,7 @@ class InferenceServer:
         # fold_in consumes rng without a dangling split chain (the
         # key-lineage engine's key-discard rule)
         phase_key = jax.random.fold_in(rng, 7)
-        self.engine.start_phase(self.params, phase_key)
+        self.engine.start_phase(self.served, phase_key)
         # set-up pays for every admission program; no pump compiles
         self.engine.compile_admission_programs()
 
@@ -312,6 +322,12 @@ class InferenceServer:
         # traces multiply span volume; size the ring before traffic
         telemetry.configure_from_dict(getattr(train, "telemetry", None))
         self._registry = telemetry.get_metrics()
+        self._registry.counter("serve/param_leaves_cast").inc(sum(
+            a is not b for a, b in zip(
+                jax.tree_util.tree_leaves(self.params),
+                jax.tree_util.tree_leaves(self.served),
+            )
+        ))
         # request tracing (telemetry/request_trace.py): with the tracer
         # enabled the engine logs decode-step cadence and done marks so
         # every completed request emits a parented span chain; disabled
@@ -937,9 +953,16 @@ class InferenceServer:
         """The ``serve/*`` slice of the metrics-registry snapshot: the
         per-request latency histograms (summaries) and counters this
         process accumulated — aggregate AND tenant-labeled keys — and
-        the histograms its engine observes (``engine/*``)."""
+        the histograms its engine observes (``engine/*``); beside them
+        what the caller's tree held on the device and what the engine
+        reads now (GB)."""
+        from trlx_tpu.utils import tree_gb
+
         snap = self._registry.snapshot()
-        out: Dict[str, Any] = {}
+        out: Dict[str, Any] = {
+            "param_gb_as_given": tree_gb(self.params),
+            "param_gb_served": self.engine.stats.param_gb,
+        }
         for section in ("counters", "gauges"):
             for name, value in snap.get(section, {}).items():
                 if name.startswith("serve/"):

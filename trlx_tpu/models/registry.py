@@ -26,6 +26,10 @@ class ModelFamily:
     # has experts an `ep` mesh axis can shard (models/gpt2_moe.py,
     # models/olmoe.py); such a family sows router losses into `moe_losses`
     supports_ep: bool = False
+    # matrices (by path substring) the family's programs consume at their
+    # stored width, besides utils.ROLLOUT_CAST_EXCLUDE: a server's copy
+    # leaves them as stored (utils.cast_is_exact)
+    stored_width_leaves: Tuple[str, ...] = ()
 
 
 _FAMILIES: Dict[str, ModelFamily] = {}
@@ -169,6 +173,11 @@ def _register_builtins() -> None:
             "granitemoehybrid", GraniteMoeHybridConfig, GraniteMoeHybridModel,
             GRANITE_HYBRID_PARTITION_RULES, init_granite_hybrid_cache,
             no_granite_checkpoint, supports_ep=True,
+            # the state-space convolution's taps (ops/ssm.py multiplies at
+            # f32), the shared expert's float32 output Dense, and the table:
+            # the lookup is scaled at f32 and the tied head is
+            # `nn.Embed.attend`, a float32 product on a float32 table
+            stored_width_leaves=("conv_weight", "shared/down_proj", "wte"),
         )
     )
     from trlx_tpu.models.zaya import (
@@ -185,5 +194,8 @@ def _register_builtins() -> None:
         ModelFamily(
             "zaya", ZayaConfig, ZayaModel, ZAYA_PARTITION_RULES, init_zaya_cache,
             no_zaya_checkpoint, supports_ep=True,
+            # CCA's taps and its [heads, Dh] bias table (ops/cca.py works at
+            # f32) and the table of the tied `nn.Embed.attend` head
+            stored_width_leaves=("conv0_weight", "conv1_bias", "wte"),
         )
     )

@@ -162,6 +162,55 @@ def compute_dtype_cast(params: Any, compute_dtype) -> Any:
     return jax.tree_util.tree_map_with_path(cast, params)
 
 
+def cast_is_exact(path, leaf, compute_dtype, keep: Iterable[str] = ()) -> bool:
+    """Whether storing ``leaf`` at the compute dtype leaves every result as
+    it was: a float leaf of rank >= 2, wider than the compute dtype, whose
+    path holds none of :data:`ROLLOUT_CAST_EXCLUDE` nor of ``keep`` (a
+    family's ``ModelFamily.stored_width_leaves``). Every program first uses
+    such a leaf through a cast to the compute dtype (a gather from an
+    embedding table commutes with it), so the stored copy is the value
+    each product already saw. Vectors stay as stored: LayerNorm and RMSNorm
+    apply ``scale`` / ``bias`` at f32, so :func:`compute_dtype_cast`, which
+    rounds them, is exact only on the initialisers' ones and zeros
+    (tests/test_served_params.py reads each family's programs for what
+    they do with every leaf; ROADMAP.md Queue 1 item 2(c): one rule)."""
+    keys = "/".join(str(getattr(p, "key", p)) for p in path)
+    return (
+        jnp.issubdtype(leaf.dtype, jnp.floating)
+        and leaf.ndim >= 2
+        and leaf.dtype.itemsize > jnp.dtype(compute_dtype).itemsize
+        and not any(ex in keys for ex in (*ROLLOUT_CAST_EXCLUDE, *keep))
+    )
+
+
+def served_params(params: Any, compute_dtype, keep: Iterable[str] = ()) -> Any:
+    """The tree a server hands its engine: every leaf :func:`cast_is_exact`
+    names stored at the compute dtype and sharded like the leaf, every
+    other leaf the caller's own array. A step under a server is one program
+    a token, so a cast left inside it is repeated every token. Decided on
+    the host: where no leaf qualifies (weights stored at the compute dtype,
+    a tree a trainer already cast) the tree comes back as the same object
+    and nothing is dispatched. Else one jitted cast a leaf, which is one
+    small program a distinct shape (7 in a 24-layer model; 3 ms each from
+    a warm compile cache on the v5e)."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    picks = [cast_is_exact(path, leaf, compute_dtype, keep) for path, leaf in flat]
+    if not any(picks):
+        return params
+    cast = jax.jit(lambda leaf: leaf.astype(compute_dtype))
+    return treedef.unflatten(
+        [cast(leaf) if pick else leaf for (_, leaf), pick in zip(flat, picks)]
+    )
+
+
+def tree_gb(tree: Any) -> float:
+    """Bytes a tree of arrays holds, in GB, from shapes and dtypes."""
+    return sum(
+        leaf.size * leaf.dtype.itemsize
+        for leaf in jax.tree_util.tree_leaves(tree)
+    ) / 1e9
+
+
 def filter_non_scalars(xs: Dict[str, Any]) -> Dict[str, float]:
     """Keep only entries castable to float — used before metric logging."""
     ys = {}
