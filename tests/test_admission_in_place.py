@@ -379,18 +379,21 @@ def test_a_group_of_dummies_changes_nothing(kv):
 
 
 def _tensor(shape, dtype):
-    return "x".join(map(str, shape)) + "x" + {"float32": "f32", "int8": "i8", "bfloat16": "bf16"}[str(dtype)]
+    return "x".join(map(str, shape)) + "x" + {"float32": "f32", "int8": "i8", "bfloat16": "bf16", "int32": "i32"}[str(dtype)]
 
 
 @pytest.mark.parametrize("program", ["prefill", "prefill_chunk"])
 @pytest.mark.parametrize("kv", ["bfloat16", "int8"])
 def test_no_slice_of_the_group_and_no_merge_back_in_the_lowered_text(kv, program):
     """StableHLO of a small paged engine's admission programs: the one
-    scatter a pool takes updates of the call's columns ``[A, T, H, Dh]``,
-    never of a slot's whole region ``[A, capacity, H, Dh]`` (the merge),
-    and the one gather a pool returns is the view ``[A, view_len, H, Dh]``
-    (the whole forward's is the capacity wide: one a pool and no more,
-    where the slice of the group was a second), with no slice of a pool."""
+    scatter a pool takes updates of the call's columns, never of a slot's
+    whole region ``[A, capacity, H, Dh]`` (the merge), and the one gather a
+    pool returns is the view ``[A, view_len, H, Dh]`` (the whole forward's
+    is the capacity wide: one a pool and no more, where the slice of the
+    group was a second), with no slice of a pool. A floating pool takes
+    the columns a block a window: ``A x T // bs`` index pairs into the pool
+    viewed by blocks ``[B, n_blocks, bs * H, Dh]`` (a reshape, no copy);
+    an int8 pool a position a window, ``A x T`` pairs ``[A, T, H, Dh]``."""
     _, _, params = _model(kv)
     eng = _engine(kv, 0)
     sds = lambda tree: jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)  # noqa: E731
@@ -404,18 +407,29 @@ def test_no_slice_of_the_group_and_no_merge_back_in_the_lowered_text(kv, program
         text, T, view = eng.prefill_chunk_jit.lower(*args, i32()).as_text(), W, Q
     layer = state.cache[0]
     B, cap, H, Dh = layer["k"].shape
+    bs = eng.block_size
     n_pools = len(state.cache) * sum(1 for k in layer if k != "block_tables")
     pools = {_tensor(v.shape, v.dtype) for k, v in layer.items() if k != "block_tables"}
     regions = {_tensor((A, cap) + v.shape[2:], v.dtype) for k, v in layer.items() if k != "block_tables"}
     views = {_tensor((A, view) + v.shape[2:], v.dtype) for k, v in layer.items() if k != "block_tables"}
-    columns = {_tensor((A, T) + v.shape[2:], v.dtype) for k, v in layer.items() if k != "block_tables"}
+    if kv == "bfloat16":
+        written = {_tensor((B, cap // bs, bs * H, Dh), layer["k"].dtype)}
+        columns = {_tensor((A, T // bs, bs * H, Dh), layer["k"].dtype)}
+        pairs = _tensor((A, T // bs, 2), "int32")
+    else:
+        written = pools
+        columns = {_tensor((A, T) + v.shape[2:], v.dtype) for k, v in layer.items() if k != "block_tables"}
+        pairs = _tensor((A, T, 2), "int32")
 
     scatters = re.findall(
-        r'"stablehlo\.scatter".*?\}\) : \(tensor<(\w+)>, tensor<\w+>, tensor<(\w+)>\)', text, re.S
+        r'"stablehlo\.scatter".*?\}\) : \(tensor<(\w+)>, tensor<(\w+)>, tensor<(\w+)>\)', text, re.S
     )
-    into_pools = [upd for operand, upd in scatters if operand in pools]
-    assert len(into_pools) == n_pools and set(into_pools) <= columns
-    assert not [upd for _, upd in scatters if upd in regions - columns]
+    into_pools = [(idx, upd) for operand, idx, upd in scatters if operand in written]
+    assert len(into_pools) == n_pools and {upd for _, upd in into_pools} <= columns
+    assert {idx for idx, _ in into_pools} == {pairs}
+    assert not [upd for _, _, upd in scatters if upd in regions - columns]
+    # the block view is a reshape of the pool and back: no copy, no transpose of one
+    assert not re.findall(r"stablehlo\.transpose[^\n]*tensor<(?:%s)>" % "|".join(pools | written), text)
     gathers = re.findall(r'"stablehlo\.gather"[^\n]*: \(tensor<(\w+)>, [^\n]*-> tensor<(\w+)>', text)
     from_pools = [res for operand, res in gathers if operand in pools]
     assert len(from_pools) == n_pools and set(from_pools) <= views

@@ -697,8 +697,10 @@ def test_skip_share_histogram_is_observed_once_an_admission(derived_server):
 PARENT_PROGRAMS = {
     "sampler": "0ad7eebdd65972e5",
     # PR 42: the forward addresses its group's rows inside the whole pool
-    # (no slice of the group, no merge back); 09eb3fddfb0669cc before it
-    "prefill": "e1d3e97bfe73739e",
+    # (no slice of the group, no merge back); 09eb3fddfb0669cc before it.
+    # PR 49: its 16 columns are whole blocks (of 4) from a Python 0, so they
+    # go into the pool a block a window; e1d3e97bfe73739e before it
+    "prefill": "d978d250b0578bbd",
     "decode_step": "d393924ac90fb367",
     "refill": "5e8422c7555df564",
 }

@@ -8,6 +8,7 @@ every such compile in this one file."""
 
 import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -127,3 +128,52 @@ def test_cca_decode_layer_steps_its_tail_and_holds_no_copy_of_its_pool(one_chip)
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
     entry = compiled.as_text().split("\nENTRY ", 1)[1]
     assert not re.search(r"= f32\[(%d,%d|%d),2,128\]" % (B, C, B * C), entry)
+
+
+@pytest.mark.parametrize("T,first", [(128, "traced"), (512, 0)], ids=["chunk", "whole"])
+@pytest.mark.parametrize(
+    "C,H", [(640, 16), (1024, 2)], ids=["pythia_16_heads", "zaya_2_kv_heads"]
+)
+def test_an_admission_layer_writes_whole_blocks_in_place(one_chip, C, H, T, first):
+    """One attention layer of an admission forward at the serving cells'
+    pools (32 slots, bf16, blocks of 16; 640 x 16 x 128 and 1024 x 2 x 128):
+    a group of 8 rows, one of them a dummy, writes ``T`` columns into the
+    donated pool and attends over its gathered view. The chip's compiler
+    makes the write one scatter a pool of ``8 x T // 16`` windows into the
+    pool viewed by blocks (a bitcast), and no operation returns, copies or
+    re-tiles anything pool-sized: the view ``[..., 16, H, 128]`` did that
+    to the two-head pool (PERF.md §6, PR 49). Nothing runs: no time here."""
+    from trlx_tpu.ops import kv_cache as kc
+    from trlx_tpu.ops.attention import decode_attention
+
+    B, A, Dh, bs, Q = 32, 8, 128, 16, 512
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((B, C, H, Dh), jnp.bfloat16)
+    cache = {"k": pool, "v": pool, "block_tables": sds((A, C // bs), jnp.int32), "slot_ids": sds((A,), jnp.int32)}
+    new = sds((A, T, H, Dh), jnp.bfloat16)
+    view = C if first == 0 else Q
+    bias = sds((A, 1, 1, view), jnp.float32)
+
+    def layer(q, k, v, cache, c, bias):
+        if first == 0:
+            return decode_attention(q, k, v, cache, 0, bias, causal=True)
+        (cache,) = kc.starting_at_block((cache,), c * (T // bs))
+        return decode_attention(q, k, v, cache, c * T, bias, causal=True)
+
+    assert kc.writes_whole_blocks(cache, new, 0)
+    compiled = jax.jit(layer, donate_argnums=(3,)).lower(new, new, new, cache, sds((), jnp.int32), bias).compile()
+    text = compiled.as_text()
+    pool_elems = B * C * H * Dh
+    moved = []
+    for m in re.finditer(r"= \w+\[([\d,]+)\]\S* (copy|copy-start|transpose|convert)\(", text):
+        dims = [int(d) for d in m.group(1).split(",")]
+        # (the whole forward's scores [8, 16, 512, 640] count as many elements as pythia's pool)
+        if np.prod(dims) >= pool_elems and dims[-1] == Dh:
+            moved.append(m.group(0))
+    assert not moved
+    windows = re.findall(r"= bf16\[%d,%d,%d,%d\]\S* scatter\(" % (B, C // bs, bs * H, Dh), text)
+    assert len(windows) == 2
+    assert not re.search(r"= bf16\[%d,%d,%d\]\S* scatter\(" % (B * C, H, Dh), text)

@@ -105,6 +105,7 @@ import numpy as np
 
 from trlx_tpu import telemetry
 from trlx_tpu.ops.kv_cache import (
+    PAGED,
     SHARED_POOL_KEYS,
     SHARE_TABLE_KEYS,
     STATE,
@@ -114,6 +115,8 @@ from trlx_tpu.ops.kv_cache import (
     empty_share_tables,
     identity_block_tables,
     init_shared_pool,
+    starting_at_block,
+    writes_whole_blocks,
 )
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
@@ -1240,6 +1243,16 @@ class ContinuousBatchingEngine:
         n_scan_chunks = max(0, n_pc - 1)
         chunk_kwargs = self._chunk_kwargs
 
+        def chunk_cache(cache, c):
+            """The group's cache as chunk ``c``'s forward is handed it:
+            where a chunk is whole blocks, with the promise that a traced
+            ``c * W`` cannot make itself, that the call's first column is
+            the first of logical block ``c * (W // bs)``
+            (``ops/kv_cache.py::writes_whole_blocks``)."""
+            if W % bs:
+                return cache
+            return starting_at_block(cache, c * (W // bs))
+
         def chunk_forward(params, cache, prompt_ids, prompt_mask,
                           positions, c):
             """Non-final chunk ``c`` forwarded against ``cache`` (heads
@@ -1255,7 +1268,7 @@ class ContinuousBatchingEngine:
                 ids_c,
                 attention_mask=prompt_mask,  # Q-wide view
                 position_ids=pos_c,
-                cache=cache,
+                cache=chunk_cache(cache, c),
                 cache_index=c * W,
                 **chunk_kwargs,
             )
@@ -1288,8 +1301,10 @@ class ContinuousBatchingEngine:
 
                 return jax.lax.cond(need[c], run, lambda cch: cch, cache), None
 
+            # (the carry holds the chunk's promise from the start: a scan's
+            # carry keeps one structure)
             cache_in, _ = jax.lax.scan(
-                body, cache_in, jnp.arange(n_scan_chunks)
+                body, chunk_cache(cache_in, 0), jnp.arange(n_scan_chunks)
             )
             return dataclasses.replace(
                 state,
@@ -1387,7 +1402,7 @@ class ContinuousBatchingEngine:
                 position_ids=jax.lax.dynamic_slice_in_dim(
                     positions, c * W, W, axis=1
                 ),
-                cache=cache_in,
+                cache=chunk_cache(cache_in, c),
                 cache_index=c * W,
                 **prefill_kwargs,
             )
@@ -1432,6 +1447,44 @@ class ContinuousBatchingEngine:
                 slot_ids,
                 land_group_cache(state, slot_ids, out["cache"]),
                 prompt_ids, prompt_mask, row_index, phase_key, out,
+            )
+
+        # what ``engine/prefill_block_write_share`` observes a dispatched
+        # forward: the write's own predicate (``writes_whole_blocks``) on
+        # each program's call, asked in shapes alone of the cache the
+        # forward is handed (a chunk's index is traced and comes with the
+        # chunk's promise; the two others' are Python integers)
+        A = self.admit_width
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+
+        def handed(state, c, *group):
+            cache = group_cache(state, *group)
+            return cache, chunk_cache(cache, c)
+
+        group_sds, chunk_sds = jax.eval_shape(
+            handed, jax.eval_shape(self._make_state), i32(), i32(A), i32(A),
+            *((i32(A, nb), i32(A, nb)) if sharing else (None, None)),
+        )
+
+        def block_write_share(cache, T, cache_index):
+            """The share of the forward's paged layers that write their
+            ``T`` columns by block; ``None`` without a paged layer."""
+            votes = [
+                writes_whole_blocks(
+                    layer,
+                    jax.ShapeDtypeStruct((A, T) + layer["k"].shape[2:], layer["k"].dtype),
+                    cache_index,
+                )
+                for layer in cache
+                if cache_kind(layer).layout == PAGED
+            ]
+            return sum(votes) / len(votes) if votes else None
+
+        self._block_write_share = {"prefill": block_write_share(group_sds, Q, 0)}
+        if W > 0:
+            self._block_write_share.update(
+                prefill_chunk=block_write_share(chunk_sds, W, i32()),
+                prefill_finish=block_write_share(group_sds, W, Q - W),
             )
 
         if self.mesh is not None and self._param_shardings is not None:
@@ -1982,6 +2035,7 @@ class ContinuousBatchingEngine:
                     self._phase_key,
                     *map_args,
                 )
+            self._observe_block_write("prefill")
             if adm["whole"]:
                 self.stats.prefill_whole += 1
                 adm["skipped"] = 0
@@ -2011,6 +2065,7 @@ class ContinuousBatchingEngine:
                     else:
                         window = np.zeros((n_scan,), bool)
                         window[run] = True
+                        self._observe_block_write("prefill_chunk")
                         self._state = self.prefill_chunks_jit(
                             self._params,
                             self._state,
@@ -2039,6 +2094,7 @@ class ContinuousBatchingEngine:
                 # one chunk a pump: one program for all of them
                 self._dispatch_chunk(adm, n_scan, map_args)
             else:
+                self._observe_block_write("prefill_finish")
                 self._state = self.prefill_finish_jit(
                     self._params,
                     self._state,
@@ -2062,8 +2118,19 @@ class ContinuousBatchingEngine:
         self._finalize_admission()
         return True, spent + 1
 
+    def _observe_block_write(self, program: str) -> None:
+        """``engine/prefill_block_write_share``, once an admission forward
+        dispatched: the share of ``program``'s paged layers that write
+        whole blocks into the pool (1.0 or 0.0 where they are alike)."""
+        share = self._block_write_share[program]
+        if share is not None:
+            telemetry.get_metrics().histogram(
+                "engine/prefill_block_write_share"
+            ).observe(share)
+
     def _dispatch_chunk(self, adm, c: int, map_args) -> None:
         """Chunk ``c`` of the in-flight group through ``prefill_chunk``."""
+        self._observe_block_write("prefill_chunk")
         self._state = self.prefill_chunk_jit(
             self._params,
             self._state,

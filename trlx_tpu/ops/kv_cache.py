@@ -79,7 +79,10 @@ blocks:
   the pool row of each of the call's rows. Its columns are scattered at
   (slot, physical position) where they lie and its view is gathered from
   there; no slice of the group's rows, no merge back (on the v5e the two
-  were 16 of a 39.6 ms chunk forward of pythia-1.4b; PERF.md §6, PR 42);
+  were 16 of a 39.6 ms chunk forward of pythia-1.4b; PERF.md §6, PR 42).
+  Where the columns are whole blocks (:func:`writes_whole_blocks`) they
+  go in a block a window, a sixteenth of the scatter's indices for the
+  same bytes (PERF.md §6, PR 49);
 - the decode step (one position a slot, a floating pool) reads the pool
   **as stored**: attention is a sum over positions, so it runs in
   physical order with the bias re-indexed (:func:`stored_order_bias`)
@@ -141,11 +144,14 @@ one).
 
 from __future__ import annotations
 
+import numbers
 import warnings
 from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from trlx_tpu.telemetry import get_metrics
 
 # KV cache: tuple over layers of {"k": [B, C, H, Dh], "v": [B, C, H, Dh]}
 Cache = Tuple[Dict[str, jax.Array], ...]
@@ -390,7 +396,9 @@ def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
     admission call: the pools are whole (``num_slots`` rows), the tables
     and the call's K/V are the group's (``A`` rows), and row ``i`` of the
     call lives in pool row ``slot_ids[i]`` (:func:`paged_write_read`).
-    ``tail`` names the keys that live by slot."""
+    ``tail`` names the keys that live by slot. (``"first_block"`` beside
+    ``"slot_ids"`` is a caller's promise about one call,
+    :func:`starting_at_block`; it changes no layer's kind.)"""
     if "ssm_state" in cache_kv:
         return CacheKind(STATE, False, False, tail=tuple(sorted(cache_kv)))
     if "block_tables" in cache_kv:
@@ -723,6 +731,67 @@ def stored_order_bias(
     return stored.reshape(lead + (capacity,))
 
 
+def starting_at_block(cache: Cache, first_block) -> Cache:
+    """A group's cache (:func:`cache_kind` ``.rows``) with the caller's
+    promise, under ``"first_block"`` beside ``"slot_ids"``, that the call's
+    first column is the first of logical block ``first_block``: what a
+    traced ``cache_index`` (chunk ``c`` at ``c * W``) cannot show by itself
+    and :func:`writes_whole_blocks` needs to hear from the caller that
+    knows. A caller makes it only where its width is whole blocks; a
+    declared call of any other width is refused at trace time."""
+    first_block = jnp.asarray(first_block, jnp.int32)
+    return tuple(
+        dict(layer, first_block=first_block) if cache_kind(layer).rows else layer
+        for layer in cache
+    )
+
+
+def writes_whole_blocks(cache_kv: Dict[str, jax.Array], k: jax.Array,
+                        cache_index) -> bool:
+    """Whether this call's columns are whole blocks of the pool, so
+    :func:`paged_write_read` writes them a block a window and not a
+    position a window. Decided on what the call shows, at trace time, in
+    the manner of :func:`reads_as_stored`:
+
+    - ``T`` columns from a scalar ``cache_index`` (not the verify step's
+      ``[B, T]`` matrix, not the decode step's per-slot vector) with ``T``
+      a multiple of the block size, **and** the first column the first of a
+      block: a Python integer shows that itself (a whole forward's ``0``),
+      a traced scalar cannot, and counts only where the caller declared it
+      (:func:`starting_at_block`, the engine's chunk forwards);
+    - a floating pool without a shared-prefix overlay: an int8 pool writes
+      four buffers and a shared region drops and publishes by position.
+
+    Everything else keeps the by-position write. A declared call whose
+    width is not whole blocks is a caller's error, refused by name."""
+    return _first_whole_block(cache_kv, k, cache_index) is not None
+
+
+def _first_whole_block(cache_kv, k, cache_index):
+    """The call's first logical block where :func:`writes_whole_blocks`
+    holds (a Python ``cache_index`` on a block's boundary shows it, a
+    traced scalar's is the caller's declared ``"first_block"``), else
+    ``None``."""
+    kind = cache_kind(cache_kv)
+    if kind.layout != PAGED:
+        return None
+    block_size = cache_kv["k"].shape[1] // cache_kv["block_tables"].shape[-1]
+    whole = k.shape[1] % block_size == 0
+    if "first_block" in cache_kv and not whole:
+        raise ValueError(
+            f"a call declared to start at a block (starting_at_block) writes "
+            f"whole blocks: {k.shape[1]} columns are no multiple of the block "
+            f"size {block_size}"
+        )
+    if not whole or kind.quantized or kind.shared:
+        return None
+    if isinstance(cache_index, numbers.Integral):
+        return cache_index // block_size if cache_index % block_size == 0 else None
+    if cache_index.ndim == 0:
+        return cache_kv.get("first_block")
+    return None
+
+
 def _pool_rows(pool: jax.Array, slot_ids) -> jax.Array:
     """[B, 1] the pool row each of a call's rows lives in: its own where
     the call spans every slot, ``slot_ids`` for a group's call."""
@@ -746,6 +815,50 @@ def _scatter_rows(
     scatter semantics — the discard sentinel relies on this)."""
     return pool.at[_pool_rows(pool, slot_ids), phys].set(
         rows.astype(pool.dtype), mode="drop"
+    )
+
+
+def physical_blocks(
+    block_tables: jax.Array,  # [B, n_blocks] int32
+    first_block,  # scalar: the call's first logical block
+    n: int,  # the blocks the call writes
+) -> jax.Array:
+    """[B, n] the physical block of each of the call's ``n`` logical blocks
+    from ``first_block``; a logical block out of range maps to ``n_blocks``
+    (out of bounds), which :func:`_scatter_blocks` drops, as
+    :func:`physical_positions` does a position."""
+    n_blocks = block_tables.shape[-1]
+    logical = jnp.broadcast_to(
+        jnp.asarray(first_block, jnp.int32) + jnp.arange(n, dtype=jnp.int32),
+        (block_tables.shape[0], n),
+    )
+    phys = jnp.take_along_axis(
+        block_tables, jnp.clip(logical, 0, n_blocks - 1), axis=1
+    )
+    return jnp.where((logical >= 0) & (logical < n_blocks), phys, n_blocks)
+
+
+def _scatter_blocks(
+    pool: jax.Array, phys_blk: jax.Array, rows: jax.Array, slot_ids=None
+) -> jax.Array:
+    """Scatter ``rows`` [B, T, H, Dh], ``T`` a whole number of blocks, into
+    ``pool`` [num_slots, cap, H, Dh] as ``B x T // bs`` windows, one a
+    block, at the physical blocks ``phys_blk`` [B, T // bs] of the call's
+    rows; an out-of-bounds block or row drops as in :func:`_scatter_rows`.
+    A block is contiguous in the pool, so the pool is viewed ``[num_slots,
+    n_blocks, bs * H, Dh]``, positions folded into heads: a split of a
+    major axis and a merge above the minor one, which moves no data
+    whatever the head count (the view ``[..., bs, H, Dh]`` does for two
+    heads: the compiler re-tiles the whole pool; PERF.md section 6, PR 49)."""
+    S, cap, H = pool.shape[:3]
+    B, n = phys_blk.shape
+    bs = rows.shape[1] // n
+    blocks = pool.reshape((S, cap // bs, bs * H) + pool.shape[3:])
+    windows = rows.astype(pool.dtype).reshape((B, n, bs * H) + pool.shape[3:])
+    return (
+        blocks.at[_pool_rows(pool, slot_ids), phys_blk]
+        .set(windows, mode="drop")
+        .reshape(pool.shape)
     )
 
 
@@ -808,12 +921,33 @@ def paged_write_read(
     writes drop like a position at ``capacity``. The pools come back whole,
     the tables and ``slot_ids`` as they were handed in.
 
-    The write is one scatter of the call's ``T`` columns at
-    ``(row, phys)``, in place in the donated pool whatever its dtype (on
-    the v5e ~75 ns a position of 16 heads x 128: 32 rows into an 84 MB
-    pool in microseconds, a chunk's 8 x 128 in 77 us, a whole prompt's 8 x
-    512 in 310 us; PERF.md §6, PR 28 and PR 42). What is returned to attend
-    over comes in two forms, and the form is most of a decode step's cost:
+    The write is one scatter a pool, in place in the donated pool
+    whatever its dtype, in one of two forms, chosen from what the call
+    shows (:func:`writes_whole_blocks`; counted a traced call site in
+    ``kv_cache/write_path{path=blocks|positions}``):
+
+    - **by block**: a call whose ``T`` columns are whole blocks from a
+      block's first column, into a floating pool without a shared-prefix
+      overlay (the engine's admission forwards: a whole prompt from a
+      Python ``0``, a chunk from a traced ``c * W`` that the engine
+      declares with :func:`starting_at_block`). The ``T // bs`` logical
+      blocks go through the table to physical blocks and each lands as
+      one window ``[bs * H, Dh]`` of the pool viewed by blocks
+      (:func:`_scatter_blocks`): ``A x T // bs`` index pairs. A block is
+      contiguous in the pool, so the same bytes land in the same places;
+    - **by position** (:func:`_scatter_rows`): everything else. ``T``
+      columns at ``(row, phys)``, ``A x T`` index pairs. The decode step's
+      one position a slot, the verify step's per-column targets, an int8
+      pool (four buffers) and a shared-prefix group (whose writes drop and
+      publish by position).
+
+    The chip takes a scatter by the index, not by the byte: on the v5e
+    ~75 ns a window whatever it holds, so 32 rows into an 84 MB pool take
+    microseconds, and a chunk's 8 x 128 columns of 16 heads x 128 took
+    77 us by position (a whole prompt's 8 x 512: 310 us) where their 4 MB
+    need 5; by block a sixteenth of the indices (PERF.md §6, PR 28, PR 42
+    and PR 49). What is returned to attend over comes in two forms, and
+    the form is most of a decode step's cost:
 
     - the **logical view** (default): one gather of (row, physical
       position) pairs from the pool into logical order, ``[B, view_len,
@@ -853,15 +987,25 @@ def paged_write_read(
     capacity = cache_kv["k"].shape[1]
     tables = cache_kv["block_tables"]
     slot_ids = cache_kv["slot_ids"] if kind.rows else None
-    idx = jnp.asarray(cache_index, jnp.int32)
-    if idx.ndim == 2:
-        # per-column targets: the caller names every column's logical
-        # position directly (OOB columns drop per element)
-        positions = idx
+    first_block = _first_whole_block(cache_kv, k, cache_index)
+    by_block = first_block is not None
+    get_metrics().counter(
+        "kv_cache/write_path{path=%s}" % ("blocks" if by_block else "positions")
+    ).inc()
+    if by_block:
+        phys_blk = physical_blocks(
+            tables, first_block, T * tables.shape[-1] // capacity
+        )
     else:
-        base = jnp.broadcast_to(idx, (B,))
-        positions = base[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    phys = physical_positions(tables, positions, capacity)
+        idx = jnp.asarray(cache_index, jnp.int32)
+        if idx.ndim == 2:
+            # per-column targets: the caller names every column's logical
+            # position directly (OOB columns drop per element)
+            positions = idx
+        else:
+            base = jnp.broadcast_to(idx, (B,))
+            positions = base[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+        phys = physical_positions(tables, positions, capacity)
     view = logical_view_index(tables, capacity)
     if 0 < view_len < capacity:
         view = view[:, :view_len]
@@ -894,6 +1038,8 @@ def paged_write_read(
         )
 
     def scatter(key, rows):
+        if by_block:
+            return _scatter_blocks(cache_kv[key], phys_blk, rows, slot_ids)
         return _scatter_rows(cache_kv[key], phys, rows, slot_ids)
 
     def logical(key):
@@ -921,6 +1067,8 @@ def paged_write_read(
         new_kv["block_tables"] = tables
         if kind.rows:
             new_kv["slot_ids"] = slot_ids
+        if "first_block" in cache_kv:
+            new_kv["first_block"] = cache_kv["first_block"]
         if sharing:
             new_kv["shared_tables"] = cache_kv["shared_tables"]
             new_kv["publish_tables"] = cache_kv["publish_tables"]
