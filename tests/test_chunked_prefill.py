@@ -695,7 +695,9 @@ def test_skip_share_histogram_is_observed_once_an_admission(derived_server):
 # locations) at commit 270555e (PR 29; PERF.md section 6 lists the same
 # digests): gpt2 2 x 32 in bf16, Q 16 + R 8, 4 slots, admit/harvest 2
 PARENT_PROGRAMS = {
-    "sampler": "0ad7eebdd65972e5",
+    # PR 51 (first asked as PR 50): its loop carries one array a kind for all layers, written in
+    # place at the layer's index; 0ad7eebdd65972e5 before it
+    "sampler": "f9ea1ab01e76431c",
     # PR 42: the forward addresses its group's rows inside the whole pool
     # (no slice of the group, no merge back); 09eb3fddfb0669cc before it.
     # PR 49: its 16 columns are whole blocks (of 4) from a Python 0, so they
@@ -775,9 +777,21 @@ def test_default_rollout_paths_lower_the_parents_programs(program):
     on the continuous engine with default options still lowers the
     monolithic ``prefill``, and ``decode_step`` and ``refill`` with it, text
     for text what they were before PR 30 (``prefill`` as PR 42 left it),
-    and the fixed sampler is not touched. A change that means to alter one of these programs updates
+    and the fixed sampler is as PR 51 left it. A change that means to alter one of these programs updates
     its digest here (``tools/program_hashes.py`` compares whole runs)."""
     assert _default_path_digests()[program] == PARENT_PROGRAMS[program]
+
+
+def test_layers_too_large_to_stage_keep_the_sampler_they_had(monkeypatch):
+    """Where a layer's buffer is too large for the compiler to stage
+    (``ppo-gpt2m-tldr``'s 73 MB bf16 layers; here the limit is set to 0)
+    the sampler's loop carries the per-layer tuple and lowers, text for
+    text, the program it was before PR 51: such a cell runs the parent's
+    operations (PERF.md §6, PR 50/51)."""
+    from trlx_tpu.ops import kv_cache
+
+    monkeypatch.setattr(kv_cache, "STAGED_LAYER_BYTES", 0)
+    assert _default_path_digests.__wrapped__()["sampler"] == "0ad7eebdd65972e5"
 
 
 # ------------------------------- FLOPs ---------------------------------- #

@@ -48,6 +48,26 @@ def _filled_cache(rng, C, Dh, dtype, cache_dtype, filled):
     return dense_write_read(cache, k, v, 0, jnp.dtype(dtype))[2]
 
 
+LAYERS, LAYER = 3, 1
+
+
+def _carry(cache):
+    """The fixed sampler's carry (``decode_kv_layout`` of a tuple: one array
+    a kind, layer-major) with ``cache`` as layer ``LAYER`` of ``LAYERS`` and
+    every other layer holding other values, as the model hands it to that
+    layer (``layer_cache``)."""
+    import jax
+
+    from trlx_tpu.ops.kv_cache import decode_kv_layout, layer_cache
+
+    layers = tuple(
+        cache if l == LAYER
+        else jax.tree_util.tree_map(lambda a: (a + 1 + l).astype(a.dtype), cache)
+        for l in range(LAYERS)
+    )
+    return layer_cache(decode_kv_layout(layers), LAYER)
+
+
 def _bias(C, index, pad, shared):
     """Additive [B|1, 1, 1, C]: causal at ``index`` and, per row, ``pad``
     masked positions on the left (row 0 of a per-row bias has none)."""
@@ -84,9 +104,8 @@ def test_fused_read_matches_generic(dtype, cache_dtype, atol, Dh, C, where,
 
     before = _counts()
     ref, ref_kv = decode_attention(q, k_new, v_new, cache, index, bias)
-    out, new_kv = decode_attention(
-        q, k_new, v_new, decode_kv_layout(cache), index, bias
-    )
+    carry = _carry(cache)
+    out, new_kv = decode_attention(q, k_new, v_new, carry, index, bias)
     after = _counts()
     assert after["generic"] == before["generic"] + 1
     assert after["fused"] == before["fused"] + 1
@@ -95,14 +114,56 @@ def test_fused_read_matches_generic(dtype, cache_dtype, atol, Dh, C, where,
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=atol
     )
-    # the write: the same buffers, in the other layout, bit for bit
+    # the write: the carry comes back whole, this layer's buffers the same
+    # as the generic read's in the other layout, bit for bit, and every
+    # other layer as it was
     want = decode_kv_layout(ref_kv)
     assert sorted(new_kv) == sorted(want)
     for name in want:
         assert new_kv[name].dtype == want[name].dtype, name
+        assert new_kv[name].shape == carry[name].shape, name
         np.testing.assert_array_equal(
-            np.asarray(new_kv[name], np.float32),
+            np.asarray(new_kv[name][LAYER], np.float32),
             np.asarray(want[name], np.float32), err_msg=name,
+        )
+        others = [l for l in range(LAYERS) if l != LAYER]
+        np.testing.assert_array_equal(
+            np.asarray(new_kv[name], np.float32)[others],
+            np.asarray(carry[name], np.float32)[others], err_msg=name,
+        )
+
+
+@pytest.mark.parametrize("dtype,cache_dtype", [p[:2] for p in PRECISIONS])
+def test_a_layers_own_buffers_read_as_the_carry_does(dtype, cache_dtype):
+    """One layer's folded dict (the pp stage scan's call: the carry with no
+    leading axis) takes the same read: output and written buffers equal the
+    carry's, bit for bit."""
+    import jax.numpy as jnp
+
+    from trlx_tpu.ops.attention import decode_attention
+    from trlx_tpu.ops.kv_cache import decode_kv_layout
+
+    rng = np.random.default_rng(3)
+    C, Dh, index = 40, 64, 17
+    cache = _filled_cache(rng, C, Dh, dtype, cache_dtype, filled=index)
+    q, k_new, v_new = (
+        jnp.asarray(rng.standard_normal((B, 1, H, Dh)), dtype) for _ in range(3)
+    )
+    bias = _bias(C, index, pad=5, shared=False)
+    before = _counts()
+    out, new_kv = decode_attention(q, k_new, v_new, _carry(cache), index, bias)
+    own, own_kv = decode_attention(
+        q, k_new, v_new, decode_kv_layout(cache), index, bias
+    )
+    assert _counts()["fused"] == before["fused"] + 2
+    np.testing.assert_array_equal(
+        np.asarray(own, np.float32), np.asarray(out, np.float32)
+    )
+    assert sorted(own_kv) == sorted(new_kv)
+    for name in own_kv:
+        np.testing.assert_array_equal(
+            np.asarray(own_kv[name], np.float32),
+            np.asarray(new_kv[name][LAYER], np.float32), err_msg=name,
         )
 
 
@@ -112,7 +173,6 @@ def test_fully_masked_row_matches_generic():
     import jax.numpy as jnp
 
     from trlx_tpu.ops.attention import NEG_INF, decode_attention
-    from trlx_tpu.ops.kv_cache import decode_kv_layout
 
     rng = np.random.default_rng(0)
     C, Dh, index = 40, 64, 17
@@ -125,27 +185,62 @@ def test_fully_masked_row_matches_generic():
     bias[1] = NEG_INF
     bias = jnp.asarray(bias)
     ref, _ = decode_attention(q, k_new, v_new, cache, index, bias)
-    out, _ = decode_attention(
-        q, k_new, v_new, decode_kv_layout(cache), index, bias
-    )
+    out, _ = decode_attention(q, k_new, v_new, _carry(cache), index, bias)
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
 
 
 def test_decode_kv_layout_shapes():
-    """Per-layer tuple and the pp sampler's layer-major dict: heads fold
-    into the minor axis, int8 scales go capacity-minor."""
+    """A tuple of layers becomes the carry, one array a kind with the
+    layers leading; one layer's dict and the pp sampler's layer-major dict
+    fold as they are: heads into the minor axis, int8 scales
+    capacity-minor."""
     import jax.numpy as jnp
 
     from trlx_tpu.ops.kv_cache import decode_kv_layout, kv_buffers
 
-    flat = decode_kv_layout(kv_buffers(2, B, 24, H, 8, jnp.bfloat16, "int8"))
-    assert len(flat) == 2
-    assert flat[0]["k"].shape == flat[1]["v"].shape == (B, 24, H * 8)
-    assert flat[0]["k"].dtype == jnp.int8
-    assert flat[0]["k_scale"].shape == flat[0]["v_scale"].shape == (B, H, 24)
+    layers = kv_buffers(2, B, 24, H, 8, jnp.bfloat16, "int8")
+    carry = decode_kv_layout(layers)
+    assert sorted(carry) == ["k", "k_scale", "v", "v_scale"]
+    assert carry["k"].shape == carry["v"].shape == (2, B, 24, H * 8)
+    assert carry["k"].dtype == jnp.int8
+    assert carry["k_scale"].shape == carry["v_scale"].shape == (2, B, H, 24)
+    own = decode_kv_layout(layers[0])
+    assert own["k"].shape == (B, 24, H * 8) and own["v_scale"].shape == (B, H, 24)
     stacked = {"k": jnp.zeros((5, B, 24, H, 8)), "v": jnp.zeros((5, B, 24, H, 8))}
     assert decode_kv_layout(stacked)["v"].shape == (5, B, 24, H * 8)
+
+
+@pytest.mark.parametrize(
+    "batch,capacity,kv,one_array",
+    [(64, 512, "int8", True), (32, 560, "bfloat16", True), (48, 560, "bfloat16", True),
+     (56, 560, "bfloat16", False), (64, 560, "bfloat16", False)],
+    ids=["longgen-33.5MB", "36.7MB", "55.1MB", "64.2MB", "tldr-73.4MB"],
+)
+def test_layers_the_compiler_would_stage_fold_into_one_array(batch, capacity, kv, one_array):
+    """gpt2-medium's layers (16 heads of 64): where a layer's buffer is
+    small enough for the chip's compiler to stage and write back whole
+    (``staged_by_the_compiler``: the sizes are the ones compiled for a
+    described v5e, PERF.md §6 PR 50) a tuple of layers folds into the
+    layer-major carry; larger layers stay a tuple of folded dicts, the
+    layout they were carried in before. Shapes only: nothing is allocated."""
+    import warnings
+
+    import jax
+    import jax.numpy as jnp
+
+    from trlx_tpu.ops.kv_cache import decode_kv_layout, kv_buffers, staged_by_the_compiler
+
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")  # int8 beyond its measured capacity
+        layers = jax.eval_shape(lambda: kv_buffers(3, batch, capacity, 16, 64, jnp.bfloat16, kv))
+    assert staged_by_the_compiler(layers[0]) == one_array
+    folded = jax.eval_shape(decode_kv_layout, layers)
+    if one_array:
+        assert isinstance(folded, dict) and folded["k"].shape == (3, batch, capacity, 1024)
+    else:
+        assert isinstance(folded, tuple) and len(folded) == 3
+        assert folded[0]["k"].shape == folded[2]["v"].shape == (batch, capacity, 1024)
 
 
 def _bypass_case(kind):
@@ -209,13 +304,12 @@ def test_decode_layout_refuses_what_it_cannot_read(kind):
     import jax.numpy as jnp
 
     from trlx_tpu.ops.attention import decode_attention
-    from trlx_tpu.ops.kv_cache import decode_kv_layout
 
     q_len, cache, index, bias = _bypass_case(kind)
     x = jnp.zeros((B, q_len, H, 8), jnp.float32)
     with pytest.raises(ValueError, match="decode_kv_layout"):
         decode_attention(
-            x, x, x, decode_kv_layout(cache), index, bias,
+            x, x, x, _carry(cache), index, bias,
             learned_bias=kind == "per_head_bias",
         )
 

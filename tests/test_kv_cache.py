@@ -128,8 +128,12 @@ def _cache(layout, kv, shared):
                 publish_tables=kc.empty_share_tables(B, C // 4),
             )
         return cache
-    cache = kc.kv_buffers(1, B, C, H, DH, jnp.float32, kv)[0]
-    return kc.decode_kv_layout(cache) if layout == kc.FOLDED else cache
+    if layout == "folded-own":  # one layer's own buffers: the pp stage scan's call
+        return kc.decode_kv_layout(kc.kv_buffers(1, B, C, H, DH, jnp.float32, kv)[0])
+    if layout == kc.FOLDED:  # the fixed sampler's carry, as handed to layer 1 of 2
+        carry = kc.decode_kv_layout(kc.kv_buffers(2, B, C, H, DH, jnp.float32, kv))
+        return kc.layer_cache(carry, 1)
+    return kc.kv_buffers(1, B, C, H, DH, jnp.float32, kv)[0]
 
 
 # (layout, storage dtype, shared overlay) -> the attention/decode_path a
@@ -140,6 +144,8 @@ KINDS = [
     ("dense", "int8", False, "generic", "generic"),
     ("folded", "bfloat16", False, "fused", "refused"),
     ("folded", "int8", False, "fused", "refused"),
+    ("folded-own", "bfloat16", False, "fused", "refused"),
+    ("folded-own", "int8", False, "fused", "refused"),
     ("paged", "bfloat16", False, "paged", "generic"),
     ("paged", "int8", False, "generic", "generic"),
     ("paged", "bfloat16", True, "generic", "generic"),
@@ -183,16 +189,23 @@ def test_cache_kind_decides_the_read(layout, kv, shared, one_token, prefill):
     import jax
     import jax.numpy as jnp
 
-    from trlx_tpu.ops.kv_cache import CacheKind, cache_kind
+    from trlx_tpu.ops.kv_cache import LAYER, CacheKind, cache_kind, layer_cache
 
     cache = _cache(layout, kv, shared)
-    want = CacheKind(layout, kv == "int8", shared)
-    assert cache_kind(cache) == want
+    # the carry says which layer a call is for under a key, and that key
+    # (no rank: a dense buffer has four axes too) is what makes it folded
+    layer = cache.get(LAYER)
+    want = CacheKind(layout.split("-")[0], kv == "int8", shared, layer=layer)
+    assert cache_kind(cache) == want and (layer is None) == (layout != "folded")
     paged = layout == "paged"
     at = jnp.full((B,), 5, jnp.int32) if paged else 5
     path, new_kv = _path_taken(cache, 1, at)
     assert path == one_token
     # the written cache is the same kind, key for key and shape for shape
+    # (the carry comes back whole, to be handed to the next layer)
+    if layer is not None:
+        assert LAYER not in new_kv
+        new_kv = layer_cache(new_kv, layer)
     assert cache_kind(new_kv) == want
     assert jax.tree_util.tree_map(jnp.shape, new_kv) == jax.tree_util.tree_map(
         jnp.shape, cache

@@ -177,3 +177,100 @@ def test_an_admission_layer_writes_whole_blocks_in_place(one_chip, C, H, T, firs
     windows = re.findall(r"= bf16\[%d,%d,%d,%d\]\S* scatter\(" % (B, C // bs, bs * H, Dh), text)
     assert len(windows) == 2
     assert not re.search(r"= bf16\[%d,%d,%d\]\S* scatter\(" % (B * C, H, Dh), text)
+
+
+def _while_bodies(text):
+    """The text of every computation some ``while`` of the compiled module
+    names as its body."""
+    computations = dict(
+        (m.group(1), m.group(2))
+        for m in re.finditer(r"^(?:ENTRY )?%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.S | re.M)
+    )
+    names = set(re.findall(r"body=%?([\w.\-]+)", text))
+    assert names and names <= set(computations)
+    return "\n".join(computations[name] for name in sorted(names))
+
+
+@pytest.mark.parametrize(
+    "B,Q,R,stored,carry",
+    [(64, 64, 448, "s8", True), (64, 512, 48, "bf16", False), (32, 512, 48, "bf16", True)],
+    ids=["longgen-int8", "tldr-bf16", "half-tldr-bf16"],
+)
+def test_the_samplers_loop_writes_its_cache_in_place_and_writes_none_of_it_back(one_chip, B, Q, R, stored, carry):
+    """The fixed sampler of the PPO cells (gpt2-medium: 24 layers, 16 heads
+    of 64, bf16 rollout parameters as ``compute_dtype_cast`` leaves them,
+    sampling on with the end token held back, as ``benchmark/ppo_driver.py``
+    sets it) at longgen's shapes (batch 64, 64 + 448, int8 by ``auto``:
+    33.5 MB a layer), at tldr's (64, 512 + 48, bf16: 73.4 MB) and at half
+    tldr's batch (36.7 MB). Carried a layer at a time, 32 of longgen's 48
+    buffers and 12 of the half batch's were written in ``S(1)`` and copied
+    back whole every step, 1.07 GB a step in longgen (PERF.md §6, PR 50).
+    Where a layer is small enough for that
+    (``kv_cache.py::staged_by_the_compiler``) the loop carries one array a
+    kind for all layers: inside the ``while`` body each layer's write is one
+    in-place ``dynamic-update-slice`` of that array in HBM and nothing
+    cache-shaped is staged or moved. tldr's layers stay on their own, are
+    written in HBM and prefetched for their read alone. In every case no
+    write lands in ``S(1)``, nothing cache-shaped is copied out of it and
+    nothing cache-shaped is copied. Nothing runs: no time here."""
+    import functools
+
+    from trlx_tpu.models.gpt2 import GPT2Config, init_cache
+    from trlx_tpu.models.heads import CausalLMWithValueHead
+    from trlx_tpu.ops.sampling import GenerationConfig, make_sampler
+    from trlx_tpu.utils import compute_dtype_cast
+
+    L, HD, C = 24, 1024, Q + R
+    cfg = GPT2Config(
+        vocab_size=50257, n_positions=1024, n_embd=HD, n_layer=L, n_head=16,
+        dtype="bfloat16", param_dtype="float32", kv_cache_dtype="auto",
+    )
+    model = CausalLMWithValueHead(cfg)
+
+    def apply_fn(params, input_ids, attention_mask=None, position_ids=None,
+                 cache=None, cache_index=None, last_only=False):
+        return model.apply(
+            {"params": params}, input_ids, attention_mask=attention_mask,
+            position_ids=position_ids, cache=cache, cache_index=cache_index,
+            last_only=last_only,
+        )
+
+    gen = GenerationConfig(
+        max_new_tokens=R, min_new_tokens=R, top_k=0, do_sample=True,
+        eos_token_id=50256, pad_token_id=50256,
+    )
+    sampler = make_sampler(apply_fn, functools.partial(init_cache, cfg), gen, Q)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(
+        lambda: compute_dtype_cast(
+            model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"],
+            jnp.bfloat16,
+        )
+    )
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), params)
+    prompts = sds((B, Q), jnp.int32)
+    text = jax.jit(sampler).lower(params, prompts, prompts, sds((2,), jnp.uint32)).compile().as_text()
+    body = _while_bodies(text)
+
+    # the carry [L, B, C, HD], a layer's buffer [B, C, HD] or a part of one
+    cache_shaped = r"%s\[(?:\d+,){1,2}%d,%d\]" % (stored, C, HD)
+    written = "%s[%s%d,%d,%d]" % (stored, "%d," % L if carry else "", B, C, HD)
+    writes = re.findall(r"= (%s)(\{[^}]*\}) dynamic-update-slice\(" % re.escape(written), body)
+    assert len(writes) == 2 * L
+    assert not [layout for _, layout in writes if "S(1)" in layout]
+    moves = [
+        line for line in body.split("\n")
+        if re.search(r" (?:copy-start|copy-done|slice-start|slice-done)\(", line)
+        and re.search(cache_shaped, line)
+    ]
+    # a copy-start's result is (destination, source, context): out of S(1)
+    # is a write-back
+    assert not [
+        line for line in moves
+        if re.search(r"= \(%s\{(?![^}]*S\(1\))[^}]*\}, %s\{[^}]*S\(1\)" % (cache_shaped, cache_shaped), line)
+    ]
+    assert not (carry and moves)
+    assert not re.findall(r"= %s\S* copy\(" % cache_shaped, body)

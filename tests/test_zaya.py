@@ -30,6 +30,7 @@ from trlx_tpu.ops.kv_cache import (
     DENSE,
     PAGED,
     STATE,
+    CacheKind,
     cache_kind,
     identity_block_tables,
     kv_buffers,
@@ -269,16 +270,16 @@ def test_registry_builds_the_family_and_its_cache():
 def test_cache_kind_on_a_cca_layer_a_state_layer_and_a_plain_paged_layer():
     cfg = model_and_params()[0]
     layer = init_zaya_cache(cfg, 2, 8)[0]
-    assert cache_kind(layer) == (DENSE, False, False, False, TAIL)
+    assert cache_kind(layer) == CacheKind(DENSE, False, False, False, TAIL)
     tables = identity_block_tables(2, 2)
     kind = cache_kind(dict(layer, block_tables=tables, slot_ids=jnp.zeros((2,), jnp.int32)))
     assert (kind.layout, kind.rows, kind.tail) == (PAGED, True, TAIL)
     kv, tail = split_tail(dict(layer, block_tables=tables))
     assert set(kv) == {"k", "v", "block_tables"} and tuple(sorted(tail)) == TAIL
     state = state_buffers(2, 4, 8, 16, 4, 32)
-    assert cache_kind(state) == (STATE, False, False, False, ("conv_tail", "ssm_state"))
+    assert cache_kind(state) == CacheKind(STATE, False, False, False, ("conv_tail", "ssm_state"))
     plain = dict(kv_buffers(1, 2, 8, 2, 16, jnp.bfloat16)[0], block_tables=tables)
-    assert cache_kind(plain) == (PAGED, False, False, False, ())
+    assert cache_kind(plain) == CacheKind(PAGED, False, False, False, ())
     assert split_tail(plain) == (plain, {})
 
 

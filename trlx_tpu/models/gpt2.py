@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -36,11 +36,13 @@ from trlx_tpu.ops.attention import (
 from trlx_tpu.ops.kv_cache import (
     Cache,
     kv_buffers,
+    layer_cache,
     # not used here: benchmark/harness.py imports it from this module, and
     # no file under benchmark/ may change outside a `benchmark` issue; held
     # until one repoints that import at ops/kv_cache.py
     resolve_kv_cache_dtype,  # noqa: F401
     validate_kv_cache_dtype,
+    with_layer_cache,
 )
 
 
@@ -236,20 +238,18 @@ class GPT2Model(nn.Module):
 
         bias, causal = causal_dispatch(T, cache, cache_index, attention_mask)
 
-        new_cache: List = []
         branch_hidden = None
         for i in range(start_layer, cfg.n_layer):
             if capture_hidden_at is not None and i == capture_hidden_at:
                 branch_hidden = x
-            layer_cache = cache[i] if cache is not None else None
-            x, new_kv = self.h[i](x, bias, layer_cache, cache_index, causal)
-            new_cache.append(new_kv)
+            x, new_kv = self.h[i](x, bias, layer_cache(cache, i), cache_index, causal)
+            cache = with_layer_cache(cache, i, new_kv)
 
         x = self.ln_f(x)
         out = {
             "logits": self.logits(x) if compute_logits else None,
             "hidden": x,
-            "cache": tuple(new_cache) if cache is not None else None,
+            "cache": cache,
         }
         if capture_hidden_at is not None:
             out["branch_hidden"] = branch_hidden

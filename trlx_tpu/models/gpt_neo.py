@@ -39,7 +39,12 @@ from trlx_tpu.ops.attention import (
     dot_product_attention,
     padding_bias,
 )
-from trlx_tpu.ops.kv_cache import kv_buffers, validate_kv_cache_dtype
+from trlx_tpu.ops.kv_cache import (
+    kv_buffers,
+    layer_cache,
+    validate_kv_cache_dtype,
+    with_layer_cache,
+)
 
 
 def expand_attention_types(attention_types, n_layer: int) -> Tuple[str, ...]:
@@ -292,23 +297,21 @@ class GPTNeoModel(nn.Module):
         )
 
         types = cfg.layer_types
-        new_cache: List = []
         branch_hidden = None
         for i in range(start_layer, cfg.num_layers):
             if capture_hidden_at is not None and i == capture_hidden_at:
                 branch_hidden = x
-            layer_cache = cache[i] if cache is not None else None
             if types[i] == "local":
-                x, new_kv = self.h[i](x, local_bias, layer_cache, cache_index, False)
+                x, new_kv = self.h[i](x, local_bias, layer_cache(cache, i), cache_index, False)
             else:
-                x, new_kv = self.h[i](x, global_bias, layer_cache, cache_index, causal)
-            new_cache.append(new_kv)
+                x, new_kv = self.h[i](x, global_bias, layer_cache(cache, i), cache_index, causal)
+            cache = with_layer_cache(cache, i, new_kv)
 
         x = self.ln_f(x)
         out = {
             "logits": self.logits(x) if compute_logits else None,
             "hidden": x,
-            "cache": tuple(new_cache) if cache is not None else None,
+            "cache": cache,
         }
         if capture_hidden_at is not None:
             out["branch_hidden"] = branch_hidden

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Optional
 
 import flax.linen as nn
 import jax
@@ -24,7 +24,12 @@ from trlx_tpu.ops.attention import (
     decode_attention,
     dot_product_attention,
 )
-from trlx_tpu.ops.kv_cache import kv_buffers, validate_kv_cache_dtype
+from trlx_tpu.ops.kv_cache import (
+    kv_buffers,
+    layer_cache,
+    validate_kv_cache_dtype,
+    with_layer_cache,
+)
 from trlx_tpu.ops.rotary import apply_rotary_half, rotary_angles
 
 
@@ -212,20 +217,18 @@ class NeoXModel(nn.Module):
 
         bias, causal = causal_dispatch(T, cache, cache_index, attention_mask)
 
-        new_cache: List = []
         branch_hidden = None
         for i in range(start_layer, cfg.num_hidden_layers):
             if capture_hidden_at is not None and i == capture_hidden_at:
                 branch_hidden = x
-            layer_cache = cache[i] if cache is not None else None
-            x, new_kv = self.h[i](x, bias, position_ids, layer_cache, cache_index, causal)
-            new_cache.append(new_kv)
+            x, new_kv = self.h[i](x, bias, position_ids, layer_cache(cache, i), cache_index, causal)
+            cache = with_layer_cache(cache, i, new_kv)
 
         x = self.ln_f(x)
         out = {
             "logits": self.logits(x) if compute_logits else None,
             "hidden": x,
-            "cache": tuple(new_cache) if cache is not None else None,
+            "cache": cache,
         }
         if capture_hidden_at is not None:
             out["branch_hidden"] = branch_hidden

@@ -41,7 +41,12 @@ from trlx_tpu.ops.attention import (
     decode_attention,
     dot_product_attention,
 )
-from trlx_tpu.ops.kv_cache import kv_buffers, validate_kv_cache_dtype
+from trlx_tpu.ops.kv_cache import (
+    kv_buffers,
+    layer_cache,
+    validate_kv_cache_dtype,
+    with_layer_cache,
+)
 from trlx_tpu.ops.rotary import apply_rotary_half, rotary_angles
 
 
@@ -257,24 +262,22 @@ class OlmoeModel(nn.Module):
         # over cache slots, not over this call's tokens
         token_mask = attention_mask if cache is None else None
 
-        new_cache: List = []
         per_block: List = []
         branch_hidden = None
         for i in range(start_layer, cfg.num_hidden_layers):
             if capture_hidden_at is not None and i == capture_hidden_at:
                 branch_hidden = x
-            layer_cache = cache[i] if cache is not None else None
             x, new_kv, stats = self.h[i](
-                x, bias, position_ids, layer_cache, cache_index, causal, token_mask
+                x, bias, position_ids, layer_cache(cache, i), cache_index, causal, token_mask
             )
-            new_cache.append(new_kv)
+            cache = with_layer_cache(cache, i, new_kv)
             per_block.append(stats)
 
         x = self.ln_f(x)
         out = {
             "logits": self.logits(x) if compute_logits else None,
             "hidden": x,
-            "cache": tuple(new_cache) if cache is not None else None,
+            "cache": cache,
         }
         if per_block:
             stacked = {k: jnp.stack([s[k] for s in per_block]) for k in per_block[0]}

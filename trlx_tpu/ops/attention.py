@@ -310,7 +310,11 @@ def _grouped_attention(q, k, v, bias, scale):
 
 def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias, scale=None):
     """One new position over a cache in ``decode_kv_layout``: write it in
-    place, then read K and V once each, as stored.
+    place, then read K and V once each, as stored. The cache is the fixed
+    sampler's carry of all layers (``cache_kind(...).layer`` says which this
+    call is for; the carry comes back whole, written at that layer) or one
+    layer's own buffers (a layer too large for the compiler to stage, the
+    pp stage scan's call): the same operations.
 
     Per-head products run on the MXU against the folded ``[C, H*Dh]`` buffer:
     ``q`` is laid out block-diagonally (``[H, H*Dh]``, head ``h``'s row holds
@@ -326,45 +330,53 @@ def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias, scale=None):
     """
     B, _, H, Dh = q.shape
     HD = H * Dh
-    quantized = cache_kind(cache_kv).quantized
+    kind = cache_kind(cache_kv)
+    quantized = kind.quantized
+    # the carry of all layers ([L, B, ...], this call's layer leading every
+    # write and read) or one layer's own buffers: the carry with no such axis
+    lead = () if kind.layer is None else (kind.layer,)
+    one = (1,) * len(lead)
     new_kv = {}
     for name, new in (("k", k_new), ("v", v_new)):
         if quantized:
             new, absmax = quantize_kv(new)
             new_kv[name + "_scale"] = jax.lax.dynamic_update_slice(
                 cache_kv[name + "_scale"],
-                absmax.reshape(B, H, 1),
-                (0, 0, cache_index),
+                absmax.reshape(*one, B, H, 1),
+                (*lead, 0, 0, cache_index),
             )
         new_kv[name] = jax.lax.dynamic_update_slice(
             cache_kv[name],
-            new.reshape(B, 1, HD).astype(cache_kv[name].dtype),
-            (0, cache_index, 0),
+            new.reshape(*one, B, 1, HD).astype(cache_kv[name].dtype),
+            (*lead, 0, cache_index, 0),
         )
     # fenced: the update stays ONE in-place op with the loop carry as its
     # only destination. Unfenced, XLA fuses a duplicate of it into the read
     # as well, two ops then write from one operand, neither can be in place
     # and copy insertion copies the whole buffer at every step (PERF.md §6)
     new_kv = jax.lax.optimization_barrier(new_kv)
+    # what this layer reads: its slice of the carry, which fuses into the
+    # products below (read-only: it may be prefetched, never written back)
+    mine = new_kv if kind.layer is None else {name: a[kind.layer] for name, a in new_kv.items()}
 
     seg = (jnp.arange(HD)[None, :] // Dh == jnp.arange(H)[:, None])
     q_blocks = jnp.where(seg[None], q.reshape(B, 1, HD), 0).astype(q.dtype)
     scores = jnp.einsum(
-        "bhk,bck->bhc", q_blocks, new_kv["k"].astype(q.dtype),
+        "bhk,bck->bhc", q_blocks, mine["k"].astype(q.dtype),
         preferred_element_type=jnp.float32,
     )
     if quantized:
-        scores = scores * new_kv["k_scale"].astype(jnp.float32)
+        scores = scores * mine["k_scale"].astype(jnp.float32)
     scores = scores * (jax.lax.rsqrt(jnp.float32(Dh)) if scale is None else jnp.float32(scale))
     scores = scores + bias[:, 0].astype(jnp.float32)
     weights = jax.nn.softmax(scores, axis=-1)
     if quantized:
-        weights = weights * new_kv["v_scale"].astype(jnp.float32)
+        weights = weights * mine["v_scale"].astype(jnp.float32)
     # rounded to the compute dtype where they meet V, as the generic read
     # does: two-term weights (16 + 16 bits) read the same log-probability
     # error on the chip, to 1% (PERF.md §6, PR 25)
     blocks = jnp.einsum(
-        "bhc,bck->bhk", weights.astype(q.dtype), new_kv["v"].astype(q.dtype),
+        "bhc,bck->bhk", weights.astype(q.dtype), mine["v"].astype(q.dtype),
         preferred_element_type=jnp.float32,
     )
     out = jnp.sum(jnp.where(seg[None], blocks, 0.0), axis=1)
@@ -397,9 +409,12 @@ def decode_attention(
     traced call site in ``attention/decode_path{path=...}``):
 
     - ``fused`` — the cache is in ``decode_kv_layout`` (the fixed
-      sampler's decode loop): :func:`_decode_read`. Such a cache takes one
-      position a call under a bias broadcast over heads; anything else is
-      refused, not rerouted;
+      sampler's decode loop, whose carry holds all layers in one array a
+      kind and comes back as ``new_kv`` whole, or one layer's dict where
+      its layers are large; the pp stage scan's, one layer's dict):
+      :func:`_decode_read`. Such a cache takes one position
+      a call under a bias broadcast over heads; anything else is refused,
+      not rerouted;
     - ``paged`` — one position a slot into a floating paged pool (the
       continuous engine's decode step; ``kv_cache.py::reads_as_stored``):
       the rows are scattered in place and :func:`dot_product_attention`

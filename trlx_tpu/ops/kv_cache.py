@@ -15,7 +15,13 @@ from its keys and ranks — the one place that does:
   ``cache_index`` and returns the buffers to attend over.
 - **folded** (:func:`decode_kv_layout`): the dense cache with heads folded
   into the minor axis, ``[B, C, H*Dh]``; the layout the fixed sampler's
-  decode loop carries. Its in-place write and single read are attention
+  decode loop carries, **layer-major** where a layer's buffer is small
+  enough for the compiler to stage (:func:`staged_by_the_compiler`): one
+  array a kind for all layers (``[L, B, C, H*Dh]``), which a model hands
+  layer by layer through :func:`layer_cache` / :func:`with_layer_cache`
+  (the layer's index rides under ``"layer"``; a dict without it is one
+  layer's own buffers: a larger layer's, the pp stage scan's call). Its
+  in-place write and single read are attention
   math and live in ``ops/attention.py::_decode_read``.
 - **paged** (:func:`init_paged_cache`): the dense pools plus
   ``"block_tables"``; the continuous-batching engine's cache.
@@ -146,7 +152,7 @@ from __future__ import annotations
 
 import numbers
 import warnings
-from typing import Dict, NamedTuple, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -257,23 +263,67 @@ def kv_buffers(
     )
 
 
+# The largest folded buffer of one layer that a loop carrying it on its own
+# would have staged: compiled for a described v5e (jax 0.9.0, libtpu 0.0.34;
+# gpt2-medium's sampler, 24 layers) the memory-space assignment puts buffers
+# of 32.5 and 33.5 MB (int8) and of 36.7 and 55.1 MB (bf16) into ``S(1)``,
+# runs the step's one-row write there and copies the whole buffer back to
+# the loop's carry every step; buffers of 64.2 and 73.4 MB are written in
+# HBM and only prefetched for their read, and one of 134 MB is left alone
+# (PERF.md section 6, PR 50)
+STAGED_LAYER_BYTES = 60 * 10**6
+
+
+def staged_by_the_compiler(layer_kv) -> bool:
+    """Whether a decode loop that carried this layer's ``k`` buffer on its
+    own would see it staged through ``S(1)`` and written back whole: its
+    bytes, whatever its dtype or layout (:data:`STAGED_LAYER_BYTES`)."""
+    k = layer_kv["k"]
+    return k.size * k.dtype.itemsize <= STAGED_LAYER_BYTES
+
+
 def decode_kv_layout(cache):
     """The dense cache in the layout the decode loop carries: heads folded
     into the minor axis — ``k``/``v`` ``[..., C, H, Dh] -> [..., C, H*Dh]``,
-    int8 scales ``[..., C, H, 1] -> [..., H, C]``.
+    int8 scales ``[..., C, H, 1] -> [..., H, C]`` — and a tuple of layers
+    whose buffers the compiler would stage (:func:`staged_by_the_compiler`)
+    folded into **one dict, layer-major** (``[L, B, C, H*Dh]``, scales
+    ``[L, B, H, C]``): the carry. A tuple of larger layers stays a tuple of
+    folded dicts.
 
     Why another layout: on the TPU a ``[B, C, H, Dh]`` buffer is tiled over
     its two minor axes, and ``Dh = 64`` fills half of a 128-lane row — the
     compiler pads it, so a gpt2-sized buffer takes (and every decode step
     reads) twice its bytes; the ``[B, C, H, 1]`` scales take 128x theirs.
-    Folded, both are lane-dense. The prefill writes the ``kv_buffers``
-    layout and the sampler converts once, before its loop
-    (``ops/sampling.py::make_sampler``); :func:`cache_kind` keys on the rank
-    of ``k``. ``cache`` is one layer's dict, a tuple of them, or the pp
-    sampler's layer-major dict (leading ``L`` axis).
+    Folded, both are lane-dense. Why one array for all layers: a loop that
+    carries a layer's 33.5 MB int8 buffer on its own invites the compiler's
+    memory-space assignment to stage it through ``S(1)``, write the step's
+    one row there and write the whole buffer back to the carry, 32 buffers
+    a step of gpt2-medium's 48 (1.58 of a 6.15 ms step on the v5e); a
+    0.8 GB array cannot be staged, so a row written at ``(l, 0, index, 0)``
+    is an in-place write by construction. A 73 MB layer is written in HBM
+    as it is and prefetched for its read, which the carry would cost it
+    (4% of such a step), so it stays on its own (PERF.md §6, PR 50). The
+    prefill writes the ``kv_buffers`` layout and the sampler converts once,
+    before its loop (``ops/sampling.py::make_sampler``); each layer is
+    written to its place in the preallocated carry in turn.
+    ``cache`` is one layer's dict, a tuple of them, or the pp sampler's
+    layer-major dict (leading ``L`` axis), which folds as a dict.
     """
     if not isinstance(cache, dict):
-        return tuple(decode_kv_layout(c) for c in cache)
+        if not staged_by_the_compiler(cache[0]):
+            return tuple(decode_kv_layout(layer) for layer in cache)
+        carry = {}
+        for l, layer in enumerate(cache):
+            for name, a in decode_kv_layout(layer).items():
+                if l == 0:
+                    # uninitialised: every layer is written below, and
+                    # zeros cost a pass over the carry
+                    carry[name] = jax.lax.empty((len(cache),) + a.shape, a.dtype)
+                carry[name] = jax.lax.dynamic_update_slice(
+                    carry[name], a[None], (l,) + (0,) * a.ndim
+                )
+        return carry
     out = {}
     for name, a in cache.items():
         if name.endswith("_scale"):
@@ -281,6 +331,34 @@ def decode_kv_layout(cache):
         else:
             out[name] = a.reshape(*a.shape[:-2], a.shape[-2] * a.shape[-1])
     return out
+
+
+# the key under which :func:`layer_cache` names the layer a call is for
+LAYER = "layer"
+
+
+def layer_cache(cache, i: int):
+    """Layer ``i``'s cache dict from what a model was handed: ``None``, a
+    tuple over layers (prefill, the engine, the fixed sampler's larger
+    layers, every path but one), or the fixed sampler's layer-major carry (:func:`decode_kv_layout` of a tuple:
+    one dict for all layers), which goes to the layer whole with ``i``
+    under ``"layer"`` — what :func:`cache_kind` reads as ``.layer``."""
+    if cache is None:
+        return None
+    if isinstance(cache, dict):
+        return {**cache, LAYER: i}
+    return cache[i]
+
+
+def with_layer_cache(cache, i: int, new_kv):
+    """What the model was handed with layer ``i``'s result put back: the
+    tuple with entry ``i`` replaced, or the carry as the layer's read
+    returned it (written in place at ``i``, every other layer untouched)."""
+    if cache is None:
+        return None
+    if isinstance(cache, dict):
+        return new_kv
+    return (*cache[:i], new_kv, *cache[i + 1:])
 
 
 # one value until a check can tell another from it: the benchmark's
@@ -387,23 +465,30 @@ class CacheKind(NamedTuple):
     # the keys kept a slot, not a position: every key of a state layer, the
     # ``tail_*`` keys of a layer of keys that keeps a tail, else none
     tail: Tuple[str, ...] = ()
+    # a folded cache that is the layer-major carry of all layers: the layer
+    # this call writes and reads (``None``: the dict is one layer's own)
+    layer: Optional[int] = None
 
 
 def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
     """Classify one layer's cache dict, at trace time, from the keys it
     carries and the rank of ``k`` — every reader of "which storage is
-    this" asks here. ``"slot_ids"`` beside ``"block_tables"`` marks an
-    admission call: the pools are whole (``num_slots`` rows), the tables
-    and the call's K/V are the group's (``A`` rows), and row ``i`` of the
-    call lives in pool row ``slot_ids[i]`` (:func:`paged_write_read`).
+    this" asks here. ``"layer"`` marks the fixed sampler's carry
+    (:func:`layer_cache`): folded, all layers in one array a kind, and
+    rank 4 like a dense buffer, which is why a key says it and no rank.
+    ``"slot_ids"`` beside ``"block_tables"`` marks an admission call: the
+    pools are whole (``num_slots`` rows), the tables and the call's K/V are
+    the group's (``A`` rows), and row ``i`` of the call lives in pool row
+    ``slot_ids[i]`` (:func:`paged_write_read`).
     ``tail`` names the keys that live by slot. (``"first_block"`` beside
     ``"slot_ids"`` is a caller's promise about one call,
     :func:`starting_at_block`; it changes no layer's kind.)"""
     if "ssm_state" in cache_kv:
         return CacheKind(STATE, False, False, tail=tuple(sorted(cache_kv)))
+    layer = cache_kv.get(LAYER)
     if "block_tables" in cache_kv:
         layout = PAGED
-    elif cache_kv["k"].ndim == 3:
+    elif layer is not None or cache_kv["k"].ndim == 3:
         layout = FOLDED
     else:
         layout = DENSE
@@ -413,6 +498,7 @@ def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
         "shared_tables" in cache_kv,
         layout == PAGED and "slot_ids" in cache_kv,
         tuple(sorted(k for k in cache_kv if k.startswith(TAIL_PREFIX))),
+        layer,
     )
 
 

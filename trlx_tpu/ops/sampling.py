@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from trlx_tpu.ops.kv_cache import cache_kind, decode_kv_layout
+from trlx_tpu.telemetry import get_metrics
 from trlx_tpu.utils import topk_mask
 
 
@@ -394,18 +395,22 @@ def make_sampler(
     buffers and re-pinned on each step's updated cache so the constraint
     sticks through the loop carry. A cache whose capacity axis is sharded
     stays in the ``kv_buffers`` layout and decodes through the generic read;
-    every other is carried in ``ops/kv_cache.py::decode_kv_layout``.
+    every other is carried in ``ops/kv_cache.py::decode_kv_layout``: one
+    array a kind for all layers, which the model's layers write in place
+    and read by slice, where a layer's own buffer is small enough for the
+    compiler to stage and write back whole, else a folded dict a layer
+    (the gauge ``sampler/carry_buffers`` counts the carry's arrays when the
+    sampler is traced: 2, or 4 with int8; times the layers for the latter).
     """
     Q = query_length
     R = gen_config.max_new_tokens
     cap = Q + R
 
-    def pin_cache(cache):
-        if cache_sharding is None:
+    def pin_cache(cache, sharding=cache_sharding):
+        if sharding is None:
             return cache
         return jax.tree_util.tree_map(
-            lambda a: jax.lax.with_sharding_constraint(a, cache_sharding),
-            cache,
+            lambda a: jax.lax.with_sharding_constraint(a, sharding), cache
         )
 
     def capacity_sharded(cache):
@@ -466,11 +471,24 @@ def make_sampler(
                 **_prefill_kwargs,
             )
         cache = out["cache"]
+        carry_sharding = cache_sharding
         if not capacity_sharded(cache):
             # the decode loop carries the lane-dense layout, which
-            # decode_attention reads once a step and writes in place
-            cache = decode_kv_layout(cache)
-        cache = pin_cache(cache)
+            # decode_attention writes in place and reads once a step: all
+            # layers in one array a kind where a layer's own buffer would
+            # be staged and written back whole, else a layer at a time
+            prefilled, cache = cache, decode_kv_layout(cache)
+            stacked = isinstance(cache, dict) and not isinstance(prefilled, dict)
+            if cache_sharding is not None and stacked:
+                # a tuple's carry leads with the layers, which no axis shards
+                carry_sharding = jax.sharding.NamedSharding(
+                    cache_sharding.mesh,
+                    jax.sharding.PartitionSpec(None, *cache_sharding.spec),
+                )
+        cache = pin_cache(cache, carry_sharding)
+        get_metrics().gauge("sampler/carry_buffers").set(
+            len(jax.tree_util.tree_leaves(cache))
+        )
         logits_last = out["logits"][:, -1].astype(jnp.float32)  # [B, V]
         if with_values:
             value_last = out["values"][:, -1].astype(jnp.float32)
@@ -519,8 +537,8 @@ def make_sampler(
                 if with_values
                 else jnp.zeros((B,), jnp.float32)
             )
-            return (t + 1, pin_cache(out["cache"]), new_logits, new_value,
-                    finished, rng, ys)
+            return (t + 1, pin_cache(out["cache"], carry_sharding), new_logits,
+                    new_value, finished, rng, ys)
 
         if gen_config.max_length > 0:
             # prompts already at/over the total-length cap emit no tokens
