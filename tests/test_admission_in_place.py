@@ -206,11 +206,11 @@ def _engine(kv, pool_blocks):
     cfg, model, _ = _model(kv)
 
     def apply_fn(p, input_ids, attention_mask=None, position_ids=None, cache=None,
-                 cache_index=None, last_only=False, skip_heads=False):
+                 cache_index=None, last_only=False):
         return model.apply(
             {"params": p}, input_ids, attention_mask=attention_mask,
             position_ids=position_ids, cache=cache, cache_index=cache_index,
-            last_only=last_only, skip_heads=skip_heads,
+            last_only=last_only,
         )
 
     gen = GenerationConfig(
@@ -220,8 +220,6 @@ def _engine(kv, pool_blocks):
         apply_fn=apply_fn, init_cache_fn=functools.partial(init_cache, cfg), gen_config=gen,
         query_length=Q, vocab_size=VOCAB, num_slots=4, admit_width=2, harvest_width=2,
         block_size=4, prefix_pool_blocks=pool_blocks, prefill_chunk=W,
-        # a budget off one builds prefill_chunks and prefill_finish too
-        prefill_chunks_per_pump=2,
     )
 
 
@@ -240,19 +238,13 @@ def _admit(eng, state, params, program, slot_ids, ids, mask, turns, maps):
     where it goes chunk by chunk."""
     key = jax.random.PRNGKey(3)
     rows = jnp.arange(len(slot_ids), dtype=jnp.int32)
-    n_chunks = Q // W
     if program == "prefill":
         return eng.prefill_jit(params, state, slot_ids, ids, mask, rows, turns, key, *maps)
-    if program == "prefill_chunk":
-        for c in range(n_chunks):
-            state = eng.prefill_chunk_jit(
-                params, state, slot_ids, ids, mask, rows, turns, key, jnp.asarray(c, jnp.int32), *maps
-            )
-        return state
-    state = eng.prefill_chunks_jit(
-        params, state, slot_ids, ids, mask, turns, jnp.ones((n_chunks - 1,), bool), *maps
-    )
-    return eng.prefill_finish_jit(params, state, slot_ids, ids, mask, rows, turns, key, *maps)
+    for c in range(Q // W):
+        state = eng.prefill_chunk_jit(
+            params, state, slot_ids, ids, mask, rows, turns, key, jnp.asarray(c, jnp.int32), *maps
+        )
+    return state
 
 
 def _copy(state):
@@ -263,7 +255,7 @@ def _fields(state):
     return {f.name: getattr(state, f.name) for f in dataclasses.fields(state) if f.name != "cache"}
 
 
-@pytest.mark.parametrize("program", ["prefill", "prefill_chunk", "prefill_chunks+finish"])
+@pytest.mark.parametrize("program", ["prefill", "prefill_chunk"])
 @pytest.mark.parametrize("pool_blocks", [0, 3], ids=["private", "shared_prefix"])
 @pytest.mark.parametrize("kv", ["bfloat16", "int8"])
 def test_an_admission_writes_its_slots_and_nothing_else(kv, pool_blocks, program):
@@ -368,7 +360,7 @@ def test_a_group_of_dummies_changes_nothing(kv):
     before = jax.device_get(_copy(state))
     dummies = jnp.full((2,), eng.num_slots, jnp.int32)
     ids, mask = _prompts(1, [13, 6])
-    for program in ("prefill", "prefill_chunk", "prefill_chunks+finish"):
+    for program in ("prefill", "prefill_chunk"):
         state = _admit(eng, state, params, program, dummies, ids, mask, jnp.asarray([2, 4], jnp.int32), [])
     after = jax.device_get(state)
     for was, now in zip(jax.tree_util.tree_leaves(before), jax.tree_util.tree_leaves(after)):

@@ -8,11 +8,13 @@ The acceptance pins (ISSUE 15 / docs/inference.md "Chunked prefill"):
   is pinned at bf16 tolerance on the fsdp×tp nightly variant), with
   prefix sharing OFF and ON;
 - the all-skipped-segment edge: an admit group whose rows are ALL
-  shorter than one chunk runs ONLY the finish chunk (the prefill mirror
+  shorter than one chunk runs ONLY the final chunk (the prefill mirror
   of the segmented-decode all-finished-tail tests);
 - the serving pump's chunk budget interleaves decode with a burst's
   admission without changing any row's bits;
-- engine-7's exact FLOP count for the chunked pair (scan + finish) is
+- a chunked admission is a host loop over ONE program, ``prefill_chunk``,
+  whoever asks and whatever the budget (ISSUE 52);
+- engine-7's exact FLOP count for a group's ``Q // W`` chunk forwards is
   STRICTLY below the monolithic prefill at the same shape.
 
 Engines here are built directly over a tiny float32 model (no trainer
@@ -105,13 +107,11 @@ def _engine(prefill_chunk=0, pool_blocks=0, chunks_per_pump=0):
     cfg, model, _ = _model_and_params()
 
     def apply_fn(p, input_ids, attention_mask=None, position_ids=None,
-                 cache=None, cache_index=None, last_only=False,
-                 skip_heads=False):
+                 cache=None, cache_index=None, last_only=False):
         return model.apply(
             {"params": p}, input_ids, attention_mask=attention_mask,
             position_ids=position_ids, cache=cache,
             cache_index=cache_index, last_only=last_only,
-            skip_heads=skip_heads,
         )
 
     gen = GenerationConfig(
@@ -155,6 +155,19 @@ def _mixed_prompts(n, seed=0, lo=2, hi=None, sort=True):
         order = np.argsort(mask.sum(axis=1))
         ids, mask = ids[order], mask[order]
     return ids, mask
+
+
+def _sharing_case(pool_blocks):
+    """(ids, mask, a fresh host pool or None): mixed lengths for a private
+    engine, full-length prompts with a common leading half for a sharing one."""
+    from trlx_tpu.serving.prefix_cache import PrefixBlockPool
+
+    if not pool_blocks:
+        return (*_mixed_prompts(8, seed=3), None)
+    rng = np.random.default_rng(11)
+    ids = rng.integers(1, 60, (8, Q)).astype(np.int32)
+    ids[:, : Q // 2] = ids[0, : Q // 2]
+    return ids, np.ones((8, Q), np.int32), PrefixBlockPool(pool_blocks, 4, (Q + R) // 4)
 
 
 def _drive_rows(engine, ids, mask, key, pool=None, pump=False, params=None):
@@ -274,22 +287,11 @@ def test_chunked_sharing_matches_monolithic():
     recomputed — and the result is still bitwise the monolithic+sharing
     engine's. Full-length prompts with a common leading half (left-padded
     prompts share iff they pad identically, docs/serving.md)."""
-    from trlx_tpu.serving.prefix_cache import PrefixBlockPool
-
     mono_sh, chunked_sh = _engine(0, pool_blocks=16), _engine(4, pool_blocks=16)
-    rng = np.random.default_rng(11)
-    prefix = rng.integers(1, 60, Q // 2).astype(np.int32)
-    N = 8
-    ids = rng.integers(1, 60, (N, Q)).astype(np.int32)
-    ids[:, : Q // 2] = prefix
-    mask = np.ones((N, Q), np.int32)
+    ids, mask, pool = _sharing_case(16)
     key = jax.random.PRNGKey(5)
-
-    def pool():
-        return PrefixBlockPool(16, mono_sh.block_size, mono_sh.n_blocks)
-
-    want = _drive_rows(mono_sh, ids, mask, key, pool=pool())
-    got = _drive_rows(chunked_sh, ids, mask, key, pool=pool())
+    want = _drive_rows(mono_sh, ids, mask, key, pool=pool)
+    got = _drive_rows(chunked_sh, ids, mask, key, pool=_sharing_case(16)[2])
     _assert_rows_equal(want, got)
     st = chunked_sh.stats
     assert st.prefix_hit_blocks > 0  # sharing actually happened
@@ -303,9 +305,9 @@ def test_chunked_sharing_matches_monolithic():
 def test_all_rows_shorter_than_one_chunk():
     """The early-exit tail edge (the prefill mirror of the segmented
     decode's all-finished-tail pins): every row of every admit group
-    fits inside the FINAL chunk, so every scan chunk skips — the group
-    pays exactly one chunk forward (finish), and the bits still match
-    the monolithic program."""
+    fits inside the FINAL chunk, so every chunk before it skips — the
+    group pays exactly one chunk forward (the final one), and the bits
+    still match the monolithic program."""
     mono, chunked = _engine(0), _engine(4)
     ids, mask = _mixed_prompts(4, seed=9, lo=1, hi=3, sort=False)
     key = jax.random.PRNGKey(13)
@@ -314,10 +316,10 @@ def test_all_rows_shorter_than_one_chunk():
     _assert_rows_equal(want, got)
     st = chunked.stats
     n_groups = st.prefills
-    n_scan = chunked.n_prefill_chunks - 1
-    assert st.prefill_chunks == n_groups  # ONLY the finish chunks ran
+    n_before = chunked.n_prefill_chunks - 1
+    assert st.prefill_chunks == n_groups  # ONLY the final chunks ran
     assert st.prefill_cols_skipped == (
-        n_groups * n_scan * chunked.prefill_chunk
+        n_groups * n_before * chunked.prefill_chunk
     )
 
 
@@ -413,13 +415,11 @@ def _family_engines(name):
     )["params"]
 
     def apply_fn(p, input_ids, attention_mask=None, position_ids=None,
-                 cache=None, cache_index=None, last_only=False,
-                 skip_heads=False):
+                 cache=None, cache_index=None, last_only=False):
         return model.apply(
             {"params": p}, input_ids, attention_mask=attention_mask,
             position_ids=position_ids, cache=cache,
             cache_index=cache_index, last_only=last_only,
-            skip_heads=skip_heads,
         )
 
     gen = GenerationConfig(
@@ -475,7 +475,103 @@ def test_chunked_matches_monolithic_by_family(family, how):
         assert stats.prefill_chunks >= stats.prefills - stats.prefill_whole
     else:
         assert stats.prefill_whole == 0
-        assert stats.prefill_chunks > stats.prefills  # > finish alone
+        assert stats.prefill_chunks > stats.prefills  # > the final chunks alone
+
+
+# --------------------- one program, whatever the budget ------------------- #
+
+
+def _count_dispatches(engine, monkeypatch):
+    """Record the chunk index of every ``prefill_chunk`` dispatch and the
+    plan of every admitted group; count ``prefill`` dispatches."""
+    seen = {"chunks": [], "plans": [], "whole": 0}
+    chunk_jit, whole_jit = engine.prefill_chunk_jit, engine.prefill_jit
+    finalize = engine._finalize_admission
+
+    def chunk(*args):
+        seen["chunks"].append(int(args[8]))
+        return chunk_jit(*args)
+
+    def whole(*args):
+        seen["whole"] += 1
+        return whole_jit(*args)
+
+    def finalized():
+        seen["plans"].append(np.asarray(engine._inflight_admission["need"]))
+        finalize()
+
+    monkeypatch.setattr(engine, "prefill_chunk_jit", chunk)
+    monkeypatch.setattr(engine, "prefill_jit", whole)
+    monkeypatch.setattr(engine, "_finalize_admission", finalized)
+    return seen
+
+
+@pytest.mark.parametrize("pool_blocks", [0, 16], ids=["private", "shared_prefix"])
+def test_an_unbudgeted_admission_is_one_dispatch_a_needed_chunk(pool_blocks, monkeypatch):
+    """``drive()`` with a chunk set (and a pump without a budget, the way a
+    sharing engine is fed here): every group goes through ``prefill_chunk``
+    alone, once for each chunk its plan needs before the final one, in
+    order, and once for the final chunk."""
+    engine = _engine(4, pool_blocks=pool_blocks)
+    ids, mask, pool = _sharing_case(pool_blocks)
+    seen = _count_dispatches(engine, monkeypatch)
+    _drive_rows(engine, ids, mask, jax.random.PRNGKey(7), pool=pool)
+    last = engine.n_prefill_chunks - 1
+    want = [
+        c for plan in seen["plans"]
+        for c in [*np.flatnonzero(plan[:last]), last]
+    ]
+    assert seen["chunks"] == want and seen["whole"] == 0
+    assert len(seen["plans"]) == engine.stats.prefills == 4
+    assert engine.stats.prefill_chunks == len(want)
+    # some group skipped, some group needed more than its final chunk
+    assert len(seen["plans"]) < len(want) < len(seen["plans"]) * (last + 1)
+
+
+@pytest.mark.parametrize("pool_blocks", [0, 16], ids=["private", "shared_prefix"])
+def test_a_budget_of_two_is_at_most_two_dispatches_a_pump(pool_blocks, monkeypatch):
+    """``prefill_chunks_per_pump=2`` means two forwards a pump iteration
+    and nothing else: each is a ``prefill_chunk`` dispatch, an iteration
+    makes at most two (and some make two), and the rows are the monolithic
+    program's bitwise."""
+    mono = _engine(0, pool_blocks=pool_blocks)
+    budgeted = _engine(4, pool_blocks=pool_blocks, chunks_per_pump=2)
+    ids, mask, pool = _sharing_case(pool_blocks)
+    key = jax.random.PRNGKey(17)
+    want = _drive_rows(mono, ids, mask, key, pool=pool, pump=True)
+    seen = _count_dispatches(budgeted, monkeypatch)
+    pump, per_pump = budgeted.pump, []
+
+    def counted():
+        before = len(seen["chunks"])
+        groups = pump()
+        per_pump.append(len(seen["chunks"]) - before)
+        return groups
+
+    monkeypatch.setattr(budgeted, "pump", counted)
+    pool = _sharing_case(pool_blocks)[2]
+    got = _drive_rows(budgeted, ids, mask, key, pool=pool, pump=True)
+    _assert_rows_equal(want, got)
+    assert max(per_pump) == 2 and seen["whole"] == 0
+    assert sum(per_pump) == budgeted.stats.prefill_chunks > budgeted.stats.prefills
+
+
+def test_an_engine_has_one_chunk_program_and_a_head_one_switch():
+    """What existed for the scan of chunks is gone: the engine's jitted
+    programs are these six names, and the value-head model's call takes
+    ``last_only`` and no other switch."""
+    import inspect
+
+    from trlx_tpu.models.heads import CausalLMWithValueHead
+
+    assert {n for n in vars(_engine(4)) if n.endswith("_jit")} == {
+        "prefill_jit", "prefill_chunk_jit", "decode_step_jit", "refill_jit",
+        "release_jit", "verify_step_jit",
+    }
+    assert list(inspect.signature(CausalLMWithValueHead.__call__).parameters) == [
+        "self", "input_ids", "attention_mask", "position_ids", "cache",
+        "cache_index", "last_only",
+    ]
 
 
 # ------------------------- what a server derives -------------------------- #
@@ -581,7 +677,7 @@ def test_server_streams_the_monolithic_programs_tokens(derived_server, monkeypat
     assert stats.prefill_whole > whole0
     monkeypatch.setattr(ContinuousBatchingEngine, "__init__", monolithic)
     mono = _server()
-    assert mono.engine.prefill_chunk == 0 and mono.engine.prefill_finish_jit is None
+    assert mono.engine.prefill_chunk == 0 and mono.engine.prefill_chunk_jit is None
     want_streams, want, _ = _stream_all(mono, prompts, every=0)
     for g, w in zip(got, want):
         assert g["tokens"] == w["tokens"] and g["length"] == w["length"]
@@ -620,8 +716,7 @@ def test_server_compiles_nothing_after_setup():
     leaves nothing for the first longer prompts to compile: backend
     compiles counted from jax's monitoring events as the benchmark's
     ``accounting.compiles_in_window`` counts them, and the engine's jitted
-    programs by their cache sizes. The scan of chunks and the finish
-    program after it are never built under a budget of one."""
+    programs by their cache sizes: they are all the programs it has."""
     from jax import monitoring
 
     compiles = []
@@ -636,7 +731,7 @@ def test_server_compiles_nothing_after_setup():
     engine = server.engine
     # 5 short prompts: a partial harvest group, so placeholders too
     _stream_all(server, _server_prompts(5, 3, seed=2), every=1)
-    assert engine.stats.prefill_chunks == engine.stats.prefills  # finish only
+    assert engine.stats.prefill_chunks == engine.stats.prefills  # final chunks only
     assert engine.stats.released > 0
     programs = {
         name: getattr(engine, name)._cache_size()
@@ -662,9 +757,11 @@ def test_server_compiles_nothing_after_setup():
     assert programs == {
         name: getattr(engine, name)._cache_size() for name in programs
     }
-    # one chunk a pump: ``prefill_chunk`` forwards the final chunk too
-    assert engine.prefill_chunks_jit._cache_size() == 0
-    assert engine.prefill_finish_jit._cache_size() == 0
+    # ``prefill_chunk`` forwards the final chunk too: no other program
+    # exists that a later admission could reach
+    assert {
+        n for n, fn in vars(engine).items() if n.endswith("_jit") and fn is not None
+    } == set(programs)
 
 
 def test_skip_share_histogram_is_observed_once_an_admission(derived_server):
@@ -767,7 +864,7 @@ def _default_path_digests():
     engine.submit(ids, mask)
     for _ in engine.drive(8):
         pass
-    assert engine.prefill_finish_jit is None and engine.stats.prefill_chunks == 0
+    assert engine.prefill_chunk_jit is None and engine.stats.prefill_chunks == 0
     return out
 
 
@@ -798,12 +895,12 @@ def test_layers_too_large_to_stage_keep_the_sampler_they_had(monkeypatch):
 
 
 def test_chunked_flops_strictly_below_monolithic():
-    """The engine-7 acceptance: the chunked pair's exact dot-FLOP count
-    (scan with EVERY chunk's cond at the run branch + finish) is
-    strictly below the monolithic prefill at the same shape — the
-    prompt-wide attention view alone guarantees it, before any chunk is
-    skipped at runtime. Also pins the flops-saved gauge's per-chunk cost
-    as a real traced number."""
+    """The engine-7 acceptance: a group's exact dot-FLOP count in chunks
+    (``Q // W`` traces of ``prefill_chunk``, every chunk run) is strictly
+    below the monolithic prefill at the same shape — the prompt-wide
+    attention view alone guarantees it, before any chunk is skipped at
+    runtime. Also pins the flops-saved gauge's per-chunk cost as a real
+    traced number: that of the program that runs."""
     from trlx_tpu.analysis.resource_audit import count_flops
 
     mono, chunked = _engine(0), _engine(4)
@@ -812,39 +909,27 @@ def test_chunked_flops_strictly_below_monolithic():
     )
     state_sds = jax.eval_shape(mono._make_state)
     A = mono.admit_width
-    n_scan = chunked.n_prefill_chunks - 1
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    f_mono = count_flops(
-        jax.make_jaxpr(mono.prefill_jit)(
-            params_sds, state_sds, i32(A), i32(A, Q), i32(A, Q),
-            i32(A), i32(A), key,
-        ).jaxpr
+    args = (
+        params_sds, state_sds, i32(A), i32(A, Q), i32(A, Q), i32(A), i32(A),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
     )
-    f_chunks = count_flops(
-        jax.make_jaxpr(chunked.prefill_chunks_jit)(
-            params_sds, state_sds, i32(A), i32(A, Q), i32(A, Q),
-            i32(A), jax.ShapeDtypeStruct((n_scan,), jnp.bool_),
-        ).jaxpr
+    f_mono = count_flops(jax.make_jaxpr(mono.prefill_jit)(*args).jaxpr)
+    f_chunk = count_flops(
+        jax.make_jaxpr(chunked.prefill_chunk_jit)(*args, i32()).jaxpr
     )
-    f_finish = count_flops(
-        jax.make_jaxpr(chunked.prefill_finish_jit)(
-            params_sds, state_sds, i32(A), i32(A, Q), i32(A, Q),
-            i32(A), i32(A), key,
-        ).jaxpr
-    )
-    assert f_chunks + f_finish < f_mono
+    assert 0 < chunked.n_prefill_chunks * f_chunk < f_mono
     # the saved-FLOPs gauge prices one skipped chunk with the SAME
     # counter over the same traced program
     chunked.start_phase(_params(), jax.random.PRNGKey(1))
-    assert chunked._chunk_flop_cost() == pytest.approx(f_chunks / n_scan)
+    assert chunked._chunk_flop_cost() == f_chunk
 
 
 def test_budget_lockfile_pins_chunked_below_monolithic():
     """The committed resource lockfile (analysis/budgets.json) carries
-    the chunked subjects, and at the audit shape the chunked pair sits
-    strictly below the monolithic entry — for the trainer engine AND
-    the sharing serving variant."""
+    the chunk program's subject, and at the audit shape (a chunk of
+    Q // 2) its two forwards sit strictly below the monolithic entry —
+    for the trainer engine AND the sharing serving variant."""
     import json
 
     from trlx_tpu.analysis.resource_audit import default_budgets_path
@@ -852,9 +937,8 @@ def test_budget_lockfile_pins_chunked_below_monolithic():
     programs = json.load(open(default_budgets_path()))["programs"]
     for suffix in ("", "_shared"):
         mono = programs[f"ppo.engine_prefill{suffix}"]["flops"]
-        ck = programs[f"ppo.engine_prefill_chunked{suffix}"]["flops"]
-        fin = programs[f"ppo.engine_prefill_finish{suffix}"]["flops"]
-        assert ck + fin < mono, suffix
+        chunk = programs[f"ppo.engine_prefill_chunk{suffix}"]["flops"]
+        assert 0 < 2 * chunk < mono, suffix
 
 
 def test_engine_serves_local_attention_gpt_neo():
@@ -885,13 +969,11 @@ def test_engine_serves_local_attention_gpt_neo():
     )["params"]
 
     def apply_fn(p, input_ids, attention_mask=None, position_ids=None,
-                 cache=None, cache_index=None, last_only=False,
-                 skip_heads=False):
+                 cache=None, cache_index=None, last_only=False):
         return model.apply(
             {"params": p}, input_ids, attention_mask=attention_mask,
             position_ids=position_ids, cache=cache,
             cache_index=cache_index, last_only=last_only,
-            skip_heads=skip_heads,
         )
 
     gen = GenerationConfig(

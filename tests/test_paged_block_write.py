@@ -116,7 +116,7 @@ def test_a_block_write_holds_the_by_position_writes_bits(h_kv, call, dummies):
         assert took in ({"blocks": 0, "positions": 1}, {"blocks": 0, "positions": 0})  # (0: traced already)
         (got_k, got_v, got_new), took = _paths(lambda: by_block(got_rows, k, v, at))
         assert took in ({"blocks": 1, "positions": 0}, {"blocks": 0, "positions": 0})
-        assert "first_block" in got_new and int(got_new.pop("first_block")) == first // BS
+        assert "first_block" not in got_new  # a promise is about one call
         got_rows = got_new
         # the view the call attends over, and every pool, bit for bit
         np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
@@ -169,8 +169,8 @@ def test_the_promise_marks_a_groups_layers_of_keys_alone():
     """A hybrid cache: a group's layer of keys, a state layer, a group's
     layer of keys with a tail beside them, and a layer of keys that is no
     group's. ``starting_at_block`` marks the two group layers; the key
-    changes no layer's kind, reaches the write through ``split_tail`` and
-    comes back with the written layer, beside ``slot_ids``."""
+    changes no layer's kind, reaches the write through ``split_tail`` and,
+    a promise about one call, does not come back with the written layer."""
     slot_ids, tables = _group(1)
     A = slot_ids.shape[0]
     keys = dict(_pool(2), block_tables=tables, slot_ids=slot_ids)
@@ -190,7 +190,7 @@ def test_the_promise_marks_a_groups_layers_of_keys_alone():
     traced = jax.jit(lambda kv, c: kc.paged_write_read(kv, k, v, c * 128, jnp.bfloat16, view_len=Q))
     (_, _, new), took = _paths(lambda: traced(kv, jnp.int32(1)))
     assert took == {"blocks": 1, "positions": 0}
-    assert set(new) == set(kv) and int(new["first_block"]) == 8
+    assert set(new) == set(kv) - {"first_block"}
     # what the model hands back: the written keys with the stepped tail
     assert kc.cache_kind(dict(new, **tail)) == kc.cache_kind(declared[2])
 
@@ -306,10 +306,10 @@ def _engine(kv, block_size, prefill_chunk, pool_blocks=0):
     cfg, model, _ = _model(kv)
 
     def apply_fn(p, input_ids, attention_mask=None, position_ids=None, cache=None,
-                 cache_index=None, last_only=False, skip_heads=False):
+                 cache_index=None, last_only=False):
         return model.apply(
             {"params": p}, input_ids, attention_mask=attention_mask, position_ids=position_ids,
-            cache=cache, cache_index=cache_index, last_only=last_only, skip_heads=skip_heads,
+            cache=cache, cache_index=cache_index, last_only=last_only,
         )
 
     gen = GenerationConfig(max_new_tokens=ER, min_new_tokens=1, eos_token_id=EOS, pad_token_id=EOS, do_sample=True)
@@ -317,19 +317,18 @@ def _engine(kv, block_size, prefill_chunk, pool_blocks=0):
         apply_fn=apply_fn, init_cache_fn=functools.partial(init_cache, cfg), gen_config=gen,
         query_length=EQ, vocab_size=VOCAB, num_slots=4, admit_width=2, harvest_width=2,
         block_size=block_size, prefix_pool_blocks=pool_blocks, prefill_chunk=prefill_chunk,
-        prefill_chunks_per_pump=2,  # builds prefill_chunks and prefill_finish too
     )
 
 
 # (kv, requested block size, requested chunk, shared-prefix blocks) -> the share a program's forwards observe
 ENGINES = {
-    "whole_blocks": (("bfloat16", 4, 4, 0), {"prefill": 1.0, "prefill_chunk": 1.0, "prefill_finish": 1.0}),
+    "whole_blocks": (("bfloat16", 4, 4, 0), {"prefill": 1.0, "prefill_chunk": 1.0}),
     # a user's chunk of 2 columns under blocks of 4: the chunks by position, the whole prompt by block
-    "a_chunk_inside_a_block": (("bfloat16", 4, 2, 0), {"prefill": 1.0, "prefill_chunk": 0.0, "prefill_finish": 0.0}),
+    "a_chunk_inside_a_block": (("bfloat16", 4, 2, 0), {"prefill": 1.0, "prefill_chunk": 0.0}),
     # blocks of 12 tile the capacity of 24 and neither the prompt nor a chunk
-    "blocks_that_tile_nothing": (("bfloat16", 12, 4, 0), {"prefill": 0.0, "prefill_chunk": 0.0, "prefill_finish": 0.0}),
-    "an_int8_pool": (("int8", 4, 4, 0), {"prefill": 0.0, "prefill_chunk": 0.0, "prefill_finish": 0.0}),
-    "a_shared_prefix_pool": (("bfloat16", 4, 4, 3), {"prefill": 0.0, "prefill_chunk": 0.0, "prefill_finish": 0.0}),
+    "blocks_that_tile_nothing": (("bfloat16", 12, 4, 0), {"prefill": 0.0, "prefill_chunk": 0.0}),
+    "an_int8_pool": (("int8", 4, 4, 0), {"prefill": 0.0, "prefill_chunk": 0.0}),
+    "a_shared_prefix_pool": (("bfloat16", 4, 4, 3), {"prefill": 0.0, "prefill_chunk": 0.0}),
 }
 
 
@@ -337,7 +336,7 @@ ENGINES = {
 def test_the_engine_observes_what_its_programs_trace(case):
     """``_block_write_share`` is the predicate's answer on the engine's own
     shapes, a program: each program's traced write sites count the same
-    path, ``n_layer`` of them (the scan form: one traced body)."""
+    path, ``n_layer`` of them."""
     (kv, bs, chunk, pool_blocks), want = ENGINES[case]
     eng = _engine(kv, bs, chunk, pool_blocks)
     assert eng._block_write_share == want
@@ -348,16 +347,13 @@ def test_the_engine_observes_what_its_programs_trace(case):
     maps = [i32(A, nb), i32(A, nb)] if pool_blocks else []
     head = [sds(params), jax.eval_shape(eng._make_state), i32(A), i32(A, EQ), i32(A, EQ)]
     seeds = [i32(A), i32(A), jax.ShapeDtypeStruct((2,), jnp.uint32)]
-    n_scan = eng.n_prefill_chunks - 1
     programs = {
         "prefill": (eng.prefill_jit, head + seeds + maps),
         "prefill_chunk": (eng.prefill_chunk_jit, head + seeds + [i32()] + maps),
-        "prefill_chunks": (eng.prefill_chunks_jit, head + [i32(A), jax.ShapeDtypeStruct((n_scan,), jnp.bool_)] + maps),
-        "prefill_finish": (eng.prefill_finish_jit, head + seeds + maps),
     }
     for name, (program, args) in programs.items():
         _, took = _paths(lambda: program.lower(*args))
-        by_block = want["prefill_chunk" if name == "prefill_chunks" else name] == 1.0
+        by_block = want[name] == 1.0
         assert took == {"blocks": 2 * by_block, "positions": 2 * (not by_block)}, name
     # the decode step's one position a slot stays by position
     _, took = _paths(lambda: eng.decode_step_jit.lower(sds(params), jax.eval_shape(eng._make_state)))

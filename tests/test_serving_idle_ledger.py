@@ -31,7 +31,7 @@ from trlx_tpu.telemetry import tracer as tracer_mod
 U = 2.0 ** -10  # the clock's unit, s; U * 1000 is exact in a double
 PROGRAMS = (
     "decode_step_jit", "verify_step_jit", "prefill_jit", "prefill_chunk_jit",
-    "prefill_chunks_jit", "prefill_finish_jit", "refill_jit", "release_jit",
+    "refill_jit", "release_jit",
 )
 
 

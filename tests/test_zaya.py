@@ -427,10 +427,10 @@ def engine(prefill_chunk=0, chunks_per_pump=0):
     params = dict(params, transformer=model_and_params()[2])
 
     def apply_fn(p, input_ids, attention_mask=None, position_ids=None, cache=None,
-                 cache_index=None, last_only=False, skip_heads=False):
+                 cache_index=None, last_only=False):
         return model.apply({"params": p}, input_ids, attention_mask=attention_mask,
                            position_ids=position_ids, cache=cache, cache_index=cache_index,
-                           last_only=last_only, skip_heads=skip_heads)
+                           last_only=last_only)
 
     gen = GenerationConfig(max_new_tokens=R, min_new_tokens=1, eos_token_id=EOS,
                            pad_token_id=EOS, do_sample=True)

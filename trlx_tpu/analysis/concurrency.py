@@ -1129,13 +1129,11 @@ def _tiny_engine_parts():
     )["params"]
 
     def apply_fn(p, input_ids, attention_mask=None, position_ids=None,
-                 cache=None, cache_index=None, last_only=False,
-                 skip_heads=False):
+                 cache=None, cache_index=None, last_only=False):
         return model.apply(
             {"params": p}, input_ids, attention_mask=attention_mask,
             position_ids=position_ids, cache=cache,
             cache_index=cache_index, last_only=last_only,
-            skip_heads=skip_heads,
         )
 
     engine = ContinuousBatchingEngine(

@@ -808,14 +808,14 @@ def _trace_chunked_prefill_programs(
 ) -> List[TracedProgram]:
     """Trace the CHUNKED prefill variant (``rollout.prefill_chunk > 0``,
     docs/inference.md "Chunked prefill"): the same engine geometry with
-    the monolithic admission prefill replaced by the
-    ``prefill_chunks`` scan (lax.cond-gated block-aligned prompt-column
-    chunks) plus the always-run ``prefill_finish`` program. Separate
-    subjects with their own resource-budget entries — the default
-    engine's ``engine_prefill`` stays byte-identical, and the engine-7
-    FLOP count pins the chunked pair strictly below the monolithic
-    entry at the audit shape (attention runs on the prompt-wide view,
-    never the full Q+R capacity).
+    the monolithic admission prefill replaced by a host loop over
+    ``prefill_chunk``, the one program that forwards a block-aligned
+    prompt-column chunk, the final one included. A separate subject
+    with its own resource-budget entry — the default engine's
+    ``engine_prefill`` stays byte-identical, and the engine-7 FLOP
+    count pins a group's ``Q // W`` chunk forwards strictly below the
+    monolithic entry at the audit shape (attention runs on the
+    prompt-wide view, never the full Q+R capacity).
     """
     import jax
     import jax.numpy as jnp
@@ -849,7 +849,6 @@ def _trace_chunked_prefill_programs(
     state_sds = jax.eval_shape(engine._make_state)
     params_sds = _sds(trainer.state.params)
     A, Q, nb = engine.admit_width, engine.Q, engine.n_blocks
-    n_scan = engine.n_prefill_chunks - 1
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     key_sds = jax.ShapeDtypeStruct((2,), jnp.uint32)
     state_sh = engine.state_sharding()
@@ -857,83 +856,37 @@ def _trace_chunked_prefill_programs(
     params_sh = trainer.state_shardings.params
     suffix = "_shared" if shared else ""
 
-    chunks_args = (
+    args = (
         params_sds, state_sds, i32(A), i32(A, Q), i32(A, Q), i32(A),
-        jax.ShapeDtypeStruct((max(1, n_scan),), jnp.bool_),
+        i32(A), key_sds, i32(),
     )
-    chunks_prefixes = (
+    prefixes = (
         "params", "state", "slots", "prompt_ids", "prompt_mask",
-        "turns", "need",
+        "rows", "turns", "phase_key", "chunk",
     )
-    chunks_shardings = (
-        params_sh, state_sh, None, batch_sh, batch_sh, None, None,
-    )
-    finish_args = (
-        params_sds, state_sds, i32(A), i32(A, Q), i32(A, Q), i32(A),
-        i32(A), key_sds,
-    )
-    finish_prefixes = (
-        "params", "state", "slots", "prompt_ids", "prompt_mask",
-        "rows", "turns", "phase_key",
-    )
-    finish_shardings = (
+    shardings = (
         params_sh, state_sh, None, batch_sh, batch_sh, None, None, None,
+        None,
     )
     if shared:
-        chunks_args += (i32(A, nb), i32(A, nb))
-        chunks_prefixes += ("shared_map", "publish_map")
-        chunks_shardings += (None, None)
-        finish_args += (i32(A, nb), i32(A, nb))
-        finish_prefixes += ("shared_map", "publish_map")
-        finish_shardings += (None, None)
+        args += (i32(A, nb), i32(A, nb))
+        prefixes += ("shared_map", "publish_map")
+        shardings += (None, None)
 
-    out: List[TracedProgram] = []
-    if engine.prefill_chunks_jit is not None and n_scan > 0:
-        out.append(
-            TracedProgram(
-                subject=f"{kind}.engine_prefill_chunked{suffix}",
-                closed_jaxpr=jax.make_jaxpr(engine.prefill_chunks_jit)(
-                    *chunks_args
-                ),
-                mesh_axes=axes,
-                input_paths=flat_input_paths(
-                    *chunks_args, prefixes=chunks_prefixes
-                ),
-                mesh_shape=mesh_shape,
-                input_divisors=flat_sharding_divisors(
-                    chunks_args, chunks_shardings
-                ),
-                input_sharded_dims=flat_sharded_dims(
-                    chunks_args, chunks_shardings
-                ),
-                def_site=callable_def_site(engine.prefill_chunks_jit),
-                jit_fn=engine.prefill_chunks_jit,
-                example_args=chunks_args,
-            )
-        )
-    out.append(
+    return [
         TracedProgram(
-            subject=f"{kind}.engine_prefill_finish{suffix}",
-            closed_jaxpr=jax.make_jaxpr(engine.prefill_finish_jit)(
-                *finish_args
-            ),
+            subject=f"{kind}.engine_prefill_chunk{suffix}",
+            closed_jaxpr=jax.make_jaxpr(engine.prefill_chunk_jit)(*args),
             mesh_axes=axes,
-            input_paths=flat_input_paths(
-                *finish_args, prefixes=finish_prefixes
-            ),
+            input_paths=flat_input_paths(*args, prefixes=prefixes),
             mesh_shape=mesh_shape,
-            input_divisors=flat_sharding_divisors(
-                finish_args, finish_shardings
-            ),
-            input_sharded_dims=flat_sharded_dims(
-                finish_args, finish_shardings
-            ),
-            def_site=callable_def_site(engine.prefill_finish_jit),
-            jit_fn=engine.prefill_finish_jit,
-            example_args=finish_args,
+            input_divisors=flat_sharding_divisors(args, shardings),
+            input_sharded_dims=flat_sharded_dims(args, shardings),
+            def_site=callable_def_site(engine.prefill_chunk_jit),
+            jit_fn=engine.prefill_chunk_jit,
+            example_args=args,
         )
-    )
-    return out
+    ]
 
 
 def _trace_serving_engine_programs(

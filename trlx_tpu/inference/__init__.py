@@ -129,13 +129,13 @@ class RolloutEngineConfig:
         ``per_row_rng: true``.
     :param prefill_chunk: chunked-prefill width in prompt columns
         (docs/inference.md "Chunked prefill"). ``> 0`` replaces the
-        engine's monolithic admission prefill with a scan over
-        block-aligned prompt-column chunks whose ``lax.cond`` skips
-        chunks no admitted row needs — leading all-pad columns of
-        left-padded prompts and blocks served from the shared-prefix
-        pool — so prefill compute scales with the group's real prompt
-        length, and prefix sharing saves prefill FLOPs, not just HBM
-        traffic. Rounded to a block-aligned divisor of the query length
+        engine's monolithic admission prefill with one dispatch a
+        block-aligned prompt-column chunk, skipping the chunks no
+        admitted row needs — leading all-pad columns of left-padded
+        prompts and blocks served from the shared-prefix pool — so
+        prefill compute scales with the group's real prompt length,
+        and prefix sharing saves prefill FLOPs, not just HBM traffic.
+        Rounded to a block-aligned divisor of the query length
         (``ops/kv_cache.py::choose_prefill_chunk``). Chunked and
         monolithic prefill are token/mask-bitwise-identical
         (logprobs/values at the engine's established bf16 resolution).

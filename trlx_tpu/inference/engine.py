@@ -221,12 +221,12 @@ class EngineStats:
         default_factory=lambda: dict.fromkeys(STARVED_PARTS, 0.0)
     )
     # chunked prefill (rollout.prefill_chunk > 0): chunks actually RUN
-    # (the finish chunk included) and prompt columns whose forward was
+    # (the final chunk included) and prompt columns whose forward was
     # skipped (leading pad + pool-covered shared blocks). What one
     # skipped column would have cost is asked of the engine when
-    # ``prefill_flops_saved`` is READ (an abstract trace of the chunked
-    # program: 8 s of host time at pythia-1.4b's size, which PR 30 found
-    # inside a serving window when the first skip priced it)
+    # ``prefill_flops_saved`` is READ (an abstract trace of the chunk
+    # program: seconds of host time at pythia-1.4b's size, which PR 30
+    # found inside a serving window when the first skip priced it)
     prefill_chunks: int = 0
     prefill_cols_skipped: int = 0
     # groups a chunked engine forwarded whole (``prefill_min_skip_share``):
@@ -376,11 +376,11 @@ class ContinuousBatchingEngine:
         (``rollout.prefill_chunk``; rounded by
         :func:`~trlx_tpu.ops.kv_cache.choose_prefill_chunk` to a
         block-aligned divisor of Q). ``> 0`` replaces the monolithic
-        admission prefill with a scan over block-aligned prompt-column
-        chunks, each wrapped in a ``lax.cond`` that SKIPS the forward
-        when no row in the admit group needs it — leading all-pad
-        columns of left-padded prompts (the mirror of PR-3's segmented
-        decode early-exit: compute scales with
+        admission prefill with a host loop over block-aligned
+        prompt-column chunks, one ``prefill_chunk`` dispatch each, that
+        SKIPS the chunks no row in the admit group needs — leading
+        all-pad columns of left-padded prompts (the mirror of PR-3's
+        segmented decode early-exit: compute scales with
         ``ceil(max_real_len/chunk)`` instead of Q) and blocks served
         read-only from the shared-prefix pool (prefix sharing becomes a
         prefill-FLOP win, not just an HBM one). Chunk forwards attend a
@@ -566,15 +566,6 @@ class ContinuousBatchingEngine:
         self._prefill_kwargs = (
             {"last_only": True} if "last_only" in fn_params else {}
         )
-        # non-final prefill chunks only want the KV-cache side effect:
-        # an apply_fn supporting ``skip_heads`` pays zero LM/value-head
-        # FLOPs per chunk (models/heads.py); otherwise fall back to the
-        # single-row last_only head
-        self._chunk_kwargs = (
-            {"skip_heads": True}
-            if "skip_heads" in fn_params
-            else dict(self._prefill_kwargs)
-        )
         self._param_shardings = param_shardings
         self._cache_sharding = cache_sharding
         self._cache_gb = self._measure_cache()
@@ -591,8 +582,8 @@ class ContinuousBatchingEngine:
         self._busy_rows: Dict[int, int] = {}  # slot -> row index
         self._done_slots: List[int] = []
         # chunked prefill: the admission group currently mid-prefill
-        # (slots reserved, some chunk windows dispatched) — the serving
-        # pump advances it by at most ``prefill_chunks_per_pump`` chunk
+        # (slots reserved, some chunks dispatched) — the serving pump
+        # advances it by at most ``prefill_chunks_per_pump`` chunk
         # forwards per iteration; drive() completes it inline
         self._inflight_admission: Optional[Dict[str, Any]] = None
         self._chunk_flops: Optional[float] = None  # lazy exact per-chunk cost
@@ -1207,41 +1198,8 @@ class ContinuousBatchingEngine:
             return new_state, done, tokens_bt, acc_bt
 
         # ------------- chunked prefill (rollout.prefill_chunk) ------------- #
-        # The monolithic `prefill` above pays full prompt-capacity
-        # attention FLOPs for every admitted row. These two programs
-        # replace it when prefill_chunk > 0:
-        #
-        # - `prefill_chunks`: lax.scan over the first n_chunks-1
-        #   block-aligned prompt-column chunks, each under a lax.cond
-        #   gated by the host-computed `need` vector — the run branch
-        #   forwards W columns (heads skipped) and writes their KV
-        #   through the block tables; the skip branch is the identity.
-        #   With LEFT-padded prompts the skippable chunks are the
-        #   LEADING ones (all-pad columns before the group's longest
-        #   row starts, and blocks served read-only from the shared
-        #   prefix pool), so this is the mirror of the segmented
-        #   decode's early-exit tail: compute scales with
-        #   ceil(max_real_len / W), not Q.
-        # - `prefill_finish`: the final chunk, always run (every
-        #   left-padded row's last real column lives there), producing
-        #   logits_last/value_last and seeding the slot fields.
-        #
-        # Both pass the PROMPT-WIDE mask (width Q, not capacity) as the
-        # attention view (ops/attention.py mask-width contract): prompt
-        # queries never attend the decode region, whose masked columns
-        # carry exactly-zero softmax weight in the monolithic program —
-        # dropping them is bitwise-safe for tokens/masks and shrinks the
-        # static attention FLOPs from Q·(Q+R) to Q·Q even before any
-        # chunk is skipped. Skipped chunks leave their cache positions
-        # zero; every read of those positions is masked (pad) or
-        # overlaid from the shared pool, and a masked column's softmax
-        # weight underflows to exactly 0.0 — so chunked and monolithic
-        # prefill agree bitwise on tokens/masks (logprobs/values at the
-        # established bf16 resolution; tests/test_chunked_prefill.py).
         W = self.prefill_chunk
         n_pc = self.n_prefill_chunks
-        n_scan_chunks = max(0, n_pc - 1)
-        chunk_kwargs = self._chunk_kwargs
 
         def chunk_cache(cache, c):
             """The group's cache as chunk ``c``'s forward is handed it:
@@ -1252,66 +1210,6 @@ class ContinuousBatchingEngine:
             if W % bs:
                 return cache
             return starting_at_block(cache, c * (W // bs))
-
-        def chunk_forward(params, cache, prompt_ids, prompt_mask,
-                          positions, c):
-            """Non-final chunk ``c`` forwarded against ``cache`` (heads
-            skipped); returns the cache with the chunk's KV written."""
-            ids_c = jax.lax.dynamic_slice_in_dim(
-                prompt_ids, c * W, W, axis=1
-            )
-            pos_c = jax.lax.dynamic_slice_in_dim(
-                positions, c * W, W, axis=1
-            )
-            out = apply_fn(
-                params,
-                ids_c,
-                attention_mask=prompt_mask,  # Q-wide view
-                position_ids=pos_c,
-                cache=chunk_cache(cache, c),
-                cache_index=c * W,
-                **chunk_kwargs,
-            )
-            return out["cache"]
-
-        @jax.named_scope("prefill")
-        def prefill_chunks(
-            params,
-            state: EngineState,
-            slot_ids,  # [A] int32; num_slots = dummy (writes drop)
-            prompt_ids,  # [A, Q] int32 left-padded
-            prompt_mask,  # [A, Q] int32
-            table_turns,  # [A] int32 block-table rotation per slot
-            need,  # [n_scan_chunks] bool — host plan ∩ pump window
-            shared_map=None,  # [A, nb] int32 (sharing engines only)
-            publish_map=None,
-        ) -> EngineState:
-            cache_in = group_cache(
-                state, slot_ids, table_turns, shared_map, publish_map
-            )
-            positions = jnp.clip(
-                jnp.cumsum(prompt_mask, axis=-1) - 1, 0, None
-            )
-
-            def body(cache, c):
-                def run(cch):
-                    return chunk_forward(
-                        params, cch, prompt_ids, prompt_mask, positions, c
-                    )
-
-                return jax.lax.cond(need[c], run, lambda cch: cch, cache), None
-
-            # (the carry holds the chunk's promise from the start: a scan's
-            # carry keeps one structure)
-            cache_in, _ = jax.lax.scan(
-                body, chunk_cache(cache_in, 0), jnp.arange(n_scan_chunks)
-            )
-            return dataclasses.replace(
-                state,
-                cache=pin_cache(
-                    land_group_cache(state, slot_ids, cache_in)
-                ),
-            )
 
         def seed_group(
             state, seed_ids, new_cache, prompt_ids, prompt_mask,
@@ -1378,17 +1276,47 @@ class ContinuousBatchingEngine:
             shared_map=None,
             publish_map=None,
         ) -> EngineState:
-            """Chunk ``c`` in a straight line, whichever it is: one
-            program for every forward of an admission that goes one
-            chunk a pump (a server builds its programs before it takes
-            traffic, and each costs 2-3 s of tracing there: PERF.md
-            section 6, PR 30). The final chunk seeds the group's slots as
-            ``prefill_finish`` does; any other writes its KV alone: its
-            seeds go to the out-of-bounds slot and drop, and its heads
-            see one column a row. No scan and no ``lax.cond``: inside the
-            scan's ``while`` the compiler converts float32 served weights
-            to bf16 whole and holds 3.3x the temporaries, 47.7 ms a
-            forward against 38.3 in pythia-1.4b's serving cell."""
+            """Chunk ``c`` of the group's prompt columns, whichever it is:
+            the one program of a chunked admission, which the host
+            dispatches once for every chunk some row needs and once for
+            the final chunk (``_advance_admission``). With LEFT-padded
+            prompts the chunks nobody needs are the LEADING ones (all-pad
+            columns before the group's longest row starts, blocks served
+            read-only from the shared-prefix pool), so compute scales
+            with ``ceil(max_real_len / W)``, not Q.
+
+            Why it agrees with the monolithic ``prefill`` bitwise on
+            tokens and masks (log-probabilities and values at the
+            established bf16 resolution; tests/test_chunked_prefill.py):
+            the forward is handed the PROMPT-WIDE mask (width Q, not the
+            capacity) as its attention view (``ops/attention.py``'s
+            mask-width contract). Prompt queries never attend the decode
+            region, whose masked columns carry exactly-zero softmax
+            weight in the monolithic program, so dropping them is exact
+            and shrinks the static attention FLOPs from Q*(Q+R) to Q*Q
+            before any chunk is skipped. A skipped chunk leaves its cache
+            positions zero; every read of them is masked (pad) or
+            overlaid from the shared pool, and a masked column's softmax
+            weight underflows to exactly 0.0.
+
+            Why a straight line, and no ``lax.scan`` of ``lax.cond``s
+            over the chunks: inside a scan's ``while`` the chip's
+            compiler converts float32 served weights to bf16 whole and
+            holds 3.3x the temporaries, 47.7 ms a forward against 38.3
+            in pythia-1.4b's serving cell (PERF.md section 6, PR 30).
+            (Where every program copies its pools whole, as at gpt2's
+            64-wide heads, a dispatch a chunk pays those copies a chunk
+            and measured slower under ``drive()`` than a scan did: the
+            copies are the fault there, PERF.md section 6, PR 52.)
+
+            Why the final chunk is this program too: a server builds its
+            programs before it takes traffic, and each costs 2-3 s of
+            tracing there. The final chunk always runs (every
+            left-padded row's last real column lives there) and seeds
+            the group's slots from its last column's logits and value;
+            any other chunk writes its KV alone: its seeds go to the
+            out-of-bounds slot and drop, and its heads see one column a
+            row (``last_only``)."""
             cache_in = group_cache(
                 state, slot_ids, table_turns, shared_map, publish_map
             )
@@ -1413,47 +1341,11 @@ class ContinuousBatchingEngine:
                 prompt_ids, prompt_mask, row_index, phase_key, out,
             )
 
-        @jax.named_scope("prefill")
-        def prefill_finish(
-            params,
-            state: EngineState,
-            slot_ids,
-            prompt_ids,
-            prompt_mask,
-            row_index,
-            table_turns,
-            phase_key,
-            shared_map=None,
-            publish_map=None,
-        ) -> EngineState:
-            cache_in = group_cache(
-                state, slot_ids, table_turns, shared_map, publish_map
-            )
-            positions = jnp.clip(
-                jnp.cumsum(prompt_mask, axis=-1) - 1, 0, None
-            )
-            off = Q - W  # static: the final chunk's column offset
-            out = apply_fn(
-                params,
-                prompt_ids[:, off:],
-                attention_mask=prompt_mask,  # Q-wide view
-                position_ids=positions[:, off:],
-                cache=cache_in,
-                cache_index=off,
-                **prefill_kwargs,
-            )
-            return seed_group(
-                state,
-                slot_ids,
-                land_group_cache(state, slot_ids, out["cache"]),
-                prompt_ids, prompt_mask, row_index, phase_key, out,
-            )
-
         # what ``engine/prefill_block_write_share`` observes a dispatched
         # forward: the write's own predicate (``writes_whole_blocks``) on
         # each program's call, asked in shapes alone of the cache the
         # forward is handed (a chunk's index is traced and comes with the
-        # chunk's promise; the two others' are Python integers)
+        # chunk's promise; the whole forward's is a Python 0)
         A = self.admit_width
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
 
@@ -1482,11 +1374,11 @@ class ContinuousBatchingEngine:
 
         self._block_write_share = {"prefill": block_write_share(group_sds, Q, 0)}
         if W > 0:
-            self._block_write_share.update(
-                prefill_chunk=block_write_share(chunk_sds, W, i32()),
-                prefill_finish=block_write_share(group_sds, W, Q - W),
+            self._block_write_share["prefill_chunk"] = block_write_share(
+                chunk_sds, W, i32()
             )
 
+        self.prefill_chunk_jit = None
         if self.mesh is not None and self._param_shardings is not None:
             from trlx_tpu.parallel.mesh import (
                 batch_sharding,
@@ -1498,9 +1390,7 @@ class ContinuousBatchingEngine:
             # declare their mesh (long prompts route attention to the
             # flash kernels, which need it — parallel/mesh.py::traced_on)
             prefill = traced_on(self.mesh, prefill)
-            prefill_chunks = traced_on(self.mesh, prefill_chunks)
             prefill_chunk = traced_on(self.mesh, prefill_chunk)
-            prefill_finish = traced_on(self.mesh, prefill_finish)
             verify_step = traced_on(self.mesh, verify_step)
             state_sh = self.state_sharding()
             batch_sh = batch_sharding(self.mesh)
@@ -1546,64 +1436,23 @@ class ContinuousBatchingEngine:
                 out_shardings=state_sh,
                 donate_argnums=(0,),
             )
+            if W > 0:
+                # ``prefill``'s arguments, then the chunk's index
+                # (replicated) ahead of the sharing maps
+                self.prefill_chunk_jit = jax.jit(
+                    prefill_chunk,
+                    in_shardings=tuple(
+                        prefill_in[:8] + [rep] + prefill_in[8:]
+                    ),
+                    out_shardings=state_sh,
+                    donate_argnums=(1,),
+                )
         else:
             self.prefill_jit = jax.jit(prefill, donate_argnums=(1,))
             self.decode_step_jit = jax.jit(decode_step, donate_argnums=(1,))
             self.refill_jit = jax.jit(refill, donate_argnums=(0,))
             self.release_jit = jax.jit(release, donate_argnums=(0,))
-
-        self.prefill_chunks_jit = None
-        self.prefill_chunk_jit = None
-        self.prefill_finish_jit = None
-        if self.prefill_chunk > 0:
-            if self.mesh is not None and self._param_shardings is not None:
-                from trlx_tpu.parallel.mesh import batch_sharding, replicated
-
-                state_sh = self.state_sharding()
-                batch_sh = batch_sharding(self.mesh)
-                rep = replicated(self.mesh)
-                chunks_in = [
-                    self._param_shardings, state_sh, rep, batch_sh,
-                    batch_sh, rep, rep,
-                ]
-                finish_in = [
-                    self._param_shardings, state_sh, rep, batch_sh,
-                    batch_sh, rep, rep, rep,
-                ]
-                if sharing:
-                    chunks_in += [rep, rep]
-                    finish_in += [rep, rep]
-                if n_scan_chunks > 0:
-                    self.prefill_chunks_jit = jax.jit(
-                        prefill_chunks,
-                        in_shardings=tuple(chunks_in),
-                        out_shardings=state_sh,
-                        donate_argnums=(1,),
-                    )
-                self.prefill_finish_jit = jax.jit(
-                    prefill_finish,
-                    in_shardings=tuple(finish_in),
-                    out_shardings=state_sh,
-                    donate_argnums=(1,),
-                )
-                # the finish program's arguments, then the chunk's index
-                # (replicated) ahead of the sharing maps
-                self.prefill_chunk_jit = jax.jit(
-                    prefill_chunk,
-                    in_shardings=tuple(
-                        finish_in[:8] + [rep] + finish_in[8:]
-                    ),
-                    out_shardings=state_sh,
-                    donate_argnums=(1,),
-                )
-            else:
-                if n_scan_chunks > 0:
-                    self.prefill_chunks_jit = jax.jit(
-                        prefill_chunks, donate_argnums=(1,)
-                    )
-                self.prefill_finish_jit = jax.jit(
-                    prefill_finish, donate_argnums=(1,)
-                )
+            if W > 0:
                 self.prefill_chunk_jit = jax.jit(
                     prefill_chunk, donate_argnums=(1,)
                 )
@@ -1887,9 +1736,9 @@ class ContinuousBatchingEngine:
         being PUBLISHED into the pool (the donor must compute what it
         publishes, pad columns included — readers gather the donor's
         bits). Leading all-pad chunks of a left-padded group and
-        fully-pool-covered shared chunks come out un-needed. The same
-        vector gates the jitted scan's ``lax.cond`` — host and device
-        share one plan, so the skip accounting is transfer-free."""
+        fully-pool-covered shared chunks come out un-needed. The plan is
+        the host's alone: a chunk nobody needs is never dispatched, so
+        the skip accounting is transfer-free."""
         Q, W = self.Q, self.prefill_chunk
         mask = np.asarray(prompt_mask)
         first_real = Q - mask.sum(axis=1)
@@ -1908,8 +1757,8 @@ class ContinuousBatchingEngine:
     def _begin_admission(self) -> None:
         """Reserve slots for the next ``admit_width`` group and stage its
         host arrays; the device dispatch happens in
-        :meth:`_advance_admission` (one monolithic prefill call, or
-        need-gated chunk windows plus the finish program)."""
+        :meth:`_advance_admission` (one monolithic prefill call, or one
+        ``prefill_chunk`` call a needed chunk and the final chunk)."""
         sharing = self.prefix_pool_blocks > 0
         nb_prompt = self.Q // self.block_size  # shareable prompt blocks
         with telemetry.span("collect/admit", force=True):
@@ -1996,7 +1845,7 @@ class ContinuousBatchingEngine:
             "t_admit": telemetry.monotonic(),
         }
         if self.prefill_min_skip_share:
-            # the finish chunk always runs, whatever ``need`` says of it
+            # the final chunk always runs, whatever ``need`` says of it
             need = self._inflight_admission["need"]
             skippable = need.size - 1 - np.count_nonzero(need[:-1])
             self._inflight_admission["whole"] = bool(
@@ -2041,82 +1890,39 @@ class ContinuousBatchingEngine:
                 adm["skipped"] = 0
             self._finalize_admission()
             return True, 1
-        n_scan = self.n_prefill_chunks - 1
-        need = adm["need"]
-        spent = 0
-        if adm["next_chunk"] < n_scan:
-            lo = adm["next_chunk"]
-            idx = [c for c in range(lo, n_scan) if need[c]]
-            if budget is not None and budget > 0 and len(idx) > budget:
-                run = idx[:budget]
-                hi = run[-1] + 1
-            else:
-                run = idx
-                hi = n_scan
-            if run:
-                with telemetry.span(
-                    "collect/prefill", force=True,
-                    admitted=adm["take"], chunks=len(run),
-                ):
-                    # a window of one chunk runs in a straight line; only
-                    # a window of several pays for the scan of conds
-                    if len(run) == 1:
-                        self._dispatch_chunk(adm, run[0], map_args)
-                    else:
-                        window = np.zeros((n_scan,), bool)
-                        window[run] = True
-                        self._observe_block_write("prefill_chunk")
-                        self._state = self.prefill_chunks_jit(
-                            self._params,
-                            self._state,
-                            jnp.asarray(adm["slot_ids"]),
-                            adm["ids"],
-                            adm["mask"],
-                            jnp.asarray(adm["turns"]),
-                            jnp.asarray(window),
-                            *map_args,
-                        )
-                self.stats.prefill_chunks += len(run)
-                spent = len(run)
-                adm["chunk_walls"].append(
-                    (run[0] * self.prefill_chunk, telemetry.monotonic())
-                )
-            adm["next_chunk"] = hi
-            if hi < n_scan or (budget is not None and spent >= budget):
-                return False, spent
-        # the finish chunk always runs: every left-padded row's last
-        # real column lives there, and it produces logits_last
-        with telemetry.span(
-            "collect/prefill", force=True,
-            admitted=adm["take"], chunks=1, finish=True,
-        ):
-            if self.prefill_chunks_per_pump == 1:
-                # one chunk a pump: one program for all of them
-                self._dispatch_chunk(adm, n_scan, map_args)
-            else:
-                self._observe_block_write("prefill_finish")
-                self._state = self.prefill_finish_jit(
-                    self._params,
-                    self._state,
-                    jnp.asarray(adm["slot_ids"]),
-                    adm["ids"],
-                    adm["mask"],
-                    jnp.asarray(adm["row_index"]),
-                    jnp.asarray(adm["turns"]),
-                    self._phase_key,
-                    *map_args,
-                )
-        self.stats.prefill_chunks += 1
-        adm["chunk_walls"].append(
-            ((self.n_prefill_chunks - 1) * self.prefill_chunk,
-             telemetry.monotonic())
-        )
-        adm["skipped"] = int(n_scan - np.count_nonzero(need[:n_scan]))
+        # the needed chunks yet to run, then the final chunk, which always
+        # runs: every left-padded row's last real column lives there, and
+        # it produces logits_last
+        need, last = adm["need"], self.n_prefill_chunks - 1
+        todo = [c for c in range(adm["next_chunk"], last) if need[c]] + [last]
+        run = todo if budget is None else todo[:budget]
+        done = run[-1] == last
+        # one span for the non-final chunks of this call, one for the final
+        parts = [(run, {})]
+        if done:
+            parts = [(run[:-1], {}), (run[-1:], {"finish": True})]
+        for part, attrs in parts:
+            if not part:
+                continue
+            with telemetry.span(
+                "collect/prefill", force=True,
+                admitted=adm["take"], chunks=len(part), **attrs,
+            ):
+                for c in part:
+                    self._dispatch_chunk(adm, c, map_args)
+            self.stats.prefill_chunks += len(part)
+            adm["chunk_walls"].append(
+                (part[0] * self.prefill_chunk, telemetry.monotonic())
+            )
+        adm["next_chunk"] = run[-1] + 1
+        if not done:
+            return False, len(run)
+        adm["skipped"] = int(last - np.count_nonzero(need[:last]))
         self.stats.prefill_cols_skipped += (
             adm["skipped"] * self.prefill_chunk
         )
         self._finalize_admission()
-        return True, spent + 1
+        return True, len(run)
 
     def _observe_block_write(self, program: str) -> None:
         """``engine/prefill_block_write_share``, once an admission forward
@@ -2223,22 +2029,16 @@ class ContinuousBatchingEngine:
 
     def _chunk_flop_cost(self) -> float:
         """Exact dot-FLOPs of ONE prefill chunk forward, read off the
-        traced chunked program with engine-7's counter
-        (``analysis/resource_audit.py::count_flops``: the scan body at
-        its cond's run branch, times one). Traced once per engine, when
-        ``stats.prefill_flops_saved`` is first read and never by an
-        admission — abstract trace only, no compilation — so
-        ``engine/prefill_flops_saved`` is a real FLOP number, not a
-        heuristic; 0.0 when tracing is unavailable."""
+        trace of the program that runs it (``prefill_chunk``) with
+        engine-7's counter (``analysis/resource_audit.py::count_flops``).
+        Traced once per engine, when ``stats.prefill_flops_saved`` is
+        first read and never by an admission — abstract trace only, no
+        compilation — so ``engine/prefill_flops_saved`` is a real FLOP
+        number, not a heuristic; 0.0 when tracing is unavailable."""
         if self._chunk_flops is not None:
             return self._chunk_flops
         self._chunk_flops = 0.0
-        n_scan = self.n_prefill_chunks - 1
-        if (
-            self.prefill_chunks_jit is None
-            or n_scan < 1
-            or self._params is None
-        ):
+        if self.prefill_chunk_jit is None or self._params is None:
             return self._chunk_flops
         try:
             from trlx_tpu.analysis.resource_audit import count_flops
@@ -2256,12 +2056,14 @@ class ContinuousBatchingEngine:
                 i32(A, Q),
                 i32(A, Q),
                 i32(A),
-                jax.ShapeDtypeStruct((n_scan,), jnp.bool_),
+                i32(A),
+                jax.ShapeDtypeStruct((2,), jnp.uint32),
+                i32(),
             ]
             if self.prefix_pool_blocks > 0:
                 args += [i32(A, self.n_blocks), i32(A, self.n_blocks)]
-            closed = jax.make_jaxpr(self.prefill_chunks_jit)(*args)
-            self._chunk_flops = count_flops(closed.jaxpr) / n_scan
+            closed = jax.make_jaxpr(self.prefill_chunk_jit)(*args)
+            self._chunk_flops = float(count_flops(closed.jaxpr))
         except Exception:  # pragma: no cover - accounting must never kill
             self._chunk_flops = 0.0
         return self._chunk_flops
@@ -2269,7 +2071,7 @@ class ContinuousBatchingEngine:
     def _admit(self) -> None:
         """Complete every possible admission inline (the drive() /
         unbudgeted-pump path): one padded prefill per ``admit_width``
-        group — monolithic, or the group's full chunk plan + finish."""
+        group — monolithic, or every chunk of the group's plan."""
         if self._inflight_admission is not None:
             self._advance_admission(None)
         while self._free and self._queue:
@@ -2302,8 +2104,8 @@ class ContinuousBatchingEngine:
         the pool is what it was. A server calls this before it takes
         traffic (after :meth:`start_phase`): a pump must never compile
         under a running stream, and which of ``prefill`` (a group
-        forwarded whole), ``prefill_chunk`` / ``prefill_chunks`` and
-        ``release`` the first prompts reach is the traffic's business."""
+        forwarded whole), ``prefill_chunk`` and ``release`` the first
+        prompts reach is the traffic's business."""
         A, Q = self.admit_width, self.Q
         slot_ids = jnp.full((A,), self.num_slots, jnp.int32)
         ids = np.full((A, Q), self.gen_config.pad_token_id, np.int32)
@@ -2329,20 +2131,6 @@ class ContinuousBatchingEngine:
                 self._params, self._state, slot_ids, ids, mask, zeros,
                 zeros, self._phase_key, jnp.zeros((), jnp.int32), *maps,
             )
-            # windows of several chunks, and the finish program after
-            # them, exist only off a budget of one
-            if self.prefill_chunks_per_pump != 1:
-                if self.prefill_chunks_jit is not None:
-                    self._state = self.prefill_chunks_jit(
-                        self._params, self._state, slot_ids, ids, mask,
-                        zeros,
-                        jnp.zeros((self.n_prefill_chunks - 1,), bool),
-                        *maps,
-                    )
-                self._state = self.prefill_finish_jit(
-                    self._params, self._state, slot_ids, ids, mask, zeros,
-                    zeros, self._phase_key, *maps,
-                )
         self._state = self.release_jit(self._state, slot_ids)
 
     def _harvest_ready(self) -> Iterator[Dict[str, Any]]:
@@ -2477,7 +2265,7 @@ class ContinuousBatchingEngine:
     def _seeded_rows(self) -> Iterable[Tuple[int, int]]:
         """``(slot, row)`` of the busy slots whose device rows are their
         row's. A slot the in-flight admission has reserved holds its
-        previous occupant's state until ``prefill_finish`` seeds it (a
+        previous occupant's state until the final chunk seeds it (a
         budget-ended occupant still reads live there), so between the
         chunk forwards of a pump-budgeted admission no token, draft or
         acceptance of such a slot belongs to the row waiting for it."""

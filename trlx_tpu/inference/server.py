@@ -253,8 +253,7 @@ class InferenceServer:
         )
 
         def apply_fn(p, input_ids, attention_mask=None, position_ids=None,
-                     cache=None, cache_index=None, last_only=False,
-                     skip_heads=False):
+                     cache=None, cache_index=None, last_only=False):
             return self.model.apply(
                 {"params": p},
                 input_ids,
@@ -263,7 +262,6 @@ class InferenceServer:
                 cache=cache,
                 cache_index=cache_index,
                 last_only=last_only,
-                skip_heads=skip_heads,
             )
 
         import functools

@@ -76,17 +76,10 @@ class CausalLMWithValueHead(nn.Module):
         cache=None,
         cache_index=None,
         last_only: bool = False,
-        skip_heads: bool = False,
     ):
         """``last_only=True`` computes logits/values only for the final
         position (sampler prefill: the [B, Q, vocab] float32 logits tensor
         for the whole prompt would be written to HBM just to read one row).
-
-        ``skip_heads=True`` computes NEITHER head: the caller only wants
-        the KV-cache side effect (the chunked prefill's non-final prompt
-        chunks — their logits/values are never read, and even the
-        ``last_only`` single-row head would pay an LM-head matmul per
-        chunk). ``logits``/``values`` are then ``None``.
         """
         out = self.backbone(
             input_ids,
@@ -94,11 +87,9 @@ class CausalLMWithValueHead(nn.Module):
             position_ids=position_ids,
             cache=cache,
             cache_index=cache_index,
-            compute_logits=not (last_only or skip_heads),
+            compute_logits=not last_only,
         )
-        if skip_heads:
-            out["values"] = None
-        elif last_only:
+        if last_only:
             h = out["hidden"][:, -1:]
             out["logits"] = self.backbone.logits(h)
             out["values"] = self.v_head(h)[..., 0]

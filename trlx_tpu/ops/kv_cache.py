@@ -1153,8 +1153,6 @@ def paged_write_read(
         new_kv["block_tables"] = tables
         if kind.rows:
             new_kv["slot_ids"] = slot_ids
-        if "first_block" in cache_kv:
-            new_kv["first_block"] = cache_kv["first_block"]
         if sharing:
             new_kv["shared_tables"] = cache_kv["shared_tables"]
             new_kv["publish_tables"] = cache_kv["publish_tables"]

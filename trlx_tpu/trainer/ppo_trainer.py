@@ -1202,7 +1202,7 @@ class PPOTrainer(BaseRLTrainer):
 
         def apply_fn(params, input_ids, attention_mask=None,
                      position_ids=None, cache=None, cache_index=None,
-                     last_only=False, skip_heads=False):
+                     last_only=False):
             return self.model.apply(
                 {"params": params},
                 input_ids,
@@ -1211,7 +1211,6 @@ class PPOTrainer(BaseRLTrainer):
                 cache=cache,
                 cache_index=cache_index,
                 last_only=last_only,
-                skip_heads=skip_heads,
             )
 
         # actor device subset (async_rl.actor_fraction < 1): the engine
