@@ -130,6 +130,46 @@ def test_cca_decode_layer_steps_its_tail_and_holds_no_copy_of_its_pool(one_chip)
     assert not re.search(r"= f32\[(%d,%d|%d),2,128\]" % (B, C, B * C), entry)
 
 
+def test_latent_decode_layer_reads_its_pool_in_stored_order(one_chip):
+    """One latent-attention sublayer of the ``serve-deepseekv3-reason``
+    decode step at published widths (64 slots x 1536 positions of one
+    576-value row, bf16; 128 heads): one row a slot written in place, the
+    absorbed read of the pool as stored. The chip's compiler takes it, no
+    float32 copy of the pool exists, and nothing re-lays the pool between
+    the write and the two products that read it: with the ``H`` queries on
+    the left of the scores' product (the grouped read at one KV head) it
+    copied the written pool position-minor, 113 MB a layer and step. What is
+    left are the two copies at the program's edges, from and to the layout
+    the runtime gives a pool whose rows are no multiple of 128 lanes
+    (position-minor, ``{1,3,2,0}``): PERF.md section 6, PR 53."""
+    from trlx_tpu.models.deepseek_v3 import DeepseekV3Attention, DeepseekV3Config, init_deepseek_v3_cache
+
+    cfg = DeepseekV3Config(num_hidden_layers=1, first_k_dense_replace=1, dtype="bfloat16", param_dtype="bfloat16")
+    B, C, n_blocks = 64, 1536, 96
+    module = DeepseekV3Attention(cfg)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+    layer = jax.eval_shape(lambda: dict(init_deepseek_v3_cache(cfg, B, C)[0], block_tables=jnp.zeros((B, n_blocks), jnp.int32)))
+    args = (sds((B, 1, cfg.hidden_size), jnp.bfloat16), sds((B, 1, 1, C), jnp.float32), sds((B, 1), jnp.int32))
+    index = sds((B,), jnp.int32)
+    params = jax.eval_shape(lambda *a: module.init(jax.random.PRNGKey(0), *a), *args, layer, index)
+
+    def step(params, x, bias, pos, layer, index):
+        return module.apply(params, x, bias, pos, layer, index)
+
+    compiled = jax.jit(step, donate_argnums=(4,)).lower(on_chip(params), *args, on_chip(layer), index).compile()
+    entry = compiled.as_text().split("\nENTRY ", 1)[1]
+    pool = r"\[%d,%d(,1)?,576\]" % (B, C)
+    assert not re.search(r"= f32" + pool, entry)
+    copies = [l for l in entry.split("\n") if re.search(r"= bf16" + pool + r"\S* (copy|transpose)\(", l)]
+    assert len(copies) <= 2, copies
+    # none of them follows the write: the scatter's result feeds the reads as it is
+    assert not [l for l in copies if "scatter" in l.split("metadata=")[-1]]
+
+
 @pytest.mark.parametrize("T,first", [(128, "traced"), (512, 0)], ids=["chunk", "whole"])
 @pytest.mark.parametrize(
     "C,H", [(640, 16), (1024, 2)], ids=["pythia_16_heads", "zaya_2_kv_heads"]

@@ -673,30 +673,50 @@ class ContinuousBatchingEngine:
 
     def _measure_cache(self) -> Dict[str, float]:
         """What the pool will hold (GB; gauges ``cache/state_gb``,
-        ``cache/kv_gb`` and ``cache/tail_gb``), from shapes alone: a state
-        layer's rows, the pools, and the rows a layer of keys keeps a slot
-        beside them (``cache_kind(...).tail``); and the refusals by-slot
-        rows bring: they cannot be rolled back to a rejected draft's column
-        or shared by prefix, and the pp runner carries KV layers only."""
+        ``cache/kv_gb``, ``cache/tail_gb`` and ``cache/latent_gb``), from
+        shapes alone: a state layer's rows, the pools of keys and values,
+        the rows a layer of keys keeps a slot beside them
+        (``cache_kind(...).tail``) and the pools of latent rows
+        (``.latent``); and the refusals they bring. By-slot rows cannot be
+        rolled back to a rejected draft's column or shared by prefix, and
+        the pp runner carries KV layers only; a latent row has no value
+        pool for ``with_pool`` to mirror, no rollback under ``verify_step``
+        and no head axis for a ``tp`` mesh, and its experts' routing is
+        built off an ``ep`` mesh only."""
         linear = jax.eval_shape(lambda: self._init_cache_fn(self.num_slots, self.capacity))
-        gb = {"state": 0.0, "kv": 0.0, "tail": 0.0}
+        gb = {"state": 0.0, "kv": 0.0, "tail": 0.0, "latent": 0.0}
         for layer in linear:
             kind = cache_kind(layer)
             for k, v in layer.items():
-                key = "state" if kind.layout == STATE else "tail" if k in kind.tail else "kv"
+                key = (
+                    "state" if kind.layout == STATE
+                    else "tail" if k in kind.tail
+                    else "latent" if kind.latent
+                    else "kv"
+                )
                 gb[key] += v.size * v.dtype.itemsize / 1e9
-        if gb["state"] or gb["tail"]:
-            pp = dict(self.mesh.shape).get("pp", 1) if self.mesh is not None else 1
-            for what, on in (
-                ("prefix_pool_blocks > 0 (a shared prefix of states)", self.prefix_pool_blocks > 0),
-                ("a speculative drafter / verify_step (a state snapshot)", self.spec_max_draft > 0),
-                ("a pp mesh", pp > 1),
-            ):
-                if on:
-                    raise ValueError(
-                        f"{what} is not built for a model with state layers "
-                        "or a tail beside its keys (ops/kv_cache.py: rows kept a slot)"
-                    )
+        axes = dict(self.mesh.shape) if self.mesh is not None else {}
+        by_slot, latent = bool(gb["state"] or gb["tail"]), bool(gb["latent"])
+        for what, on in (
+            ("prefix_pool_blocks > 0 (a shared prefix of states)", by_slot and self.prefix_pool_blocks > 0),
+            ("a speculative drafter / verify_step (a state snapshot)", by_slot and self.spec_max_draft > 0),
+            ("a pp mesh", by_slot and axes.get("pp", 1) > 1),
+        ):
+            if on:
+                raise ValueError(
+                    f"{what} is not built for a model with state layers "
+                    "or a tail beside its keys (ops/kv_cache.py: rows kept a slot)"
+                )
+        for what, on in (
+            ("prefix_pool_blocks > 0 (a shared pool of latent rows)", self.prefix_pool_blocks > 0),
+            ("a speculative drafter / verify_step (a latent row's rollback)", self.spec_max_draft > 0),
+            *((f"a {axis} mesh", axes.get(axis, 1) > 1) for axis in ("tp", "ep", "pp")),
+        ):
+            if latent and on:
+                raise ValueError(
+                    f"{what} is not built for a latent cache (ops/kv_cache.py: "
+                    "one row a position, no values)"
+                )
         self._publish_cache_gauges(gb)
         return gb
 
@@ -2536,7 +2556,7 @@ class ContinuousBatchingEngine:
         registry.gauge("engine/slot_util").set(self.stats.slot_util)
         # again: the registry may have been cleared
         registry.gauge("engine/param_gb").set(self.stats.param_gb)
-        if self._cache_gb["state"] or self._cache_gb["tail"]:
+        if self._cache_gb["state"] or self._cache_gb["tail"] or self._cache_gb["latent"]:
             self._publish_cache_gauges(self._cache_gb)
         t_done = telemetry.monotonic() if self.trace_requests else 0.0
         if rows is None:

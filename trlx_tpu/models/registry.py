@@ -199,3 +199,19 @@ def _register_builtins() -> None:
             stored_width_leaves=("conv0_weight", "conv1_bias", "wte"),
         )
     )
+    from trlx_tpu.models.deepseek_v3 import (
+        DEEPSEEK_V3_PARTITION_RULES,
+        DeepseekV3Config,
+        DeepseekV3Model,
+        init_deepseek_v3_cache,
+        no_deepseek_v3_checkpoint,
+    )
+
+    # not supports_ep: nothing trains its router (no loss is sown), and a
+    # trainer refuses an ep axis for it by name
+    register_model_family(
+        ModelFamily(
+            "deepseek_v3", DeepseekV3Config, DeepseekV3Model, DEEPSEEK_V3_PARTITION_RULES,
+            init_deepseek_v3_cache, no_deepseek_v3_checkpoint,
+        )
+    )

@@ -28,7 +28,8 @@ step) is ``dense_write_read`` / ``paged_write_read`` +
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import numbers
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -233,7 +234,9 @@ def dot_product_attention(
     learned_bias: bool = False,
     scale: Optional[float] = None,
 ) -> jax.Array:
-    """Multi-head attention; returns [B, Q, H, D].
+    """Multi-head attention; returns [B, Q, H, D] (``D`` the values' head
+    size where it differs from the scores': the XLA path takes any, the
+    flash kernels one size).
 
     ``k``/``v`` may hold fewer heads than ``q`` (grouped-query attention,
     ``H = G * H_kv``): query head ``h`` reads KV head ``h // G``, and a
@@ -305,7 +308,7 @@ def _grouped_attention(q, k, v, bias, scale):
         "bhgqk,bkhd->bqhgd", weights.astype(v.dtype), v,
         preferred_element_type=jnp.float32,
     )
-    return out.reshape(B, Q, H, D).astype(q.dtype)
+    return out.reshape(B, Q, H, v.shape[-1]).astype(q.dtype)
 
 
 def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias, scale=None):
@@ -383,10 +386,85 @@ def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias, scale=None):
     return out.reshape(B, 1, H, Dh).astype(q.dtype), new_kv
 
 
+class Latent(NamedTuple):
+    """How a latent cache's rows become keys and values (multi-head latent
+    attention, DeepSeek-V2 section 2.1): a cached row is ``[c | k_r]``,
+    ``c`` the compressed key-value latent (``w_ukv.shape[0]`` wide) and
+    ``k_r`` the one rotated key part every head shares; head ``h``'s key is
+    ``[c W_uk[h] | k_r]`` and its value ``c W_uv[h]``, ``w_ukv[:, h]``
+    being ``[W_uk[h] | W_uv[h]]`` with ``nope`` columns of the first. A
+    query head is ``[q_nope | q_rope]``, ``nope`` and ``k_r``'s width."""
+
+    w_ukv: jax.Array  # [C, H, nope + Dv], the compute dtype
+    nope: int
+
+
+def latent_attention(q, rows, bias, latent: Latent, *, scale, causal: bool = False):
+    """Attention over latent ``rows`` [B, K, 1, C + rope] in the published
+    form: every row is decompressed through ``w_ukv`` into ``H`` keys and
+    values and the heads attend as heads do. What a call of many columns
+    takes (an uncached forward over its own rows, an admission over its
+    view of the pool): the products are over ``K`` rows once, not once a
+    query."""
+    C, H = latent.w_ukv.shape[:2]
+    with jax.named_scope("mla_decompress"):
+        kv = jnp.einsum(
+            "bkc,chd->bkhd", rows[:, :, 0, :C], latent.w_ukv,
+            preferred_element_type=jnp.float32,
+        ).astype(q.dtype)
+        shared = jnp.broadcast_to(
+            rows[:, :, :, C:], rows.shape[:2] + (H, rows.shape[-1] - C)
+        )
+        k = jnp.concatenate([kv[..., : latent.nope], shared], axis=-1)
+    return dot_product_attention(
+        q, k, kv[..., latent.nope:], bias, causal=causal, scale=scale
+    )
+
+
+def _latent_absorbed_read(q, rows, bias, scale, latent: Latent):
+    """The same function with the up-projections absorbed into the query
+    and the output (``(q W_uk^T) . c = q . (c W_uk)``): ``H`` query heads
+    of ``C + rope`` over the rows as they lie, one cached head whose value
+    is the first ``C`` columns of its key. Nothing is decompressed: what a
+    one-row query takes (the decode step over a whole pool), where the
+    rows are read once and the two small products are per query.
+
+    Both products take the rows in the order they are stored, ``[B, K, C +
+    rope]``: the scores with the rows on the left (``K`` rows times the
+    ``H`` queries, ``[B, K, H]``), the values with the rows on the right
+    (summed over ``K``). Written as ``H`` queries times the rows' transpose
+    (:func:`dot_product_attention`'s grouped read at one KV head) the v5e
+    compiler re-laid the whole pool position-minor for the first product
+    and back for the second, two pool-sized copies a layer and step.
+    Softmax in float32 over ``K``, weights rounded to the compute dtype
+    where they meet the rows, as everywhere here."""
+    C, nope = latent.w_ukv.shape[0], latent.nope
+    with jax.named_scope("mla_absorbed_read"):
+        q_lat = jnp.einsum(
+            "bqhn,chn->bqhc", q[..., :nope], latent.w_ukv[..., :nope],
+            preferred_element_type=jnp.float32,
+        ).astype(q.dtype)
+        q_abs = jnp.concatenate([q_lat, q[..., nope:]], axis=-1)[:, 0]  # [B, H, C + rope]
+        stored = rows[:, :, 0, :]  # [B, K, C + rope]
+        scores = jnp.einsum(
+            "bkd,bhd->bkh", stored, q_abs, preferred_element_type=jnp.float32
+        ) * jnp.float32(scale)
+        scores = scores + jnp.swapaxes(bias[:, 0].astype(jnp.float32), 1, 2)  # [B, K, 1]
+        weights = jax.nn.softmax(scores, axis=1)
+        out = jnp.einsum(
+            "bkh,bkd->bhd", weights.astype(q.dtype), stored,
+            preferred_element_type=jnp.float32,
+        )[..., :C].astype(q.dtype)
+        return jnp.einsum(
+            "bhc,chv->bhv", out, latent.w_ukv[..., nope:],
+            preferred_element_type=jnp.float32,
+        ).astype(q.dtype)[:, None]
+
+
 def decode_attention(
     q: jax.Array,  # [B, Q, H, D]
     k_new: jax.Array,  # [B, Q, H, D]
-    v_new: jax.Array,  # [B, Q, H, D]
+    v_new: Optional[jax.Array],  # [B, Q, H, D]; None into a latent cache
     cache_kv,
     cache_index,
     bias: Optional[jax.Array],
@@ -394,6 +472,7 @@ def decode_attention(
     causal: bool = False,
     learned_bias: bool = False,
     scale: Optional[float] = None,
+    latent: Optional[Latent] = None,
 ):
     """Write this call's keys/values into ``cache_kv`` at ``cache_index``
     and attend over the cache; returns ``(out [B, Q, H, D], new_kv)``. The
@@ -433,8 +512,30 @@ def decode_attention(
       capacity axis is sharded (the sampler leaves those in the
       ``kv_buffers`` layout), and a one-token call into a paged int8 pool
       or a paged pool with a shared-prefix overlay.
+
+    A **latent** cache (``cache_kind(...).latent``: one row a position, no
+    values; ``k_new`` is the call's rows ``[B, Q, 1, C + rope]``, ``v_new``
+    None, ``latent`` says how rows become keys and values) is a paged pool
+    and takes the same paths by the same rules: ``paged`` reads the pool as
+    stored in the absorbed form (:func:`_latent_absorbed_read`),
+    ``paged_rows`` (and a paged ``generic`` call) gathers the view and
+    attends in the published form (:func:`latent_attention`) over the
+    positions the call can see: a call of ``T`` columns from a Python
+    ``cache_index`` sees ``cache_index + T`` of them and decompresses no
+    more. Outside a paged pool a latent cache is refused by name.
     """
     kind = cache_kind(cache_kv)
+    if kind.latent != (latent is not None) or (kind.latent and (
+        kind.layout != PAGED or v_new is not None or learned_bias or causal or bias is None
+    )):
+        raise ValueError(
+            "a latent cache (one row a position, no values) is read through a "
+            "paged pool (rollout.engine: continuous, InferenceServer) with "
+            "`latent` given, v_new=None and an explicit bias, and a cache of "
+            f"keys and values without; got layout {kind.layout!r}, "
+            f"latent={latent is not None} over a cache that is "
+            f"{'latent' if kind.latent else 'keys and values'}"
+        )
     # attend over the buffer VIEW the bias was built for: a bias narrower
     # than capacity (the chunked prefill's prompt-only mask) narrows the
     # view to match (0 = the whole capacity)
@@ -459,7 +560,19 @@ def decode_attention(
             cache_kv, k_new, v_new, cache_index, q.dtype, as_stored=True
         )
         bias = stored_order_bias(cache_kv["block_tables"], bias)
+        if latent is not None:
+            return _latent_absorbed_read(q, k, bias, scale, latent), new_kv
         return dot_product_attention(q, k, v, bias, scale=scale), new_kv
+    if latent is not None:
+        if isinstance(cache_index, numbers.Integral):
+            # columns past the call's last are under its causal mask whatever
+            # the bias's width: neither gathered nor decompressed
+            view_len = min(view_len, cache_index + q.shape[1])
+            bias = bias[..., :view_len]
+        rows, _, new_kv = paged_write_read(
+            cache_kv, k_new, None, cache_index, q.dtype, view_len=view_len
+        )
+        return latent_attention(q, rows, bias, latent, scale=scale), new_kv
     if fused:
         if (
             q.shape[1] != 1

@@ -53,6 +53,16 @@ use: those keys are taken and set back by slot, the rest are pools. The
 tail never reaches ``decode_attention``: the model hands it the layer
 without (:func:`split_tail`).
 
+A **latent** layer (:func:`latent_buffers`) keeps one row a position and
+no values: ``{"k": [B, C, 1, W]}`` with no ``"v"`` beside it, ``W`` a
+compressed key-value latent and the one rotated key part all heads share
+(``ops/attention.py::decode_attention`` with ``latent``: the values are the
+row's leading columns, or what an up-projection makes of them). It is
+written, paged, scattered by block (one head: ``H`` = 1) and read as
+stored like any pool of keys; every write and read here skips the absent
+``"v"``. ``cache_kind(...).latent`` says so. No int8 form, no shared-prefix
+pool (both refused by name where they would be built).
+
 The pools are sized by **KV heads**: a family with fewer KV heads than
 query heads (grouped-query attention) allocates and reads ``H_kv`` of them,
 and the reads in ``ops/attention.py`` map query head ``h`` to KV head
@@ -263,6 +273,29 @@ def kv_buffers(
     )
 
 
+def latent_buffers(
+    n_layer: int,
+    batch_size: int,
+    capacity: int,
+    width: int,
+    dtype,
+    kv_cache_dtype: str = "bfloat16",
+) -> Cache:
+    """Per-layer latent buffers: one row of ``width`` values a position
+    under ``"k"`` (``[B, C, 1, width]``: one head, so every pool operation
+    takes it as it takes keys) and no ``"v"``. A latent row is what a
+    family's attention compressed keys *and* values into; quantising it
+    per row would share one scale between a normed latent and a rotated
+    key part, which nothing here has measured: int8 is refused by name."""
+    if kv_cache_dtype != "bfloat16":
+        raise ValueError(
+            f"kv_cache_dtype={kv_cache_dtype!r} is not built for a latent "
+            "cache (one row a position, no values): choose 'bfloat16'"
+        )
+    shape = (batch_size, capacity, 1, width)
+    return tuple({"k": jnp.zeros(shape, jnp.dtype(dtype))} for _ in range(n_layer))
+
+
 # The largest folded buffer of one layer that a loop carrying it on its own
 # would have staged: compiled for a described v5e (jax 0.9.0, libtpu 0.0.34;
 # gpt2-medium's sampler, 24 layers) the memory-space assignment puts buffers
@@ -468,6 +501,8 @@ class CacheKind(NamedTuple):
     # a folded cache that is the layer-major carry of all layers: the layer
     # this call writes and reads (``None``: the dict is one layer's own)
     layer: Optional[int] = None
+    # one row a position under ``"k"`` and no ``"v"`` (:func:`latent_buffers`)
+    latent: bool = False
 
 
 def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
@@ -480,7 +515,8 @@ def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
     pools are whole (``num_slots`` rows), the tables and the call's K/V are
     the group's (``A`` rows), and row ``i`` of the call lives in pool row
     ``slot_ids[i]`` (:func:`paged_write_read`).
-    ``tail`` names the keys that live by slot. (``"first_block"`` beside
+    ``tail`` names the keys that live by slot; a layer of ``"k"`` without
+    ``"v"`` is ``latent``. (``"first_block"`` beside
     ``"slot_ids"`` is a caller's promise about one call,
     :func:`starting_at_block`; it changes no layer's kind.)"""
     if "ssm_state" in cache_kv:
@@ -499,6 +535,7 @@ def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
         layout == PAGED and "slot_ids" in cache_kv,
         tuple(sorted(k for k in cache_kv if k.startswith(TAIL_PREFIX))),
         layer,
+        "v" not in cache_kv,
     )
 
 
@@ -986,12 +1023,12 @@ def _shared_gather(
 def paged_write_read(
     cache_kv: Dict[str, jax.Array],
     k: jax.Array,  # [B, T, H, Dh] new keys (compute dtype)
-    v: jax.Array,
+    v: Optional[jax.Array],  # None for a latent layer, which keeps none
     cache_index,  # scalar/[B] logical base position, or [B, T] per column
     dtype,
     view_len: int = 0,
     as_stored: bool = False,
-) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+) -> Tuple[jax.Array, Optional[jax.Array], Dict[str, jax.Array]]:
     """Paged counterpart of :func:`dense_write_read`: write the new K/V
     rows through the block table, then return the buffers to attend over
     (plus the updated cache dict).
@@ -1157,6 +1194,17 @@ def paged_write_read(
             new_kv["shared_tables"] = cache_kv["shared_tables"]
             new_kv["publish_tables"] = cache_kv["publish_tables"]
         return new_kv
+
+    if kind.latent:
+        # one pool a layer and no values: the caller's ``v`` is None and so
+        # is what comes back for it
+        if v is not None or sharing:
+            raise ValueError(
+                "a latent layer (one row a position, no values) takes v=None "
+                "and no shared-prefix overlay"
+            )
+        new_kv = carry({"k": scatter("k", k)})
+        return (new_kv["k"] if as_stored else logical("k")), None, new_kv
 
     if kind.quantized:
         k_q, k_s = quantize_kv(k)

@@ -62,7 +62,9 @@ expert); on an ``ep`` mesh it is refused by name: nothing runs it there.
 family whose router is not one matrix on the block's input (an MLP, a carry
 from the layer before, a bias that moves the choice and not the weight)
 computes its :class:`Routing` itself and hands it over; steps 2-4 are the
-same. A **skip** choice rides on the share above: a router ``E + 1`` wide
+same. One such router lives here because it is one matrix after all:
+:func:`route_group_limited`, sigmoid scores under a selection bias, the
+choice limited to the best groups of experts. A **skip** choice rides on the share above: a router ``E + 1`` wide
 over ``E`` held experts, whose last index no expert holds, so its copies
 are multiplied with nothing and contribute exactly zero
 (:func:`routing_stats` counts them as ``skip_share``). Off a mesh only.
@@ -103,6 +105,44 @@ def route(h: jax.Array, router_w: jax.Array, k: int, norm_topk: bool = False) ->
     if norm_topk:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return Routing(logits, probs, weights, experts.astype(jnp.int32))
+
+
+def route_group_limited(h: jax.Array, router_w: jax.Array, bias: jax.Array, k: int, *,
+                        n_group: int, topk_group: int, scale: float = 1.0) -> Routing:
+    """Float32 routing of ``h`` [N, D] over ``router_w`` [D, E] by sigmoid
+    scores, a selection bias and a limit on the groups a token may reach
+    (DeepSeek-V3, section 2.1.2 and its auxiliary-loss-free balancing):
+
+        s = sigmoid(h W_r);  s' = s + bias          the bias moves the choice only
+        E experts in n_group groups of E / n_group; a group's score is the
+        sum of its two largest s'; the topk_group best groups stay
+        (e_1..e_k) = the k largest s' among the experts of those groups
+        w_j = scale * s[e_j] / (sum_j s[e_j] + 1e-20)
+
+    A dropped group's scores are masked with ``-inf``: none of its experts
+    can be chosen whatever the bias's sign. ``probs`` are the ``E`` sigmoid
+    scores (they do not sum to 1), ``logits`` what they are the sigmoid of."""
+    N, E = h.shape[0], router_w.shape[-1]
+    if E % n_group or not 0 < topk_group <= n_group or k > topk_group * (E // n_group):
+        raise ValueError(
+            f"{E} experts do not divide into {n_group} groups of which "
+            f"{topk_group} hold the {k} a token takes"
+        )
+    logits = jnp.dot(
+        h.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    scores = jax.nn.sigmoid(logits)
+    biased = scores + bias.astype(jnp.float32)
+    grouped = biased.reshape(N, n_group, E // n_group)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(group_score, topk_group)
+    stays = jnp.zeros((N, n_group), bool).at[jnp.arange(N)[:, None], kept].set(True)
+    limited = jnp.where(stays[:, :, None], grouped, -jnp.inf).reshape(N, E)
+    _, experts = jax.lax.top_k(limited, k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return Routing(logits, scores, weights, experts.astype(jnp.int32))
 
 
 # -- permutations whose backward pass is a gather too ---------------------- #

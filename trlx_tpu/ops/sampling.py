@@ -446,12 +446,13 @@ def make_sampler(
 
         cache = init_cache_fn(B, cap)
         if not isinstance(cache, dict) and any(
-            cache_kind(layer).tail for layer in cache
+            cache_kind(layer).tail or cache_kind(layer).latent for layer in cache
         ):
             raise ValueError(
                 "the fixed sampler carries KV layers only (its loop folds "
                 "every layer into decode_kv_layout): a model with state "
-                "layers (granitemoehybrid) or a tail beside its keys (zaya) "
+                "layers (granitemoehybrid), a latent cache (deepseek_v3) or "
+                "a tail beside its keys (zaya) "
                 "samples through rollout.engine: continuous"
             )
         cache = pin_cache(cache)
