@@ -13,6 +13,14 @@ A traffic file with ``"driver": "serve"`` gives: ``seq_length``,
 ``prompt_lengths``, ``max_new_tokens``, ``slots``, ``admit_width``,
 ``harvest_width``, ``arrivals`` (``process``, ``knee_per_s``, ``load``[,
 ``cv``]), ``drain_limit_s``, ``warmup_requests``, ``trace_seconds``.
+Three more keys are optional, for a mix in which a seed would otherwise
+change the work (``loadgen``'s docstring): ``min_new_tokens`` (1 where
+absent), the program's own ``gen_kwargs`` key, below which it draws no
+EOS, so a mix that sets it to its ``max_new_tokens`` gets requests that
+run to their budget whatever the seeded head says; ``weights_seed``,
+which makes the served parameters in ``--seed``'s place, one model for
+every run of the cell; and ``order_seed``, which orders the prompt lengths
+in ``--seed``'s place, the same lengths at the same instants in every run.
 
 A traced run offers the cell's whole window, like any other run, and
 every histogram, counter and client clock is read over all of it. Only
@@ -65,7 +73,7 @@ def build_config(cell: Dict[str, Any]):
             "name": "PPOConfig",
             "gen_kwargs": {
                 "max_new_tokens": t["max_new_tokens"],
-                "min_new_tokens": 1,
+                "min_new_tokens": t.get("min_new_tokens", 1),
                 "top_k": 0,
                 "do_sample": True,
                 "eos_token_id": eos,
@@ -225,17 +233,18 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
     spans = harness.Spans()
     config = build_config(cell)
     t_imported = time.time()
-    params = seeded_params(config, seed)
+    params = seeded_params(config, t.get("weights_seed", seed))
     server = InferenceServer(config, params=params, seed=loadgen.program_seed(seed))
     t_built = time.time()
     vocab, budget = cf["vocab_size"], t["max_new_tokens"]
-    eos = vocab - 1
+    eos, least = vocab - 1, config.method.gen_kwargs["min_new_tokens"]  # as build_config read it from the mix
 
     window_s = float(seconds)
     due = loadgen.arrival_times(t["arrivals"], window_s, t["traffic_seed"])
     n_warm = int(t["warmup_requests"])
     prompts = loadgen.draw_prompts(
-        t["prompt_lengths"], len(due) + n_warm, vocab, t["traffic_seed"], seed
+        t["prompt_lengths"], len(due) + n_warm, vocab, t["traffic_seed"], seed,
+        order_seed=t.get("order_seed"),
     )
     # warm-up: the cell's own widths, streamed, with a partial last group so
     # the placeholder (release) program is built too
@@ -280,14 +289,14 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
         res = out["results"][c.rid]
         toks = res["tokens"]
         stopped = bool(toks) and toks[-1] == eos
-        if not (res["length"] == budget or (stopped and 1 <= res["length"] < budget)):
+        if not (res["length"] == budget or (stopped and least <= res["length"] < budget)):
             bad_len += 1
         if len(c.token_times) != res["length"]:
             bad_len += 1
         if any(not 0 <= int(x) < vocab for x in toks):
             bad_tok += 1
     ok &= log.line("accounting.requests_off_budget", bad_len,
-                   f"== 0 (each returned {budget} tokens or stopped on EOS; "
+                   f"== 0 (each returned {budget} tokens or stopped on EOS after {least} or more; "
                    "streamed as many as returned)", bad_len == 0)
     ok &= log.line("accounting.requests_with_token_outside_vocab", bad_tok, "== 0", bad_tok == 0)
     ok &= log.line("accounting.compiles_in_window", compiled_in_window, "== 0",

@@ -47,7 +47,7 @@ def main() -> int:
     harness.require_chips(int(cell["chips"]))
     config = serve_driver.build_config(cell)
     server = InferenceServer(
-        config, params=serve_driver.seeded_params(config, args.seed),
+        config, params=serve_driver.seeded_params(config, t.get("weights_seed", args.seed)),
         seed=loadgen.program_seed(args.seed),
     )
     spans, budget = harness.Spans(), t["max_new_tokens"]
@@ -59,7 +59,8 @@ def main() -> int:
         arrivals = dict(t["arrivals"], knee_per_s=rate, load=1.0)
         due = loadgen.arrival_times(arrivals, args.seconds, t["traffic_seed"])
         prompts = loadgen.draw_prompts(
-            t["prompt_lengths"], len(due), cf["vocab_size"], t["traffic_seed"], args.seed)
+            t["prompt_lengths"], len(due), cf["vocab_size"], t["traffic_seed"], args.seed,
+            order_seed=t.get("order_seed"))
         est = server.engine.stats
         occ0, steps0 = est.occupancy_sum, est.decode_steps
         out = serve_driver.drive(server, prompts, due, args.seconds, 120.0, spans, budget)

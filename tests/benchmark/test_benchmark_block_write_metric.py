@@ -15,11 +15,10 @@ import time
 import pytest
 
 from benchmark import harness, readers, serve_driver
+from manifest_cells import PPO_CELLS, every_serve_cell_and_no_ppo_cell
 from test_benchmark_rehearsal import quiet_program, shrunk  # noqa: F401  (autouse fixture)
 
 NAME = "serve_prefill_block_write_share"
-SERVE_CELLS = {"serve-pythia1b4-chat", "serve-olmoe1b7b-chat", "serve-granite4hs-chat",
-               "serve-zaya1-8b-reason"}
 
 
 # the rehearsal's toy widths (16 prompt columns + 8 new tokens: blocks of
@@ -55,14 +54,13 @@ def test_the_serve_cells_list_the_share_and_no_ppo_cell_does():
     spec = {m["name"]: m for m in manifest["per_layer"]}[NAME]
     assert (spec["unit"], spec["better"], spec["source"], spec["layer"], spec["moves"]) == (
         "share", "higher", "program_counter", "rollout engine", "serve_itl_p95_ms")
-    assert SERVE_CELLS <= set(spec["workloads"])
-    assert not any(name.startswith("ppo-") for name in spec["workloads"])
+    assert every_serve_cell_and_no_ppo_cell(spec["workloads"])
     assert set(spec["workloads"]) <= {w["name"] for w in manifest["workloads"]}
     with open(os.path.join(harness.HERE, "layer_metrics", f"{NAME}.json")) as f:
         assert json.load(f) == {
             "reader": {"kind": "histogram", "name": "engine/prefill_block_write_share", "stat": "mean"}}
 
 
-@pytest.mark.parametrize("name", ["ppo-gpt2m-tldr", "ppo-gpt2m-longgen"])
+@pytest.mark.parametrize("name", PPO_CELLS)
 def test_a_ppo_cell_does_not_read_it(name):
     assert NAME not in {s["name"] for s in harness.load_layer_metrics(name)}

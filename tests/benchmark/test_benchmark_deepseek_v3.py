@@ -3,9 +3,10 @@ decode step's bytes pinned by hand (ISSUE 53's arithmetic), the published
 keys against the catalog row, the reference's two halves and its blocks,
 the count functions of the new readers on made-up trace operations, the
 tolerance file under its rule, the manifest's entries, and a CPU rehearsal
-of ``serve-deepseekv3-reason`` at a toy size through the code the chip runs
+of ``serve-deepseekv3-reason1k`` at a toy size through the code the chip runs
 (form only: CPU numbers)."""
 
+import contextlib
 import json
 import re
 
@@ -15,7 +16,8 @@ import pytest
 from benchmark import arithmetic, checks, harness
 from benchmark.run import run_cell
 
-CELL = "serve-deepseekv3-reason"
+CELL = "serve-deepseekv3-reason1k"
+OLD_CELL = "serve-deepseekv3-reason"  # replaced by PR 55; its readings stay in the tolerance file as evidence
 # by hand, d 7168, 128 heads of nope 128 + rope 64 (scores) and 128 (values), c_q 1536, c_kv 512:
 # W_dq 7168 x 1536 + W_uq 1536 x 24576 + W_dkv 7168 x 576 + W_ukv 512 x 32768 + W_o 16384 x 7168; the two inner norms
 ATTN = 11_010_048 + 37_748_736 + 4_128_768 + 16_777_216 + 117_440_512
@@ -269,7 +271,7 @@ def test_manifest_lists_the_cell_and_its_readers():
         manifest = json.load(f)
     cell = [w for w in manifest["workloads"] if w["name"] == CELL]
     assert len(cell) == 1 and cell[0]["chips"] == 1 and len(cell[0]["why"]) <= 200
-    assert (cell[0]["config"], cell[0]["traffic"]) == ("deepseek-v3", "reason-deepseekv3")
+    assert (cell[0]["config"], cell[0]["traffic"]) == ("deepseek-v3", "reason1k-deepseekv3")
     assert cell[0]["why"] == harness.load_json("workloads", f"{CELL}.json")["why"]
     config = [c for c in manifest["configs"] if c["name"] == "deepseek-v3"]
     assert len(config) == 1 and len(config[0]["why"]) <= 200
@@ -295,10 +297,14 @@ def test_manifest_lists_the_cell_and_its_readers():
     readers = {s["name"]: s["reader"] for s in harness.load_layer_metrics(CELL)}
     assert readers["mla_latent_gb"] == {"kind": "counter", "name": "cache/latent_gb"}
     assert all(readers[n]["kind"] == "op_roofline" for n in OWN if n != "mla_latent_gb")
-    traffic = harness.load_json("traffic", "reason-deepseekv3.json")
+    traffic = harness.load_json("traffic", "reason1k-deepseekv3.json")
     zaya = harness.load_json("traffic", "reason-zaya1-8b.json")
-    # zaya's mix key for key but for the answers' length, the slots, the drain, the seed and the knee, the sweep's
-    assert set(zaya) == set(traffic)
+    # zaya's mix key for key but for the answers' length, the slots, the drain, the seed and the knee, the sweep's,
+    # and the three keys that keep a seed from changing the work (PR 55): every answer runs to its budget, one model,
+    # one order of the prompt lengths
+    assert set(traffic) - set(zaya) == {"min_new_tokens", "weights_seed", "order_seed"} and set(zaya) <= set(traffic)
+    assert traffic["min_new_tokens"] == traffic["max_new_tokens"]
+    assert traffic["weights_seed"] == traffic["order_seed"] == traffic["traffic_seed"]
     assert {k for k in zaya if zaya[k] != traffic[k]} == {
         "name", "traffic_seed", "max_new_tokens", "slots", "arrivals", "drain_limit_s"}
     assert (traffic["seq_length"], traffic["max_new_tokens"], traffic["slots"], traffic["admit_width"],
@@ -319,8 +325,11 @@ def test_the_tolerances_the_cell_is_held_to(config_file):
     measured = table["measured"]["bfloat16/kv-bfloat16"][CELL]
     assert measured["logprob_rms"]["runs"] >= 8 and measured["logprob_rms"]["seeds"] >= 4
     assert measured["logprob_rms"]["max"] < tol["logprob_rms"] <= 3 * measured["logprob_rms"]["max"]
-    cheaper = table["cheaper"]["bfloat16/kv-bfloat16"][CELL]
-    assert cheaper["logprob_rms"]["runs"] >= 4 and cheaper["logprob_rms"]["min"] > tol["logprob_rms"]
+    # the control, read on the cell as it was and again on this one; the replaced cell's readings stay as evidence
+    for cell in (CELL, OLD_CELL):
+        cheaper = table["cheaper"]["bfloat16/kv-bfloat16"][cell]
+        assert cheaper["logprob_rms"]["runs"] >= 4 and cheaper["logprob_rms"]["min"] > tol["logprob_rms"]
+    assert table["measured"]["bfloat16/kv-bfloat16"][OLD_CELL]["logprob_rms"]["runs"] == 36
 
 
 @pytest.fixture
@@ -335,7 +344,7 @@ def shrunk():
     cell["config_file"].pop("tolerances", None)  # measured at the published sizes: the shared table at a toy size
     cell["mesh"] = {"dp": -1, "fsdp": 1, "tp": 1}
     cell["traffic_file"].update(
-        seq_length=16, max_new_tokens=8, slots=16, admit_width=8, harvest_width=8,
+        seq_length=16, max_new_tokens=8, min_new_tokens=8, slots=16, admit_width=8, harvest_width=8,
         prompt_lengths={"dist": "lognormal", "median": 8, "sigma": 0.5, "lo": 2, "hi": 16},
         arrivals={"process": "poisson", "knee_per_s": 25.0, "load": 0.8}, warmup_requests=12,
         drain_limit_s=30, trace_seconds=1)
@@ -362,3 +371,47 @@ def test_cpu_rehearsal_of_the_cell(trace, capsys, quiet_program):
     assert 0 < out["metrics"]["moe_rows_here_share"]["value"] < 1
     assert out["metrics"]["moe_experts_touched"]["value"] <= 4
     assert "mla_absorbed_read_roofline" not in out["metrics"] and "busy_s" not in out["device"]
+
+
+@pytest.mark.parametrize("seed", [2**31 + 57, 2**31 + 58])
+def test_the_float8_latent_control_comes_out_not_correct(seed, monkeypatch, quiet_program):
+    """The cell's control as the tree keeps it (``benchmark/tools/control_run.py --control
+    float8_latent``; the chip's readings at the cell's own size are the tolerance file's ``cheaper``
+    group) planted under a rehearsal, beside a sound run of the same seed. Two things are the toy's
+    own, for both runs alike: every expert is chosen (4 of 4, so no choice can fall the other way
+    between bfloat16 and the float32 reference, which at this size swamps any rounding), and the
+    seeded matrices are four times louder (at width 64, normal(0.02) gives logits too flat for a
+    rounded cache to move). The reference lifts the server's own tree, so it follows both."""
+    import importlib.util
+    import os
+
+    import jax
+
+    from benchmark import serve_driver
+
+    spec = importlib.util.spec_from_file_location(
+        "control_run", os.path.join(harness.REPO, "benchmark", "tools", "control_run.py"))
+    control_run = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(control_run)
+    seeded = serve_driver.seeded_params
+    monkeypatch.setattr(serve_driver, "seeded_params", lambda config, s: jax.tree_util.tree_map(
+        lambda x: 4.0 * x if x.ndim >= 2 else x, seeded(config, s)))
+
+    def rms(control):
+        cell = shrunk()
+        cell["config_file"].update(num_router_experts=4, n_routed_experts=4, first_local_expert=0,
+                                   num_experts_per_tok=4, n_group=1, topk_group=1)
+        with control_run.CONTROLS["float8_latent"]() if control else contextlib.nullcontext():
+            out = json.loads(run_cell(CELL, seed, 1.0, False, allow_cpu=True, cell=cell))
+        check = out["checks"]["reference.sampled_logprob_rms"]
+        assert out["correct"] is check["ok"] and out["failed"] == 0
+        return check["value"], check["ok"]
+
+    import trlx_tpu.models.deepseek_v3 as family
+
+    program = family.decode_attention
+    sound, ok = rms(False)
+    assert ok
+    cheaper, ok = rms(True)
+    assert not ok and cheaper > 2 * sound
+    assert family.decode_attention is program  # the control takes itself out again

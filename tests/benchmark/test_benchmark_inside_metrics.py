@@ -54,6 +54,9 @@ def test_ppo_metrics_read_the_phase_from_inside(name):
     inside = got["collect_wait_ms"]["value"] + got["collect_detok_ms"]["value"]
     assert 0.0 < inside <= got["collect_decode_ms"]["value"]
     assert inside >= 0.9 * got["collect_decode_ms"]["value"]
+    # (by count too, which a loaded CPU cannot stretch: each child is opened once inside each parent)
+    opened = {k: record["tracer_stats"][k]["count"] for k in ("collect/decode", "collect/wait", "collect/detokenize")}
+    assert len(set(opened.values())) == 1 and opened["collect/decode"] >= 1, opened
     # a program without the spans (the parent commit) reports none of them
     stats = {k: v for k, v in record["tracer_stats"].items()
              if k not in ("collect/wait", "collect/detokenize")}

@@ -13,11 +13,10 @@ import time
 import pytest
 
 from benchmark import harness, readers, serve_driver
+from manifest_cells import PPO_CELLS, every_serve_cell_and_no_ppo_cell
 from test_benchmark_rehearsal import quiet_program, shrunk  # noqa: F401  (autouse fixture)
 
 NAME = "serve_param_gb"
-SERVE_CELLS = {"serve-pythia1b4-chat", "serve-olmoe1b7b-chat", "serve-granite4hs-chat",
-               "serve-zaya1-8b-reason"}
 
 
 def test_the_gauge_reads_the_served_tree_from_a_serve_record():
@@ -51,13 +50,12 @@ def test_the_serve_cells_list_the_gauge_and_no_ppo_cell_does():
     spec = {m["name"]: m for m in manifest["per_layer"]}[NAME]
     assert (spec["unit"], spec["better"], spec["source"], spec["layer"], spec["moves"]) == (
         "GB", "lower", "program_counter", "rollout engine", "serve_itl_p95_ms")
-    assert SERVE_CELLS <= set(spec["workloads"])
-    assert not any(name.startswith("ppo-") for name in spec["workloads"])
+    assert every_serve_cell_and_no_ppo_cell(spec["workloads"])
     assert set(spec["workloads"]) <= {w["name"] for w in manifest["workloads"]}
     with open(os.path.join(harness.HERE, "layer_metrics", f"{NAME}.json")) as f:
         assert json.load(f) == {"reader": {"kind": "counter", "name": "engine/param_gb"}}
 
 
-@pytest.mark.parametrize("name", ["ppo-gpt2m-tldr", "ppo-gpt2m-longgen"])
+@pytest.mark.parametrize("name", PPO_CELLS)
 def test_a_ppo_cell_does_not_read_it(name):
     assert NAME not in {s["name"] for s in harness.load_layer_metrics(name)}
