@@ -429,9 +429,12 @@ def kv_ops_in_loops(hlo_text, kv_shapes):
 
 
 def decode_loop_kv_ops(arch, kv_cache_dtype, *, batch=8, seq_length=112,
-                       new_tokens=16):
+                       new_tokens=48):
     """Compile the fixed sampler for the default device at a small shape
-    and list what :func:`kv_ops_in_loops` finds in its optimised HLO."""
+    and list what :func:`kv_ops_in_loops` finds in its optimised HLO: a
+    whole layer's buffer, or its leading positions at one of the widths the
+    decode read takes (112 + 48: 128 and 160; each branch's slice has to
+    fuse into the products, never be staged or copied on its own)."""
     import functools
 
     import jax
@@ -439,6 +442,7 @@ def decode_loop_kv_ops(arch, kv_cache_dtype, *, batch=8, seq_length=112,
 
     from trlx_tpu.models.gpt2 import GPT2Config, GPT2Model, init_cache
     from trlx_tpu.models.heads import CausalLMWithValueHead
+    from trlx_tpu.ops.kv_cache import decode_read_widths
     from trlx_tpu.ops.sampling import GenerationConfig, make_sampler
 
     cfg = GPT2Config.from_dict(
@@ -473,8 +477,8 @@ def decode_loop_kv_ops(arch, kv_cache_dtype, *, batch=8, seq_length=112,
     heads, width = cfg.n_head, cfg.n_embd
     element = "s8" if kv_cache_dtype == "int8" else "bf16"
     return kv_ops_in_loops(text, [
-        f"{element}[{batch},{capacity},{width}]",
         f"{element}[{batch},{capacity},{heads},{width // heads}]",
+        *(f"{element}[{batch},{w},{width}]" for w in decode_read_widths(capacity, seq_length)),
     ])
 
 

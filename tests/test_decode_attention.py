@@ -190,6 +190,155 @@ def test_fully_masked_row_matches_generic():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
 
 
+# --------- the read's width under the sampler's promise --------------- #
+
+# a call's first index and capacity: longgen's (four widths, 128..512)
+PROMISED, CAPACITY = 64, 512
+WIDTHS = (128, 256, 384, 512)
+# both sides of every boundary, the first call and the last position
+INDICES = sorted(
+    {PROMISED, CAPACITY - 1} | {w + d for w in WIDTHS for d in (-2, -1, 0) if w + d < CAPACITY}
+)
+
+
+def _promised(cache_kv, first_index=PROMISED):
+    """``cache_kv`` (the carry as a layer is handed it, or one layer's own
+    dict) under the sampler's promise."""
+    from trlx_tpu.ops.kv_cache import written_to_index
+
+    return written_to_index((cache_kv,), first_index)[0]
+
+
+@pytest.mark.parametrize(
+    "capacity,first,widths",
+    [(512, 64, (128, 256, 384, 512)), (560, 512, (560,)),
+     (2560, 512, (1024, 1536, 2048, 2560)), (128, 0, (128,)),
+     (512, 128, (256, 384, 512)), (1024, 0, (256, 512, 768, 1024)),
+     (160, 100, (128, 160)), (512, None, (512,))],
+    ids=["longgen", "tldr", "long-answer", "one-tile", "first-on-a-tile",
+         "from-zero", "two", "no-promise"],
+)
+def test_the_widths_follow_from_the_capacity_and_the_first_index(capacity, first, widths):
+    """Derived, no knob: the multiples of 128 inside ``(first, capacity)``,
+    then the capacity; beyond four, every ``ceil(n / 4)``-th counted back
+    from the capacity. Every index a call may come at has a width."""
+    from trlx_tpu.ops.kv_cache import decode_read_widths
+
+    got = decode_read_widths(capacity, first)
+    assert got == widths
+    assert got[-1] == capacity and len(got) <= 4 and list(got) == sorted(set(got))
+    assert first is None or got[0] > first
+
+
+@pytest.mark.parametrize("index", INDICES)
+@pytest.mark.parametrize("own", [False, True], ids=["carry", "own_dict"])
+@pytest.mark.parametrize("dtype,cache_dtype,atol", PRECISIONS)
+def test_promised_read_matches_the_whole_read(dtype, cache_dtype, atol, own, index):
+    """Under ``written_to_index`` the read takes the narrowest width that
+    holds ``cache_index`` (left-padded rows, both sides of every boundary):
+    the output is the whole-capacity read's to float32 summation order, the
+    written buffers are bit for bit the same, and what comes back carries
+    no promise."""
+    import jax.numpy as jnp
+
+    from trlx_tpu.ops.attention import decode_attention
+    from trlx_tpu.ops.kv_cache import decode_kv_layout, decode_read_widths
+    from trlx_tpu.telemetry import get_metrics
+
+    assert decode_read_widths(CAPACITY, PROMISED) == WIDTHS
+    rng = np.random.default_rng(index)
+    Dh = 16
+    cache = _filled_cache(rng, CAPACITY, Dh, dtype, cache_dtype, filled=index)
+    cache_kv = decode_kv_layout(cache) if own else _carry(cache)
+    q, k_new, v_new = (
+        jnp.asarray(rng.standard_normal((B, 1, H, Dh)), dtype) for _ in range(3)
+    )
+    bias = _bias(CAPACITY, index, pad=PROMISED // 4, shared=False)
+    read = lambda w: get_metrics().counter("attention/decode_read_width{width=%d}" % w).value  # noqa: E731
+    before = {w: read(w) for w in WIDTHS}
+    whole, whole_kv = decode_attention(q, k_new, v_new, cache_kv, index, bias)
+    assert {w: read(w) - before[w] for w in WIDTHS} == {128: 0, 256: 0, 384: 0, 512: 1}
+    out, new_kv = decode_attention(q, k_new, v_new, _promised(cache_kv), index, bias)
+    # one traced read site a width
+    assert {w: read(w) - before[w] for w in WIDTHS} == {128: 1, 256: 1, 384: 1, 512: 2}
+
+    assert out.shape == whole.shape and out.dtype == whole.dtype
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(whole, np.float32), atol=atol
+    )
+    assert sorted(new_kv) == sorted(whole_kv)
+    for name in whole_kv:
+        np.testing.assert_array_equal(
+            np.asarray(new_kv[name], np.float32),
+            np.asarray(whole_kv[name], np.float32), err_msg=name,
+        )
+
+
+def test_promised_read_of_a_fully_masked_row_is_finite():
+    """A row with every position masked softmaxes to uniform weights over
+    the width it reads: finite, and every other row the whole read's."""
+    import jax.numpy as jnp
+
+    from trlx_tpu.ops.attention import NEG_INF, decode_attention
+
+    rng = np.random.default_rng(0)
+    Dh, index = 16, 200
+    cache = _filled_cache(rng, CAPACITY, Dh, "float32", "float32", filled=index)
+    q, k_new, v_new = (
+        jnp.asarray(rng.standard_normal((B, 1, H, Dh)), jnp.float32) for _ in range(3)
+    )
+    bias = np.array(_bias(CAPACITY, index, pad=3, shared=False))
+    bias[1] = NEG_INF
+    bias = jnp.asarray(bias)
+    whole, _ = decode_attention(q, k_new, v_new, _carry(cache), index, bias)
+    out, _ = decode_attention(q, k_new, v_new, _promised(_carry(cache)), index, bias)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out)[[0, 2]], np.asarray(whole)[[0, 2]], atol=1e-6)
+
+
+# the jaxpr of the read without a promise at the commit before the promise
+# existed (PR 53's tree): printed, hashed
+PARENT_READ_JAXPR = {"carry": "a6826f3ea1c77c05", "own_dict": "f79d13217aee52f9"}
+
+
+@pytest.mark.parametrize("own", [False, True], ids=["carry", "own_dict"])
+def test_no_promise_reads_the_whole_capacity_as_before(own):
+    """A caller that promises nothing (the pp stage scan, a layer's own
+    dict handed over by anyone) traces the read it traced before: the
+    parent's jaxpr, no ``cond``. So does a promise under which the rule
+    gives one width (tldr's 512 of 560)."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from trlx_tpu.ops.attention import decode_attention
+    from trlx_tpu.ops.kv_cache import cache_kind, decode_kv_layout, kv_buffers
+
+    C, Dh = 560, 16
+    cache = kv_buffers(1, B, C, H, Dh, jnp.bfloat16, "int8")[0]
+    cache_kv = decode_kv_layout(cache) if own else _carry(cache)
+    x = jnp.zeros((B, 1, H, Dh), jnp.bfloat16)
+    bias = jnp.zeros((B, 1, 1, C), jnp.float32)
+
+    def traced(cache_kv):
+        static = {k: a for k, a in cache_kv.items() if not hasattr(a, "shape")}
+        arrays = {k: a for k, a in cache_kv.items() if k not in static}
+        return str(jax.make_jaxpr(
+            lambda arrays, index: decode_attention(x, x, x, {**arrays, **static}, index, bias)
+        )(arrays, jnp.int32(520)))
+
+    assert cache_kind(cache_kv).written_to_index is None
+    plain = traced(cache_kv)
+    assert "cond" not in plain
+    key = "own_dict" if own else "carry"
+    assert hashlib.sha256(plain.encode()).hexdigest()[:16] == PARENT_READ_JAXPR[key]
+    promised = _promised(cache_kv, first_index=512)
+    assert cache_kind(promised).written_to_index == 512
+    assert traced(promised) == plain
+    assert "cond" in traced(_promised(cache_kv, first_index=64))
+
+
 def test_decode_kv_layout_shapes():
     """A tuple of layers becomes the carry, one array a kind with the
     layers leading; one layer's dict and the pp sampler's layer-major dict

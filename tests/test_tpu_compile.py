@@ -131,7 +131,7 @@ def test_cca_decode_layer_steps_its_tail_and_holds_no_copy_of_its_pool(one_chip)
 
 
 def test_latent_decode_layer_reads_its_pool_in_stored_order(one_chip):
-    """One latent-attention sublayer of the ``serve-deepseekv3-reason``
+    """One latent-attention sublayer of the ``serve-deepseekv3-reason1k``
     decode step at published widths (64 slots x 1536 positions of one
     576-value row, bf16; 128 heads): one row a slot written in place, the
     absorbed read of the pool as stored. The chip's compiler takes it, no
@@ -219,24 +219,45 @@ def test_an_admission_layer_writes_whole_blocks_in_place(one_chip, C, H, T, firs
     assert not re.search(r"= bf16\[%d,%d,%d\]\S* scatter\(" % (B * C, H, Dh), text)
 
 
-def _while_bodies(text):
+def _while_bodies(text, fused=False):
     """The text of every computation some ``while`` of the compiled module
-    names as its body."""
+    names as its body, and of the branches of the ``conditional``s in them;
+    ``fused``: of the fusion bodies those call, and no other."""
     computations = dict(
         (m.group(1), m.group(2))
         for m in re.finditer(r"^(?:ENTRY )?%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.S | re.M)
     )
     names = set(re.findall(r"body=%?([\w.\-]+)", text))
     assert names and names <= set(computations)
+    for name in sorted(names):
+        for branches in re.findall(r"branch_computations=\{([^}]*)\}", computations[name]):
+            names |= set(re.findall(r"%?([\w.\-]+)", branches))
+    if fused:
+        todo, names = sorted(names), set()
+        while todo:
+            for callee in re.findall(r"calls=%?([\w.\-]+)", computations[todo.pop()]):
+                if callee not in names:
+                    names.add(callee)
+                    todo.append(callee)
     return "\n".join(computations[name] for name in sorted(names))
 
 
+def _two_slices(a, layer, width, name):
+    """``ops/attention.py::_leading`` as it must not be written: the layer
+    first, its leading positions after."""
+    mine = a if layer is None else a[layer]
+    return mine[..., :width] if name.endswith("_scale") else mine[:, :width]
+
+
 @pytest.mark.parametrize(
-    "B,Q,R,stored,carry",
-    [(64, 64, 448, "s8", True), (64, 512, 48, "bf16", False), (32, 512, 48, "bf16", True)],
-    ids=["longgen-int8", "tldr-bf16", "half-tldr-bf16"],
+    "B,Q,R,stored,carry,read",
+    [(64, 64, 448, "s8", True, None), (64, 64, 448, "s8", True, _two_slices),
+     (64, 512, 48, "bf16", False, None), (32, 512, 48, "bf16", True, None)],
+    ids=["longgen-int8", "longgen-int8-sliced-twice", "tldr-bf16", "half-tldr-bf16"],
 )
-def test_the_samplers_loop_writes_its_cache_in_place_and_writes_none_of_it_back(one_chip, B, Q, R, stored, carry):
+def test_the_samplers_loop_writes_its_cache_in_place_and_writes_none_of_it_back(
+    one_chip, monkeypatch, B, Q, R, stored, carry, read
+):
     """The fixed sampler of the PPO cells (gpt2-medium: 24 layers, 16 heads
     of 64, bf16 rollout parameters as ``compute_dtype_cast`` leaves them,
     sampling on with the end token held back, as ``benchmark/ppo_driver.py``
@@ -252,8 +273,23 @@ def test_the_samplers_loop_writes_its_cache_in_place_and_writes_none_of_it_back(
     cache-shaped is staged or moved. tldr's layers stay on their own, are
     written in HBM and prefetched for their read alone. In every case no
     write lands in ``S(1)``, nothing cache-shaped is copied out of it and
-    nothing cache-shaped is copied. Nothing runs: no time here."""
+    nothing cache-shaped is copied.
+
+    The read's width follows the written context (PR 56): longgen's steps
+    read 128, 256, 384 and 512 positions, one branch of a ``conditional`` a
+    layer and width, each taking its slice of the carry in ONE ``slice``
+    that fuses into the two products (nested operands ``s8[64,w,1024]``):
+    no branch stages a layer in ``S(1)`` or copies anything cache-shaped,
+    and the 48 + 48 writes stay in place. Sliced twice (the layer, then its
+    leading positions) every branch first stages the whole 33.5 MB layer
+    into ``S(1)`` and narrows it after, so all the bytes are read again:
+    the case kept to show it. tldr's one width lowers to the text the
+    sampler had before. Nothing runs: no time here."""
     import functools
+    import hashlib
+
+    from trlx_tpu.ops import attention
+    from trlx_tpu.ops.kv_cache import decode_read_widths
 
     from trlx_tpu.models.gpt2 import GPT2Config, init_cache
     from trlx_tpu.models.heads import CausalLMWithValueHead
@@ -292,11 +328,38 @@ def test_the_samplers_loop_writes_its_cache_in_place_and_writes_none_of_it_back(
     )
     params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), params)
     prompts = sds((B, Q), jnp.int32)
-    text = jax.jit(sampler).lower(params, prompts, prompts, sds((2,), jnp.uint32)).compile().as_text()
+    if read is not None:
+        monkeypatch.setattr(attention, "_leading", read)
+    lowered = jax.jit(sampler).lower(params, prompts, prompts, sds((2,), jnp.uint32))
+    if (B, Q, R) == (64, 512, 48):
+        # ppo-gpt2m-tldr: the program of the commit before the read's width
+        assert hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16] == "6317887e45a55647"
+    text = lowered.compile().as_text()
     body = _while_bodies(text)
 
+    widths = decode_read_widths(C, Q)
+    assert widths == ((128, 256, 384, 512) if Q == 64 else (C,))
+    assert len(re.findall(r" conditional\(", body)) == (L if len(widths) > 1 else 0)
+    # a layer's leading positions [B, w, HD], in fast memory: staged whole
+    staged = re.findall(
+        r"= %s\[%d,(?:%s),%d\]\{[^}]*S\(1\)[^}]*\} fusion\(" % (stored, B, "|".join(map(str, widths)), HD),
+        body,
+    )
+    if read is not None:
+        assert len(staged) >= 2 * L and all("[%d,%d,%d]" % (B, C, HD) in line for line in staged)
+        return
+    assert not staged
+    products = _while_bodies(text, fused=True)
+    for w in widths[:-1]:
+        # the narrower widths exist only as operands fused into the products
+        assert "%s[%d,%d,%d]" % (stored, B, w, HD) in products
+        assert "%s[%d,%d,%d]" % (stored, B, w, HD) not in body
+    if stored == "s8":
+        scales = re.findall(r"= bf16\[%d,%d,16,%d\]\S* dynamic-update-slice\(" % (L, B, C), body)
+        assert len(scales) == 2 * L
+
     # the carry [L, B, C, HD], a layer's buffer [B, C, HD] or a part of one
-    cache_shaped = r"%s\[(?:\d+,){1,2}%d,%d\]" % (stored, C, HD)
+    cache_shaped = r"%s\[(?:\d+,){1,2}(?:%s),%d\]" % (stored, "|".join(map(str, widths)), HD)
     written = "%s[%s%d,%d,%d]" % (stored, "%d," % L if carry else "", B, C, HD)
     writes = re.findall(r"= (%s)(\{[^}]*\}) dynamic-update-slice\(" % re.escape(written), body)
     assert len(writes) == 2 * L

@@ -39,6 +39,7 @@ from trlx_tpu.ops.kv_cache import (
     FOLDED,
     PAGED,
     cache_kind,
+    decode_read_widths,
     dense_write_read,
     paged_write_read,
     quantize_kv,
@@ -330,6 +331,18 @@ def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias, scale=None):
     Products accumulate in float32 and the softmax is float32, as in
     :func:`dot_product_attention`; ``scale`` as there. Equal heads only:
     :func:`decode_attention` refuses grouped heads on this read by name.
+
+    How many positions are read follows what the call shows: the whole
+    capacity, or under the caller's promise that nothing past
+    ``cache_index`` holds anything (``kv_cache.py::written_to_index``, the
+    fixed sampler's loop; ``cache_kind(...).written_to_index``) the
+    narrowest of ``decode_read_widths(C, first index)`` that holds
+    ``cache_index``, chosen by a ``switch`` after the write. The write, the
+    block-diagonal ``q``, the scales and the float32 softmax are the same
+    at every width; the result differs from the whole read's by float32
+    summation order alone (the dropped positions' weights are exactly 0
+    there). Each traced width counts once in
+    ``attention/decode_read_width{width=...}``.
     """
     B, _, H, Dh = q.shape
     HD = H * Dh
@@ -358,32 +371,79 @@ def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias, scale=None):
     # as well, two ops then write from one operand, neither can be in place
     # and copy insertion copies the whole buffer at every step (PERF.md §6)
     new_kv = jax.lax.optimization_barrier(new_kv)
-    # what this layer reads: its slice of the carry, which fuses into the
-    # products below (read-only: it may be prefetched, never written back)
-    mine = new_kv if kind.layer is None else {name: a[kind.layer] for name, a in new_kv.items()}
 
-    seg = (jnp.arange(HD)[None, :] // Dh == jnp.arange(H)[:, None])
-    q_blocks = jnp.where(seg[None], q.reshape(B, 1, HD), 0).astype(q.dtype)
-    scores = jnp.einsum(
-        "bhk,bck->bhc", q_blocks, mine["k"].astype(q.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    if quantized:
-        scores = scores * mine["k_scale"].astype(jnp.float32)
-    scores = scores * (jax.lax.rsqrt(jnp.float32(Dh)) if scale is None else jnp.float32(scale))
-    scores = scores + bias[:, 0].astype(jnp.float32)
-    weights = jax.nn.softmax(scores, axis=-1)
-    if quantized:
-        weights = weights * mine["v_scale"].astype(jnp.float32)
-    # rounded to the compute dtype where they meet V, as the generic read
-    # does: two-term weights (16 + 16 bits) read the same log-probability
-    # error on the chip, to 1% (PERF.md §6, PR 25)
-    blocks = jnp.einsum(
-        "bhc,bck->bhk", weights.astype(q.dtype), mine["v"].astype(q.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    out = jnp.sum(jnp.where(seg[None], blocks, 0.0), axis=1)
-    return out.reshape(B, 1, H, Dh).astype(q.dtype), new_kv
+    def attend(q, mine, bias):
+        """One layer's buffers ``[B, w, H*Dh]`` (scales ``[B, H, w]``) read
+        under ``bias`` ``[B, 1, 1, w]``: the ``[B, 1, H, Dh]`` output."""
+        seg = (jnp.arange(HD)[None, :] // Dh == jnp.arange(H)[:, None])
+        q_blocks = jnp.where(seg[None], q.reshape(B, 1, HD), 0).astype(q.dtype)
+        scores = jnp.einsum(
+            "bhk,bck->bhc", q_blocks, mine["k"].astype(q.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        if quantized:
+            scores = scores * mine["k_scale"].astype(jnp.float32)
+        scores = scores * (jax.lax.rsqrt(jnp.float32(Dh)) if scale is None else jnp.float32(scale))
+        scores = scores + bias[:, 0].astype(jnp.float32)
+        weights = jax.nn.softmax(scores, axis=-1)
+        if quantized:
+            weights = weights * mine["v_scale"].astype(jnp.float32)
+        # rounded to the compute dtype where they meet V, as the generic read
+        # does: two-term weights (16 + 16 bits) read the same log-probability
+        # error on the chip, to 1% (PERF.md §6, PR 25)
+        blocks = jnp.einsum(
+            "bhc,bck->bhk", weights.astype(q.dtype), mine["v"].astype(q.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        out = jnp.sum(jnp.where(seg[None], blocks, 0.0), axis=1)
+        return out.reshape(B, 1, H, Dh).astype(q.dtype)
+
+    widths = decode_read_widths(cache_kv["k"].shape[-2], kind.written_to_index)
+    for w in widths:
+        get_metrics().counter("attention/decode_read_width{width=%d}" % w).inc()
+    if len(widths) == 1:
+        # what this layer reads: its slice of the carry, which fuses into the
+        # products (read-only: it may be prefetched, never written back)
+        mine = new_kv if kind.layer is None else {name: a[kind.layer] for name, a in new_kv.items()}
+        out = attend(q, mine, bias)
+    else:
+        # the caller's promise (``kv_cache.py::written_to_index``): nothing
+        # past ``cache_index`` is valid, so the read takes the narrowest of a
+        # few static widths that holds it; the positions it drops all carry
+        # ``NEG_INF`` in the whole read, where their weights are exactly 0.
+        # One branch a width, each reading the carry and returning the
+        # layer's output: a ``switch`` around a read, not around the carry,
+        # so no branch returns, rewrites or copies a cache
+        def at_width(w):
+            # fenced: the branches end in the same few operations, which the
+            # compiler otherwise moves out of the `switch` and has every
+            # branch hand over the [B, H, H*Dh] float32 products instead
+            return lambda q, kv, bias: jax.lax.optimization_barrier(attend(
+                q,
+                {name: _leading(a, kind.layer, w, name) for name, a in kv.items()},
+                bias[..., :w],
+            ))
+
+        bucket = jnp.sum(cache_index >= jnp.asarray(widths[:-1]), dtype=jnp.int32)
+        out = jax.lax.switch(bucket, [at_width(w) for w in widths], q, new_kv, bias)
+    return out, new_kv
+
+
+def _leading(a, layer, width: int, name: str):
+    """Layer ``layer``'s leading ``width`` positions of a cache array in
+    ``decode_kv_layout`` (``[L, B, C, H*Dh]``, scales ``[L, B, H, C]``;
+    ``layer`` ``None``: the array is one layer's own), taken in ONE slice.
+    Sliced twice (``a[layer]``, then ``[:, :width]``) inside a branch of a
+    ``switch`` the v5e compiler stages the whole layer into ``S(1)`` first
+    and narrows it after: every byte is read again
+    (``tests/test_tpu_compile.py`` keeps a case that shows it)."""
+    axis = a.ndim - (1 if name.endswith("_scale") else 2)
+    start, limit = [0] * a.ndim, list(a.shape)
+    limit[axis] = width
+    if layer is None:
+        return jax.lax.slice(a, start, limit)
+    start[0], limit[0] = layer, layer + 1
+    return jax.lax.squeeze(jax.lax.slice(a, start, limit), (0,))
 
 
 class Latent(NamedTuple):

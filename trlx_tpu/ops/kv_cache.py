@@ -383,14 +383,61 @@ def layer_cache(cache, i: int):
     return cache[i]
 
 
+# the key under which :func:`written_to_index` carries the sampler's promise
+WRITTEN_TO_INDEX = "written_to_index"
+
+
+def written_to_index(cache, first_index: int):
+    """A cache in :func:`decode_kv_layout` (the carry, or a tuple of folded
+    layers) with its caller's promise, under ``"written_to_index"`` in every
+    dict a layer is handed: positions past a call's ``cache_index`` hold
+    nothing this call may read, and the first call comes at ``first_index``.
+    What a traced ``cache_index`` cannot show a layer and the fixed
+    sampler's loop knows of itself (its prefill fills ``[0, Q)``, step ``t``
+    writes ``Q + t``): ``ops/attention.py::_decode_read`` then reads the
+    leading :func:`decode_read_widths` positions and no more. A static key
+    for one call, like ``"layer"`` and ``"first_block"``: the read returns
+    arrays alone, and the caller takes the key off the carry its model hands
+    back before its loop carries it. A caller that makes no promise (the pp
+    stage scan, anything that is not that loop) has the whole capacity
+    read."""
+    if isinstance(cache, dict):
+        return {**cache, WRITTEN_TO_INDEX: first_index}
+    return tuple({**layer, WRITTEN_TO_INDEX: first_index} for layer in cache)
+
+
+# the lane tile of the decode read's scores ([B, H, C], C minor)
+READ_WIDTH_TILE = 128
+
+
+def decode_read_widths(capacity: int, first_index: Optional[int]) -> Tuple[int, ...]:
+    """The widths the decode read of a ``capacity``-position buffer takes
+    under a promise that its first call comes at ``first_index``
+    (:func:`written_to_index`; ``None``: no promise, the capacity alone),
+    ascending and ending at ``capacity``; a call at ``cache_index`` ``p``
+    reads the smallest that is ``>= p + 1``. Derived: the multiples of the
+    scores' lane tile inside ``(first_index, capacity)``, then the capacity;
+    where that is more than four, every ``ceil(n / 4)``-th counted back from
+    the capacity. (64, 512): 128, 256, 384, 512; (512, 560): 560 alone;
+    (512, 2560): 1024, 1536, 2048, 2560."""
+    if first_index is None:
+        return (capacity,)
+    tile = READ_WIDTH_TILE
+    widths = [*range((first_index // tile + 1) * tile, capacity, tile), capacity]
+    stride = -(-len(widths) // 4)
+    return tuple(widths[(len(widths) - 1) % stride :: stride])
+
+
 def with_layer_cache(cache, i: int, new_kv):
     """What the model was handed with layer ``i``'s result put back: the
     tuple with entry ``i`` replaced, or the carry as the layer's read
-    returned it (written in place at ``i``, every other layer untouched)."""
+    returned it (written in place at ``i``, every other layer untouched),
+    under what else the model was handed with it (the sampler's promise,
+    :func:`written_to_index`, is for every layer of the call)."""
     if cache is None:
         return None
     if isinstance(cache, dict):
-        return new_kv
+        return {**cache, **new_kv}
     return (*cache[:i], new_kv, *cache[i + 1:])
 
 
@@ -503,6 +550,9 @@ class CacheKind(NamedTuple):
     layer: Optional[int] = None
     # one row a position under ``"k"`` and no ``"v"`` (:func:`latent_buffers`)
     latent: bool = False
+    # the first index of the caller's promise that nothing past a call's
+    # ``cache_index`` is read (:func:`written_to_index`; ``None``: no promise)
+    written_to_index: Optional[int] = None
 
 
 def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
@@ -518,7 +568,9 @@ def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
     ``tail`` names the keys that live by slot; a layer of ``"k"`` without
     ``"v"`` is ``latent``. (``"first_block"`` beside
     ``"slot_ids"`` is a caller's promise about one call,
-    :func:`starting_at_block`; it changes no layer's kind.)"""
+    :func:`starting_at_block`; it changes no layer's kind.
+    ``"written_to_index"`` is the fixed sampler's about one call of its
+    loop, :func:`written_to_index`, reported as it was given.)"""
     if "ssm_state" in cache_kv:
         return CacheKind(STATE, False, False, tail=tuple(sorted(cache_kv)))
     layer = cache_kv.get(LAYER)
@@ -536,6 +588,7 @@ def cache_kind(cache_kv: Dict[str, jax.Array]) -> CacheKind:
         tuple(sorted(k for k in cache_kv if k.startswith(TAIL_PREFIX))),
         layer,
         "v" not in cache_kv,
+        cache_kv.get(WRITTEN_TO_INDEX),
     )
 
 

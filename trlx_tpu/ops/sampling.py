@@ -30,7 +30,13 @@ import flax.struct as struct
 import jax
 import jax.numpy as jnp
 
-from trlx_tpu.ops.kv_cache import cache_kind, decode_kv_layout
+from trlx_tpu.ops.kv_cache import (
+    WRITTEN_TO_INDEX,
+    cache_kind,
+    decode_kv_layout,
+    decode_read_widths,
+    written_to_index,
+)
 from trlx_tpu.telemetry import get_metrics
 from trlx_tpu.utils import topk_mask
 
@@ -401,6 +407,18 @@ def make_sampler(
     compiler to stage and write back whole, else a folded dict a layer
     (the gauge ``sampler/carry_buffers`` counts the carry's arrays when the
     sampler is traced: 2, or 4 with int8; times the layers for the latter).
+
+    What the loop knows and no layer can see, it promises the decode read:
+    the prefill fills ``[0, Q)``, step ``t`` writes ``Q + t`` and nothing
+    past it holds anything. Each step puts that on the folded cache it
+    hands the model (``kv_cache.py::written_to_index(cache, Q)``) and takes
+    it off what comes back, so the carry is arrays alone; the read then
+    takes the leading ``decode_read_widths(Q + R, Q)`` positions and no
+    more (64 + 448: 128, 256, 384, 512; 512 + 48: 560 alone, the program
+    it was). The gauge ``sampler/read_share`` is the mean over the ``R``
+    steps of (the width a step reads / the capacity). A cache whose
+    capacity axis is sharded, and the pp sampler's layer-major dict (its
+    stage scan takes the cache apart itself), are promised nothing.
     """
     Q = query_length
     R = gen_config.max_new_tokens
@@ -473,6 +491,12 @@ def make_sampler(
             )
         cache = out["cache"]
         carry_sharding = cache_sharding
+        # what this loop can promise the decode read and no layer can see:
+        # the prefill filled [0, Q), step t writes Q + t, nothing past it
+        # holds anything (kv_cache.py::written_to_index). Made for the
+        # model's own layers over a folded cache; the pp stage scan takes
+        # its layer-major dict apart itself and is promised nothing
+        first_written = None
         if not capacity_sharded(cache):
             # the decode loop carries the lane-dense layout, which
             # decode_attention writes in place and reads once a step: all
@@ -480,6 +504,7 @@ def make_sampler(
             # be staged and written back whole, else a layer at a time
             prefilled, cache = cache, decode_kv_layout(cache)
             stacked = isinstance(cache, dict) and not isinstance(prefilled, dict)
+            first_written = None if isinstance(prefilled, dict) else Q
             if cache_sharding is not None and stacked:
                 # a tuple's carry leads with the layers, which no axis shards
                 carry_sharding = jax.sharding.NamedSharding(
@@ -489,6 +514,11 @@ def make_sampler(
         cache = pin_cache(cache, carry_sharding)
         get_metrics().gauge("sampler/carry_buffers").set(
             len(jax.tree_util.tree_leaves(cache))
+        )
+        # the mean over the R steps of (the width step t reads / capacity)
+        widths = decode_read_widths(cap, first_written)
+        get_metrics().gauge("sampler/read_share").set(
+            sum(min(w for w in widths if w > Q + t) for t in range(R)) / (max(R, 1) * cap)
         )
         logits_last = out["logits"][:, -1].astype(jnp.float32)  # [B, V]
         if with_values:
@@ -529,7 +559,7 @@ def make_sampler(
                 token[:, None],
                 attention_mask=cache_mask_t,
                 position_ids=(n_real + t)[:, None],
-                cache=cache,
+                cache=cache if first_written is None else written_to_index(cache, first_written),
                 cache_index=Q + t,
             )
             new_logits = out["logits"][:, 0].astype(jnp.float32)
@@ -538,7 +568,12 @@ def make_sampler(
                 if with_values
                 else jnp.zeros((B,), jnp.float32)
             )
-            return (t + 1, pin_cache(out["cache"], carry_sharding), new_logits,
+            new_cache = out["cache"]
+            if isinstance(new_cache, dict):
+                # the model hands the carry back as it came, promise and
+                # all; the loop carries the arrays
+                new_cache = {k: a for k, a in new_cache.items() if k != WRITTEN_TO_INDEX}
+            return (t + 1, pin_cache(new_cache, carry_sharding), new_logits,
                     new_value, finished, rng, ys)
 
         if gen_config.max_length > 0:
@@ -551,7 +586,13 @@ def make_sampler(
         # emits, (pad, 0, 0.0, 0.0), and each step writes its row `t`, so
         # they are bitwise what the full R-step run gives (rows never
         # un-finish; the RNG carry is not an output). The cache is a plain
-        # loop carry — no `cond` around it, whose branch boundaries copy it.
+        # loop carry: no `cond` hands it through, whose branch boundaries
+        # copy it (PERF.md §6, PR 25). The one `switch` in the step is
+        # around the decode read (attention.py::_decode_read): its branches
+        # take the carry read-only and return a layer's output, and
+        # compiled for a described v5e at longgen's shapes the body holds
+        # no cache-shaped copy, nothing of the carry in S(1), and the 96
+        # writes in place (tests/test_tpu_compile.py; PERF.md §6, PR 56).
         ys0 = (
             jnp.full((R, B), gen_config.pad_token_id, jnp.int32),
             jnp.zeros((R, B), jnp.int32),
