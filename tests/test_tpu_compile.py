@@ -130,7 +130,66 @@ def test_cca_decode_layer_steps_its_tail_and_holds_no_copy_of_its_pool(one_chip)
     assert not re.search(r"= f32\[(%d,%d|%d),2,128\]" % (B, C, B * C), entry)
 
 
-def test_latent_decode_layer_reads_its_pool_in_stored_order(one_chip):
+def _latent_sublayer(one_chip, held, B, C, n_blocks, T, rows=None):
+    """One ``DeepseekV3Attention`` sublayer at published widths over a pool
+    of ``B`` slots x ``C`` positions, compiled with the layer donated:
+    ``T`` columns a row, for all slots (a decode step) or for a group of
+    ``rows`` slots at a traced first block (a chunk of an admission). With
+    ``held`` the pool is as the engine holds it across programs, taken from
+    the rule and not restated (``ops/kv_cache.py::hold_pool``: rows padded
+    to whole lanes); without, as the model's ``init_cache`` makes it. Every
+    layout is the runtime's own. Returns the entry computation's
+    pool-shaped ``copy`` / ``transpose`` lines and the pool parameter's."""
+    from trlx_tpu.models.deepseek_v3 import DeepseekV3Attention, DeepseekV3Config, init_deepseek_v3_cache
+    from trlx_tpu.ops import kv_cache as kc
+
+    cfg = DeepseekV3Config(num_hidden_layers=1, first_k_dense_replace=1, dtype="bfloat16", param_dtype="bfloat16")
+    module = DeepseekV3Attention(cfg)
+    A = rows or B
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+
+    def make():
+        layer = dict(init_deepseek_v3_cache(cfg, B, C)[0], block_tables=jnp.zeros((A, n_blocks), jnp.int32))
+        if rows:
+            layer["slot_ids"] = jnp.zeros((A,), jnp.int32)
+        return kc.hold_pool(layer) if held else layer
+
+    layer = jax.eval_shape(make)
+    width = layer["k"].shape[-1]
+    assert (cfg.latent_width, width) == (576, 640 if held else 576)
+    if rows:
+        assert kc.writes_whole_blocks(layer, jnp.zeros((A, T, 1, 1)), 0)
+    view = C if not rows else 512
+    args = (sds((A, T, cfg.hidden_size), jnp.bfloat16), sds((A, 1, 1, view), jnp.float32), sds((A, T), jnp.int32))
+    index = sds((), jnp.int32) if rows else sds((B,), jnp.int32)
+    params = jax.eval_shape(
+        lambda *a: module.init(jax.random.PRNGKey(0), *a), *args, layer, jnp.zeros(index.shape, jnp.int32)
+    )
+
+    def step(params, x, bias, pos, layer, index):
+        if rows:  # chunk ``index`` of an admission: whole blocks from a traced first block
+            (layer,) = kc.starting_at_block((layer,), index * (T // (C // n_blocks)))
+            index = index * T
+        return module.apply(params, x, bias, pos, layer, index)
+
+    compiled = jax.jit(step, donate_argnums=(4,)).lower(on_chip(params), *args, on_chip(layer), index).compile()
+    entry = compiled.as_text().split("\nENTRY ", 1)[1].split("\n")
+    pool = r"\[%d,%d(,1)?,%d\]" % (B, C, width)
+    assert not [l for l in entry if re.search(r"= f32" + pool, l)]
+    copies = [l for l in entry if re.search(r"= bf16" + pool + r"\S* (copy|transpose)\(", l)]
+    (parameter,) = [l for l in entry if re.search(r"= bf16" + pool + r"\S* parameter\(", l)]
+    return copies, parameter
+
+
+HELD = pytest.mark.parametrize("held", [True, False], ids=["held_in_rows_of_whole_lanes", "as_the_model_allocates_it"])
+
+
+@HELD
+def test_latent_decode_layer_reads_its_pool_in_stored_order(one_chip, held):
     """One latent-attention sublayer of the ``serve-deepseekv3-reason1k``
     decode step at published widths (64 slots x 1536 positions of one
     576-value row, bf16; 128 heads): one row a slot written in place, the
@@ -138,36 +197,31 @@ def test_latent_decode_layer_reads_its_pool_in_stored_order(one_chip):
     float32 copy of the pool exists, and nothing re-lays the pool between
     the write and the two products that read it: with the ``H`` queries on
     the left of the scores' product (the grouped read at one KV head) it
-    copied the written pool position-minor, 113 MB a layer and step. What is
-    left are the two copies at the program's edges, from and to the layout
-    the runtime gives a pool whose rows are no multiple of 128 lanes
-    (position-minor, ``{1,3,2,0}``): PERF.md section 6, PR 53."""
-    from trlx_tpu.models.deepseek_v3 import DeepseekV3Attention, DeepseekV3Config, init_deepseek_v3_cache
-
-    cfg = DeepseekV3Config(num_hidden_layers=1, first_k_dense_replace=1, dtype="bfloat16", param_dtype="bfloat16")
-    B, C, n_blocks = 64, 1536, 96
-    module = DeepseekV3Attention(cfg)
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
-    layer = jax.eval_shape(lambda: dict(init_deepseek_v3_cache(cfg, B, C)[0], block_tables=jnp.zeros((B, n_blocks), jnp.int32)))
-    args = (sds((B, 1, cfg.hidden_size), jnp.bfloat16), sds((B, 1, 1, C), jnp.float32), sds((B, 1), jnp.int32))
-    index = sds((B,), jnp.int32)
-    params = jax.eval_shape(lambda *a: module.init(jax.random.PRNGKey(0), *a), *args, layer, index)
-
-    def step(params, x, bias, pos, layer, index):
-        return module.apply(params, x, bias, pos, layer, index)
-
-    compiled = jax.jit(step, donate_argnums=(4,)).lower(on_chip(params), *args, on_chip(layer), index).compile()
-    entry = compiled.as_text().split("\nENTRY ", 1)[1]
-    pool = r"\[%d,%d(,1)?,576\]" % (B, C)
-    assert not re.search(r"= f32" + pool, entry)
-    copies = [l for l in entry.split("\n") if re.search(r"= bf16" + pool + r"\S* (copy|transpose)\(", l)]
-    assert len(copies) <= 2, copies
+    copied the written pool position-minor, 113 MB a layer and step
+    (PERF.md section 6, PR 53). Held as the engine holds it, rows of whole
+    lanes, the runtime lays the pool row-minor itself and **no** operation
+    of the program copies or transposes a pool (PR 57). The second case
+    documents why: a pool whose rows are no multiple of 128 lanes the
+    runtime lays position-minor (``{1,3,2,0}``) and the program copies it at
+    both edges; a libtpu that stops doing so fails that case by name, and
+    the rule has lost its reason."""
+    copies, parameter = _latent_sublayer(one_chip, held, B=64, C=1536, n_blocks=96, T=1)
     # none of them follows the write: the scatter's result feeds the reads as it is
     assert not [l for l in copies if "scatter" in l.split("metadata=")[-1]]
+    assert len(copies) == (0 if held else 2), copies
+    assert ("{3,1,2,0:" if held else "{1,3,2,0:") in parameter, parameter
+
+
+@HELD
+def test_a_latent_chunk_admission_holds_no_copy_of_its_pool(one_chip, held):
+    """The same sublayer in a chunk of an admission (a group of 8 rows,
+    ``T`` 128 columns at a traced first block, whole blocks of 16 scattered
+    into the pool viewed by blocks, ``ops/kv_cache.py::_scatter_blocks``):
+    held by the engine's rule no pool-shaped copy either, and the same two
+    as the model's ``init_cache`` makes it, so the ten copies go from a
+    chunk forward as from a decode step."""
+    copies, _ = _latent_sublayer(one_chip, held, B=64, C=1536, n_blocks=96, T=128, rows=8)
+    assert len(copies) == (0 if held else 2), copies
 
 
 @pytest.mark.parametrize("T,first", [(128, "traced"), (512, 0)], ids=["chunk", "whole"])

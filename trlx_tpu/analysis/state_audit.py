@@ -260,6 +260,11 @@ EPHEMERAL_CONTRACTS: Dict[Tuple[str, str], str] = {
         "memoized FLOP cost per chunk shape (pure function of config); "
         "refilled on first use after rebuild"
     ),
+    ("ContinuousBatchingEngine", "_latent_pinned_share"): (
+        "gauge value read off the fresh state's arrays by init_state (how "
+        "the latent pools lie on the device: a function of the config and "
+        "the backend); read again by the next init_state, feeds no token"
+    ),
     # ---- QoS scheduler ------------------------------------------------ #
     ("QoSScheduler", "_queues"): (
         "in-flight request queues: the preemption contract drains the "

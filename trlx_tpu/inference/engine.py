@@ -113,6 +113,8 @@ from trlx_tpu.ops.kv_cache import (
     choose_block_size,
     choose_prefill_chunk,
     empty_share_tables,
+    held_row_width,
+    hold_pool,
     identity_block_tables,
     init_shared_pool,
     starting_at_block,
@@ -568,7 +570,11 @@ class ContinuousBatchingEngine:
         )
         self._param_shardings = param_shardings
         self._cache_sharding = cache_sharding
+        self._latent_pinned_share: Optional[float] = None  # read in init_state
         self._cache_gb = self._measure_cache()
+        self._pads_a_pool = jax.eval_shape(self._held_cache) != jax.eval_shape(
+            lambda: self._init_cache_fn(self.num_slots, self.capacity)
+        )
         self._build_programs()
 
         # host bookkeeping (reset per phase)
@@ -669,12 +675,34 @@ class ContinuousBatchingEngine:
         state = self._make_state()
         if self.mesh is not None:
             state = jax.device_put(state, self.state_sharding())
+        self._measure_pinned(state)
         return state
+
+    def _measure_pinned(self, state: EngineState) -> None:
+        """Gauge ``cache/latent_pinned_share``: the share of the latent
+        pools' bytes that ``state`` holds as its programs compute on them,
+        read off the arrays as they lie: rows of whole lanes
+        (``ops/kv_cache.py::held_row_width``) and, of the axes that are
+        longer than one, the row minor-most on the device and the positions
+        next. Nothing where there is no latent pool."""
+        held = total = 0
+        for layer in state.cache:
+            if not cache_kind(layer).latent:
+                continue
+            pool = layer["k"]
+            total += pool.nbytes
+            order = [a for a in pool.format.layout.major_to_minor if pool.shape[a] > 1]
+            if held_row_width(layer) == pool.shape[-1] and order[-2:] == [1, 3]:
+                held += pool.nbytes
+        self._latent_pinned_share = held / total if total else None
+        self._publish_cache_gauges(self._cache_gb)
 
     def _measure_cache(self) -> Dict[str, float]:
         """What the pool will hold (GB; gauges ``cache/state_gb``,
         ``cache/kv_gb``, ``cache/tail_gb`` and ``cache/latent_gb``), from
-        shapes alone: a state layer's rows, the pools of keys and values,
+        the shapes the model asks for (logical bytes: the state holds a
+        latent row padded to whole lanes, ``_make_state``, 576 -> 640
+        values, a ninth more): a state layer's rows, the pools of keys and values,
         the rows a layer of keys keeps a slot beside them
         (``cache_kind(...).tail``) and the pools of latent rows
         (``.latent``); and the refusals they bring. By-slot rows cannot be
@@ -720,16 +748,27 @@ class ContinuousBatchingEngine:
         self._publish_cache_gauges(gb)
         return gb
 
-    @staticmethod
-    def _publish_cache_gauges(gb: Dict[str, float]) -> None:
+    def _publish_cache_gauges(self, gb: Dict[str, float]) -> None:
         registry = telemetry.get_metrics()
         for key, value in gb.items():
             registry.gauge(f"cache/{key}_gb").set(value)
+        if self._latent_pinned_share is not None:
+            registry.gauge("cache/latent_pinned_share").set(self._latent_pinned_share)
+
+    def _held_cache(self):
+        """The model's cache with every pool as its holder keeps it across
+        programs: a latent pool's rows padded to whole lanes, or each
+        program copies the pool in and out (``ops/kv_cache.py::hold_pool``)."""
+        return tuple(
+            hold_pool(layer) for layer in self._init_cache_fn(self.num_slots, self.capacity)
+        )
 
     def _make_state(self) -> EngineState:
         B, Q, R, V = self.num_slots, self.Q, self.R, self.vocab_size
         cfg = self.gen_config
-        linear = self._init_cache_fn(B, self.capacity)
+        # a padded pool is built in one program, so that the zeros it is
+        # padded from never lie beside it
+        linear = jax.jit(self._held_cache)() if self._pads_a_pool else self._held_cache()
         tables = identity_block_tables(B, self.n_blocks)
         # one table array PER layer (logically shared, physically
         # distinct): the jitted programs donate the whole state, and XLA

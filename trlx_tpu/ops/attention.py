@@ -467,13 +467,16 @@ def latent_attention(q, rows, bias, latent: Latent, *, scale, causal: bool = Fal
     view of the pool): the products are over ``K`` rows once, not once a
     query."""
     C, H = latent.w_ukv.shape[:2]
+    # a row's own columns: the pool it was gathered from may be held wider
+    # (``ops/kv_cache.py::hold_pool``)
+    rope = q.shape[-1] - latent.nope
     with jax.named_scope("mla_decompress"):
         kv = jnp.einsum(
             "bkc,chd->bkhd", rows[:, :, 0, :C], latent.w_ukv,
             preferred_element_type=jnp.float32,
         ).astype(q.dtype)
         shared = jnp.broadcast_to(
-            rows[:, :, :, C:], rows.shape[:2] + (H, rows.shape[-1] - C)
+            rows[:, :, :, C : C + rope], rows.shape[:2] + (H, rope)
         )
         k = jnp.concatenate([kv[..., : latent.nope], shared], axis=-1)
     return dot_product_attention(
@@ -492,7 +495,8 @@ def _latent_absorbed_read(q, rows, bias, scale, latent: Latent):
     Both products take the rows in the order they are stored, ``[B, K, C +
     rope]``: the scores with the rows on the left (``K`` rows times the
     ``H`` queries, ``[B, K, H]``), the values with the rows on the right
-    (summed over ``K``). Written as ``H`` queries times the rows' transpose
+    (summed over ``K``); where the pool is held wider than a row, a row's
+    own columns of it. Written as ``H`` queries times the rows' transpose
     (:func:`dot_product_attention`'s grouped read at one KV head) the v5e
     compiler re-laid the whole pool position-minor for the first product
     and back for the second, two pool-sized copies a layer and step.
@@ -505,7 +509,10 @@ def _latent_absorbed_read(q, rows, bias, scale, latent: Latent):
             preferred_element_type=jnp.float32,
         ).astype(q.dtype)
         q_abs = jnp.concatenate([q_lat, q[..., nope:]], axis=-1)[:, 0]  # [B, H, C + rope]
-        stored = rows[:, :, 0, :]  # [B, K, C + rope]
+        # a row's own columns of a pool that may be held wider
+        # (``ops/kv_cache.py::hold_pool``), taken of the rows viewed [B, K, W]:
+        # taken of the four-axis pool the slice re-laid the whole pool
+        stored = rows[:, :, 0, :][..., : q_abs.shape[-1]]  # [B, K, C + rope]
         scores = jnp.einsum(
             "bkd,bhd->bkh", stored, q_abs, preferred_element_type=jnp.float32
         ) * jnp.float32(scale)

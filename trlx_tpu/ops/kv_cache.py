@@ -61,7 +61,11 @@ row's leading columns, or what an up-projection makes of them). It is
 written, paged, scattered by block (one head: ``H`` = 1) and read as
 stored like any pool of keys; every write and read here skips the absent
 ``"v"``. ``cache_kind(...).latent`` says so. No int8 form, no shared-prefix
-pool (both refused by name where they would be built).
+pool (both refused by name where they would be built). Whoever holds such
+a pool across programs holds its rows padded to whole lanes
+(:func:`hold_pool`), or every program copies the pool in and out; a pool
+may therefore be **wider than the rows written to it**, the write pads a
+row with zeros and the reads take a row's own columns.
 
 The pools are sized by **KV heads**: a family with fewer KV heads than
 query heads (grouped-query attention) allocates and reads ``H_kv`` of them,
@@ -294,6 +298,75 @@ def latent_buffers(
         )
     shape = (batch_size, capacity, 1, width)
     return tuple({"k": jnp.zeros(shape, jnp.dtype(dtype))} for _ in range(n_layer))
+
+
+# A lane row of the TPU's vector memory: the minor axis of a device buffer
+# is tiled by it.
+LANES = 128
+
+
+def held_row_width(layer_kv) -> int:
+    """The width at which one layer's pool of rows is held by whoever keeps
+    it across programs (the engine's state): a **latent** pool whose row is
+    no whole number of lanes, the next whole number of them (576 -> 640);
+    every other layer, and a latent row of whole lanes, the width it has.
+    Decided on what the layer is, nothing else. What was compiled and seen,
+    one ``DeepseekV3Attention`` sublayer's decode step and a chunk of an
+    admission for a described v5e:2x2 at the seventh cell's widths (bf16,
+    64 slots x 1536 positions, the pool donated; jax 0.9.0, libtpu 0.0.34;
+    ``tests/test_tpu_compile.py``; PERF.md section 6, PR 57):
+
+    - ``[64, 1536, 1, 576]``: the runtime lays it **position-minor**
+      (``{1,3,2,0:T(8,128)(2,1)}``: 576 is no multiple of 128 lanes and 1536
+      is; ``[64, 1536, 576]`` and ``[1, 64, 1536, 576]`` alike), the in-place
+      write and both absorbed products compute on it row-minor
+      (``{3,1,0,2}``), so the program copies the pool on entry and again
+      before its result: two copies of 113 MB a layer in every program that
+      runs the model, ten a decode step at five layers (0.97 of a 7.93 s
+      slice, ledger PR 56);
+    - ``[64, 1536, 1, 640]``: the runtime lays it row-minor itself
+      (``{3,1,2,0:T(8,128)(2,1)}``, positions second-minor), the write is in
+      place on the parameter and no pool-shaped ``copy`` or ``transpose`` is
+      left in ``ENTRY``, with the reads taking ``[..., :576]`` of the rows
+      viewed ``[B, K, W]`` (taken of the four-axis pool, before the head
+      axis is dropped, the slice brought one position-minor copy back);
+    - the layout pinned on the 576-wide pool instead
+      (``jax.experimental.layout.Format(Layout(major_to_minor=(2, 0, 1, 3)),
+      sharding)`` on the argument and the result of every program, what
+      ISSUE 57 asked for): compiled cold it has no copy either and ran 22.4
+      against 26.0 ms a step on the chip, **and it does not survive the
+      persistent compile cache**: an executable that jax loads from it hands
+      back arrays labelled with the runtime's default layout whatever it was
+      compiled to return (the bytes are as compiled: a device-to-host read
+      is right), so the next program, whose ``in_shardings`` hold the
+      ``Format``, refuses its own state (``Layout passed to jit does not
+      match``); every warm start of a server died there. A pool of whole
+      lanes needs no layout of its own, so nothing is pinned.
+
+    The padding is what the pinned layout pays too (a device row is whole
+    lanes either way): a ninth more than the logical bytes
+    ``cache/latent_gb`` counts."""
+    width = layer_kv["k"].shape[-1] if "k" in layer_kv else 0
+    return -(-width // LANES) * LANES if cache_kind(layer_kv).latent else width
+
+
+def _zero_padded(rows: jax.Array, width: int) -> jax.Array:
+    pad = width - rows.shape[-1]
+    if pad == 0:
+        return rows
+    return jnp.pad(rows, ((0, 0),) * (rows.ndim - 1) + ((0, pad),))
+
+
+def hold_pool(layer_kv):
+    """One layer's cache dict as its holder keeps it: the pool's rows
+    zero-padded to :func:`held_row_width` (a latent pool of 576-value rows,
+    640 wide), every other key and every other kind as it is. Zeros: a
+    padded column is never read (the reads take a row's own columns), and a
+    row is written whole, its padding with it (:func:`paged_write_read`)."""
+    width = held_row_width(layer_kv)
+    if "k" not in layer_kv or width == layer_kv["k"].shape[-1]:
+        return layer_kv
+    return dict(layer_kv, k=_zero_padded(layer_kv["k"], width))
 
 
 # The largest folded buffer of one layer that a loop carrying it on its own
@@ -1256,7 +1329,9 @@ def paged_write_read(
                 "a latent layer (one row a position, no values) takes v=None "
                 "and no shared-prefix overlay"
             )
-        new_kv = carry({"k": scatter("k", k)})
+        # a pool may be held wider than its rows (hold_pool): what comes
+        # back to attend over is at the pool's width, stored or gathered
+        new_kv = carry({"k": scatter("k", _zero_padded(k, cache_kv["k"].shape[-1]))})
         return (new_kv["k"] if as_stored else logical("k")), None, new_kv
 
     if kind.quantized:
