@@ -63,6 +63,12 @@ ARCHS = {
                  moe_intermediate_size=32, num_experts=4, num_experts_per_tok=1,
                  router_hidden_size=16, partial_rotary_factor=0.5,
                  rope_parameters=ZAYA_ROPE, rms_norm_eps=1e-5),
+    "qwen3_next": dict(
+        vocab_size=96, hidden_size=64, num_hidden_layers=4, full_attention_interval=4,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=8, linear_value_head_dim=16,
+        linear_chunk_size=8, moe_intermediate_size=32, shared_expert_intermediate_size=48,
+        num_experts=4, num_router_experts=8, first_local_expert=0, num_experts_per_tok=2),
 }
 
 
@@ -260,7 +266,7 @@ def test_each_excluded_name_is_a_leaf_some_program_uses_at_its_width():
     from trlx_tpu.models.registry import get_model_family
 
     earned = {}
-    for model_type in ("gpt2_moe", "granitemoehybrid", "zaya"):
+    for model_type in ("gpt2_moe", "granitemoehybrid", "zaya", "qwen3_next"):
         earned[model_type] = set()
         for path, (leaf, cast, names, found) in leaf_consumers(model_type, 8).items():
             if found - THROUGH_THE_CAST:

@@ -215,3 +215,21 @@ def _register_builtins() -> None:
             init_deepseek_v3_cache, no_deepseek_v3_checkpoint,
         )
     )
+    from trlx_tpu.models.qwen3_next import (
+        QWEN3_NEXT_PARTITION_RULES,
+        Qwen3NextConfig,
+        Qwen3NextModel,
+        init_qwen3_next_cache,
+        no_qwen3_next_checkpoint,
+    )
+
+    # not supports_ep: nothing trains its router (no loss is sown), and the
+    # model refuses an ep axis by name
+    register_model_family(
+        ModelFamily(
+            "qwen3_next", Qwen3NextConfig, Qwen3NextModel, QWEN3_NEXT_PARTITION_RULES,
+            init_qwen3_next_cache, no_qwen3_next_checkpoint,
+            # the convolution's taps (ops/ssm.py::causal_conv multiplies at f32)
+            stored_width_leaves=("conv_weight",),
+        )
+    )

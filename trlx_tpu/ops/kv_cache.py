@@ -559,21 +559,24 @@ def hybrid_cache(
     kv_cache_dtype: str,
     state: Dict[str, int],
     state_dtype: str = "float32",
+    keys: Tuple[str, ...] = ("attention",),
 ) -> Cache:
     """The cache of a model whose layers differ: :func:`kv_buffers` for an
-    ``"attention"`` entry of ``layer_types``, :func:`state_buffers` (with
-    the sizes in ``state``) for any other. ``int8`` has no state form and
+    entry of ``layer_types`` that ``keys`` names (the caller says which of
+    its family's layer kinds hold keys), :func:`state_buffers` (with the
+    sizes in ``state``) for any other. ``int8`` has no state form and
     a step that reads one layer in ten through it gains nothing: with a
     state layer it is refused by name (``auto`` could resolve to it)."""
-    if any(t != "attention" for t in layer_types) and kv_cache_dtype != "bfloat16":
+    stateful = sorted(set(layer_types) - set(keys))
+    if stateful and kv_cache_dtype != "bfloat16":
         raise ValueError(
             f"kv_cache_dtype={kv_cache_dtype!r} with a state layer "
-            f"({sorted(set(layer_types) - {'attention'})}) is not built: a "
+            f"({stateful}) is not built: a "
             "state has no int8 form; choose 'bfloat16'"
         )
     return tuple(
         kv_buffers(1, batch_size, capacity, n_kv_head, head_dim, dtype, kv_cache_dtype)[0]
-        if kind == "attention"
+        if kind in keys
         else state_buffers(batch_size, state_dtype=state_dtype, **state)
         for kind in layer_types
     )
