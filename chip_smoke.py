@@ -553,8 +553,10 @@ _CUSTOM_CALL_SHAPE = re.compile(r"=\s*\(?\s*\w+\[([\d,]+)\]")
 
 
 def flash_call_shapes(hlo_text):
-    """[B, H, T, D] result shapes of the Mosaic custom calls in a compiled
-    (post-partitioning, so per-device) HLO module."""
+    """Result shapes of the Mosaic custom calls in a compiled
+    (post-partitioning, so per-device) HLO module: a call's first result,
+    q-shaped as the kernels hold it ([B, T, H * D], or heads-major
+    [B * H, T, D]: ``ops/flash_attention.py::operand_layout``)."""
     shapes = []
     for line in hlo_text.splitlines():
         if 'custom_call_target="tpu_custom_call"' not in line:
@@ -616,15 +618,16 @@ def leg_flash_in_train_step(arch, mesh, out_dir, *, seq_length=960,
             "train step: its attention did not reach the flash kernels",
         )
         axes = dict(trainer.mesh.shape)
-        local = (
-            batch_size // (axes["dp"] * axes["fsdp"]),
-            arch["n_head"] // axes["tp"],
-        )
-        gathered = sorted({s for s in shapes if s[:2] != local})
+        rows = batch_size // (axes["dp"] * axes["fsdp"])
+        heads = arch["n_head"] // axes["tp"]
+        T, D = seq_length + new_tokens, arch["n_embd"] // arch["n_head"]
+        local = {(rows, T, heads * D), (rows * heads, T, D)}
+        gathered = sorted(set(shapes) - local)
         check(
             not gathered,
             f"flash custom calls on mesh {axes} see {gathered}, not the "
-            f"per-device [B, H] = {local}: operands were gathered",
+            f"per-device {rows} rows x {heads} heads ({sorted(local)}): "
+            "operands were gathered",
         )
     trainer.state, stats = compiled(trainer.state, batch)
     loss = float(np.asarray(stats["losses/total_loss"]))

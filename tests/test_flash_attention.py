@@ -8,6 +8,9 @@ T5-style per-head biases. Gradients are checked through the custom VJP
 against JAX autodiff of the reference path.
 """
 
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,6 +29,7 @@ from trlx_tpu.ops.flash_attention import (
     _row_chunk,
     fitted_block,
     flash_attention,
+    operand_layout,
 )
 
 RNG = np.random.default_rng(0)
@@ -163,7 +167,7 @@ def test_tiles_are_fitted_to_the_length():
     # the rows a loop iteration of the one-tile kernels takes: the whole tile
     # while it is small, else a divisor of it in bf16 sublanes
     assert [_row_chunk(t) for t in (8, 32, 128, 512, 560, 576, 592, 640, 1008)] == [
-        8, 32, 128, 128, 112, 96, 16, 128, 112,
+        8, 32, 128, 256, 112, 192, 16, 160, 144,
     ]
     assert all(
         _row_chunk(t) <= ROW_CHUNK and t % _row_chunk(t) == 0
@@ -171,11 +175,34 @@ def test_tiles_are_fitted_to_the_length():
     )
 
 
-@pytest.mark.parametrize("D", [64, 128])
-@pytest.mark.parametrize("T", [512, 560, 640])
-def test_fitted_tiles_match_xla_at_the_update_lengths(T, D):
+def test_operands_fold_where_whole_lanes_of_heads_divide_the_head_count():
+    # (heads a grid step, folded to [B, T, H * Dh]?) from the call's own H, Dh
+    assert [tuple(operand_layout(H, D)[2:]) for H, D in (
+        (16, 64), (4, 128), (16, 256), (4, 32), (3, 64), (4, 80), (2, 16),
+    )] == [
+        (2, True), (1, True), (1, True), (4, True), (1, False), (1, False),
+        (1, False),
+    ]
+
+
+@pytest.mark.parametrize(
+    "T,H,D",
+    [
+        (512, 2, 64), (560, 2, 64), (640, 2, 64),
+        (512, 2, 128), (560, 2, 128), (640, 2, 128),
+        (560, 16, 64),  # gpt2-medium's heads: eight blocks of two
+        (512, 4, 128),  # one head a block
+        (560, 3, 64),   # an odd count of 64-wide heads: heads-major
+        (512, 4, 80),   # heads of 80 fill no whole lanes: heads-major
+        (100, 4, 64),   # a length that pads to the tile
+        (100, 3, 64),
+    ],
+)
+def test_fitted_tiles_match_xla_at_the_update_lengths(T, H, D):
     """The uncached causal call of an update at the tiles the rule picks
-    (no ``block_q`` / ``block_k``): output and dq, dk, dv against the XLA
+    (no ``block_q`` / ``block_k``), in both operand layouts (folded: two
+    heads of 64 or one of 128 a grid step; heads-major where the heads do
+    not fill whole lanes): output and dq, dk, dv against the XLA
     path under a left-padding ``[B, 1, 1, T]`` bias. Row 0 is half padding,
     row 1 has none. A padding position's own output is uniform weights over
     the keys its row visits, on both paths and over the same keys while the
@@ -185,7 +212,7 @@ def test_fitted_tiles_match_xla_at_the_update_lengths(T, D):
     backward is only good for that (it recomputes the weights from a
     logsumexp in which -1e9 has absorbed log n, so an all-padding row's
     are 1 and not 1/n)."""
-    B, H = 2, 2
+    B = 2
     q, k, v = rand(B, T, H, D), rand(B, T, H, D), rand(B, T, H, D)
     pads = np.array([[T // 2], [0]])
     mask = jnp.asarray(np.arange(T)[None] >= pads, jnp.float32)
@@ -205,20 +232,37 @@ def test_fitted_tiles_match_xla_at_the_update_lengths(T, D):
 
 
 @pytest.mark.parametrize(
-    "Q,K,bias_shape,causal",
+    "Q,K,H,D,bias_shape,causal",
     [
-        (160, 160, (1, 2, 160, 160), False),  # per-head, per-row bias: sliced by chunk
-        (160, 200, (2, 1, 160, 200), True),   # Q < K, neither a lane multiple
-        (144, 144, None, True),               # no bias at all
-        (272, 40, (2, 1, 1, 40), False),      # cross-attention style: padding only
+        (320, 320, 2, 32, (1, 2, 320, 320), False),  # per-head, per-row bias: sliced by chunk
+        (320, 200, 2, 32, (2, 1, 320, 200), True),   # Q < K, neither a lane multiple
+        (288, 288, 2, 32, None, True),               # no bias at all
+        (272, 40, 2, 32, (2, 1, 1, 40), False),      # cross-attention style: padding only
+        # folded operands, two heads of 64 a grid step
+        (320, 320, 4, 64, (1, 4, 320, 320), False),  # a step reads its two heads' bias planes
+        (320, 320, 4, 64, (1, 1, 320, 320), True),   # [1, 1, Q, K]
+        (320, 200, 4, 64, (2, 1, 1, 200), False),    # [B, 1, 1, K]
+        (288, 288, 4, 64, None, False),
+        (288, 288, 4, 128, None, True),              # one head of 128 a step
+        (320, 320, 4, 128, (2, 1, 1, 320), True),
+        # heads-major: the same kernels, a head a step
+        (320, 320, 3, 64, (1, 3, 320, 320), True),
+        (288, 288, 4, 80, (1, 1, 288, 288), False),
+        (320, 320, 4, 80, None, True),
     ],
-    ids=["per-row-bias", "unequal-causal", "no-bias", "short-keys"],
+    ids=[
+        "per-row-bias", "unequal-causal", "no-bias", "short-keys",
+        "folded-per-head-bias", "folded-row-bias-causal", "folded-key-bias",
+        "folded-no-bias", "folded-128-no-bias-causal", "folded-128-key-bias",
+        "odd-heads-per-head-bias", "heads-of-80-row-bias", "heads-of-80-causal",
+    ],
 )
-def test_one_tile_row_loop_matches_xla(Q, K, bias_shape, causal):
+def test_one_tile_row_loop_matches_xla(Q, K, H, D, bias_shape, causal):
     """The one-tile kernels where their row loop runs more than once (over
     ``ROW_CHUNK`` query rows) for every kind of bias the BlockSpecs
-    broadcast: output and gradients against the XLA path."""
-    B, H, D = 2, 2, 32
+    broadcast, in both operand layouts: output and gradients against the
+    XLA path."""
+    B = 2
     assert fitted_block(Q) // _row_chunk(fitted_block(Q)) > 1
     q, k, v = rand(B, Q, H, D), rand(B, K, H, D), rand(B, K, H, D)
     bias = None if bias_shape is None else rand(*bias_shape)
@@ -233,6 +277,73 @@ def test_one_tile_row_loop_matches_xla(Q, K, bias_shape, causal):
     _assert_output_and_grads_match(
         xla, flash, lambda out: (out ** 2).sum(), q, k, v
     )
+
+
+@pytest.mark.parametrize(
+    "H,D,bias_shape",
+    [(4, 64, (1, 1, 1, LONG_SEQ)), (2, 128, None), (3, 64, (1, 3, 1, LONG_SEQ))],
+    ids=["two-heads-a-step", "one-head-a-step", "heads-major"],
+)
+def test_tiled_kernels_match_xla_from_long_seq(H, D, bias_shape):
+    """From ``LONG_SEQ`` the tiled kernels (``LONG_BLOCK`` x ``LONG_BLOCK``,
+    the running softmax carried between key tiles, dQ and dK/dV in two
+    kernels), causal, in each operand layout."""
+    B, T = 1, LONG_SEQ
+    q, k, v = rand(B, T, H, D), rand(B, T, H, D), rand(B, T, H, D)
+    bias = None if bias_shape is None else rand(*bias_shape)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, bias, causal=True, interpret=True)
+
+    def xla(q, k, v):
+        return dot_product_attention(q, k, v, bias, causal=True)
+
+    _assert_output_and_grads_match(
+        xla, flash, lambda out: (out ** 2).sum(), q, k, v
+    )
+
+
+def _flash_site(monkeypatch, H, D):
+    """Trace (no compile) one call site of ``dot_product_attention`` as a
+    TPU process would route it: the kernels' path for a causal T 512."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x = jax.ShapeDtypeStruct((1, 512, H, D), jnp.bfloat16)
+    jax.eval_shape(
+        lambda q, k, v: dot_product_attention(q, k, v, causal=True), x, x, x
+    )
+
+
+def test_folded_share_gauge_counts_the_flash_sites(monkeypatch):
+    """``attention/flash_folded_share``: 1.0 while every traced flash site
+    took the folded operands, under 1 once a site fell back to heads-major,
+    and never set where no call takes the kernels."""
+    from trlx_tpu.telemetry import MetricsRegistry, scoped_metrics
+
+    def gauges(registry):
+        return registry.snapshot()["gauges"]
+
+    with scoped_metrics(MetricsRegistry()) as registry:
+        q = rand(1, 16, 2, 64)
+        dot_product_attention(q, q, q, causal=True)  # the XLA path
+        assert "attention/flash_folded_share" not in gauges(registry)
+        _flash_site(monkeypatch, 16, 64)
+        _flash_site(monkeypatch, 16, 128)
+        assert gauges(registry)["attention/flash_folded_share"] == 1.0
+        _flash_site(monkeypatch, 3, 64)
+        assert gauges(registry)["attention/flash_folded_share"] == pytest.approx(2 / 3)
+        counters = registry.snapshot()["counters"]
+        assert counters["attention/flash_operands{layout=folded}"] == 2
+        assert counters["attention/flash_operands{layout=heads_major}"] == 1
+        assert counters["attention/path{path=flash}"] == 3
+    # the benchmark's ``attn_folded_share`` reads this gauge by name
+    reader = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "layer_metrics", "attn_folded_share.json",
+    )
+    with open(reader) as f:
+        assert json.load(f)["reader"] == {
+            "kind": "counter", "name": "attention/flash_folded_share",
+        }
 
 
 class TestBlockHelpers:

@@ -97,6 +97,65 @@ def test_the_updates_attention_compiles_to_the_kernels_and_no_scores(
     assert not re.search(r"\[%d,%d,%d,%d\]" % (B, H, T, T), text)
 
 
+@pytest.mark.parametrize("program,calls", [("forward", 1), ("grad", 2)])
+@pytest.mark.parametrize(
+    "B,T,H,Dh",
+    [(16, 560, 16, 64), (16, 512, 16, 64), (8, 1000, 16, 128)],
+    ids=["tldr-update", "longgen-update", "one-tile-limit-Dh128"],
+)
+def test_a_block_as_the_model_writes_it_holds_no_copy_of_q_k_v_or_o(
+    one_chip, monkeypatch, B, T, H, Dh, program, calls
+):
+    """An attention block as ``models/gpt2.py`` writes it (the fused
+    ``[B, T, 3 * H * Dh]`` projection, ``split``, the reshape to
+    ``[B, T, H, Dh]``, ``dot_product_attention(causal=True)``, the reshape
+    back, the output projection), forward and ``jax.grad``: the kernels read
+    and write ``[B, T, H * Dh]``, which is those reshapes' own array, so the
+    compiled entry holds the custom calls (the forward's; with the one
+    backward kernel under ``grad``) and no ``copy`` or ``transpose`` of a
+    q-sized array between the projections and them (before PR 59: q, k, v
+    and ``do`` in, ``o``, ``dq``, ``dk``, ``dv`` out, eight a layer, 11.1 of
+    tldr's 173.7 ms step). The block and not the bare call: a ``[B, T, H,
+    Dh]`` entry parameter is laid position-minor by the compiler and copied
+    whatever the kernels read."""
+    from trlx_tpu.ops.attention import dot_product_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    d = H * Dh
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def block(x, w_qkv, w_out, bias):
+        q, k, v = (
+            a.reshape(B, T, H, Dh) for a in jnp.split(x @ w_qkv, 3, axis=-1)
+        )
+        out = dot_product_attention(q, k, v, bias, causal=True)
+        return out.reshape(B, T, d) @ w_out
+
+    def loss(*args):
+        return jnp.sum(block(*args).astype(jnp.float32) ** 2)
+
+    fn = block if program == "forward" else jax.grad(loss, argnums=(0, 1, 2))
+    text = (
+        jax.jit(fn)
+        .lower(
+            sds((B, T, d), jnp.bfloat16), sds((d, 3 * d), jnp.bfloat16),
+            sds((d, d), jnp.bfloat16), sds((B, 1, 1, T), jnp.float32),
+        )
+        .compile()
+        .as_text()
+    )
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == calls
+    moved = [
+        line.strip()[:120]
+        for line in text.split("\nENTRY ", 1)[1].split("\n")
+        for m in [re.search(r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(", line)]
+        if m and np.prod([int(n) for n in m.group(1).split(",")]) == B * T * d
+    ]
+    assert not moved, moved
+
+
 def test_cca_decode_layer_steps_its_tail_and_holds_no_copy_of_its_pool(one_chip):
     """One CCA attention sublayer of the ``serve-zaya1-8b-reason`` decode step
     at published widths (32 slots x 1024 positions x 2 KV heads of 128, bf16

@@ -10,7 +10,10 @@ the XLA path of ``dot_product_attention`` (pinned with ``learned_bias=True``,
 which computes the same thing) against ``flash_attention`` at a list of
 tilings. A tiling is ``(padded T, block_q, block_k)``: the inputs are padded
 to ``padded T`` here, as the kernel's own prologue would, so a tile larger
-than T can be timed too.
+than T can be timed too. q, k and v are held ``[B, T, H * D]``, as a model's
+projections leave them, and each path splits the heads off by a reshape and
+folds its output back, as a model's block does: what either path then pays
+to reach its own layout is in its time.
 
 Each variant runs ``--layers`` times inside one jitted ``lax.scan`` whose
 carry depends on the result, and the window ends on ``block_until_ready``;
@@ -38,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from trlx_tpu.ops.attention import NEG_INF, dot_product_attention, padding_bias
-from trlx_tpu.ops.flash_attention import flash_attention
+from trlx_tpu.ops.flash_attention import fitted_block, flash_attention
 
 # (B, T, H, D, with the backward?)
 SHAPES = [
@@ -73,6 +76,16 @@ def tilings(T):
     if T < 1024:
         out.append((1024, 512, 512))  # what min(512, ceil8(T)) chose
     return out
+
+
+def as_the_model_holds_them(fn, H):
+    """``fn`` over ``[B, T, H, D]`` as a function of ``[B, T, H * D]``."""
+    def folded(q, k, v, bias):
+        B, T, _ = q.shape
+        out = fn(*(x.reshape(B, T, H, -1) for x in (q, k, v)), bias)
+        return out.reshape(B, T, -1)
+
+    return folded
 
 
 def xla_path(q, k, v, bias):
@@ -129,7 +142,7 @@ def looped(fn, layers, backward):
 def inputs(B, T, H, D, seed):
     rng = np.random.default_rng(seed)
     q, k, v = (
-        jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.bfloat16) for _ in range(3)
+        jnp.asarray(rng.normal(size=(B, T, H * D)), jnp.bfloat16) for _ in range(3)
     )
     # left padding as the cells have it: a row's first columns are pad,
     # anywhere from none to three quarters of the prompt. A pad position's
@@ -140,7 +153,7 @@ def inputs(B, T, H, D, seed):
     pads = rng.integers(0, (3 * T) // 4, size=B)
     mask = (np.arange(T)[None, :] >= pads[:, None]).astype(np.int32)
     w = jnp.asarray(
-        rng.normal(size=(B, T, H, D)) * mask[:, :, None, None], jnp.float32
+        rng.normal(size=(B, T, H * D)) * mask[:, :, None], jnp.float32
     )
     return q, k, v, padding_bias(jnp.asarray(mask)), w
 
@@ -182,6 +195,10 @@ def main():
         "--only", type=lambda s: [int(i) for i in s.split(",")], default=None,
         help="indices into SHAPES, comma-separated (default: all)",
     )
+    ap.add_argument(
+        "--fitted", action="store_true",
+        help="time only the tiling fitted_block chooses at each length",
+    )
     opts = ap.parse_args()
     if jax.default_backend() != "tpu":
         print("attention_crossover: no TPU; a CPU time is no crossover", file=sys.stderr)
@@ -193,12 +210,16 @@ def main():
     ):
         args = inputs(B, T, H, D, opts.seed)
         for backward in ([False, True] if has_bwd else [False]):
-            ref = once(xla_path, args, backward)
-            xla_ms = 1e3 * timed(looped(xla_path, opts.layers, backward), args, opts.reps) / opts.layers
+            xla = as_the_model_holds_them(xla_path, H)
+            ref = once(xla, args, backward)
+            xla_ms = 1e3 * timed(looped(xla, opts.layers, backward), args, opts.reps) / opts.layers
             rows.append(dict(shape=[B, T, H, D], backward=backward, path="xla", ms=xla_ms))
             print(f"[{B},{T},{H},{D}] {'fwd+bwd' if backward else 'fwd    '} xla {xla_ms:8.3f} ms", flush=True)
+            fit = fitted_block(T)
             for pad_T, bq, bk in tilings(T):
-                fn = flash_path(pad_T, bq, bk)
+                if opts.fitted and (pad_T, bq, bk) != (-(-T // fit) * fit, fit, fit):
+                    continue
+                fn = as_the_model_holds_them(flash_path(pad_T, bq, bk), H)
                 row = dict(shape=[B, T, H, D], backward=backward, path="flash", tiling=[pad_T, bq, bk])
                 try:
                     got = once(fn, args, backward)

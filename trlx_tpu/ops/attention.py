@@ -50,18 +50,22 @@ from trlx_tpu.telemetry import get_metrics
 
 NEG_INF = -1e9  # large-negative mask value; avoids -inf NaN propagation in softmax
 
-# Flash kernel dispatch, measured on one TPU v5e chip, jax 0.9.0, 2026-09-29
-# (tools/attention_crossover.py; the tables are PERF.md §6 "PR 37"): bf16,
-# 16 rows x 16 heads of 64, causal with a left-padding bias, tiles as
+# Flash kernel dispatch, measured on one TPU v5e chip, jax 0.9.0
+# (tools/attention_crossover.py; the tables are PERF.md §6 "PR 37" and
+# "PR 59"): bf16, 16 rows x 16 heads of 64 held [B, T, H * Dh] as a model's
+# projections leave them, causal with a left-padding bias, tiles as
 # flash_attention.py::fitted_block chooses them, XLA's time over the kernels'.
-# Forward + backward: T 256 0.25 (the general kernels; not read again), T 384
-# 1.05, T 512 1.69, T 560 1.82, T 1024 1.83; forward alone: 0.27, 1.04, 1.63,
-# 1.51, 1.88 (heads of 128: 1.91 and 1.63 at 512, 2.02 and 1.70 at 640). So
-# the uncached causal self-attention of an update or a scoring forward takes
-# the kernels from 512, the lowest length at which they clearly won (384 is
-# level). Every other call (a cached call's Q x K rectangle under an explicit
-# bias, where nothing is differentiated) keeps the crossover of the earlier
-# sweep of 1k-4k contexts, which these tables did not repeat.
+# Forward + backward: T 256 0.66, T 384 1.48, T 512 2.71, T 560 2.33, T 1024
+# 2.33; forward alone: 0.52, 1.54, 2.39, 1.70, 2.03 (heads of 128: 2.63 and
+# 5.56 at 512, 2.09 and 4.54 at 640). The thresholds are those of the
+# kernels before PR 59, which took heads-major operands through transposes
+# (0.25, 1.05, 1.69, 1.82, 1.83 and 0.27, 1.04, 1.63, 1.51, 1.88): the
+# uncached causal self-attention of an update or a scoring forward takes the
+# kernels from 512, the lowest length at which they clearly won then (384 was
+# level and now wins by half: moving the threshold is a change of its own).
+# Every other call (a cached call's Q x K rectangle under an explicit bias,
+# where nothing is differentiated) keeps the crossover of the earlier sweep
+# of 1k-4k contexts, which these tables did not repeat.
 FLASH_MIN_SEQ = 1024
 FLASH_MIN_SEQ_CAUSAL = 512
 
@@ -178,6 +182,24 @@ def attention_path(q_shape, k_shape, *, causal, learned_bias, scale) -> str:
     return "xla"
 
 
+def _count_flash_site(folded: bool) -> None:
+    """One traced flash call site: the counter
+    ``attention/flash_operands{layout=folded|heads_major}`` and the gauge
+    ``attention/flash_folded_share``, the share of the sites traced in the
+    process whose kernels read the caller's own ``[B, T, H * Dh]`` (1.0: no
+    site pays the transposes to heads-major; never set where no call takes
+    the kernels)."""
+    metrics = get_metrics()
+    layouts = [
+        metrics.counter("attention/flash_operands{layout=%s}" % name)
+        for name in ("folded", "heads_major")
+    ]
+    layouts[0 if folded else 1].inc()
+    sites = sum(c.value for c in layouts)
+    if sites:  # a disabled registry counts nothing
+        metrics.gauge("attention/flash_folded_share").set(layouts[0].value / sites)
+
+
 def flash_on_program_mesh(q, k, v, bias=None, *, causal=False,
                           interpret=False):
     """The flash kernels over the mesh of the program being traced.
@@ -190,12 +212,17 @@ def flash_on_program_mesh(q, k, v, bias=None, *, causal=False,
     nothing is gathered. A single-device program, and a call already inside
     a ``shard_map`` body (the pipeline's stages), run the kernel as it is.
     """
-    from trlx_tpu.ops.flash_attention import flash_attention
+    from trlx_tpu.ops.flash_attention import flash_attention, operand_layout
     from trlx_tpu.parallel.mesh import AXIS_TP, BATCH_AXES, program_mesh
 
-    kernel = functools.partial(
-        flash_attention, causal=causal, interpret=interpret
-    )
+    def kernel(q, k, v, bias=None):
+        # traced once a call site, on the shapes the kernels get (under the
+        # shard_map below: a device's own heads)
+        _count_flash_site(operand_layout(q.shape[2], q.shape[3]).folded)
+        return flash_attention(
+            q, k, v, bias, causal=causal, interpret=interpret
+        )
+
     mesh = program_mesh()
     if (
         mesh is None
