@@ -1,6 +1,6 @@
-"""Goodput & utilization attribution layer (PR 12): metrics registry,
-attribution math, run ledger + --compare/--watch, serving histograms,
-counter-track export, flight-recorder metrics embedding.
+"""Metrics registry, run ledger + --compare/--watch, serving histograms,
+counter-track export, flight-recorder metrics embedding (PR 12; the
+utilization table it came with went with its last caller, PR 60).
 
 All tier-1-cheap: pure host-side units — no trainer builds, no jit
 compiles (the heaviest fixture is a FlightRecorder dict).
@@ -171,131 +171,6 @@ def test_registry_gauge_series_feeds_counter_export():
     assert len(events) == 2
     assert all(e["name"] == "mem/hbm_live_bytes" for e in events)
     assert events[0]["args"]["value"] == 2**20
-
-
-# ------------------------- attribution fixtures --------------------------- #
-
-
-def _span_stats():
-    return {
-        "phase/collect": {"count": 5, "p50_ms": 1000.0, "total_ms": 5000.0},
-        "phase/train": {"count": 5, "p50_ms": 400.0, "total_ms": 2000.0},
-        "train/drain": {"count": 5, "p50_ms": 50.0, "total_ms": 250.0},
-        "train/epoch1_dispatch": {"count": 20, "p50_ms": 1.0, "total_ms": 20.0},
-        "train/residual": {"count": 5, "p50_ms": 10.0, "total_ms": 50.0},
-        "collect/decode": {"count": 10, "p50_ms": 400.0, "total_ms": 4000.0},
-        "collect/admit": {"count": 40, "p50_ms": 0.5, "total_ms": 100.0},
-    }
-
-
-def test_attribution_hand_computed_mfu():
-    """FLOPs ÷ span-time MFU against published v5e peaks, by hand:
-    train_step = 1e12 FLOPs x 20 fires over the 2 s train window on one
-    chip -> 1e13 FLOP/s = 10 TFLOP/s; v5e bf16 peak 197 -> MFU
-    10/197."""
-    from trlx_tpu.telemetry import attribution as A
-
-    resources = {
-        "ppo.train_step": {
-            "flops": 1.0e12,
-            "input_bytes": 50_000_000,
-            "output_bytes": 10_000_000,
-        },
-        "ppo.rollout": {
-            "flops": 2.0e11,
-            "input_bytes": 8_000_000,
-            "output_bytes": 2_000_000,
-        },
-    }
-    rows = A.attribute(
-        resources,
-        _span_stats(),
-        device_kind="TPU v5 lite",
-        n_devices=1,
-        work=A.PPO_FIXED_WORK,
-    )
-    by_program = {r.program: r for r in rows}
-    step = by_program["ppo.train_step"]
-    assert step.span == "phase/train"
-    assert step.calls == 20  # from the count_span, not the window span
-    assert step.achieved_tflops_per_dev == pytest.approx(10.0)
-    assert step.mfu == pytest.approx(10.0 / 197.0)
-    # HBM: 60 MB x 20 / 2 s = 600 MB/s over the 819 GB/s peak
-    assert step.achieved_gbps_per_dev == pytest.approx(0.6)
-    assert step.hbm_util == pytest.approx(0.6 / 819.0)
-    roll = by_program["ppo.rollout"]
-    # 2e11 x 10 / 5 s = 4e11 FLOP/s = 0.4 TFLOP/s
-    assert roll.achieved_tflops_per_dev == pytest.approx(0.4)
-    # n_devices divides the per-device FLOP rate, but NOT the bytes —
-    # engine-7 input bytes already carry per-device sharding divisors
-    rows2 = A.attribute(
-        resources, _span_stats(), "TPU v5 lite", n_devices=4,
-        work=A.PPO_FIXED_WORK,
-    )
-    step2 = {r.program: r for r in rows2}["ppo.train_step"]
-    assert step2.achieved_tflops_per_dev == pytest.approx(2.5)
-    assert step2.achieved_gbps_per_dev == pytest.approx(0.6)
-
-
-def test_attribution_count_key_unknown_device_and_missing():
-    from trlx_tpu.telemetry import attribution as A
-
-    resources = {"ppo.engine_decode_step": {"flops": 1.0e9}}
-    work = (A.WorkItem(
-        "ppo.engine_decode_step", "phase/collect",
-        count_key="engine/decode_steps",
-    ),)
-    kind = "TPU v5 lite"
-    # count from the stats dict, not any span
-    rows = A.attribute(
-        resources, _span_stats(), kind, work=work,
-        counts={"engine/decode_steps": 500.0},
-    )
-    assert rows[0].calls == 500.0
-    assert rows[0].mfu == pytest.approx(
-        1.0e9 * 500 / 5.0 / 1e12 / A.BF16_PEAK_TFLOPS[kind]
-    )
-    # a device without a published spec (the CPU included) is an error,
-    # never an assumed peak
-    for unknown in ("cpu", "Quantum Abacus"):
-        with pytest.raises(ValueError, match="no published peaks"):
-            A.attribute(
-                resources, _span_stats(), unknown, work=work,
-                counts={"engine/decode_steps": 500.0},
-            )
-    # zero counts / missing programs / missing spans yield no row
-    assert A.attribute(
-        resources, _span_stats(), kind, work=work, counts={}
-    ) == []
-    assert A.attribute({}, _span_stats(), kind, work=work) == []
-
-
-def test_bubble_breakdown_and_goodput():
-    from trlx_tpu.telemetry import attribution as A
-
-    spans = _span_stats()
-    stats = {"async/guard_hold_ms": 30.0, "async/learner_idle_ms": 80.0}
-    bub = A.bubble_breakdown(spans, stats, phases=5)
-    # phase wall = (5000 + 2000) / 5
-    assert bub["phase_wall_ms"] == pytest.approx(1400.0)
-    assert bub["bubble/drain_ms"] == pytest.approx(50.0)
-    assert bub["bubble/admit_ms"] == pytest.approx(20.0)
-    assert bub["bubble/guard_hold_ms"] == pytest.approx(30.0)
-    assert bub["bubble/learner_idle_ms"] == pytest.approx(80.0)
-    assert bub["bubble/drain_frac"] == pytest.approx(50.0 / 1400.0)
-    # sync run: learner idle falls back to the drain
-    bub_sync = A.bubble_breakdown(spans, None, phases=5)
-    assert bub_sync["bubble/learner_idle_ms"] == pytest.approx(50.0)
-    gp = A.phase_goodput(spans, samples_per_phase=128, phases=5)
-    assert gp["goodput_samples_per_sec"] == pytest.approx(128 / 1.4)
-    # rendering carries the table, the bubbles, and the goodput line
-    rows = A.attribute(
-        {"ppo.train_step": {"flops": 1e12, "input_bytes": 1, "output_bytes": 1}},
-        spans, "TPU v5 lite", work=A.PPO_FIXED_WORK,
-    )
-    text = A.format_attribution(rows, bub, gp)
-    assert "ppo.train_step" in text and "guard_hold" in text
-    assert "goodput" in text
 
 
 # ----------------------------- run ledger --------------------------------- #

@@ -47,6 +47,19 @@ def test_a_gap_that_straddles_two_spans_is_split_by_overlap(tool):
     assert sum(got["between"].values()) + got["in_module"] == got["idle"]
 
 
+def test_a_gap_under_a_collection_inside_a_dispatch_goes_to_the_collection(tool):
+    """A full collection (``host/gc``, ISSUE 60) that fell inside the call
+    into the step, 420-580 of ``engine/dispatch`` 410-590: the idle under
+    it is the collector's, what is left of the call's the call's."""
+    spans = [(50, 950, "serve/step"), (410, 590, "engine/dispatch"), (420, 580, "host/gc")]
+    got = tool.idle_by_span(BUSY, MODULES, WINDOW, spans)
+    assert got["between"] == {
+        "host/gc": 160, "engine/dispatch": 10 + 10,
+        "serve/step": 10 + 10 + 50 + 50,  # 400-410, 590-600; 50-100, 900-950
+        "caller": 50 + 50,
+    }
+
+
 def test_a_piece_under_no_span_is_the_callers(tool):
     got = tool.idle_by_span(BUSY, MODULES, WINDOW, [])
     assert got["between"] == {"caller": 400} and got["in_module"] == 50

@@ -168,6 +168,17 @@ EPHEMERAL_CONTRACTS: Dict[Tuple[str, str], str] = {
         "append-only sink whose rows are already on disk — reopened "
         "in append mode on rebuild"
     ),
+    ("BaseRLTrainer", "_phase_timing"): (
+        "host-stall's timing series and the mark taken at a phase's "
+        "start (telemetry/health.py): wall-clock levels of this host, "
+        "warmed up again in three phases; never read by the schedule"
+    ),
+    ("HealthMonitor", "_timing"): (
+        "host-stall's running levels of phase and iteration walls: "
+        "wall-clock of the host the run is on, kept out of state_dict "
+        "on purpose (a resumed run is on another host and warms up "
+        "again in three observations); no token or update reads it"
+    ),
     # ---- PPOTrainer --------------------------------------------------- #
     ("PPOTrainer", "_behavior_params"): (
         "phase-scoped behavior-policy snapshot: begin_streamed_phase "

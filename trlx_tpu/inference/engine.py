@@ -151,6 +151,7 @@ class HeldStep:
     taps: Optional[Tuple[jax.Array, jax.Array]]  # (token, live) if routed
     log_end: int  # the cadence log's absolute end (``trace_requests``)
     forwards: int  # admission forwards dispatched before it, all told
+    wholes: int  # of them, the groups a chunked engine forwarded whole
 
 
 @struct.dataclass
@@ -211,6 +212,11 @@ class EngineStats:
     # backend that keeps one program in flight: the CPU's multi-device
     # client): a step's wall less this is the host's own exposed cost
     host_blocked_ms: float = 0.0
+    # wall of the calls that dispatch a decode or verify step (the
+    # engine/dispatch spans), on the loop's own clock reads so it stands
+    # with the tracer off: short where the backend queues the step,
+    # and where it grew the host was held inside the call
+    dispatch_ms: float = 0.0
     # the starved ledger: wall from the return of a *draining* fetch (a
     # blocking fetch of an output of the newest dispatched program: that
     # program has ended and nothing is queued behind it) to the entry of
@@ -324,6 +330,7 @@ class EngineStats:
             "engine/released": float(self.released),
             "engine/param_gb": round(self.param_gb, 4),
             "engine/host_blocked_ms": round(self.host_blocked_ms, 3),
+            "engine/dispatch_ms": round(self.dispatch_ms, 3),
             "engine/starved_ms": round(self.starved_ms, 3),
             "engine/prefill_chunks": float(self.prefill_chunks),
             "engine/prefill_cols_skipped": float(self.prefill_cols_skipped),
@@ -622,8 +629,11 @@ class ContinuousBatchingEngine:
         self._last_refill = -1  # that count at the newest harvest
         #: admission forwards (whole or chunk) dispatched ahead of the
         #: newest step read: where it grew, the step this iteration
-        #: waited for ran behind a forward (``serve/admit_pump_ms``)
+        #: waited for ran behind a forward (``serve/admit_pump_ms``);
+        #: ``wholes_waited`` likewise for a group forwarded whole, many
+        #: times a chunk's length (``host-stall`` keeps the two apart)
         self.forwards_waited = 0
+        self.wholes_waited = 0
         # the starved ledger's open episode (EngineStats.starved_by_ms
         # holds the closed ones): when the chip was seen drained (None:
         # it is fed, or the host runs ahead of it), the part of the loop
@@ -1573,6 +1583,7 @@ class ContinuousBatchingEngine:
         self._dispatches = 0
         self._last_refill = -1
         self.forwards_waited = 0
+        self.wholes_waited = 0
         self._drained_at = None
         self._starved_part = None
         self._episode = dict.fromkeys(STARVED_PARTS, 0.0)
@@ -2373,6 +2384,7 @@ class ContinuousBatchingEngine:
         into the drafter histories / stream taps, and prefetch the next
         step's drafts (host drafting overlaps the device's next work;
         the stage is dropped if a push/admission/harvest intervenes)."""
+        entered = telemetry.monotonic()
         with telemetry.span("engine/dispatch", program="verify_step"):
             self._fed()
             self._state, done, toks, acc = self.verify_step_jit(
@@ -2381,6 +2393,7 @@ class ContinuousBatchingEngine:
                 jnp.asarray(draft),
                 jnp.asarray(lens),
             )
+        self.stats.dispatch_ms += (telemetry.monotonic() - entered) * 1000.0
         try:
             done.copy_to_host_async()
         except (AttributeError, RuntimeError):
@@ -2396,6 +2409,7 @@ class ContinuousBatchingEngine:
             toks, acc, what="tokens", newest=True
         )
         self.forwards_waited = self.stats.forwards
+        self.wholes_waited = self.stats.prefill_whole
         with telemetry.span("engine/route"):
             self._route_verified(lens, tok_host, acc_host)
         registry = telemetry.get_metrics()
@@ -2471,13 +2485,13 @@ class ContinuousBatchingEngine:
                     self._params, self._state
                 )
                 token = live = None
+        dispatch_ms = (telemetry.monotonic() - entered) * 1000.0
+        self.stats.dispatch_ms += dispatch_ms
         if behind and prev.done.is_ready():
             # queued behind a running step, the call came back with that
             # step ended: it waited for the device (a backend that keeps
             # one program in flight), which is no work of the host's
-            self.stats.host_blocked_ms += (
-                telemetry.monotonic() - entered
-            ) * 1000.0
+            self.stats.host_blocked_ms += dispatch_ms
         done = polled.pop("done")
         # streaming tap: this step's live emissions go to the per-request
         # queues when the step is read — time-to-first-token decouples
@@ -2515,6 +2529,7 @@ class ContinuousBatchingEngine:
             taps=taps,
             log_end=self._step_base + len(self._step_log),
             forwards=self.stats.forwards,
+            wholes=self.stats.prefill_whole,
         )
         if prev is not None:
             self._read_step(prev)
@@ -2536,6 +2551,7 @@ class ContinuousBatchingEngine:
         # nothing dispatched since: this fetch drains the chip
         newest = held.seq == self._dispatches
         self.forwards_waited = held.forwards
+        self.wholes_waited = held.wholes
         rows = [
             (slot, row)
             for slot, row in held.rows

@@ -66,6 +66,16 @@ STARVED_HISTOGRAMS = {
     for part in ("tap", "admit", "land", "caller")
 }
 
+#: the parts of an iteration that ``host-stall`` names when its wall
+#: grows: the first two from the engine's own accumulators
+#: (``EngineStats.host_blocked_ms``, ``dispatch_ms``), the rest the
+#: tracer's walls by span (``STALL_SPANS``)
+STALL_SPANS = (
+    "serve/schedule", "serve/land", "engine/route",
+    "collect/admit", "collect/prefill", "collect/slot_recycle",
+)
+STALL_PARTS = ("engine/fetch", "engine/dispatch") + STALL_SPANS
+
 
 def observe_request_metrics(
     registry,
@@ -362,10 +372,14 @@ class InferenceServer:
 
         # generation-health watch: non-finite logprobs/values in a served
         # group trip nan-precursor, per-tenant queue-wait p95 over the
-        # SLO budget trips slo-breach; zero events == healthy serving
-        self.health_monitor = HealthMonitor(
-            HealthConfig.from_dict({"enabled": True})
-        )
+        # SLO budget trips slo-breach; zero events == healthy serving.
+        # Always on; `train.health`'s per-detector tuning and `disable`
+        # apply here as in a trainer (host-stall's ratio and min_ms)
+        tuned = dict(train.health or {})
+        self.health_monitor = HealthMonitor(HealthConfig.from_dict({
+            "enabled": True,
+            **{k: tuned[k] for k in ("detectors", "disable") if k in tuned},
+        }))
         self._requests: Dict[int, Any] = {}  # request_id -> Request
         # trace-emission retention: Request refs (tenant/priority/trace
         # marks) kept until the row HARVESTS — pop_result may drop
@@ -387,6 +401,18 @@ class InferenceServer:
         # the last iteration handed the loop back with rows in flight
         self._starved_seen = dict(self.engine.stats.starved_by_ms)
         self._returned_at: Optional[float] = None
+        # host-stall (docs/observability.md "Host pauses"): an iteration's
+        # wall against the running level of its own class (it met no
+        # admission forward, a chunk's, a whole group's: each many times
+        # the one before; met: dispatched it, or read a step that ran
+        # behind it), and where the host stood at its entry
+        self._stall_mark = telemetry.HostMark(STALL_SPANS)
+        self._stall_series = tuple(
+            self.health_monitor.timing_series(
+                f"time/iter_ms[class={cls}]", STALL_PARTS
+            )
+            for cls in ("step", "admit", "admit_whole")
+        )
 
     # ------------------------------ API -------------------------------- #
 
@@ -672,13 +698,17 @@ class InferenceServer:
         engine fetches every step's tokens, so the walls are the
         device's; with no stream open only the ``done`` flags are."""
         from trlx_tpu import telemetry
+        from trlx_tpu.telemetry.health import announce
 
         engine = self.engine
         stats = engine.stats
         forwards = stats.forwards
         steps, ahead = stats.decode_steps, stats.steps_ahead
-        waited = engine.forwards_waited
+        waited, wholes = engine.forwards_waited, engine.wholes_waited
+        whole_forwards = stats.prefill_whole
         blocked_ms = stats.host_blocked_ms
+        dispatch_ms = stats.dispatch_ms
+        self._stall_mark.take()
         engine.mark_starved("admit")
         with telemetry.span("serve/step", force=True) as sp:
             self._stamp_caller(sp.start)
@@ -724,6 +754,24 @@ class InferenceServer:
                 registry.histogram("serve/step_ahead").observe(
                     float(stats.steps_ahead > ahead)
                 )
+            if (
+                engine.wholes_waited > wholes
+                or stats.prefill_whole > whole_forwards
+            ):
+                series = self._stall_series[2]
+            else:
+                series = self._stall_series[
+                    behind_forward or stats.forwards > forwards
+                ]
+            if series is not None:
+                series.values[0] = stats.host_blocked_ms - blocked_ms
+                series.values[1] = stats.dispatch_ms - dispatch_ms
+                self._stall_mark.fill(series, offset=2)
+                event = self.health_monitor.observe_timing(
+                    series, wall_ms, step=stats.decode_steps
+                )
+                if event is not None:
+                    announce(event)
         # the caller's turn: starved time only while rows wait on it
         waiting = engine.pending > 0
         engine.mark_starved("caller" if waiting else None)
@@ -756,6 +804,8 @@ class InferenceServer:
         return self.step()
 
     def _observe_group(self, lp, vals, mask) -> None:
+        from trlx_tpu import telemetry
+
         m = mask.astype(bool)
         picked = lp[m] if m.any() else lp.ravel()
         row = {
@@ -768,6 +818,9 @@ class InferenceServer:
         row.update(self.scheduler.slo_ratio_rows())
         self.health_monitor.observe(row, step=self._groups_served)
         self._groups_served += 1
+        # the host's counters stand at 0.0, not absent, after a cleared
+        # registry and in a window in which nothing paused
+        telemetry.touch_host_counters()
 
     def _land_group(self, group) -> None:
         engine = self.engine

@@ -85,6 +85,11 @@ NAN_WATCH_PREFIXES = (
 #:   nonfinite— any watched stat is NaN/Inf or exceeds ``huge`` in
 #:              magnitude (always armed; the precursor fires on the huge
 #:              value BEFORE check_anomalies sees the NaN it becomes)
+#:   stall    — the wall of a phase or a serving iteration exceeds its
+#:              running level by more than ``max(ratio - 1, min_ms /
+#:              level)`` of it (armed after its own ``warmup``
+#:              observations of the series); fed through
+#:              :meth:`HealthMonitor.observe_timing`, not ``observe``
 DEFAULT_DETECTORS: Dict[str, Dict[str, Any]] = {
     "kl-spike": dict(
         series=("policy/mean_rollout_kl", "policy/approx_kl"),
@@ -133,6 +138,19 @@ DEFAULT_DETECTORS: Dict[str, Dict[str, Any]] = {
     "slo-breach": dict(
         series=(), series_prefix=("serve/slo_queue_wait_ratio",),
         kind="above", severity="warning", threshold=1.0,
+    ),
+    # a pause of the host (docs/observability.md, "Host pauses"): the
+    # phase loop feeds one timing row a phase (``time/phase_ms`` with its
+    # parts by span), the serving loop one an iteration that did device
+    # work (``time/iter_ms[class=...]``), each with the collector's and
+    # the compiler's share of it and the loop thread's CPU share. A trip
+    # names the part that grew. Warning severity: no ``on_error`` policy
+    # ever dumps or aborts for a slow host. ``warmup`` is the detector's
+    # own (a benchmark window is about 14 phases behind 2 of warm-up; the
+    # monitor's 8 would leave half of it unwatched)
+    "host-stall": dict(
+        series=(), kind="stall", severity="warning",
+        ratio=1.25, min_ms=50.0, warmup=3,
     ),
 }
 
@@ -290,6 +308,56 @@ class _SeriesState:
         self.window.append(value)
 
 
+class TimingSeries:
+    """``host-stall``'s state for one loop: the running level of a wall
+    (``time/phase_ms``, ``time/iter_ms[class=step]``) and of each of its
+    ``parts``, and the row being judged. The loop writes the row in
+    place — ``values[i]`` the wall under ``parts[i]``, ``gc_ms`` and
+    ``compile_ms`` the collector's and the compiler's time in it,
+    ``cpu_share`` the loop thread's CPU time over its wall — and hands
+    the series to :meth:`HealthMonitor.observe_timing`: no dict, no list
+    and no event is built for a row that trips nothing.
+
+    The level is the least wall of the warm-up (a first phase that
+    compiled must not set it), then an EWMA at the monitor's ``alpha``
+    (four times that upwards) in which a tripping wall counts as
+    ``ratio`` times the level, so one stall hardly moves it; a trip that
+    comes again inside the cooldown of the last moves it halfway, so a
+    lasting change, or a level that began too low, is followed in two
+    or three. Kept out of
+    ``state_dict``: a resumed run is on another host and warms up again
+    in ``warmup`` observations."""
+
+    __slots__ = (
+        "series", "parts", "values", "gc_ms", "compile_ms", "cpu_share",
+        "level", "levels", "count", "quiet_until", "recent",
+    )
+
+    def __init__(self, series: str, parts: Sequence[str], window: int):
+        self.series = series
+        self.parts = tuple(parts)
+        self.values = [0.0] * len(self.parts)
+        self.gc_ms = self.compile_ms = self.cpu_share = 0.0
+        self.level = 0.0
+        self.levels = [0.0] * len(self.parts)
+        self.count = 0
+        self.quiet_until = 0
+        self.recent: "deque[float]" = deque(maxlen=window)
+
+    def row(self, wall_ms: float) -> Dict[str, float]:
+        """The row as a stats dict (flight records, the logger)."""
+        from trlx_tpu.telemetry.metrics import split_metric_label
+
+        label = split_metric_label(self.series)[1]
+        out = {self.series: wall_ms}
+        for part, value in zip(self.parts, self.values):
+            out[f"time/{part}_ms{label}"] = value
+        out["time/gc_ms" + label] = self.gc_ms
+        out["time/compile_ms" + label] = self.compile_ms
+        out["time/cpu_share" + label] = self.cpu_share
+        return out
+
+
 def _host_float(value: Any) -> Optional[float]:
     """``value`` as a host float, or None when it is not already host-side.
 
@@ -340,6 +408,7 @@ class HealthMonitor:
             merged = dict(spec)
             merged.update(self.config.detectors.get(did, {}))
             self._specs[did] = merged
+        self._timing: Dict[str, TimingSeries] = {}
 
     # ---------------------------- checkpointing ---------------------------- #
 
@@ -430,10 +499,15 @@ class HealthMonitor:
             **extra,
         )
         events.append(ev)
+        self._keep_event(ev)
+
+    def _keep_event(self, ev: HealthEvent) -> None:
         self.events.append(ev)
         if len(self.events) > self.config.max_events:
             del self.events[: len(self.events) - self.config.max_events]
-        self.event_counts[detector] = self.event_counts.get(detector, 0) + 1
+        self.event_counts[ev.detector] = (
+            self.event_counts.get(ev.detector, 0) + 1
+        )
 
     def _evaluate(
         self,
@@ -582,7 +656,7 @@ class HealthMonitor:
         # prefix-series detectors (slo-breach) match dynamically-named
         # keys like serve/slo_queue_wait_ratio[tenant=...]
         for did, spec in self._specs.items():
-            if spec["kind"] == "nonfinite":
+            if spec["kind"] in ("nonfinite", "stall"):
                 continue
             candidates = [k for k in spec["series"] if k in values]
             for prefix in spec.get("series_prefix", ()):
@@ -601,6 +675,119 @@ class HealthMonitor:
         self.latest.update(values)
         self._observations += 1
         return events
+
+    # ------------------------------ host-stall ---------------------------- #
+
+    def timing_series(
+        self, series: str, parts: Sequence[str]
+    ) -> Optional[TimingSeries]:
+        """The ``host-stall`` state for ``series`` (made on first use),
+        or ``None`` where the detector is disabled: the loop then builds
+        no timing row."""
+        if "host-stall" not in self._specs:
+            return None
+        ts = self._timing.get(series)
+        if ts is None:
+            ts = self._timing[series] = TimingSeries(
+                series, parts, self.config.window
+            )
+        return ts
+
+    def observe_timing(
+        self,
+        ts: TimingSeries,
+        wall_ms: float,
+        step: Optional[int] = None,
+        phase: Optional[int] = None,
+    ) -> Optional[HealthEvent]:
+        """Judge one timing row (``ts`` as its loop filled it, the phase's
+        or iteration's ``wall_ms``) by the ``host-stall`` rule. O(1) and
+        nothing built where the row trips nothing. A trip advances the
+        counters ``host/stalls`` and ``host/stall_ms`` (the excess over
+        the level) with their ``[by=<part>]`` twins: the part whose wall
+        grew most over its own level, ``gc`` or ``compile`` where the
+        collector's or the compiler's time covers most of the excess.
+        Returned is the event where one was left: not inside the
+        ``cooldown`` (counted in observations of this series; a trip in
+        there is counted and moves the level halfway to it), and not
+        for a compile, which has a span and counters of its own
+        (``jit/compile``) and would otherwise leave an event for every
+        program a warm-up builds."""
+        spec = self._specs["host-stall"]
+        count = ts.count
+        ts.count = count + 1
+        ts.recent.append(wall_ms)
+        level, levels, values = ts.level, ts.levels, ts.values
+        if count < spec["warmup"]:
+            if count == 0 or wall_ms < level:
+                ts.level = wall_ms
+                levels[:] = values
+            return None
+        ratio = float(spec["ratio"])
+        excess = wall_ms - level
+        allowed = max((ratio - 1.0) * level, float(spec["min_ms"]))
+        alpha = self._alpha
+        if excess <= allowed:
+            # up four times as fast as down: a series of two lengths (an
+            # iteration that met a whole forward, one that did not) settles
+            # near the longer, where neither trips
+            ts.level = level + (4.0 * alpha if excess > 0.0 else alpha) * excess
+            for i in range(len(levels)):
+                levels[i] += alpha * (values[i] - levels[i])
+            return None
+        # a lone stall hardly moves the level; one that comes again inside
+        # the cooldown of the last is how the series runs now: halfway there
+        recurring = count < ts.quiet_until
+        grew, most = "other", 0.0
+        for part, value, usual in zip(ts.parts, values, levels):
+            if value - usual > most:
+                grew, most = part, value - usual
+        if recurring:
+            ts.level = level + 0.5 * excess
+            for i in range(len(levels)):
+                levels[i] += 0.5 * (values[i] - levels[i])
+        else:
+            ts.level = level + alpha * (ratio - 1.0) * level
+        by = grew
+        if ts.gc_ms >= 0.5 * excess:
+            by = "gc"
+        elif ts.compile_ms >= 0.5 * excess:
+            by = "compile"
+        from trlx_tpu.telemetry.metrics import get_metrics
+
+        registry = get_metrics()
+        registry.counter("host/stalls").inc()
+        registry.counter("host/stall_ms").inc(excess)
+        registry.counter(f"host/stalls[by={by}]").inc()
+        registry.counter(f"host/stall_ms[by={by}]").inc(excess)
+        if by == "compile" or recurring:
+            return None
+        ts.quiet_until = count + 1 + int(self.config.cooldown)
+        what = "iteration" if phase is None else "phase"
+        which = count if step is None else step
+        named = f"{grew} +{most:.0f}"
+        if by == "gc":
+            named = f"gc +{ts.gc_ms:.0f} under {grew}"
+        ev = HealthEvent(
+            detector="host-stall",
+            severity=spec["severity"],
+            series=ts.series,
+            value=wall_ms,
+            step=int(which),
+            phase=phase,
+            message=(
+                f"{what} {phase if phase is not None else which} "
+                f"{wall_ms:.0f} ms against {level:.4g}; {named}; "
+                f"gc {ts.gc_ms:.0f} ms, compile {ts.compile_ms:.0f} ms, "
+                f"cpu_share {ts.cpu_share:.2f}"
+            ),
+            fingerprint=self.fingerprint,
+            baseline=level,
+            threshold=level + allowed,
+            window=[round(v, 3) for v in ts.recent],
+        )
+        self._keep_event(ev)
+        return ev
 
     def state_summary(self) -> Dict[str, Dict[str, float]]:
         """Per-series EWMA snapshot for the flight recorder."""
@@ -638,6 +825,42 @@ def detector_defaults_table() -> List[Tuple[str, str, str, str]]:
         )
         rows.append((did, spec["kind"], spec["severity"], params))
     return rows
+
+
+def announce(ev: HealthEvent, logger=None) -> None:
+    """Where every trip shows, whoever observed it: a zero-length
+    ``health/<detector>`` marker on the span timeline, next to what
+    produced it, and the ``health_event`` line of ``logger`` (stderr
+    without one)."""
+    import sys
+
+    from trlx_tpu import telemetry
+
+    with telemetry.span(
+        "health/" + ev.detector,
+        severity=ev.severity,
+        series=ev.series,
+        step=ev.step,
+    ):
+        pass
+    if logger is not None:
+        # (an event a host raised with no step carries -1)
+        logger.log_health_event(
+            ev.to_dict(), step=ev.step if ev.step >= 0 else None
+        )
+    else:
+        print(
+            f"health: {ev.severity} {ev.detector}: {ev.message}",
+            file=sys.stderr,
+        )
+
+
+def without_timing(events: Sequence[HealthEvent]) -> List[HealthEvent]:
+    """``events`` less the ``host-stall`` warnings: what a smoke or a test
+    that asks for a clean run holds the run to. A stalled host is the
+    machine's doing (a shared CPU stalls of its own accord, and so does a
+    loop whose program is still being built), not the run's health."""
+    return [ev for ev in events if ev.detector != "host-stall"]
 
 
 def format_events(events: Sequence[HealthEvent]) -> str:

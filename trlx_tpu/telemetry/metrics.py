@@ -173,7 +173,9 @@ class MetricsRegistry:
         self.enabled = enabled
         self.max_samples = int(max_samples)
         self._instruments: Dict[str, Any] = {}
-        self._lock = threading.Lock()
+        # reentrant: the collector's hook (telemetry.watch_host) looks its
+        # counters up from inside whatever the collecting thread was doing
+        self._lock = threading.RLock()
 
     # ------------------------------ access ------------------------------ #
 

@@ -202,8 +202,17 @@ class Tracer:
         self.enabled = enabled
         self.dropped = 0
         self._records: "deque[Span]" = deque(maxlen=max_records)
+        #: summed wall (ms) of the recorded spans by name, since the tracer
+        #: was built: not cleared with the ring and never evicted, so a loop
+        #: reads what a phase or an iteration spent under a name as the
+        #: difference of two reads (the timing rows of ``host-stall``,
+        #: telemetry/health.py)
+        self.totals: Dict[str, float] = {}
         self._local = threading.local()
-        self._lock = threading.Lock()
+        # reentrant: the collector's hook (telemetry.watch_host) records
+        # a span from inside whatever the collecting thread was doing,
+        # which may be one of this class's own locked sections
+        self._lock = threading.RLock()
         self._next_index = 0
 
     # ------------------------------- API -------------------------------- #
@@ -234,9 +243,7 @@ class Tracer:
         with self._lock:
             span.index = self._next_index
             self._next_index += 1
-            if len(self._records) == self._records.maxlen:
-                self.dropped += 1
-            self._records.append(span)
+            self._keep(span)
         return span.index
 
     def current(self) -> Optional[Span]:
@@ -310,6 +317,17 @@ class Tracer:
 
     # ----------------------------- internal ----------------------------- #
 
+    def _keep(self, span: Span) -> None:
+        """A closed span into the ring and the totals (lock held)."""
+        if len(self._records) == self._records.maxlen:
+            self.dropped += 1
+        self._records.append(span)
+        ms = (span.end - span.start) * 1000.0
+        try:
+            self.totals[span.name] += ms
+        except KeyError:
+            self.totals[span.name] = ms
+
     def _stack(self) -> List[Span]:
         stack = getattr(self._local, "stack", None)
         if stack is None:
@@ -337,9 +355,7 @@ class Tracer:
             if top is span:
                 break
         with self._lock:
-            if len(self._records) == self._records.maxlen:
-                self.dropped += 1
-            self._records.append(span)
+            self._keep(span)
 
 
 def quantile(sorted_durs: Sequence[float], q: float) -> float:

@@ -107,6 +107,7 @@ class BaseRLTrainer(ABC):
         self._health_ev = True  # GRPO opts out (placeholder returns slot)
         self.health_monitor = None
         self.flight_recorder = None
+        self._phase_timing = None  # (TimingSeries, HostMark) once a phase is marked
         self._phase_log = None  # run_dir live --watch feed (run_ledger.py)
         if not self._health_enabled:
             return
@@ -156,27 +157,22 @@ class BaseRLTrainer(ABC):
         monitor = self.health_monitor
         if monitor is None:
             return
-        from trlx_tpu import telemetry
-
         events = monitor.observe(row, step=step, phase=phase)
-        if not events:
-            return
+        if events:
+            self._sink_health_events(events, row, phase)
+
+    def _sink_health_events(
+        self, events, row: Dict[str, Any], phase: Optional[int]
+    ) -> None:
+        """Where a detector's trips go: the span stream, the Logger (or
+        stderr without one) and, for ``error`` trips, the
+        ``health.on_error`` policy."""
+        from trlx_tpu.telemetry.health import announce
+
+        monitor = self.health_monitor
         logger = getattr(self, "logger", None)
         for ev in events:
-            # zero-length marker span: the trip shows on the trace
-            # timeline next to the phase whose stats produced it
-            with telemetry.span(
-                "health/" + ev.detector,
-                severity=ev.severity,
-                series=ev.series,
-                step=ev.step,
-            ):
-                pass
-            if logger is not None:
-                logger.log_health_event(ev.to_dict(), step=ev.step)
-            else:
-                print(f"health: {ev.severity} {ev.detector}: {ev.message}",
-                      file=sys.stderr)
+            announce(ev, logger)
         errors = [ev for ev in events if ev.severity == "error"]
         policy = self.health_config.on_error
         if not errors or policy == "warn":
@@ -220,6 +216,64 @@ class BaseRLTrainer(ABC):
                 f"tripped at step {first.step} ({first.message}); "
                 f"flight record(s): {self.flight_recorder.dumped if self.flight_recorder else 'disabled'}"
             )
+
+    # the parts of a phase by span (docs/observability.md, "Host pauses"):
+    # what ``host-stall`` names when a phase's wall grows; the second row
+    # is the continuous engine's
+    PHASE_SPANS = ("phase/begin", "phase/collect", "phase/train")
+    PHASE_PARTS = (
+        "phase/begin", "collect/wait", "collect/detokenize", "collect/score",
+        "collect/land", "train/drain", "train/residual",
+    )
+    ENGINE_PHASE_PARTS = (
+        "collect/admit", "collect/prefill", "collect/slot_recycle",
+        "engine/fetch",
+    )
+
+    def mark_phase_timing(self) -> None:
+        """A phase begins: note where the host stands (the clock, this
+        thread's CPU time, the collector's and the compiler's totals, the
+        tracer's walls by span) for :meth:`observe_phase_timing`. Nothing
+        is built with ``train.health`` off or ``host-stall`` disabled."""
+        monitor = self.health_monitor
+        if monitor is None:
+            return
+        timing = self._phase_timing
+        if timing is None:
+            from trlx_tpu import telemetry
+
+            parts = self.PHASE_PARTS
+            if getattr(self, "rollout_engine", "fixed") == "continuous":
+                parts = parts + self.ENGINE_PHASE_PARTS
+            series = monitor.timing_series("time/phase_ms", parts)
+            if series is None:
+                return
+            timing = self._phase_timing = (
+                series, telemetry.HostMark(parts, wall=self.PHASE_SPANS)
+            )
+        timing[1].take()
+
+    def observe_phase_timing(self, phase: Optional[int]) -> Dict[str, float]:
+        """A phase has ended: its timing row (``time/phase_ms`` = the
+        walls of ``phase/begin``, ``phase/collect`` and ``phase/train``,
+        its parts by span, ``time/gc_ms``, ``time/compile_ms``,
+        ``time/cpu_share``) to the ``host-stall`` detector, through the
+        sinks of every detector. Returns the row ({} where none was
+        built: health off, no mark taken, or a tracer that records
+        nothing)."""
+        timing = self._phase_timing
+        if timing is None or not timing[1].t:
+            return {}
+        series, mark = timing
+        wall_ms = mark.fill(series)
+        mark.t = 0.0  # one row a mark
+        if wall_ms <= 0.0:
+            return {}
+        row = series.row(wall_ms)
+        event = self.health_monitor.observe_timing(series, wall_ms, phase=phase)
+        if event is not None:
+            self._sink_health_events([event], row, phase)
+        return row
 
     def observe_health_rows(
         self,
@@ -269,8 +323,7 @@ class BaseRLTrainer(ABC):
         line. Unlike :meth:`observe_health` this never applies the
         ``health.on_error`` policy — degradations are the alternative
         to aborting, not a trigger for it."""
-        from trlx_tpu import telemetry
-        from trlx_tpu.telemetry.health import HealthEvent
+        from trlx_tpu.telemetry.health import HealthEvent, announce
 
         monitor = self.health_monitor
         ev = HealthEvent(
@@ -288,20 +341,7 @@ class BaseRLTrainer(ABC):
             monitor.event_counts[detector] = (
                 monitor.event_counts.get(detector, 0) + 1
             )
-        with telemetry.span(
-            "health/" + detector,
-            severity=severity,
-            series=series,
-            step=ev.step,
-        ):
-            pass
-        logger = getattr(self, "logger", None)
-        if logger is not None:
-            logger.log_health_event(ev.to_dict(), step=step)
-        else:
-            print(
-                f"health: {severity} {detector}: {message}", file=sys.stderr
-            )
+        announce(ev, getattr(self, "logger", None))
 
     def maybe_drain(
         self, phase: Optional[int] = None, step: Optional[int] = None

@@ -1427,44 +1427,50 @@ class PPOTrainer(BaseRLTrainer):
                 "(or abort_streamed_phase after an error) before beginning "
                 "another"
             )
-        method: PPOConfig = self.config.method
-        train = self.config.train
-        total = int(num_rollouts if num_rollouts is not None
-                    else method.num_rollouts)
-        plan = make_stream_plan(
-            total, train.batch_size, method.ppo_epochs, seed
-        )
-        if len(self.buffer):
-            self.buffer.clear_history()
-        self.buffer.begin_stream(plan.total)
-        # direct drivers (bench, harnesses) never advance _phase_index;
-        # bump the fallback health-phase id HERE so collect-window
-        # events and the phase's flight record agree on the id
-        self._health_phase += 1
-        # the legacy lazy cast copy is dead weight once the snapshot exists
-        self._rollout_params_cache = None
-        # recorded so error recovery (the engine-fallback path in the
-        # orchestrator) can re-begin THIS phase with the same plan seed
-        self._last_stream_seed = seed
-        # fresh per-row RNG phase: both rollout engines derive row keys
-        # from the same single split, so a phase collected continuously
-        # is row-comparable to the same phase collected fixed-batch
-        self.reset_rollout_phase()
-        self._behavior_params = self._behavior_snapshot_jit(self.state.params)
-        # async actor–learner mode rides the streamed-phase machinery
-        # with version/guard/push state on top (trainer/async_rl.py);
-        # the explicit overlap=False escape (the serial parity baseline)
-        # still runs the plain serial schedule even under async config
-        phase_cls = (
-            _AsyncStreamedPhase
-            if self.async_config.enabled and overlap is not False
-            else _StreamedPhase
-        )
-        self._stream = phase_cls(
-            plan,
-            overlap=train.phase_overlap if overlap is None else bool(overlap),
-        )
-        return self._stream
+        # the phase's timing row starts here (health on), and so does
+        # the first of the three spans that tile a streamed phase: what
+        # the program does before the orchestrator's first
+        # collect/dispatch (the plan, the buffer, the behaviour snapshot)
+        self.mark_phase_timing()
+        with telemetry.span("phase/begin", force=True):
+            method: PPOConfig = self.config.method
+            train = self.config.train
+            total = int(num_rollouts if num_rollouts is not None
+                        else method.num_rollouts)
+            plan = make_stream_plan(
+                total, train.batch_size, method.ppo_epochs, seed
+            )
+            if len(self.buffer):
+                self.buffer.clear_history()
+            self.buffer.begin_stream(plan.total)
+            # direct drivers (bench, harnesses) never advance _phase_index;
+            # bump the fallback health-phase id HERE so collect-window
+            # events and the phase's flight record agree on the id
+            self._health_phase += 1
+            # the legacy lazy cast copy is dead weight once the snapshot exists
+            self._rollout_params_cache = None
+            # recorded so error recovery (the engine-fallback path in the
+            # orchestrator) can re-begin THIS phase with the same plan seed
+            self._last_stream_seed = seed
+            # fresh per-row RNG phase: both rollout engines derive row keys
+            # from the same single split, so a phase collected continuously
+            # is row-comparable to the same phase collected fixed-batch
+            self.reset_rollout_phase()
+            self._behavior_params = self._behavior_snapshot_jit(self.state.params)
+            # async actor–learner mode rides the streamed-phase machinery
+            # with version/guard/push state on top (trainer/async_rl.py);
+            # the explicit overlap=False escape (the serial parity baseline)
+            # still runs the plain serial schedule even under async config
+            phase_cls = (
+                _AsyncStreamedPhase
+                if self.async_config.enabled and overlap is not False
+                else _StreamedPhase
+            )
+            self._stream = phase_cls(
+                plan,
+                overlap=train.phase_overlap if overlap is None else bool(overlap),
+            )
+            return self._stream
 
     def on_rollouts_landed(self) -> None:
         """Orchestrator hook, called after each rollout chunk lands in the
@@ -1751,6 +1757,9 @@ class PPOTrainer(BaseRLTrainer):
         # async/learner_idle_ms, mem/hbm_* — the bubble-breakdown
         # inputs), snapshot-able by the ledger/flight recorder/bench
         telemetry.get_metrics().absorb(self._last_overlap_stats)
+        # the host's counters stand at 0.0, not absent, in a phase in
+        # which no collection fell and nothing stalled
+        telemetry.touch_host_counters()
 
         # run-health: feed every fetched update row to the detector
         # engine in execution order, the phase-level rollout KL (the
@@ -1778,6 +1787,8 @@ class PPOTrainer(BaseRLTrainer):
                     phase=phase_id,
                     phase_row=phase_row,
                 )
+                # which part of the phase grew, if its wall did
+                last_row.update(self.observe_phase_timing(phase_id))
             finally:
                 self.record_flight_phase(
                     phase_id, stats_row=last_row, kl_seq=kl_seq
@@ -1838,6 +1849,7 @@ class PPOTrainer(BaseRLTrainer):
         # phase's device work dispatches
         self._phase_index += 1
         self._phase_profiler.on_phase_start(self._phase_index)
+        self.mark_phase_timing()
         # non-streamed collections need the per-row phase reset too
         # (begin_streamed_phase repeats it harmlessly for streamed ones)
         self.reset_rollout_phase()
@@ -2111,6 +2123,8 @@ class PPOTrainer(BaseRLTrainer):
                     phase=self._phase_index,
                     phase_row={"policy/mean_rollout_kl": float(mean_kl)},
                 )
+                telemetry.touch_host_counters()
+                self.observe_phase_timing(self._phase_index)
                 self.check_anomalies(rows, iter_count)
                 step_stats = {}
                 for k in range(n_minibatches):
