@@ -165,14 +165,23 @@ def test_tiles_are_fitted_to_the_length():
     ]
     assert [fitted_block(t) for t in (LONG_SEQ, 1500, 4096)] == [LONG_BLOCK] * 3
     # the rows a loop iteration of the one-tile kernels takes: the whole tile
-    # while it is small, else a divisor of it in bf16 sublanes
-    assert [_row_chunk(t) for t in (8, 32, 128, 512, 560, 576, 592, 640, 1008)] == [
-        8, 32, 128, 256, 112, 192, 16, 160, 144,
+    # while it is small, else the tile over the fewest iterations that keep a
+    # chunk under the cap, in bf16 sublanes: fitted to the length, no divisor
+    # of it (as divisors T 560 ran chunks of 112 rows and T 592 of 16)
+    assert [_row_chunk(t) for t in (8, 32, 128, 320, 336, 512, 560, 576, 592, 640, 768, 1008)] == [
+        8, 32, 128, 320, 176, 256, 288, 288, 304, 320, 256, 256,
     ]
-    assert all(
-        _row_chunk(t) <= ROW_CHUNK and t % _row_chunk(t) == 0
-        for t in map(fitted_block, range(1, LONG_SEQ))
-    )
+    assert 512 % _row_chunk(512) == 0  # longgen's T: the program it was
+    for t in sorted(set(map(fitted_block, range(1, LONG_SEQ)))):
+        c = _row_chunk(t)
+        n = -(-t // c)
+        assert c <= ROW_CHUNK and n == -(-t // ROW_CHUNK)  # the fewest iterations
+        if n > 1:
+            # whole sublanes, and the last chunk, aligned to the tile's end,
+            # revisits less than a chunk, none of it before the chunk before
+            assert c % 16 == 0 and 0 <= n * c - t < c and (n - 1) * c <= t
+        # no other multiple of 16 does it in as few iterations of fewer rows
+        assert c == t or all(n * d < t for d in range(16, c, 16))
 
 
 def test_operands_fold_where_whole_lanes_of_heads_divide_the_head_count():
@@ -234,36 +243,52 @@ def test_fitted_tiles_match_xla_at_the_update_lengths(T, H, D):
 @pytest.mark.parametrize(
     "Q,K,H,D,bias_shape,causal",
     [
-        (320, 320, 2, 32, (1, 2, 320, 320), False),  # per-head, per-row bias: sliced by chunk
-        (320, 200, 2, 32, (2, 1, 320, 200), True),   # Q < K, neither a lane multiple
-        (288, 288, 2, 32, None, True),               # no bias at all
-        (272, 40, 2, 32, (2, 1, 1, 40), False),      # cross-attention style: padding only
+        (352, 352, 2, 32, (1, 2, 352, 352), False),  # per-head, per-row bias: sliced by chunk
+        (352, 200, 2, 32, (2, 1, 352, 200), True),   # Q < K, neither a lane multiple
+        (384, 384, 2, 32, None, True),               # no bias at all
+        (352, 40, 2, 32, (2, 1, 1, 40), False),      # cross-attention style: padding only
         # folded operands, two heads of 64 a grid step
-        (320, 320, 4, 64, (1, 4, 320, 320), False),  # a step reads its two heads' bias planes
-        (320, 320, 4, 64, (1, 1, 320, 320), True),   # [1, 1, Q, K]
-        (320, 200, 4, 64, (2, 1, 1, 200), False),    # [B, 1, 1, K]
-        (288, 288, 4, 64, None, False),
-        (288, 288, 4, 128, None, True),              # one head of 128 a step
-        (320, 320, 4, 128, (2, 1, 1, 320), True),
+        (352, 352, 4, 64, (1, 4, 352, 352), False),  # a step reads its two heads' bias planes
+        (352, 352, 4, 64, (1, 1, 352, 352), True),   # [1, 1, Q, K]
+        (352, 200, 4, 64, (2, 1, 1, 200), False),    # [B, 1, 1, K]
+        (384, 384, 4, 64, None, False),
+        (384, 384, 4, 128, None, True),              # one head of 128 a step
+        (352, 352, 4, 128, (2, 1, 1, 352), True),
         # heads-major: the same kernels, a head a step
-        (320, 320, 3, 64, (1, 3, 320, 320), True),
-        (288, 288, 4, 80, (1, 1, 288, 288), False),
-        (320, 320, 4, 80, None, True),
+        (352, 352, 3, 64, (1, 3, 352, 352), True),
+        (384, 384, 4, 80, (1, 1, 384, 384), False),
+        (352, 352, 4, 80, None, True),
+        # the chunk does not divide the tile (336 = 176 + 176 - 16, 400 = 208
+        # + 208 - 16, 650 -> 656 = 3 x 224 - 16): the last chunk is aligned to
+        # the tile's end and revisits 16 rows of the one before it, which have
+        # to count once in dk and dv and keep their own o, lse and dq
+        (336, 336, 4, 64, (2, 1, 1, 336), True),     # folded, two heads a step: an update's call
+        (336, 336, 3, 64, None, True),               # heads-major
+        (336, 336, 2, 32, (1, 2, 336, 336), False),  # per-head, per-row bias: sliced by chunk
+        (400, 200, 4, 64, (2, 1, 400, 200), True),   # Q != K
+        (650, 650, 2, 128, None, True),              # three chunks, a tile padded past T
     ],
     ids=[
         "per-row-bias", "unequal-causal", "no-bias", "short-keys",
         "folded-per-head-bias", "folded-row-bias-causal", "folded-key-bias",
         "folded-no-bias", "folded-128-no-bias-causal", "folded-128-key-bias",
         "odd-heads-per-head-bias", "heads-of-80-row-bias", "heads-of-80-causal",
+        "overlap-folded-causal", "overlap-heads-major", "overlap-per-row-bias",
+        "overlap-unequal", "overlap-three-chunks",
     ],
 )
-def test_one_tile_row_loop_matches_xla(Q, K, H, D, bias_shape, causal):
+def test_one_tile_row_loop_matches_xla(request, Q, K, H, D, bias_shape, causal):
     """The one-tile kernels where their row loop runs more than once (over
     ``ROW_CHUNK`` query rows) for every kind of bias the BlockSpecs
-    broadcast, in both operand layouts: output and gradients against the
-    XLA path."""
+    broadcast, in both operand layouts, with chunks that divide the tile
+    and chunks whose last overlaps the one before: output and gradients
+    against the XLA path (a row counted twice shows in dk and dv, a row
+    written by the wrong chunk in dq)."""
     B = 2
-    assert fitted_block(Q) // _row_chunk(fitted_block(Q)) > 1
+    tile = fitted_block(Q)
+    assert tile > _row_chunk(tile)
+    overlaps = tile % _row_chunk(tile) != 0
+    assert overlaps == request.node.callspec.id.startswith("overlap")
     q, k, v = rand(B, Q, H, D), rand(B, K, H, D), rand(B, K, H, D)
     bias = None if bias_shape is None else rand(*bias_shape)
 
@@ -303,14 +328,24 @@ def test_tiled_kernels_match_xla_from_long_seq(H, D, bias_shape):
     )
 
 
-def _flash_site(monkeypatch, H, D):
+def _flash_site(monkeypatch, H, D, T=512):
     """Trace (no compile) one call site of ``dot_product_attention`` as a
     TPU process would route it: the kernels' path for a causal T 512."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    x = jax.ShapeDtypeStruct((1, 512, H, D), jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((1, T, H, D), jnp.bfloat16)
     jax.eval_shape(
         lambda q, k, v: dot_product_attention(q, k, v, causal=True), x, x, x
     )
+
+
+def _benchmark_reader(metric):
+    """How the benchmark reads a per-layer metric: gauges are read by name."""
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "layer_metrics", f"{metric}.json",
+    )
+    with open(path) as f:
+        return json.load(f)["reader"]
 
 
 def test_folded_share_gauge_counts_the_flash_sites(monkeypatch):
@@ -335,15 +370,37 @@ def test_folded_share_gauge_counts_the_flash_sites(monkeypatch):
         assert counters["attention/flash_operands{layout=folded}"] == 2
         assert counters["attention/flash_operands{layout=heads_major}"] == 1
         assert counters["attention/path{path=flash}"] == 3
-    # the benchmark's ``attn_folded_share`` reads this gauge by name
-    reader = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmark", "layer_metrics", "attn_folded_share.json",
-    )
-    with open(reader) as f:
-        assert json.load(f)["reader"] == {
-            "kind": "counter", "name": "attention/flash_folded_share",
-        }
+    assert _benchmark_reader("attn_folded_share") == {
+        "kind": "counter", "name": "attention/flash_folded_share",
+    }
+
+
+def test_row_chunk_gauge_holds_the_fewest_rows_of_the_one_tile_sites(monkeypatch):
+    """``attention/flash_row_chunk``: the fewest query rows a loop iteration
+    takes among the one-tile sites traced in the process (tldr's T 560: 288,
+    longgen's T 512: 256), untouched by a site of the tiled kernels and
+    never set where no call takes the one-tile kernels."""
+    from trlx_tpu.telemetry import MetricsRegistry, scoped_metrics
+
+    def gauge(registry):
+        return registry.snapshot()["gauges"].get("attention/flash_row_chunk")
+
+    with scoped_metrics(MetricsRegistry()) as registry:
+        q = rand(1, 16, 2, 64)
+        dot_product_attention(q, q, q, causal=True)  # the XLA path
+        _flash_site(monkeypatch, 16, 64, T=LONG_SEQ)  # the tiled kernels
+        assert gauge(registry) is None
+        _flash_site(monkeypatch, 16, 64, T=640)
+        assert gauge(registry) == 320
+        _flash_site(monkeypatch, 16, 64, T=560)
+        assert gauge(registry) == 288 == _row_chunk(560)
+        _flash_site(monkeypatch, 16, 64, T=592)
+        assert gauge(registry) == 288  # 304 rows there: the fewest stays
+        _flash_site(monkeypatch, 3, 64)
+        assert gauge(registry) == 256 == _row_chunk(512)
+    assert _benchmark_reader("attn_row_chunk") == {
+        "kind": "counter", "name": "attention/flash_row_chunk",
+    }
 
 
 class TestBlockHelpers:

@@ -57,23 +57,9 @@ def test_paged_decode_layer_holds_no_copy_of_its_pool(one_chip):
     assert not re.search(r"= f32\[(%d,%d|%d),%d,%d\]" % (B, C, B * C, H, Dh), entry)
 
 
-@pytest.mark.parametrize(
-    "B,T,H,Dh",
-    [(16, 560, 16, 64), (16, 512, 16, 64), (8, 1000, 16, 128)],
-    ids=["tldr-update", "longgen-update", "one-tile-limit-Dh128"],
-)
-def test_the_updates_attention_compiles_to_the_kernels_and_no_scores(
-    one_chip, monkeypatch, B, T, H, Dh
-):
-    """A layer's uncached causal self-attention at the PPO cells' update
-    shapes (minibatch 16, gpt2-medium's 16 heads of 64, T 560 and 512),
-    forward and backward: on the TPU's path Mosaic takes the one-tile
-    kernels ``fitted_block`` chooses (the third case is the largest tile the
-    rule can hand it, just under ``LONG_SEQ``, at heads of 128: it has to fit
-    the kernels' VMEM), two custom calls (the forward and the one backward
-    kernel of a tile that covers both axes), and no ``[B, H, T, T]`` array
-    is left in the program, which on the XLA path holds the float32 scores
-    (321 MB a layer at T 560) forward and backward (PERF.md §6, PR 37)."""
+def _updates_attention_grad(one_chip, monkeypatch, B, T, H, Dh):
+    """``jax.grad`` of a layer's uncached causal self-attention under a
+    ``[B, 1, 1, T]`` padding bias, compiled for the described chip."""
     from trlx_tpu.ops.attention import dot_product_attention
 
     # the rule reads the backend at trace time and this process's is the CPU
@@ -87,14 +73,53 @@ def test_the_updates_attention_compiles_to_the_kernels_and_no_scores(
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     x = sds((B, T, H, Dh), jnp.bfloat16)
-    text = (
+    return (
         jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
         .lower(x, x, x, sds((B, 1, 1, T), jnp.float32))
         .compile()
-        .as_text()
     )
+
+
+@pytest.mark.parametrize(
+    "B,T,H,Dh",
+    [(16, 560, 16, 64), (16, 512, 16, 64), (8, 1000, 16, 128), (8, 944, 16, 128)],
+    ids=["tldr-update", "longgen-update", "one-tile-limit-Dh128", "widest-chunk-Dh128"],
+)
+def test_the_updates_attention_compiles_to_the_kernels_and_no_scores(
+    one_chip, monkeypatch, B, T, H, Dh
+):
+    """A layer's uncached causal self-attention at the PPO cells' update
+    shapes (minibatch 16, gpt2-medium's 16 heads of 64, T 560 and 512),
+    forward and backward: on the TPU's path Mosaic takes the one-tile
+    kernels ``fitted_block`` chooses (the third case is the largest tile the
+    rule can hand it, just under ``LONG_SEQ``, at heads of 128, and the
+    fourth the most rows a loop iteration takes over the most keys, 320 of
+    944 with the last chunk overlapping: both have to fit the kernels'
+    VMEM), two custom calls (the forward and the one backward
+    kernel of a tile that covers both axes), and no ``[B, H, T, T]`` array
+    is left in the program, which on the XLA path holds the float32 scores
+    (321 MB a layer at T 560) forward and backward (PERF.md §6, PR 37)."""
+    text = _updates_attention_grad(one_chip, monkeypatch, B, T, H, Dh).as_text()
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 2
     assert not re.search(r"\[%d,%d,%d,%d\]" % (B, H, T, T), text)
+
+
+def test_the_updates_kernels_stay_a_loop_in_the_serialized_program(
+    one_chip, monkeypatch
+):
+    """The same program at tldr's shape, serialized as the compile cache
+    holds it before compression: Mosaic unrolls a chunk's ``[rows, T]``
+    passes, so the kernels' code grows with the rows a loop iteration takes,
+    and 24 layers of it are most of ``jit_train_step``'s cache entry, which
+    two checkouts' warm runs have to share the chip machine's 192 MiB with
+    (PERF.md §7 (24)). As compiled here: five chunks of 112 rows 0.92 MB,
+    two of 288 (``_row_chunk(560)``) 1.16 MB, the whole tile as straight
+    code 1.40 MB; an edit that unrolls the loop, or a cap that takes the
+    whole tile, fails here and not in a cell's ``setup_s``."""
+    from jax.experimental.serialize_executable import serialize
+
+    compiled = _updates_attention_grad(one_chip, monkeypatch, 16, 560, 16, 64)
+    assert len(serialize(compiled)[0]) < 1.25e6
 
 
 @pytest.mark.parametrize("program,calls", [("forward", 1), ("grad", 2)])

@@ -22,12 +22,17 @@ milliseconds a layer. Beside it the largest absolute difference from the
 XLA path (output, and gradients where taken). The constants this sets are
 ``FLASH_MIN_SEQ_CAUSAL`` (``ops/attention.py``) and ``fitted_block``
 (``ops/flash_attention.py``); the table it printed is in ``PERF.md`` §6.
+``--row-caps 128,256,320,1024`` times every tiling once a cap on the query
+rows a loop iteration of the one-tile kernels takes (``ROW_CHUNK``, set for
+the sweep; a cap of the whole length is the tile as straight code): the
+table that constant comes from, in one call with ``--fitted``.
 Refuses to run without a TPU: a CPU time is no crossover.
 """
 
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import os
 import statistics
@@ -41,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from trlx_tpu.ops.attention import NEG_INF, dot_product_attention, padding_bias
+import trlx_tpu.ops.flash_attention as kernels
 from trlx_tpu.ops.flash_attention import fitted_block, flash_attention
 
 # (B, T, H, D, with the backward?)
@@ -54,6 +60,7 @@ SHAPES = [
     (16, 512, 16, 128, True),  # Dh 128 (pythia, olmoe, granite)
     (16, 640, 16, 128, True),
     (16, 1024, 16, 64, True),  # the tiling measured before this table
+    (16, 592, 16, 64, True),   # 16 x a prime: no divisor for a row chunk but 16
 ]
 
 
@@ -199,6 +206,11 @@ def main():
         "--fitted", action="store_true",
         help="time only the tiling fitted_block chooses at each length",
     )
+    ap.add_argument(
+        "--row-caps", type=lambda s: [int(i) for i in s.split(",")], default=[None],
+        help="values of flash_attention.ROW_CHUNK to time each tiling under "
+        "(default: the one the tree has)",
+    )
     opts = ap.parse_args()
     if jax.default_backend() != "tpu":
         print("attention_crossover: no TPU; a CPU time is no crossover", file=sys.stderr)
@@ -216,11 +228,19 @@ def main():
             rows.append(dict(shape=[B, T, H, D], backward=backward, path="xla", ms=xla_ms))
             print(f"[{B},{T},{H},{D}] {'fwd+bwd' if backward else 'fwd    '} xla {xla_ms:8.3f} ms", flush=True)
             fit = fitted_block(T)
-            for pad_T, bq, bk in tilings(T):
+            for (pad_T, bq, bk), cap in itertools.product(tilings(T), opts.row_caps):
                 if opts.fitted and (pad_T, bq, bk) != (-(-T // fit) * fit, fit, fit):
                     continue
+                if cap is not None:
+                    kernels.ROW_CHUNK = cap  # read where a call is traced
                 fn = as_the_model_holds_them(flash_path(pad_T, bq, bk), H)
                 row = dict(shape=[B, T, H, D], backward=backward, path="flash", tiling=[pad_T, bq, bk])
+                label = f"   flash pad {pad_T:4d} tiles {bq:4d} x {bk:4d}"
+                if (bq, bk) == (pad_T, pad_T):  # the one-tile kernels: their row loop
+                    row["rows_a_chunk"] = kernels._row_chunk(pad_T)
+                    label += f" rows {row['rows_a_chunk']:4d}"
+                elif cap is not opts.row_caps[0]:
+                    continue  # the tiled kernels have no row loop to sweep
                 try:
                     got = once(fn, args, backward)
                     row["max_abs_diff"] = [
@@ -230,14 +250,14 @@ def main():
                     row["ms"] = 1e3 * timed(looped(fn, opts.layers, backward), args, opts.reps) / opts.layers
                     row["xla_over_flash"] = xla_ms / row["ms"]
                     print(
-                        f"   flash pad {pad_T:4d} tiles {bq:4d} x {bk:4d} {row['ms']:8.3f} ms"
+                        f"{label} {row['ms']:8.3f} ms"
                         f"  xla/flash {row['xla_over_flash']:5.2f}  diff "
                         + " ".join(f"{d:.3g}" for d in row["max_abs_diff"]),
                         flush=True,
                     )
                 except Exception as e:  # a tiling Mosaic refuses is a row of the table
                     row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
-                    print(f"   flash pad {pad_T:4d} tiles {bq:4d} x {bk:4d} refused: {row['error'][:120]}", flush=True)
+                    print(f"{label} refused: {row['error'][:120]}", flush=True)
                 rows.append(row)
     os.makedirs(os.path.dirname(opts.out) or ".", exist_ok=True)
     with open(opts.out, "w") as f:

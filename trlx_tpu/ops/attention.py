@@ -182,13 +182,16 @@ def attention_path(q_shape, k_shape, *, causal, learned_bias, scale) -> str:
     return "xla"
 
 
-def _count_flash_site(folded: bool) -> None:
+def _count_flash_site(folded: bool, loop_rows: Optional[int]) -> None:
     """One traced flash call site: the counter
     ``attention/flash_operands{layout=folded|heads_major}`` and the gauge
     ``attention/flash_folded_share``, the share of the sites traced in the
     process whose kernels read the caller's own ``[B, T, H * Dh]`` (1.0: no
     site pays the transposes to heads-major; never set where no call takes
-    the kernels)."""
+    the kernels). For a site of the one-tile kernels, ``loop_rows`` query
+    rows an iteration of their loop: the gauge ``attention/flash_row_chunk``
+    holds the fewest among the sites traced in the process (each key block
+    is loaded into the MXU once an iteration, so few rows is a slow site)."""
     metrics = get_metrics()
     layouts = [
         metrics.counter("attention/flash_operands{layout=%s}" % name)
@@ -198,6 +201,9 @@ def _count_flash_site(folded: bool) -> None:
     sites = sum(c.value for c in layouts)
     if sites:  # a disabled registry counts nothing
         metrics.gauge("attention/flash_folded_share").set(layouts[0].value / sites)
+    if loop_rows is not None:
+        fewest = metrics.gauge("attention/flash_row_chunk")
+        fewest.set(min(fewest.value or loop_rows, loop_rows))  # 0: not set yet
 
 
 def flash_on_program_mesh(q, k, v, bias=None, *, causal=False,
@@ -212,13 +218,20 @@ def flash_on_program_mesh(q, k, v, bias=None, *, causal=False,
     nothing is gathered. A single-device program, and a call already inside
     a ``shard_map`` body (the pipeline's stages), run the kernel as it is.
     """
-    from trlx_tpu.ops.flash_attention import flash_attention, operand_layout
+    from trlx_tpu.ops.flash_attention import (
+        flash_attention,
+        one_tile_loop_rows,
+        operand_layout,
+    )
     from trlx_tpu.parallel.mesh import AXIS_TP, BATCH_AXES, program_mesh
 
     def kernel(q, k, v, bias=None):
         # traced once a call site, on the shapes the kernels get (under the
         # shard_map below: a device's own heads)
-        _count_flash_site(operand_layout(q.shape[2], q.shape[3]).folded)
+        _count_flash_site(
+            operand_layout(q.shape[2], q.shape[3]).folded,
+            one_tile_loop_rows(q.shape[1], k.shape[1]),
+        )
         return flash_attention(
             q, k, v, bias, causal=causal, interpret=interpret
         )

@@ -65,15 +65,18 @@ from jax.experimental.pallas import tpu as pltpu
 from trlx_tpu.ops.attention import NEG_INF
 
 # Tiles fitted to the length, from one v5e chip (tools/attention_crossover.py;
-# the tables are PERF.md §6 "PR 37" and "PR 59"; [16, T, 16 * 64] bf16, causal,
-# forward + backward, ms a layer). Under LONG_SEQ one tile over the whole
-# length wins at every length measured, because a grid step (~0.44 us) costs
-# more than skipping a future tile saves: T 560 as one 560 tile 1.60, padded
-# to one 640 tile 2.07, 256 x 256 over 768 4.7, 320 x 128 over 640 5.7,
-# 128 x 128 6.6, and the 512 x 512 over 1024 that min(512, ceil8(T)) used to
-# choose 5.1 (XLA 3.7). From LONG_SEQ 512 x 512 (T 1024: 4.7 against XLA's
-# 11.0, and 3.7 as one 1024 tile, whose VMEM grows with the length; the 1k-4k
-# sweep before it), which bounds VMEM at any length.
+# the tables are PERF.md §6 "PR 37", "PR 59" and "PR 61"; [16, T, 16 * 64]
+# bf16, causal, forward + backward, ms a layer). Under LONG_SEQ one tile over
+# the whole length wins at every length measured, because a grid step (~0.44
+# us) costs more than skipping a future tile saves: T 560 as one 560 tile 1.37
+# (its rows walked in two chunks of 288; 1.59 in five of 112, 1.17 as straight
+# code), padded to one 640 tile 2.07, 256 x 256 over 768 4.7, 320 x 128 over
+# 640 5.7, 128 x 128 6.6, and the 512 x 512 over 1024 that min(512, ceil8(T))
+# used to choose 5.1 (XLA 3.7); T 592 as one tile 1.47 (two chunks of 304;
+# 8.38 in the 37 chunks of 16 rows its divisors allowed, XLA 3.9). From
+# LONG_SEQ 512 x 512 (T 1024: 4.7 against XLA's 11.0, and 3.7 as one 1024
+# tile, whose VMEM grows with the length; the 1k-4k sweep before it), which
+# bounds VMEM at any length.
 LONG_SEQ = 1024
 LONG_BLOCK = 512
 LANES = 128  # trailing broadcast dim for row statistics
@@ -571,35 +574,70 @@ def _bwd(q, k, v, bias, o, lse, do, *, lay, scale, block_q, block_k, causal,
 
 # Rows a chunk: the stationary operand of each product is [Kp, 128] of keys or
 # values, loaded into the MXU once a chunk, so the more query rows stream past
-# it the better the MXU is used; 256 is what the code's size allows (one v5e
-# chip, PR 59, [16, 512, 16, 64] forward + backward, ms a layer and the
-# serialized executable of the pair: chunks of 128 1.21 and 0.51 MB, 256 1.00
-# and 0.61 MB, the whole 512 rows as straight code 0.91 and 0.80 MB; T 560,
-# whose only chunks are 112 and 560: 1.60 and 0.50 MB, 1.17 and 0.98 MB, which
-# 24 layers of a train step's cache entry cannot take: PERF.md §7).
-ROW_CHUNK = 256
+# it the better the MXU is used, and Mosaic unrolls a chunk's [rows, Kp]
+# passes, so the code grows with them: the cap is what the code's size allows.
+# One v5e chip, tools/attention_crossover.py --fitted --row-caps (PR 61, call
+# p61A), [16, T, 16, 64] forward + backward and [64, T, 16, 64] forward, ms a
+# layer, and the grad program of tests/test_tpu_compile.py serialized for a
+# described v5e (24 of them are most of a train step's cache entry, PERF.md
+# §7 (24)):
+#   T 560   5 x 112 rows  1.59  2.49  0.92 MB   (its largest divisor: PR 59)
+#           3 x 192       1.48  2.05  1.04 MB   (a cap of 256)
+#           2 x 288       1.37  1.83  1.16 MB   <- the last 16 rows twice
+#           1 x 560       1.17  1.56  1.40 MB   (straight code)
+#   T 592   37 x 16 rows  8.38   -              (its only divisor: 16 x 37)
+#           3 x 208       1.63   -
+#           2 x 304       1.47   -    1.08 MB
+#           1 x 592       1.24   -
+#   T 512   4 x 128 1.21 (PR 59)  2 x 256 1.00 1.18 MB  1 x 512 0.91 1.37 MB
+ROW_CHUNK = 320
 
 
 def _row_chunk(rows: int) -> int:
-    """Query rows a loop iteration takes: all of them up to ``ROW_CHUNK``,
-    else the largest divisor of ``rows`` that is a multiple of 16 (a bf16
-    tile's sublanes; ``_one_tile`` pads to that) and at most ``ROW_CHUNK``."""
+    """Query rows a loop iteration takes, fitted to the tile and no divisor
+    of it: all of them up to ``ROW_CHUNK``, else the tile over the fewest
+    iterations that keep a chunk at most ``ROW_CHUNK``, rounded up to 16 (a
+    bf16 tile's sublanes; ``_one_tile`` pads to that)."""
     if rows <= ROW_CHUNK:
         return rows
-    return max(c for c in range(16, ROW_CHUNK + 1, 16) if rows % c == 0)
+    n = -(-rows // ROW_CHUNK)
+    return -(-rows // (16 * n)) * 16
+
+
+def one_tile_loop_rows(q_len: int, k_len: int) -> Optional[int]:
+    """Query rows a loop iteration takes in a call of these lengths, which
+    takes the one-tile kernels; ``None`` from ``LONG_SEQ`` on either axis,
+    where the tiled kernels take it."""
+    if max(q_len, k_len) >= LONG_SEQ:
+        return None
+    return _row_chunk(_one_tile(q_len))
 
 
 def _for_row_chunks(n_rows, chunk, body):
-    """``body(r0)`` for each chunk's first row; one chunk is straight code."""
+    """``body(r0, fresh)`` for each chunk's first row; one chunk is straight
+    code. Where ``chunk`` does not divide ``n_rows`` the last chunk is
+    aligned to the tile's end and revisits rows of the one before it:
+    ``fresh`` is then the [chunk, 1] mask of the rows no later chunk takes
+    again (a sum over the chunks counts those alone, and a plain store ends
+    up holding each row's last, fresh, value); ``None`` where every row is."""
     if n_rows == chunk:
-        body(0)
+        body(0, None)
         return
+    n = -(-n_rows // chunk)
+    last = n_rows - chunk  # the last chunk's first row
 
     def step(i, carry):
-        body(pl.multiple_of(i * chunk, chunk))
+        if n * chunk == n_rows:
+            body(pl.multiple_of(i * chunk, chunk), None)
+            return carry
+        r0 = pl.multiple_of(jnp.minimum(i * chunk, last), 16)
+        # the next chunk's first row; the tile's end after the last
+        end = jnp.where(i + 1 < n, jnp.minimum((i + 1) * chunk, last), n_rows)
+        row = r0 + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+        body(r0, row < end)
         return carry
 
-    jax.lax.fori_loop(0, n_rows // chunk, step, 0)
+    jax.lax.fori_loop(0, n, step, 0)
 
 
 def _fwd_one_tile_kernel(*refs, scale, rows, hb, has_bias, causal):
@@ -609,7 +647,7 @@ def _fwd_one_tile_kernel(*refs, scale, rows, hb, has_bias, causal):
         q_ref, k_ref, v_ref, o_ref, lse_ref = refs
         bias_ref = None
 
-    def chunk(r0):
+    def chunk(r0, fresh):  # a revisited row is written its own value again
         at = pl.ds(r0, rows)
         q = q_ref[0, at, :]
         k_all = k_ref[0]
@@ -644,10 +682,16 @@ def _bwd_one_tile_kernel(*refs, scale, rows, hb, has_bias, causal):
     dk_s[:] = jnp.zeros_like(dk_s)
     dv_s[:] = jnp.zeros_like(dv_s)
 
-    def chunk(r0):
+    def chunk(r0, fresh):
         at = pl.ds(r0, rows)
         q = q_ref[0, at, :]
         do = do_ref[0, at, :].astype(jnp.float32)
+        if fresh is not None:
+            # a row the next chunk takes again counts there: with its
+            # cotangent zeroed here its delta and ds are zero too, so it adds
+            # nothing to dk and dv, and the zeros this chunk stores in its dq
+            # are overwritten by the chunk that owns it
+            do = jnp.where(fresh, do, 0.0)
         o = o_ref[0, at, :].astype(jnp.float32)
         k_all = k_ref[0]
         v32 = v_ref[0].astype(jnp.float32)
