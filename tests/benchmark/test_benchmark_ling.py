@@ -1,0 +1,545 @@
+"""The ling-3.0-flash-vl configuration and its cell: the shape rule's counts
+and a decode step's bytes pinned by hand (ISSUE 62's arithmetic), the
+published keys against the catalog row, the reference's two halves, the count
+functions of the new readers on made-up trace operations, the tolerance file
+under its rule, the manifest's entries, a CPU rehearsal of
+``serve-ling3flash-reason1k`` at a toy size through the code the chip runs
+(form only: CPU numbers), and the cell's control (the compute one precision
+below) coming out not correct there."""
+
+import contextlib
+import json
+import re
+
+import numpy as np
+import pytest
+
+from benchmark import arithmetic, checks, harness
+from benchmark.run import run_cell
+
+CELL = "serve-ling3flash-reason1k"
+CONFIG = "ling-3.0-flash-vl"
+TRAFFIC = "reason1k-ling3flash"
+# by hand, d 2560. A KDA mixer: W_q, W_k, W_v and W_g 2560 x 4096 each, the two head-wise matrices 2560 x 32,
+# W_o 4096 x 2560; the taps 4 x 12288, A_log 32, dt_bias 4096, the head norm 128
+KDA_MATRICES = 4 * 10_485_760 + 2 * 81_920 + 10_485_760
+KDA = KDA_MATRICES + 49_152 + 32 + 4096 + 128
+# a latent mixer: W_q 2560 x 32 x 192, W_kva 2560 x 576, W_kvb 512 x 32 x 256, W_o 4096 x 2560; the latent's norm 512
+LATENT_MATRICES = 15_728_640 + 1_474_560 + 4_194_304 + 10_485_760
+LATENT = LATENT_MATRICES + 512
+EXPERT = 3 * 2560 * 768
+SHARED = EXPERT
+ROUTER = 2560 * 512
+DENSE = 3 * 2560 * 6144
+TABLE = 19648 * 2560
+FIXED_KDA = KDA + SHARED + ROUTER + 512 + 2 * 2560  # a routed block without its routed experts (512: the selection bias)
+FIXED_LATENT = LATENT + SHARED + ROUTER + 512 + 2 * 2560
+STATE_VALUES = 32 * 128 * 128 + 3 * 12288
+
+
+@pytest.fixture(scope="module")
+def config_file():
+    return harness.load_json("configs", f"{CONFIG}.json")
+
+
+def shape_of(cf):
+    return arithmetic.model_shape(harness.load_family(cf), cf)
+
+
+def test_parameters_of_the_cut_and_of_the_whole_model(config_file):
+    assert (KDA, LATENT, EXPERT, ROUTER, DENSE) == (52_646_048, 31_883_776, 5_898_240, 1_310_720, 47_185_920)
+    dense_block = KDA + DENSE + 2 * 2560
+    assert (dense_block, FIXED_KDA + 64 * EXPERT, FIXED_LATENT + 64 * EXPERT) == (99_837_088, 437_348_000, 416_585_728)
+    held = dense_block + 5 * (FIXED_KDA + 64 * EXPERT) + (FIXED_LATENT + 64 * EXPERT) + 2 * TABLE + 2560
+    assert held == 2_803_763_136 == config_file["parameters"]  # the issue's 2.80 B
+    assert arithmetic.backbone_params(shape_of(config_file)) == held
+    assert 2 * held / 1e9 == pytest.approx(5.61, abs=0.005)  # bf16
+    whole = dict(config_file, num_hidden_layers=42, first_k_dense_replace=2, num_experts=512, vocab_size=157184)
+    want = (2 * dense_block + 33 * (FIXED_KDA + 512 * EXPERT) + 7 * (FIXED_LATENT + 512 * EXPERT)
+            + 2 * 157184 * 2560 + 2560)
+    assert arithmetic.backbone_params(shape_of(whole)) == want == 124_049_503_712 == config_file["parameters_whole_model"]
+    assert "124,049,503,712" in config_file["published"]["parameters"]
+
+
+def test_shape_entries(config_file):
+    s = shape_of(config_file)
+    kinds = harness.load_family(config_file).layer_kinds(config_file)
+    assert kinds == ["kda"] * 5 + ["latent_attention", "kda"]
+    assert len(s["layers"]) == 7
+    first = s["layers"][0]  # the one leading dense block: a KDA mixer and the dense SwiGLU
+    assert first["params"] == first["read_params"] == 99_837_088 and "routed" not in first
+    assert first["matmul_params"] == KDA_MATRICES + DENSE
+    assert (first["attn_dim"], first["kv_values"], first["state_values"]) == (0, 0, STATE_VALUES)
+    for kind, layer in list(zip(kinds, s["layers"]))[1:]:
+        # 8 choices x 64 of 512 held: one expert a token, counted as 8 x 64 x 5898240 // 512
+        routed_share = 8 * 64 * EXPERT // 512
+        assert routed_share == EXPERT
+        assert layer["routed"] == {"expert_params": 5_898_240, "per_token": 8}
+        if kind == "kda":
+            assert layer["params"] == 437_348_000 and layer["read_params"] == FIXED_KDA
+            assert layer["matmul_params"] == KDA_MATRICES + SHARED + ROUTER + routed_share
+            assert (layer["attn_dim"], layer["kv_values"], layer["state_values"]) == (0, 0, STATE_VALUES)
+            assert STATE_VALUES == 561_152
+        else:
+            assert layer["params"] == 416_585_728 and layer["read_params"] == FIXED_LATENT
+            assert layer["matmul_params"] == LATENT_MATRICES + SHARED + ROUTER + routed_share
+            # 2 (128 + 64) + 2 x 128 FLOPs a head and pair; one row of 512 + 64 values a position
+            assert (layer["attn_dim"], layer["kv_values"]) == (32 * 320 // 2, 576) and "state_values" not in layer
+        assert "kv_read_cap" not in layer
+        assert arithmetic.decode_read_params(layer) == layer["read_params"] + 8 * EXPERT
+    assert s["embed_params"] == TABLE == 50_298_880
+    assert s["final"] == {"params": 2560 + TABLE, "matmul_params": TABLE, "read_params": 2560 + TABLE}
+
+
+def test_a_decode_steps_bytes_by_hand(config_file):
+    s = shape_of(config_file)
+    # the weights a step must read once in bf16 (8 experts a routed block: one token's choices), the head; 256
+    # sequences: a state and a tail a KDA layer read and written at float32, 640 cached rows of 576 values in the
+    # one latent layer
+    weights = 2 * ((KDA + DENSE + 5120) + 5 * (FIXED_KDA + 8 * EXPERT) + (FIXED_LATENT + 8 * EXPERT) + 2560 + TABLE)
+    kv = 576 * 256 * 641 * 2
+    state = 2 * 6 * STATE_VALUES * 256 * 4
+    assert (weights, kv, state) == (1_543_311_232, 189_038_592, 6_895_435_776)
+    assert arithmetic.decode_step_bytes(s, 256, 640, weight_bytes=2, kv_bytes=2, state_bytes=4) == weights + kv + state
+    with pytest.raises(ValueError, match="state_dtype"):
+        arithmetic.decode_step_bytes(s, 256, 640)
+    # the issue's arithmetic at 256 slots: 2 x 537 MB a state layer a step; the footprint of the states, the
+    # tails and the one latent pool of capacity 1536 as the engine holds it (640 wide)
+    assert 256 * 32 * 128 * 128 * 4 == 536_870_912
+    assert 6 * 2 * 536_870_912 / 1e9 == pytest.approx(6.44, abs=0.005)
+    assert 256 * 6 * 32 * 128 * 128 * 4 / 1e9 == pytest.approx(3.22, abs=0.005)
+    assert 256 * 6 * 3 * 12288 * 4 / 1e9 == pytest.approx(0.23, abs=0.005)
+    assert 256 * 1536 * 640 * 2 / 1e9 == pytest.approx(0.50, abs=0.005)
+    # the states are most of what a step must move
+    assert state / (weights + kv + state) > 0.75
+
+
+def test_published_keys_are_the_catalog_rows(config_file):
+    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
+        rows = [json.loads(line) for line in f]
+    row = [r for r in rows if r["source_url"] == config_file["source"]]
+    assert len(row) == 1 and row[0]["name"] == "Ling-3.0-flash-VL"
+    published = row[0]["config"]
+    differs = sorted(k for k, v in published.items() if config_file.get(k, "absent") != v)
+    assert differs == sorted(config_file["reduced"]) == [
+        "expert_swiglu_limit_list", "first_k_dense_replace", "num_experts", "num_hidden_layers",
+        "share_expert_swiglu_limit_list", "vocab_size"]
+    assert sorted(config_file["reduced_how"]) == differs
+    assert {k: config_file["published"][k] for k in differs} == {k: published[k] for k in differs}
+    assert (config_file["num_hidden_layers"], config_file["first_k_dense_replace"], config_file["num_experts"],
+            config_file["vocab_size"]) == (7, 1, 64, 19648)
+    assert config_file["vocab_size"] * 8 == published["vocab_size"]  # an eighth: the floor
+    # one leading dense block and one whole period of the pattern
+    assert config_file["num_hidden_layers"] - config_file["first_k_dense_replace"] == published["layer_group_size"]
+    # the limit lists are cut with the depth, and every entry the cut keeps is the published one: no clamp
+    for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+        assert config_file[key] == published[key][:7] == [0] * 7
+    assert (config_file["num_router_experts"], config_file["first_local_expert"]) == (512, 0)
+    assert config_file["num_experts"] * published["n_group"] == published["num_experts"]  # one router group
+    # every published width unchanged
+    assert (config_file["hidden_size"], config_file["num_attention_heads"], config_file["head_dim"],
+            config_file["kv_lora_rank"], config_file["qk_rope_head_dim"], config_file["moe_intermediate_size"],
+            config_file["n_group"], config_file["num_experts_per_tok"], config_file["routed_scaling_factor"],
+            config_file["short_conv_kernel_size"], config_file["kda_lower_bound"]) == (
+        2560, 32, 128, 512, 64, 768, 8, 8, 2.5, 4, -5)
+    # the cut brings keys of its own and no width: every other top-level number is a published key's
+    own = {k for k, v in config_file.items() if isinstance(v, (int, float)) and not isinstance(v, bool)} - set(published)
+    assert own == {"num_router_experts", "first_local_expert", "parameters", "parameters_whole_model"}
+    assert config_file["source"] == "https://huggingface.co/inclusionAI/Ling-3.0-flash-VL/blob/main/config.json"
+    assert set(config_file["assumed"]) >= {
+        "weights", "language_model_only", "tie_word_embeddings", "rope_scaling", "layer_pattern", "kda_conv",
+        "kda_qk_norm", "kda_safe_gate", "kda_output_gate", "rotary_pair_layout", "group_score", "router_bias",
+        "swiglu_limit", "multi_token_prediction", "initialisers", "eos_token_id", "state_dtype"}
+    assert "8 chips" in config_file["deployment"] and "64 of 512" in config_file["deployment"]
+    assert "19,648" in config_file["deployment"]
+    assert set(config_file["run"]["arch_keys"]) >= (set(published) - {
+        "image_patch_token", "video_patch_token", "image_start_token", "video_start_token",
+        "partial_rotary_factor", "mtp_use_kda"}) | {"num_router_experts", "first_local_expert", "state_dtype"}
+    run = config_file["run"]
+    assert run["dtype"] == run["param_dtype"] == run["kv_cache_dtype"] == "bfloat16" and run["state_dtype"] == "float32"
+
+
+def test_check_config_refuses_an_inconsistent_file(config_file):
+    family = harness.load_family(config_file)
+    family.check_config(config_file)
+    for over, said in [
+        ({"rope_scaling": {"type": "yarn", "factor": 4}}, "rope_scaling"),
+        ({"q_lora_rank": 1536}, "q_lora_rank"),
+        ({"use_mla_nope": True}, "use_mla_nope"),
+        ({"kda_safe_gate": False}, "kda_safe_gate"),
+        ({"use_kda_lora": True}, "use_kda_lora"),
+        ({"group_norm_size": 4}, "group_norm_size"),
+        ({"score_function": "softmax"}, "score_function"),
+        ({"tie_word_embeddings": True}, "tie_word_embeddings"),
+        ({"expert_swiglu_limit_list": [0, 0, 0, 0, 0, 0, 4]}, "SwiGLU clamp"),
+        ({"share_expert_swiglu_limit_list": [5] * 7}, "SwiGLU clamp"),
+        ({"kda_lower_bound": 0}, "kda_lower_bound"),
+        ({"rotary_dim": 32}, "rotary_dim"),
+        ({"first_k_dense_replace": 8}, "first_k_dense_replace"),
+        ({"num_experts": 513}, "not among the router's 512"),
+        ({"first_local_expert": 449}, "not among the router's 512"),
+        ({"n_group": 7}, "n_group"),
+        ({"state_dtype": "bfloat16"}, "state_dtype"),
+        ({"run": dict(config_file["run"], kv_cache_dtype="int8")}, "int8"),
+    ]:
+        with pytest.raises(ValueError, match=said):
+            family.check_config(dict(config_file, **over))
+
+
+def test_the_program_builds_the_configuration(config_file):
+    import jax
+
+    from trlx_tpu.models.registry import get_model_family
+    from trlx_tpu.ops.kv_cache import cache_kind, hold_pool
+
+    family = get_model_family(config_file["model_type"])
+    assert family.name == "ling"
+    cfg = family.config_cls.from_dict(harness.arch_of(config_file))
+    assert (cfg.num_experts, cfg.num_router_experts, cfg.first_local_expert) == (64, 512, 0)
+    assert list(cfg.layer_types) == harness.load_family(config_file).layer_kinds(config_file)
+    assert (cfg.conv_channels, cfg.latent_width, cfg.qk_head_dim) == (12288, 576, 192)
+    cache = jax.eval_shape(lambda: family.init_cache(cfg, 256, 1536))
+    for kind, layer in zip(cfg.layer_types, cache):
+        if kind == "latent_attention":
+            assert set(layer) == {"k"} and layer["k"].shape == (256, 1536, 1, 576) and cache_kind(layer).latent
+            held = jax.eval_shape(lambda: hold_pool(family.init_cache(cfg, 2, 16)[5]))
+            assert held["k"].shape == (2, 16, 1, 640)  # whole lanes, as the engine holds it
+        else:
+            assert layer["ssm_state"].shape == (256, 32, 128, 128) and layer["conv_tail"].shape == (256, 3, 12288)
+            assert layer["ssm_state"].dtype == layer["conv_tail"].dtype == np.float32
+    # 1.2 KB a position in the one latent layer: the row the shape rule counts; 12.6 MB of state a sequence
+    assert shape_of(config_file)["layers"][5]["kv_values"] * 2 == 1152
+    assert 6 * 32 * 128 * 128 * 4 / 1e6 == pytest.approx(12.6, abs=0.05)
+
+
+TINY = dict(
+    vocab_size=96, hidden_size=64, num_attention_heads=4, num_key_value_heads=4, head_dim=16, kv_lora_rank=24,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, rotary_dim=8, v_head_dim=16, intermediate_size=96,
+    moe_intermediate_size=32, moe_shared_expert_intermediate_size=32, num_experts=4, num_router_experts=16,
+    first_local_expert=4, num_experts_per_tok=4, n_group=4, topk_group=2,
+)
+
+
+def test_the_halves_compose_and_the_program_reads_the_same_logits(config_file):
+    import jax
+    import jax.numpy as jnp
+
+    from trlx_tpu.models.registry import get_model_family
+
+    cf = dict(config_file, **TINY)
+    family = harness.load_family(cf)
+    fam = get_model_family(cf["model_type"])
+    arch = dict(harness.arch_of(cf), dtype="float32", param_dtype="float32")
+    model = fam.backbone_cls(fam.config_cls.from_dict(arch))
+    ids = jnp.asarray(np.random.default_rng(0).integers(0, 95, (2, 12)), jnp.int32)
+    mask = jnp.asarray([[1] * 12, [0] * 5 + [1] * 7], jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    hidden = family.trunk(params, cf, ids, mask)
+    whole = np.asarray(family.forward(params, cf, ids, mask))
+    np.testing.assert_array_equal(np.asarray(family.head(params, cf, hidden)), whole)
+    assert family.HEAD_BLOCKS > 1 and whole.shape == (2, 12, 96)
+    # the rows the checks ask for are the same rows of the whole
+    part = family.head(params, cf, hidden[:, 7:-1])
+    np.testing.assert_allclose(np.asarray(part), whole[:, 7:-1], rtol=1e-6, atol=1e-6)
+    # and the program at float32, holding experts 4..7 of 16, reads the same logits
+    got = model.apply({"params": params}, ids, attention_mask=mask)["logits"]
+    real = np.asarray(mask) > 0
+    assert np.abs(np.asarray(got) - whole)[real].max() / whole[real].std() < 3e-5
+
+
+def record_of(config_file, gauges=None):
+    return {"cell": {"config_file": config_file, "traffic_file": {"slots": 256}}, "gauges": gauges or {}}
+
+
+def pattern(name):
+    return harness.load_json("layer_metrics", f"{name}.json")["reader"]["op"]
+
+
+# as a trace of the finished program names them (my chip run, PR 62): six reads a step, one a KDA layer, and the
+# passes that write the new states
+STEP_OPS = {"multiply_reduce_fusion f32[256,32,128]": {"s": 1.0, "count": 60},
+            "multiply_add_fusion f32[256,32,128,128]": {"s": 1.0, "count": 60}}
+CHUNK_OPS = {"convolution_add_fusion f32[8,32,128,128]": {"s": 1.0, "count": 24},
+             "multiply_convert_fusion bf16[8,32,64,128]": {"s": 1.0, "count": 24},
+             "fusion f32[8,32,64,128]": {"s": 1.0, "count": 72},
+             "fusion bf16[8,32,4,16,64]": {"s": 1.0, "count": 24},
+             "convolution_negate_fusion f32[8,32,16,2,2]": {"s": 1.0, "count": 24},
+             "copy-done f32[2,8,32,64,128]": {"s": 1.0, "count": 24},
+             "bitcast_dynamic-update-slice_fusion f32[8,8,32,64,128]": {"s": 1.0, "count": 48}}
+
+
+def test_count_functions_of_the_new_readers(config_file):
+    family = harness.load_family(config_file)
+    # the step's passes over one layer's state: 256 slots x 32 x 128 x 128 float32 read and written (2 x 537 MB),
+    # counted at the operation that reads a layer's state out; the pass that writes the new state adds its time
+    flops, moved = family.kda_step_count(record_of(config_file), STEP_OPS)
+    assert moved == 60 * 2 * 536_870_912 and flops == 60 * 7 * 134_217_728
+    assert moved / 819e9 > flops / 197e12  # bound by the bytes
+    assert all(re.search(pattern("kda_step_roofline"), name) for name in STEP_OPS)
+    for other in ("broadcast f32[256,32,128]", "multiply_reduce_fusion f32[256,32]", "fusion f32[256,3,12288]",
+                  "multiply_reduce_fusion f32[128,32,128]"):
+        assert not re.search(pattern("kda_step_roofline"), other)
+    # a chunk of the rule: 8 rows, 32 heads, L = 64, counted once a chunk at the operation that forms a chunk's
+    # outgoing state [rows, H, D, D]
+    flops, moved = family.kda_chunk_prefill_count(record_of(config_file), CHUNK_OPS)
+    L = 64
+    a_head = 4 * L * L * 128 + L**3 + 4 * L * L * 128 + 3 * 2 * L * 128 * 128 + 2 * L * L * 128
+    assert flops == 24 * 8 * 32 * a_head
+    assert moved == 24 * 8 * 32 * (2 * L * 4 * 128 + 4 * L * 128 + 8 * 128 * 128)
+    assert all(re.search(pattern("kda_chunk_prefill_roofline"), name) for name in CHUNK_OPS)
+    # the latent layer's, the experts' and the decode step's operations stay out
+    assert family.CHUNK_COLUMNS == 64
+    for other in ("fusion f32[8,32,512]", "fusion bf16[8,32,128,512]", "fusion bf16[8,128,12288]",
+                  "convolution_convert_fusion bf16[8,512,32,256]", "ragged-dot-none bf16[8192,768]",
+                  "multiply_add_fusion f32[256,32,128,128]", "fusion f32[8,32]",
+                  "dynamic-slice_dynamic-update-slice_fusion f32[8,32,128,128]"):
+        assert not re.search(pattern("kda_chunk_prefill_roofline"), other)
+    # a decode step's grouped multiplication: the touched held experts x one d x F matrix in bf16
+    ops = {"ragged-dot-none bf16[2048,768]": {"s": 1.0, "count": 120}, "ragged-dot-none bf16[2048,2560]": {"s": 1.0, "count": 60}}
+    assert family.moe_ep8_gmm_decode_count(record_of(config_file), ops) == (0.0, 0.0)
+    gauges = {"moe/experts_touched": 61.0, "moe/rows_here_share": 0.125}
+    flops, moved = family.moe_ep8_gmm_decode_count(record_of(config_file, gauges), ops)
+    assert moved == 180 * 61.0 * 2560 * 768 * 2 and flops == pytest.approx(180 * 2 * 0.125 * 2048 * 2560 * 768)
+    assert moved / 819e9 > flops / 197e12  # bound by the bytes: a held expert sees 4 rows
+    assert all(re.search(pattern("moe_ep8_gmm_decode_roofline"), name) for name in ops)
+    # at an admission's rows: the rows whose expert is held here, every held expert's matrix read once
+    ops = {"ragged-dot-none bf16[8192,768]": {"s": 1.0, "count": 16}, "ragged-dot-none bf16[32768,2560]": {"s": 1.0, "count": 8}}
+    rows = 8192 * 16 + 32768 * 8
+    flops, moved = family.moe_ep8_gmm_prefill_count(record_of(config_file), ops)
+    assert flops == 2 * rows / 8 * 2560 * 768  # the even share, 64 of 512
+    flops, moved = family.moe_ep8_gmm_prefill_count(record_of(config_file, gauges), ops)
+    assert flops == pytest.approx(2 * 0.125 * rows * 2560 * 768)
+    assert moved == pytest.approx(2 * 0.125 * rows * (2560 + 768) + 24 * 2 * 64 * 2560 * 768)
+    assert all(re.search(pattern("moe_ep8_gmm_prefill_roofline"), name) for name in ops)
+    # the other routed cells' row counts read nothing here, and this cell's nothing there
+    for other in ("moe_gmm_decode_roofline", "moe_share_gmm_decode_roofline", "moe_top1_gmm_decode_roofline",
+                  "moe_ep16_gmm_decode_roofline", "moe_ep4_gmm_decode_roofline"):
+        assert not re.search(pattern(other), "ragged-dot-none bf16[2048,768]")
+    assert not re.search(pattern("moe_ep8_gmm_decode_roofline"), "ragged-dot-none bf16[1280,512]")
+    assert not re.search(pattern("gdn_step_roofline"), "multiply_add_fusion f32[256,32,128,128]")
+
+
+def test_count_function_of_the_latent_layers_read(config_file):
+    # the one latent layer's absorbed read of the pool beside the states: once a step, counted at the scores
+    # f32[slots, capacity, H]; the values' product adds its time only (a trace of the finished program, PR 62)
+    family = harness.load_family(config_file)
+    ops = {"fusion f32[256,1536,32]": {"s": 1.0, "count": 146}, "fusion bf16[256,32,576]": {"s": 1.0, "count": 146}}
+    record = dict(record_of(config_file), decode={"batch": 170.0, "mean_context": 150.0 + 512.0})
+    flops, moved = family.mla_hybrid_absorbed_read_count(record, ops)
+    positions = 146 * 170.0 * 663.0
+    assert moved == 2 * 576 * positions  # one row of 512 + 64 bf16 values a cached position
+    assert flops == 2 * 32 * (2 * 512 + 64) * positions
+    assert moved / 819e9 > flops / 197e12  # bound by the bytes
+    # under the pool as the program reads it: every slot's whole capacity, held 640 wide
+    assert moved / 146 < 256 * 1536 * 640 * 2
+    assert all(re.search(pattern("mla_hybrid_absorbed_read_roofline"), name) for name in ops)
+    # deepseek-v3's shapes, the softmax's statistics, the pool's write and the KDA step's reads stay out
+    for other in ("fusion f32[64,1536,128]", "fusion bf16[64,128,576]", "fusion f32[256,32]",
+                  "fusion bf16[256,1536,640]", "copy bf16[256,32,576]", "multiply_reduce_fusion f32[256,32,128]"):
+        assert not re.search(pattern("mla_hybrid_absorbed_read_roofline"), other)
+    assert not re.search(pattern("mla_absorbed_read_roofline"), "fusion f32[256,1536,32]")
+
+
+OWN = {"kda_step_roofline": "KDA layer", "kda_chunk_prefill_roofline": "KDA layer",
+       "moe_ep8_gmm_decode_roofline": "expert layer", "moe_ep8_gmm_prefill_roofline": "expert layer"}
+# the latent layer beside the states, under names of its own (`mla_latent_gb` and `mla_absorbed_read_roofline` are
+# pinned to deepseek-v3's cell and shapes): (layer, moves, unit, better, source)
+LATENT_OWN = {"mla_hybrid_latent_gb": ("device", "serve_tokens_per_s", "GB", "lower", "program_counter"),
+              "mla_hybrid_absorbed_read_roofline": ("latent attention", "serve_itl_p95_ms", "%", "higher", "device_trace")}
+
+
+def test_manifest_lists_the_cell_and_its_readers():
+    with open(harness.REPO + "/BENCHMARK.json") as f:
+        manifest = json.load(f)
+    cell = [w for w in manifest["workloads"] if w["name"] == CELL]
+    assert len(cell) == 1 and cell[0]["chips"] == 1 and len(cell[0]["why"]) <= 200
+    assert (cell[0]["config"], cell[0]["traffic"]) == (CONFIG, TRAFFIC)
+    assert cell[0]["why"] == harness.load_json("workloads", f"{CELL}.json")["why"]
+    assert manifest["workloads"][-1]["name"] == CELL and manifest["configs"][-1]["name"] == CONFIG  # put last
+    config = [c for c in manifest["configs"] if c["name"] == CONFIG]
+    assert len(config) == 1 and len(config[0]["why"]) <= 200 and len(config[0]["source"]) <= 200
+    assert sorted(config[0]["reduced"]) == sorted(harness.load_json("configs", f"{CONFIG}.json")["reduced"])
+    assert config[0]["file"] == f"benchmark/configs/{CONFIG}.json"
+    for m in manifest["end_to_end"]:
+        if m["name"].startswith("serve_"):
+            assert m["workloads"][-1] == CELL
+    for m in manifest["per_layer"]:
+        if m["name"] in OWN:
+            assert m["workloads"] == [CELL] and (m["layer"], m["moves"]) == (OWN[m["name"]], "serve_itl_p95_ms")
+            assert (m["unit"], m["better"], m["source"]) == ("%", "higher", "device_trace")
+        if m["name"] in LATENT_OWN:
+            assert m["workloads"] == [CELL]
+            assert (m["layer"], m["moves"], m["unit"], m["better"], m["source"]) == LATENT_OWN[m["name"]]
+    assert [m["name"] for m in manifest["per_layer"]][-6:] == list(OWN) + list(LATENT_OWN)
+    names = {s["name"] for s in harness.load_layer_metrics(CELL)}
+    assert set(OWN) | set(LATENT_OWN) | {"decode_serve_roofline", "moe_experts_touched", "moe_max_load", "moe_rows_here_share",
+                       "ssm_state_gb", "mla_pool_pinned_share", "hbm_peak_gb.serve",
+                       "serve_step_ahead_share", "serve_long_gap_share"} <= names
+    # every serve metric the other serve cells all report is read here too
+    from manifest_cells import SERVE_CELLS
+
+    others = [set(s["name"] for s in harness.load_layer_metrics(c)) for c in SERVE_CELLS if c != CELL]
+    assert len(others) == 6 and set.intersection(*others) <= names
+    # `mla_latent_gb` stays deepseek-v3's alone: `test_benchmark_deepseek_v3.py` pins its list to that cell, and a
+    # `model_config` PR edits no file the benchmark has (CHANGES.md, PR 62); the same gauge is read here as
+    # `mla_hybrid_latent_gb`
+    assert "mla_latent_gb" not in names
+    # the other cells' patterns and the tail's readers read nothing here
+    assert not {"moe_gmm_decode_roofline", "moe_share_gmm_decode_roofline", "moe_top1_gmm_decode_roofline",
+                "moe_ep16_gmm_decode_roofline", "moe_ep4_gmm_decode_roofline", "ssm_step_roofline",
+                "ssm_scan_prefill_roofline", "gdn_step_roofline", "gdn_chunk_prefill_roofline", "cca_tail_gb",
+                "mla_absorbed_read_roofline", "mla_prefill_attn_roofline", "moe_skip_share"} & names
+    readers = {s["name"]: s["reader"] for s in harness.load_layer_metrics(CELL)}
+    assert readers["ssm_state_gb"] == {"kind": "counter", "name": "cache/state_gb"}
+    assert readers["mla_hybrid_latent_gb"] == {"kind": "counter", "name": "cache/latent_gb"}
+    assert readers["mla_hybrid_absorbed_read_roofline"]["count"] == "mla_hybrid_absorbed_read_count"
+    assert readers["mla_pool_pinned_share"] == {"kind": "counter", "name": "cache/latent_pinned_share"}
+    assert all(readers[n]["kind"] == "op_roofline" for n in OWN)
+    assert {n: readers[n]["count"] for n in OWN} == {
+        "kda_step_roofline": "kda_step_count", "kda_chunk_prefill_roofline": "kda_chunk_prefill_count",
+        "moe_ep8_gmm_decode_roofline": "moe_ep8_gmm_decode_count",
+        "moe_ep8_gmm_prefill_roofline": "moe_ep8_gmm_prefill_count"}
+    family = harness.load_family(harness.load_json("configs", f"{CONFIG}.json"))
+    assert all(callable(getattr(family, readers[n]["count"])) for n in OWN)
+    # the mix: reason1k-deepseekv3 key for key but for the slots, the seeds and the knee, as ISSUE 62 named it. The
+    # drain is that mix's 40 s: a request is 1024 steps of 41.6 ms = 42.6 s here (27.7 s there), so the window's
+    # last four seconds of arrivals cannot end (16 of 180 read `failed` in every run, `correct` all the same:
+    # the cell's `why` and PERF.md say so; a longer drain is a `benchmark` PR's to bring, PERF.md section 7 (72))
+    traffic = harness.load_json("traffic", f"{TRAFFIC}.json")
+    model = harness.load_json("traffic", "reason1k-deepseekv3.json")
+    differs = sorted(k for k in set(traffic) | set(model) if traffic.get(k) != model.get(k))
+    assert differs == ["arrivals", "name", "order_seed", "slots", "traffic_seed", "weights_seed"]
+    assert traffic["drain_limit_s"] == 40 < 1024 * 0.0416 and "outlast a 40 s drain" in cell[0]["why"]
+    assert traffic["slots"] in (256, 128) and (traffic["slots"] == 256 or "128 slots" in cell[0]["why"])
+    assert traffic["weights_seed"] == traffic["order_seed"] == traffic["traffic_seed"] == 20261004
+    assert (traffic["seq_length"], traffic["max_new_tokens"], traffic["min_new_tokens"], traffic["admit_width"],
+            traffic["harvest_width"]) == (512, 1024, 1024, 8, 8)
+    assert traffic["arrivals"]["process"] == "poisson" and traffic["arrivals"]["load"] == 0.8
+    assert set(traffic["arrivals"]) == set(model["arrivals"])
+    # the rate the cell's `why` states is the mix's
+    assert "%g/s" % round(traffic["arrivals"]["knee_per_s"] * 0.8, 2) in cell[0]["why"]
+    # over an eighth of the vocabulary a request of 1024 tokens would draw EOS with 5%: past the README's 1%
+    assert 1 - (1 - 1 / 19648) ** 1024 == pytest.approx(0.0508, abs=0.0005)
+
+
+def test_the_tolerances_the_cell_is_held_to(config_file):
+    tol = checks.tolerances_of(config_file, "bfloat16")
+    assert set(tol) >= {"logprob_rms", "logprob_max"}
+    assert config_file["tolerances"] == f"benchmark/tolerances/{CONFIG}.json"
+    with open(harness.REPO + "/" + config_file["tolerances"]) as f:
+        table = json.load(f)
+    checks.check_tolerance_file(table, config_file["tolerances"])  # measured on itself, and kept to the rule
+    measured = table["measured"]["bfloat16/kv-bfloat16"][CELL]
+    assert measured["logprob_rms"]["runs"] >= 8 and measured["logprob_rms"]["seeds"] >= 4
+    assert measured["logprob_rms"]["max"] < tol["logprob_rms"] <= 3 * measured["logprob_rms"]["max"]
+    cheaper = table["cheaper"]["bfloat16/kv-bfloat16"][CELL]
+    assert cheaper["logprob_rms"]["runs"] >= 4 and cheaper["logprob_rms"]["min"] > tol["logprob_rms"]
+    # what the tolerance does not hold is said, with its readings beside
+    assert "DOES NOT HOLD" in table["how"] and table["beside"]
+
+
+@pytest.fixture
+def quiet_program(monkeypatch):
+    monkeypatch.setenv("WANDB_DISABLED", "1")
+    monkeypatch.setattr(harness, "place_compile_cache", lambda: "off")
+
+
+def shrunk():
+    cell = harness.load_cell(CELL)
+    cell["config_file"].update(TINY)
+    cell["config_file"].pop("tolerances", None)  # measured at the published sizes: the shared table at a toy size
+    cell["mesh"] = {"dp": -1, "fsdp": 1, "tp": 1}
+    cell["traffic_file"].update(
+        seq_length=16, max_new_tokens=8, min_new_tokens=8, slots=16, admit_width=8, harvest_width=8,
+        prompt_lengths={"dist": "lognormal", "median": 8, "sigma": 0.5, "lo": 2, "hi": 16},
+        arrivals={"process": "poisson", "knee_per_s": 25.0, "load": 0.8}, warmup_requests=12,
+        drain_limit_s=30, trace_seconds=1)
+    return cell
+
+
+@pytest.mark.parametrize("trace", [False, True], ids=["end-to-end", "traced"])
+def test_cpu_rehearsal_of_the_cell(trace, capsys, quiet_program):
+    line = run_cell(CELL, 2**31 + 62, 2.0, trace, allow_cpu=True, cell=shrunk())
+    out = json.loads(line)
+    said = capsys.readouterr().out
+    assert out["correct"] is True and out["failed"] == 0 and out["attempted"] == 40
+    assert out["device"]["platform"] == "cpu"
+    assert all(c["ok"] for c in out["checks"].values())
+    assert out["checks"]["reference.sampled_logprob_rms"]["value"] < 5e-3
+    assert "check accounting.compiles_in_window" in said
+    if not trace:
+        assert set(out["metrics"]) == {"serve_itl_p95_ms", "serve_tokens_per_s", "setup_s"}
+        return
+    # program counters read on any platform; the device trace has no TPU plane here
+    assert {"ssm_state_gb", "mla_pool_pinned_share", "moe_rows_here_share", "moe_experts_touched",
+            "moe_max_load", "engine_slot_util", "serve_itl_p99_ms", "serve_step_ahead_share"} <= set(out["metrics"])
+    # six KDA layers x 16 slots x (4 x 16 x 16 state + 3 x 192 tail) float32
+    assert out["metrics"]["ssm_state_gb"]["value"] == pytest.approx(6 * 16 * (1024 + 576) * 4 / 1e9)
+    assert out["metrics"]["mla_pool_pinned_share"]["value"] == 1.0
+    # the one latent layer's pool: 16 slots x 24 positions x (24 + 8) bfloat16 values, as the model addresses it
+    assert out["metrics"]["mla_hybrid_latent_gb"]["value"] == pytest.approx(16 * 24 * 32 * 2 / 1e9)
+    assert 0 < out["metrics"]["moe_rows_here_share"]["value"] < 1
+    assert out["metrics"]["moe_experts_touched"]["value"] <= 4
+    assert not (set(OWN) | {"mla_hybrid_absorbed_read_roofline"}) & set(out["metrics"]) and "busy_s" not in out["device"]
+
+
+@contextlib.contextmanager
+def float8_compute():
+    """The cell's control (the tolerance file's ``cheaper`` group; the chip's
+    readings at the cell's own size are there): the compute one precision
+    below the bfloat16 the configuration states. The input of every Dense
+    projection (both mixers, the dense block, the shared expert's gate and
+    up, the head) and of the expert layer rounded to float8_e4m3fn; weights
+    as served, accumulation float32, the state float32."""
+    import flax.linen as nn
+    import jax.numpy as jnp
+
+    from trlx_tpu.ops import moe
+
+    f8 = lambda x: x.astype(jnp.float8_e4m3fn).astype(x.dtype)
+    dense_call, layer = nn.Dense.__call__, moe.expert_layer
+    nn.Dense.__call__ = lambda self, x: dense_call(self, f8(x))
+    moe.expert_layer = lambda h, *a, **kw: layer(f8(h), *a, **kw)
+    try:
+        yield
+    finally:
+        nn.Dense.__call__, moe.expert_layer = dense_call, layer
+
+
+@pytest.mark.parametrize("seed", [2**31 + 61, 2**31 + 62])
+def test_the_float8_compute_control_comes_out_not_correct(seed, monkeypatch, quiet_program):
+    """The control planted under a rehearsal, beside a sound run of the same
+    seed. Two things are the toy's own, for both runs alike: every expert is
+    chosen (4 of 4, so no choice can fall the other way between bfloat16 and
+    the float32 reference, which at this size swamps any rounding), and the
+    seeded matrices are twice as loud (at width 64, normal(0.02) gives
+    logits so flat that the rounded inputs pass the shared table's limit by
+    half only; four times, as deepseek-v3's toy takes them, and seven
+    blocks over six states carry the sound run itself past it). The
+    reference lifts the server's own tree, so it follows both."""
+    import flax.linen as nn
+    import jax
+
+    from benchmark import serve_driver
+    from trlx_tpu.ops import moe
+
+    seeded = serve_driver.seeded_params
+    monkeypatch.setattr(serve_driver, "seeded_params", lambda config, s: jax.tree_util.tree_map(
+        lambda x: 2.0 * x if x.ndim >= 2 else x, seeded(config, s)))
+
+    def rms(control):
+        cell = shrunk()
+        cell["config_file"].update(num_router_experts=4, num_experts=4, first_local_expert=0,
+                                   num_experts_per_tok=4, n_group=1, topk_group=1)
+        with float8_compute() if control else contextlib.nullcontext():
+            out = json.loads(run_cell(CELL, seed, 1.0, False, allow_cpu=True, cell=cell))
+        check = out["checks"]["reference.sampled_logprob_rms"]
+        assert out["correct"] is check["ok"] and out["failed"] == 0
+        return check["value"], check["ok"]
+
+    dense_call, layer = nn.Dense.__call__, moe.expert_layer
+    sound, ok = rms(False)
+    assert ok
+    cheaper, ok = rms(True)
+    assert not ok and cheaper > 2 * sound
+    assert nn.Dense.__call__ is dense_call and moe.expert_layer is layer  # the control takes itself out again

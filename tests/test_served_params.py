@@ -69,6 +69,12 @@ ARCHS = {
         linear_num_value_heads=4, linear_key_head_dim=8, linear_value_head_dim=16,
         linear_chunk_size=8, moe_intermediate_size=32, shared_expert_intermediate_size=48,
         num_experts=4, num_router_experts=8, first_local_expert=0, num_experts_per_tok=2),
+    "ling": dict(
+        vocab_size=96, hidden_size=64, num_hidden_layers=7, first_k_dense_replace=1, layer_group_size=6,
+        num_attention_heads=4, num_key_value_heads=4, head_dim=16, kv_lora_rank=24, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, rotary_dim=8, v_head_dim=16, intermediate_size=96, moe_intermediate_size=32,
+        moe_shared_expert_intermediate_size=32, num_experts=4, num_router_experts=8, first_local_expert=0,
+        num_experts_per_tok=2, n_group=4, topk_group=2),
 }
 
 
@@ -219,7 +225,13 @@ def leaf_consumers(model_type, columns):
     )
     keep = family.stored_width_leaves
     B, C = 2, 16
-    cache = jax.eval_shape(lambda: family.init_cache(model_config, B, C))
+    from trlx_tpu.ops.kv_cache import cache_kind, identity_block_tables
+
+    # a latent layer is read through block tables only (ops/attention.py::decode_attention)
+    cache = jax.eval_shape(lambda: tuple(
+        dict(layer, block_tables=identity_block_tables(B, C // 4)) if cache_kind(layer).latent else layer
+        for layer in family.init_cache(model_config, B, C)
+    ))
 
     def call(p, ids, mask, positions, cache, index):
         return model.apply({"params": p}, ids, attention_mask=mask, position_ids=positions,
@@ -266,7 +278,7 @@ def test_each_excluded_name_is_a_leaf_some_program_uses_at_its_width():
     from trlx_tpu.models.registry import get_model_family
 
     earned = {}
-    for model_type in ("gpt2_moe", "granitemoehybrid", "zaya", "qwen3_next"):
+    for model_type in ("gpt2_moe", "granitemoehybrid", "zaya", "qwen3_next", "ling"):
         earned[model_type] = set()
         for path, (leaf, cast, names, found) in leaf_consumers(model_type, 8).items():
             if found - THROUGH_THE_CAST:

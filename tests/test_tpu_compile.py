@@ -214,6 +214,58 @@ def test_cca_decode_layer_steps_its_tail_and_holds_no_copy_of_its_pool(one_chip)
     assert not re.search(r"= f32\[(%d,%d|%d),2,128\]" % (B, C, B * C), entry)
 
 
+def _kda_mixer(one_chip, B, T):
+    """``ops/delta.py::kda_mix`` between its projections at Ling-3.0-flash's
+    published widths (32 heads of 128 keys and 128 values, conv 4, the gate
+    bounded at -5), on ``B`` rows of ``T`` columns with the layer's state
+    donated: ``(compiled, the optimised text)``."""
+    from trlx_tpu.ops import delta
+
+    H, D = 32, 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def mix(qkv, g_raw, b_raw, conv_weight, dt_bias, A_log, mask, layer):
+        return delta.kda_mix(qkv, g_raw, b_raw, conv_weight=conv_weight, dt_bias=dt_bias, A_log=A_log, n_heads=H,
+                             key_dim=D, value_dim=D, lower_bound=-5.0, chunk=64, mask=mask, cache_layer=layer)
+
+    layer = {"ssm_state": sds((B, H, D, D), jnp.float32), "conv_tail": sds((B, 3, 3 * H * D), jnp.float32)}
+    args = (sds((B, T, 3 * H * D), jnp.bfloat16), sds((B, T, H * D), jnp.bfloat16), sds((B, T, H), jnp.bfloat16),
+            sds((4, 3 * H * D), jnp.float32), sds((H * D,), jnp.bfloat16), sds((H,), jnp.bfloat16),
+            sds((B, T), jnp.float32), layer)
+    compiled = jax.jit(mix, donate_argnums=(7,)).lower(*args).compile()
+    return compiled, compiled.as_text()
+
+
+def test_kda_decode_layer_steps_its_state_in_two_passes_and_in_place(one_chip):
+    """One KDA layer of the ``serve-ling3flash-reason1k`` decode step: 256
+    slots of a ``[32, 128, 128]`` float32 state, 537 MB. The chip's compiler
+    takes the vector decay's step; the state is touched by two operations,
+    one that reads it out under ``k`` and ``q`` (both reads in one pass) and
+    one that reads and writes it, and nothing state-sized is kept beside
+    the donated buffer."""
+    B = 256
+    compiled, text = _kda_mixer(one_chip, B, 1)
+    state_bytes = B * 32 * 128 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < state_bytes // 8
+    entry = text.split("\nENTRY ", 1)[1]
+    readers = [line for line in entry.split("\n") if "fusion(" in line and "%layer__ssm_state__" in line]
+    assert len(readers) == 2, readers
+    writers = [line for line in readers if re.search(r"= f32\[%d,32,128,128\]" % B, line)]
+    assert len(writers) == 1 and not re.search(r"= f32\[%d,32,128,128\]\S* copy\(" % B, entry)
+
+
+def test_kda_chunk_admission_compiles_in_row_blocks_of_sixteen(one_chip):
+    """The same layer on an admission chunk (8 rows x 128 columns: two
+    chunks of 64 in a loop): the chip's compiler takes the chunked form,
+    whose scores are formed a row block of 16 at a time (``bf16[8, 32, 4,
+    16, 64]``), and the loop's temporaries stay far under one decode state."""
+    compiled, text = _kda_mixer(one_chip, 8, 128)
+    assert re.search(r"bf16\[8,32,4,16,64\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 10**6
+
+
 def _latent_sublayer(one_chip, held, B, C, n_blocks, T, rows=None):
     """One ``DeepseekV3Attention`` sublayer at published widths over a pool
     of ``B`` slots x ``C`` positions, compiled with the layer donated:

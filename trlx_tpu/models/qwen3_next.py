@@ -373,10 +373,10 @@ class Qwen3NextBlock(nn.Module):
         return x + y, new_layer, stats
 
 
-def _refuse_sharded_mesh():
+def _refuse_sharded_mesh(family: str = "qwen3_next"):
     """A ``tp``, ``ep`` or ``pp`` axis on the mesh the traced program
     declares (``parallel/mesh.py::traced_on``), or an installed ``ep`` mesh
-    context: none shards this family."""
+    context: none shards ``family`` (this one, and ``models/ling.py``)."""
     from trlx_tpu.models.gpt2_moe import get_ep_mesh
     from trlx_tpu.parallel.mesh import program_mesh
 
@@ -388,8 +388,8 @@ def _refuse_sharded_mesh():
     for axis in ("tp", "ep", "pp"):
         if axis in sharded:
             raise ValueError(
-                f"a {axis} mesh is not built for qwen3_next: a state has no head axis "
-                "sharded here and the gated shared expert beside the experts is built "
+                f"a {axis} mesh is not built for {family}: a state has no head axis "
+                "sharded here and the shared expert beside the experts is built "
                 "off an ep mesh only (ops/moe.py); use dp / fsdp"
             )
 

@@ -233,3 +233,25 @@ def _register_builtins() -> None:
             stored_width_leaves=("conv_weight",),
         )
     )
+    from trlx_tpu.models.ling import (
+        LING_PARTITION_RULES,
+        LingConfig,
+        LingModel,
+        init_ling_cache,
+        no_ling_checkpoint,
+    )
+
+    # not supports_ep: nothing trains its router (no loss is sown), and the
+    # model refuses an ep axis by name. `bailing_hybrid` is the model_type
+    # the family's published config.json carries
+    register_model_family(
+        ModelFamily(
+            "ling", LingConfig, LingModel, LING_PARTITION_RULES,
+            init_ling_cache, no_ling_checkpoint,
+            # the convolution's taps (ops/ssm.py::causal_conv multiplies at
+            # f32); A_log, dt_bias, the norms and the selection bias are
+            # vectors, which every server keeps as stored
+            stored_width_leaves=("conv_weight",),
+        ),
+        "bailing_hybrid",
+    )

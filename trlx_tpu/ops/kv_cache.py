@@ -553,20 +553,28 @@ def hybrid_cache(
     batch_size: int,
     capacity: int,
     *,
-    n_kv_head: int,
-    head_dim: int,
+    n_kv_head: Optional[int] = None,
+    head_dim: Optional[int] = None,
     dtype,
     kv_cache_dtype: str,
     state: Dict[str, int],
     state_dtype: str = "float32",
     keys: Tuple[str, ...] = ("attention",),
+    latent_width: Optional[int] = None,
 ) -> Cache:
     """The cache of a model whose layers differ: :func:`kv_buffers` for an
     entry of ``layer_types`` that ``keys`` names (the caller says which of
     its family's layer kinds hold keys), :func:`state_buffers` (with the
-    sizes in ``state``) for any other. ``int8`` has no state form and
-    a step that reads one layer in ten through it gains nothing: with a
-    state layer it is refused by name (``auto`` could resolve to it)."""
+    sizes in ``state``) for any other. With ``latent_width`` the layers
+    ``keys`` names keep one latent row of that width a position and no
+    values (:func:`latent_buffers`), so one sequence holds a matrix state
+    and a latent row in the same cache and :func:`cache_kind` answers for
+    each layer by itself; a caller gives either that width or ``n_kv_head``
+    and ``head_dim``, and one that gives both or neither is refused (a
+    forgotten size would be a buffer of no width). ``int8`` has no state
+    form and a step that reads one layer in ten through it gains nothing:
+    with a state layer it is refused by name (``auto`` could resolve to
+    it)."""
     stateful = sorted(set(layer_types) - set(keys))
     if stateful and kv_cache_dtype != "bfloat16":
         raise ValueError(
@@ -574,10 +582,21 @@ def hybrid_cache(
             f"({stateful}) is not built: a "
             "state has no int8 form; choose 'bfloat16'"
         )
+    sized = (n_kv_head is not None, head_dim is not None)
+    if any(sized) != all(sized) or (latent_width is not None) == all(sized):
+        raise ValueError(
+            "hybrid_cache takes either latent_width (a latent row a position) or "
+            f"n_kv_head and head_dim (keys and values), got latent_width={latent_width!r}, "
+            f"n_kv_head={n_kv_head!r}, head_dim={head_dim!r}"
+        )
+
+    def holds_keys():
+        if latent_width is not None:
+            return latent_buffers(1, batch_size, capacity, latent_width, dtype, kv_cache_dtype)[0]
+        return kv_buffers(1, batch_size, capacity, n_kv_head, head_dim, dtype, kv_cache_dtype)[0]
+
     return tuple(
-        kv_buffers(1, batch_size, capacity, n_kv_head, head_dim, dtype, kv_cache_dtype)[0]
-        if kind in keys
-        else state_buffers(batch_size, state_dtype=state_dtype, **state)
+        holds_keys() if kind in keys else state_buffers(batch_size, state_dtype=state_dtype, **state)
         for kind in layer_types
     )
 
