@@ -38,6 +38,7 @@ from trlx_tpu.ops.kv_cache import (
     cache_kind,
     hybrid_cache,
     identity_block_tables,
+    hold_pool,
     rotate_block_table,
     state_buffers,
 )
@@ -98,24 +99,27 @@ def test_uncached_forward_matches_the_reference_on_left_padded_rows():
     assert float(stats["experts_touched"]) <= 4 and 0 < float(stats["rows_here_share"]) < 1
 
 
+@pytest.mark.parametrize("head_dim", [16, 256], ids=["heads_of_16", "heads_of_256_held_in_lane_rows"])
 @pytest.mark.parametrize("chunk", [0, 4], ids=["whole", "chunked"])
-def test_admission_then_decode_through_state_and_paged_pool_matches_the_full_forward(chunk):
+def test_admission_then_decode_through_state_and_paged_pool_matches_the_full_forward(chunk, head_dim):
     """An admission of 16 columns (whole, or in chunks of 4 that carry the
     state and the tail from call to call) then five steps, the full layer
     through a paged pool whose second row's blocks are rotated, the linear
     layers through their state: logits against the reference's full
-    forward."""
-    cfg, model, params = model_and_params()
+    forward. The pool is as its holder keeps it (``hold_pool``): at the
+    published head of 256, each head as two lane rows."""
+    cfg, model, params = model_and_params(head_dim=head_dim)
     T, Q, cap = 21, 16, 24
     ids, mask = left_padded([21, 13, 6], T, seed=1)
-    want = ref.forward(params, reference_cfg(cfg), ids, mask)
+    want = ref.forward(params, reference_cfg(cfg, head_dim=head_dim), ids, mask)
     tables = identity_block_tables(3, cap // 4)
     tables = tables.at[1].set(rotate_block_table(tables[1], 2))
     cache = tuple(
-        c if cache_kind(c).layout == STATE else dict(c, block_tables=tables)
+        c if cache_kind(c).layout == STATE else dict(hold_pool(c), block_tables=tables)
         for c in init_qwen3_next_cache(cfg, 3, cap)
     )
     assert [cache_kind(c).layout for c in cache] == [STATE, STATE, STATE, PAGED]
+    assert cache[3]["k"].shape == ((3, cap, 4, 128) if head_dim == 256 else (3, cap, 2, 16))
     grow = lambda m: jnp.concatenate([m, jnp.zeros((3, cap - m.shape[1]), jnp.int32)], axis=1)
     positions = jnp.clip(jnp.cumsum(mask, axis=-1) - 1, 0, None)
     for lo in range(0, Q, chunk or Q):
@@ -520,15 +524,15 @@ Q, R, EOS = 16, 6, 95
 
 
 @functools.lru_cache(maxsize=None)
-def engine(prefill_chunk=0, chunks_per_pump=0):
+def engine(prefill_chunk=0, chunks_per_pump=0, **over):
     from trlx_tpu.inference.engine import ContinuousBatchingEngine
     from trlx_tpu.models.heads import CausalLMWithValueHead
     from trlx_tpu.ops.sampling import GenerationConfig
 
-    cfg, _, _ = model_and_params()
+    cfg, _, _ = model_and_params(**over)
     model = CausalLMWithValueHead(cfg, backbone_cls=Qwen3NextModel)
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    params = dict(params, transformer=model_and_params()[2])
+    params = dict(params, transformer=model_and_params(**over)[2])
 
     def apply_fn(p, input_ids, attention_mask=None, position_ids=None, cache=None,
                  cache_index=None, last_only=False):
@@ -573,21 +577,28 @@ def drive(eng, params, ids, mask, pump):
     return got
 
 
-@pytest.mark.parametrize("chunk,pump", [(0, False), (4, False), (4, True)],
-                         ids=["whole", "chunked", "chunk-a-pump"])
-def test_engine_logprobs_match_the_uncached_forward_on_the_tokens_it_drew(chunk, pump):
+@pytest.mark.parametrize("chunk,pump,over", [(0, False, {}), (4, False, {}), (4, True, {}),
+                                             (4, True, {"head_dim": 256})],
+                         ids=["whole", "chunked", "chunk-a-pump", "chunk-a-pump-heads-of-256"])
+def test_engine_logprobs_match_the_uncached_forward_on_the_tokens_it_drew(chunk, pump, over):
     """Ten requests through four slots: every slot is recycled, after
     requests of other lengths (the longest first), with whole and chunked
     admission. The recorded log-probability of every drawn token is the
-    reference's on [prompt; drawn tokens]."""
-    eng, params = engine(chunk, 1 if pump else 0)
-    cfg = model_and_params()[0]
+    reference's on [prompt; drawn tokens]. At the published head of 256 the
+    engine holds the pool in lane rows (``ops/kv_cache.py::hold_pool``):
+    recycled slots' rotated tables, the block write, the gathered view and
+    the read as stored all go through the held shape."""
+    eng, params = engine(chunk, 1 if pump else 0, **over)
+    cfg = model_and_params(**over)[0]
+    if over:
+        (pool,) = [c["k"] for c in jax.eval_shape(eng._make_state).cache if "k" in c]
+        assert pool.shape[2:] == (4, 128)
     lens = [16, 15, 3, 9, 2, 12, 5, 16, 4, 7]
     ids, mask = left_padded(lens, Q, seed=4)
     ids, mask = np.asarray(ids), np.asarray(mask)
     got = drive(eng, params, ids, mask, pump)
     assert sorted(got) == list(range(len(lens)))
-    forward = jax.jit(lambda p, i, m: ref.forward(p, reference_cfg(cfg), i, m))
+    forward = jax.jit(lambda p, i, m: ref.forward(p, reference_cfg(cfg, **over), i, m))
     for r, row in got.items():
         full_ids = jnp.asarray(np.r_[ids[r], row["tokens"]])[None]
         full_mask = jnp.asarray(np.r_[mask[r], row["response_mask"]])[None]

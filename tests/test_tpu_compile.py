@@ -409,6 +409,83 @@ def test_an_admission_layer_writes_whole_blocks_in_place(one_chip, C, H, T, firs
     assert not re.search(r"= bf16\[%d,%d,%d\]\S* scatter\(" % (B * C, H, Dh), text)
 
 
+@HELD
+@pytest.mark.parametrize("program", ["chunk", "whole", "step"])
+def test_a_pool_of_heads_of_256_is_held_in_lane_rows_and_no_program_relays_it(one_chip, held, program):
+    """One full-attention sublayer's cache traffic at the
+    ``serve-qwen3next-chat512`` cell's widths (bf16, 128 slots x 1024
+    positions, 2 KV heads of 256 under 16 query heads, blocks of 16, the
+    pool donated) in the engine's three programs: a chunk of an admission
+    (a group of 8 rows x 128 columns from a traced block), a whole admission
+    (8 x 512) and the decode step (one row a slot, the pool read as stored).
+    Held by the rule (``ops/kv_cache.py::hold_pool``: ``[128, 1024, 4,
+    128]``, a head as its two lane rows) the pool's view by blocks is a
+    bitcast and no operation of ``ENTRY`` returns anything pool-sized but
+    the two writes, in place; the chunk's and the step's temporaries stay
+    under an eighth of a pool (the whole admission's are its float32 scores,
+    ``[8, 16, 512, 1024]``, twice a pool, as they are without the rule). The
+    other case documents why: as the model allocates it (``[128, 1024, 2,
+    256]``, ``T(2,128)``) each admission re-tiles the whole pool into the
+    block view (``[128, 64, 32, 256]``, ``T(8,128)``) and back, a ``reshape``
+    each way for K and for V (0.59 ms each on the chip, PERF.md section 6,
+    PR 63), and the step, which writes by position, does not; a libtpu that
+    stops doing so fails that case by name, and the rule has lost its
+    reason. Nothing runs: no time here."""
+    from trlx_tpu.ops import kv_cache as kc
+    from trlx_tpu.ops.attention import decode_attention
+
+    S, C, H, Hq, Dh, A, bs, Q = 128, 1024, 2, 16, 256, 8, 16, 512
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    layer = jax.eval_shape(lambda: kc.kv_buffers(1, S, C, H, Dh, jnp.bfloat16)[0])
+    if held:
+        layer = jax.eval_shape(kc.hold_pool, layer)
+    shape = layer["k"].shape
+    assert shape == ((S, C, 4, 128) if held else (S, C, H, Dh)) and kc.block_view_is_bitcast(layer) == held
+    pool = sds(shape, jnp.bfloat16)
+    if program == "step":
+        cache = {"k": pool, "v": pool, "block_tables": sds((S, C // bs), jnp.int32)}
+        q, new = sds((S, 1, Hq, Dh), jnp.bfloat16), sds((S, 1, H, Dh), jnp.bfloat16)
+        assert kc.reads_as_stored(cache, new, sds((S,), jnp.int32))
+        lowered = jax.jit(decode_attention, donate_argnums=(3,)).lower(
+            q, new, new, cache, sds((S,), jnp.int32), sds((S, 1, 1, C), jnp.float32)
+        )
+    else:
+        T = 128 if program == "chunk" else Q
+        cache = {"k": pool, "v": pool, "block_tables": sds((A, C // bs), jnp.int32), "slot_ids": sds((A,), jnp.int32)}
+        q, new = sds((A, T, Hq, Dh), jnp.bfloat16), sds((A, T, H, Dh), jnp.bfloat16)
+        assert kc.writes_whole_blocks(cache, new, 0)
+
+        def admit(q, k, v, cache, c, bias):
+            if program == "whole":
+                return decode_attention(q, k, v, cache, 0, bias, causal=True)
+            (cache,) = kc.starting_at_block((cache,), c * (T // bs))
+            return decode_attention(q, k, v, cache, c * T, bias, causal=True)
+
+        bias = sds((A, 1, 1, Q if program == "chunk" else C), jnp.float32)
+        lowered = jax.jit(admit, donate_argnums=(3,)).lower(q, new, new, cache, sds((), jnp.int32), bias)
+    compiled = lowered.compile()
+    entry = compiled.as_text().split("\nENTRY ", 1)[1]
+    pool_elems = int(np.prod(shape))
+    returned = {}
+    for m in re.finditer(r"= bf16\[([\d,]+)\]\S* ([\w\-]+)\(", entry):
+        dims = [int(d) for d in m.group(1).split(",")]
+        if np.prod(dims) == pool_elems and dims[0] == S and m.group(2) not in ("parameter", "bitcast"):
+            returned.setdefault(m.group(2), []).append(dims)
+    relaid = 0 if held or program == "step" else 4
+    assert len(returned.pop("reshape", [])) == relaid, entry
+    # what is left are the two writes, in place: a fusion each, of the pool as it lies or as viewed by blocks
+    assert list(returned) == ["fusion"] and len(returned["fusion"]) == 2, returned
+    if not relaid and program != "whole":
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * pool_elems // 8
+    if program != "step":
+        view = [S, C // bs, bs * shape[2], shape[3]]
+        assert returned["fusion"] == [view, view]
+        assert len(re.findall(r"= bf16\[%d,%d,%d,%d\]\S* bitcast\(" % tuple(view), entry)) == (2 if held else 0)
+
+
 def _while_bodies(text, fused=False):
     """The text of every computation some ``while`` of the compiled module
     names as its body, and of the branches of the ``conditional``s in them;

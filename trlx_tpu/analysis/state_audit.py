@@ -276,6 +276,10 @@ EPHEMERAL_CONTRACTS: Dict[Tuple[str, str], str] = {
         "the latent pools lie on the device: a function of the config and "
         "the backend); read again by the next init_state, feeds no token"
     ),
+    ("ContinuousBatchingEngine", "_block_bitcast_share"): (
+        "gauge value read off the held cache's shapes by _make_state (a "
+        "function of the config); read again by the next, feeds no token"
+    ),
     # ---- QoS scheduler ------------------------------------------------ #
     ("QoSScheduler", "_queues"): (
         "in-flight request queues: the preemption contract drains the "

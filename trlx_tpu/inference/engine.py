@@ -109,6 +109,7 @@ from trlx_tpu.ops.kv_cache import (
     SHARED_POOL_KEYS,
     SHARE_TABLE_KEYS,
     STATE,
+    block_view_is_bitcast,
     cache_kind,
     choose_block_size,
     choose_prefill_chunk,
@@ -578,6 +579,7 @@ class ContinuousBatchingEngine:
         self._param_shardings = param_shardings
         self._cache_sharding = cache_sharding
         self._latent_pinned_share: Optional[float] = None  # read in init_state
+        self._block_bitcast_share: Optional[float] = None  # read in _make_state
         self._cache_gb = self._measure_cache()
         self._pads_a_pool = jax.eval_shape(self._held_cache) != jax.eval_shape(
             lambda: self._init_cache_fn(self.num_slots, self.capacity)
@@ -764,21 +766,41 @@ class ContinuousBatchingEngine:
             registry.gauge(f"cache/{key}_gb").set(value)
         if self._latent_pinned_share is not None:
             registry.gauge("cache/latent_pinned_share").set(self._latent_pinned_share)
+        if self._block_bitcast_share is not None:
+            registry.gauge("cache/block_write_bitcast_share").set(self._block_bitcast_share)
 
     def _held_cache(self):
         """The model's cache with every pool as its holder keeps it across
-        programs: a latent pool's rows padded to whole lanes, or each
-        program copies the pool in and out (``ops/kv_cache.py::hold_pool``)."""
+        programs (``ops/kv_cache.py::hold_pool``): a latent pool's rows
+        padded to whole lanes, or each program copies the pool in and out;
+        a head of several lane rows as those rows, or each admission
+        re-tiles the pool around its block write."""
         return tuple(
             hold_pool(layer) for layer in self._init_cache_fn(self.num_slots, self.capacity)
         )
 
+    def _measure_block_bitcast(self, cache) -> None:
+        """Gauge ``cache/block_write_bitcast_share``: of the layers of
+        ``cache`` (as held) that keep keys and values, the share whose
+        pool the admission's block write views by blocks without moving it
+        (``ops/kv_cache.py::block_view_is_bitcast``: read off the held
+        shape, the predicate ``hold_pool`` holds a pool by). Nothing where
+        no layer keeps keys and values."""
+        kinds = [(layer, cache_kind(layer)) for layer in cache]
+        votes = [
+            block_view_is_bitcast(layer)
+            for layer, kind in kinds
+            if kind.layout != STATE and not kind.latent
+        ]
+        self._block_bitcast_share = sum(votes) / len(votes) if votes else None
+
     def _make_state(self) -> EngineState:
         B, Q, R, V = self.num_slots, self.Q, self.R, self.vocab_size
         cfg = self.gen_config
-        # a padded pool is built in one program, so that the zeros it is
-        # padded from never lie beside it
+        # a pool held at another shape than the model's is built in one
+        # program, so that the zeros it is made from never lie beside it
         linear = jax.jit(self._held_cache)() if self._pads_a_pool else self._held_cache()
+        self._measure_block_bitcast(linear)
         tables = identity_block_tables(B, self.n_blocks)
         # one table array PER layer (logically shared, physically
         # distinct): the jitted programs donate the whole state, and XLA
@@ -2611,8 +2633,7 @@ class ContinuousBatchingEngine:
         registry.gauge("engine/slot_util").set(self.stats.slot_util)
         # again: the registry may have been cleared
         registry.gauge("engine/param_gb").set(self.stats.param_gb)
-        if self._cache_gb["state"] or self._cache_gb["tail"] or self._cache_gb["latent"]:
-            self._publish_cache_gauges(self._cache_gb)
+        self._publish_cache_gauges(self._cache_gb)
         t_done = telemetry.monotonic() if self.trace_requests else 0.0
         if rows is None:
             rows = list(self._busy_rows.items())

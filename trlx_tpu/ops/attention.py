@@ -338,18 +338,58 @@ def _grouped_attention(q, k, v, bias, scale):
         preferred_element_type=jnp.float32,
     ) * scale
     if bias is not None:
-        b32 = bias.astype(jnp.float32)
-        if bias.shape[1] == 1:
-            b32 = b32[:, :, None]
-        else:
-            b32 = b32.reshape(bias.shape[0], H_kv, G, Q, K)
-        logits = logits + b32
+        logits = logits + _grouped_bias(bias, H_kv, G)
     weights = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum(
         "bhgqk,bkhd->bqhgd", weights.astype(v.dtype), v,
         preferred_element_type=jnp.float32,
     )
     return out.reshape(B, Q, H, v.shape[-1]).astype(q.dtype)
+
+
+def _grouped_bias(bias, H_kv: int, G: int):
+    """``bias`` ``[B or 1, 1 or H, Q, K]`` in float32 over grouped scores
+    ``[B, H_kv, G, Q, K]``: a per-head bias is per query head."""
+    b32 = bias.astype(jnp.float32)
+    if bias.shape[1] == 1:
+        return b32[:, :, None]
+    return b32.reshape(bias.shape[0], H_kv, G, *bias.shape[2:])
+
+
+def _lane_rows_read(q, k, v, bias, scale):
+    """:func:`_grouped_attention` over pools that hold a head as ``J`` rows
+    of one lane row each (``ops/kv_cache.py::hold_pool``): ``k`` and ``v``
+    ``[B, K, H_kv * J, Dh // J]``, row ``kv * J + j`` of a position the
+    columns ``[j Dh / J, (j + 1) Dh / J)`` of KV head ``kv``, read as they
+    are stored. The query is arranged the same way (``[B, Q, H_kv * J, G,
+    Dh // J]``, query-sized), so the scores' product is the grouped read
+    over ``H_kv * J`` heads of one lane row; a head's ``J`` partial scores
+    are added in float32 before the scale, the bias and the softmax, the
+    weights are repeated over ``j`` for the values, and the output's lane
+    rows go back side by side. The same sums as over whole heads, a head's
+    channels taken in ``J`` parts; nothing pool-sized is reshaped."""
+    B, Q, H, D = q.shape
+    K, R, W = k.shape[1:]
+    J = D // W
+    H_kv = R // J
+    if J * W != D or H_kv * J != R or H % H_kv:
+        raise ValueError(
+            f"{H} query heads of {D} do not read a pool of {R} rows of {W} a position"
+        )
+    G = H // H_kv
+    scale = jax.lax.rsqrt(jnp.float32(D)) if scale is None else jnp.float32(scale)
+    q_rows = jnp.swapaxes(q.reshape(B, Q, H_kv, G, J, W), 3, 4).reshape(B, Q, R, G, W)
+    parts = jnp.einsum(
+        "bqrgd,bkrd->brgqk", q_rows, k, preferred_element_type=jnp.float32
+    )
+    logits = jnp.sum(parts.reshape(B, H_kv, J, G, Q, K), axis=2) * scale
+    weights = jax.nn.softmax(logits + _grouped_bias(bias, H_kv, G), axis=-1).astype(v.dtype)
+    w_rows = jnp.broadcast_to(weights[:, :, None], (B, H_kv, J, G, Q, K)).reshape(B, R, G, Q, K)
+    out = jnp.einsum(
+        "brgqk,bkrd->bqrgd", w_rows, v, preferred_element_type=jnp.float32
+    )
+    out = jnp.swapaxes(out.reshape(B, Q, H_kv, J, G, W), 3, 4)
+    return out.reshape(B, Q, H, D).astype(q.dtype)
 
 
 def _decode_read(q, k_new, v_new, cache_kv, cache_index, bias, scale=None):
@@ -605,7 +645,10 @@ def decode_attention(
       continuous engine's decode step; ``kv_cache.py::reads_as_stored``):
       the rows are scattered in place and :func:`dot_product_attention`
       reads the pools as stored, in each slot's physical order, under the
-      bias re-indexed to that order. No logical view is gathered;
+      bias re-indexed to that order. No logical view is gathered; a pool
+      its holder keeps with a head as several lane rows
+      (``kv_cache.py::hold_pool``, heads of 256) is read in those rows
+      (:func:`_lane_rows_read`), the pool never reshaped;
     - ``paged_rows`` — a group's rows inside the whole paged pool (the
       engine's admission programs; ``cache_kind(...).rows``):
       ``paged_write_read`` scatters the call's columns at (slot, physical
@@ -669,6 +712,9 @@ def decode_attention(
         bias = stored_order_bias(cache_kv["block_tables"], bias)
         if latent is not None:
             return _latent_absorbed_read(q, k, bias, scale, latent), new_kv
+        if k.shape[-1] != k_new.shape[-1]:
+            # a head held as several lane rows (kv_cache.py::hold_pool)
+            return _lane_rows_read(q, k, v, bias, scale), new_kv
         return dot_product_attention(q, k, v, bias, scale=scale), new_kv
     if latent is not None:
         if isinstance(cache_index, numbers.Integral):

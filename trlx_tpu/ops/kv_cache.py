@@ -70,7 +70,11 @@ row with zeros and the reads take a row's own columns.
 The pools are sized by **KV heads**: a family with fewer KV heads than
 query heads (grouped-query attention) allocates and reads ``H_kv`` of them,
 and the reads in ``ops/attention.py`` map query head ``h`` to KV head
-``h // (H_q / H_kv)``.
+``h // (H_q / H_kv)``. Whoever holds a paged pool across programs holds a
+head that is several whole lane rows wide (256, 512) **as those rows**
+(:func:`hold_pool`: ``[B, C, H * Dh // 128, 128]``, the same bytes), or
+every admission re-tiles the pool around its block write; the writes and
+reads here take such a head's own rows.
 
 Paging
 ------
@@ -345,9 +349,67 @@ def held_row_width(layer_kv) -> int:
 
     The padding is what the pinned layout pays too (a device row is whole
     lanes either way): a ninth more than the logical bytes
-    ``cache/latent_gb`` counts."""
-    width = layer_kv["k"].shape[-1] if "k" in layer_kv else 0
-    return -(-width // LANES) * LANES if cache_kind(layer_kv).latent else width
+    ``cache/latent_gb`` counts.
+
+    The same rule for a pool of **keys and values whose head is several
+    whole lane rows** (``Dh % 128 == 0 and Dh > 128``; :func:`lane_rows`):
+    it is held with each head as its ``Dh // 128`` rows of one lane row,
+    ``[slots, capacity, H * Dh // 128, 128]``, the same bytes with no
+    padding either way. The runtime tiles a pool's last two axes ``(H,
+    Dh)``; a head of 256 takes two tiles side by side, and merging positions
+    into heads for the block write (:func:`_scatter_blocks`) re-tiles the
+    whole pool on entry and again before the result. One full-attention
+    sublayer of qwen3-next at its cell's widths (bf16, 128 slots x 1024
+    positions, 2 KV heads of 256 under 16 query heads, blocks of 16, the
+    pool donated; the same compiler; PERF.md section 6, PR 63), a chunk of
+    an admission (8 rows x 128 columns), a whole one (8 x 512) and the
+    decode step:
+
+    - ``[128, 1024, 2, 256]`` (``T(2,128)(2,1)``): the block view ``[128,
+      64, 32, 256]`` is ``T(8,128)(2,1)``, so each admission program holds a
+      ``reshape`` of the whole pool in and one out, for K and for V (134 MB
+      of temporaries; 0.59 ms each on the chip, 4.7 of a 42.5 ms chunk
+      forward at the cell's two such layers); the step, which writes by
+      position and reads as stored, has none;
+    - ``[128, 1024, 4, 128]`` (``T(4,128)(2,1)``): the block view ``[128,
+      64, 64, 128]`` is a bitcast as it is at zaya's ``[.., 2, 128]`` and
+      pythia's ``[.., 16, 128]``, and no program of the three returns
+      anything pool-sized but the two in-place writes;
+    - ``[128, 1024, 512]`` (heads folded into the row, ``T(8,128)(2,1)``):
+      a bitcast too, and a second rank beside every other pool's four.
+
+    An int8 pool keeps its heads (a scale is a head's). A head narrower than
+    a lane row (gpt2's 64) is this rule's other half and is not built:
+    :func:`lane_rows` is where it would go (ROADMAP.md Queue 1 item 8)."""
+    if "k" not in layer_kv:
+        return 0
+    kind, width = cache_kind(layer_kv), layer_kv["k"].shape[-1]
+    if kind.latent:
+        return -(-width // LANES) * LANES
+    return width // lane_rows(layer_kv)
+
+
+def block_view_is_bitcast(layer_kv) -> bool:
+    """Whether one layer's pool of keys, at the shape it has, is viewed by
+    blocks (:func:`_scatter_blocks`: positions merged into heads) without
+    moving: its row is one lane row or less, so the merge lies above whole
+    tiles (:func:`held_row_width` has what was compiled). The shape alone."""
+    return layer_kv["k"].shape[-1] <= LANES
+
+
+def lane_rows(layer_kv) -> int:
+    """The rows of one lane row each that a head of this layer's pool of
+    keys and values is held as: ``Dh // 128`` for a floating pool whose
+    head is more than one whole lane row, 1 for everything else (a head of
+    one lane row or less, a head that fills no whole number of them, a
+    latent row, an int8 pool, a state layer, the sampler's folded rows) and
+    for a pool already held so. A head *narrower* than a lane row would be
+    held by the same rule and is not: this is where it would go."""
+    kind = cache_kind(layer_kv)
+    if kind.layout not in (DENSE, PAGED) or kind.latent or kind.quantized:
+        return 1
+    width = layer_kv["k"].shape[-1]
+    return 1 if block_view_is_bitcast(layer_kv) or width % LANES else width // LANES
 
 
 def _zero_padded(rows: jax.Array, width: int) -> jax.Array:
@@ -358,15 +420,41 @@ def _zero_padded(rows: jax.Array, width: int) -> jax.Array:
 
 
 def hold_pool(layer_kv):
-    """One layer's cache dict as its holder keeps it: the pool's rows
-    zero-padded to :func:`held_row_width` (a latent pool of 576-value rows,
-    640 wide), every other key and every other kind as it is. Zeros: a
-    padded column is never read (the reads take a row's own columns), and a
-    row is written whole, its padding with it (:func:`paged_write_read`)."""
+    """One layer's cache dict as its holder keeps it, its pools' rows at
+    :func:`held_row_width`: a latent pool's zero-padded to it (576-value
+    rows, 640 wide), a pool of keys and values whose head is several lane
+    rows with each head as those rows (``[S, C, 2, 256]`` held ``[S, C, 4,
+    128]``: the same bytes, row ``kv * J + j`` of a position is columns
+    ``[128 j, 128 (j + 1))`` of head ``kv``), every other key and every
+    other kind as it is. Nothing is pinned: the held shape's default layout
+    is the one the programs compute on. Zeros: a padded column is never read
+    (the reads take a row's own columns), and a row is written whole, its
+    padding with it. A write takes the call's rows to the pool's shape
+    (:func:`_as_held`) and never the pool to the rows'; the gathered view
+    comes back in the call's own heads (:func:`paged_write_read`) and the
+    read of the pool as stored takes a head's lane rows where they lie
+    (``ops/attention.py::decode_attention``, ``path=paged``)."""
     width = held_row_width(layer_kv)
     if "k" not in layer_kv or width == layer_kv["k"].shape[-1]:
         return layer_kv
-    return dict(layer_kv, k=_zero_padded(layer_kv["k"], width))
+    if cache_kind(layer_kv).latent:
+        return dict(layer_kv, k=_zero_padded(layer_kv["k"], width))
+    S, C, H, Dh = layer_kv["k"].shape
+    held = (S, C, H * Dh // width, width)
+    return dict(layer_kv, k=layer_kv["k"].reshape(held), v=layer_kv["v"].reshape(held))
+
+
+def _as_held(rows: jax.Array, pool: jax.Array) -> jax.Array:
+    """A call's rows ``[B, T, H, Dh]`` in the shape ``pool`` ``[.., H * J,
+    Dh // J]`` holds a position in (:func:`hold_pool`: a head as its ``J``
+    lane rows): a reshape of the call's rows, which where the pool holds
+    whole heads is no operation at all."""
+    if rows.shape[2] * rows.shape[3] != pool.shape[-2] * pool.shape[-1] or pool.shape[-2] % rows.shape[2]:
+        raise ValueError(
+            f"rows of {rows.shape[2]} heads of {rows.shape[3]} do not fill a pool "
+            f"that holds {pool.shape[-2]} rows of {pool.shape[-1]} a position"
+        )
+    return rows.reshape(rows.shape[:2] + pool.shape[-2:])
 
 
 # The largest folded buffer of one layer that a loop carrying it on its own
@@ -1063,6 +1151,13 @@ def _first_whole_block(cache_kv, k, cache_index):
     return None
 
 
+def _in_heads(view: jax.Array, rows: jax.Array) -> jax.Array:
+    """A gathered view ``[B, view, H * J, Dh // J]`` in the heads of the
+    call's ``rows`` ``[B, T, H, Dh]``: :func:`_as_held` undone, on what was
+    gathered."""
+    return view.reshape(view.shape[:2] + rows.shape[2:])
+
+
 def _pool_rows(pool: jax.Array, slot_ids) -> jax.Array:
     """[B, 1] the pool row each of a call's rows lives in: its own where
     the call spans every slot, ``slot_ids`` for a group's call."""
@@ -1085,7 +1180,7 @@ def _scatter_rows(
     position or row (a group's dummy, ``slot_ids == num_slots``) drops (jax
     scatter semantics — the discard sentinel relies on this)."""
     return pool.at[_pool_rows(pool, slot_ids), phys].set(
-        rows.astype(pool.dtype), mode="drop"
+        _as_held(rows, pool).astype(pool.dtype), mode="drop"
     )
 
 
@@ -1120,12 +1215,19 @@ def _scatter_blocks(
     n_blocks, bs * H, Dh]``, positions folded into heads: a split of a
     major axis and a merge above the minor one, which moves no data
     whatever the head count (the view ``[..., bs, H, Dh]`` does for two
-    heads: the compiler re-tiles the whole pool; PERF.md section 6, PR 49)."""
+    heads: the compiler re-tiles the whole pool; PERF.md section 6, PR 49)
+    **where a head is one lane row or less** (:func:`block_view_is_bitcast`).
+    A head of 256 is two tiles side by side and the merge re-tiles the pool
+    both ways, which is why its holder keeps such a pool with each head as
+    its lane rows (:func:`hold_pool`; what was compiled, with jax 0.9.0 and
+    libtpu 0.0.34, is in :func:`held_row_width`): the pool's own ``H`` and
+    ``Dh`` are then ``H * J`` and ``128``, and the call's rows are taken to
+    that shape (:func:`_as_held`), never the pool to the rows'."""
     S, cap, H = pool.shape[:3]
     B, n = phys_blk.shape
     bs = rows.shape[1] // n
     blocks = pool.reshape((S, cap // bs, bs * H) + pool.shape[3:])
-    windows = rows.astype(pool.dtype).reshape((B, n, bs * H) + pool.shape[3:])
+    windows = _as_held(rows, pool).astype(pool.dtype).reshape((B, n, bs * H) + pool.shape[3:])
     return (
         blocks.at[_pool_rows(pool, slot_ids), phys_blk]
         .set(windows, mode="drop")
@@ -1142,6 +1244,7 @@ def _publish_rows(
     pool allocator guarantees distinct rows never publish to the same
     block, so the scatter is collision-free."""
     idx = pub_pos.reshape(-1)
+    rows = _as_held(rows, pool)
     flat = rows.reshape((-1,) + rows.shape[2:])
     return pool.at[idx].set(flat.astype(pool.dtype), mode="drop")
 
@@ -1241,6 +1344,14 @@ def paged_write_read(
     slots use, applied per column instead of per row). int8 pools
     quantize on write and dequantize the gathered view — same bits as
     the dense int8 path per logical position.
+
+    A pool may be held in another shape than the call's rows
+    (:func:`hold_pool`: a latent row padded to whole lanes, a head of
+    several lane rows as those rows). The writes take the rows to the
+    pool's shape, the logical view comes back in the call's own heads
+    (merged on what was gathered, :func:`_in_heads`), and ``as_stored``
+    returns the pools as they are held: the caller reads a head's lane rows
+    where they lie (``ops/attention.py::_lane_rows_read``).
 
     ``view_len > 0`` narrows the returned logical view (and the shared
     overlay) to the leading ``view_len`` positions — chunk-granular
@@ -1397,6 +1508,8 @@ def paged_write_read(
     if sharing:
         new_kv["shared_k"] = _publish_rows(cache_kv["shared_k"], pub_pos, k)
         new_kv["shared_v"] = _publish_rows(cache_kv["shared_v"], pub_pos, v)
-    k_full = overlay(logical("k"), "shared_k")
-    v_full = overlay(logical("v"), "shared_v")
+    # a head held as several lane rows (hold_pool) is merged on the gathered
+    # view, the group's rows and no more, never on the pool
+    k_full = _in_heads(overlay(logical("k"), "shared_k"), k)
+    v_full = _in_heads(overlay(logical("v"), "shared_v"), v)
     return k_full, v_full, new_kv
