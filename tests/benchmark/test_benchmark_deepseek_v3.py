@@ -16,7 +16,11 @@ import pytest
 from benchmark import arithmetic, checks, harness
 from benchmark.run import run_cell
 
+import manifest_cells
+
 CELL = "serve-deepseekv3-reason1k"
+CONFIG = "deepseek-v3"
+TRAFFIC = "reason1k-deepseekv3"
 OLD_CELL = "serve-deepseekv3-reason"  # replaced by PR 55; its readings stay in the tolerance file as evidence
 # by hand, d 7168, 128 heads of nope 128 + rope 64 (scores) and 128 (values), c_q 1536, c_kv 512:
 # W_dq 7168 x 1536 + W_uq 1536 x 24576 + W_dkv 7168 x 576 + W_ukv 512 x 32768 + W_o 16384 x 7168; the two inner norms
@@ -266,39 +270,27 @@ OWN = {"mla_latent_gb": ("device", "serve_tokens_per_s"), "mla_absorbed_read_roo
        "moe_ep16_gmm_prefill_roofline": ("expert layer", "serve_itl_p95_ms")}
 
 
-def test_manifest_lists_the_cell_and_its_readers():
-    with open(harness.REPO + "/BENCHMARK.json") as f:
-        manifest = json.load(f)
-    cell = [w for w in manifest["workloads"] if w["name"] == CELL]
-    assert len(cell) == 1 and cell[0]["chips"] == 1 and len(cell[0]["why"]) <= 200
-    assert (cell[0]["config"], cell[0]["traffic"]) == ("deepseek-v3", "reason1k-deepseekv3")
-    assert cell[0]["why"] == harness.load_json("workloads", f"{CELL}.json")["why"]
-    config = [c for c in manifest["configs"] if c["name"] == "deepseek-v3"]
-    assert len(config) == 1 and len(config[0]["why"]) <= 200
-    assert sorted(config[0]["reduced"]) == sorted(harness.load_json("configs", "deepseek-v3.json")["reduced"])
-    assert config[0]["file"] == "benchmark/configs/deepseek-v3.json"
-    for m in manifest["end_to_end"]:
-        if m["name"].startswith("serve_"):
-            assert CELL in m["workloads"]
-    for m in manifest["per_layer"]:
-        if m["name"] in OWN:
-            assert m["workloads"] == [CELL] and (m["layer"], m["moves"]) == OWN[m["name"]]
-            assert m["unit"] == ("GB" if m["name"] == "mla_latent_gb" else "%")
-    names = {s["name"] for s in harness.load_layer_metrics(CELL)}
+def test_manifest_lists_the_cell_and_its_readers(either_tree):
+    manifest, root = either_tree
+    manifest_cells.cell_is_listed(manifest, root, CELL, CONFIG, TRAFFIC, chips=1)
+    manifest_cells.own_metrics_list_the_cell(manifest, CELL, {
+        n: manifest_cells.gauge(layer, moves, "GB", "lower") if n == "mla_latent_gb" else manifest_cells.roofline(layer, moves)
+        for n, (layer, moves) in OWN.items()})
+    names = manifest_cells.metric_names(CELL, root)
     assert set(OWN) | {"decode_serve_roofline", "moe_experts_touched", "moe_max_load", "moe_rows_here_share",
                        "hbm_peak_gb.serve", "serve_step_ahead_share", "serve_long_gap_share"} <= names
-    # every serve metric the other four serve cells all report is read here too
-    others = [set(s["name"] for s in harness.load_layer_metrics(c))
-              for c in ("serve-pythia1b4-chat", "serve-olmoe1b7b-chat", "serve-granite4hs-chat", "serve-zaya1-8b-reason")]
-    assert set.intersection(*others) <= names
+    # every serve metric the other serve cells all report is read here too
+    manifest_cells.lists_what_every_other_serve_cell_lists(manifest, root, CELL)
+    # a latent pool has no block view: the share of block writes that are bitcasts is the cells' that keep keys and values
+    assert "serve_pool_block_bitcast_share" not in names
     # the other routed cells' patterns and the state's and the tail's readers read nothing here
     assert not {"moe_gmm_decode_roofline", "moe_share_gmm_decode_roofline", "moe_top1_gmm_decode_roofline",
                 "ssm_state_gb", "cca_tail_gb", "moe_skip_share"} & names
-    readers = {s["name"]: s["reader"] for s in harness.load_layer_metrics(CELL)}
+    readers = {s["name"]: s["reader"] for s in harness.load_layer_metrics(CELL, root=root)}
     assert readers["mla_latent_gb"] == {"kind": "counter", "name": "cache/latent_gb"}
     assert all(readers[n]["kind"] == "op_roofline" for n in OWN if n != "mla_latent_gb")
-    traffic = harness.load_json("traffic", "reason1k-deepseekv3.json")
-    zaya = harness.load_json("traffic", "reason-zaya1-8b.json")
+    traffic = manifest_cells.load(root, "traffic", TRAFFIC)
+    zaya = manifest_cells.load(root, "traffic", "reason-zaya1-8b")
     # zaya's mix key for key but for the answers' length, the slots, the drain, the seed and the knee, the sweep's,
     # and the three keys that keep a seed from changing the work (PR 55): every answer runs to its budget, one model,
     # one order of the prompt lengths

@@ -13,9 +13,12 @@ import pytest
 
 from benchmark import arithmetic, checks, harness
 from benchmark.run import run_cell
-from manifest_cells import SERVE_CELLS
+
+import manifest_cells
 
 CELL = "serve-granite4hs-chat"
+CONFIG = "granite-4.0-h-small"
+TRAFFIC = "chat-granite4hs"
 # by hand, d 4096: a mamba mixer = 4096 x 16768 (in: 8192 z + 8448 xBC + 128 dt)
 # + 4 x 8448 + 8448 (conv, bias) + 3 x 128 (dt_bias, A_log, D) + 8192 (norm) +
 # 8192 x 4096 (out) = 102,286,976; attention = 2 x 4096^2 + 2 x 4096 x 1024 =
@@ -202,22 +205,25 @@ def test_count_functions_of_the_new_readers(config_file):
     assert flops == pytest.approx(0.45 * every) and moved == pytest.approx(0.45 * 2 * 10240 * (768 + 4096) * 30)
 
 
-def test_manifest_lists_the_cell_and_its_readers():
-    with open(harness.REPO + "/BENCHMARK.json") as f:
-        manifest = json.load(f)
-    cell = [w for w in manifest["workloads"] if w["name"] == CELL]
-    assert len(cell) == 1 and cell[0]["chips"] == 1 and CELL in SERVE_CELLS
-    for m in manifest["end_to_end"]:
-        if m["name"].startswith("serve_"):
-            assert CELL in m["workloads"]
-    names = {s["name"] for s in harness.load_layer_metrics(CELL)}
-    assert {"ssm_step_roofline", "ssm_scan_prefill_roofline", "moe_share_gmm_decode_roofline",
-            "moe_share_gmm_prefill_roofline", "ssm_state_gb", "moe_rows_here_share", "decode_serve_roofline", "moe_experts_touched",
-            "hbm_peak_gb.serve"} <= names
+OWN = {"ssm_step_roofline": manifest_cells.roofline("state-space layer"),
+       "ssm_scan_prefill_roofline": manifest_cells.roofline("state-space layer"),
+       "moe_share_gmm_decode_roofline": manifest_cells.roofline("expert layer"),
+       "moe_share_gmm_prefill_roofline": manifest_cells.roofline("expert layer")}
+
+
+def test_manifest_lists_the_cell_and_its_readers(either_tree):
+    manifest, root = either_tree
+    manifest_cells.cell_is_listed(manifest, root, CELL, CONFIG, TRAFFIC, chips=1)
+    manifest_cells.own_metrics_list_the_cell(manifest, CELL, OWN)
+    names = manifest_cells.metric_names(CELL, root)
+    assert set(OWN) | {"ssm_state_gb", "moe_rows_here_share", "decode_serve_roofline", "moe_experts_touched",
+                       "hbm_peak_gb.serve", "serve_pool_block_bitcast_share"} <= names
+    # every serve metric the other serve cells all report is read here too
+    manifest_cells.lists_what_every_other_serve_cell_lists(manifest, root, CELL)
     # olmoe's patterns (256; 8192 | 32768 rows) read nothing here
     assert not {"moe_gmm_decode_roofline", "moe_gmm_prefill_roofline"} & names
-    traffic = harness.load_json("traffic", "chat-granite4hs.json")
-    chat = harness.load_json("traffic", "chat.json")
+    traffic = manifest_cells.load(root, "traffic", TRAFFIC)
+    chat = manifest_cells.load(root, "traffic", "chat")
     # chat.json key for key but for the knee, the sweep's
     assert set(chat) == set(traffic) and {k for k in chat if chat[k] != traffic[k]} <= {"name", "arrivals"}
     assert traffic["arrivals"]["load"] == chat["arrivals"]["load"] == 0.8

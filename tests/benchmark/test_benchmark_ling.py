@@ -17,6 +17,8 @@ import pytest
 from benchmark import arithmetic, checks, harness
 from benchmark.run import run_cell
 
+import manifest_cells
+
 CELL = "serve-ling3flash-reason1k"
 CONFIG = "ling-3.0-flash-vl"
 TRAFFIC = "reason1k-ling3flash"
@@ -348,38 +350,18 @@ LATENT_OWN = {"mla_hybrid_latent_gb": ("device", "serve_tokens_per_s", "GB", "lo
               "mla_hybrid_absorbed_read_roofline": ("latent attention", "serve_itl_p95_ms", "%", "higher", "device_trace")}
 
 
-def test_manifest_lists_the_cell_and_its_readers():
-    with open(harness.REPO + "/BENCHMARK.json") as f:
-        manifest = json.load(f)
-    cell = [w for w in manifest["workloads"] if w["name"] == CELL]
-    assert len(cell) == 1 and cell[0]["chips"] == 1 and len(cell[0]["why"]) <= 200
-    assert (cell[0]["config"], cell[0]["traffic"]) == (CONFIG, TRAFFIC)
-    assert cell[0]["why"] == harness.load_json("workloads", f"{CELL}.json")["why"]
-    assert manifest["workloads"][-1]["name"] == CELL and manifest["configs"][-1]["name"] == CONFIG  # put last
-    config = [c for c in manifest["configs"] if c["name"] == CONFIG]
-    assert len(config) == 1 and len(config[0]["why"]) <= 200 and len(config[0]["source"]) <= 200
-    assert sorted(config[0]["reduced"]) == sorted(harness.load_json("configs", f"{CONFIG}.json")["reduced"])
-    assert config[0]["file"] == f"benchmark/configs/{CONFIG}.json"
-    for m in manifest["end_to_end"]:
-        if m["name"].startswith("serve_"):
-            assert m["workloads"][-1] == CELL
-    for m in manifest["per_layer"]:
-        if m["name"] in OWN:
-            assert m["workloads"] == [CELL] and (m["layer"], m["moves"]) == (OWN[m["name"]], "serve_itl_p95_ms")
-            assert (m["unit"], m["better"], m["source"]) == ("%", "higher", "device_trace")
-        if m["name"] in LATENT_OWN:
-            assert m["workloads"] == [CELL]
-            assert (m["layer"], m["moves"], m["unit"], m["better"], m["source"]) == LATENT_OWN[m["name"]]
-    assert [m["name"] for m in manifest["per_layer"]][-6:] == list(OWN) + list(LATENT_OWN)
-    names = {s["name"] for s in harness.load_layer_metrics(CELL)}
+def test_manifest_lists_the_cell_and_its_readers(either_tree):
+    manifest, root = either_tree
+    listed, _ = manifest_cells.cell_is_listed(manifest, root, CELL, CONFIG, TRAFFIC, chips=1)
+    manifest_cells.own_metrics_list_the_cell(manifest, CELL, {n: manifest_cells.roofline(l) for n, l in OWN.items()})
+    manifest_cells.own_metrics_list_the_cell(
+        manifest, CELL, {n: dict(zip(manifest_cells.FIELDS, v)) for n, v in LATENT_OWN.items()})
+    names = manifest_cells.metric_names(CELL, root)
     assert set(OWN) | set(LATENT_OWN) | {"decode_serve_roofline", "moe_experts_touched", "moe_max_load", "moe_rows_here_share",
                        "ssm_state_gb", "mla_pool_pinned_share", "hbm_peak_gb.serve",
                        "serve_step_ahead_share", "serve_long_gap_share"} <= names
     # every serve metric the other serve cells all report is read here too
-    from manifest_cells import SERVE_CELLS
-
-    others = [set(s["name"] for s in harness.load_layer_metrics(c)) for c in SERVE_CELLS if c != CELL]
-    assert len(others) == 6 and set.intersection(*others) <= names
+    manifest_cells.lists_what_every_other_serve_cell_lists(manifest, root, CELL)
     # `mla_latent_gb` stays deepseek-v3's alone: `test_benchmark_deepseek_v3.py` pins its list to that cell, and a
     # `model_config` PR edits no file the benchmark has (CHANGES.md, PR 62); the same gauge is read here as
     # `mla_hybrid_latent_gb`
@@ -389,7 +371,7 @@ def test_manifest_lists_the_cell_and_its_readers():
                 "moe_ep16_gmm_decode_roofline", "moe_ep4_gmm_decode_roofline", "ssm_step_roofline",
                 "ssm_scan_prefill_roofline", "gdn_step_roofline", "gdn_chunk_prefill_roofline", "cca_tail_gb",
                 "mla_absorbed_read_roofline", "mla_prefill_attn_roofline", "moe_skip_share"} & names
-    readers = {s["name"]: s["reader"] for s in harness.load_layer_metrics(CELL)}
+    readers = {s["name"]: s["reader"] for s in harness.load_layer_metrics(CELL, root=root)}
     assert readers["ssm_state_gb"] == {"kind": "counter", "name": "cache/state_gb"}
     assert readers["mla_hybrid_latent_gb"] == {"kind": "counter", "name": "cache/latent_gb"}
     assert readers["mla_hybrid_absorbed_read_roofline"]["count"] == "mla_hybrid_absorbed_read_count"
@@ -399,25 +381,25 @@ def test_manifest_lists_the_cell_and_its_readers():
         "kda_step_roofline": "kda_step_count", "kda_chunk_prefill_roofline": "kda_chunk_prefill_count",
         "moe_ep8_gmm_decode_roofline": "moe_ep8_gmm_decode_count",
         "moe_ep8_gmm_prefill_roofline": "moe_ep8_gmm_prefill_count"}
-    family = harness.load_family(harness.load_json("configs", f"{CONFIG}.json"))
+    family = harness.load_family(manifest_cells.load(root, "configs", CONFIG), root)
     assert all(callable(getattr(family, readers[n]["count"])) for n in OWN)
     # the mix: reason1k-deepseekv3 key for key but for the slots, the seeds and the knee, as ISSUE 62 named it. The
     # drain is that mix's 40 s: a request is 1024 steps of 41.6 ms = 42.6 s here (27.7 s there), so the window's
     # last four seconds of arrivals cannot end (16 of 180 read `failed` in every run, `correct` all the same:
     # the cell's `why` and PERF.md say so; a longer drain is a `benchmark` PR's to bring, PERF.md section 7 (72))
-    traffic = harness.load_json("traffic", f"{TRAFFIC}.json")
-    model = harness.load_json("traffic", "reason1k-deepseekv3.json")
+    traffic = manifest_cells.load(root, "traffic", TRAFFIC)
+    model = manifest_cells.load(root, "traffic", "reason1k-deepseekv3")
     differs = sorted(k for k in set(traffic) | set(model) if traffic.get(k) != model.get(k))
     assert differs == ["arrivals", "name", "order_seed", "slots", "traffic_seed", "weights_seed"]
-    assert traffic["drain_limit_s"] == 40 < 1024 * 0.0416 and "outlast a 40 s drain" in cell[0]["why"]
-    assert traffic["slots"] in (256, 128) and (traffic["slots"] == 256 or "128 slots" in cell[0]["why"])
+    assert traffic["drain_limit_s"] == 40 < 1024 * 0.0416 and "outlast a 40 s drain" in listed["why"]
+    assert traffic["slots"] in (256, 128) and (traffic["slots"] == 256 or "128 slots" in listed["why"])
     assert traffic["weights_seed"] == traffic["order_seed"] == traffic["traffic_seed"] == 20261004
     assert (traffic["seq_length"], traffic["max_new_tokens"], traffic["min_new_tokens"], traffic["admit_width"],
             traffic["harvest_width"]) == (512, 1024, 1024, 8, 8)
     assert traffic["arrivals"]["process"] == "poisson" and traffic["arrivals"]["load"] == 0.8
     assert set(traffic["arrivals"]) == set(model["arrivals"])
     # the rate the cell's `why` states is the mix's
-    assert "%g/s" % round(traffic["arrivals"]["knee_per_s"] * 0.8, 2) in cell[0]["why"]
+    assert "%g/s" % round(traffic["arrivals"]["knee_per_s"] * 0.8, 2) in listed["why"]
     # over an eighth of the vocabulary a request of 1024 tokens would draw EOS with 5%: past the README's 1%
     assert 1 - (1 - 1 / 19648) ** 1024 == pytest.approx(0.0508, abs=0.0005)
 

@@ -3,8 +3,10 @@ data files the harness finds by name; and that a model family, a
 configuration, a cell and a per-layer metric can each be added as new
 files and manifest entries only (``files_only/`` holds the files: the family with its two halves, a window
 block and a state block in its shape rule, and a tolerance file of the
-configuration's own)."""
+configuration's own; ``conftest.py`` lays them over a copy, and every family's
+manifest test runs on that tree too: the last test here holds each to it)."""
 
+import ast
 import json
 import os
 import re
@@ -14,6 +16,9 @@ import numpy as np
 import pytest
 
 from benchmark import arithmetic, checks, harness, readers
+
+import manifest_cells
+from manifest_cells import one_line
 
 REPO = harness.REPO
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -31,10 +36,6 @@ def manifest():
     assert os.path.getsize(path) <= 64 * 1024
     with open(path) as f:
         return json.load(f)
-
-
-def one_line(text, limit=200):
-    return isinstance(text, str) and 1 <= len(text) <= limit and "\n" not in text and "\t" not in text
 
 
 def test_top_level_keys_and_command(manifest):
@@ -162,38 +163,11 @@ def test_a_metric_that_lists_no_cells_is_refused_by_name(tmp_path, manifest):
     root = tmp_path / "benchmark"
     shutil.copytree(os.path.join(harness.HERE, "layer_metrics"), root / "layer_metrics")
     entries = [dict(m) for m in manifest["per_layer"]]
-    del entries[-1]["workloads"]
+    bare = next(m for m in entries if m["name"] == "hbm_peak_gb.serve")  # any one: none of the asked cell's
+    del bare["workloads"]
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(dict(manifest, per_layer=entries)))
-    with pytest.raises(KeyError, match=entries[-1]["name"].replace(".", r"\.")):
+    with pytest.raises(KeyError, match=r"hbm_peak_gb\.serve"):
         harness.load_layer_metrics("ppo-gpt2m-tldr", root=str(root))
-
-
-FILES_ONLY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "files_only")
-
-
-@pytest.fixture()
-def a_later_prs_tree(tmp_path, manifest):
-    """A copy of the benchmark with ``files_only/`` laid over it and its
-    manifest entries added, as a later PR would: ``(root, bytes of every
-    file that was there before)``."""
-    root = tmp_path / "benchmark"
-    for kind in ("configs", "workloads", "traffic", "layer_metrics", "reference"):
-        shutil.copytree(os.path.join(harness.HERE, kind), root / kind,
-                        ignore=shutil.ignore_patterns("__pycache__"))
-    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
-    shutil.copytree(os.path.join(FILES_ONLY, "benchmark"), root, dirs_exist_ok=True,
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    with open(os.path.join(FILES_ONLY, "manifest_entries.json")) as f:
-        entries = json.load(f)
-    new = json.loads(json.dumps(manifest))
-    for group in ("configs", "workloads", "per_layer"):
-        new[group] += entries[group]
-    cell = entries["workloads"][0]["name"]
-    for m in new["end_to_end"] + new["per_layer"]:  # its name joins one list per metric it reads
-        if m["name"] in entries["joins"]:
-            m["workloads"].append(cell)
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(new))
-    return root, before
 
 
 def test_a_family_a_config_a_cell_and_a_metric_are_added_as_files_only(a_later_prs_tree):
@@ -274,6 +248,38 @@ def test_a_family_a_config_a_cell_and_a_metric_are_added_as_files_only(a_later_p
     assert all(p.read_bytes() == b for p, b in before.items())  # nothing edited
 
 
+def test_a_later_prs_serve_cell_comes_after_every_cell_and_a_metric_lists_some_serve_cells_only(a_later_prs_tree):
+    """What every family's manifest test meets on its second tree."""
+    root = str(a_later_prs_tree[0])
+    later, stands = manifest_cells.read_manifest(root), manifest_cells.read_manifest()
+    for group in ("configs", "workloads", "per_layer"):  # appended: everything that was there stands first, as it was
+        assert [e["name"] for e in later[group]][:len(stands[group])] == [e["name"] for e in stands[group]]
+    added = [w for w in later["workloads"] if w not in stands["workloads"]]
+    assert [(w["name"], w["chips"]) for w in added] == [("ppo-toymoe-tldr", 1), ("serve-toymoe-chat", 4)]
+    serve = manifest_cells.cells_by_driver(later, root)["serve"]
+    assert serve == manifest_cells.SERVE_CELLS + ["serve-toymoe-chat"]
+    assert harness.load_cell("serve-toymoe-chat", root=root)["traffic_file"]["driver"] == "serve"
+    # it joins whatever every serve cell listed, the end-to-end metrics with them, and nothing of the PPO cells'
+    everywhere = {m["name"] for m in stands["end_to_end"] + stands["per_layer"]
+                  if manifest_cells.every_serve_cell_and_no_ppo_cell(m.get("workloads", ()))}
+    assert {"serve_itl_p95_ms", "serve_tokens_per_s", "decode_serve_roofline"} <= everywhere
+    reads = {m["name"] for m in later["end_to_end"] + later["per_layer"]
+             if "serve-toymoe-chat" in m.get("workloads", ())}
+    assert reads == everywhere | {"moe_rows_served"}
+    manifest_cells.lists_what_every_other_serve_cell_lists(later, root, "serve-toymoe-chat")
+    # the metric over a strict subset of the serve cells: two of them, one that was there
+    some = next(m for m in later["per_layer"] if m["name"] == "moe_rows_served")["workloads"]
+    assert len(some) == 2 and set(some) < set(serve) and set(some) & set(manifest_cells.SERVE_CELLS)
+    for cell in some:
+        assert "moe_rows_served" in manifest_cells.metric_names(cell, root)
+    # and the repo's own tree has one such since PR 64: no rule asks it of the cells it leaves out
+    witness = next(m for m in stands["per_layer"] if m["name"] == "serve_pool_block_bitcast_share")["workloads"]
+    left_out = set(manifest_cells.SERVE_CELLS) - set(witness)
+    assert len(left_out) >= 2  # each has another that lacks it among its others (one alone would be asked for it)
+    for cell in left_out:
+        manifest_cells.lists_what_every_other_serve_cell_lists(stands, harness.HERE, cell)
+
+
 def test_the_added_familys_reference_runs_through_the_checks(a_later_prs_tree):
     import jax
 
@@ -327,3 +333,27 @@ def test_the_ppo_driver_takes_the_rollout_engine_from_the_traffic_file():
     assert ppo_driver.build_config(cell, 1).train.rollout["engine"] == "fixed"
     cell["traffic_file"]["engine"] = "continuous"
     assert ppo_driver.build_config(cell, 1).train.rollout["engine"] == "continuous"
+
+
+def test_every_familys_manifest_test_takes_both_trees():
+    """A test file of a family (it names its ``CELL``) has a manifest test,
+    and that test takes ``conftest.py``'s ``either_tree``: so it runs on a
+    tree with a later PR's cell, configuration and metrics after its own,
+    and one that pins a position, counts the cells or lists the other cells
+    by hand fails in the PR that writes it. Read off the source: no family
+    file is imported."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    families = {}
+    for name in sorted(os.listdir(here)):
+        if not re.fullmatch(r"test_benchmark_\w+\.py", name):
+            continue
+        with open(os.path.join(here, name)) as f:
+            body = ast.parse(f.read()).body
+        assigned = {t.id for node in body if isinstance(node, ast.Assign)
+                    for t in node.targets if isinstance(t, ast.Name)}
+        if "CELL" in assigned:
+            families[name] = [[a.arg for a in node.args.args] for node in body
+                              if isinstance(node, ast.FunctionDef) and node.name.startswith("test_manifest_")]
+    assert "test_benchmark_olmoe.py" in families  # the walk finds them
+    for name, signatures in families.items():
+        assert any("either_tree" in args for args in signatures), f"{name}: no manifest test takes `either_tree`"

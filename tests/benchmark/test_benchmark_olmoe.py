@@ -1,7 +1,8 @@
 """The OLMoE configuration and its cell: parameters and shape sums pinned
 by hand, the count functions of the grouped multiplication on made-up
-trace operations, and a CPU rehearsal of ``serve-olmoe1b7b-chat`` at a toy
-size through the code the chip runs (form only: CPU numbers)."""
+trace operations, the manifest's entries, and a CPU rehearsal of
+``serve-olmoe1b7b-chat`` at a toy size through the code the chip runs
+(form only: CPU numbers)."""
 
 import json
 
@@ -10,6 +11,11 @@ import pytest
 from benchmark import arithmetic, harness
 from benchmark.run import run_cell
 
+import manifest_cells
+
+CELL = "serve-olmoe1b7b-chat"
+CONFIG = "olmoe-1b-7b"
+TRAFFIC = "chat-olmoe"
 # by hand: a block = 4 x 2048^2 (attention) + 2048 x 64 (router) + 64 x 3 x
 # 2048 x 1024 (experts) + 4 x 2048 (norms) = 419,569,664; the embedding and
 # the head 50304 x 2048 each, the final norm 2048
@@ -19,7 +25,7 @@ ENDS = 2 * 103_022_592 + 2_048
 
 @pytest.fixture(scope="module")
 def config_file():
-    return harness.load_json("configs", "olmoe-1b-7b.json")
+    return harness.load_json("configs", f"{CONFIG}.json")
 
 
 def shape_of(cf):
@@ -124,6 +130,34 @@ def test_decode_count_reads_the_touched_experts_only(config_file):
     assert family.gmm_decode_count(record_of(config_file), ops) == (0.0, 0.0)
 
 
+OWN = {"moe_gmm_decode_roofline": manifest_cells.roofline("expert layer"),
+       "moe_gmm_prefill_roofline": manifest_cells.roofline("expert layer")}
+
+
+def test_manifest_lists_the_cell_and_its_readers(either_tree):
+    manifest, root = either_tree
+    _, config = manifest_cells.cell_is_listed(manifest, root, CELL, CONFIG, TRAFFIC, chips=1)
+    assert config["reduced"] == ["num_hidden_layers"]  # 12 of the published 16 blocks, every width as published
+    manifest_cells.own_metrics_list_the_cell(manifest, CELL, OWN)
+    names = manifest_cells.metric_names(CELL, root)
+    assert set(OWN) | {"decode_serve_roofline", "moe_experts_touched", "moe_max_load", "hbm_peak_gb.serve",
+                       "serve_pool_block_bitcast_share"} <= names
+    # every serve metric the other serve cells all report is read here too
+    manifest_cells.lists_what_every_other_serve_cell_lists(manifest, root, CELL)
+    # every expert is held here, and the block keeps neither a state, a tail nor a latent row: those read nothing
+    assert not {"moe_rows_here_share", "moe_skip_share", "ssm_state_gb", "cca_tail_gb", "mla_pool_pinned_share"} & names
+    readers = {s["name"]: s["reader"] for s in harness.load_layer_metrics(CELL, root=root)}
+    assert all(readers[n]["kind"] == "op_roofline" for n in OWN)
+    assert {n: readers[n]["count"] for n in OWN} == {
+        "moe_gmm_decode_roofline": "gmm_decode_count", "moe_gmm_prefill_roofline": "gmm_prefill_count"}
+    family = harness.load_family(manifest_cells.load(root, "configs", CONFIG), root)
+    assert all(callable(getattr(family, readers[n]["count"])) for n in OWN)
+    # chat.json key for key but for the knee, the sweep's
+    traffic, chat = manifest_cells.load(root, "traffic", TRAFFIC), manifest_cells.load(root, "traffic", "chat")
+    assert set(chat) == set(traffic) and {k for k in chat if chat[k] != traffic[k]} <= {"name", "arrivals"}
+    assert traffic["arrivals"]["load"] == chat["arrivals"]["load"] == 0.8
+
+
 TINY = {"vocab_size": 96, "max_position_embeddings": 64, "hidden_size": 32, "num_hidden_layers": 2,
         "num_attention_heads": 4, "num_key_value_heads": 4, "intermediate_size": 16,
         "num_experts": 8, "num_experts_per_tok": 2}
@@ -132,7 +166,7 @@ TINY = {"vocab_size": 96, "max_position_embeddings": 64, "hidden_size": 32, "num
 def test_cpu_rehearsal_of_the_cell(capsys, monkeypatch):
     monkeypatch.setenv("WANDB_DISABLED", "1")
     monkeypatch.setattr(harness, "place_compile_cache", lambda: "off")
-    cell = harness.load_cell("serve-olmoe1b7b-chat")
+    cell = harness.load_cell(CELL)
     cell["config_file"].update(TINY)
     cell["mesh"] = {"dp": -1, "fsdp": 1, "tp": 1}
     cell["traffic_file"].update(

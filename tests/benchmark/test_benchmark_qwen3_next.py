@@ -15,8 +15,11 @@ import pytest
 from benchmark import arithmetic, checks, harness
 from benchmark.run import run_cell
 
+import manifest_cells
+
 CELL = "serve-qwen3next-chat512"
 CONFIG = "qwen3-next-80b-a3b"
+TRAFFIC = "chat512-qwen3next"
 # by hand, d 2048. A linear mixer: W_qkvz 2048 x (2048 + 2048 + 4096 + 4096), W_ba 2048 x 64, W_o 4096 x 2048;
 # the taps 4 x 8192, dt_bias and A_log 32 each, the gated norm 128
 LINEAR_MATRICES = 25_165_824 + 131_072 + 8_388_608
@@ -286,55 +289,39 @@ OWN = {"gdn_step_roofline": "linear attention", "gdn_chunk_prefill_roofline": "l
        "moe_ep4_gmm_decode_roofline": "expert layer", "moe_ep4_gmm_prefill_roofline": "expert layer"}
 
 
-def test_manifest_lists_the_cell_and_its_readers():
-    with open(harness.REPO + "/BENCHMARK.json") as f:
-        manifest = json.load(f)
-    cell = [w for w in manifest["workloads"] if w["name"] == CELL]
-    assert len(cell) == 1 and cell[0]["chips"] == 1 and len(cell[0]["why"]) <= 200
-    assert (cell[0]["config"], cell[0]["traffic"]) == (CONFIG, "chat512-qwen3next")
-    assert cell[0]["why"] == harness.load_json("workloads", f"{CELL}.json")["why"]
-    assert not [w for w in manifest["workloads"] if w["chips"] != 1]  # nothing it measures exists only across chips
-    config = [c for c in manifest["configs"] if c["name"] == CONFIG]
-    assert len(config) == 1 and len(config[0]["why"]) <= 200 and len(config[0]["source"]) <= 200
-    assert sorted(config[0]["reduced"]) == sorted(harness.load_json("configs", f"{CONFIG}.json")["reduced"])
-    assert config[0]["file"] == f"benchmark/configs/{CONFIG}.json"
-    for m in manifest["end_to_end"]:
-        if m["name"].startswith("serve_"):
-            assert CELL in m["workloads"]
-    for m in manifest["per_layer"]:
-        if m["name"] in OWN:
-            assert m["workloads"] == [CELL] and (m["layer"], m["moves"]) == (OWN[m["name"]], "serve_itl_p95_ms")
-            assert (m["unit"], m["better"], m["source"]) == ("%", "higher", "device_trace")
-    names = {s["name"] for s in harness.load_layer_metrics(CELL)}
+def test_manifest_lists_the_cell_and_its_readers(either_tree):
+    manifest, root = either_tree
+    # one chip: nothing it measures exists only across chips
+    listed, _ = manifest_cells.cell_is_listed(manifest, root, CELL, CONFIG, TRAFFIC, chips=1)
+    manifest_cells.own_metrics_list_the_cell(manifest, CELL, {n: manifest_cells.roofline(l) for n, l in OWN.items()})
+    names = manifest_cells.metric_names(CELL, root)
     assert set(OWN) | {"decode_serve_roofline", "moe_experts_touched", "moe_max_load", "moe_rows_here_share",
-                       "ssm_state_gb", "hbm_peak_gb.serve", "serve_step_ahead_share", "serve_long_gap_share"} <= names
+                       "ssm_state_gb", "hbm_peak_gb.serve", "serve_step_ahead_share", "serve_long_gap_share",
+                       "serve_pool_block_bitcast_share"} <= names
     # every serve metric the other serve cells all report is read here too
-    others = [set(s["name"] for s in harness.load_layer_metrics(c))
-              for c in ("serve-pythia1b4-chat", "serve-olmoe1b7b-chat", "serve-granite4hs-chat",
-                        "serve-zaya1-8b-reason", "serve-deepseekv3-reason1k")]
-    assert set.intersection(*others) <= names
+    manifest_cells.lists_what_every_other_serve_cell_lists(manifest, root, CELL)
     # the other cells' patterns and the tail's and the latent pool's readers read nothing here
     assert not {"moe_gmm_decode_roofline", "moe_share_gmm_decode_roofline", "moe_top1_gmm_decode_roofline",
                 "moe_ep16_gmm_decode_roofline", "ssm_step_roofline", "ssm_scan_prefill_roofline", "cca_tail_gb",
                 "mla_latent_gb", "moe_skip_share"} & names
-    readers = {s["name"]: s["reader"] for s in harness.load_layer_metrics(CELL)}
+    readers = {s["name"]: s["reader"] for s in harness.load_layer_metrics(CELL, root=root)}
     assert readers["ssm_state_gb"] == {"kind": "counter", "name": "cache/state_gb"}
     assert all(readers[n]["kind"] == "op_roofline" for n in OWN)
     assert {n: readers[n]["count"] for n in OWN} == {
         "gdn_step_roofline": "gdn_step_count", "gdn_chunk_prefill_roofline": "gdn_chunk_prefill_count",
         "moe_ep4_gmm_decode_roofline": "moe_ep4_gmm_decode_count",
         "moe_ep4_gmm_prefill_roofline": "moe_ep4_gmm_prefill_count"}
-    family = harness.load_family(harness.load_json("configs", f"{CONFIG}.json"))
+    family = harness.load_family(manifest_cells.load(root, "configs", CONFIG), root)
     assert all(callable(getattr(family, readers[n]["count"])) for n in OWN)
-    traffic = harness.load_json("traffic", "chat512-qwen3next.json")
+    traffic = manifest_cells.load(root, "traffic", TRAFFIC)
     assert (traffic["driver"], traffic["seq_length"], traffic["max_new_tokens"], traffic["min_new_tokens"],
             traffic["slots"], traffic["admit_width"], traffic["harvest_width"], traffic["drain_limit_s"],
             traffic["warmup_requests"], traffic["trace_seconds"]) == ("serve", 512, 512, 512, 128, 8, 8, 30, 12, 8)
-    assert traffic["prompt_lengths"] == harness.load_json("traffic", "reason-zaya1-8b.json")["prompt_lengths"]
+    assert traffic["prompt_lengths"] == manifest_cells.load(root, "traffic", "reason-zaya1-8b")["prompt_lengths"]
     assert traffic["prompt_lengths"] == {"dist": "lognormal", "median": 128, "sigma": 0.8, "lo": 16, "hi": 512}
     assert traffic["weights_seed"] == traffic["order_seed"] == traffic["traffic_seed"] == 20261003
     assert traffic["arrivals"]["process"] == "poisson" and traffic["arrivals"]["load"] in (0.8, 0.7)
-    assert traffic["arrivals"]["load"] == 0.8 or "0.7" in cell[0]["why"]  # 0.7 only with its reason in `why`
+    assert traffic["arrivals"]["load"] == 0.8 or "0.7" in listed["why"]  # 0.7 only with its reason in `why`
     # over the quarter vocabulary a request of 512 tokens would draw EOS with 1.3%: past the README's 1%
     assert 1 - (1 - 1 / 37984) ** 512 == pytest.approx(0.0134, abs=0.0005)
 

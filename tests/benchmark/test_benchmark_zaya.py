@@ -13,7 +13,11 @@ import pytest
 from benchmark import arithmetic, checks, harness
 from benchmark.run import run_cell
 
+import manifest_cells
+
 CELL = "serve-zaya1-8b-reason"
+CONFIG = "zaya1-8b"
+TRAFFIC = "reason-zaya1-8b"
 # by hand, d 2048, 8 query and 2 KV heads of 128, R 256, E 16, F 2048:
 # projections 2048 x 1024 + 2 x 2048 x 256 + 1024 x 2048 = 5,242,880; the mix 10 x 2 x 128 x 128 =
 # 327,680 taps + 2 x 1280 depthwise + 2 x 1280 biases + 2 temperatures = 5,122; the router 2048 x 256 +
@@ -205,33 +209,27 @@ def test_count_functions_of_the_new_readers(config_file):
     assert not re.search(reader["op"], "fusion f32[10,1024,1280]")
 
 
-def test_manifest_lists_the_cell_and_its_readers():
-    with open(harness.REPO + "/BENCHMARK.json") as f:
-        manifest = json.load(f)
-    cell = [w for w in manifest["workloads"] if w["name"] == CELL]
-    assert len(cell) == 1 and cell[0]["chips"] == 1 and len(cell[0]["why"]) <= 200
-    config = [c for c in manifest["configs"] if c["name"] == "zaya1-8b"]
-    assert len(config) == 1 and config[0]["reduced"] == ["num_hidden_layers", "layer_types"]
-    for m in manifest["end_to_end"]:
-        if m["name"].startswith("serve_"):
-            assert CELL in m["workloads"]
-    own = {"moe_top1_gmm_decode_roofline", "moe_top1_gmm_prefill_roofline", "cca_mix_prefill_roofline",
-           "moe_skip_share", "cca_tail_gb"}
-    for m in manifest["per_layer"]:
-        if m["name"] in own:
-            assert m["workloads"] == [CELL]
-            assert m["moves"] == ("serve_tokens_per_s" if m["name"] == "cca_tail_gb" else "serve_itl_p95_ms")
-    names = {s["name"] for s in harness.load_layer_metrics(CELL)}
-    assert own | {"decode_serve_roofline", "moe_experts_touched", "moe_max_load", "hbm_peak_gb.serve",
-                  "serve_step_ahead_share", "serve_long_gap_share"} <= names
-    # every serve metric the other three serve cells all report is read here too
-    others = [set(s["name"] for s in harness.load_layer_metrics(c))
-              for c in ("serve-pythia1b4-chat", "serve-olmoe1b7b-chat", "serve-granite4hs-chat")]
-    assert set.intersection(*others) <= names
+OWN = {"moe_top1_gmm_decode_roofline": manifest_cells.roofline("expert layer"),
+       "moe_top1_gmm_prefill_roofline": manifest_cells.roofline("expert layer"),
+       "cca_mix_prefill_roofline": manifest_cells.roofline("CCA layer"),
+       "moe_skip_share": manifest_cells.gauge("expert layer", "serve_itl_p95_ms", "share", "lower"),
+       "cca_tail_gb": manifest_cells.gauge("device", "serve_tokens_per_s", "GB", "lower")}
+
+
+def test_manifest_lists_the_cell_and_its_readers(either_tree):
+    manifest, root = either_tree
+    _, config = manifest_cells.cell_is_listed(manifest, root, CELL, CONFIG, TRAFFIC, chips=1)
+    assert config["reduced"] == ["num_hidden_layers", "layer_types"]
+    manifest_cells.own_metrics_list_the_cell(manifest, CELL, OWN)
+    names = manifest_cells.metric_names(CELL, root)
+    assert set(OWN) | {"decode_serve_roofline", "moe_experts_touched", "moe_max_load", "hbm_peak_gb.serve",
+                       "serve_step_ahead_share", "serve_long_gap_share", "serve_pool_block_bitcast_share"} <= names
+    # every serve metric the other serve cells all report is read here too
+    manifest_cells.lists_what_every_other_serve_cell_lists(manifest, root, CELL)
     # the other routed cells' patterns (256 | 320 rows) and the state's readers read nothing here
     assert not {"moe_gmm_decode_roofline", "moe_share_gmm_decode_roofline", "ssm_state_gb", "moe_rows_here_share"} & names
-    traffic = harness.load_json("traffic", "reason-zaya1-8b.json")
-    chat = harness.load_json("traffic", "chat.json")
+    traffic = manifest_cells.load(root, "traffic", TRAFFIC)
+    chat = manifest_cells.load(root, "traffic", "chat")
     # chat.json key for key but for the answers' length and the knee, the sweep's (4.0 here as there)
     assert set(chat) == set(traffic)
     assert {"name", "max_new_tokens"} <= {k for k in chat if chat[k] != traffic[k]} <= {"name", "arrivals", "max_new_tokens"}
