@@ -75,6 +75,12 @@ ARCHS = {
         qk_rope_head_dim=8, rotary_dim=8, v_head_dim=16, intermediate_size=96, moe_intermediate_size=32,
         moe_shared_expert_intermediate_size=32, num_experts=4, num_router_experts=8, first_local_expert=0,
         num_experts_per_tok=2, n_group=4, topk_group=2),
+    "nemotron_h": dict(
+        vocab_size=96, hidden_size=64, num_hidden_layers=4, hybrid_override_pattern="ME*E",
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16, mamba_num_heads=8, mamba_head_dim=16,
+        ssm_state_size=16, n_groups=2, chunk_size=8, n_routed_experts=4, num_router_experts=8,
+        first_local_expert=0, num_experts_per_tok=3, moe_latent_size=32, moe_intermediate_size=48,
+        moe_shared_expert_intermediate_size=80),
 }
 
 
@@ -278,7 +284,7 @@ def test_each_excluded_name_is_a_leaf_some_program_uses_at_its_width():
     from trlx_tpu.models.registry import get_model_family
 
     earned = {}
-    for model_type in ("gpt2_moe", "granitemoehybrid", "zaya", "qwen3_next", "ling"):
+    for model_type in ("gpt2_moe", "granitemoehybrid", "zaya", "qwen3_next", "ling", "nemotron_h"):
         earned[model_type] = set()
         for path, (leaf, cast, names, found) in leaf_consumers(model_type, 8).items():
             if found - THROUGH_THE_CAST:

@@ -255,3 +255,24 @@ def _register_builtins() -> None:
         ),
         "bailing_hybrid",
     )
+    from trlx_tpu.models.nemotron_h import (
+        NEMOTRON_H_PARTITION_RULES,
+        NemotronHConfig,
+        NemotronHModel,
+        init_nemotron_h_cache,
+        no_nemotron_h_checkpoint,
+    )
+
+    # not supports_ep: nothing trains its router (no loss is sown), and the
+    # model refuses an ep axis by name
+    register_model_family(
+        ModelFamily(
+            "nemotron_h", NemotronHConfig, NemotronHModel, NEMOTRON_H_PARTITION_RULES,
+            init_nemotron_h_cache, no_nemotron_h_checkpoint,
+            # the convolution's taps (ops/ssm.py::causal_conv multiplies at
+            # f32); A_log, dt_bias, D, the norms and the selection bias are
+            # vectors, which every server keeps as stored, and the router is
+            # kept by utils.ROLLOUT_CAST_EXCLUDE
+            stored_width_leaves=("conv_weight",),
+        )
+    )

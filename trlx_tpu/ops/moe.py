@@ -69,6 +69,17 @@ over ``E`` held experts, whose last index no expert holds, so its copies
 are multiplied with nothing and contribute exactly zero
 (:func:`routing_stats` counts them as ``skip_share``). Off a mesh only.
 
+**A plain expert** (:func:`expert_layer` with ``w_gate`` None and
+``activation`` naming the nonlinearity): ``W_down act(W_up h)``, two matrices
+an expert and no gate, through the same dispatch, grouped multiplication and
+combine (two calls on the sorted rows where the gated form makes three).
+**Rows of the caller's width**: with a finished ``routing`` the layer never
+reads the width of ``h``, so a family whose experts work in a latent of the
+stream (the router reads the stream, the experts its projection) hands the
+latent rows over and gets rows of ``w_down``'s width back; what is that wide
+in the model's own width (a shared expert) is the caller's to add after the
+way back up, not ``shared``'s.
+
 Training forwards sow ``aux_loss`` (load balancing over the top-k
 assignments, ``E * sum_e f_e P_e`` with ``f_e`` the copies routed to ``e``
 per token and ``P_e`` the mean router probability; uniform routing gives
@@ -210,25 +221,32 @@ def grouped_matmul(rows: jax.Array, weights: jax.Array, group_sizes: jax.Array) 
     )
 
 
-def _experts_on_sorted(rows, w_gate, w_up, w_down, group_sizes):
+ACTIVATIONS = {"relu2": lambda x: jnp.square(jax.nn.relu(x))}  # the plain expert's, by the name a config gives it
+
+
+def _experts_on_sorted(rows, w_gate, w_up, w_down, group_sizes, activation=None):
     with jax.named_scope("moe_experts"):
+        if w_gate is None:  # a plain expert: no gate, the caller's activation
+            up = grouped_matmul(rows, w_up, group_sizes)
+            return grouped_matmul(ACTIVATIONS[activation](up), w_down, group_sizes)
         gate = grouped_matmul(rows, w_gate, group_sizes)
         up = grouped_matmul(rows, w_up, group_sizes)
         return grouped_matmul(jax.nn.silu(gate) * up, w_down, group_sizes)
 
 
 def _apply_routed(h, routing: Routing, w_gate, w_up, w_down, dtype,
-                  num_experts: int, first_expert=0):
+                  num_experts: int, first_expert=0, activation=None):
     """The expert layer after routing, over the experts ``w_*`` hold
     (all ``E`` of them, or a rank's ``E / ep`` starting at
     ``first_expert``): [N, D] in ``h``'s token order, float32."""
     N, k = routing.experts.shape
-    local = w_gate.shape[0]
+    local = w_up.shape[0]
     with jax.named_scope("moe_dispatch"):
         order, inverse, sizes = sort_by_expert(routing.experts, num_experts, first_expert)
         rows = _take_copies(h.astype(dtype), order // k, inverse)
     out = _experts_on_sorted(
-        rows, w_gate.astype(dtype), w_up.astype(dtype), w_down.astype(dtype), sizes[:local]
+        rows, None if w_gate is None else w_gate.astype(dtype), w_up.astype(dtype),
+        w_down.astype(dtype), sizes[:local], activation,
     )
     with jax.named_scope("moe_combine"):
         if local < num_experts:
@@ -318,9 +336,14 @@ def expert_layer(h: jax.Array, router_w, w_gate, w_up, w_down, *, k: Optional[in
                  norm_topk: bool = False, dtype=jnp.bfloat16,
                  mesh: Optional[Mesh] = None, batch_axes=("dp", "fsdp"),
                  first_expert: int = 0, shared: Optional[jax.Array] = None,
-                 routing: Optional[Routing] = None):
+                 routing: Optional[Routing] = None, activation: Optional[str] = None):
     """``h`` [B, T, D] -> ``(y [B, T, D] in dtype, routing)``; ``routing``
     is over all ``B * T`` tokens, for the losses and the statistics.
+
+    The experts are gated (``w_gate``, ``w_up``, ``w_down``: SwiGLU) or
+    plain: ``w_gate`` None and ``activation`` one of ``ACTIVATIONS``
+    (``W_down act(W_up h)``; off a mesh only). Which form a traced call
+    site took is counted in ``moe/expert_form{form=gated|plain}``.
 
     The router is ``router_w`` [D, E] with ``k`` and ``norm_topk``
     (:func:`route`), or the caller's own: ``router_w`` None and ``routing``
@@ -336,6 +359,16 @@ def expert_layer(h: jax.Array, router_w, w_gate, w_up, w_down, *, k: Optional[in
     (off a mesh only)."""
     if (router_w is None) == (routing is None):
         raise ValueError("expert_layer takes router_w (with k) or a finished routing, not both or neither")
+    plain = w_gate is None
+    if plain != (activation is not None) or (plain and activation not in ACTIVATIONS):
+        raise ValueError(
+            f"expert_layer takes gated experts (w_gate, no activation) or plain ones (w_gate None and an "
+            f"activation of {sorted(ACTIVATIONS)}); got w_gate {'None' if plain else 'given'}, "
+            f"activation={activation!r}"
+        )
+    from trlx_tpu.telemetry import get_metrics
+
+    get_metrics().counter("moe/expert_form{form=%s}" % ("plain" if plain else "gated")).inc()
     D = h.shape[-1]
     E = routing.probs.shape[-1] if router_w is None else router_w.shape[-1]
 
@@ -344,21 +377,23 @@ def expert_layer(h: jax.Array, router_w, w_gate, w_up, w_down, *, k: Optional[in
         if routing is None:
             with jax.named_scope("moe_router"):
                 routing = route(flat, router_w, k, norm_topk)
-        return _apply_routed(flat, routing, w_gate, w_up, w_down, dtype, E, first_expert), routing
+        return _apply_routed(flat, routing, w_gate, w_up, w_down, dtype, E, first_expert, activation), routing
 
     if mesh is None or dict(mesh.shape).get("ep", 1) == 1:
-        if not 0 <= first_expert <= E - w_gate.shape[0]:
+        if not 0 <= first_expert <= E - w_up.shape[0]:
             raise ValueError(
-                f"experts {first_expert} .. {first_expert + w_gate.shape[0]} "
+                f"experts {first_expert} .. {first_expert + w_up.shape[0]} "
                 f"are not among the router's {E}"
             )
         y, routing = run(h, router_w, w_gate, w_up, w_down, first_expert)
-        y = y.reshape(h.shape)
+        y = y.reshape(h.shape[:-1] + y.shape[-1:])
         if shared is not None:
             y = y + shared.astype(jnp.float32)
         return y.astype(dtype), routing
 
     ep = mesh.shape["ep"]
+    if w_gate is None:
+        raise ValueError("a plain expert (no gate) is not built on an ep mesh")
     if router_w is None:
         raise ValueError("a caller's own routing (a skip among its choices) is not built on an ep mesh")
     if E % ep or w_gate.shape[0] != E or first_expert:

@@ -11,7 +11,10 @@ x state size), over the columns ``t`` of a sequence:
 ``x``, ``B`` and ``C`` come out of a depthwise causal convolution of width
 ``K`` over the projected columns (:func:`causal_conv`), whose last ``K - 1``
 inputs are the **tail** a sequence carries beside its state. ``B`` and ``C``
-are shared by all heads (one group).
+are shared by the heads of a **group**: all heads read one pair (``[.., N]``,
+one group), or ``G`` groups of ``H / G`` consecutive heads read a pair each
+(``[.., G, N]``: head ``h`` reads group ``h // (H / G)``). One group is
+computed as it always was, the same operations in the same order.
 
 Two ways to compute it, both exact:
 
@@ -92,11 +95,14 @@ def causal_conv(x, weight, bias, tail, mask):
 
 def ssd_scan(x, dt, A, B, C, D, mask, state, chunk: int):
     """The recurrence over ``T`` columns in chunks. ``x`` [B, T, H, P] and
-    ``B``, ``C`` [B, T, N] in the compute dtype; ``dt`` [B, T, H] float32
-    (after the softplus); ``A`` (negative), ``D`` [H]; ``mask`` [B, T];
-    ``state`` [B, H, P, N] float32. Returns ``(y [B, T, H, P] float32,
-    final state float32)``."""
+    ``B``, ``C`` [B, T, N] (one group) or [B, T, G, N] in the compute dtype;
+    ``dt`` [B, T, H] float32 (after the softplus); ``A`` (negative), ``D``
+    [H]; ``mask`` [B, T]; ``state`` [B, H, P, N] float32. Returns ``(y
+    [B, T, H, P] float32, final state float32)``."""
     Bsz, T, H, P = x.shape
+    grouped = B.ndim == 4
+    G = B.shape[2] if grouped else 1
+    by_group = lambda a: a.reshape(a.shape[:1] + (G, H // G) + a.shape[2:])  # [B, H, ..] -> [B, G, H/G, ..]
     cd = x.dtype
     L = min(int(chunk), T)
     pad = (-T) % L
@@ -114,25 +120,42 @@ def ssd_scan(x, dt, A, B, C, D, mask, state, chunk: int):
         cs = jnp.cumsum(jnp.swapaxes(dt_c, 1, 2) * A.astype(jnp.float32)[None, :, None], axis=-1)  # [B, H, L]
         dtx = x_c.astype(jnp.float32) * dt_c[..., None]  # [B, L, H, P]
         # inside the chunk: y_t += sum_{s<=t} exp(cs_t - cs_s) (C_t . B_s) dt_s x_s
-        scores = jnp.einsum("btn,bsn->bts", C_c, B_c, preferred_element_type=jnp.float32)
+        if grouped:  # a group's scores, under each of its heads' decays
+            scores = jnp.einsum("btgn,bsgn->bgts", C_c, B_c, preferred_element_type=jnp.float32)
+        else:
+            scores = jnp.einsum("btn,bsn->bts", C_c, B_c, preferred_element_type=jnp.float32)
         span = jnp.where(lower, cs[..., :, None] - cs[..., None, :], 0.0)
         decay = jnp.where(lower, jnp.exp(span), 0.0)  # [B, H, t, s]
+        weights = (
+            (scores[:, :, None] * by_group(decay)).reshape(decay.shape) if grouped
+            else scores[:, None] * decay
+        )
         y = jnp.einsum(
-            "bhts,bshp->bthp", (scores[:, None] * decay).astype(cd), dtx.astype(cd),
+            "bhts,bshp->bthp", weights.astype(cd), dtx.astype(cd),
             preferred_element_type=jnp.float32,
         )
-        # from the chunks before: y_t += exp(cs_t) * S C_t
-        carried = jnp.einsum(
-            "bhpn,btn->bthp", S.astype(cd), C_c, preferred_element_type=jnp.float32
-        )
+        # from the chunks before: y_t += exp(cs_t) * S C_t (a head's state against its group's C)
+        if grouped:
+            carried = jnp.einsum(
+                "bghpn,btgn->btghp", by_group(S.astype(cd)), C_c, preferred_element_type=jnp.float32
+            ).reshape(Bsz, L, H, P)
+        else:
+            carried = jnp.einsum(
+                "bhpn,btn->bthp", S.astype(cd), C_c, preferred_element_type=jnp.float32
+            )
         y = y + carried * jnp.swapaxes(jnp.exp(cs), 1, 2)[..., None]
         # the state the chunk leaves
         to_end = jnp.swapaxes(jnp.exp(cs[..., -1:] - cs), 1, 2)  # [B, L, H]
-        S = S * jnp.exp(cs[..., -1])[..., None, None] + jnp.einsum(
-            "bshp,bsn->bhpn", (dtx * to_end[..., None]).astype(cd), B_c,
-            preferred_element_type=jnp.float32,
-        )
-        return S, y
+        kept = S * jnp.exp(cs[..., -1])[..., None, None]
+        leaves = (dtx * to_end[..., None]).astype(cd)
+        if grouped:
+            added = jnp.einsum(
+                "bsghp,bsgn->bghpn", leaves.reshape(Bsz, L, G, H // G, P), B_c,
+                preferred_element_type=jnp.float32,
+            ).reshape(S.shape)
+        else:
+            added = jnp.einsum("bshp,bsn->bhpn", leaves, B_c, preferred_element_type=jnp.float32)
+        return kept + added, y
 
     state, ys = jax.lax.scan(
         one_chunk, state.astype(jnp.float32), (chunks(x), chunks(dt), chunks(B), chunks(C))
@@ -143,13 +166,21 @@ def ssd_scan(x, dt, A, B, C, D, mask, state, chunk: int):
 
 def ssd_step(x, dt, A, B, C, D, mask, state):
     """One column a row. ``x`` [B, H, P]; ``dt`` [B, H] float32; ``B``,
-    ``C`` [B, N]; ``mask`` [B]; ``state`` [B, H, P, N]. Returns ``(y
-    [B, H, P] float32, new state float32)``; a masked row's state comes
-    back as it was."""
+    ``C`` [B, N] (one group) or [B, G, N]; ``mask`` [B]; ``state``
+    [B, H, P, N]. Returns ``(y [B, H, P] float32, new state float32)``; a
+    masked row's state comes back as it was."""
     f32 = jnp.float32
     dt = dt * mask[:, None]
     x32 = x.astype(f32)
     a = jnp.exp(dt * A.astype(f32)[None, :])
+    if B.ndim == 3:  # a head reads its group's pair: the state viewed [B, G, H/G, P, N]
+        Bsz, H, P = x.shape
+        G = B.shape[1]
+        S = state.astype(f32).reshape(Bsz, G, H // G, P, -1) * a.reshape(Bsz, G, H // G, 1, 1) + (
+            (dt[..., None] * x32).reshape(Bsz, G, H // G, P, 1) * B.astype(f32)[:, :, None, None, :]
+        )
+        y = jnp.sum(S * C.astype(f32)[:, :, None, None, :], axis=-1).reshape(Bsz, H, P)
+        return y + D.astype(f32)[None, :, None] * x32, S.reshape(state.shape)
     S = state.astype(f32) * a[..., None, None] + (
         (dt[..., None] * x32)[..., None] * B.astype(f32)[:, None, None, :]
     )
@@ -157,10 +188,16 @@ def ssd_step(x, dt, A, B, C, D, mask, state):
     return y + D.astype(f32)[None, :, None] * x32, S
 
 
-def gated_rms_norm(y, gate, weight, eps: float):
-    """``rms(y * silu(gate)) * weight`` over the last axis, float32."""
+def gated_rms_norm(y, gate, weight, eps: float, n_groups: int = 1):
+    """``rms(y * silu(gate)) * weight``, float32: the mean square over the
+    last axis, or over each of its ``n_groups`` equal runs of channels by
+    itself (a group's heads are normalised together and no others)."""
     g = y.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
-    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    if n_groups == 1:
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    else:
+        runs = g.reshape(g.shape[:-1] + (n_groups, g.shape[-1] // n_groups))
+        g = (runs * jax.lax.rsqrt(jnp.mean(runs * runs, axis=-1, keepdims=True) + eps)).reshape(g.shape)
     return g * weight.astype(jnp.float32)
 
 
@@ -168,10 +205,12 @@ def mamba2_mix(
     xBC, dt_raw, *, conv_weight, conv_bias, dt_bias, A_log, D,
     n_heads: int, head_dim: int, d_state: int, chunk: int,
     mask=None, fresh=None, cache_layer: Optional[Dict[str, jax.Array]] = None,
+    n_groups: int = 1,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
-    """The mixer between its two projections: ``xBC`` [B, T, H*P + 2N] and
-    ``dt_raw`` [B, T, H] (the projected columns, zero where ``mask`` is)
-    -> ``(y [B, T, H*P] float32, the layer's new cache dict or None)``.
+    """The mixer between its two projections: ``xBC`` [B, T, H*P + 2GN]
+    (``[x | B | C]``, ``B`` and ``C`` a group after another) and ``dt_raw``
+    [B, T, H] (the projected columns, zero where ``mask`` is) -> ``(y
+    [B, T, H*P] float32, the layer's new cache dict or None)``.
 
     ``cache_layer`` is a state layer's dict (``ops/kv_cache.py``): the rows'
     ``ssm_state`` [B, H, P, N] and ``conv_tail`` [B, K - 1, C]; rows that
@@ -179,7 +218,9 @@ def mamba2_mix(
     is :func:`ssd_step`, everything else :func:`ssd_scan` (counted per
     traced call site in ``ssm/path{path=scan|step}``)."""
     Bsz, T, width = xBC.shape
-    H, P, N = n_heads, head_dim, d_state
+    H, P, N, G = n_heads, head_dim, d_state, n_groups
+    if H % G or width != H * P + 2 * G * N:
+        raise ValueError(f"{width} channels are not {H} heads of {P} and {G} groups of B and C of {N}, {H} / {G} heads each")
     K = conv_weight.shape[0]
     f32 = jnp.float32
     if mask is None:
@@ -198,11 +239,14 @@ def mamba2_mix(
         conv, new_tail = causal_conv(xBC, conv_weight, conv_bias, tail, mask)
         conv = (jax.nn.silu(conv) * mask[..., None]).astype(xBC.dtype)
         x = conv[..., : H * P].reshape(Bsz, T, H, P)
-        B_, C_ = conv[..., H * P : H * P + N], conv[..., H * P + N :]
+        B_, C_ = conv[..., H * P : H * P + G * N], conv[..., H * P + G * N :]
+        if G > 1:
+            B_, C_ = B_.reshape(Bsz, T, G, N), C_.reshape(Bsz, T, G, N)
         dt = jax.nn.softplus(dt_raw.astype(f32) + dt_bias.astype(f32))
         A = -jnp.exp(A_log.astype(f32))
     step = cache_layer is not None and T == 1
     get_metrics().counter("ssm/path{path=%s}" % ("step" if step else "scan")).inc()
+    get_metrics().gauge("ssm/groups").set(G)
     if step:
         with jax.named_scope("ssm_step"):
             y, new_state = ssd_step(x[:, 0], dt[:, 0], A, B_[:, 0], C_[:, 0], D, mask[:, 0], state)
