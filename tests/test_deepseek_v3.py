@@ -160,7 +160,11 @@ def test_absorbed_and_published_forms_agree_on_the_same_cache():
     with telemetry.scoped_metrics() as reg:
         absorbed, new_kv = decode_attention(q, row, None, cache, at, bias, scale=0.2, latent=latent)
         counted = {k: v for k, v in reg.snapshot()["counters"].items() if not k.startswith(("jit/", "host/"))}
-        assert counted == {"attention/decode_path{path=paged}": 1.0, "kv_cache/write_path{path=positions}": 1.0}
+        assert counted == {
+            "attention/decode_path{path=paged}": 1.0,
+            "attention/paged_read{read=whole}": 1.0,  # a latent pool keeps the absorbed read
+            "kv_cache/write_path{path=positions}": 1.0,
+        }
     # the same rows in logical order, decompressed and attended head by head
     view, none, _ = paged_write_read(cache, row, None, at, jnp.float32)
     assert none is None and view.shape == (3, 24, 1, 24)

@@ -27,14 +27,22 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_paged_decode_layer_holds_no_copy_of_its_pool(one_chip):
+def test_paged_decode_layer_holds_no_copy_of_its_pool(one_chip, monkeypatch):
     """One layer of the serving cells' decode step (pythia-1.4b and
     OLMoE-1B-7B: 32 slots x 640 positions x 16 heads of 128, bf16, blocks of
     16): write one row a slot, attend over the pool. Read through the
     logical view this compiled to a bf16 gather and a float32 convert of the
     whole pool, for K and for V: 168 MB of temporaries a layer and 1.4 ms a
-    layer on the chip (PERF.md §6, PR 28). Read as stored it needs none."""
-    from trlx_tpu.ops.attention import decode_attention
+    layer on the chip (PERF.md §6, PR 28). Read as stored it needs none.
+    This is the whole read, which a program on one device leaves to the
+    read by live chunks since PR 67 (the next test) and a program on several
+    devices keeps: taken here as such a program takes it."""
+    from trlx_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_reads_live_chunks", lambda *call: False)
+
+    def decode_attention(*call):  # a function of this test's: jax keeps traces by function
+        return attention.decode_attention(*call)
 
     B, C, H, Dh, n_blocks = 32, 640, 16, 128, 40
 
@@ -55,6 +63,63 @@ def test_paged_decode_layer_holds_no_copy_of_its_pool(one_chip):
     # (fused converts live inside a fusion and return scores or outputs)
     entry = compiled.as_text().split("\nENTRY ", 1)[1]
     assert not re.search(r"= f32\[(%d,%d|%d),%d,%d\]" % (B, C, B * C, H, Dh), entry)
+
+
+@pytest.mark.parametrize(
+    "B,C,H,H_kv", [(32, 640, 16, 16), (32, 1024, 8, 2)], ids=["pythia-olmoe", "zaya"]
+)
+def test_paged_decode_layer_reads_live_chunks_in_one_kernel_over_the_pool_as_stored(
+    one_chip, monkeypatch, B, C, H, H_kv
+):
+    """The same layer where ``decode_attention`` takes the read by live
+    chunks (``ops/paged_live_read.py``; PERF.md §6, PR 67), at pythia's and
+    OLMoE's pool, and the kernel at zaya's (8 query over 2 KV heads: a
+    position of 512 bytes, which the dispatch leaves to the whole read,
+    ``kv_cache.py::reads_live_chunks``; sent there here so that grouped
+    heads stay compiled for the chip): one Mosaic call, whose K and V
+    operands are the pools themselves, written in place by the scatter
+    before it and bitcast to rows ``[C * H_kv, Dh]``. The entry computation
+    holds no operation that returns anything pool-sized but the two fused
+    scatters (no ``copy``, ``copy-start``, ``slice``, ``convert``,
+    ``transpose``: XLA's whole read moved a pool a layer through ``S(1)``,
+    2.4 of pythia's 12.35 ms step), and the temporaries are the chunk lists
+    and a bias a pool row, not a pool."""
+    from trlx_tpu.ops import attention, kv_cache
+
+    # the kernel is interpreted off the TPU, and this process's backend is the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kv_cache, "LIVE_POSITION_BYTES", 0)
+    Dh, n_blocks = 128, C // 16
+
+    def decode_attention(*call):  # a function of this test's: jax keeps traces by function
+        return attention.decode_attention(*call)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((B, C, H_kv, Dh), jnp.bfloat16)
+    cache = {"k": pool, "v": pool, "block_tables": sds((B, n_blocks), jnp.int32)}
+    compiled = (
+        jax.jit(decode_attention, donate_argnums=(3,))
+        .lower(
+            sds((B, 1, H, Dh), jnp.bfloat16),
+            *[sds((B, 1, H_kv, Dh), jnp.bfloat16)] * 2,
+            cache, sds((B,), jnp.int32), sds((B, 1, 1, C), jnp.float32),
+        )
+        .compile()
+    )
+    pool_bytes = B * C * H_kv * Dh * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+    entry = compiled.as_text().split("\nENTRY ", 1)[1]
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", entry)) == 1
+    pool_sized = re.findall(
+        r"= \w+\[(?:%d,%d,%d|%d,%d|%d,%d),%d\]\S* ([\w-]+)\("
+        % (B, C, H_kv, B * C, H_kv, B, C * H_kv, Dh),
+        entry,
+    )
+    # the two pools, each written in place by one fused scatter and handed
+    # to the kernel as rows: no copy, copy-start, slice, convert, transpose
+    assert sorted(pool_sized) == ["bitcast", "bitcast", "fusion", "fusion", "parameter", "parameter"]
 
 
 def _updates_attention_grad(one_chip, monkeypatch, B, T, H, Dh):

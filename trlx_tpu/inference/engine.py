@@ -104,6 +104,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from trlx_tpu import telemetry
+from trlx_tpu.ops.attention import NEG_INF, padding_bias
 from trlx_tpu.ops.kv_cache import (
     PAGED,
     SHARED_POOL_KEYS,
@@ -118,7 +119,11 @@ from trlx_tpu.ops.kv_cache import (
     hold_pool,
     identity_block_tables,
     init_shared_pool,
+    live_chunk_positions,
+    live_chunks,
+    reads_live_chunks,
     starting_at_block,
+    stored_order_bias,
     writes_whole_blocks,
 )
 from trlx_tpu.ops.sampling import (
@@ -584,6 +589,7 @@ class ContinuousBatchingEngine:
         self._pads_a_pool = jax.eval_shape(self._held_cache) != jax.eval_shape(
             lambda: self._init_cache_fn(self.num_slots, self.capacity)
         )
+        self._live_read_layer = self._first_live_read_layer()
         self._build_programs()
 
         # host bookkeeping (reset per phase)
@@ -778,6 +784,25 @@ class ContinuousBatchingEngine:
         return tuple(
             hold_pool(layer) for layer in self._init_cache_fn(self.num_slots, self.capacity)
         )
+
+    def _first_live_read_layer(self) -> Optional[int]:
+        """The first layer whose pool the decode step reads by its live
+        chunks (``ops/kv_cache.py::reads_live_chunks``, the rule
+        ``decode_attention`` dispatches its ``paged`` read by, asked of the
+        pool as held and the head as the model makes it), ``None`` where no
+        layer does, where a shared-prefix overlay keeps every read on the
+        logical view, and on a mesh of several devices, whose programs keep
+        the whole read (XLA partitions no Mosaic kernel)."""
+        if self.prefix_pool_blocks > 0 or (self.mesh is not None and self.mesh.size > 1):
+            return None
+        made = jax.eval_shape(lambda: self._init_cache_fn(self.num_slots, self.capacity))
+        tables = jax.ShapeDtypeStruct((self.num_slots, self.n_blocks), jnp.int32)
+        for i, (layer, held) in enumerate(zip(made, jax.eval_shape(self._held_cache))):
+            if "k" in layer and reads_live_chunks(
+                dict(held, block_tables=tables), layer["k"].shape[-1]
+            ):
+                return i
+        return None
 
     def _measure_block_bitcast(self, cache) -> None:
         """Gauge ``cache/block_write_bitcast_share``: of the layers of
@@ -1059,7 +1084,13 @@ class ContinuousBatchingEngine:
         def decode_step(params, state: EngineState):
             """One token for every slot. Finished/idle slots ride along
             with deterministic pad emissions whose output and cache
-            writes resolve out of bounds and drop."""
+            writes resolve out of bounds and drop: such a slot's
+            ``cache_index`` is the capacity, the sentinel by which
+            ``decode_attention`` knows a row nobody reads, so a layer that
+            reads its pool by live chunks fetches none of that slot's and
+            hands back zeros for it. Where some layer does, the step also
+            polls the share of the pools' chunks it read
+            (``attention/paged_chunks_read_share``)."""
             if cfg.min_new_tokens > 0 or cfg.min_length > 0:
                 min_new = jnp.maximum(
                     cfg.min_new_tokens, cfg.min_length - state.n_real
@@ -1099,14 +1130,37 @@ class ContinuousBatchingEngine:
                 state.query_mask, jnp.ones((B, R), state.query_mask.dtype)
             )
             cache_index = jnp.where(live == 1, Q + state.t, cap)
+            cache, chunks_read = state.cache, {}
+            if self._live_read_layer is not None:
+                # some layer reads its pool by live chunks: every paged
+                # layer's table is the same array by value (an admission
+                # sets the group's rows in all of them), so the step reads
+                # one, and what each layer derives from tables and mask
+                # (the bias in stored order, the chunk lists) is derived
+                # once; the state keeps its own array a layer
+                layer = state.cache[self._live_read_layer]
+                tables = layer["block_tables"]
+                cache = tuple(
+                    dict(l, block_tables=tables) if "block_tables" in l else l
+                    for l in state.cache
+                )
+                chunks_read["paged_chunks_read_share"] = live_chunks(
+                    stored_order_bias(tables, padding_bias(cache_mask_t)),
+                    cache_index, live_chunk_positions(layer), NEG_INF / 2,
+                ).share
             out = apply_fn(
                 params,
                 token[:, None],
                 attention_mask=cache_mask_t,
                 position_ids=(state.n_real + state.t)[:, None],
-                cache=state.cache,
+                cache=cache,
                 cache_index=cache_index,
             )
+            if self._live_read_layer is not None:
+                out["cache"] = tuple(
+                    dict(new, block_tables=old["block_tables"]) if "block_tables" in old else new
+                    for new, old in zip(out["cache"], state.cache)
+                )
             new_logits = out["logits"][:, 0].astype(jnp.float32)
             new_value = (
                 out["values"][:, 0].astype(jnp.float32)
@@ -1130,7 +1184,7 @@ class ContinuousBatchingEngine:
             # what the host polls: the done flags and, from a routed
             # family, the step's routing statistics (device scalars in the
             # same fetch: no wait of their own)
-            polled = {"done": done, **out.get("moe_stats", {})}
+            polled = {"done": done, **out.get("moe_stats", {}), **chunks_read}
             if self.stream_taps:
                 # streaming decode: this step's emissions come home with
                 # the done flags so the host can route tokens the step
@@ -1483,6 +1537,9 @@ class ContinuousBatchingEngine:
             prefill = traced_on(self.mesh, prefill)
             prefill_chunk = traced_on(self.mesh, prefill_chunk)
             verify_step = traced_on(self.mesh, verify_step)
+            # and the step, whose read of a paged pool by live chunks is a
+            # Mosaic kernel on one device and the whole read on more
+            decode_step = traced_on(self.mesh, decode_step)
             state_sh = self.state_sharding()
             batch_sh = batch_sharding(self.mesh)
             rep = replicated(self.mesh)
@@ -2622,6 +2679,16 @@ class ContinuousBatchingEngine:
             done, moe_stats or {}, what="done", newest=newest
         )
         self.stats.done_polls += 1
+        registry = telemetry.get_metrics()
+        share = moe_host.pop("paged_chunks_read_share", None)
+        if share is not None:
+            # the mean over the steps polled since the registry was cleared
+            steps = registry.counter("attention/paged_chunks_read_steps")
+            total = registry.counter("attention/paged_chunks_read_sum")
+            steps.inc()
+            total.inc(float(share))
+            if steps.value:  # 0 while the registry is disabled
+                registry.gauge("attention/paged_chunks_read_share").set(total.value / steps.value)
         if moe_host:
             from trlx_tpu.ops.moe import record_step_stats
 
@@ -2629,7 +2696,6 @@ class ContinuousBatchingEngine:
         # occupancy timeseries: one gauge sample per paid done-poll
         # (the registry's ring is bounded; one host call per poll)
         # — the Perfetto counter track rides these samples
-        registry = telemetry.get_metrics()
         registry.gauge("engine/slot_util").set(self.stats.slot_util)
         # again: the registry may have been cleared
         registry.gauge("engine/param_gb").set(self.stats.param_gb)

@@ -41,9 +41,12 @@ from trlx_tpu.ops.kv_cache import (
     cache_kind,
     decode_read_widths,
     dense_write_read,
+    live_chunk_positions,
+    live_chunks,
     paged_write_read,
     quantize_kv,
     reads_as_stored,
+    reads_live_chunks,
     stored_order_bias,
 )
 from trlx_tpu.telemetry import get_metrics
@@ -608,6 +611,47 @@ def _latent_absorbed_read(q, rows, bias, scale, latent: Latent):
         ).astype(q.dtype)[:, None]
 
 
+def _reads_live_chunks(q, k_new, cache_kv, bias, scale) -> bool:
+    """Whether a ``paged`` call of :func:`decode_attention` reads its pools'
+    live chunks (:func:`_live_chunks_read`) and not the pools whole, from
+    what the call shows: the pool is one the read is built for
+    (``kv_cache.py::reads_live_chunks``: keys and values, bfloat16, the
+    stored row the call's whole head of whole lane rows), the query is in
+    the pool's dtype, the bias is broadcast over heads (its live positions
+    are then every head's), the scale is a number of the program's and not
+    a traced one, and the program runs on one device (XLA partitions no
+    Mosaic kernel; a program on more declares its mesh,
+    ``parallel/mesh.py::traced_on``, and keeps the whole read)."""
+    from trlx_tpu.parallel.mesh import program_mesh
+
+    mesh = program_mesh()
+    return (
+        reads_live_chunks(cache_kv, k_new.shape[-1])
+        and q.dtype == cache_kv["k"].dtype
+        and bias.shape[1] == 1
+        and (scale is None or isinstance(scale, numbers.Real))
+        and (mesh is None or mesh.size == 1)
+    )
+
+
+def _live_chunks_read(q, k, v, bias, cache_kv, cache_index, scale):
+    """The ``paged`` read over the chunks of the pools, as stored, in which
+    a position can carry a weight (``ops/paged_live_read.py``): ``bias`` is
+    in stored order, and a slot whose ``cache_index`` is at the capacity has
+    no such chunk. The same products, float32 softmax and compute-dtype
+    weights as :func:`dot_product_attention` over the whole pool, whose
+    other positions weigh exactly 0; float32 sums in another order."""
+    from trlx_tpu.ops.paged_live_read import paged_live_read
+
+    live = live_chunks(bias, cache_index, live_chunk_positions(cache_kv), NEG_INF / 2)
+    return paged_live_read(
+        q, k, v, bias, live,
+        scale=q.shape[-1] ** -0.5 if scale is None else float(scale),
+        floor=NEG_INF,
+        interpret=jax.default_backend() != "tpu",
+    )
+
+
 def decode_attention(
     q: jax.Array,  # [B, Q, H, D]
     k_new: jax.Array,  # [B, Q, H, D]
@@ -643,12 +687,26 @@ def decode_attention(
       not rerouted;
     - ``paged`` — one position a slot into a floating paged pool (the
       continuous engine's decode step; ``kv_cache.py::reads_as_stored``):
-      the rows are scattered in place and :func:`dot_product_attention`
-      reads the pools as stored, in each slot's physical order, under the
-      bias re-indexed to that order. No logical view is gathered; a pool
-      its holder keeps with a head as several lane rows
-      (``kv_cache.py::hold_pool``, heads of 256) is read in those rows
-      (:func:`_lane_rows_read`), the pool never reshaped;
+      the rows are scattered in place and the pools are read as stored, in
+      each slot's physical order, under the bias re-indexed to that order.
+      No logical view is gathered. Which read, again on what the call shows
+      (counted in ``attention/paged_read{read=live_chunks|whole}``): a pool
+      of keys and values in bfloat16 whose stored row is the call's whole
+      head of whole lane rows, under a bias broadcast over heads, in a
+      program on one device, is read **by its live chunks**
+      (:func:`_reads_live_chunks`, :func:`_live_chunks_read`: one Pallas
+      kernel over the pools where they lie, fetching only the chunks in
+      which some position's bias is above ``NEG_INF / 2``; the same sums in
+      another order). Every other ``paged`` call keeps the whole read:
+      :func:`dot_product_attention` over the pools, a pool its holder
+      keeps with a head as several lane rows (``kv_cache.py::hold_pool``,
+      heads of 256) in those rows (:func:`_lane_rows_read`), the pool never
+      reshaped. **A slot whose ``cache_index`` is at or past the capacity**
+      (the engine's row that is not live: its write is dropped here and its
+      output by ``decode_step``) has no live chunk whatever its bias says:
+      on this path that slot's output is unspecified (the read by live
+      chunks returns zeros, the whole read attends under the slot's stale
+      mask) and no caller may use it;
     - ``paged_rows`` — a group's rows inside the whole paged pool (the
       engine's admission programs; ``cache_kind(...).rows``):
       ``paged_write_read`` scatters the call's columns at (slot, physical
@@ -710,6 +768,12 @@ def decode_attention(
             cache_kv, k_new, v_new, cache_index, q.dtype, as_stored=True
         )
         bias = stored_order_bias(cache_kv["block_tables"], bias)
+        by_chunk = latent is None and _reads_live_chunks(q, k_new, cache_kv, bias, scale)
+        get_metrics().counter(
+            "attention/paged_read{read=%s}" % ("live_chunks" if by_chunk else "whole")
+        ).inc()
+        if by_chunk:
+            return _live_chunks_read(q, k, v, bias, cache_kv, cache_index, scale), new_kv
         if latent is not None:
             return _latent_absorbed_read(q, k, bias, scale, latent), new_kv
         if k.shape[-1] != k_new.shape[-1]:

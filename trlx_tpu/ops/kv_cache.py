@@ -1090,6 +1090,144 @@ def stored_order_bias(
     return stored.reshape(lead + (capacity,))
 
 
+# The least a position of a pool holds, K or V, for the read by live chunks
+# to be taken (:func:`reads_live_chunks` has what was measured either side)
+LIVE_POSITION_BYTES = 4096
+
+
+def reads_live_chunks(layer_kv: Dict[str, jax.Array], head_size: int) -> bool:
+    """Whether the decode step's read of this layer's pool as stored
+    (:func:`reads_as_stored`) takes the chunks a live position lies in
+    (``ops/paged_live_read.py``) and not the whole pool: a paged pool of
+    keys **and** values (a latent pool keeps the absorbed read), floating
+    bfloat16 (int8 and an overlay read the logical view anyway), whose
+    stored row is the call's whole head and whole lane rows (``head_size %
+    128 == 0`` and the pool's row as wide: a head narrower than a lane row
+    fills half of every tile and is copied as the whole of it; a head held
+    as several lane rows, :func:`hold_pool`, keeps
+    ``ops/attention.py::_lane_rows_read``), and **whose position is at
+    least** ``LIVE_POSITION_BYTES``. ``head_size``: the width of a
+    head as the layer's model makes it, which a held pool no longer shows.
+    Decided on what the layer is; read by ``decode_attention`` for its
+    dispatch and by the engine, which counts the chunks read only where
+    some layer takes them.
+
+    Where the line on a position's bytes fell, and why (one TPU v5e, a
+    layer's write and read in a chain of 12, microseconds a layer; the
+    whole XLA read against the kernel at the share of chunks live that the
+    cell's traffic gives, with every slot live, and with every chunk live;
+    PERF.md section 6, PR 67): the whole read runs at its bytes whatever
+    is live, the kernel pays a copy and two small products a chunk, so it
+    gains what it skips and loses what a thin position makes of a chunk:
+
+    - 4 KiB a position (pythia, OLMoE: ``[32, 640, 16, 128]``): 309
+      whole; 56 at 0.12 of the chunks, 232 at 0.61, 370 at 1.0. Taken: it
+      wins up to four fifths of the chunks live, and a serving pool that
+      full has no slot to admit into;
+    - 2 KiB (granite: ``[32, 640, 8, 128]``): 138 whole; 48 at 0.15, 162 at
+      0.61, 261 at 1.0. Not taken: it loses from under half the chunks, a
+      step of 22 ms holds 70% of its slots, and its one layer is 0.6% of it;
+    - 512 B (zaya ``[32, 1024, 2, 128]``, nemotron ``[64, 1024, 2, 128]``):
+      74 and 138 whole; 101 at 0.36 and 143 at 0.25, 253 and 516 at 1.0.
+      Not taken at any share its traffic shows: a chunk of 128 positions is
+      64 KB and half a microsecond of loop whatever it holds."""
+    if "k" not in layer_kv:  # a state layer
+        return False
+    kind = cache_kind(layer_kv)
+    pool = layer_kv["k"]
+    return (
+        kind.layout == PAGED
+        and not (kind.latent or kind.quantized or kind.shared or kind.rows)
+        and pool.dtype == jnp.bfloat16
+        and head_size % LANES == 0
+        and pool.shape[-1] == head_size
+        and pool.shape[-2] * head_size * pool.dtype.itemsize >= LIVE_POSITION_BYTES
+        and live_chunk_positions(layer_kv) > 0
+    )
+
+
+# The most a chunk of one pool holds, K or V: two such buffers and a slot's
+# float32 scores are what the kernel keeps in VMEM (pythia's 128 positions
+# x 16 heads x 128: 0.5 MB a buffer, 0.66 MB of scores)
+LIVE_CHUNK_BYTES = 512 * 1024
+# and the most positions, however thin a position is: what is skipped is
+# skipped a chunk at a time, so a chunk of a whole slot skips nothing
+LIVE_CHUNK_POSITIONS = 128
+
+
+def live_chunk_positions(layer_kv: Dict[str, jax.Array]) -> int:
+    """The positions a chunk of this layer's paged pool holds for the read
+    of its live chunks: whole blocks (a table moves whole blocks, so that is
+    the grain at which a slot's live positions lie together), a divisor of
+    the capacity, as many as ``LIVE_CHUNK_POSITIONS`` and
+    ``LIVE_CHUNK_BYTES`` of one pool allow, and a chunk's pool rows
+    (positions x KV heads) whole lane rows, which is how wide the scores of
+    a chunk are. Computed from the shape; 0 where no width is all of it."""
+    _, capacity, heads, width = layer_kv["k"].shape
+    n_blocks = layer_kv["block_tables"].shape[-1]
+    block = capacity // n_blocks
+    position_bytes = heads * width * layer_kv["k"].dtype.itemsize
+    for blocks in range(n_blocks, 0, -1):
+        chunk = blocks * block
+        if (
+            n_blocks % blocks == 0
+            and chunk <= LIVE_CHUNK_POSITIONS
+            and chunk * position_bytes <= LIVE_CHUNK_BYTES
+            and (chunk * heads) % LANES == 0
+        ):
+            return chunk
+    return 0
+
+
+class LiveChunks(NamedTuple):
+    """:func:`live_chunks`: which chunks of a pool a decode step reads."""
+
+    slots: jax.Array  # [B] int32: the slots with a live chunk, in order, then zeros
+    n_slots: jax.Array  # [1] int32: how many
+    counts: jax.Array  # [B] int32: a slot's live chunks
+    chunks: jax.Array  # [B, n_chunks] int32: which, in order, then zeros
+    share: jax.Array  # float32: live chunks / all chunks
+
+
+def live_chunks(bias: jax.Array, cache_index, chunk: int, floor: float) -> LiveChunks:
+    """Each slot's chunks of ``chunk`` physical positions in which some
+    position can carry a weight, from the two things a decode call shows:
+    ``bias`` ``[B, 1, 1, C]`` in **stored** order (:func:`stored_order_bias`;
+    a position at or under ``floor``, the callers' ``NEG_INF / 2``, weighs
+    exactly 0 in a float32 softmax beside any live one) and ``cache_index``
+    (``[B]`` or a scalar: a slot at or past the capacity is the engine's
+    row that is not live, whose write is dropped and whose output nobody
+    reads; it has no live chunk whatever mask its last request left).
+    Lists are in stored order, compacted by a prefix sum (no sort), int32
+    for a kernel's scalar prefetch. The same for every layer of a step whose
+    layers share a mask and tables."""
+    B, capacity = bias.shape[0], bias.shape[-1]
+    n = capacity // chunk
+    above = (bias[:, 0, 0] > floor).reshape(B, n, chunk)
+    live = jnp.any(above, axis=-1) & (
+        jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32), (B,)) < capacity
+    )[:, None]
+
+    def compact(flags):
+        """The indices at which ``flags`` ``[..., n]`` holds, in order,
+        then zeros, and how many."""
+        width = flags.shape[-1]
+        at = jnp.cumsum(flags, axis=-1, dtype=jnp.int32) - 1
+        ids = jnp.arange(width, dtype=jnp.int32)
+        lands = flags[..., :, None] & (at[..., :, None] == ids)  # [..., from, to]
+        return (
+            jnp.sum(jnp.where(lands, ids[:, None], 0), axis=-2, dtype=jnp.int32),
+            jnp.sum(flags, axis=-1, dtype=jnp.int32),
+        )
+
+    chunks, counts = compact(live)
+    slots, n_slots = compact(counts > 0)
+    return LiveChunks(
+        slots, n_slots[None], counts, chunks,
+        jnp.sum(counts).astype(jnp.float32) / (B * n),
+    )
+
+
 def starting_at_block(cache: Cache, first_block) -> Cache:
     """A group's cache (:func:`cache_kind` ``.rows``) with the caller's
     promise, under ``"first_block"`` beside ``"slot_ids"``, that the call's
