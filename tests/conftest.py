@@ -48,6 +48,27 @@ jax.config.update("jax_enable_compilation_cache", False)
 import pytest  # noqa: E402
 
 
+# ---------------------------------------------------------------------- #
+# The model families' shared tests (tests/family_harness.py): a family's
+# test file names its ``FAMILY`` record and imports the contract tests it is
+# held to; their cases are that record's.
+# ---------------------------------------------------------------------- #
+
+pytest.register_assert_rewrite("family_harness")
+
+
+@pytest.fixture
+def family(request):
+    return request.module.FAMILY
+
+
+def pytest_generate_tests(metafunc):
+    for argument, field in (("refusals", "refusals"), ("engine_case", "engine_cases")):
+        if argument in metafunc.fixturenames:
+            cases = getattr(metafunc.module.FAMILY, field)
+            metafunc.parametrize(argument, list(cases.values()), ids=list(cases))
+
+
 @pytest.fixture(autouse=True)
 def _reset_global_parallel_context():
     yield

@@ -175,7 +175,7 @@ def test_fp64_rule_fires_on_x64_program():
 
     from trlx_tpu.analysis.jaxpr_audit import check_no_fp64
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda x: jnp.sum(x * jnp.float64(2.0))
         )(jnp.ones((4,), jnp.float64))
@@ -282,6 +282,34 @@ def test_precision_leak_ignores_scalar_and_rank2_casts():
     assert check_precision_leak(
         jaxpr, "fixture", repo_root=REPO.rsplit("/", 1)[0]
     ) == []
+
+
+def test_an_audit_that_cannot_read_an_equations_frames_raises(monkeypatch):
+    """jax 0.9.0 changed what ``source_info_util.user_frames`` takes; the
+    call sat under ``except Exception: return None`` and every located
+    finding of four engines was dropped in silence. The seam catches
+    nothing now: when jax's reader moves again, the audit raises."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src import source_info_util
+
+    from trlx_tpu.analysis.jaxpr_audit import check_precision_leak
+    from trlx_tpu.analysis.nan_flow import analyze_program
+
+    upcast = jax.make_jaxpr(lambda x: x.astype(jnp.float32) * 2)(jnp.ones((2, 4, 8), jnp.bfloat16))
+    log = jax.make_jaxpr(lambda x: jnp.log(x))(jnp.ones((4, 8)))
+    root = REPO.rsplit("/", 1)[0]
+    assert check_precision_leak(upcast, "fixture", repo_root=root)  # both see, unpatched
+    assert analyze_program(log, "fixture", repo_root=root)
+
+    def moved(*args, **kwargs):
+        raise TypeError("user_frames() takes a Traceback")
+
+    monkeypatch.setattr(source_info_util, "user_frames", moved)
+    with pytest.raises(TypeError, match="user_frames"):
+        check_precision_leak(upcast, "fixture", repo_root=root)
+    with pytest.raises(TypeError, match="user_frames"):
+        analyze_program(log, "fixture", repo_root=root)
 
 
 # ------------------------ partition-rule validation ---------------------- #

@@ -14,6 +14,7 @@ import pytest
 from trlx_tpu.analysis import hlo_audit as hlo
 from trlx_tpu.analysis.findings import Finding, filter_suppressed
 
+REPO = __file__.rsplit("/tests/", 1)[0]
 MESH222 = {"dp": 2, "fsdp": 2, "tp": 2}
 
 # Canned optimized-HLO lines in the exact shapes jaxlib 0.4.x prints —
@@ -342,19 +343,19 @@ def test_blessed_helper_names_are_exempt():
 # ----------------------- the tier-1 planted canary ------------------------ #
 
 def test_planted_concat_canary_compiles_and_trips_both_rules():
-    """The PR-2 shape, end to end on one tiny program: compile the
-    seeded eager concat and require BOTH the jaxpr-side hazard rule and
-    the compiled-side drift rule (on the minted replica-axis sum)."""
+    """The PR-2 shape, end to end on one tiny program: the jaxpr-side
+    hazard rule names the seeded eager concat whatever the compiler does
+    with it, and the compiled side says what this jaxlib (0.9.0) does: two
+    all-gathers and a slice, no replica-axis sum, so the drift rule is
+    quiet. A jaxlib that mints the sum again turns this red, and then
+    ``spmd_stack`` / ``concat_cols`` / ``stack_cols`` are live again
+    (tools/pp_miscompile_repro.py; ROADMAP.md Queue 3 item 8)."""
     program = hlo.plant_hazard_program()
     cp = hlo.compile_program(program)
-    minted = hlo.concat_minted_collectives(cp.collectives)
-    assert minted, "jaxlib no longer mints the PR-2 replica-sum — " \
-        "run tools/pp_miscompile_repro.py and retire the quarantine"
-    assert minted[0].axes(cp.mesh_shape) == ("dp", "fsdp", "tp")
-    drift = hlo.check_lowering_drift(cp, None)
-    hazard = hlo.check_concat_hazard(program)
-    assert [f.rule for f in drift] == ["lowering-collective-drift"]
-    assert [f.rule for f in hazard] == ["spmd-concat-hazard"]
+    assert [f.rule for f in hlo.check_concat_hazard(program)] == ["spmd-concat-hazard"]
+    assert {c.kind for c in cp.collectives} == {"all-gather"}
+    assert hlo.concat_minted_collectives(cp.collectives) == []
+    assert hlo.check_lowering_drift(cp, None) == []
 
 
 # -------------------------- suppression round-trip ------------------------ #
@@ -573,7 +574,7 @@ def test_cli_hlo_audit_strict_json_clean():
     proc = subprocess.run(
         [sys.executable, "-m", "trlx_tpu.analysis", "--hlo-audit",
          "--strict", "--json"],
-        capture_output=True, text=True, timeout=1500,
+        capture_output=True, text=True, timeout=1500, cwd=REPO,
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
     payload = json.loads(proc.stdout)
@@ -588,10 +589,11 @@ def test_cli_hlo_audit_strict_json_clean():
 def test_cli_plant_hazard_exits_one_naming_both_rules():
     proc = subprocess.run(
         [sys.executable, "-m", "trlx_tpu.analysis", "--plant-hazard"],
-        capture_output=True, text=True, timeout=600,
+        capture_output=True, text=True, timeout=600, cwd=REPO,
     )
     assert proc.returncode == 1, proc.stdout + proc.stderr
     assert "spmd-concat-hazard" in proc.stdout
-    assert "lowering-collective-drift" in proc.stdout
     assert "hlo_audit.py" in proc.stdout  # planted concat localized
-    assert "replica-axis all-reduce" in proc.stdout
+    # jaxlib 0.9.0 lowers the plant to all-gathers: no replica-axis sum to
+    # drift (test_planted_concat_canary_compiles_and_trips_both_rules)
+    assert "lowering-collective-drift" not in proc.stdout

@@ -12,10 +12,10 @@ with sorts and computes every held expert on every token: it shares no code
 with ops/. Tolerances: the program in float32 against the float32 reference
 differs by summation order alone, 1e-5 of the logits' standard deviation
 (the cached paths too, absorbed or not); recorded log-probabilities 2e-5
-nats, as the other families' tests hold theirs.
+nats, as the other families' tests hold theirs. Programs, engine and the tests
+every family is held to come from ``tests/family_harness.py``.
 """
 
-import dataclasses
 import functools
 import math
 
@@ -26,6 +26,28 @@ import jax
 import jax.numpy as jnp
 
 from benchmark.reference import deepseek_v3 as ref
+from family_harness import (  # noqa: F401  (the contract tests run here, on FAMILY; tests/test_pool_layout.py takes EOS, Q, R from here)
+    EOS,
+    Q,
+    R,
+    Family,
+    admit_beside_a_running_group,
+    engine,
+    grow,
+    left_padded,
+    model_and_params,
+    paged,
+    positions_of,
+    programs,
+    refused,
+    rel_err,
+    slot_3_rows,
+    test_engine_logprobs_match_the_uncached_forward_on_the_tokens_it_drew,
+    test_registry_builds_the_family_and_its_cache_by_kind,
+    test_uncached_forward_matches_the_reference_on_left_padded_rows,
+    test_what_the_family_does_not_build_is_refused_by_name,
+    test_which_paths_the_engines_programs_traced,
+)
 from trlx_tpu.models.deepseek_v3 import (
     DeepseekV3Config,
     DeepseekV3Model,
@@ -35,6 +57,7 @@ from trlx_tpu.models.deepseek_v3 import (
 from trlx_tpu.ops import moe
 from trlx_tpu.ops.attention import Latent, decode_attention, latent_attention
 from trlx_tpu.ops.kv_cache import (
+    DENSE,
     PAGED,
     CacheKind,
     cache_kind,
@@ -57,84 +80,127 @@ ARCH = dict(
     rope_scaling=YARN, rope_theta=10000.0, rms_norm_eps=1e-6, dtype="float32", param_dtype="float32",
 )
 WIDTH = 16 + 8  # a cached row: [c_kv | k_r]
+TOL = 1e-5
 
 
-@functools.lru_cache(maxsize=None)
-def model_and_params():
-    cfg = DeepseekV3Config.from_dict(ARCH)
-    model = DeepseekV3Model(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    # move the ones and zeros (norm scales, the selection bias) off their defaults
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
-    leaves = [a + 0.1 * jax.random.normal(k, a.shape) for a, k in zip(leaves, keys)]
-    return cfg, model, jax.tree_util.tree_unflatten(tree, leaves)
-
-
-def left_padded(lens, T, seed=0, vocab=95):
-    rng = np.random.default_rng(seed)
-    ids = jnp.asarray(rng.integers(0, vocab, (len(lens), T)), jnp.int32)
-    mask = jnp.asarray(np.stack([np.r_[np.zeros(T - n), np.ones(n)] for n in lens]), jnp.int32)
-    return ids, mask
-
-
-def positions_of(mask):
-    return jnp.clip(jnp.cumsum(mask, axis=-1) - 1, 0, None)
-
-
-def rel_err(got, want, where):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    where = np.asarray(where).astype(bool)
-    return np.abs(got - want)[where].max() / want[where].std()
-
-
-def paged(cache, tables):
-    return tuple(dict(c, block_tables=tables) for c in cache)
-
-
-# ------------------------------ the model ------------------------------ #
-
-
-def test_uncached_forward_matches_the_reference_on_left_padded_rows():
-    cfg, model, params = model_and_params()
+def check_forward(cfg, params, out):
     assert float(jnp.abs(params["h_1"]["mlp"]["router_bias"]).min()) > 0  # a selection bias off zero
-    ids, mask = left_padded([21, 13, 5], 21)
-    out = model.apply({"params": params}, ids, attention_mask=mask)
-    want = ref.forward(params, ARCH, ids, mask)
-    assert rel_err(out["logits"], want, mask) < 1e-5
     stats = out["moe_stats"]
     assert set(stats) == {"experts_touched", "max_load", "rows_routed"}  # every expert held: no share to report
     assert float(stats["experts_touched"]) <= 16 and float(stats["rows_routed"]) == 2 * 3 * 21 * 4
 
 
+def refuse_more(cfg, model, params):
+    from trlx_tpu.models import gpt2_moe
+
+    assert cfg.latent_width == WIDTH and cfg.num_router_experts == 16
+    ids = jnp.zeros((2, 2), jnp.int32)
+    apply = functools.partial(model.apply, {"params": params}, ids)
+    refused("verify", apply, attention_mask=jnp.ones((2, 8), jnp.int32),
+            cache=paged(FAMILY, cfg, 2, 8), cache_index=jnp.zeros((2, 2), jnp.int32))
+    for hook in ({"start_layer": 1}, {"hidden_override": jnp.zeros((2, 2, 64))}, {"capture_hidden_at": 1}):
+        refused("hydra branch .* is not built for deepseek_v3", apply, **hook)
+    # a latent cache is read through a paged pool, and only a latent cache takes `latent`
+    refused("a latent cache .* is read through a paged pool", apply, attention_mask=jnp.ones((2, 8), jnp.int32),
+            cache=init_deepseek_v3_cache(cfg, 2, 8), cache_index=0)
+    cache, latent, q, row = latent_case()
+    plain = dict(kv_buffers(1, 3, 24, 1, 24, jnp.float32)[0], block_tables=cache["block_tables"])
+    bias = jnp.zeros((3, 1, 1, 24))
+    with pytest.raises(ValueError, match="a cache of keys and values without"):
+        decode_attention(q, row, row, plain, jnp.zeros((3,), jnp.int32), bias, latent=latent)
+    with pytest.raises(ValueError, match="takes v=None"):
+        paged_write_read(cache, row, row, jnp.zeros((3,), jnp.int32), jnp.float32)
+    with pytest.raises(ValueError, match="not built for a latent cache"):
+        latent_buffers(1, 2, 8, 24, jnp.float32, "int8")
+    gpt2_moe.set_ep_mesh(jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("ep",)))
+    try:
+        refused("ep mesh is not built for deepseek_v3", apply)
+    finally:
+        gpt2_moe.reset()
+
+
+def check_registry(family, cfg, cache):
+    from trlx_tpu.trainer import BaseRLTrainer
+
+    assert len(cache) == 3 and set(cache[0]) == {"k"} and cache[0]["k"].shape == (2, 8, 1, WIDTH)
+    # nothing trains its router (no loss is sown), so a trainer refuses an ep axis for it by name
+    assert not family.supports_ep
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("ep",))
+    with pytest.raises(NotImplementedError, match="'deepseek_v3' has no experts to shard"):
+        BaseRLTrainer.setup_ep_axis(None, mesh, family)
+    with pytest.raises(ValueError, match="no checkpoint converter"):
+        family.load_checkpoint("somewhere")
+
+
+def check_paths(t):
+    """The decode step reads every layer's latent pool as stored
+    (``paged``: absorbed) after one write by position; an admission program
+    addresses its group's rows inside the whole pool (``paged_rows``:
+    decompressed) after a write by block; each scope where it belongs."""
+    L = t.cfg.num_hidden_layers
+    for scope in ("mla_q", "mla_kv_down", "moe_group_router", "moe_shared", "moe_dispatch", "moe_experts", "moe_combine"):
+        assert scope in t.step_text and scope in t.chunk_text, scope
+    assert "mla_absorbed_read" in t.step_text and "mla_decompress" not in t.step_text
+    assert "mla_decompress" in t.chunk_text and "mla_absorbed_read" not in t.chunk_text
+    assert t.after_step["attention/decode_path{path=paged}"] == L
+    assert t.after_step["kv_cache/write_path{path=positions}"] == L
+    assert t.counters["attention/decode_path{path=paged_rows}"] == L
+    assert t.counters["kv_cache/write_path{path=blocks}"] == L
+    assert t.eng._block_write_share == {"prefill": 1.0, "prefill_chunk": 1.0}
+
+
+FAMILY = Family(
+    name="deepseek_v3", config_cls=DeepseekV3Config, model_cls=DeepseekV3Model, reference=ref, arch=ARCH,
+    reference_cfg=lambda cfg, **over: dict(ARCH, **over), init_cache=init_deepseek_v3_cache, tol=TOL, logprob_tol=2e-5,
+    cache_layouts=(DENSE,) * 3,
+    refusals={"deepseek_v3": [
+        ({"num_nextn_predict_layers": 1}, "multi-token prediction"),
+        ({"scoring_func": "softmax"}, "scoring_func"),
+        ({"topk_method": "greedy"}, "topk_method"),
+        ({"norm_topk_prob": False}, "norm_topk_prob"),
+        ({"q_lora_rank": None}, "q_lora_rank"),
+        ({"moe_layer_freq": 2}, "moe_layer_freq"),
+        ({"attention_bias": True}, "attention_bias"),
+        ({"tie_word_embeddings": True}, "tie_word_embeddings"),
+        ({"hidden_act": "gelu"}, "hidden_act"),
+        ({"num_key_value_heads": 2}, "num_key_value_heads"),
+        ({"kv_cache_dtype": "int8"}, "kv_cache_dtype='int8' for a latent row"),
+        ({"rope_scaling": {"type": "linear", "factor": 2}}, "rope_scaling of type 'linear'"),
+        ({"first_local_expert": 1}, "not among the router's 16"),
+        ({"first_k_dense_replace": 4}, "first_k_dense_replace"),
+    ]},
+    engine_cases={"whole": (0, False, {}), "chunked": (4, False, {}), "chunk-a-pump": (4, True, {})},
+    check_forward=check_forward, check_paths=check_paths, check_registry=check_registry, refuse_more=refuse_more,
+)
+
+
+# ------------------------------ the model ------------------------------ #
+
+
 @pytest.mark.parametrize("chunks", [1, 2, 4], ids=["whole", "two-chunks", "four-chunks"])
 def test_prefill_then_decode_through_the_paged_latent_pool_matches_the_full_forward(chunks):
-    cfg, model, params = model_and_params()
-    T, Q, cap = 21, 16, 24
+    cfg, model, params = model_and_params(FAMILY)
+    T, cap = 21, 24
     ids, mask = left_padded([21, 13, 6], T, seed=1)
-    want = ref.forward(params, ARCH, ids, mask)
-    tables = identity_block_tables(3, cap // 4)
-    tables = tables.at[1].set(rotate_block_table(tables[1], 2))
-    cache = paged(init_deepseek_v3_cache(cfg, 3, cap), tables)
+    _, cached, reference = programs(FAMILY)
+    want = reference(params, ids, mask)
+    cache = paged(FAMILY, cfg, 3, cap, rotate=1)
     assert all(set(c) == {"k", "block_tables"} and c["k"].shape == (3, cap, 1, WIDTH) for c in cache)
     pos = positions_of(mask)
-    grow = lambda m: jnp.concatenate([m, jnp.zeros((3, cap - m.shape[1]), jnp.int32)], axis=1)
     W = Q // chunks
     logits = []
     for c in range(chunks):  # the admission: the published form over the view
         cols = slice(c * W, (c + 1) * W)
-        out = model.apply({"params": params}, ids[:, cols], attention_mask=grow(mask[:, :Q]),
-                          position_ids=pos[:, cols], cache=cache,
-                          cache_index=0 if chunks == 1 else jnp.asarray(c * W))
+        out = cached(params, ids[:, cols], grow(mask[:, :Q], cap), cache, 0 if chunks == 1 else jnp.asarray(c * W),
+                     pos[:, cols])
         cache = out["cache"]
         logits.append(out["logits"])
     for t in range(Q, T):  # the decode step: absorbed, over the pool as stored
-        out = model.apply({"params": params}, ids[:, t : t + 1], attention_mask=grow(mask[:, : t + 1]),
-                          position_ids=pos[:, t : t + 1], cache=cache,
-                          cache_index=jnp.full((3,), t, jnp.int32))
+        out = cached(params, ids[:, t : t + 1], grow(mask[:, : t + 1], cap), cache, jnp.full((3,), t, jnp.int32),
+                     pos[:, t : t + 1])
         cache = out["cache"]
         logits.append(out["logits"])
-    assert rel_err(jnp.concatenate(logits, axis=1), want, mask) < 1e-5
+    assert rel_err(jnp.concatenate(logits, axis=1), want, mask) < TOL
     assert all(set(c) == {"k", "block_tables"} for c in cache)
 
 
@@ -283,7 +349,7 @@ def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer
     router's ``num_router_experts`` and returns its own experts' part of the
     sum plus the shared expert. Over all 16 shares, the shared expert (which
     every chip computes alike) counted once, that is the whole layer."""
-    cfg, _, params = model_and_params()
+    cfg, _, params = model_and_params(FAMILY)
     layer = params["h_1"]
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 9, 64))
     whole_cfg = cfg
@@ -308,61 +374,10 @@ def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer
         held[f"h_{i}"]["mlp"] = dict(held[f"h_{i}"]["mlp"], **{
             k: held[f"h_{i}"]["mlp"][k][8:12] for k in ("w_gate", "w_up", "w_down")})
     ids, mask = left_padded([12, 7], 12, seed=2)
-    out = DeepseekV3Model(DeepseekV3Config.from_dict(cut)).apply({"params": held}, ids, attention_mask=mask)
-    assert rel_err(out["logits"], ref.forward(held, cut, ids, mask), mask) < 1e-5
+    share = DeepseekV3Model(DeepseekV3Config.from_dict(cut))
+    out = jax.jit(lambda p: share.apply({"params": p}, ids, attention_mask=mask))(held)
+    assert rel_err(out["logits"], jax.jit(lambda p: ref.forward(p, cut, ids, mask))(held), mask) < TOL
     assert 0 < float(out["moe_stats"]["rows_here_share"]) < 1 and float(out["moe_stats"]["experts_touched"]) <= 4
-
-
-def test_what_the_family_does_not_build_is_refused_by_name():
-    for over, said in [
-        ({"num_nextn_predict_layers": 1}, "multi-token prediction"),
-        ({"scoring_func": "softmax"}, "scoring_func"),
-        ({"topk_method": "greedy"}, "topk_method"),
-        ({"norm_topk_prob": False}, "norm_topk_prob"),
-        ({"q_lora_rank": None}, "q_lora_rank"),
-        ({"moe_layer_freq": 2}, "moe_layer_freq"),
-        ({"attention_bias": True}, "attention_bias"),
-        ({"tie_word_embeddings": True}, "tie_word_embeddings"),
-        ({"hidden_act": "gelu"}, "hidden_act"),
-        ({"num_key_value_heads": 2}, "num_key_value_heads"),
-        ({"kv_cache_dtype": "int8"}, "kv_cache_dtype='int8' for a latent row"),
-        ({"rope_scaling": {"type": "linear", "factor": 2}}, "rope_scaling of type 'linear'"),
-        ({"first_local_expert": 1}, "not among the router's 16"),
-        ({"first_k_dense_replace": 4}, "first_k_dense_replace"),
-    ]:
-        with pytest.raises(ValueError, match=said):
-            DeepseekV3Config.from_dict(dict(ARCH, **over))
-    cfg, model, params = model_and_params()
-    assert cfg.latent_width == WIDTH and cfg.num_router_experts == 16
-    ids = jnp.zeros((2, 2), jnp.int32)
-    with pytest.raises(ValueError, match="verify"):
-        model.apply({"params": params}, ids, attention_mask=jnp.ones((2, 8), jnp.int32),
-                    cache=paged(init_deepseek_v3_cache(cfg, 2, 8), identity_block_tables(2, 2)),
-                    cache_index=jnp.zeros((2, 2), jnp.int32))
-    for hook in ({"start_layer": 1}, {"hidden_override": jnp.zeros((2, 2, 64))}, {"capture_hidden_at": 1}):
-        with pytest.raises(ValueError, match="hydra branch .* is not built for deepseek_v3"):
-            model.apply({"params": params}, ids, **hook)
-    # a latent cache is read through a paged pool, and only a latent cache takes `latent`
-    with pytest.raises(ValueError, match="a latent cache .* is read through a paged pool"):
-        model.apply({"params": params}, ids, attention_mask=jnp.ones((2, 8), jnp.int32),
-                    cache=init_deepseek_v3_cache(cfg, 2, 8), cache_index=0)
-    cache, latent, q, row = latent_case()
-    plain = dict(kv_buffers(1, 3, 24, 1, 24, jnp.float32)[0], block_tables=cache["block_tables"])
-    bias = jnp.zeros((3, 1, 1, 24))
-    with pytest.raises(ValueError, match="a cache of keys and values without"):
-        decode_attention(q, row, row, plain, jnp.zeros((3,), jnp.int32), bias, latent=latent)
-    with pytest.raises(ValueError, match="takes v=None"):
-        paged_write_read(cache, row, row, jnp.zeros((3,), jnp.int32), jnp.float32)
-    with pytest.raises(ValueError, match="not built for a latent cache"):
-        latent_buffers(1, 2, 8, 24, jnp.float32, "int8")
-    from trlx_tpu.models import gpt2_moe
-
-    gpt2_moe.set_ep_mesh(jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("ep",)))
-    try:
-        with pytest.raises(ValueError, match="ep mesh is not built for deepseek_v3"):
-            model.apply({"params": params}, ids)
-    finally:
-        gpt2_moe.reset()
 
 
 def test_a_yarn_group_that_scales_sin_and_cos_is_refused_by_name():
@@ -376,28 +391,9 @@ def test_a_yarn_group_that_scales_sin_and_cos_is_refused_by_name():
             rotary_angles(ids, 8, 10000.0, dict(YARN, **over))
     bad = dict(ARCH, rope_scaling=dict(YARN, mscale_all_dim=0.707))
     model = DeepseekV3Model(DeepseekV3Config.from_dict(bad))
-    with pytest.raises(ValueError, match="mscale_all_dim=0.707"):
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
+    refused("mscale_all_dim=0.707", model.init, jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32))
     # a factor of 1 and under stretches nothing and scales nothing, whatever the two say
     rotary_angles(ids, 8, 10000.0, dict(YARN, factor=1, mscale_all_dim=0))
-
-
-def test_registry_builds_the_family_and_its_cache():
-    from trlx_tpu.models.registry import get_model_family
-
-    family = get_model_family("deepseek_v3")
-    cfg = family.config_cls.from_dict(ARCH)
-    cache = family.init_cache(cfg, 2, 8)
-    assert len(cache) == 3 and set(cache[0]) == {"k"} and cache[0]["k"].shape == (2, 8, 1, WIDTH)
-    # nothing trains its router (no loss is sown), so a trainer refuses an ep axis for it by name
-    assert not family.supports_ep
-    from trlx_tpu.trainer import BaseRLTrainer
-
-    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("ep",))
-    with pytest.raises(NotImplementedError, match="'deepseek_v3' has no experts to shard"):
-        BaseRLTrainer.setup_ep_axis(None, mesh, family)
-    with pytest.raises(ValueError, match="no checkpoint converter"):
-        family.load_checkpoint("somewhere")
 
 
 def test_cache_kind_on_a_latent_layer():
@@ -416,137 +412,22 @@ def test_cache_kind_on_a_latent_layer():
 
 # ------------------------------ the engine ------------------------------ #
 
-Q, R, EOS = 16, 6, 95
-
-
-@functools.lru_cache(maxsize=None)
-def engine(prefill_chunk=0, chunks_per_pump=0):
-    from trlx_tpu.inference.engine import ContinuousBatchingEngine
-    from trlx_tpu.models.heads import CausalLMWithValueHead
-    from trlx_tpu.ops.sampling import GenerationConfig
-
-    cfg = model_and_params()[0]
-    model = CausalLMWithValueHead(cfg, backbone_cls=DeepseekV3Model)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    params = dict(params, transformer=model_and_params()[2])
-
-    def apply_fn(p, input_ids, attention_mask=None, position_ids=None, cache=None,
-                 cache_index=None, last_only=False):
-        return model.apply({"params": p}, input_ids, attention_mask=attention_mask,
-                           position_ids=position_ids, cache=cache, cache_index=cache_index,
-                           last_only=last_only)
-
-    gen = GenerationConfig(max_new_tokens=R, min_new_tokens=1, eos_token_id=EOS,
-                           pad_token_id=EOS, do_sample=True)
-    eng = ContinuousBatchingEngine(
-        apply_fn=apply_fn, init_cache_fn=functools.partial(init_deepseek_v3_cache, cfg),
-        gen_config=gen, query_length=Q, vocab_size=cfg.vocab_size, num_slots=4, admit_width=2,
-        harvest_width=2, block_size=4, prefill_chunk=prefill_chunk,
-        prefill_chunks_per_pump=chunks_per_pump,
-    )
-    return eng, params
-
-
-def drive(eng, params, ids, mask, pump):
-    eng.start_phase(params, jax.random.PRNGKey(5))
-    got = {}
-
-    def land(group):
-        arrs = {k: np.asarray(group[k]) for k in ("tokens", "response_mask", "logprobs")}
-        for j, r in enumerate(group["rows"]):
-            got[r] = {k: v[j] for k, v in arrs.items()}
-
-    if not pump:
-        eng.submit(ids, mask)
-        for group in eng.drive(len(ids)):
-            land(group)
-        return got
-    fed = 0
-    while len(got) < len(ids):  # the serving pump: one step in flight
-        free = eng.free_capacity
-        if fed < len(ids) and free > 0:
-            take = min(free, eng.admit_width, len(ids) - fed)
-            eng.submit(ids[fed : fed + take], mask[fed : fed + take])
-            fed += take
-        for group in eng.pump():
-            land(group)
-    return got
-
-
-@pytest.mark.parametrize("chunk,pump", [(0, False), (4, False), (4, True)],
-                         ids=["whole", "chunked", "chunk-a-pump"])
-def test_engine_logprobs_match_the_uncached_forward_on_the_tokens_it_drew(chunk, pump):
-    """Ten requests through four slots: every slot is recycled (its block
-    table rotated), with whole and chunked admission and with the step in
-    flight. The recorded log-probability of every drawn token is the
-    reference's on [prompt; drawn tokens]."""
-    eng, params = engine(chunk, 1 if pump else 0)
-    lens = [16, 15, 3, 9, 2, 12, 5, 16, 4, 7]
-    ids, mask = left_padded(lens, Q, seed=4)
-    ids, mask = np.asarray(ids), np.asarray(mask)
-    got = drive(eng, params, ids, mask, pump)
-    assert sorted(got) == list(range(len(lens)))
-    for r, row in got.items():
-        full_ids = jnp.asarray(np.r_[ids[r], row["tokens"]])[None]
-        full_mask = jnp.asarray(np.r_[mask[r], row["response_mask"]])[None]
-        logits = ref.forward(params["transformer"], ARCH, full_ids, full_mask)[0]
-        lp = jax.nn.log_softmax(logits[Q - 1 : -1], axis=-1)
-        want = np.take_along_axis(np.asarray(lp), row["tokens"][:, None], axis=1)[:, 0]
-        live = row["response_mask"].astype(bool)
-        np.testing.assert_allclose(row["logprobs"][live], want[live], rtol=0, atol=2e-5)
-    if chunk:
-        assert eng.stats.prefill_cols_skipped > 0  # all-pad chunks were not computed
-
-
 @pytest.mark.parametrize("program", ["prefill", "prefill_chunk"])
 def test_an_admission_leaves_every_other_slots_rows_as_they_were(program):
     """Every layer's latent pool is handed to the forward whole and written
     where the group's rows lie. Slots 0 and 1 (a running group, two steps
     in) and the idle slot 2 read bit for bit what they read before slot 3
     and a dummy are admitted, and slot 3 holds the rows of its prompt alone."""
-    eng, params = engine(4, 1)
-    cfg, _, backbone = model_and_params()
-    state = eng.init_state()
-    ids0, mask0 = left_padded([9, 16], Q, seed=1)
-    key = jax.random.PRNGKey(5)
-    state = eng.prefill_jit(params, state, jnp.asarray([0, 1], jnp.int32), ids0, mask0,
-                            jnp.asarray([7, 8], jnp.int32), jnp.asarray([1, 3], jnp.int32), key)
-    for _ in range(2):
-        state = eng.decode_step_jit(params, state)[0]
-    before = jax.device_get(jax.tree_util.tree_map(jnp.array, state))
-
-    slot_ids = jnp.asarray([3, eng.num_slots], jnp.int32)
-    turns = jnp.asarray([2, 4], jnp.int32)
-    ids, mask = left_padded([13, 6], Q, seed=2)
-    rows = jnp.arange(2, dtype=jnp.int32)
-    if program == "prefill":
-        state = eng.prefill_jit(params, state, slot_ids, ids, mask, rows, turns, key)
-    else:
-        for c in range(Q // 4):
-            state = eng.prefill_chunk_jit(params, state, slot_ids, ids, mask, rows, turns, key,
-                                          jnp.asarray(c, jnp.int32))
-    after = jax.device_get(state)
-
-    others = [0, 1, 2]
-    for was, now in zip(before.cache, after.cache):
-        assert set(was) == {"k", "block_tables"}
-        for k in was:
-            np.testing.assert_array_equal(np.asarray(now[k])[others], np.asarray(was[k])[others], err_msg=k)
-    for f in dataclasses.fields(before):
-        if f.name != "cache":
-            np.testing.assert_array_equal(np.asarray(getattr(after, f.name))[others],
-                                          np.asarray(getattr(before, f.name))[others], err_msg=f.name)
+    eng, params = engine(FAMILY, 4, 1)
+    cfg, _, backbone = model_and_params(FAMILY)
+    before, after, ids, mask = admit_beside_a_running_group(eng, params, program)
+    assert all(set(was) == {"k", "block_tables"} for was in before.cache)
     # slot 3 against the same prompt through a paged pool of one row with a plain table
-    alone = paged(init_deepseek_v3_cache(cfg, 1, eng.capacity), identity_block_tables(1, eng.n_blocks))
+    table = identity_block_tables(1, eng.n_blocks)
+    alone = tuple(dict(c, block_tables=table) for c in init_deepseek_v3_cache(cfg, 1, eng.capacity))
     cache_mask = jnp.concatenate([mask[:1], jnp.zeros((1, R), mask.dtype)], axis=1)
-    want = DeepseekV3Model(cfg).apply(
-        {"params": backbone}, ids[:1], attention_mask=cache_mask,
-        position_ids=positions_of(mask[:1]), cache=alone, cache_index=0,
-    )["cache"]
-    nb, bs = eng.n_blocks, eng.block_size
-    table = (np.arange(nb) + 2) % nb
-    real = np.flatnonzero(np.asarray(mask[0]))
-    phys = table[real // bs] * bs + real % bs
+    want = programs(FAMILY)[1](backbone, ids[:1], cache_mask, alone, 0, positions_of(mask[:1]))["cache"]
+    table, real, phys = slot_3_rows(eng, mask)
     for now, ref_layer in zip(after.cache, want):
         np.testing.assert_array_equal(np.asarray(now["block_tables"])[3], table)
         # the engine holds a row padded to whole lanes (ops/kv_cache.py::hold_pool): a row's own columns, then zeros
@@ -562,7 +443,7 @@ def test_engine_and_fixed_sampler_refuse_what_a_latent_row_cannot_give():
     from trlx_tpu.ops.sampling import GenerationConfig, make_sampler
     from trlx_tpu.parallel.mesh import make_mesh
 
-    cfg = model_and_params()[0]
+    cfg = model_and_params(FAMILY)[0]
     init = functools.partial(init_deepseek_v3_cache, cfg)
     common = dict(apply_fn=lambda *a, **k: None, init_cache_fn=init, gen_config=GenerationConfig(max_new_tokens=4),
                   query_length=8, vocab_size=96, num_slots=2)
@@ -583,36 +464,3 @@ def test_engine_and_fixed_sampler_refuse_what_a_latent_row_cannot_give():
     sampler = make_sampler(lambda *a, **k: None, init, GenerationConfig(max_new_tokens=4), 8, with_values=False)
     with pytest.raises(ValueError, match="a latent cache .deepseek_v3. or a tail beside its keys .zaya. samples through"):
         sampler(None, jnp.zeros((2, 8), jnp.int32), jnp.ones((2, 8), jnp.int32), jax.random.PRNGKey(0))
-
-
-def test_which_paths_the_engines_programs_traced():
-    """Counted per traced call site: the decode step reads every layer's
-    latent pool as stored (``paged``: absorbed) after one write by position;
-    an admission program addresses its group's rows inside the whole pool
-    (``paged_rows``: decompressed) after a write by block, none left under
-    ``generic``; the device scopes that docs/observability.md names are in
-    the lowered programs, each where it belongs."""
-    from trlx_tpu import telemetry
-
-    eng, params = engine.__wrapped__(4, 1)  # its own: a program traced before counts nothing again
-    L = model_and_params()[0].num_hidden_layers
-    with telemetry.scoped_metrics() as reg:
-        state = jax.eval_shape(eng._make_state)
-        abstract = jax.eval_shape(lambda: params)
-        step = eng.decode_step_jit.lower(abstract, state)
-        after_step = dict(reg.snapshot()["counters"])
-        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-        chunk = eng.prefill_chunk_jit.lower(abstract, state, i32(2), i32(2, Q), i32(2, Q), i32(2), i32(2),
-                                            jax.ShapeDtypeStruct((2,), jnp.uint32), i32())
-        after_chunk = reg.snapshot()["counters"]
-    step_text, chunk_text = step.as_text(debug_info=True), chunk.as_text(debug_info=True)
-    for scope in ("mla_q", "mla_kv_down", "moe_group_router", "moe_shared", "moe_dispatch", "moe_experts", "moe_combine"):
-        assert scope in step_text and scope in chunk_text, scope
-    assert "mla_absorbed_read" in step_text and "mla_decompress" not in step_text
-    assert "mla_decompress" in chunk_text and "mla_absorbed_read" not in chunk_text
-    assert after_step["attention/decode_path{path=paged}"] == L
-    assert after_step["kv_cache/write_path{path=positions}"] == L
-    assert after_chunk["attention/decode_path{path=paged_rows}"] == L
-    assert after_chunk["kv_cache/write_path{path=blocks}"] == L
-    assert "attention/decode_path{path=generic}" not in after_chunk
-    assert eng._block_write_share == {"prefill": 1.0, "prefill_chunk": 1.0}

@@ -7,7 +7,8 @@ handling of layers that keep a state.
 Everything is compared on logits (never sampled tokens) with the plain
 float32 reference ``benchmark/reference/granitemoehybrid.py``, which runs
 the recurrence a position at a time and every held expert on every token:
-it shares no code with ops/ssm.py or ops/moe.py.
+it shares no code with ops/ssm.py or ops/moe.py. Programs, engine and the
+tests every family is held to come from ``tests/family_harness.py``.
 """
 
 import functools
@@ -19,6 +20,28 @@ import jax
 import jax.numpy as jnp
 
 from benchmark.reference import granitemoehybrid as ref
+from family_harness import (  # noqa: F401  (the contract tests run here, on FAMILY)
+    Q,
+    R,
+    Family,
+    admit_beside_a_running_group,
+    engine,
+    grow,
+    left_padded,
+    model_and_params,
+    paged,
+    positions_of,
+    programs,
+    refused,
+    rel_err,
+    slot_3_rows,
+    test_a_parked_row_keeps_its_state_and_a_fresh_row_forgets_the_slot,
+    test_engine_logprobs_match_the_uncached_forward_on_the_tokens_it_drew,
+    test_registry_builds_the_family_and_its_cache_by_kind,
+    test_uncached_forward_matches_the_reference_on_left_padded_rows,
+    test_what_the_family_does_not_build_is_refused_by_name,
+    test_which_paths_the_engines_programs_traced,
+)
 from trlx_tpu.models.granite_hybrid import (
     GraniteMoeHybridConfig,
     GraniteMoeHybridModel,
@@ -34,7 +57,6 @@ from trlx_tpu.ops.kv_cache import (
     hybrid_cache,
     identity_block_tables,
     kv_buffers,
-    rotate_block_table,
 )
 
 ARCH = dict(
@@ -46,6 +68,7 @@ ARCH = dict(
     mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_chunk_size=8,
     dtype="float32", param_dtype="float32",
 )
+TOL = 1e-5
 
 
 def reference_cfg(cfg: GraniteMoeHybridConfig, **over):
@@ -54,106 +77,47 @@ def reference_cfg(cfg: GraniteMoeHybridConfig, **over):
     return dict(ARCH, **{k: getattr(cfg, k) for k in keys}, **over)
 
 
-@functools.lru_cache(maxsize=None)
-def model_and_params(**over):
-    cfg = GraniteMoeHybridConfig.from_dict(dict(ARCH, **over))
-    model = GraniteMoeHybridModel(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    # move the ones-initialised vectors (dt_bias, D, norms) off their defaults
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
-    leaves = [a + 0.1 * jax.random.normal(k, a.shape) for a, k in zip(leaves, keys)]
-    return cfg, model, jax.tree_util.tree_unflatten(tree, leaves)
-
-
-def left_padded(lens, T, seed=0, vocab=95):
-    rng = np.random.default_rng(seed)
-    ids = jnp.asarray(rng.integers(0, vocab, (len(lens), T)), jnp.int32)
-    mask = jnp.asarray(np.stack([np.r_[np.zeros(T - n), np.ones(n)] for n in lens]), jnp.int32)
-    return ids, mask
-
-
-def rel_err(got, want, where):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    where = np.asarray(where).astype(bool)
-    return np.abs(got - want)[where].max() / want[where].std()
-
-
-# ------------------------------ the model ------------------------------ #
-
-
-def test_uncached_forward_matches_the_reference_on_left_padded_rows():
-    cfg, model, params = model_and_params()
-    ids, mask = left_padded([21, 13, 5], 21)
-    out = model.apply({"params": params}, ids, attention_mask=mask)
-    want = ref.forward(params, reference_cfg(cfg), ids, mask)
-    assert rel_err(out["logits"], want, mask) < 1e-5
+def check_forward(cfg, params, out):
     stats = out["moe_stats"]
     assert set(stats) == {"experts_touched", "max_load", "rows_routed", "rows_here_share"}
     assert float(stats["experts_touched"]) <= 4 and 0 < float(stats["rows_here_share"]) < 1
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
-def test_prefill_then_decode_through_the_cache_matches_the_full_forward(paged):
-    cfg, model, params = model_and_params()
-    T, Q, cap = 21, 16, 24
-    ids, mask = left_padded([21, 13, 6], T, seed=1)
-    want = ref.forward(params, reference_cfg(cfg), ids, mask)
-    cache = init_granite_hybrid_cache(cfg, 3, cap)
-    if paged:
-        tables = identity_block_tables(3, cap // 4)
-        tables = tables.at[1].set(rotate_block_table(tables[1], 2))
-        cache = tuple(
-            c if cache_kind(c).layout == STATE else dict(c, block_tables=tables) for c in cache
-        )
-        assert [cache_kind(c).layout for c in cache] == [STATE, PAGED, STATE, STATE]
-    grow = lambda m: jnp.concatenate([m, jnp.zeros((3, cap - m.shape[1]), jnp.int32)], axis=1)
-    out = model.apply({"params": params}, ids[:, :Q], attention_mask=grow(mask[:, :Q]),
-                      cache=cache, cache_index=0)
-    assert rel_err(out["logits"], want[:, :Q], mask[:, :Q]) < 1e-5
-    cache = out["cache"]
-    for t in range(Q, T):
-        # the paged pool takes per-row targets, as the engine's decode step gives them
-        at = jnp.full((3,), t, jnp.int32) if paged else t
-        out = model.apply({"params": params}, ids[:, t : t + 1], attention_mask=grow(mask[:, : t + 1]),
-                          cache=cache, cache_index=at)
-        cache = out["cache"]
-        assert rel_err(out["logits"][:, 0], want[:, t], mask[:, t]) < 1e-5
+def refuse_more(cfg, model, params):
+    with pytest.raises(ValueError, match="int8"):
+        hybrid_cache(["mamba", "attention"], 2, 8, n_kv_head=2, head_dim=4, dtype="float32",
+                     kv_cache_dtype="int8", state=dict(n_head=2, head_dim=4, d_state=4,
+                                                        conv_width=4, conv_channels=16))
+    refused("verify", model.apply, {"params": params}, jnp.zeros((2, 2), jnp.int32),
+            attention_mask=jnp.ones((2, 8), jnp.int32),
+            cache=init_granite_hybrid_cache(cfg, 2, 8), cache_index=jnp.zeros((2, 2), jnp.int32))
 
 
-def test_a_parked_row_keeps_its_state_and_a_fresh_row_forgets_the_slot():
-    """The engine's two conventions as the model reads them from the cache
-    mask: a row whose ``cache_index`` is past the mask's width (idle or
-    finished) leaves state and tail bit for bit; a row with no valid column
-    before the call starts from zeros whatever the slot held."""
-    cfg, model, params = model_and_params()
-    cap = 12
-    ids, mask = left_padded([8, 8], 8, seed=2)
-    grow = lambda m: jnp.concatenate([m, jnp.zeros((2, cap - m.shape[1]), jnp.int32)], axis=1)
-    tables = identity_block_tables(2, cap // 4)  # per-row targets need the paged pool
-    clean = tuple(
-        c if cache_kind(c).layout == STATE else dict(c, block_tables=tables)
-        for c in init_granite_hybrid_cache(cfg, 2, cap)
-    )
-    dirty = tuple(
-        {k: jnp.ones_like(v) * 3 for k, v in c.items()} if cache_kind(c).layout == STATE else c
-        for c in clean
-    )
-    a = model.apply({"params": params}, ids, attention_mask=grow(mask), cache=dirty, cache_index=0)
-    b = model.apply({"params": params}, ids, attention_mask=grow(mask), cache=clean, cache_index=0)
-    np.testing.assert_array_equal(np.asarray(a["logits"]), np.asarray(b["logits"]))
-    step_mask = grow(jnp.ones((2, 9), jnp.int32))
-    out = model.apply({"params": params}, ids[:, :1], attention_mask=step_mask, cache=a["cache"],
-                      cache_index=jnp.asarray([8, cap], jnp.int32))
-    for before, after in zip(a["cache"], out["cache"]):
-        if cache_kind(before).layout == STATE:
-            for k in before:
-                np.testing.assert_array_equal(np.asarray(before[k][1]), np.asarray(after[k][1]))
-                assert not np.array_equal(np.asarray(before[k][0]), np.asarray(after[k][0]))
+def check_registry(family, cfg, cache):
+    assert cache[1]["k"].shape == (2, 8, 2, 16)  # sized by KV heads
+    assert cache[0]["ssm_state"].shape == (2, 16, 8, 16) and cache[0]["conv_tail"].shape == (2, 3, 160)
+    assert cache[0]["ssm_state"].dtype == jnp.float32
 
 
-def test_what_the_family_does_not_build_is_refused_by_name():
-    for over, said in [
+def check_paths(t):
+    """The decode step reads its one KV layer as stored (``paged``) and
+    steps its three state layers; an admission program scans them and
+    addresses its group's rows inside the whole pool (``paged_rows``)."""
+    for scope in ("ssm_in_proj", "ssm_conv", "ssm_step", "ssm_out", "moe_shared", "moe_experts"):
+        assert scope in t.step_text, scope
+    assert "ssm_scan" in t.chunk_text and "ssm_scan" not in t.step_text and "ssm_step" not in t.chunk_text
+    n_state = t.cfg.layer_types.count("mamba")
+    assert t.after_step["ssm/path{path=step}"] == n_state and "ssm/path{path=scan}" not in t.after_step
+    assert t.after_step["attention/decode_path{path=paged}"] == 1
+    assert t.counters["ssm/path{path=scan}"] == n_state
+    assert t.counters["attention/decode_path{path=paged_rows}"] == 1
+
+
+FAMILY = Family(
+    name="granitemoehybrid", config_cls=GraniteMoeHybridConfig, model_cls=GraniteMoeHybridModel, reference=ref,
+    arch=ARCH, reference_cfg=reference_cfg, init_cache=init_granite_hybrid_cache, tol=TOL, logprob_tol=2e-5,
+    cache_layouts=("state", "dense", "state", "state"),
+    refusals={"granitemoehybrid": [
         ({"rope_scaling": {"type": "linear"}}, "rope_scaling"),
         ({"position_embedding_type": "rope"}, "position_embedding_type"),
         ({"attention_bias": True}, "attention_bias"),
@@ -162,33 +126,44 @@ def test_what_the_family_does_not_build_is_refused_by_name():
         ({"kv_cache_dtype": "int8"}, "kv_cache_dtype"),
         ({"state_dtype": "int8"}, "state_dtype"),
         ({"num_local_experts": 9}, "router"),
-    ]:
-        with pytest.raises(ValueError, match=said):
-            GraniteMoeHybridConfig.from_dict(dict(ARCH, **over))
-    with pytest.raises(ValueError, match="int8"):
-        hybrid_cache(["mamba", "attention"], 2, 8, n_kv_head=2, head_dim=4, dtype="float32",
-                     kv_cache_dtype="int8", state=dict(n_head=2, head_dim=4, d_state=4,
-                                                        conv_width=4, conv_channels=16))
-    cfg, model, params = model_and_params()
-    with pytest.raises(ValueError, match="verify"):
-        model.apply({"params": params}, jnp.zeros((2, 2), jnp.int32),
-                    attention_mask=jnp.ones((2, 8), jnp.int32),
-                    cache=init_granite_hybrid_cache(cfg, 2, 8), cache_index=jnp.zeros((2, 2), jnp.int32))
+    ]},
+    engine_cases={"whole": (0, False, {}), "chunked": (4, False, {}), "chunk-a-pump": (4, True, {})},
+    check_forward=check_forward, check_paths=check_paths, check_registry=check_registry, refuse_more=refuse_more,
+)
 
 
-def test_registry_builds_the_family_and_its_cache_by_kind():
-    from trlx_tpu.models.registry import get_model_family
+# ------------------------------ the model ------------------------------ #
 
-    family = get_model_family("granitemoehybrid")
-    cfg = family.config_cls.from_dict(ARCH)
-    cache = family.init_cache(cfg, 2, 8)
-    assert [cache_kind(c).layout for c in cache] == ["state", "dense", "state", "state"]
-    assert cache[1]["k"].shape == (2, 8, 2, 16)  # sized by KV heads
-    assert cache[0]["ssm_state"].shape == (2, 16, 8, 16) and cache[0]["conv_tail"].shape == (2, 3, 160)
-    assert cache[0]["ssm_state"].dtype == jnp.float32
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_prefill_then_decode_through_the_cache_matches_the_full_forward(layout):
+    cfg, model, params = model_and_params(FAMILY)
+    T, cap = 21, 24
+    ids, mask = left_padded([21, 13, 6], T, seed=1)
+    _, cached, reference = programs(FAMILY)
+    want = reference(params, ids, mask)
+    cache = init_granite_hybrid_cache(cfg, 3, cap)
+    if layout == "paged":
+        cache = paged(FAMILY, cfg, 3, cap, rotate=1)
+        assert [cache_kind(c).layout for c in cache] == [STATE, PAGED, STATE, STATE]
+    positions = positions_of(mask)  # handed over as the engine hands them, whatever the family makes of them
+    out = cached(params, ids[:, :Q], grow(mask[:, :Q], cap), cache, 0, positions[:, :Q])
+    assert rel_err(out["logits"], want[:, :Q], mask[:, :Q]) < TOL
+    cache = out["cache"]
+    for t in range(Q, T):
+        # the paged pool takes per-row targets, as the engine's decode step gives them
+        at = jnp.full((3,), t, jnp.int32) if layout == "paged" else jnp.asarray(t)
+        out = cached(params, ids[:, t : t + 1], grow(mask[:, : t + 1], cap), cache, at, positions[:, t : t + 1])
+        cache = out["cache"]
+        assert rel_err(out["logits"][:, 0], want[:, t], mask[:, t]) < TOL
 
 
 # ---------------------------- ops/ssm.py -------------------------------- #
+
+
+# the scan and the step as one program a shape (tests/family_harness.py says why)
+ssd_scan = jax.jit(ssm.ssd_scan, static_argnames=("chunk",))
+ssd_step = jax.jit(ssm.ssd_step)
 
 
 def scan_inputs(B=2, T=24, H=4, P=8, N=16, seed=0):
@@ -207,7 +182,7 @@ def sequential(x, dt, A, B, C, D, mask, state):
     """The recurrence a column at a time through :func:`ssm.ssd_step`."""
     ys = []
     for t in range(x.shape[1]):
-        y, state = ssm.ssd_step(x[:, t], dt[:, t], A, B[:, t], C[:, t], D, mask[:, t], state)
+        y, state = ssd_step(x[:, t], dt[:, t], A, B[:, t], C[:, t], D, mask[:, t], state)
         ys.append(y)
     return jnp.stack(ys, axis=1), state
 
@@ -218,7 +193,7 @@ def test_chunked_scan_matches_the_sequential_recurrence(chunk):
     mask = jnp.ones((2, 24))
     state = jax.random.normal(jax.random.PRNGKey(9), (2, 4, 8, 16))
     want_y, want_s = sequential(**a, mask=mask, state=state)
-    y, s = ssm.ssd_scan(**a, mask=mask, state=state, chunk=chunk)
+    y, s = ssd_scan(**a, mask=mask, state=state, chunk=chunk)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(s), np.asarray(want_s), rtol=2e-4, atol=2e-4)
 
@@ -227,10 +202,10 @@ def test_two_calls_that_carry_the_state_equal_one():
     a = scan_inputs(T=16)
     mask = jnp.ones((2, 16))
     zero = jnp.zeros((2, 4, 8, 16))
-    whole_y, whole_s = ssm.ssd_scan(**a, mask=mask, state=zero, chunk=4)
+    whole_y, whole_s = ssd_scan(**a, mask=mask, state=zero, chunk=4)
     cut = lambda lo, hi: {k: (v[:, lo:hi] if v.ndim > 1 else v) for k, v in a.items()}
-    y1, s1 = ssm.ssd_scan(**cut(0, 8), mask=mask[:, :8], state=zero, chunk=4)
-    y2, s2 = ssm.ssd_scan(**cut(8, 16), mask=mask[:, 8:], state=s1, chunk=4)
+    y1, s1 = ssd_scan(**cut(0, 8), mask=mask[:, :8], state=zero, chunk=4)
+    y2, s2 = ssd_scan(**cut(8, 16), mask=mask[:, 8:], state=s1, chunk=4)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 1)), np.asarray(whole_y), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(s2), np.asarray(whole_s), rtol=1e-5, atol=1e-5)
 
@@ -239,11 +214,11 @@ def test_a_masked_column_leaves_state_and_tail_untouched():
     a = scan_inputs(T=8)
     state = jax.random.normal(jax.random.PRNGKey(3), (2, 4, 8, 16))
     mask = jnp.asarray([[0] * 8, [0, 0, 0, 1, 1, 1, 1, 1]], jnp.float32)
-    _, s = ssm.ssd_scan(**a, mask=mask, state=state, chunk=4)
+    _, s = ssd_scan(**a, mask=mask, state=state, chunk=4)
     np.testing.assert_array_equal(np.asarray(s[0]), np.asarray(state[0]))  # an all-pad row: bit for bit
     assert not np.allclose(np.asarray(s[1]), np.asarray(state[1]))
     step = {k: (v[:, 0] if v.ndim > 1 else v) for k, v in a.items()}
-    _, s1 = ssm.ssd_step(**step, mask=jnp.asarray([0.0, 1.0]), state=state)
+    _, s1 = ssd_step(**step, mask=jnp.asarray([0.0, 1.0]), state=state)
     np.testing.assert_array_equal(np.asarray(s1[0]), np.asarray(state[0]))
     # the convolution's tail: kept where the call holds no valid column,
     # the last K - 1 inputs (pads as zeros) where it does
@@ -279,8 +254,8 @@ def long_carry(state_dtype, seed, T=320, T0=256):
         S = S * np.exp(d[:, t] * A)[..., None, None] + (d[:, t][..., None] * x[:, t])[..., None] * B[:, t][:, None, None, :]
         want.append((S * C[:, t][:, None, None, :]).sum(-1) + x[:, t])
     cut = lambda lo, hi: {n: (v[:, lo:hi] if v.ndim > 1 else v) for n, v in a.items()}
-    _, state = ssm.ssd_scan(**cut(0, T0), mask=jnp.ones((1, T0)), state=jnp.zeros((1, H, P, N)), chunk=64)
-    step, got = jax.jit(ssm.ssd_step), []
+    _, state = ssd_scan(**cut(0, T0), mask=jnp.ones((1, T0)), state=jnp.zeros((1, H, P, N)), chunk=64)
+    step, got = ssd_step, []
     for t in range(T0, T):
         now = {n: (v[:, t] if v.ndim > 1 else v) for n, v in a.items()}
         y, state = step(**now, mask=jnp.ones((1,)), state=state.astype(state_dtype))
@@ -318,7 +293,7 @@ def test_call_columns_reads_validity_and_freshness_from_the_cache_mask():
 
 def test_the_uncached_scan_is_differentiable():
     a = scan_inputs(T=8)
-    loss = lambda x: ssm.ssd_scan(x, a["dt"], a["A"], a["B"], a["C"], a["D"], jnp.ones((2, 8)),
+    loss = lambda x: ssd_scan(x, a["dt"], a["A"], a["B"], a["C"], a["D"], jnp.ones((2, 8)),
                                   jnp.zeros((2, 4, 8, 16)), 4)[0].sum()
     g = jax.grad(loss)(a["x"])
     assert np.isfinite(np.asarray(g)).all() and float(jnp.abs(g).sum()) > 0
@@ -432,9 +407,9 @@ def test_the_shares_add_up_to_the_uncut_layer():
 def test_the_model_halves_compose_to_the_uncut_model_layer():
     """The same through the model: one block with experts 0-3, one with 4-7
     (same other weights), against the reference holding all 8."""
-    cfg_a, model_a, params = model_and_params(num_hidden_layers=1, layer_types=("mamba",))
-    cfg_b = GraniteMoeHybridConfig.from_dict(
-        dict(ARCH, num_hidden_layers=1, layer_types=("mamba",), first_local_expert=4))
+    over = dict(num_hidden_layers=1, layer_types=("mamba",))
+    cfg_a, model_a, params = model_and_params(FAMILY, **over)
+    cfg_b = GraniteMoeHybridConfig.from_dict(dict(ARCH, **over, first_local_expert=4))
     ids, mask = left_padded([9, 4], 9, seed=3)
     other = jax.tree_util.tree_map(lambda a: a, params)
     keys = jax.random.split(jax.random.PRNGKey(7), 3)
@@ -444,100 +419,19 @@ def test_the_model_halves_compose_to_the_uncut_model_layer():
     for name in ("w_gate", "w_up", "w_down"):
         whole["h_0"]["mlp"][name] = jnp.concatenate([params["h_0"]["mlp"][name], other["h_0"]["mlp"][name]])
     rc = reference_cfg(cfg_a, num_hidden_layers=1, layer_types=["mamba"])
-    ref_whole = ref.trunk(whole, dict(rc, num_local_experts=8), ids, mask)
-    ref_a = ref.trunk(params, rc, ids, mask)
-    ref_b = ref.trunk(other, dict(rc, first_local_expert=4), ids, mask)
-    got_a = model_a.apply({"params": params}, ids, attention_mask=mask)["hidden"]
-    got_b = GraniteMoeHybridModel(cfg_b).apply({"params": other}, ids, attention_mask=mask)["hidden"]
-    assert rel_err(got_a, ref_a, mask) < 1e-5 and rel_err(got_b, ref_b, mask) < 1e-5
+    trunk = lambda cfg: jax.jit(lambda p: ref.trunk(p, cfg, ids, mask))
+    hidden = lambda model: jax.jit(lambda p: model.apply({"params": p}, ids, attention_mask=mask)["hidden"])
+    ref_whole = trunk(dict(rc, num_local_experts=8))(whole)
+    ref_a = trunk(rc)(params)
+    ref_b = trunk(dict(rc, first_local_expert=4))(other)
+    got_a = hidden(model_a)(params)
+    got_b = hidden(GraniteMoeHybridModel(cfg_b))(other)
+    assert rel_err(got_a, ref_a, mask) < TOL and rel_err(got_b, ref_b, mask) < TOL
     # the halves differ, and neither is the whole: the absent experts' terms are left out
     assert rel_err(ref_a, ref_whole, mask) > 1e-3 and rel_err(ref_b, ref_whole, mask) > 1e-3
 
 
 # ------------------------------ the engine ------------------------------ #
-
-Q, R, EOS = 16, 6, 95
-
-
-@functools.lru_cache(maxsize=None)
-def engine(prefill_chunk=0, chunks_per_pump=0):
-    from trlx_tpu.inference.engine import ContinuousBatchingEngine
-    from trlx_tpu.models.heads import CausalLMWithValueHead
-    from trlx_tpu.ops.sampling import GenerationConfig
-
-    cfg, _, _ = model_and_params()
-    model = CausalLMWithValueHead(cfg, backbone_cls=GraniteMoeHybridModel)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    params = dict(params, transformer=model_and_params()[2])
-
-    def apply_fn(p, input_ids, attention_mask=None, position_ids=None, cache=None,
-                 cache_index=None, last_only=False):
-        return model.apply({"params": p}, input_ids, attention_mask=attention_mask,
-                           position_ids=position_ids, cache=cache, cache_index=cache_index,
-                           last_only=last_only)
-
-    gen = GenerationConfig(max_new_tokens=R, min_new_tokens=1, eos_token_id=EOS,
-                           pad_token_id=EOS, do_sample=True)
-    eng = ContinuousBatchingEngine(
-        apply_fn=apply_fn, init_cache_fn=functools.partial(init_granite_hybrid_cache, cfg),
-        gen_config=gen, query_length=Q, vocab_size=cfg.vocab_size, num_slots=4, admit_width=2,
-        harvest_width=2, block_size=4, prefill_chunk=prefill_chunk,
-        prefill_chunks_per_pump=chunks_per_pump,
-    )
-    return eng, params
-
-
-def drive(eng, params, ids, mask, pump):
-    eng.start_phase(params, jax.random.PRNGKey(5))
-    got = {}
-
-    def land(group):
-        arrs = {k: np.asarray(group[k]) for k in ("tokens", "response_mask", "logprobs")}
-        for j, r in enumerate(group["rows"]):
-            got[r] = {k: v[j] for k, v in arrs.items()}
-
-    if not pump:
-        eng.submit(ids, mask)
-        for group in eng.drive(len(ids)):
-            land(group)
-        return got
-    fed = 0
-    while len(got) < len(ids):
-        free = eng.free_capacity
-        if fed < len(ids) and free > 0:
-            take = min(free, eng.admit_width, len(ids) - fed)
-            eng.submit(ids[fed : fed + take], mask[fed : fed + take])
-            fed += take
-        for group in eng.pump():
-            land(group)
-    return got
-
-
-@pytest.mark.parametrize("chunk,pump", [(0, False), (4, False), (4, True)],
-                         ids=["whole", "chunked", "chunk-a-pump"])
-def test_engine_logprobs_match_the_uncached_forward_on_the_tokens_it_drew(chunk, pump):
-    """Ten requests through four slots: every slot is recycled, after
-    requests of other lengths (the longest first), with whole and chunked
-    admission. The recorded log-probability of every drawn token is the
-    reference's on [prompt; drawn tokens]."""
-    eng, params = engine(chunk, 1 if pump else 0)
-    cfg = model_and_params()[0]
-    lens = [16, 15, 3, 9, 2, 12, 5, 16, 4, 7]
-    ids, mask = left_padded(lens, Q, seed=4)
-    ids, mask = np.asarray(ids), np.asarray(mask)
-    got = drive(eng, params, ids, mask, pump)
-    assert sorted(got) == list(range(len(lens)))
-    for r, row in got.items():
-        full_ids = jnp.asarray(np.r_[ids[r], row["tokens"]])[None]
-        full_mask = jnp.asarray(np.r_[mask[r], row["response_mask"]])[None]
-        logits = ref.forward(params["transformer"], reference_cfg(cfg), full_ids, full_mask)[0]
-        lp = jax.nn.log_softmax(logits[Q - 1 : -1], axis=-1)
-        want = np.take_along_axis(np.asarray(lp), row["tokens"][:, None], axis=1)[:, 0]
-        live = row["response_mask"].astype(bool)
-        np.testing.assert_allclose(row["logprobs"][live], want[live], rtol=0, atol=2e-5)
-    if chunk:
-        assert eng.stats.prefill_cols_skipped > 0  # all-pad chunks were not computed
-
 
 @pytest.mark.parametrize("program", ["prefill", "prefill_chunk"])
 def test_an_admission_leaves_every_other_slots_keys_and_state_as_they_were(program):
@@ -547,50 +441,14 @@ def test_an_admission_leaves_every_other_slots_keys_and_state_as_they_were(progr
     running group, two steps in) and the idle slot 2 read bit for bit what
     they read before slot 3 and a dummy are admitted, and slot 3 holds the
     keys and the state of its prompt alone."""
-    import dataclasses
-
-    eng, params = engine(4, 1)
-    cfg, _, backbone = model_and_params()
-    state = eng.init_state()
-    ids0, mask0 = left_padded([9, 16], Q, seed=1)
-    key = jax.random.PRNGKey(5)
-    state = eng.prefill_jit(params, state, jnp.asarray([0, 1], jnp.int32), ids0, mask0,
-                            jnp.asarray([7, 8], jnp.int32), jnp.asarray([1, 3], jnp.int32), key)
-    for _ in range(2):
-        state = eng.decode_step_jit(params, state)[0]
-    before = jax.device_get(jax.tree_util.tree_map(jnp.array, state))
-
-    slot_ids = jnp.asarray([3, eng.num_slots], jnp.int32)
-    turns = jnp.asarray([2, 4], jnp.int32)
-    ids, mask = left_padded([13, 6], Q, seed=2)
-    rows = jnp.arange(2, dtype=jnp.int32)
-    if program == "prefill":
-        state = eng.prefill_jit(params, state, slot_ids, ids, mask, rows, turns, key)
-    else:
-        for c in range(Q // 4):
-            state = eng.prefill_chunk_jit(params, state, slot_ids, ids, mask, rows, turns, key,
-                                          jnp.asarray(c, jnp.int32))
-    after = jax.device_get(state)
-
-    others = [0, 1, 2]
-    for was, now in zip(before.cache, after.cache):
-        for k in was:
-            np.testing.assert_array_equal(np.asarray(now[k])[others], np.asarray(was[k])[others], err_msg=k)
-    for f in dataclasses.fields(before):
-        if f.name != "cache":
-            np.testing.assert_array_equal(np.asarray(getattr(after, f.name))[others],
-                                          np.asarray(getattr(before, f.name))[others], err_msg=f.name)
+    eng, params = engine(FAMILY, 4, 1)
+    cfg, _, backbone = model_and_params(FAMILY)
+    _, after, ids, mask = admit_beside_a_running_group(eng, params, program)
     # slot 3 against the same prompt through a dense cache of one row
     dense = init_granite_hybrid_cache(cfg, 1, eng.capacity)
     cache_mask = jnp.concatenate([mask[:1], jnp.zeros((1, R), mask.dtype)], axis=1)
-    want = GraniteMoeHybridModel(cfg).apply(
-        {"params": backbone}, ids[:1], attention_mask=cache_mask,
-        position_ids=jnp.clip(jnp.cumsum(mask[:1], axis=-1) - 1, 0, None), cache=dense, cache_index=0,
-    )["cache"]
-    nb, bs = eng.n_blocks, eng.block_size
-    table = (np.arange(nb) + 2) % nb
-    real = np.flatnonzero(np.asarray(mask[0]))
-    phys = table[real // bs] * bs + real % bs
+    want = programs(FAMILY)[1](backbone, ids[:1], cache_mask, dense, 0, positions_of(mask[:1]))["cache"]
+    table, real, phys = slot_3_rows(eng, mask)
     for now, ref_layer in zip(after.cache, want):
         if cache_kind(now).layout == STATE:
             for k in ref_layer:
@@ -606,7 +464,7 @@ def test_engine_refuses_what_a_state_layer_cannot_give():
     from trlx_tpu.inference.engine import ContinuousBatchingEngine
     from trlx_tpu.ops.sampling import GenerationConfig
 
-    cfg = model_and_params()[0]
+    cfg = model_and_params(FAMILY)[0]
     common = dict(
         apply_fn=lambda *a, **k: None, init_cache_fn=functools.partial(init_granite_hybrid_cache, cfg),
         gen_config=GenerationConfig(max_new_tokens=4), query_length=8, vocab_size=96, num_slots=2,
@@ -628,7 +486,7 @@ def test_engine_refuses_what_a_state_layer_cannot_give():
 def test_the_fixed_sampler_refuses_state_layers_by_name():
     from trlx_tpu.ops.sampling import GenerationConfig, make_sampler
 
-    cfg = model_and_params()[0]
+    cfg = model_and_params(FAMILY)[0]
     sampler = make_sampler(
         lambda *a, **k: None, functools.partial(init_granite_hybrid_cache, cfg),
         GenerationConfig(max_new_tokens=4), 8, with_values=False,
@@ -652,34 +510,3 @@ def test_the_fused_read_takes_a_scale_over_an_int8_cache_as_the_generic_read_doe
     got, new_kv = decode_attention(q, k_new, v_new, decode_kv_layout(cache), at, bias, scale=0.2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
     assert new_kv["k"].dtype == jnp.int8 and new_kv["k_scale"].shape == (B, H, C)
-
-
-def test_which_paths_the_engines_programs_traced():
-    """Counted per traced call site: the decode step reads its one KV layer
-    as stored (``paged``) and steps its three state layers; an admission
-    program scans them and addresses its group's rows inside the whole
-    pool (``paged_rows``), none left under ``generic``."""
-    from trlx_tpu import telemetry
-
-    eng, params = engine.__wrapped__(4, 1)  # its own: a program traced before counts nothing again
-    cfg = model_and_params()[0]
-    with telemetry.scoped_metrics() as reg:
-        state = jax.eval_shape(eng._make_state)
-        abstract = jax.eval_shape(lambda: params)
-        step = eng.decode_step_jit.lower(abstract, state)
-        after_step = dict(reg.snapshot()["counters"])
-        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-        chunk = eng.prefill_chunk_jit.lower(abstract, state, i32(2), i32(2, Q), i32(2, Q), i32(2), i32(2),
-                                            jax.ShapeDtypeStruct((2,), jnp.uint32), i32())
-        after_chunk = reg.snapshot()["counters"]
-    # the device scopes docs/observability.md names are in the lowered programs
-    step_text, chunk_text = step.as_text(debug_info=True), chunk.as_text(debug_info=True)
-    for scope in ("ssm_in_proj", "ssm_conv", "ssm_step", "ssm_out", "moe_shared", "moe_experts"):
-        assert scope in step_text, scope
-    assert "ssm_scan" in chunk_text and "ssm_scan" not in step_text and "ssm_step" not in chunk_text
-    n_state = cfg.layer_types.count("mamba")
-    assert after_step["ssm/path{path=step}"] == n_state and "ssm/path{path=scan}" not in after_step
-    assert after_step["attention/decode_path{path=paged}"] == 1
-    assert after_chunk["ssm/path{path=scan}"] == n_state
-    assert after_chunk["attention/decode_path{path=paged_rows}"] == 1
-    assert "attention/decode_path{path=generic}" not in after_chunk

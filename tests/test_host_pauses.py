@@ -440,29 +440,41 @@ def _serve(server, n, seed, on_step=None):
 
 def test_a_slow_token_sink_is_named_as_engine_route_while_the_ledger_reads_zero(
         server, monkeypatch, capsys):
+    """The stall is planted where the detector sees it whatever else the
+    host is doing: three times the running level of the step class (80 ms
+    at the least: beside busy neighbours an iteration's level reads
+    hundreds of ms, and 80 over it trips nothing), in the first sink call
+    past the eleventh that lies outside the cooldown of a stall the host
+    made on its own (inside one a trip is counted and leaves no event)."""
     with telemetry.scoped_tracer() as tracer, telemetry.scoped_metrics() as registry:
         monkeypatch.setattr(server, "_registry", registry)
         _serve(server, 8, seed=0)  # programs built, levels warm
         before = len(server.health_events)
-        sink, calls = server._router.on_tokens, []
+        steps = server._stall_series[0]  # iterations that met no admission forward
+        sink, calls, planted_ms = server._router.on_tokens, [], []
 
         def slow(emitted):
             calls.append(None)
-            if len(calls) == 12:
-                time.sleep(0.08)
+            if not planted_ms and len(calls) >= 12 and steps.count >= steps.quiet_until:
+                planted_ms.append(max(80.0, 3.0 * steps.level))
+                time.sleep(planted_ms[0] / 1e3)
             return sink(emitted)
 
         monkeypatch.setattr(server._router, "on_tokens", slow)
         registry.clear()
-        _serve(server, 8, seed=1)
+        _serve(server, 16, seed=1)  # two waves through the eight slots: room for the plant after a cooldown
+        (stall_ms,) = planted_ms
         events = server.health_events[before:]
         assert {e.detector for e in events} == {"host-stall"}
         # (a busy CPU may stall an iteration of its own accord: the planted one is the sink's)
-        (planted,) = [e for e in events if "; engine/route +" in e.message]
-        assert planted.phase is None and planted.value >= 80.0
+        (planted,) = [e for e in events if "; engine/route +" in e.message and e.value >= stall_ms]
+        assert planted.phase is None
         counters = _counters(registry)
-        assert counters["host/stalls[by=engine/route]"] == 1.0
-        assert counters["host/stall_ms[by=engine/route]"] == pytest.approx(80.0, abs=15.0)
+        assert counters["host/stalls[by=engine/route]"] >= 1.0
+        if counters["host/stalls[by=engine/route]"] == 1.0:
+            # the excess over the level is the sink's sleep, give or take what an iteration lies off its level
+            assert counters["host/stall_ms[by=engine/route]"] == pytest.approx(
+                stall_ms, abs=max(15.0, planted.baseline))
         # the stall ended inside the step in flight: no drained chip was seen
         starved = registry.snapshot()["histograms"]["serve/starved_ms"]
         assert starved["max"] < 40.0

@@ -10,7 +10,9 @@ row.
 Everything is compared on logits (never sampled tokens) with the plain float32
 reference ``benchmark/reference/ling.py``, which runs the rule a position at a
 time, the latent attention decompressed and every held expert on every token:
-it shares no code with ops/delta.py, ops/attention.py or ops/moe.py.
+it shares no code with ops/delta.py, ops/attention.py or ops/moe.py. Programs,
+engine and the tests every family is held to come from
+``tests/family_harness.py``.
 """
 
 import functools
@@ -22,6 +24,24 @@ import jax
 import jax.numpy as jnp
 
 from benchmark.reference import ling as ref
+from family_harness import (  # noqa: F401  (the contract tests run here, on FAMILY)
+    Family,
+    engine,
+    grow,
+    left_padded,
+    model_and_params,
+    paged,
+    positions_of,
+    programs,
+    refused,
+    rel_err,
+    test_a_parked_row_keeps_its_state_and_a_fresh_row_forgets_the_slot,
+    test_engine_logprobs_match_the_uncached_forward_on_the_tokens_it_drew,
+    test_registry_builds_the_family_and_its_cache_by_kind,
+    test_uncached_forward_matches_the_reference_on_left_padded_rows,
+    test_what_the_family_does_not_build_is_refused_by_name,
+    test_which_paths_the_engines_programs_traced,
+)
 from trlx_tpu.models.deepseek_v3 import DeepseekV3MLP, DeepseekV3SparseMLP
 from trlx_tpu.models.ling import KDA, LATENT, LingConfig, LingLatentAttention, LingModel, init_ling_cache
 from trlx_tpu.ops import delta
@@ -34,7 +54,6 @@ from trlx_tpu.ops.kv_cache import (
     hold_pool,
     hybrid_cache,
     identity_block_tables,
-    rotate_block_table,
 )
 
 ARCH = dict(
@@ -45,6 +64,7 @@ ARCH = dict(
     num_experts_per_tok=4, n_group=4, topk_group=2,
     dtype="float32", param_dtype="float32",
 )
+TOL = 3e-5  # float32 arithmetic on both sides, 24 positions through seven blocks: rounding alone reads 7e-6
 
 
 def reference_cfg(cfg: LingConfig, **over):
@@ -55,139 +75,91 @@ def reference_cfg(cfg: LingConfig, **over):
     )
 
 
-@functools.lru_cache(maxsize=None)
-def model_and_params(**over):
-    cfg = LingConfig.from_dict(dict(ARCH, **over))
-    model = LingModel(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    # move the ones- and zeros-initialised vectors (norm scales, the selection bias) off their defaults
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
-    leaves = [a + 0.1 * jax.random.normal(k, a.shape) for a, k in zip(leaves, keys)]
-    return cfg, model, jax.tree_util.tree_unflatten(tree, leaves)
-
-
-def left_padded(lens, T, seed=0, vocab=95):
-    rng = np.random.default_rng(seed)
-    ids = jnp.asarray(rng.integers(0, vocab, (len(lens), T)), jnp.int32)
-    mask = jnp.asarray(np.stack([np.r_[np.zeros(T - n), np.ones(n)] for n in lens]), jnp.int32)
-    return ids, mask
-
-
-def rel_err(got, want, where):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    where = np.asarray(where).astype(bool)
-    return np.abs(got - want)[where].max() / want[where].std()
-
-
-# ------------------------------ the model ------------------------------ #
-
-
-def test_uncached_forward_matches_the_reference_on_left_padded_rows():
-    cfg, model, params = model_and_params()
+def check_forward(cfg, params, out):
     assert cfg.layer_types == (KDA,) * 5 + (LATENT, KDA)
     assert float(jnp.abs(params["h_1"]["mlp"]["router_bias"]).max()) > 0  # the selection bias is not zero
     assert set(params["h_0"]["mlp"]) == {"gate_proj", "up_proj", "down_proj"} and "shared" not in params["h_0"]
-    ids, mask = left_padded([21, 13, 5], 21)
-    out = model.apply({"params": params}, ids, attention_mask=mask)
-    want = ref.forward(params, reference_cfg(cfg), ids, mask)
-    assert rel_err(out["logits"], want, mask) < 3e-5
     stats = out["moe_stats"]
     assert set(stats) == {"experts_touched", "max_load", "rows_routed", "rows_here_share"}
     assert float(stats["experts_touched"]) <= 4 and 0 < float(stats["rows_here_share"]) < 1
 
 
-@pytest.mark.parametrize("what", ["gate", "solve"])
-def test_bfloat16_where_float32_is_stated_fails_the_tolerance(what, monkeypatch):
-    """The 3e-5 the comparisons above are held to (float32 arithmetic on both
-    sides, 24 positions through seven blocks: rounding alone reads 7e-6) is
-    tight enough that the gate or the solve computed in bfloat16 fails it by
-    more than ten times; the state's type is held by
-    ``test_a_long_carry_holds_the_state_to_float32``."""
-    cfg, model, params = model_and_params()
-    ids, mask = left_padded([21, 13, 5], 21)
-    want = ref.forward(params, reference_cfg(cfg), ids, mask)
-    bf16 = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)
-    if what == "solve":
-        inverse = delta.unit_lower_inverse
-        monkeypatch.setattr(delta, "unit_lower_inverse", lambda A: bf16(inverse(bf16(A))))
-    else:
-        chunked = delta.kda_chunk
-        monkeypatch.setattr(delta, "kda_chunk", lambda q, k, v, g, *rest: chunked(q, k, v, bf16(g), *rest))
-    out = model.apply({"params": params}, ids, attention_mask=mask)
-    assert rel_err(out["logits"], want, mask) > 10 * 3e-5
+def refuse_more(cfg, model, params):
+    from trlx_tpu.models import gpt2_moe
+    from trlx_tpu.parallel.mesh import make_mesh, traced_on
+
+    # the limit lists as published: zeros for the blocks the cut keeps, a clamp past them
+    LingConfig.from_dict(dict(ARCH, expert_swiglu_limit_list=[0] * 7 + [4] * 3))
+    ids = jnp.zeros((2, 2), jnp.int32)
+    apply = functools.partial(model.apply, {"params": params}, ids)
+    refused("verify", apply, attention_mask=jnp.ones((2, 8), jnp.int32),
+            cache=init_ling_cache(cfg, 2, 8), cache_index=jnp.zeros((2, 2), jnp.int32))
+    for hook in ({"start_layer": 1}, {"hidden_override": jnp.zeros((2, 2, 64))}, {"capture_hidden_at": 1}):
+        refused("hydra branch .* is not built for ling", apply, **hook)
+    # a latent layer's cache that is not paged (ops/attention.py::decode_attention)
+    refused("paged", apply, attention_mask=jnp.ones((2, 8), jnp.int32), cache=init_ling_cache(cfg, 2, 8), cache_index=0)
+    gpt2_moe.set_ep_mesh(jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("ep",)))
+    try:
+        refused("a ep mesh is not built for ling", apply)
+    finally:
+        gpt2_moe.reset()
+    for axis in ("tp", "ep", "pp"):
+        mesh = make_mesh({"dp": 1, "fsdp": 1, "tp": 1, axis: 2}, devices=jax.devices()[:2])
+        refused(f"a {axis} mesh is not built for ling", traced_on(mesh, apply))
+    dp = make_mesh({"dp": 2, "fsdp": 1, "tp": 1}, devices=jax.devices()[:2])
+    jax.eval_shape(traced_on(dp, apply))  # data axes shard nothing of the model
 
 
-@pytest.mark.parametrize("chunk", [0, 4], ids=["whole", "chunked"])
-def test_admission_then_decode_through_states_and_a_paged_latent_pool_matches_the_full_forward(chunk):
-    """An admission of 16 columns (whole, or in chunks of 4 that carry the
-    state and the tail from call to call) then five steps, the latent layer
-    through a paged pool whose second row's blocks are rotated and whose
-    rows are held padded (``hold_pool``), the KDA layers through their
-    state: logits against the reference's full forward."""
-    cfg, model, params = model_and_params()
-    T, Q, cap = 21, 16, 24
-    ids, mask = left_padded([21, 13, 6], T, seed=1)
-    want = ref.forward(params, reference_cfg(cfg), ids, mask)
-    tables = identity_block_tables(3, cap // 4)
-    tables = tables.at[1].set(rotate_block_table(tables[1], 2))
-    cache = tuple(
-        c if cache_kind(c).layout == STATE else dict(hold_pool(c), block_tables=tables)
-        for c in init_ling_cache(cfg, 3, cap)
-    )
-    assert [cache_kind(c).layout for c in cache] == [STATE] * 5 + [PAGED, STATE]
-    assert cache_kind(cache[5]).latent and cache[5]["k"].shape == (3, cap, 1, 128)  # 32 -> whole lanes
-    grow = lambda m: jnp.concatenate([m, jnp.zeros((3, cap - m.shape[1]), jnp.int32)], axis=1)
-    positions = jnp.clip(jnp.cumsum(mask, axis=-1) - 1, 0, None)
-    for lo in range(0, Q, chunk or Q):
-        hi = lo + (chunk or Q)
-        out = model.apply({"params": params}, ids[:, lo:hi], attention_mask=grow(mask[:, :Q]),
-                          position_ids=positions[:, lo:hi], cache=cache, cache_index=lo)
-        cache = out["cache"]
-        assert rel_err(out["logits"], want[:, lo:hi], mask[:, lo:hi]) < 3e-5
-    for t in range(Q, T):
-        out = model.apply({"params": params}, ids[:, t : t + 1], attention_mask=grow(mask[:, : t + 1]),
-                          position_ids=positions[:, t : t + 1], cache=cache,
-                          cache_index=jnp.full((3,), t, jnp.int32))
-        cache = out["cache"]
-        assert rel_err(out["logits"][:, 0], want[:, t], mask[:, t]) < 3e-5
-    assert cache[5]["k"].shape[-1] == 128 and "v" not in cache[5]
+def check_registry(family, cfg, cache):
+    from trlx_tpu.models.registry import get_model_family
+    from trlx_tpu.trainer import BaseRLTrainer
+
+    assert get_model_family("bailing_hybrid") is family  # the published model_type
+    assert [cache_kind(c).latent for c in cache] == [False] * 5 + [True, False]
+    assert set(cache[5]) == {"k"} and cache[5]["k"].shape == (2, 8, 1, 32)  # one row [c | k_r] a position
+    # [B, heads, key size, value size] and the tail over [q | k | v]
+    assert cache[0]["ssm_state"].shape == (2, 4, 16, 16) and cache[0]["conv_tail"].shape == (2, 3, 192)
+    assert cache[0]["ssm_state"].dtype == cache[0]["conv_tail"].dtype == jnp.float32
+    assert not family.supports_ep and family.stored_width_leaves == ("conv_weight",)
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("ep",))
+    with pytest.raises(NotImplementedError, match="'ling' has no experts to shard"):
+        BaseRLTrainer.setup_ep_axis(None, mesh, family)
+    with pytest.raises(ValueError, match="no checkpoint converter"):
+        family.load_checkpoint("somewhere")
+    # the published keys alone derive the pattern: every sixth block latent, two leading dense blocks
+    whole = family.config_cls()
+    assert whole.layer_types.count(LATENT) == 7 and whole.layer_types[5] == whole.layer_types[41] == LATENT
+    assert (whole.conv_channels, whole.latent_width, whole.num_router_experts) == (12288, 576, 512)
+    assert (whole.qk_head_dim, whole.kda_lower_bound) == (192, -5.0)
+    assert delta.kda_sub_chunk(whole.kda_lower_bound) == 16
 
 
-def test_a_parked_row_keeps_its_state_and_a_fresh_row_forgets_the_slot():
-    """The engine's two conventions as the model reads them from the cache
-    mask: a row whose ``cache_index`` is past the mask's width (idle or
-    finished) leaves state and tail bit for bit; a row with no valid column
-    before the call starts from zeros whatever the slot held (a recycled
-    slot)."""
-    cfg, model, params = model_and_params()
-    cap = 12
-    ids, mask = left_padded([8, 8], 8, seed=2)
-    grow = lambda m: jnp.concatenate([m, jnp.zeros((2, cap - m.shape[1]), jnp.int32)], axis=1)
-    tables = identity_block_tables(2, cap // 4)
-    clean = tuple(
-        c if cache_kind(c).layout == STATE else dict(c, block_tables=tables)
-        for c in init_ling_cache(cfg, 2, cap)
-    )
-    dirty = tuple(
-        {k: jnp.ones_like(v) * 3 for k, v in c.items()} if cache_kind(c).layout == STATE else c
-        for c in clean
-    )
-    a = model.apply({"params": params}, ids, attention_mask=grow(mask), cache=dirty, cache_index=0)
-    b = model.apply({"params": params}, ids, attention_mask=grow(mask), cache=clean, cache_index=0)
-    np.testing.assert_array_equal(np.asarray(a["logits"]), np.asarray(b["logits"]))
-    step_mask = grow(jnp.ones((2, 9), jnp.int32))
-    out = model.apply({"params": params}, ids[:, :1], attention_mask=step_mask, cache=a["cache"],
-                      cache_index=jnp.asarray([8, cap], jnp.int32))
-    for before, after in zip(a["cache"], out["cache"]):
-        if cache_kind(before).layout == STATE:
-            for k in before:
-                np.testing.assert_array_equal(np.asarray(before[k][1]), np.asarray(after[k][1]))
-                assert not np.array_equal(np.asarray(before[k][0]), np.asarray(after[k][0]))
+def check_paths(t):
+    """The decode step reads its one latent layer as stored (``paged``,
+    absorbed) and steps its six state layers; an admission program runs the
+    chunked form in row blocks and addresses its group's rows inside the
+    whole pool (``paged_rows``)."""
+    for scope in ("kda_in_proj", "kda_conv", "kda_gate", "kda_out", "mla_q", "mla_kv_down",
+                  "moe_group_router", "moe_shared", "moe_experts"):
+        assert scope in t.step_text and scope in t.chunk_text, scope
+    assert "kda_step" in t.step_text and "kda_chunk" not in t.step_text
+    assert "kda_chunk" in t.chunk_text and "kda_step" not in t.chunk_text
+    assert "mla_absorbed_read" in t.step_text and "mla_decompress" in t.chunk_text
+    n_state = t.cfg.layer_types.count(KDA)
+    assert t.after_step["kda/path{path=step}"] == n_state and "kda/path{path=chunk}" not in t.after_step
+    assert t.after_step["attention/decode_path{path=paged}"] == 1
+    assert t.counters["kda/path{path=chunk}"] == n_state
+    assert t.counters["attention/decode_path{path=paged_rows}"] == 1
+    # the row block the traced chunked form took: the bound's 16 of a chunk of 64
+    assert t.gauges["kda/sub_chunk"] == 16
+    assert "gdn/path{path=step}" not in t.after_step and "gdn/path{path=chunk}" not in t.counters
 
 
-def test_what_the_family_does_not_build_is_refused_by_name():
-    for over, said in [
+FAMILY = Family(
+    name="ling", config_cls=LingConfig, model_cls=LingModel, reference=ref, arch=ARCH,
+    reference_cfg=reference_cfg, init_cache=init_ling_cache, tol=TOL, logprob_tol=3e-5,
+    cache_layouts=[STATE] * 5 + [DENSE, STATE],
+    refusals={"ling": [
         ({"rope_scaling": {"type": "yarn", "factor": 4}}, "rope_scaling"),
         ({"q_lora_rank": 1536}, "q_lora_rank=1536"),
         ({"use_mla_nope": True}, "use_mla_nope"),
@@ -214,66 +186,64 @@ def test_what_the_family_does_not_build_is_refused_by_name():
         ({"rotary_dim": 16}, "rotary_dim"),
         ({"kda_lower_bound": 0.0}, "kda_lower_bound"),
         ({"num_experts": 14}, "not among the router's 16"),
-    ]:
-        with pytest.raises(ValueError, match=said):
-            LingConfig.from_dict(dict(ARCH, **over))
-    # the limit lists as published: zeros for the blocks the cut keeps, a clamp past them
-    LingConfig.from_dict(dict(ARCH, expert_swiglu_limit_list=[0] * 7 + [4] * 3))
-    cfg, model, params = model_and_params()
-    ids = jnp.zeros((2, 2), jnp.int32)
-    with pytest.raises(ValueError, match="verify"):
-        model.apply({"params": params}, ids, attention_mask=jnp.ones((2, 8), jnp.int32),
-                    cache=init_ling_cache(cfg, 2, 8), cache_index=jnp.zeros((2, 2), jnp.int32))
-    for hook in ({"start_layer": 1}, {"hidden_override": jnp.zeros((2, 2, 64))}, {"capture_hidden_at": 1}):
-        with pytest.raises(ValueError, match="hydra branch .* is not built for ling"):
-            model.apply({"params": params}, ids, **hook)
-    # a latent layer's cache that is not paged (ops/attention.py::decode_attention)
-    with pytest.raises(ValueError, match="paged"):
-        model.apply({"params": params}, ids, attention_mask=jnp.ones((2, 8), jnp.int32),
-                    cache=init_ling_cache(cfg, 2, 8), cache_index=0)
-    from trlx_tpu.models import gpt2_moe
-    from trlx_tpu.parallel.mesh import make_mesh, traced_on
-
-    gpt2_moe.set_ep_mesh(jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("ep",)))
-    try:
-        with pytest.raises(ValueError, match="a ep mesh is not built for ling"):
-            model.apply({"params": params}, ids)
-    finally:
-        gpt2_moe.reset()
-    for axis in ("tp", "ep", "pp"):
-        mesh = make_mesh({"dp": 1, "fsdp": 1, "tp": 1, axis: 2}, devices=jax.devices()[:2])
-        with pytest.raises(ValueError, match=f"a {axis} mesh is not built for ling"):
-            traced_on(mesh, lambda: model.apply({"params": params}, ids))()
-    dp = make_mesh({"dp": 2, "fsdp": 1, "tp": 1}, devices=jax.devices()[:2])
-    traced_on(dp, lambda: model.apply({"params": params}, ids))()  # data axes shard nothing of the model
+    ]},
+    engine_cases={"whole": (0, False, {}), "chunked": (4, False, {}), "chunk-a-pump": (4, True, {})},
+    check_forward=check_forward, check_paths=check_paths, check_registry=check_registry, refuse_more=refuse_more,
+)
 
 
-def test_registry_builds_the_family_and_its_cache_by_kind():
-    from trlx_tpu.models.registry import get_model_family
-    from trlx_tpu.trainer import BaseRLTrainer
+# ------------------------------ the model ------------------------------ #
 
-    family = get_model_family("ling")
-    assert get_model_family("bailing_hybrid") is family  # the published model_type
-    cfg = family.config_cls.from_dict(ARCH)
-    cache = family.init_cache(cfg, 2, 8)
-    assert [cache_kind(c).layout for c in cache] == [STATE] * 5 + [DENSE, STATE]
-    assert [cache_kind(c).latent for c in cache] == [False] * 5 + [True, False]
-    assert set(cache[5]) == {"k"} and cache[5]["k"].shape == (2, 8, 1, 32)  # one row [c | k_r] a position
-    # [B, heads, key size, value size] and the tail over [q | k | v]
-    assert cache[0]["ssm_state"].shape == (2, 4, 16, 16) and cache[0]["conv_tail"].shape == (2, 3, 192)
-    assert cache[0]["ssm_state"].dtype == cache[0]["conv_tail"].dtype == jnp.float32
-    assert not family.supports_ep and family.stored_width_leaves == ("conv_weight",)
-    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("ep",))
-    with pytest.raises(NotImplementedError, match="'ling' has no experts to shard"):
-        BaseRLTrainer.setup_ep_axis(None, mesh, family)
-    with pytest.raises(ValueError, match="no checkpoint converter"):
-        family.load_checkpoint("somewhere")
-    # the published keys alone derive the pattern: every sixth block latent, two leading dense blocks
-    whole = family.config_cls()
-    assert whole.layer_types.count(LATENT) == 7 and whole.layer_types[5] == whole.layer_types[41] == LATENT
-    assert (whole.conv_channels, whole.latent_width, whole.num_router_experts) == (12288, 576, 512)
-    assert (whole.qk_head_dim, whole.kda_lower_bound) == (192, -5.0)
-    assert delta.kda_sub_chunk(whole.kda_lower_bound) == 16
+
+@pytest.mark.parametrize("what", ["gate", "solve"])
+def test_bfloat16_where_float32_is_stated_fails_the_tolerance(what, monkeypatch):
+    """The 3e-5 the comparisons are held to (float32 arithmetic on both
+    sides, 24 positions through seven blocks: rounding alone reads 7e-6) is
+    tight enough that the gate or the solve computed in bfloat16 fails it by
+    more than ten times; the state's type is held by
+    ``test_a_long_carry_holds_the_state_to_float32``."""
+    cfg, model, params = model_and_params(FAMILY)
+    ids, mask = left_padded([21, 13, 5], 21)
+    want = programs(FAMILY)[2](params, ids, mask)
+    bf16 = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)
+    if what == "solve":
+        inverse = delta.unit_lower_inverse
+        monkeypatch.setattr(delta, "unit_lower_inverse", lambda A: bf16(inverse(bf16(A))))
+    else:
+        chunked = delta.kda_chunk
+        monkeypatch.setattr(delta, "kda_chunk", lambda q, k, v, g, *rest: chunked(q, k, v, bf16(g), *rest))
+    out = jax.jit(lambda p: model.apply({"params": p}, ids, attention_mask=mask))(params)  # traced under the patch
+    assert rel_err(out["logits"], want, mask) > 10 * TOL
+
+
+@pytest.mark.parametrize("chunk", [0, 4], ids=["whole", "chunked"])
+def test_admission_then_decode_through_states_and_a_paged_latent_pool_matches_the_full_forward(chunk):
+    """An admission of 16 columns (whole, or in chunks of 4 that carry the
+    state and the tail from call to call) then five steps, the latent layer
+    through a paged pool whose second row's blocks are rotated and whose
+    rows are held padded (``hold_pool``), the KDA layers through their
+    state: logits against the reference's full forward."""
+    cfg, model, params = model_and_params(FAMILY)
+    T, Q, cap = 21, 16, 24
+    ids, mask = left_padded([21, 13, 6], T, seed=1)
+    _, cached, reference = programs(FAMILY)
+    want = reference(params, ids, mask)
+    cache = paged(FAMILY, cfg, 3, cap, rotate=1, hold=True)
+    assert [cache_kind(c).layout for c in cache] == [STATE] * 5 + [PAGED, STATE]
+    assert cache_kind(cache[5]).latent and cache[5]["k"].shape == (3, cap, 1, 128)  # 32 -> whole lanes
+    positions = positions_of(mask)
+    for lo in range(0, Q, chunk or Q):
+        hi = lo + (chunk or Q)
+        out = cached(params, ids[:, lo:hi], grow(mask[:, :Q], cap), cache, jnp.asarray(lo) if chunk else 0,
+                     positions[:, lo:hi])
+        cache = out["cache"]
+        assert rel_err(out["logits"], want[:, lo:hi], mask[:, lo:hi]) < TOL
+    for t in range(Q, T):
+        out = cached(params, ids[:, t : t + 1], grow(mask[:, : t + 1], cap), cache, jnp.full((3,), t, jnp.int32),
+                     positions[:, t : t + 1])
+        cache = out["cache"]
+        assert rel_err(out["logits"][:, 0], want[:, t], mask[:, t]) < TOL
+    assert cache[5]["k"].shape[-1] == 128 and "v" not in cache[5]
 
 
 def test_hybrid_cache_with_a_latent_layer_among_state_layers():
@@ -308,6 +278,13 @@ def test_hybrid_cache_with_a_latent_layer_among_state_layers():
 # ---------------------------- ops/delta.py ------------------------------ #
 
 
+# the rule's forms as one program a shape (tests/family_harness.py says why); the mixer tests patch `delta` and stay eager
+kda_chunk = jax.jit(delta.kda_chunk, static_argnames=("chunk", "sub_chunk"))
+kda_step = jax.jit(delta.kda_step)
+gated_delta_chunk = jax.jit(delta.gated_delta_chunk, static_argnames=("chunk",))
+gated_delta_step = jax.jit(delta.gated_delta_step)
+
+
 def rule_inputs(B=2, T=24, H=3, Dk=8, Dv=16, seed=0, dtype=jnp.float32, floor=None):
     """``g`` a vector a head in (-5, 0), spread over the whole range; with
     ``floor`` every channel of every column at that value."""
@@ -337,7 +314,7 @@ def recurrence(q, k, v, g, beta, state):
 def steps(a, state, lo=0, hi=None):
     outs = []
     for t in range(lo, a["q"].shape[1] if hi is None else hi):
-        o, state = delta.kda_step(*(a[n][:, t] for n in ("q", "k", "v", "g", "beta")), state)
+        o, state = kda_step(*(a[n][:, t] for n in ("q", "k", "v", "g", "beta")), state)
         outs.append(o)
     return jnp.stack(outs, axis=1), state
 
@@ -350,7 +327,7 @@ def test_the_chunked_form_the_step_and_the_recurrence_agree_on_mixed_gates(chunk
     a = rule_inputs()
     state = jax.random.normal(jax.random.PRNGKey(9), (2, 3, 8, 16))
     want_o, want_s = recurrence(**a, state=state)
-    o, s = delta.kda_chunk(**a, state=state, chunk=chunk, sub_chunk=sub)
+    o, s = kda_chunk(**a, state=state, chunk=chunk, sub_chunk=sub)
     np.testing.assert_allclose(np.asarray(o), want_o, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(s), want_s, rtol=2e-5, atol=2e-5)
     o, s = steps(a, state)
@@ -367,11 +344,11 @@ def test_every_column_at_the_floor_stays_in_float32(T):
     a = rule_inputs(T=T, floor=-5.0)
     state = jax.random.normal(jax.random.PRNGKey(9), (2, 3, 8, 16))
     want_o, want_s = recurrence(**a, state=state)
-    o, s = delta.kda_chunk(**a, state=state, chunk=64, sub_chunk=delta.kda_sub_chunk(-5.0))
+    o, s = kda_chunk(**a, state=state, chunk=64, sub_chunk=delta.kda_sub_chunk(-5.0))
     assert np.isfinite(np.asarray(o)).all() and np.isfinite(np.asarray(s)).all()
     np.testing.assert_allclose(np.asarray(o), want_o, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(s), want_s, rtol=1e-5, atol=1e-6)
-    whole_o, _ = delta.kda_chunk(**a, state=state, chunk=64, sub_chunk=64)  # one reference a chunk
+    whole_o, _ = kda_chunk(**a, state=state, chunk=64, sub_chunk=64)  # one reference a chunk
     assert not np.isfinite(np.asarray(whole_o)).all()
 
 
@@ -382,7 +359,7 @@ def test_the_row_block_follows_the_bound():
         delta.kda_sub_chunk(0.0)
     a = rule_inputs(T=8)
     with pytest.raises(ValueError, match="power of two"):
-        delta.kda_chunk(**a, state=jnp.zeros((2, 3, 8, 16)), chunk=8, sub_chunk=3)
+        kda_chunk(**a, state=jnp.zeros((2, 3, 8, 16)), chunk=8, sub_chunk=3)
 
 
 def test_a_gate_constant_over_a_heads_channels_is_the_scalar_rule():
@@ -392,12 +369,12 @@ def test_a_gate_constant_over_a_heads_channels_is_the_scalar_rule():
     state = jax.random.normal(jax.random.PRNGKey(3), (2, 3, 8, 16))
     scalar = a["g"][..., 0]
     same = dict(a, g=jnp.broadcast_to(scalar[..., None], a["g"].shape))
-    o1, s1 = delta.kda_chunk(**same, state=state, chunk=16, sub_chunk=4)
-    o2, s2 = delta.gated_delta_chunk(a["q"], a["k"], a["v"], scalar, a["beta"], state, chunk=16)
+    o1, s1 = kda_chunk(**same, state=state, chunk=16, sub_chunk=4)
+    o2, s2 = gated_delta_chunk(a["q"], a["k"], a["v"], scalar, a["beta"], state, chunk=16)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), rtol=1e-5, atol=1e-5)
-    o3, s3 = delta.kda_step(*(same[n][:, 0] for n in ("q", "k", "v", "g", "beta")), state)
-    o4, s4 = delta.gated_delta_step(a["q"][:, 0], a["k"][:, 0], a["v"][:, 0], scalar[:, 0], a["beta"][:, 0], state)
+    o3, s3 = kda_step(*(same[n][:, 0] for n in ("q", "k", "v", "g", "beta")), state)
+    o4, s4 = gated_delta_step(a["q"][:, 0], a["k"][:, 0], a["v"][:, 0], scalar[:, 0], a["beta"][:, 0], state)
     np.testing.assert_allclose(np.asarray(o3), np.asarray(o4), rtol=1e-6, atol=1e-6)
     np.testing.assert_allclose(np.asarray(s3), np.asarray(s4), rtol=1e-6, atol=1e-6)
 
@@ -405,10 +382,10 @@ def test_a_gate_constant_over_a_heads_channels_is_the_scalar_rule():
 def test_two_calls_that_carry_the_state_equal_one_and_steps_go_on_from_a_chunk():
     a = rule_inputs(T=16)
     zero = jnp.zeros((2, 3, 8, 16))
-    whole_o, whole_s = delta.kda_chunk(**a, state=zero, chunk=4, sub_chunk=2)
+    whole_o, whole_s = kda_chunk(**a, state=zero, chunk=4, sub_chunk=2)
     cut = lambda lo, hi: {n: v[:, lo:hi] for n, v in a.items()}
-    o1, s1 = delta.kda_chunk(**cut(0, 10), state=zero, chunk=4, sub_chunk=2)  # a call that ends inside a chunk
-    o2, s2 = delta.kda_chunk(**cut(10, 16), state=s1, chunk=4, sub_chunk=2)
+    o1, s1 = kda_chunk(**cut(0, 10), state=zero, chunk=4, sub_chunk=2)  # a call that ends inside a chunk
+    o2, s2 = kda_chunk(**cut(10, 16), state=s1, chunk=4, sub_chunk=2)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([o1, o2], 1)), np.asarray(whole_o), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(s2), np.asarray(whole_s), rtol=1e-5, atol=1e-5)
     o3, s3 = steps(a, s1, 10)  # a prefill, then decode steps, against the full sequence
@@ -430,10 +407,10 @@ def test_a_masked_column_leaves_the_state_bit_for_bit():
     state = jax.random.normal(jax.random.PRNGKey(3), (2, 3, 8, 16))
     mask = jnp.asarray([[0] * 8, [0, 0, 0, 1, 1, 1, 1, 1]], jnp.float32)
     masked = dict(a, g=a["g"] * mask[..., None, None], beta=a["beta"] * mask[..., None])
-    _, s = delta.kda_chunk(**masked, state=state, chunk=4, sub_chunk=2)
+    _, s = kda_chunk(**masked, state=state, chunk=4, sub_chunk=2)
     np.testing.assert_array_equal(np.asarray(s[0]), np.asarray(state[0]))
     assert not np.allclose(np.asarray(s[1]), np.asarray(state[1]))
-    _, s1 = delta.kda_step(*(masked[n][:, 0] for n in ("q", "k", "v", "g", "beta")), state)
+    _, s1 = kda_step(*(masked[n][:, 0] for n in ("q", "k", "v", "g", "beta")), state)
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(state))  # column 0 is masked in both rows
     keys = jax.random.split(jax.random.PRNGKey(4), 6)
     H, D = 4, 16
@@ -498,8 +475,8 @@ def long_carry(state_dtype, seed, T=320, T0=256):
     zero = jnp.zeros((1, H, Dk, Dv))
     want_o, want_s = recurrence(**a, state=zero)
     cut = {n: v[:, :T0] for n, v in a.items()}
-    _, state = delta.kda_chunk(**cut, state=zero, chunk=64, sub_chunk=16)
-    step, got = jax.jit(delta.kda_step), []
+    _, state = kda_chunk(**cut, state=zero, chunk=64, sub_chunk=16)
+    step, got = kda_step, []
     for t in range(T0, T):
         o, state = step(*(a[n][:, t] for n in ("q", "k", "v", "g", "beta")), state.astype(state_dtype))
         got.append(np.asarray(o, np.float64))
@@ -513,7 +490,7 @@ def test_a_long_carry_holds_the_state_to_float32(seed):
     (20)) is held here: the state a cache allocates is float32, and over a
     carry of 320 positions it stays within limits that the same ops with
     the state rounded to bfloat16 between calls do not keep."""
-    cfg = model_and_params()[0]
+    cfg = model_and_params(FAMILY)[0]
     allocated = init_ling_cache(cfg, 1, 8)[0]["ssm_state"].dtype
     assert allocated == jnp.float32
     o_err, s_err = long_carry(allocated, seed)
@@ -524,7 +501,7 @@ def test_a_long_carry_holds_the_state_to_float32(seed):
 
 def test_the_uncached_chunked_form_is_differentiable():
     a = rule_inputs(T=8)
-    loss = lambda v: delta.kda_chunk(a["q"], a["k"], v, a["g"], a["beta"], jnp.zeros((2, 3, 8, 16)), 4, 2)[0].sum()
+    loss = lambda v: kda_chunk(a["q"], a["k"], v, a["g"], a["beta"], jnp.zeros((2, 3, 8, 16)), 4, 2)[0].sum()
     g = jax.grad(loss)(a["v"])
     assert np.isfinite(np.asarray(g)).all() and float(jnp.abs(g).sum()) > 0
 
@@ -583,7 +560,7 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer()
     and the shared expert, counted once, give what the reference computes
     for the whole layer (all 16 held) through the family's own modules."""
     over = dict(num_hidden_layers=2, n_group=8, topk_group=4, num_experts=2, first_local_expert=0)
-    cfg0, model0, params0 = model_and_params(**over)
+    cfg0, model0, params0 = model_and_params(FAMILY, **over)
     ids, mask = left_padded([9, 4], 9, seed=3)
     keys = jax.random.split(jax.random.PRNGKey(7), 3)
     all_experts = {n: 0.1 * jax.random.normal(k, (16,) + params0["h_1"]["mlp"][n].shape[1:])
@@ -610,23 +587,27 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer()
             w = ref.router_weights(h, blk["mlp"], cf)
             return h, ref.held_experts(h, blk["mlp"], w[..., first : first + held]), ref.swiglu(h, blk["shared"])
 
-    h, whole_routed, shared = moe_terms(with_experts(0, 16), 0, 16)
+    h, whole_routed, shared = jax.jit(moe_terms, static_argnums=(1, 2))(with_experts(0, 16), 0, 16)
     parts = []
     for first in range(0, 16, 2):
         cfg = LingConfig.from_dict(dict(ARCH, **dict(over, first_local_expert=first)))
         tree = with_experts(first, 2)
-        term = DeepseekV3MLP(cfg, cfg.moe_shared_expert_intermediate_size, f32_out=True).apply(
-            {"params": tree["h_1"]["shared"]}, h)
+        @jax.jit
+        def this_share(tree, cfg=cfg, first=first):  # the family's own modules and the reference, one program a share
+            term = DeepseekV3MLP(cfg, cfg.moe_shared_expert_intermediate_size, f32_out=True).apply(
+                {"params": tree["h_1"]["shared"]}, h)
+            with_shared, stats = DeepseekV3SparseMLP(cfg).apply({"params": tree["h_1"]["mlp"]}, h, term)
+            without, _ = DeepseekV3SparseMLP(cfg).apply({"params": tree["h_1"]["mlp"]}, h, None)
+            got = LingModel(cfg).apply({"params": tree}, ids, attention_mask=mask)["hidden"]
+            return term, with_shared, without, stats, got, ref.trunk(tree, dict(rc, first_local_expert=first), ids, mask)
+
+        term, with_shared, without, stats, got, want = this_share(tree)
         np.testing.assert_allclose(np.asarray(term), np.asarray(shared), rtol=2e-5, atol=2e-6)
-        with_shared, stats = DeepseekV3SparseMLP(cfg).apply({"params": tree["h_1"]["mlp"]}, h, term)
-        without, _ = DeepseekV3SparseMLP(cfg).apply({"params": tree["h_1"]["mlp"]}, h, None)
         np.testing.assert_allclose(np.asarray(with_shared - without), np.asarray(shared), rtol=1e-4, atol=1e-5)
         parts.append(without)
         assert 0 <= float(stats["rows_here_share"]) < 1 and float(stats["experts_touched"]) <= 2
         # and the model's own forward with this share is the reference's with the same share
-        got = LingModel(cfg).apply({"params": tree}, ids, attention_mask=mask)["hidden"]
-        want = ref.trunk(tree, dict(rc, first_local_expert=first), ids, mask)
-        assert rel_err(got, want, mask) < 3e-5
+        assert rel_err(got, want, mask) < TOL
     total = sum(parts) + shared  # the shared expert counted once
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole_routed + shared), rtol=2e-4, atol=2e-5)
     # no share is the whole: the absent experts' terms are left out
@@ -635,90 +616,6 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer()
 
 # ------------------------------ the engine ------------------------------ #
 
-Q, R, EOS = 16, 6, 95
-
-
-@functools.lru_cache(maxsize=None)
-def engine(prefill_chunk=0, chunks_per_pump=0):
-    from trlx_tpu.inference.engine import ContinuousBatchingEngine
-    from trlx_tpu.models.heads import CausalLMWithValueHead
-    from trlx_tpu.ops.sampling import GenerationConfig
-
-    cfg, _, _ = model_and_params()
-    model = CausalLMWithValueHead(cfg, backbone_cls=LingModel)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    params = dict(params, transformer=model_and_params()[2])
-
-    def apply_fn(p, input_ids, attention_mask=None, position_ids=None, cache=None,
-                 cache_index=None, last_only=False):
-        return model.apply({"params": p}, input_ids, attention_mask=attention_mask,
-                           position_ids=position_ids, cache=cache, cache_index=cache_index,
-                           last_only=last_only)
-
-    gen = GenerationConfig(max_new_tokens=R, min_new_tokens=1, eos_token_id=EOS,
-                           pad_token_id=EOS, do_sample=True)
-    eng = ContinuousBatchingEngine(
-        apply_fn=apply_fn, init_cache_fn=functools.partial(init_ling_cache, cfg),
-        gen_config=gen, query_length=Q, vocab_size=cfg.vocab_size, num_slots=4, admit_width=2,
-        harvest_width=2, block_size=4, prefill_chunk=prefill_chunk,
-        prefill_chunks_per_pump=chunks_per_pump,
-    )
-    return eng, params
-
-
-def drive(eng, params, ids, mask, pump):
-    eng.start_phase(params, jax.random.PRNGKey(5))
-    got = {}
-
-    def land(group):
-        arrs = {k: np.asarray(group[k]) for k in ("tokens", "response_mask", "logprobs")}
-        for j, r in enumerate(group["rows"]):
-            got[r] = {k: v[j] for k, v in arrs.items()}
-
-    if not pump:
-        eng.submit(ids, mask)
-        for group in eng.drive(len(ids)):
-            land(group)
-        return got
-    fed = 0
-    while len(got) < len(ids):
-        free = eng.free_capacity
-        if fed < len(ids) and free > 0:
-            take = min(free, eng.admit_width, len(ids) - fed)
-            eng.submit(ids[fed : fed + take], mask[fed : fed + take])
-            fed += take
-        for group in eng.pump():
-            land(group)
-    return got
-
-
-@pytest.mark.parametrize("chunk,pump", [(0, False), (4, False), (4, True)],
-                         ids=["whole", "chunked", "chunk-a-pump"])
-def test_engine_logprobs_match_the_uncached_forward_on_the_tokens_it_drew(chunk, pump):
-    """Ten requests through four slots: every slot is recycled, after
-    requests of other lengths (the longest first), with whole and chunked
-    admission: the states are zeroed at recycle and the latent pool's block
-    tables rotated. The recorded log-probability of every drawn token is
-    the reference's on [prompt; drawn tokens]."""
-    eng, params = engine(chunk, 1 if pump else 0)
-    cfg = model_and_params()[0]
-    lens = [16, 15, 3, 9, 2, 12, 5, 16, 4, 7]
-    ids, mask = left_padded(lens, Q, seed=4)
-    ids, mask = np.asarray(ids), np.asarray(mask)
-    got = drive(eng, params, ids, mask, pump)
-    assert sorted(got) == list(range(len(lens)))
-    forward = jax.jit(lambda p, i, m: ref.forward(p, reference_cfg(cfg), i, m))
-    for r, row in got.items():
-        full_ids = jnp.asarray(np.r_[ids[r], row["tokens"]])[None]
-        full_mask = jnp.asarray(np.r_[mask[r], row["response_mask"]])[None]
-        logits = forward(params["transformer"], full_ids, full_mask)[0]
-        lp = jax.nn.log_softmax(logits[Q - 1 : -1], axis=-1)
-        want = np.take_along_axis(np.asarray(lp), row["tokens"][:, None], axis=1)[:, 0]
-        live = row["response_mask"].astype(bool)
-        np.testing.assert_allclose(row["logprobs"][live], want[live], rtol=0, atol=3e-5)
-    if chunk:
-        assert eng.stats.prefill_cols_skipped > 0  # all-pad chunks were not computed
-
 
 def test_engine_and_fixed_sampler_refuse_what_a_state_and_a_latent_row_cannot_give():
     from trlx_tpu import telemetry
@@ -726,7 +623,7 @@ def test_engine_and_fixed_sampler_refuse_what_a_state_and_a_latent_row_cannot_gi
     from trlx_tpu.ops.sampling import GenerationConfig, make_sampler
     from trlx_tpu.parallel.mesh import make_mesh
 
-    cfg = model_and_params()[0]
+    cfg = model_and_params(FAMILY)[0]
     init = functools.partial(init_ling_cache, cfg)
     common = dict(apply_fn=lambda *a, **k: None, init_cache_fn=init,
                   gen_config=GenerationConfig(max_new_tokens=4), query_length=8, vocab_size=96, num_slots=2)
@@ -762,47 +659,8 @@ def test_the_engines_state_holds_the_latent_pool_pinned_beside_the_states():
     one latent pool among six state layers, its rows whole lanes."""
     from trlx_tpu import telemetry
 
-    eng, params = engine.__wrapped__(4, 1)
+    eng, params = engine.__wrapped__(FAMILY, 4, 1)
     eng.start_phase(params, jax.random.PRNGKey(5))
     gauges = telemetry.get_metrics().snapshot()["gauges"]
     assert gauges["cache/latent_pinned_share"] == 1.0
     assert gauges["cache/state_gb"] > 0 and gauges["cache/latent_gb"] > 0
-
-
-def test_which_paths_the_engines_programs_traced():
-    """Counted per traced call site: the decode step reads its one latent
-    layer as stored (``paged``, absorbed) and steps its six state layers;
-    an admission program runs the chunked form in row blocks and addresses
-    its group's rows inside the whole pool (``paged_rows``), none left
-    under ``generic``; the device scopes docs/observability.md names are in
-    the lowered programs."""
-    from trlx_tpu import telemetry
-
-    eng, params = engine.__wrapped__(4, 1)  # its own: a program traced before counts nothing again
-    cfg = model_and_params()[0]
-    with telemetry.scoped_metrics() as reg:
-        state = jax.eval_shape(eng._make_state)
-        abstract = jax.eval_shape(lambda: params)
-        step = eng.decode_step_jit.lower(abstract, state)
-        after_step = dict(reg.snapshot()["counters"])
-        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-        chunk = eng.prefill_chunk_jit.lower(abstract, state, i32(2), i32(2, Q), i32(2, Q), i32(2), i32(2),
-                                            jax.ShapeDtypeStruct((2,), jnp.uint32), i32())
-        after_chunk = reg.snapshot()
-    step_text, chunk_text = step.as_text(debug_info=True), chunk.as_text(debug_info=True)
-    for scope in ("kda_in_proj", "kda_conv", "kda_gate", "kda_out", "mla_q", "mla_kv_down",
-                  "moe_group_router", "moe_shared", "moe_experts"):
-        assert scope in step_text and scope in chunk_text, scope
-    assert "kda_step" in step_text and "kda_chunk" not in step_text
-    assert "kda_chunk" in chunk_text and "kda_step" not in chunk_text
-    assert "mla_absorbed_read" in step_text and "mla_decompress" in chunk_text
-    n_state = cfg.layer_types.count(KDA)
-    assert after_step["kda/path{path=step}"] == n_state and "kda/path{path=chunk}" not in after_step
-    assert after_step["attention/decode_path{path=paged}"] == 1
-    counters = after_chunk["counters"]
-    assert counters["kda/path{path=chunk}"] == n_state
-    assert counters["attention/decode_path{path=paged_rows}"] == 1
-    assert "attention/decode_path{path=generic}" not in counters
-    # the row block the traced chunked form took: the bound's 16 of a chunk of 64
-    assert after_chunk["gauges"]["kda/sub_chunk"] == 16
-    assert "gdn/path{path=step}" not in after_step and "gdn/path{path=chunk}" not in counters

@@ -22,6 +22,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 from benchmark.reference import olmoe as reference  # noqa: E402
+from family_harness import (  # noqa: E402, F401  (the contract test runs here, on FAMILY)
+    Family,
+    test_what_the_family_does_not_build_is_refused_by_name,
+)
 from trlx_tpu.models.olmoe import OlmoeConfig, OlmoeModel  # noqa: E402
 from trlx_tpu.ops import moe  # noqa: E402
 from trlx_tpu.telemetry.health import without_timing  # noqa: E402
@@ -33,24 +37,44 @@ ARCH = dict(
 )
 MASK = np.array([[0] * 4 + [1] * 8, [1] * 12, [0] * 7 + [1] * 5], np.int32)
 IDS = np.random.default_rng(1).integers(0, 95, MASK.shape).astype(np.int32)
+FAMILY = Family(
+    name="olmoe", config_cls=OlmoeConfig, model_cls=OlmoeModel, arch=ARCH,
+    refusals={key: [({key: value}, key)] for key, value in [
+        ("num_key_value_heads", 2), ("clip_qkv", 8.0), ("rope_scaling", {"type": "linear", "factor": 2}),
+        ("tie_word_embeddings", True), ("hidden_act", "gelu"),
+    ]},
+)
 
 
 def build(dtype="float32", seed=0, expert_scale=15, **arch):
     cfg = OlmoeConfig.from_dict(dict(ARCH, dtype=dtype, param_dtype="float32", **arch))
     model = OlmoeModel(cfg)
-    params = model.init(jax.random.PRNGKey(seed), IDS, MASK)["params"]
-    # norm scales away from 1, routing away from uniform and the experts'
-    # output as large as the residual stream, so that an error in any shows
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-    params = jax.tree_util.tree_map(
-        lambda a: a * (1 + 0.1 * jax.random.normal(next(keys), a.shape)) if a.ndim == 1
-        else a * (3, 3, expert_scale)[a.ndim - 1],
-        params,
-    )
-    return model, params
+
+    def seeded(key, noise):
+        params = model.init(key, IDS, MASK)["params"]
+        # norm scales away from 1, routing away from uniform and the experts'
+        # output as large as the residual stream, so that an error in any shows
+        keys = iter(jax.random.split(noise, 64))
+        return jax.tree_util.tree_map(
+            lambda a: a * (1 + 0.1 * jax.random.normal(next(keys), a.shape)) if a.ndim == 1
+            else a * (3, 3, expert_scale)[a.ndim - 1],
+            params,
+        )
+
+    return model, jax.jit(seeded)(jax.random.PRNGKey(seed), jax.random.PRNGKey(seed + 1))
 
 
-def rel_err(got, want, where=None):
+def logits_of(model, params, ids=IDS, mask=MASK):
+    """One jitted program a call (tests/family_harness.py says why); a test
+    that patches an op gets its trace after the patch."""
+    return jax.jit(lambda p: model.apply({"params": p}, ids, mask)["logits"])(params)
+
+
+def reference_logits(params, cfg=ARCH, ids=IDS, mask=MASK):
+    return jax.jit(lambda p: reference.forward(p, cfg, ids, mask))(params)
+
+
+def peak_err(got, want, where=None):
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
     if where is not None:
         got, want = got[where], want[where]
@@ -70,16 +94,13 @@ def f32():
 
 def test_logits_match_reference_on_left_padded_rows(f32):
     model, params = f32
-    got = model.apply({"params": params}, IDS, MASK)["logits"]
-    want = reference.forward(params, ARCH, IDS, MASK)
-    assert rel_err(got, want, REAL) <= 1e-4
+    assert peak_err(logits_of(model, params), reference_logits(params), REAL) <= 1e-4
 
 
 def test_norm_topk_prob_matches_reference():
     model, params = build(norm_topk_prob=True)
-    got = model.apply({"params": params}, IDS, MASK)["logits"]
-    want = reference.forward(params, dict(ARCH, norm_topk_prob=True), IDS, MASK)
-    assert rel_err(got, want, REAL) <= 1e-4
+    want = reference_logits(params, dict(ARCH, norm_topk_prob=True))
+    assert peak_err(logits_of(model, params), want, REAL) <= 1e-4
 
 
 # 2 ------------------------------------------------------------------------- #
@@ -104,7 +125,7 @@ def grads(f32):
         return ppo_like_loss(*reference.forward_with_aux(p, ARCH, IDS, MASK))
 
     flat = lambda g: {jax.tree_util.keystr(k): v for k, v in jax.tree_util.tree_leaves_with_path(g)}
-    return flat(jax.grad(through_program)(params)), flat(jax.grad(through_reference)(params))
+    return flat(jax.jit(jax.grad(through_program))(params)), flat(jax.jit(jax.grad(through_reference))(params))
 
 
 LEAVES = sorted(
@@ -118,7 +139,7 @@ LEAVES = sorted(
 @pytest.mark.parametrize("leaf", LEAVES)
 def test_gradient_matches_reference(grads, leaf):
     got, want = grads
-    assert rel_err(got[leaf], want[leaf]) <= 1e-3
+    assert peak_err(got[leaf], want[leaf]) <= 1e-3
 
 
 def test_unrouted_expert_has_exactly_zero_gradient():
@@ -135,7 +156,7 @@ def test_unrouted_expert_has_exactly_zero_gradient():
         assert routing.experts.shape == (18, 2)
         return jnp.sum(y ** 2)
 
-    for g in jax.grad(loss, argnums=(0, 1, 2))(*weights):
+    for g in jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(*weights):
         g = np.asarray(g)
         assert not g[7].any() and g[:7].any()
 
@@ -313,9 +334,8 @@ def test_every_token_on_one_expert_is_not_dropped(f32):
         # expert 2 wins everywhere, expert 5 is everyone's second
         rigged[f"h_{i}"]["mlp"]["router"] = r.at[:, 2].set(4.0).at[:, 5].set(2.0)
     # h W_r has the sign of sum(h): make the choice independent of it
-    out = model.apply({"params": rigged}, IDS, MASK)
-    want = reference.forward(rigged, ARCH, IDS, MASK)
-    assert rel_err(out["logits"], want, REAL) <= 1e-4
+    out = jax.jit(lambda p: model.apply({"params": p}, IDS, MASK))(rigged)
+    assert peak_err(out["logits"], reference_logits(rigged), REAL) <= 1e-4
     assert float(out["moe_stats"]["experts_touched"]) <= 4
 
 
@@ -346,8 +366,8 @@ def test_bf16_compute_stays_inside_the_benchmark_tolerance():
     # at the scale of an initialisation: with experts as large as the
     # residual stream a tiny top-2-of-8 router flips on bf16 near-ties
     model, params = build(dtype="bfloat16", expert_scale=3)
-    got = model.apply({"params": params}, IDS, MASK)["logits"]
-    want = np.asarray(reference.forward(params, ARCH, IDS, MASK))
+    got = logits_of(model, params)
+    want = np.asarray(reference_logits(params))
     rms, mx = checks.error_stats(got, want, float(want[REAL].std()), REAL)
     assert rms <= tol["logits_rms_rel"] and mx <= tol["logits_max_rel"]
     assert rms > 1e-4  # and bf16 is not float32: the comparison sees it
@@ -364,9 +384,7 @@ def test_a_bf16_router_softmax_fails_the_float32_tolerance(f32, monkeypatch):
                            experts.astype(jnp.int32))
 
     monkeypatch.setattr(moe, "route", bf16_route)
-    got = model.apply({"params": params}, IDS, MASK)["logits"]
-    want = reference.forward(params, ARCH, IDS, MASK)
-    assert rel_err(got, want, REAL) > 1e-4
+    assert peak_err(logits_of(model, params), reference_logits(params), REAL) > 1e-4
 
 
 # 6 ------------------------------------------------------------------------- #
@@ -392,7 +410,7 @@ def test_experts_over_ep_match_one_rank(f32, ep):
     try:
         (l2, logits2), g2 = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
         # a decode step's row count need not divide the data shards
-        one = model.apply({"params": params}, ids[:3, :1], None)["logits"]
+        one = logits_of(model, params, ids[:3, :1], None)
     finally:
         gpt2_moe.set_ep_mesh(None)
     np.testing.assert_allclose(np.asarray(logits2), np.asarray(logits1), atol=1e-4, rtol=1e-4)
@@ -400,8 +418,7 @@ def test_experts_over_ep_match_one_rank(f32, ep):
     f2, _ = jax.flatten_util.ravel_pytree(g2)
     np.testing.assert_allclose(np.asarray(f2), np.asarray(f1), atol=1e-5, rtol=1e-3)
     np.testing.assert_allclose(
-        np.asarray(one), np.asarray(model.apply({"params": params}, ids[:3, :1], None)["logits"]),
-        atol=1e-4, rtol=1e-4)
+        np.asarray(one), np.asarray(logits_of(model, params, ids[:3, :1], None)), atol=1e-4, rtol=1e-4)
 
 
 def test_partition_rules_put_experts_on_ep_and_attention_on_tp(f32):
@@ -445,21 +462,11 @@ def test_transformers_checkpoint_loads_to_equal_logits(tmp_path):
     ids = np.random.default_rng(2).integers(0, 211, size=(2, 11))
     with torch.no_grad():
         want = hf(input_ids=torch.tensor(ids)).logits.numpy()
-    got = OlmoeModel(config).apply({"params": params}, jnp.asarray(ids))["logits"]
-    assert rel_err(got, want) <= 1e-4
+    ids = jnp.asarray(ids)
+    assert peak_err(logits_of(OlmoeModel(config), params, ids, None), want) <= 1e-4
     # and the reference's equations are the ones transformers computes
-    cfg = dict(ARCH, vocab_size=211)
-    ref = reference.forward(params, cfg, jnp.asarray(ids), jnp.ones_like(ids))
-    assert rel_err(ref, want) <= 1e-4
-
-
-@pytest.mark.parametrize("key,value", [
-    ("num_key_value_heads", 2), ("clip_qkv", 8.0), ("rope_scaling", {"type": "linear", "factor": 2}),
-    ("tie_word_embeddings", True), ("hidden_act", "gelu"),
-])
-def test_what_the_family_does_not_build_is_refused_by_name(key, value):
-    with pytest.raises(ValueError, match=key):
-        OlmoeConfig.from_dict(dict(ARCH, **{key: value}))
+    ref = reference_logits(params, dict(ARCH, vocab_size=211), ids, jnp.ones_like(ids))
+    assert peak_err(ref, want) <= 1e-4
 
 
 def test_pipeline_parallel_refuses_the_family():

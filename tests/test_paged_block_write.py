@@ -367,10 +367,12 @@ def _family_engine(family):
     own test files. Q 16; a chunk of 4 columns, one a pump."""
     if family == "gpt2":
         return _engine("bfloat16", 4, 4), _model("bfloat16")[2]
+    import family_harness
     import test_granite_hybrid
     import test_zaya
 
-    return {"granite": test_granite_hybrid, "zaya": test_zaya}[family].engine.__wrapped__(4, 1)
+    record = {"granite": test_granite_hybrid, "zaya": test_zaya}[family].FAMILY
+    return family_harness.engine.__wrapped__(record, 4, 1)
 
 
 @pytest.mark.parametrize("program", ["prefill", "prefill_chunk"])

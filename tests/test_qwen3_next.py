@@ -8,7 +8,8 @@ with head norms and a partial rotary, a gated shared expert beside a share of
 Everything is compared on logits (never sampled tokens) with the plain
 float32 reference ``benchmark/reference/qwen3_next.py``, which runs the rule
 a position at a time and every held expert on every token: it shares no code
-with ops/delta.py or ops/moe.py.
+with ops/delta.py or ops/moe.py. Programs, engine and the tests every family is
+held to come from ``tests/family_harness.py``.
 """
 
 import functools
@@ -20,6 +21,23 @@ import jax
 import jax.numpy as jnp
 
 from benchmark.reference import qwen3_next as ref
+from family_harness import (  # noqa: F401  (the contract tests run here, on FAMILY)
+    Family,
+    grow,
+    left_padded,
+    model_and_params,
+    paged,
+    positions_of,
+    programs,
+    refused,
+    rel_err,
+    test_a_parked_row_keeps_its_state_and_a_fresh_row_forgets_the_slot,
+    test_engine_logprobs_match_the_uncached_forward_on_the_tokens_it_drew,
+    test_registry_builds_the_family_and_its_cache_by_kind,
+    test_uncached_forward_matches_the_reference_on_left_padded_rows,
+    test_what_the_family_does_not_build_is_refused_by_name,
+    test_which_paths_the_engines_programs_traced,
+)
 from trlx_tpu.models.qwen3_next import (
     FULL,
     LINEAR,
@@ -31,17 +49,7 @@ from trlx_tpu.models.qwen3_next import (
     init_qwen3_next_cache,
 )
 from trlx_tpu.ops import delta
-from trlx_tpu.ops.kv_cache import (
-    DENSE,
-    PAGED,
-    STATE,
-    cache_kind,
-    hybrid_cache,
-    identity_block_tables,
-    hold_pool,
-    rotate_block_table,
-    state_buffers,
-)
+from trlx_tpu.ops.kv_cache import DENSE, PAGED, STATE, cache_kind, hybrid_cache, state_buffers
 
 ARCH = dict(
     vocab_size=96, hidden_size=64, num_hidden_layers=4, full_attention_interval=4,
@@ -52,173 +60,46 @@ ARCH = dict(
     num_router_experts=16, first_local_expert=4, num_experts_per_tok=4,
     dtype="float32", param_dtype="float32",
 )
+TOL = 2e-5
 
 
 def reference_cfg(cfg: Qwen3NextConfig, **over):
     return dict(ARCH, rms_norm_eps=cfg.rms_norm_eps, norm_topk_prob=cfg.norm_topk_prob, **over)
 
 
-@functools.lru_cache(maxsize=None)
-def model_and_params(**over):
-    cfg = Qwen3NextConfig.from_dict(dict(ARCH, **over))
-    model = Qwen3NextModel(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    # move the zero- and ones-initialised vectors (norm offsets, the rule's norm) off their defaults
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
-    leaves = [a + 0.1 * jax.random.normal(k, a.shape) for a, k in zip(leaves, keys)]
-    return cfg, model, jax.tree_util.tree_unflatten(tree, leaves)
-
-
-def left_padded(lens, T, seed=0, vocab=95):
-    rng = np.random.default_rng(seed)
-    ids = jnp.asarray(rng.integers(0, vocab, (len(lens), T)), jnp.int32)
-    mask = jnp.asarray(np.stack([np.r_[np.zeros(T - n), np.ones(n)] for n in lens]), jnp.int32)
-    return ids, mask
-
-
-def rel_err(got, want, where):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    where = np.asarray(where).astype(bool)
-    return np.abs(got - want)[where].max() / want[where].std()
-
-
-# ------------------------------ the model ------------------------------ #
-
-
-def test_uncached_forward_matches_the_reference_on_left_padded_rows():
-    cfg, model, params = model_and_params()
+def check_forward(cfg, params, out):
     assert cfg.layer_types == (LINEAR, LINEAR, LINEAR, FULL)
     assert float(jnp.abs(params["h_0"]["ln_1"]["scale"]).max()) > 0  # the offsets are not zero
-    ids, mask = left_padded([21, 13, 5], 21)
-    out = model.apply({"params": params}, ids, attention_mask=mask)
-    want = ref.forward(params, reference_cfg(cfg), ids, mask)
-    assert rel_err(out["logits"], want, mask) < 2e-5
     stats = out["moe_stats"]
     assert set(stats) == {"experts_touched", "max_load", "rows_routed", "rows_here_share"}
     assert float(stats["experts_touched"]) <= 4 and 0 < float(stats["rows_here_share"]) < 1
 
 
-@pytest.mark.parametrize("head_dim", [16, 256], ids=["heads_of_16", "heads_of_256_held_in_lane_rows"])
-@pytest.mark.parametrize("chunk", [0, 4], ids=["whole", "chunked"])
-def test_admission_then_decode_through_state_and_paged_pool_matches_the_full_forward(chunk, head_dim):
-    """An admission of 16 columns (whole, or in chunks of 4 that carry the
-    state and the tail from call to call) then five steps, the full layer
-    through a paged pool whose second row's blocks are rotated, the linear
-    layers through their state: logits against the reference's full
-    forward. The pool is as its holder keeps it (``hold_pool``): at the
-    published head of 256, each head as two lane rows."""
-    cfg, model, params = model_and_params(head_dim=head_dim)
-    T, Q, cap = 21, 16, 24
-    ids, mask = left_padded([21, 13, 6], T, seed=1)
-    want = ref.forward(params, reference_cfg(cfg, head_dim=head_dim), ids, mask)
-    tables = identity_block_tables(3, cap // 4)
-    tables = tables.at[1].set(rotate_block_table(tables[1], 2))
-    cache = tuple(
-        c if cache_kind(c).layout == STATE else dict(hold_pool(c), block_tables=tables)
-        for c in init_qwen3_next_cache(cfg, 3, cap)
-    )
-    assert [cache_kind(c).layout for c in cache] == [STATE, STATE, STATE, PAGED]
-    assert cache[3]["k"].shape == ((3, cap, 4, 128) if head_dim == 256 else (3, cap, 2, 16))
-    grow = lambda m: jnp.concatenate([m, jnp.zeros((3, cap - m.shape[1]), jnp.int32)], axis=1)
-    positions = jnp.clip(jnp.cumsum(mask, axis=-1) - 1, 0, None)
-    for lo in range(0, Q, chunk or Q):
-        hi = lo + (chunk or Q)
-        out = model.apply({"params": params}, ids[:, lo:hi], attention_mask=grow(mask[:, :Q]),
-                          position_ids=positions[:, lo:hi], cache=cache, cache_index=lo)
-        cache = out["cache"]
-        assert rel_err(out["logits"], want[:, lo:hi], mask[:, lo:hi]) < 2e-5
-    for t in range(Q, T):
-        out = model.apply({"params": params}, ids[:, t : t + 1], attention_mask=grow(mask[:, : t + 1]),
-                          position_ids=positions[:, t : t + 1], cache=cache,
-                          cache_index=jnp.full((3,), t, jnp.int32))
-        cache = out["cache"]
-        assert rel_err(out["logits"][:, 0], want[:, t], mask[:, t]) < 2e-5
-
-
-def test_a_parked_row_keeps_its_state_and_a_fresh_row_forgets_the_slot():
-    """The engine's two conventions as the model reads them from the cache
-    mask: a row whose ``cache_index`` is past the mask's width (idle or
-    finished) leaves state and tail bit for bit; a row with no valid column
-    before the call starts from zeros whatever the slot held (a recycled
-    slot)."""
-    cfg, model, params = model_and_params()
-    cap = 12
-    ids, mask = left_padded([8, 8], 8, seed=2)
-    grow = lambda m: jnp.concatenate([m, jnp.zeros((2, cap - m.shape[1]), jnp.int32)], axis=1)
-    tables = identity_block_tables(2, cap // 4)
-    clean = tuple(
-        c if cache_kind(c).layout == STATE else dict(c, block_tables=tables)
-        for c in init_qwen3_next_cache(cfg, 2, cap)
-    )
-    dirty = tuple(
-        {k: jnp.ones_like(v) * 3 for k, v in c.items()} if cache_kind(c).layout == STATE else c
-        for c in clean
-    )
-    a = model.apply({"params": params}, ids, attention_mask=grow(mask), cache=dirty, cache_index=0)
-    b = model.apply({"params": params}, ids, attention_mask=grow(mask), cache=clean, cache_index=0)
-    np.testing.assert_array_equal(np.asarray(a["logits"]), np.asarray(b["logits"]))
-    step_mask = grow(jnp.ones((2, 9), jnp.int32))
-    out = model.apply({"params": params}, ids[:, :1], attention_mask=step_mask, cache=a["cache"],
-                      cache_index=jnp.asarray([8, cap], jnp.int32))
-    for before, after in zip(a["cache"], out["cache"]):
-        if cache_kind(before).layout == STATE:
-            for k in before:
-                np.testing.assert_array_equal(np.asarray(before[k][1]), np.asarray(after[k][1]))
-                assert not np.array_equal(np.asarray(before[k][0]), np.asarray(after[k][0]))
-
-
-def test_what_the_family_does_not_build_is_refused_by_name():
-    for over, said in [
-        ({"rope_scaling": {"type": "linear", "factor": 2}}, "rope_scaling"),
-        ({"mlp_only_layers": [1]}, "mlp_only_layers"),
-        ({"decoder_sparse_step": 2}, "decoder_sparse_step"),
-        ({"use_sliding_window": True}, "use_sliding_window"),
-        ({"attention_bias": True}, "attention_bias"),
-        ({"tie_word_embeddings": True}, "tie_word_embeddings"),
-        ({"hidden_act": "gelu"}, "hidden_act"),
-        ({"kv_cache_dtype": "int8"}, "kv_cache_dtype='int8' beside state layers"),
-        ({"state_dtype": "bfloat16"}, "state_dtype"),
-        ({"num_experts": 14}, "not among the router's 16"),
-        ({"linear_num_value_heads": 3}, "linear_num_value_heads"),
-        ({"num_key_value_heads": 3}, "num_key_value_heads"),
-        ({"layer_types": ["attention"] * 4}, "layer_types"),
-    ]:
-        with pytest.raises(ValueError, match=said):
-            Qwen3NextConfig.from_dict(dict(ARCH, **over))
-    cfg, model, params = model_and_params()
-    ids = jnp.zeros((2, 2), jnp.int32)
-    with pytest.raises(ValueError, match="verify"):
-        model.apply({"params": params}, ids, attention_mask=jnp.ones((2, 8), jnp.int32),
-                    cache=init_qwen3_next_cache(cfg, 2, 8), cache_index=jnp.zeros((2, 2), jnp.int32))
-    for hook in ({"start_layer": 1}, {"hidden_override": jnp.zeros((2, 2, 64))}, {"capture_hidden_at": 1}):
-        with pytest.raises(ValueError, match="hydra branch .* is not built for qwen3_next"):
-            model.apply({"params": params}, ids, **hook)
+def refuse_more(cfg, model, params):
     from trlx_tpu.models import gpt2_moe
     from trlx_tpu.parallel.mesh import make_mesh, traced_on
 
+    ids = jnp.zeros((2, 2), jnp.int32)
+    apply = functools.partial(model.apply, {"params": params}, ids)
+    refused("verify", apply, attention_mask=jnp.ones((2, 8), jnp.int32),
+            cache=init_qwen3_next_cache(cfg, 2, 8), cache_index=jnp.zeros((2, 2), jnp.int32))
+    for hook in ({"start_layer": 1}, {"hidden_override": jnp.zeros((2, 2, 64))}, {"capture_hidden_at": 1}):
+        refused("hydra branch .* is not built for qwen3_next", apply, **hook)
     gpt2_moe.set_ep_mesh(jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("ep",)))
     try:
-        with pytest.raises(ValueError, match="a ep mesh is not built for qwen3_next"):
-            model.apply({"params": params}, ids)
+        refused("a ep mesh is not built for qwen3_next", apply)
     finally:
         gpt2_moe.reset()
     for axis in ("tp", "ep", "pp"):
         mesh = make_mesh({"dp": 1, "fsdp": 1, "tp": 1, axis: 2}, devices=jax.devices()[:2])
-        with pytest.raises(ValueError, match=f"a {axis} mesh is not built for qwen3_next"):
-            traced_on(mesh, lambda: model.apply({"params": params}, ids))()
+        refused(f"a {axis} mesh is not built for qwen3_next", traced_on(mesh, apply))
     dp = make_mesh({"dp": 2, "fsdp": 1, "tp": 1}, devices=jax.devices()[:2])
-    traced_on(dp, lambda: model.apply({"params": params}, ids))()  # data axes shard nothing of the model
+    jax.eval_shape(traced_on(dp, apply))  # data axes shard nothing of the model
 
 
-def test_registry_builds_the_family_and_its_cache_by_kind():
-    from trlx_tpu.models.registry import get_model_family
+def check_registry(family, cfg, cache):
     from trlx_tpu.trainer import BaseRLTrainer
 
-    family = get_model_family("qwen3_next")
-    cfg = family.config_cls.from_dict(ARCH)
-    cache = family.init_cache(cfg, 2, 8)
-    assert [cache_kind(c).layout for c in cache] == [STATE, STATE, STATE, DENSE]
     assert cache[3]["k"].shape == (2, 8, 2, 16)  # sized by KV heads of head_dim
     # [B, value heads, key size, value size] and the tail over [q | k | v]
     assert cache[0]["ssm_state"].shape == (2, 4, 8, 16) and cache[0]["conv_tail"].shape == (2, 3, 96)
@@ -233,6 +114,93 @@ def test_registry_builds_the_family_and_its_cache_by_kind():
     whole = family.config_cls()
     assert whole.layer_types.count(FULL) == 12 and whole.layer_types[3] == whole.layer_types[47] == FULL
     assert (whole.conv_channels, whole.rotary_dim, whole.num_router_experts) == (8192, 64, 512)
+
+
+def check_engine(eng, over):
+    """At the published head of 256 the engine holds the pool in lane rows
+    (``ops/kv_cache.py::hold_pool``): recycled slots' rotated tables, the
+    block write, the gathered view and the read as stored all go through
+    the held shape."""
+    if over:
+        (pool,) = [c["k"] for c in jax.eval_shape(eng._make_state).cache if "k" in c]
+        assert pool.shape[2:] == (4, 128)
+
+
+def check_paths(t):
+    """The decode step reads its one KV layer as stored (``paged``) and
+    steps its three state layers; an admission program runs the chunked
+    form and addresses its group's rows inside the whole pool
+    (``paged_rows``)."""
+    for scope in ("gdn_in_proj", "gdn_conv", "gdn_out", "attn_gate", "moe_shared", "moe_experts"):
+        assert scope in t.step_text and scope in t.chunk_text, scope
+    assert "gdn_step" in t.step_text and "gdn_chunk" not in t.step_text
+    assert "gdn_chunk" in t.chunk_text and "gdn_step" not in t.chunk_text
+    n_state = t.cfg.layer_types.count(LINEAR)
+    assert t.after_step["gdn/path{path=step}"] == n_state and "gdn/path{path=chunk}" not in t.after_step
+    assert t.after_step["attention/decode_path{path=paged}"] == 1
+    assert t.counters["gdn/path{path=chunk}"] == n_state
+    assert t.counters["attention/decode_path{path=paged_rows}"] == 1
+    assert "ssm/path{path=step}" not in t.after_step and "ssm/path{path=scan}" not in t.counters
+
+
+FAMILY = Family(
+    name="qwen3_next", config_cls=Qwen3NextConfig, model_cls=Qwen3NextModel, reference=ref, arch=ARCH,
+    reference_cfg=reference_cfg, init_cache=init_qwen3_next_cache, tol=TOL, logprob_tol=3e-5,
+    cache_layouts=(STATE, STATE, STATE, DENSE),
+    refusals={"qwen3_next": [
+        ({"rope_scaling": {"type": "linear", "factor": 2}}, "rope_scaling"),
+        ({"mlp_only_layers": [1]}, "mlp_only_layers"),
+        ({"decoder_sparse_step": 2}, "decoder_sparse_step"),
+        ({"use_sliding_window": True}, "use_sliding_window"),
+        ({"attention_bias": True}, "attention_bias"),
+        ({"tie_word_embeddings": True}, "tie_word_embeddings"),
+        ({"hidden_act": "gelu"}, "hidden_act"),
+        ({"kv_cache_dtype": "int8"}, "kv_cache_dtype='int8' beside state layers"),
+        ({"state_dtype": "bfloat16"}, "state_dtype"),
+        ({"num_experts": 14}, "not among the router's 16"),
+        ({"linear_num_value_heads": 3}, "linear_num_value_heads"),
+        ({"num_key_value_heads": 3}, "num_key_value_heads"),
+        ({"layer_types": ["attention"] * 4}, "layer_types"),
+    ]},
+    engine_cases={"whole": (0, False, {}), "chunked": (4, False, {}), "chunk-a-pump": (4, True, {}),
+                  "chunk-a-pump-heads-of-256": (4, True, {"head_dim": 256})},
+    check_forward=check_forward, check_engine=check_engine, check_paths=check_paths,
+    check_registry=check_registry, refuse_more=refuse_more,
+)
+
+
+# ------------------------------ the model ------------------------------ #
+
+
+@pytest.mark.parametrize("head_dim", [16, 256], ids=["heads_of_16", "heads_of_256_held_in_lane_rows"])
+@pytest.mark.parametrize("chunk", [0, 4], ids=["whole", "chunked"])
+def test_admission_then_decode_through_state_and_paged_pool_matches_the_full_forward(chunk, head_dim):
+    """An admission of 16 columns (whole, or in chunks of 4 that carry the
+    state and the tail from call to call) then five steps, the full layer
+    through a paged pool whose second row's blocks are rotated, the linear
+    layers through their state: logits against the reference's full
+    forward. The pool is as its holder keeps it (``hold_pool``): at the
+    published head of 256, each head as two lane rows."""
+    cfg, model, params = model_and_params(FAMILY, head_dim=head_dim)
+    T, Q, cap = 21, 16, 24
+    ids, mask = left_padded([21, 13, 6], T, seed=1)
+    _, cached, reference = programs(FAMILY, head_dim=head_dim)
+    want = reference(params, ids, mask)
+    cache = paged(FAMILY, cfg, 3, cap, rotate=1, hold=True)
+    assert [cache_kind(c).layout for c in cache] == [STATE, STATE, STATE, PAGED]
+    assert cache[3]["k"].shape == ((3, cap, 4, 128) if head_dim == 256 else (3, cap, 2, 16))
+    positions = positions_of(mask)
+    for lo in range(0, Q, chunk or Q):
+        hi = lo + (chunk or Q)
+        out = cached(params, ids[:, lo:hi], grow(mask[:, :Q], cap), cache, jnp.asarray(lo) if chunk else 0,
+                     positions[:, lo:hi])
+        cache = out["cache"]
+        assert rel_err(out["logits"], want[:, lo:hi], mask[:, lo:hi]) < TOL
+    for t in range(Q, T):
+        out = cached(params, ids[:, t : t + 1], grow(mask[:, : t + 1], cap), cache, jnp.full((3,), t, jnp.int32),
+                     positions[:, t : t + 1])
+        cache = out["cache"]
+        assert rel_err(out["logits"][:, 0], want[:, t], mask[:, t]) < TOL
 
 
 def test_hybrid_cache_takes_the_kinds_that_hold_keys_from_its_caller():
@@ -254,6 +222,12 @@ def test_hybrid_cache_takes_the_kinds_that_hold_keys_from_its_caller():
 
 
 # ---------------------------- ops/delta.py ------------------------------ #
+
+
+# the rule's forms as one program a shape (tests/family_harness.py says why)
+gated_delta_chunk = jax.jit(delta.gated_delta_chunk, static_argnames=("chunk",))
+gated_delta_step = jax.jit(delta.gated_delta_step)
+unit_lower_inverse = jax.jit(delta.unit_lower_inverse)
 
 
 def rule_inputs(B=2, T=24, H=3, Dk=8, Dv=16, seed=0, dtype=jnp.float32):
@@ -282,7 +256,7 @@ def recurrence(q, k, v, g, beta, state):
 def steps(a, state, lo=0, hi=None):
     outs = []
     for t in range(lo, a["q"].shape[1] if hi is None else hi):
-        o, state = delta.gated_delta_step(*(a[n][:, t] for n in ("q", "k", "v", "g", "beta")), state)
+        o, state = gated_delta_step(*(a[n][:, t] for n in ("q", "k", "v", "g", "beta")), state)
         outs.append(o)
     return jnp.stack(outs, axis=1), state
 
@@ -294,7 +268,7 @@ def test_the_chunked_form_the_step_and_the_recurrence_agree(chunk):
     a = rule_inputs()
     state = jax.random.normal(jax.random.PRNGKey(9), (2, 3, 8, 16))
     want_o, want_s = recurrence(**a, state=state)
-    o, s = delta.gated_delta_chunk(**a, state=state, chunk=chunk)
+    o, s = gated_delta_chunk(**a, state=state, chunk=chunk)
     np.testing.assert_allclose(np.asarray(o), want_o, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(s), want_s, rtol=2e-5, atol=2e-5)
     o, s = steps(a, state)
@@ -305,10 +279,10 @@ def test_the_chunked_form_the_step_and_the_recurrence_agree(chunk):
 def test_two_calls_that_carry_the_state_equal_one_and_steps_go_on_from_a_chunk():
     a = rule_inputs(T=16)
     zero = jnp.zeros((2, 3, 8, 16))
-    whole_o, whole_s = delta.gated_delta_chunk(**a, state=zero, chunk=4)
+    whole_o, whole_s = gated_delta_chunk(**a, state=zero, chunk=4)
     cut = lambda lo, hi: {n: v[:, lo:hi] for n, v in a.items()}
-    o1, s1 = delta.gated_delta_chunk(**cut(0, 10), state=zero, chunk=4)  # a call that ends inside a chunk
-    o2, s2 = delta.gated_delta_chunk(**cut(10, 16), state=s1, chunk=4)
+    o1, s1 = gated_delta_chunk(**cut(0, 10), state=zero, chunk=4)  # a call that ends inside a chunk
+    o2, s2 = gated_delta_chunk(**cut(10, 16), state=s1, chunk=4)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([o1, o2], 1)), np.asarray(whole_o), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(s2), np.asarray(whole_s), rtol=1e-5, atol=1e-5)
     o3, s3 = steps(a, s1, 10)  # an admission, then decode steps
@@ -321,11 +295,11 @@ def test_the_unit_triangular_solve_against_a_plain_inverse():
     one, ``beta`` one), where the Neumann factors grow like binomials."""
     lower = jnp.tril(jnp.ones((64, 64)), -1)
     for A in (0.3 * jax.random.normal(jax.random.PRNGKey(0), (2, 3, 64, 64)) * lower, lower[None]):
-        got = delta.unit_lower_inverse(A)
+        got = unit_lower_inverse(A)
         want = np.linalg.inv(np.eye(64) + np.asarray(A, np.float64))
         np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4 * np.abs(want).max())
     with pytest.raises(ValueError, match="power of two"):
-        delta.unit_lower_inverse(jnp.zeros((6, 6)))
+        unit_lower_inverse(jnp.zeros((6, 6)))
 
 
 def test_a_masked_column_leaves_the_state_bit_for_bit():
@@ -336,10 +310,10 @@ def test_a_masked_column_leaves_the_state_bit_for_bit():
     state = jax.random.normal(jax.random.PRNGKey(3), (2, 3, 8, 16))
     mask = jnp.asarray([[0] * 8, [0, 0, 0, 1, 1, 1, 1, 1]], jnp.float32)
     masked = dict(a, g=a["g"] * mask[..., None], beta=a["beta"] * mask[..., None])
-    _, s = delta.gated_delta_chunk(**masked, state=state, chunk=4)
+    _, s = gated_delta_chunk(**masked, state=state, chunk=4)
     np.testing.assert_array_equal(np.asarray(s[0]), np.asarray(state[0]))
     assert not np.allclose(np.asarray(s[1]), np.asarray(state[1]))
-    _, s1 = delta.gated_delta_step(*(masked[n][:, 0] for n in ("q", "k", "v", "g", "beta")), state)
+    _, s1 = gated_delta_step(*(masked[n][:, 0] for n in ("q", "k", "v", "g", "beta")), state)
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(state))  # column 0 is masked in both rows
     keys = jax.random.split(jax.random.PRNGKey(4), 6)
     Hk, Hv, Dk, Dv = 2, 4, 8, 16
@@ -379,8 +353,8 @@ def long_carry(state_dtype, seed, T=320, T0=256):
     zero = jnp.zeros((1, H, Dk, Dv))
     want_o, want_s = recurrence(**a, state=zero)
     cut = {n: v[:, :T0] for n, v in a.items()}
-    _, state = delta.gated_delta_chunk(**cut, state=zero, chunk=64)
-    step, got = jax.jit(delta.gated_delta_step), []
+    _, state = gated_delta_chunk(**cut, state=zero, chunk=64)
+    step, got = gated_delta_step, []
     for t in range(T0, T):
         o, state = step(*(a[n][:, t] for n in ("q", "k", "v", "g", "beta")), state.astype(state_dtype))
         got.append(np.asarray(o, np.float64))
@@ -415,7 +389,7 @@ def test_the_gated_norm_is_the_norm_first_and_the_gate_after():
 
 def test_the_uncached_chunked_form_is_differentiable():
     a = rule_inputs(T=8)
-    loss = lambda v: delta.gated_delta_chunk(a["q"], a["k"], v, a["g"], a["beta"], jnp.zeros((2, 3, 8, 16)), 4)[0].sum()
+    loss = lambda v: gated_delta_chunk(a["q"], a["k"], v, a["g"], a["beta"], jnp.zeros((2, 3, 8, 16)), 4)[0].sum()
     g = jax.grad(loss)(a["v"])
     assert np.isfinite(np.asarray(g)).all() and float(jnp.abs(g).sum()) > 0
 
@@ -472,7 +446,7 @@ def test_the_four_shares_and_the_gated_shared_expert_once_add_up_to_the_uncut_la
     computes for the whole layer (all 16 held) - through the model's own
     block, the shared term computed by the family."""
     cfg_whole = dict(ARCH, num_hidden_layers=1, full_attention_interval=4)
-    cfg0, model0, params0 = model_and_params(num_hidden_layers=1, first_local_expert=0)
+    cfg0, model0, params0 = model_and_params(FAMILY, num_hidden_layers=1, first_local_expert=0)
     ids, mask = left_padded([9, 4], 9, seed=3)
     keys = jax.random.split(jax.random.PRNGKey(7), 3)
     all_experts = {n: 0.1 * jax.random.normal(k, (16,) + params0["h_0"]["mlp"][n].shape[1:])
@@ -496,22 +470,26 @@ def test_the_four_shares_and_the_gated_shared_expert_once_add_up_to_the_uncut_la
             w = ref.router_weights(h, blk["mlp"]["router"], cf["num_experts_per_tok"])
             return h, ref.held_experts(h, blk["mlp"], w[..., first : first + held]), ref.shared_expert(h, blk["shared"])
 
-    h, whole_routed, shared = moe_out(with_experts(0, 16), rc, 0, 16)
+    h, whole_routed, shared = jax.jit(lambda tree: moe_out(tree, rc, 0, 16))(with_experts(0, 16))
     parts = []
     for first in (0, 4, 8, 12):
         cfg = Qwen3NextConfig.from_dict(dict(cfg_whole, first_local_expert=first))
         tree = with_experts(first, 4)
-        term = Qwen3NextSharedExpert(cfg).apply({"params": tree["h_0"]["shared"]}, h)
+        @jax.jit
+        def this_share(tree, cfg=cfg, first=first):  # the family's own modules and the reference, one program a share
+            term = Qwen3NextSharedExpert(cfg).apply({"params": tree["h_0"]["shared"]}, h)
+            with_shared, stats = Qwen3NextSparseMLP(cfg).apply({"params": tree["h_0"]["mlp"]}, h, term)
+            without, _ = Qwen3NextSparseMLP(cfg).apply({"params": tree["h_0"]["mlp"]}, h, None)
+            got = Qwen3NextModel(cfg).apply({"params": tree}, ids, attention_mask=mask)["hidden"]
+            return term, with_shared, without, stats, got, ref.trunk(tree, dict(rc, first_local_expert=first), ids, mask)
+
+        term, with_shared, without, stats, got, want = this_share(tree)
         np.testing.assert_allclose(np.asarray(term), np.asarray(shared), rtol=2e-5, atol=2e-6)
-        with_shared, stats = Qwen3NextSparseMLP(cfg).apply({"params": tree["h_0"]["mlp"]}, h, term)
-        without, _ = Qwen3NextSparseMLP(cfg).apply({"params": tree["h_0"]["mlp"]}, h, None)
         np.testing.assert_allclose(np.asarray(with_shared - without), np.asarray(shared), rtol=1e-4, atol=1e-5)
         parts.append(without)
         assert 0 < float(stats["rows_here_share"]) < 1 and float(stats["experts_touched"]) <= 4
         # and the model's own forward with this share is the reference's with the same share
-        got = Qwen3NextModel(cfg).apply({"params": tree}, ids, attention_mask=mask)["hidden"]
-        want = ref.trunk(tree, dict(rc, first_local_expert=first), ids, mask)
-        assert rel_err(got, want, mask) < 2e-5
+        assert rel_err(got, want, mask) < TOL
     total = sum(parts) + shared  # the shared expert counted once
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole_routed + shared), rtol=2e-4, atol=2e-5)
     # no share is the whole: the absent experts' terms are left out
@@ -520,96 +498,6 @@ def test_the_four_shares_and_the_gated_shared_expert_once_add_up_to_the_uncut_la
 
 # ------------------------------ the engine ------------------------------ #
 
-Q, R, EOS = 16, 6, 95
-
-
-@functools.lru_cache(maxsize=None)
-def engine(prefill_chunk=0, chunks_per_pump=0, **over):
-    from trlx_tpu.inference.engine import ContinuousBatchingEngine
-    from trlx_tpu.models.heads import CausalLMWithValueHead
-    from trlx_tpu.ops.sampling import GenerationConfig
-
-    cfg, _, _ = model_and_params(**over)
-    model = CausalLMWithValueHead(cfg, backbone_cls=Qwen3NextModel)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    params = dict(params, transformer=model_and_params(**over)[2])
-
-    def apply_fn(p, input_ids, attention_mask=None, position_ids=None, cache=None,
-                 cache_index=None, last_only=False):
-        return model.apply({"params": p}, input_ids, attention_mask=attention_mask,
-                           position_ids=position_ids, cache=cache, cache_index=cache_index,
-                           last_only=last_only)
-
-    gen = GenerationConfig(max_new_tokens=R, min_new_tokens=1, eos_token_id=EOS,
-                           pad_token_id=EOS, do_sample=True)
-    eng = ContinuousBatchingEngine(
-        apply_fn=apply_fn, init_cache_fn=functools.partial(init_qwen3_next_cache, cfg),
-        gen_config=gen, query_length=Q, vocab_size=cfg.vocab_size, num_slots=4, admit_width=2,
-        harvest_width=2, block_size=4, prefill_chunk=prefill_chunk,
-        prefill_chunks_per_pump=chunks_per_pump,
-    )
-    return eng, params
-
-
-def drive(eng, params, ids, mask, pump):
-    eng.start_phase(params, jax.random.PRNGKey(5))
-    got = {}
-
-    def land(group):
-        arrs = {k: np.asarray(group[k]) for k in ("tokens", "response_mask", "logprobs")}
-        for j, r in enumerate(group["rows"]):
-            got[r] = {k: v[j] for k, v in arrs.items()}
-
-    if not pump:
-        eng.submit(ids, mask)
-        for group in eng.drive(len(ids)):
-            land(group)
-        return got
-    fed = 0
-    while len(got) < len(ids):
-        free = eng.free_capacity
-        if fed < len(ids) and free > 0:
-            take = min(free, eng.admit_width, len(ids) - fed)
-            eng.submit(ids[fed : fed + take], mask[fed : fed + take])
-            fed += take
-        for group in eng.pump():
-            land(group)
-    return got
-
-
-@pytest.mark.parametrize("chunk,pump,over", [(0, False, {}), (4, False, {}), (4, True, {}),
-                                             (4, True, {"head_dim": 256})],
-                         ids=["whole", "chunked", "chunk-a-pump", "chunk-a-pump-heads-of-256"])
-def test_engine_logprobs_match_the_uncached_forward_on_the_tokens_it_drew(chunk, pump, over):
-    """Ten requests through four slots: every slot is recycled, after
-    requests of other lengths (the longest first), with whole and chunked
-    admission. The recorded log-probability of every drawn token is the
-    reference's on [prompt; drawn tokens]. At the published head of 256 the
-    engine holds the pool in lane rows (``ops/kv_cache.py::hold_pool``):
-    recycled slots' rotated tables, the block write, the gathered view and
-    the read as stored all go through the held shape."""
-    eng, params = engine(chunk, 1 if pump else 0, **over)
-    cfg = model_and_params(**over)[0]
-    if over:
-        (pool,) = [c["k"] for c in jax.eval_shape(eng._make_state).cache if "k" in c]
-        assert pool.shape[2:] == (4, 128)
-    lens = [16, 15, 3, 9, 2, 12, 5, 16, 4, 7]
-    ids, mask = left_padded(lens, Q, seed=4)
-    ids, mask = np.asarray(ids), np.asarray(mask)
-    got = drive(eng, params, ids, mask, pump)
-    assert sorted(got) == list(range(len(lens)))
-    forward = jax.jit(lambda p, i, m: ref.forward(p, reference_cfg(cfg, **over), i, m))
-    for r, row in got.items():
-        full_ids = jnp.asarray(np.r_[ids[r], row["tokens"]])[None]
-        full_mask = jnp.asarray(np.r_[mask[r], row["response_mask"]])[None]
-        logits = forward(params["transformer"], full_ids, full_mask)[0]
-        lp = jax.nn.log_softmax(logits[Q - 1 : -1], axis=-1)
-        want = np.take_along_axis(np.asarray(lp), row["tokens"][:, None], axis=1)[:, 0]
-        live = row["response_mask"].astype(bool)
-        np.testing.assert_allclose(row["logprobs"][live], want[live], rtol=0, atol=3e-5)
-    if chunk:
-        assert eng.stats.prefill_cols_skipped > 0  # all-pad chunks were not computed
-
 
 def test_engine_and_fixed_sampler_refuse_what_a_state_layer_cannot_give():
     from trlx_tpu import telemetry
@@ -617,7 +505,7 @@ def test_engine_and_fixed_sampler_refuse_what_a_state_layer_cannot_give():
     from trlx_tpu.ops.sampling import GenerationConfig, make_sampler
     from trlx_tpu.parallel.mesh import make_mesh
 
-    cfg = model_and_params()[0]
+    cfg = model_and_params(FAMILY)[0]
     init = functools.partial(init_qwen3_next_cache, cfg)
     common = dict(apply_fn=lambda *a, **k: None, init_cache_fn=init,
                   gen_config=GenerationConfig(max_new_tokens=4), query_length=8, vocab_size=96, num_slots=2)
@@ -638,36 +526,3 @@ def test_engine_and_fixed_sampler_refuse_what_a_state_layer_cannot_give():
     sampler = make_sampler(lambda *a, **k: None, init, GenerationConfig(max_new_tokens=4), 8, with_values=False)
     with pytest.raises(ValueError, match="rollout.engine: continuous"):
         sampler(None, jnp.zeros((2, 8), jnp.int32), jnp.ones((2, 8), jnp.int32), jax.random.PRNGKey(0))
-
-
-def test_which_paths_the_engines_programs_traced():
-    """Counted per traced call site: the decode step reads its one KV layer
-    as stored (``paged``) and steps its three state layers; an admission
-    program runs the chunked form and addresses its group's rows inside the
-    whole pool (``paged_rows``), none left under ``generic``; the device
-    scopes docs/observability.md names are in the lowered programs."""
-    from trlx_tpu import telemetry
-
-    eng, params = engine.__wrapped__(4, 1)  # its own: a program traced before counts nothing again
-    cfg = model_and_params()[0]
-    with telemetry.scoped_metrics() as reg:
-        state = jax.eval_shape(eng._make_state)
-        abstract = jax.eval_shape(lambda: params)
-        step = eng.decode_step_jit.lower(abstract, state)
-        after_step = dict(reg.snapshot()["counters"])
-        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-        chunk = eng.prefill_chunk_jit.lower(abstract, state, i32(2), i32(2, Q), i32(2, Q), i32(2), i32(2),
-                                            jax.ShapeDtypeStruct((2,), jnp.uint32), i32())
-        after_chunk = reg.snapshot()["counters"]
-    step_text, chunk_text = step.as_text(debug_info=True), chunk.as_text(debug_info=True)
-    for scope in ("gdn_in_proj", "gdn_conv", "gdn_out", "attn_gate", "moe_shared", "moe_experts"):
-        assert scope in step_text and scope in chunk_text, scope
-    assert "gdn_step" in step_text and "gdn_chunk" not in step_text
-    assert "gdn_chunk" in chunk_text and "gdn_step" not in chunk_text
-    n_state = cfg.layer_types.count(LINEAR)
-    assert after_step["gdn/path{path=step}"] == n_state and "gdn/path{path=chunk}" not in after_step
-    assert after_step["attention/decode_path{path=paged}"] == 1
-    assert after_chunk["gdn/path{path=chunk}"] == n_state
-    assert after_chunk["attention/decode_path{path=paged_rows}"] == 1
-    assert "attention/decode_path{path=generic}" not in after_chunk
-    assert "ssm/path{path=step}" not in after_step and "ssm/path{path=scan}" not in after_chunk

@@ -32,11 +32,8 @@ import os
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 from trlx_tpu.analysis.findings import Finding, filter_suppressed
+from trlx_tpu.analysis.jaxpr_audit import is_jit_eqn
 from trlx_tpu.analysis.registry import get_rule
-
-# jit spellings whose donate_argnums mark an assigned callable as donating
-_JIT_SUFFIXES = ("jit", "pjit")
-
 
 # ----------------------------- jaxpr rules ------------------------------- #
 
@@ -44,7 +41,7 @@ def _donating_pjit(closed_jaxpr):
     """(inner jaxpr, donated mask) of a traced jitted callable, or
     (outer jaxpr, all-False) when no pjit wrapper is present."""
     outer = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
-    pjit_eqns = [e for e in outer.eqns if e.primitive.name == "pjit"]
+    pjit_eqns = [e for e in outer.eqns if is_jit_eqn(e)]
     if len(outer.eqns) == 1 and pjit_eqns:
         eqn = pjit_eqns[0]
         inner = eqn.params["jaxpr"].jaxpr
@@ -114,25 +111,34 @@ def check_alias_escape(
     input_paths: Optional[Sequence[str]] = None,
     def_site: Optional[Tuple[str, int]] = None,
 ) -> List[Finding]:
-    """Outputs that ARE non-donated inputs: pjit forwards the caller's
+    """Outputs that ARE non-donated inputs: jit forwards the caller's
     buffer instead of materializing a fresh one (forwarding a *donated*
-    input is intended aliasing and allowed). jax hoists pass-through
-    outputs OUT of the pjit body, so the check runs on the outer jaxpr:
-    an outer outvar that is an outer invar never went through the
-    program at all — it is the caller's buffer, returned."""
+    input is intended aliasing and allowed). A pass-through output shows
+    in the traced program in one of two ways, and both are read: the jit
+    call's body returns one of its own inputs (this jax), or the outer
+    jaxpr returns its own input and the value never enters the jit call
+    (the ones before hoisted it out). Either way it is the caller's
+    buffer, returned."""
     rule = get_rule("alias-escape")
     outer = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
     donated_by_var: Dict[int, bool] = {}
+    forwards: Dict[int, Any] = {}  # a jit call's output -> the operand its body returns
     for eqn in outer.eqns:
-        if eqn.primitive.name != "pjit":
+        if not is_jit_eqn(eqn):
             continue
         for v, don in zip(eqn.invars, eqn.params.get("donated_invars", ())):
             if not hasattr(v, "val"):
                 donated_by_var[id(v)] = donated_by_var.get(id(v), False) or don
+        body = eqn.params["jaxpr"].jaxpr
+        operand = {id(b): v for b, v in zip(body.invars, eqn.invars)}
+        for out, returned in zip(eqn.outvars, body.outvars):
+            if id(returned) in operand:
+                forwards[id(out)] = operand[id(returned)]
     in_index = {id(v): i for i, v in enumerate(outer.invars)}
     findings: List[Finding] = []
     file, line = def_site or (None, None)
     for o, v in enumerate(outer.outvars):
+        v = forwards.get(id(v), v)
         if hasattr(v, "val"):
             continue
         i = in_index.get(id(v))
@@ -200,9 +206,10 @@ def _dotted(node: ast.AST) -> Optional[str]:
 
 
 def _donate_positions(call: ast.Call) -> Optional[Tuple[int, ...]]:
-    """donate_argnums of a jax.jit/pjit call, or None when absent."""
+    """donate_argnums of a ``jit`` call as the source spells it
+    (``jax.jit``, ``jit``), or None when absent."""
     func = _dotted(call.func)
-    if func is None or func.split(".")[-1] not in _JIT_SUFFIXES:
+    if func is None or func.split(".")[-1] != "jit":
         return None
     for kw in call.keywords:
         if kw.arg != "donate_argnums":

@@ -707,6 +707,7 @@ def check_concat_hazard(program, repo_root: Optional[str] = None) -> List[Findin
         _repo_frame,
         _sub_jaxprs,
         default_repo_root,
+        frame_function,
     )
 
     rule = get_rule("spmd-concat-hazard")
@@ -759,7 +760,7 @@ def check_concat_hazard(program, repo_root: Optional[str] = None) -> List[Findin
                 ]
                 if len(operands) >= 2 and len(hot) >= 2:
                     frame = _repo_frame(eqn, repo_root)
-                    fn_name = getattr(frame, "function_name", "") if frame else ""
+                    fn_name = frame_function(frame) if frame else ""
                     if fn_name not in BLESSED_CONCAT_HELPERS:
                         file = frame.file_name if frame else None
                         line = frame.start_line if frame else None

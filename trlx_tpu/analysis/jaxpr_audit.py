@@ -92,6 +92,57 @@ def iter_eqns(jaxpr) -> Iterator[Any]:
             yield from iter_eqns(sub)
 
 
+# Call-like primitives besides the jit call, and the params key that
+# holds the sub-program each one enters.
+_CALL_JAXPR_KEYS = {
+    "closed_call": "call_jaxpr",
+    "core_call": "call_jaxpr",
+    "remat": "jaxpr",
+    "remat2": "jaxpr",
+    "checkpoint": "jaxpr",
+    "custom_jvp_call": "call_jaxpr",
+    "custom_vjp_call": "call_jaxpr",
+    "custom_vjp_call_jaxpr": "fun_jaxpr",
+}
+
+
+def is_jit_eqn(eqn) -> bool:
+    """Whether ``eqn`` calls a jitted sub-program (``params["jaxpr"]``,
+    ``donated_invars``): the primitive is named ``jit`` by the jax this
+    repo runs on and ``pjit`` by the ones before it."""
+    return eqn.primitive.name in ("jit", "pjit")
+
+
+def called_jaxpr(eqn):
+    """The (closed) jaxpr a call-like equation enters with its operands
+    mapped 1:1 (a jit call, ``closed_call``, remat, ``custom_jvp``/
+    ``custom_vjp``), or None."""
+    key = "jaxpr" if is_jit_eqn(eqn) else _CALL_JAXPR_KEYS.get(eqn.primitive.name)
+    return None if key is None else eqn.params.get(key)
+
+
+def user_frames(eqn) -> Iterator[Any]:
+    """The frames of ``eqn``'s traceback outside jax and the standard
+    library, innermost first: the one place the engines read a
+    ``source_info``. Nothing is caught here: when jax's own reader moves
+    or takes something else, the audit raises. An audit that cannot see
+    where an equation came from drops every located finding, which
+    reads as clean."""
+    source_info = getattr(eqn, "source_info", None)
+    if source_info is None:
+        return iter(())
+    from jax._src import source_info_util
+
+    return source_info_util.user_frames(source_info.traceback)
+
+
+def frame_function(frame) -> str:
+    """The function a frame ran in, as its ``def`` spells it: this jax
+    reports the qualified name (``GPT2Model.embed``, ``f.<locals>.g``),
+    the ones before it the bare one. Allow-lists name the bare one."""
+    return frame.function_name.rsplit(".", 1)[-1]
+
+
 def _repo_frame(eqn, repo_root: str, innermost_only: bool = False):
     """A traceback frame pointing into this repo, or None.
 
@@ -101,19 +152,11 @@ def _repo_frame(eqn, repo_root: str, innermost_only: bool = False):
     has a library file as its innermost frame even though repo lines sit
     above it in the stack; those libraries own their numerics.
     """
-    source_info = getattr(eqn, "source_info", None)
-    if source_info is None:
-        return None
-    try:
-        from jax._src import source_info_util
-
-        for frame in source_info_util.user_frames(source_info):
-            if repo_root in frame.file_name:
-                return frame
-            if innermost_only:
-                return None
-    except Exception:
-        return None
+    for frame in user_frames(eqn):
+        if repo_root in frame.file_name:
+            return frame
+        if innermost_only:
+            return None
     return None
 
 
@@ -209,12 +252,12 @@ def check_donation(
     ``n_state_leaves`` flat inputs (the train-state buffers)."""
     rule = get_rule("donation")
     inner = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
-    pjit_eqns = [e for e in inner.eqns if e.primitive.name == "pjit"]
+    pjit_eqns = [e for e in inner.eqns if is_jit_eqn(e)]
     if not pjit_eqns:
         return [
             Finding(
                 rule=rule.id,
-                message="no pjit equation found — the step function is "
+                message="no jit equation found — the step function is "
                 "not jitted at all",
                 severity=rule.severity,
                 subject=subject,
@@ -282,7 +325,7 @@ def check_precision_leak(
         for file_suffix, func in allowlist:
             if file_suffix and not rel.endswith(file_suffix):
                 continue
-            if func is not None and frame.function_name != func:
+            if func is not None and frame_function(frame) != func:
                 continue
             allowed = True
             break

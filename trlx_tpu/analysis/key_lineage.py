@@ -37,6 +37,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 from trlx_tpu.analysis.findings import Finding, Report, filter_suppressed
+from trlx_tpu.analysis.jaxpr_audit import called_jaxpr
 from trlx_tpu.analysis.registry import get_rule
 
 # primitives that CONSUME a key's randomness (a second consumption of the
@@ -52,19 +53,6 @@ KEY_CONSUMERS = {
 
 # identity-preserving wrappers: out is the SAME lineage as in
 _KEY_IDENTITY = {"random_wrap", "random_unwrap", "convert_element_type"}
-
-# call-like primitives entered with an invar->canonical mapping
-_CALL_PRIMS = {
-    "pjit": "jaxpr",
-    "closed_call": "call_jaxpr",
-    "core_call": "call_jaxpr",
-    "remat": "jaxpr",
-    "remat2": "jaxpr",
-    "checkpoint": "jaxpr",
-    "custom_jvp_call": "call_jaxpr",
-    "custom_vjp_call": "call_jaxpr",
-    "custom_vjp_call_jaxpr": "fun_jaxpr",
-}
 
 
 def _is_key_aval(aval) -> bool:
@@ -180,23 +168,20 @@ class _KeyFlow:
                     env[eqn.outvars[0]] = src
                 continue
 
-            if name in _CALL_PRIMS:
-                closed = eqn.params.get(_CALL_PRIMS[name])
-                if closed is not None:
-                    sub = getattr(closed, "jaxpr", closed)
-                    sub_env: Dict[Any, int] = {}
-                    for outer, inner_v in zip(eqn.invars, sub.invars):
-                        c = canon(outer)
+            closed = called_jaxpr(eqn)
+            if closed is not None:
+                sub = getattr(closed, "jaxpr", closed)
+                sub_env: Dict[Any, int] = {}
+                for outer, inner_v in zip(eqn.invars, sub.invars):
+                    c = canon(outer)
+                    if c is not None:
+                        sub_env[inner_v] = c
+                self._walk(sub, sub_env, repeat_ids)
+                for outer_out, inner_out in zip(eqn.outvars, sub.outvars):
+                    if not hasattr(inner_out, "val"):
+                        c = sub_env.get(inner_out)
                         if c is not None:
-                            sub_env[inner_v] = c
-                    self._walk(sub, sub_env, repeat_ids)
-                    for outer_out, inner_out in zip(
-                        eqn.outvars, sub.outvars
-                    ):
-                        if not hasattr(inner_out, "val"):
-                            c = sub_env.get(inner_out)
-                            if c is not None:
-                                env[outer_out] = c
+                            env[outer_out] = c
                 continue
 
             if name == "scan":

@@ -51,6 +51,9 @@ from dataclasses import dataclass, replace
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from trlx_tpu.analysis.findings import Finding
+from trlx_tpu.analysis.jaxpr_audit import (
+    _repo_frame, called_jaxpr, frame_function, is_jit_eqn,
+)
 from trlx_tpu.analysis.registry import get_rule
 
 # (file suffix, function name) pairs allowed to run the flagged op
@@ -272,19 +275,14 @@ class _Analyzer:
         tb = getattr(source_info, "traceback", None)
         if tb is None:
             return False
-        try:
-            for frame in tb.frames:
-                fn = frame.file_name
-                if "/jax/" in fn or "/jaxlib/" in fn:
-                    continue  # jax machinery is transparent
-                return self.repo_root not in fn
-        except Exception:
-            return False
+        for frame in tb.frames:  # a traceback without `frames` raises
+            fn = frame.file_name
+            if "/jax/" in fn or "/jaxlib/" in fn:
+                continue  # jax machinery is transparent
+            return self.repo_root not in fn
         return False
 
     def _report(self, eqn, rule_id: str, message: str) -> None:
-        from trlx_tpu.analysis.jaxpr_audit import _repo_frame
-
         frame = _repo_frame(eqn, self.repo_root, innermost_only=True)
         if frame is None:
             return  # library-internal numerics guard themselves
@@ -296,7 +294,7 @@ class _Analyzer:
         for file_suffix, func in self.allowlist:
             if file_suffix and not rel.endswith(file_suffix):
                 continue
-            if func is not None and frame.function_name != func:
+            if func is not None and frame_function(frame) != func:
                 continue
             return  # curated: the site's invariant is documented
         rule = get_rule(rule_id)
@@ -344,20 +342,10 @@ class _Analyzer:
         ``jnp.where`` must not erase the guard they establish)."""
         name = eqn.primitive.name
         params = eqn.params
-        if name in ("pjit", "closed_call", "core_call", "remat", "remat2",
-                    "checkpoint", "custom_vjp_call_jaxpr"):
-            closed = params.get("jaxpr") or params.get("fun_jaxpr")
-            if closed is None:
-                return None
+        closed = called_jaxpr(eqn)
+        if closed is not None:
             inner = getattr(closed, "jaxpr", closed)
-            consts = getattr(closed, "consts", ())
-            return self.walk(inner, consts, facts)
-        if name in ("custom_jvp_call", "custom_vjp_call"):
-            closed = params.get("call_jaxpr") or params.get("fun_jaxpr")
-            if closed is not None:
-                inner = getattr(closed, "jaxpr", closed)
-                return self.walk(inner, getattr(closed, "consts", ()), facts)
-            return None
+            return self.walk(inner, getattr(closed, "consts", ()), facts)
         if name == "scan":
             closed = params["jaxpr"]
             inner = getattr(closed, "jaxpr", closed)
@@ -405,10 +393,8 @@ class _Analyzer:
         name = eqn.primitive.name
         n_out = len(eqn.outvars)
 
-        if name in ("pjit", "closed_call", "core_call", "remat", "remat2",
-                    "checkpoint", "custom_jvp_call", "custom_vjp_call",
-                    "custom_vjp_call_jaxpr", "scan", "while", "cond",
-                    "shard_map"):
+        if called_jaxpr(eqn) is not None or name in (
+                "scan", "while", "cond", "shard_map"):
             sub_out = self._sub_jaxpr_facts(eqn, facts)
             if sub_out is not None and len(sub_out) == n_out:
                 return sub_out
@@ -605,10 +591,9 @@ class _Analyzer:
         """Pick the rule id: the where-grad-trap variant when the risky
         op's output feeds a select_n at this jaxpr level."""
         def _is_select(c) -> bool:
-            # jnp.where arrives as a pjit named `_where` wrapping select_n
+            # jnp.where arrives as a jit call named `_where` wrapping select_n
             return c.primitive.name == "select_n" or (
-                c.primitive.name == "pjit"
-                and c.params.get("name") == "_where"
+                is_jit_eqn(c) and c.params.get("name") == "_where"
             )
 
         feeds_select = any(

@@ -44,6 +44,7 @@ from typing import (
 )
 
 from trlx_tpu.analysis.findings import Finding, Report
+from trlx_tpu.analysis.jaxpr_audit import is_jit_eqn
 from trlx_tpu.analysis.registry import get_rule
 
 BUDGETS_SCHEMA_VERSION = 1
@@ -363,7 +364,7 @@ def analyze_closed_jaxpr(
     outer = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
     axis_sizes = axis_sizes or {}
     target, donated, divisors = outer, None, input_divisors
-    pjit_eqns = [e for e in outer.eqns if e.primitive.name == "jit"]
+    pjit_eqns = [e for e in outer.eqns if is_jit_eqn(e)]
     if len(outer.eqns) == 1 and pjit_eqns:
         eqn = pjit_eqns[0]
         target = eqn.params["jaxpr"].jaxpr

@@ -30,20 +30,8 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from trlx_tpu.analysis.findings import Finding, Report
+from trlx_tpu.analysis.jaxpr_audit import called_jaxpr
 from trlx_tpu.analysis.registry import get_rule
-
-# Call-like primitives entered recursively (params key holding the jaxpr).
-_CALL_PRIMS = {
-    "pjit": "jaxpr",
-    "closed_call": "call_jaxpr",
-    "core_call": "call_jaxpr",
-    "remat": "jaxpr",
-    "remat2": "jaxpr",
-    "checkpoint": "jaxpr",
-    "custom_jvp_call": "call_jaxpr",
-    "custom_vjp_call": "call_jaxpr",
-    "custom_vjp_call_jaxpr": "fun_jaxpr",
-}
 
 
 @dataclass
@@ -197,15 +185,12 @@ class _Replayer:
 
     def _eval_eqn(self, eqn, invals, names: Dict[int, str]):
         name = eqn.primitive.name
-        if name in _CALL_PRIMS:
-            closed = eqn.params.get(_CALL_PRIMS[name])
-            if closed is not None:
-                inner = getattr(closed, "jaxpr", closed)
-                consts = getattr(closed, "consts", ())
-                inner_names = [
-                    names.get(id(v)) for v in eqn.invars
-                ]
-                return self.replay(inner, consts, invals, arg_names=inner_names)
+        closed = called_jaxpr(eqn)
+        if closed is not None:
+            inner = getattr(closed, "jaxpr", closed)
+            consts = getattr(closed, "consts", ())
+            inner_names = [names.get(id(v)) for v in eqn.invars]
+            return self.replay(inner, consts, invals, arg_names=inner_names)
         if name == "scan":
             return self._eval_scan(eqn, invals, names)
         if name == "cond":

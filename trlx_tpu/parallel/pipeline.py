@@ -611,15 +611,13 @@ def pipeline_apply_remat(
             _, dxs, dparams, daux = jax.lax.fori_loop(
                 0, S + M - 1, tick, (buf0, dxs0, dp0, da0)
             )
-            # each data shard saw only its rows of every microbatch, so its
-            # dparams is a PARTIAL batch sum — reduce over the batch axes
-            # (autodiff gets this psum from shard_map's transpose; omitting
-            # it here left the out_spec claiming a replication that did not
-            # hold, and check_rep=False silently shipped one shard's
-            # partial: stage grads were wrong on any dp/fsdp > 1 mesh)
-            dparams = jax.tree_util.tree_map(
-                lambda d: jax.lax.psum(d, batch_axes), dparams
-            )
+            # each data shard saw only its rows of every microbatch, and
+            # dparams is still the WHOLE batch's sum: this shard_map tracks
+            # which axes a value varies over, the stage parameters do not
+            # vary over the batch axes, and `jax.vjp` therefore hands back
+            # their cotangent already summed over those axes (the transpose
+            # of the broadcast it inserted). A psum here would count every
+            # shard's rows once more for each data shard.
             dxs = jnp.where(idx == 0, dxs, jnp.zeros_like(dxs))
             dxs = jax.lax.psum(dxs, axis_name)
             # aux is shared by every stage: total cotangent sums over pp
